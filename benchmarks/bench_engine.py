@@ -12,8 +12,7 @@ os.environ.setdefault("LIBTPU_INIT_ARGS",
 # scheduler + async collectives) via the import-time env hook BEFORE the
 # backend initializes. Only effective for the FIRST engine of a process
 # — in-process variant re-timings change the program-level annotations
-# but inherit the headline's flags (full-flag A/B runs per-variant
-# subprocesses, __graft_entry__.measured_multichip).
+# but inherit the headline's flags.
 if os.environ.get("BENCH_COMM_OVERLAP") == "1":
     os.environ.setdefault("DSTPU_COMM_OVERLAP", "1")
 
@@ -101,6 +100,9 @@ def build_bench_engine():
     import deepspeed_tpu
     from deepspeed_tpu.models import GPT2, GPT2MoE, GPT2MoEConfig
     from deepspeed_tpu.utils import groups
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     cfg = build_bench_config()
     seq_len = cfg.max_seq_len

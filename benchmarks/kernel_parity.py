@@ -766,14 +766,17 @@ _GATES = (
 )
 
 
-def run(seed=0):
-    """Run every kernel parity gate on the default backend. Returns
-    {gate_name: "ok" | "FAILED: ..."} — failures are isolated so one
-    broken path never hides the status of the rest."""
+def run(seed=0, only=None):
+    """Run every kernel parity gate (or the ``only`` named ones) on the
+    default backend. Returns {gate_name: "ok" | "FAILED: ..."} — failures
+    are isolated so one broken path never hides the status of the rest;
+    the caller decides what a non-"ok" entry costs."""
     rng = jax.random.key(seed)
     rngs = jax.random.split(rng, len(_GATES))
     out = {}
     for (name, fn), r in zip(_GATES, rngs):
+        if only is not None and name not in only:
+            continue
         try:
             fn(r)
             out[name] = "ok"

@@ -9,8 +9,6 @@ reference's ``deepspeed/__init__.py``: ``initialize`` (:69),
 
 __version__ = "0.1.0"
 
-from .utils import compat as _compat  # noqa: F401  (older-jax shims)
-
 # DSTPU_COMM_OVERLAP=1: apply the comm-overlap XLA flag set (latency-
 # hiding scheduler + async collectives; runtime/zero/overlap.py) NOW,
 # before anything can initialize the backend — the only reliable point

@@ -25,6 +25,12 @@ def get_accelerator():
     from .tpu_accelerator import TpuAccelerator
     acc = TpuAccelerator()
     if not acc.is_available():
+        # right for tests and dev containers, wrong for a run that claims
+        # the chip — so never silent (once: the choice is cached above)
+        from ..utils.logging import logger
+        logger.warning(
+            "get_accelerator(): JAX found no TPU; using the CPU "
+            "accelerator (set DS_ACCELERATOR to choose explicitly)")
         acc = _make("cpu")
     set_accelerator(acc)
     return _accelerator

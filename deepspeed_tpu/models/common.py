@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 
 def resolve_flash(value):
@@ -22,11 +23,8 @@ def constrain_fn():
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
         return lambda x, spec: x
-    axis_types = getattr(mesh, "axis_types", None)
-    if axis_types is None:        # older jax (compat shim): the ambient
-        return lax.with_sharding_constraint   # mesh is always GSPMD-auto
     from jax.sharding import AxisType
-    if not any(t == AxisType.Auto for t in axis_types):
+    if not any(t == AxisType.Auto for t in mesh.axis_types):
         return lambda x, spec: x
     return lax.with_sharding_constraint
 
@@ -203,17 +201,29 @@ def fused_linear_xent_kernel(norm_fn, chunk, norm_params, w, hidden,
     return _fused_xent_k(norm_fn, chunk, norm_params, w, hidden, targets)
 
 
+def _sharded_unembed_stats(h, w, targets):
+    """The Pallas unembed kernel over batch-sharded rows: each device
+    scores its own (batch-major) rows against the whole unembed matrix."""
+    from ..ops.pallas._common import dividing_axes, shard_kernel
+    from ..ops.pallas.fused_ce import unembed_logits_stats
+    from ..utils.groups import BATCH_AXES
+    rows = dividing_axes(h.shape[0], BATCH_AXES)
+    return shard_kernel(
+        unembed_logits_stats,
+        (P(rows, None), P(None, None), P(rows)),
+        (P(rows, None), P(rows), P(rows)))(h, w, targets)
+
+
 @_partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _fused_xent_k(norm_fn, chunk, norm_params, w, hidden, targets):
     # primal/eval path: loss only, no gradient work
-    from ..ops.pallas.fused_ce import unembed_logits_stats
     B, T, D = hidden.shape
     xs, ts, valid, _ = _xent_chunks(hidden, targets, chunk)
 
     def body(acc, xtm):
         x, t, m = xtm
         h = norm_fn(norm_params, x)
-        _, logz, gold = unembed_logits_stats(
+        _, logz, gold = _sharded_unembed_stats(
             h.reshape(-1, D), w, t.reshape(-1))
         per = (logz - gold).reshape(x.shape[0], x.shape[1])
         return acc + jnp.sum(jnp.where(m, per, 0.0)), None
@@ -224,7 +234,6 @@ def _fused_xent_k(norm_fn, chunk, norm_params, w, hidden, targets):
 
 
 def _fused_xent_k_fwd(norm_fn, chunk, norm_params, w, hidden, targets):
-    from ..ops.pallas.fused_ce import unembed_logits_stats
     B, T, D = hidden.shape
     xs, ts, valid, n = _xent_chunks(hidden, targets, chunk)
     denom = B * T
@@ -242,7 +251,7 @@ def _fused_xent_k_fwd(norm_fn, chunk, norm_params, w, hidden, targets):
         h, norm_vjp = jax.vjp(norm_fn, norm_params, x)
         hf = h.reshape(-1, D)
         tf = t.reshape(-1)
-        logits, logz, gold = unembed_logits_stats(hf, w, tf)
+        logits, logz, gold = _sharded_unembed_stats(hf, w, tf)
         per = (logz - gold).reshape(x.shape[0], c)
         acc_loss = acc_loss + jnp.sum(jnp.where(m, per, 0.0))
         p = jnp.exp(logits.astype(jnp.float32) - logz[:, None])

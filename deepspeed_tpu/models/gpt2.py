@@ -578,22 +578,25 @@ class GPT2:
             # (ops/pallas/flash_attention.py). Heads shard over 'tensor'.
             # Inputs arrive from block_qkv as (B, H, hd, T) when
             # cfg.flash_qkv_t (default), else heads-major (B, H, T, hd).
+            from ..ops.pallas._common import dividing_axes, shard_kernel
             from ..ops.pallas.flash_attention import flash_attention
-            head_spec = P(BATCH_AXES, "tensor", None, None)
+            head_spec = P(dividing_axes(q.shape[0], BATCH_AXES),
+                          dividing_axes(q.shape[1], "tensor"), None, None)
             q = constrain(q, head_spec)
             kk = constrain(kk, head_spec)
             v = constrain(v, head_spec)
-            attn = flash_attention(
-                q, kk, v, causal=True,
-                scale=None if cfg.scale_attn else 1.0,
-                block_q=cfg.flash_block_q,
-                block_k=cfg.flash_block_k,
-                block_h=cfg.flash_block_h,
-                block_q_bwd=cfg.flash_block_q_bwd or None,
-                block_k_bwd=cfg.flash_block_k_bwd or None,
-                heads_major=not cfg.flash_qkv_t,
-                qkv_t=cfg.flash_qkv_t,
-                bwd_qmajor=cfg.flash_bwd_qmajor).astype(dt)
+            attn = shard_kernel(
+                partial(flash_attention, causal=True,
+                        scale=None if cfg.scale_attn else 1.0,
+                        block_q=cfg.flash_block_q,
+                        block_k=cfg.flash_block_k,
+                        block_h=cfg.flash_block_h,
+                        block_q_bwd=cfg.flash_block_q_bwd or None,
+                        block_k_bwd=cfg.flash_block_k_bwd or None,
+                        heads_major=not cfg.flash_qkv_t,
+                        qkv_t=cfg.flash_qkv_t,
+                        bwd_qmajor=cfg.flash_bwd_qmajor),
+                (head_spec,) * 3, head_spec)(q, kk, v).astype(dt)
             from jax.ad_checkpoint import checkpoint_name
             attn = checkpoint_name(attn, "attn_out")
         else:

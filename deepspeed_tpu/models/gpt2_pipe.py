@@ -64,20 +64,6 @@ class GPT2Pipe(GPT2):
             return 1
         return mesh.shape["pipe"]
 
-    def _block_constrain(self):
-        """Sharding constraints for the code INSIDE the pipelined
-        region. On a pipe-only mesh (every non-pipe axis size 1) the
-        constraints are semantic no-ops — and skipping them keeps the
-        partial-manual shard_map program legal on legacy jaxlib, which
-        has no shard_map replication rule for sharding_constraint (the
-        reason the data>1 pipeline tests carry
-        ``legacy_jax_pipeline_xfail``)."""
-        mesh = jax.sharding.get_abstract_mesh()
-        if not mesh.empty and all(
-                n == 1 for a, n in mesh.shape.items() if a != "pipe"):
-            return lambda x, spec: x
-        return lax.with_sharding_constraint
-
     def _resolved_pipe(self, S):
         """(schedule, microbatches, offload) for this trace: the
         engine-installed ``_pipe_cfg`` (runtime/config.py
@@ -126,7 +112,7 @@ class GPT2Pipe(GPT2):
         act_spec = P(BATCH_AXES, "seq" if seq_sharded else None, None)
         mb_act_spec = P(None, BATCH_AXES, "seq" if seq_sharded else None,
                         None)
-        constrain = self._block_constrain()
+        constrain = lax.with_sharding_constraint
 
         # --- embedding (outside the pipe; replicated over 'pipe') ---
         x = self.embed(params, input_ids, rng=rng, train=train,
@@ -231,7 +217,7 @@ class GPT2Pipe(GPT2):
             raise ValueError(f"batch {B} not divisible by "
                              f"pipe_microbatches {M}")
         act_spec = P(BATCH_AXES, "seq" if seq_sharded else None, None)
-        constrain = self._block_constrain()
+        constrain = lax.with_sharding_constraint
         x = self.embed(params, ids, rng=rng, train=train,
                        constrain=constrain, act_spec=act_spec)
         causal = jnp.tril(jnp.ones((T, T), jnp.bool_))

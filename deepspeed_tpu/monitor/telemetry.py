@@ -53,33 +53,32 @@ from .tag_schema import TAG_SCHEMA
 
 # --------------------------------------------------------------- peak flops
 # bf16 peak per chip by device_kind substring (first match wins; order
-# matters: 'v5p' before the bare 'v5'/'v5 lite' family). Unknown chips
-# (CPU dev containers, future TPUs) fall back to the v5e figure with
-# ``assumed=True`` so an MFU number is never silently built on a wrong
-# denominator without saying so.
+# matters: 'v5p' before the bare 'v5'/'v5 lite' family). A device the table
+# does not know (a CPU dev container, a future TPU) has NO peak: MFU is then
+# not reported, never built on another chip's denominator.
 _PEAK_BF16 = (
     ("v6", 918e12), ("trillium", 918e12),
     ("v5p", 459e12), ("v5", 197e12),
     ("v4", 275e12), ("v3", 123e12),
 )
-_FALLBACK_PEAK = 197e12
 
 
 def peak_flops_per_chip(device_kind):
-    """-> (peak_flops, assumed). ``DSTPU_PEAK_FLOPS`` overrides (exact
-    hardware the operator knows better than the table)."""
+    """bf16 peak FLOP/s of one chip of ``device_kind``, or None when the
+    table does not know it. ``DSTPU_PEAK_FLOPS`` overrides (exact hardware
+    the operator knows better than the table)."""
     env = os.environ.get("DSTPU_PEAK_FLOPS")
     if env:
         try:
-            return float(env), False
+            return float(env)
         except ValueError:
             logger.warning(f"DSTPU_PEAK_FLOPS={env!r} is not a float; "
                            f"using the device-kind table")
     kind = str(device_kind or "").lower()
     for key, peak in _PEAK_BF16:
         if key in kind:
-            return peak, False
-    return _FALLBACK_PEAK, True
+            return peak
+    return None
 
 
 def percentile(samples, p):
@@ -397,8 +396,7 @@ class TelemetryCollector:
                                      node=node)
         self.flight.set_root(cfg.flightrec_dir
                              or os.environ.get("DSTPU_FLIGHTREC_DIR"))
-        self.peak_flops, self.peak_assumed = \
-            peak_flops_per_chip(device_kind)
+        self.peak_flops = peak_flops_per_chip(device_kind)
         self.cluster = (ClusterAggregator()
                         if cfg.resolve_cluster_agg() else None)
         self.profiler = ProfilerControl(port=cfg.profile_port,
@@ -622,13 +620,12 @@ class TelemetryCollector:
             "elastic_generation": int(
                 os.environ.get("ELASTIC_GENERATION", 0) or 0),
             "peak_flops_per_chip": self.peak_flops,
-            "peak_assumed": self.peak_assumed,
         }
         if tokens and window_s > 0:
             snap["tokens_per_sec_chip"] = round(
                 tokens / window_s / self.n_devices, 1)
         c = self._costs or {}
-        if c.get("flops_per_chip"):
+        if c.get("flops_per_chip") and self.peak_flops:
             snap["mfu_pct"] = round(
                 100.0 * c["flops_per_chip"]
                 / (mean_ms / 1e3) / self.peak_flops, 3)

@@ -176,11 +176,43 @@ def sds(shape, dtype, like):
     """ShapeDtypeStruct whose varying-manual-axes match ``like`` — required
     when a kernel runs inside a shard_map region (e.g. quantized
     collectives, pipelined blocks)."""
-    typeof = getattr(jax, "typeof", None)
-    vma = getattr(typeof(like), "vma", None) if typeof is not None else None
+    vma = getattr(jax.typeof(like), "vma", None)
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def dividing_axes(n, axes):
+    """``axes`` (a mesh axis name or tuple of names) if their ambient-mesh
+    size divides ``n``, else None. shard_map needs even shards where GSPMD
+    would pad, so a kernel operand whose dim does not divide is replicated
+    over those axes instead."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return axes
+    size = 1
+    for a in (axes,) if isinstance(axes, str) else axes:
+        size *= mesh.shape[a]
+    return axes if n % size == 0 else None
+
+
+def shard_kernel(fn, in_specs, out_specs):
+    """``fn`` — a function that runs Pallas kernels — made legal in a
+    GSPMD-partitioned program. A Mosaic custom call cannot be partitioned
+    automatically (the TPU lowering refuses: "wrap the call in a
+    shard_map"), which interpret mode on a CPU mesh never shows. Under an
+    ambient mesh with auto axes of size > 1 the call runs per shard over
+    those axes; with no mesh, one device, or inside a fully-manual region
+    it is ``fn`` itself."""
+    from jax.sharding import AxisType
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = () if mesh.empty else tuple(
+        a for a, t in zip(mesh.axis_names, mesh.axis_types)
+        if t == AxisType.Auto)
+    if all(mesh.shape[a] == 1 for a in auto):
+        return fn
+    return jax.shard_map(fn, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=frozenset(auto), check_vma=False)
 
 
 def round_up(n, m):

@@ -20,9 +20,9 @@ def _param_count(params):
 
 
 def compiled_costs(compiled):
-    """Normalize ``Compiled.cost_analysis()`` across jax versions into
-    one flat dict (older jax returns ``[dict]``; key spellings vary
-    between ``bytes accessed`` and ``bytes_accessed``). The single
+    """Normalize ``Compiled.cost_analysis()`` into one flat dict (some
+    backends return ``[dict]``; key spellings vary between
+    ``bytes accessed`` and ``bytes_accessed``). The single
     extraction point the engine's flops hook and the telemetry layer's
     MFU both read — the two can never disagree on what "step flops"
     means."""

@@ -29,19 +29,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-# The steady-state executors compute every gradient EXPLICITLY inside
-# the manual region (jax.vjp over per-shard closures; nothing
-# differentiates through the shard_map itself) and reduce stage-local
-# results with explicit psums, so legacy jax's check_rep machinery —
-# whose cond-branch replication unification predates the vma typing the
-# executors' pcast annotations target — adds no safety, only spurious
-# mismatches (e.g. on the head-loss cond). vma-era jax keeps full
-# checking; the GPipe path (spmd_pipeline), which IS differentiated
-# through, always keeps it (its transpose relies on the rewrite pass).
-_STEADY_STATE_KW = {} if hasattr(jax.lax, "pvary") else \
-    {"check_vma": False}
-
-
 def spmd_pipeline(block_fn, layers, x_mb, *, pipe_axis="pipe",
                   unroll_local=False):
     """Run ``x`` through all L layers, pipelined over the pipe axis.
@@ -404,7 +391,6 @@ def pipeline_1f1b_grads(block_fn, head_loss_fn, layers_params, layers_aux,
         in_specs=(P(pipe_axis), P(pipe_axis), P(), P(), P()),
         out_specs=(P(), P(pipe_axis), P(), P()),
         axis_names={pipe_axis},
-        **_STEADY_STATE_KW,
     )(layers_params, layers_aux, head_params, x_mb, tgt_mb)
     dlayers = jax.tree.map(lambda g, p: g.astype(p.dtype),
                            gacc, layers_params)
@@ -728,7 +714,6 @@ def pipeline_zb_grads(block_fn, head_loss_fn, layers_params, layers_aux,
         in_specs=(P(pipe_axis), P(pipe_axis), P(), P(), P()),
         out_specs=(P(), P(pipe_axis), P(), P()),
         axis_names={pipe_axis},
-        **_STEADY_STATE_KW,
     )(layers_params, layers_aux, head_params, x_mb, tgt_mb)
     dlayers = jax.tree.map(lambda g, p: g.astype(p.dtype),
                            gacc, layers_params)

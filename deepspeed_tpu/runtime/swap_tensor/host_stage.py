@@ -14,40 +14,40 @@ streams; here the compiler owns the schedule). The pipeline executors
 ``activation_checkpointing`` CPU-checkpoint trade — in host RAM, and the
 engine uses the same memory kind for optimizer-moment placement.
 
-Platform reality: TPU exposes ``pinned_host`` next to ``device``; the
-CPU backend has a SINGLE memory space (``unpinned_host`` is the default
-memory), so there the transfer is an identity and ``available()`` is
-False — callers gate structural assertions on it and 'auto' knobs
-resolve off.
+Platform contract (jax 0.9.0):
+
+  * TPU lists ``pinned_host`` beside its default ``device`` memory and
+    its compiler implements the placement: ``available()`` is True,
+    ``to_host`` moves bytes, shardings take the host memory kind.
+  * The CPU backend ALSO lists ``pinned_host``/``unpinned_host`` beside
+    ``device``, but XLA:CPU compiles no placement: an in-program
+    ``device_put`` to the host space is an identity and a host memory
+    kind on ``out_shardings`` is refused ("No registered implementation
+    for ... annotate_device_placement"). It is one memory space in
+    practice, so there ``host_memory_kind()`` is None, ``available()`` is
+    False, every transfer is an identity, callers gate structural
+    assertions on it and 'auto' knobs resolve off.
 """
 
 import functools
 
 import jax
+from jax.memory import Space
 
 from ...utils.logging import logger
-
-try:                                    # jax >= 0.6 exports it publicly
-    from jax.sharding import TransferToMemoryKind as _TransferToMemoryKind
-except ImportError:                     # legacy jax (0.4.x dev container)
-    try:
-        from jax._src.sharding_impls import TransferToMemoryKind \
-            as _TransferToMemoryKind
-    except ImportError:                 # no memory-kind support at all
-        _TransferToMemoryKind = None
 
 
 @functools.lru_cache(maxsize=None)
 def memory_kinds():
     """(default_kind, host_kind): the default device memory kind and the
-    best host-side kind, or (None, None) when the backend predates
-    memory spaces. Cached — backend memories are fixed per process."""
-    try:
-        dev = jax.devices()[0]
-        default = dev.default_memory().kind
-        kinds = {m.kind for m in dev.addressable_memories()}
-    except Exception:  # noqa: BLE001 - legacy backends lack the API
-        return None, None
+    host-side kind programs can be placed in, or None for the latter on
+    a platform with one memory space in practice (see the module
+    docstring). Cached — backend memories are fixed per process."""
+    dev = jax.devices()[0]
+    default = dev.default_memory().kind
+    if dev.platform == "cpu":
+        return default, None
+    kinds = {m.kind for m in dev.addressable_memories()}
     for host in ("pinned_host", "unpinned_host"):
         if host in kinds and host != default:
             return default, host
@@ -62,8 +62,7 @@ def host_memory_kind():
 
 def available():
     """True iff host staging actually moves bytes on this backend."""
-    return _TransferToMemoryKind is not None \
-        and host_memory_kind() is not None
+    return host_memory_kind() is not None
 
 
 def to_host(x):
@@ -71,19 +70,17 @@ def to_host(x):
     distinct host space — the CPU test mesh). Usable inside jit and
     inside shard_map manual regions (memory kinds are orthogonal to
     sharding)."""
-    kind = host_memory_kind()
-    if kind is None or _TransferToMemoryKind is None:
+    if host_memory_kind() is None:
         return x
-    return jax.device_put(x, _TransferToMemoryKind(kind))
+    return jax.device_put(x, Space.Host)
 
 
 def to_device(x):
     """Bring a host-staged value back to device memory (identity when
     staging is unavailable)."""
-    default, host = memory_kinds()
-    if host is None or _TransferToMemoryKind is None:
+    if host_memory_kind() is None:
         return x
-    return jax.device_put(x, _TransferToMemoryKind(default))
+    return jax.device_put(x, Space.Device)
 
 
 def with_host_memory_kind(sharding):

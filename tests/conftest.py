@@ -18,9 +18,6 @@ from __graft_entry__ import _provision  # noqa: E402
 
 _provision(8)
 
-import deepspeed_tpu  # noqa: E402, F401  (installs older-jax compat shims
-#                       before test modules do `from jax import shard_map`)
-
 import pytest  # noqa: E402
 
 

@@ -83,3 +83,19 @@ def test_env_override(monkeypatch):
     monkeypatch.setenv("DS_ACCELERATOR", "bogus")
     with pytest.raises(ValueError):
         ra.get_accelerator()
+
+
+def test_missing_tpu_falls_back_to_cpu_loudly_once(monkeypatch):
+    """No override and no TPU: the CPU accelerator, with ONE warning — a
+    run that claims the chip must be able to see it did not get it."""
+    import deepspeed_tpu.accelerator.real_accelerator as ra
+    from deepspeed_tpu.utils.logging import logger
+    warned = []
+    monkeypatch.setattr(logger, "warning", warned.append)
+    monkeypatch.setattr(ra, "_accelerator", None)
+    monkeypatch.delenv("DS_ACCELERATOR", raising=False)
+    monkeypatch.delenv("DSTPU_ACCELERATOR", raising=False)
+    assert isinstance(ra.get_accelerator(), CpuAccelerator)
+    assert isinstance(ra.get_accelerator(), CpuAccelerator)
+    assert len(warned) == 1 and "no TPU" in warned[0]
+

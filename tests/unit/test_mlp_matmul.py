@@ -33,9 +33,14 @@ class TestMlpMatmulForward:
         w = _rand(ks[1], (K, d), jnp.bfloat16)
         y = mlp_matmul(x, w, x_t=x_t, out_t=out_t, **_KW)
         assert y.shape == ((B, d, T) if out_t else (B, T, d))
+        # the reference runs on widened operands: XLA:CPU has no
+        # bf16 x bf16 -> f32 dot over a transposed operand, and a product
+        # of two bf16 values is exact in f32, so the numbers are the
+        # kernel's own — f32 accumulation, one round to bf16
+        ref = _ref_proj(x.astype(jnp.float32), w.astype(jnp.float32),
+                        x_t, out_t).astype(jnp.bfloat16)
         np.testing.assert_allclose(
-            np.asarray(y, np.float32),
-            np.asarray(_ref_proj(x, w, x_t, out_t), np.float32),
+            np.asarray(y, np.float32), np.asarray(ref, np.float32),
             rtol=2e-2, atol=2e-2)
 
     def test_fp32_exact(self):

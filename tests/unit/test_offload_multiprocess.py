@@ -87,15 +87,6 @@ def _run_world(nproc):
     raise AssertionError("no LOSSES line from rank 0")
 
 
-@pytest.mark.xfail(
-    __import__("jax").__version_info__ < (0, 6),
-    reason="legacy jaxlib CPU backend cannot compile multiprocess "
-           "computations at all ('Multiprocess computations aren't "
-           "implemented on the CPU backend' from the first jitted init "
-           "with non-addressable out_shardings) — an environment limit, "
-           "not an offload bug; passes on driver jax >= 0.9 whose CPU "
-           "collectives run cross-process",
-    strict=False)
 @pytest.mark.slow
 def test_two_process_offload_matches_single():
     # same global batch (2 x micro 1 vs 1 x ... both dp=2 over 2 devices;

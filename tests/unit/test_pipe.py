@@ -28,24 +28,6 @@ from deepspeed_tpu.utils.groups import TopologyConfig
 # compile-heavy: excluded from the fast core set (pytest -m 'not slow')
 pytestmark = pytest.mark.slow
 
-# The SPMD-pipelined end-to-end tests below need vma-era jax BECAUSE
-# their meshes carry auto (non-pipe) axes > 1: legacy jaxlib cannot
-# SPMD-partition the partial-manual shard_map pipeline program
-# (XlaRuntimeError: "PartitionId instruction is not supported for SPMD
-# partitioning" at the lax.axis_index inside the pipe-manual region),
-# regardless of the lax.pcast compat shim (utils/compat.py) that fixes
-# the API gap. They pass on current jax (the driver env). The mark is
-# scoped to exactly these tests: pipe-ONLY meshes (every auto axis
-# size 1) partition fine on legacy jaxlib, so tier-1 schedule-parity
-# and pp=2 loss-parity coverage lives unmarked in test_pipe_fast.py.
-legacy_jax_pipeline_xfail = pytest.mark.xfail(
-    jax.__version_info__ < (0, 6),
-    reason="partial-manual shard_map pipelines need vma-era jax/jaxlib; "
-           "legacy jaxlib cannot SPMD-partition the manual-pipe program "
-           "(passes on driver jax >= 0.9)",
-    strict=False)
-
-
 
 # ---------------------------------------------------------------- topology
 class TestProcessTopology:
@@ -274,7 +256,6 @@ def _make_mesh(pipe, data):
     return topo.mesh
 
 
-@legacy_jax_pipeline_xfail
 class TestSpmdPipeline:
     def test_matches_sequential(self):
         mesh = _make_mesh(pipe=2, data=4)
@@ -337,7 +318,6 @@ class TestSpmdPipeline:
 
 
 # -------------------------------------------------------------- end-to-end
-@legacy_jax_pipeline_xfail
 class TestGPT2Pipe:
     def _cfg(self, **kw):
         from deepspeed_tpu.models import GPT2Config
@@ -454,7 +434,6 @@ class TestGPT2Pipe:
         assert l1 < l0  # optimizing the same batch must reduce loss
 
 
-@legacy_jax_pipeline_xfail
 class Test1F1BSchedule:
     """pipe_schedule='1f1b': the interleaved executor
     (runtime/pipe/spmd.py pipeline_1f1b_grads; reference

@@ -13,7 +13,7 @@ import re
 import numpy as np
 import pytest
 
-import deepspeed_tpu  # noqa: F401 - compat shims before jax use
+import deepspeed_tpu
 import jax
 
 from deepspeed_tpu.autotuning import planner, reconcile

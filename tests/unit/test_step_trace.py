@@ -12,7 +12,6 @@ import os
 import numpy as np
 import pytest
 
-import deepspeed_tpu  # noqa: F401 - compat shims before jax use
 import jax
 
 from deepspeed_tpu.profiling import step_trace

@@ -15,7 +15,7 @@ import time
 import numpy as np
 import pytest
 
-import deepspeed_tpu  # noqa: F401 - compat shims before jax use
+import deepspeed_tpu
 import jax
 
 from deepspeed_tpu.monitor import flight_recorder
@@ -156,15 +156,13 @@ class TestAggregation:
 
     def test_peak_flops_table(self, monkeypatch):
         monkeypatch.delenv("DSTPU_PEAK_FLOPS", raising=False)
-        v5e, assumed = peak_flops_per_chip("TPU v5 lite")
-        assert v5e == 197e12 and not assumed
-        v5p, _ = peak_flops_per_chip("TPU v5p")
-        assert v5p == 459e12
-        cpu, assumed = peak_flops_per_chip("cpu")
-        assert assumed
+        assert peak_flops_per_chip("TPU v5 lite") == 197e12
+        assert peak_flops_per_chip("TPU v5p") == 459e12
+        # a device the table does not know has no peak — not v5e's
+        assert peak_flops_per_chip("cpu") is None
+        assert peak_flops_per_chip("TPU v9x") is None
         monkeypatch.setenv("DSTPU_PEAK_FLOPS", "1e15")
-        forced, assumed = peak_flops_per_chip("cpu")
-        assert forced == 1e15 and not assumed
+        assert peak_flops_per_chip("cpu") == 1e15
 
 
 # -------------------------------------------------------- fs cluster ring
@@ -664,7 +662,14 @@ def _tiny_engine(tmp_path=None, telemetry=None, tp=1):
 
 
 class TestEngineTelemetry:
-    def test_step_analytics_flow_through_fanout(self):
+    @pytest.mark.parametrize("peak", [None, "1e12"])
+    def test_step_analytics_flow_through_fanout(self, monkeypatch, peak):
+        """The CPU mesh is a device the peak table does not know: MFU is
+        reported only when the operator names a peak."""
+        if peak:
+            monkeypatch.setenv("DSTPU_PEAK_FLOPS", peak)
+        else:
+            monkeypatch.delenv("DSTPU_PEAK_FLOPS", raising=False)
         engine, batch = _tiny_engine(
             telemetry={"enabled": True, "interval_steps": 3,
                        "cluster_agg": False})
@@ -678,12 +683,16 @@ class TestEngineTelemetry:
             tags = {t for t, _, _ in stub.events}
             assert "Train/Telemetry/step_time_ms_p50" in tags
             assert "Train/Telemetry/goodput_pct" in tags
-            assert "Train/Telemetry/mfu_pct" in tags
+            assert ("Train/Telemetry/mfu_pct" in tags) == bool(peak)
             for t in tags:
                 assert t in TAG_SCHEMA, f"undocumented tag {t}"
             snap = engine.telemetry_report()
-            assert snap["flops_source"] == "hlo"
-            assert snap["mfu_pct"] > 0
+            if peak:
+                assert snap["flops_source"] == "hlo"
+                assert snap["mfu_pct"] > 0
+            else:
+                assert snap["peak_flops_per_chip"] is None
+                assert "mfu_pct" not in snap
             assert snap["tokens_per_sec_chip"] > 0
             assert "collectives" in snap
         finally:

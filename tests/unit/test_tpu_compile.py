@@ -1,0 +1,133 @@
+"""Main-path Pallas kernels compiled for a described TPU v5e at GPT-2 350M
+widths — no chip attached, no chip time.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+chip that is described, not attached (``topologies.get_topology_desc``).
+Interpret-mode tests cannot see what it refuses: a slice off the tiling,
+a kernel over its VMEM budget. These are the kernels ``chip_smoke.py``'s
+train and serve phases run, at the shapes they run them; each must lower
+to a Mosaic custom call. Nothing executes, so nothing is said about
+results or speed. Skipped where the topology cannot be described.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.ops.pallas.fused_ce import unembed_logits_stats
+from deepspeed_tpu.ops.pallas.paged_attention import (
+    paged_chunk_attention, paged_decode_attention)
+
+# GPT-2 350M serving/training geometry (chip_smoke.py FULL)
+B, T, H, HD, D, V = 8, 1024, 16, 64, 1024, 50304
+BS, MB = 64, 16                  # kv_block_size, blocks per sequence
+NB = 1 + B * MB                  # the v2 engine's default pool
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here: skip
+        pytest.skip(f"cannot describe a v5e topology: "
+                    f"{type(e).__name__}: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip; keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+bf16, i32 = jnp.bfloat16, jnp.int32
+QKV_T = [((B, H, HD, T), bf16)] * 3          # the qkv einsum's own layout
+
+
+def _flash_train(block, block_h):
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True, qkv_t=True,
+                            block_q=block, block_k=block, block_h=block_h,
+                            interpret=False)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+def _paged_chunk(q, kc, vc, table, start, true_len):
+    return paged_chunk_attention(q, kc, vc, table, start, true_len,
+                                 block_c=128, interpret=False)
+
+
+POOL = [((NB, H, BS, HD), bf16)] * 2
+CASES = {
+    # training: the headline's whole-sequence tile, and the config default
+    "flash_fwd_bwd_1024x1024_bh1": (_flash_train(1024, 1), QKV_T),
+    "flash_fwd_bwd_128x128_bh2": (_flash_train(128, 2), QKV_T),
+    "fused_ce_unembed": (
+        lambda h, w, t: unembed_logits_stats(h, w, t, block_m=512,
+                                             block_n=512, interpret=False),
+        [((B * 512, D), bf16), ((V, D), bf16), ((B * 512,), i32)]),
+    # serving: decode step, split-fuse chunk, whole-prompt prefill buckets
+    # (the last not a multiple of the q tile)
+    "paged_decode": (
+        lambda q, kc, vc, tb, ln: paged_decode_attention(
+            q, kc, vc, tb, ln, interpret=False),
+        [((B, H, HD), bf16)] + POOL + [((B, MB), i32), ((B,), i32)]),
+    "paged_chunk_c256": (
+        _paged_chunk,
+        [((256, H, HD), bf16)] + POOL + [((MB,), i32), ((), i32), ((), i32)]),
+    "paged_prefill_c1024": (
+        _paged_chunk,
+        [((1024, H, HD), bf16)] + POOL + [((MB,), i32), ((), i32),
+                                         ((), i32)]),
+    "paged_prefill_c320": (
+        _paged_chunk,
+        [((320, H, HD), bf16)] + POOL + [((5,), i32), ((), i32), ((), i32)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(v5e, name):
+    fn, shapes = CASES[name]
+    _compile(fn, shapes, v5e)
+
+
+def test_vmem_refusal_is_what_a_bad_tile_looks_like(v5e):
+    """The failure kind this file exists to catch: whole-sequence flash
+    tiles with two (batch, head) instances per grid step do not fit VMEM.
+    The autotune search space proposes full-T blocks only with
+    block_h=1 (autotuning/kernel_registry.py), so this is an example,
+    not a repair to make."""
+    with pytest.raises(Exception, match="(?i)vmem|RESOURCE_EXHAUSTED"):
+        _compile(_flash_train(1024, 2), QKV_T, v5e)
+
+
+def test_host_staging_compiles_to_the_host_memory_space(v5e, monkeypatch):
+    """runtime/swap_tensor/host_stage.py on its TPU arm (this process sees
+    the CPU, where staging is an identity — so the arm is chosen here, in
+    the test): the round trip through ``jax.memory.Space.Host`` compiles
+    for the v5e and the staged value carries the host space, S(5)."""
+    from deepspeed_tpu.runtime.swap_tensor import host_stage
+    monkeypatch.setattr(host_stage, "host_memory_kind",
+                        lambda: "pinned_host")
+    x = jax.ShapeDtypeStruct((1024, 1024), bf16, sharding=v5e)
+    compiled = jax.jit(lambda v: host_stage.to_device(
+        host_stage.to_host(v * 2)) + 1).lower(x).compile()
+    assert "S(5)" in compiled.as_text()
+
