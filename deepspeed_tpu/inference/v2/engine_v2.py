@@ -32,6 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ...utils import groups
 from ...utils.groups import TopologyConfig
 from ...utils.logging import log_dist
+from ...monitor.telemetry import span
 from ..utils import shard_params
 from .ragged import DSStateManager, RaggedBatchWrapper
 
@@ -417,11 +418,12 @@ class InferenceEngineV2:
 
     # ------------------------------------------------------------- requests
     def put(self, prompt, max_new_tokens=32, eos_token_id=-1, uid=None,
-            temperature=None, top_k=None, klass=0):
+            temperature=None, top_k=None, klass=0, waited_s=0.0):
         """Queue a generation request (sampling params per request, like
         FastGen; None = the engine-config defaults; ``klass`` = the
         router's request class, keying the per-class acceptance EMAs in
-        serving telemetry). Returns its uid."""
+        serving telemetry; ``waited_s`` = how long it already queued in
+        the router, for the queue-wait window). Returns its uid."""
         if uid is None:
             uid = self._uid_next
             self._uid_next += 1
@@ -453,7 +455,7 @@ class InferenceEngineV2:
             top_k=(self.config.top_k if top_k is None else int(top_k))))
         if self.telemetry is not None:
             # TTFT clock starts at submit; the class keys acceptance EMAs
-            self.telemetry.on_submit(uid, klass=klass)
+            self.telemetry.on_submit(uid, klass=klass, waited_s=waited_s)
         return uid
 
     def is_done(self, uid):
@@ -1037,6 +1039,17 @@ class InferenceEngineV2:
         if self.telemetry is not None:
             self.telemetry.on_handoff_out(uid)
 
+    def _dispatch_span(self, kind, active, steps, chunk_tokens=0):
+        """The ``dstpu.engine.dispatch`` span of one program call, opened
+        once the batch is assembled (its stats are fixed here); a
+        decode-bearing dispatch also feeds the occupancy counter."""
+        active = int(active)
+        slots = self.config.max_batch_size
+        if steps and self.telemetry is not None:
+            self.telemetry.on_decode_batch(active, slots)
+        return span("dstpu.engine.dispatch", kind=kind, active=active,
+                    slots=slots, steps=steps, chunk_tokens=chunk_tokens)
+
     def _step_splitfuse_chunk(self):
         """Run one fused dispatch: the next chunk of the oldest
         prefilling sequence + n decode steps (chunk-only when nothing is
@@ -1046,131 +1059,159 @@ class InferenceEngineV2:
         to the prompt bucket."""
         mgr = self.state_mgr
         C = self.config.splitfuse_tokens or self.config.prompt_bucket
-        uid = self._prefill_q[0]
-        seq = mgr.get_sequence(uid)
-        off = seq.prefill_offset
-        true_len = min(C, len(seq.prompt) - off)
-        ids = np.zeros((1, C), np.int32)
-        ids[0, :true_len] = seq.prompt[off:off + true_len]
-        tb = np.zeros((C,), np.int32)
-        to = np.zeros((C,), np.int32)
-        fb, fo = mgr.token_placement(seq)
-        tb[:true_len] = fb[off:off + true_len]
-        to[:true_len] = fo[off:off + true_len]
-        table = np.zeros((self.max_blocks_per_seq,), np.int32)
-        table[:len(seq.blocks)] = seq.blocks
-
-        if self.kv_pool is not None:
-            # offload: chunk-only dispatch over the resident history +
-            # destination blocks, then the grouped decode path keeps the
-            # running sequences fed (the fused program would need the
-            # union working set resident)
-            live = seq.blocks[:mgr.blocks_needed(off + true_len)]
-            # blocks starting at/after the chunk's first position hold
-            # no prior tokens — this dispatch writes them from scratch,
-            # so they need slots but no host upload
-            first_fresh = -(-off // mgr.block_size)
-            self.cache = self.kv_pool.ensure(
-                self.cache, live, skip_upload=live[first_fresh:])
-            dest = sorted({int(b) for b in tb[:true_len]})
+        with span("dstpu.engine.build"):
+            uid = self._prefill_q[0]
+            seq = mgr.get_sequence(uid)
+            off = seq.prefill_offset
+            true_len = min(C, len(seq.prompt) - off)
+            last = off + true_len >= len(seq.prompt)
+            ids = np.zeros((1, C), np.int32)
+            ids[0, :true_len] = seq.prompt[off:off + true_len]
+            tb = np.zeros((C,), np.int32)
+            to = np.zeros((C,), np.int32)
+            fb, fo = mgr.token_placement(seq)
+            tb[:true_len] = fb[off:off + true_len]
+            to[:true_len] = fo[off:off + true_len]
+            table = np.zeros((self.max_blocks_per_seq,), np.int32)
+            table[:len(seq.blocks)] = seq.blocks
+            c_temp = np.asarray([seq.temperature], np.float32)
+            c_topk = np.asarray([seq.top_k], np.int32)
+            if self.kv_pool is not None:
+                # offload: chunk-only dispatch over the resident history
+                # + destination blocks, then the grouped decode path keeps
+                # the running sequences fed (the fused program would need
+                # the union working set resident)
+                live = seq.blocks[:mgr.blocks_needed(off + true_len)]
+                # blocks starting at/after the chunk's first position
+                # hold no prior tokens — this dispatch writes them from
+                # scratch, so they need slots but no host upload
+                first_fresh = -(-off // mgr.block_size)
+                self.cache = self.kv_pool.ensure(
+                    self.cache, live, skip_upload=live[first_fresh:])
+                dest = sorted({int(b) for b in tb[:true_len]})
+                tb = self.kv_pool.translate(tb)
+                table = self.kv_pool.translate(table)
+                batch = None
+            else:
+                batch = mgr.decode_batch(exclude=self._decode_hold)
             self._rng, sub = jax.random.split(self._rng)
-            fn = self._get_chunk_only()
-            with jax.set_mesh(self.mesh):
-                c_tok, self.cache = fn(
-                    self.params, self.cache, ids,
-                    self.kv_pool.translate(tb), to, np.int32(off),
-                    np.int32(true_len), self.kv_pool.translate(table),
-                    np.asarray([seq.temperature], np.float32),
-                    np.asarray([seq.top_k], np.int32), sub,
-                    seq.temperature == 0.0)
-            self.kv_pool.mark_dirty(dest)
-            seq.prefill_offset = off + true_len
-            if seq.prefill_offset >= len(seq.prompt):
-                self._prefill_q.popleft()
-                self._post_token(seq, int(np.asarray(c_tok)[0]))
-            return self._step_offload_decode()
+            fused = batch is not None and bool(batch.active.any())
+            fn = self._get_splitfuse() if fused else self._get_chunk_only()
 
-        batch = mgr.decode_batch(exclude=self._decode_hold)
-        self._rng, sub = jax.random.split(self._rng)
-        c_temp = np.asarray([seq.temperature], np.float32)
-        c_topk = np.asarray([seq.top_k], np.int32)
-        if not batch.active.any():
-            fn = self._get_chunk_only()
-            with jax.set_mesh(self.mesh):
-                c_tok, self.cache = fn(
-                    self.params, self.cache, ids, tb, to, np.int32(off),
-                    np.int32(true_len), table, c_temp, c_topk, sub,
-                    seq.temperature == 0.0)
-            toks = np.zeros((0, self.config.max_batch_size), np.int32)
+        if fused:
+            dispatch = self._dispatch_span(
+                "fused", batch.active.sum(),
+                max(1, self.config.decode_steps_per_dispatch), true_len)
         else:
-            all_greedy = (seq.temperature == 0.0
-                          and not bool(batch.temps.any()))
-            fn = self._get_splitfuse()
-            with jax.set_mesh(self.mesh):
-                c_tok, toks, self.cache = fn(
-                    self.params, self.cache, ids, tb, to, np.int32(off),
-                    np.int32(true_len), table, c_temp, c_topk,
-                    batch.tokens, batch.lengths, batch.block_tables, sub,
-                    batch.temps, batch.top_ks, all_greedy)
-            toks = np.asarray(toks)
-        seq.prefill_offset = off + true_len
-        if seq.prefill_offset >= len(seq.prompt):
-            self._prefill_q.popleft()
-            self._post_token(seq, int(np.asarray(c_tok)[0]))
-        return self._post_decode_tokens(batch, toks)
+            dispatch = self._dispatch_span("chunk", 0, 0, true_len)
+        with dispatch:
+            with span("dstpu.engine.fetch"):
+                with jax.set_mesh(self.mesh):
+                    if fused:
+                        all_greedy = (seq.temperature == 0.0
+                                      and not bool(batch.temps.any()))
+                        c_tok, toks, self.cache = fn(
+                            self.params, self.cache, ids, tb, to,
+                            np.int32(off), np.int32(true_len), table,
+                            c_temp, c_topk, batch.tokens, batch.lengths,
+                            batch.block_tables, sub, batch.temps,
+                            batch.top_ks, all_greedy)
+                    else:
+                        c_tok, self.cache = fn(
+                            self.params, self.cache, ids, tb, to,
+                            np.int32(off), np.int32(true_len), table,
+                            c_temp, c_topk, sub, seq.temperature == 0.0)
+                        toks = np.zeros((0, self.config.max_batch_size),
+                                        np.int32)
+                toks = np.asarray(toks)
+                # the chunk's token is read only when the prompt ends: a
+                # chunk-only dispatch in mid-prompt stays asynchronous
+                first = int(np.asarray(c_tok)[0]) if last else None
+            with span("dstpu.engine.post"):
+                if self.kv_pool is not None:
+                    self.kv_pool.mark_dirty(dest)
+                seq.prefill_offset = off + true_len
+                if last:
+                    self._prefill_q.popleft()
+                    self._post_token(seq, first)
+                out = [] if batch is None \
+                    else self._post_decode_tokens(batch, toks)
+        if self.kv_pool is not None:
+            return self._step_offload_decode()
+        return out
 
     # ----------------------------------------------------------------- step
     def _admit_pending(self):
         mgr = self.state_mgr
-        bucket = self.config.prompt_bucket
+        tel = self.telemetry
         while self._pending:
             req = self._pending[0]
             if not mgr.can_admit(len(req.prompt), req.max_new_tokens,
                                  prompt=req.prompt):
                 break
-            self._pending.popleft()
-            slot, seq = mgr.admit(req.uid, req.prompt, req.max_new_tokens,
-                                  req.eos_token_id,
-                                  temperature=req.temperature,
-                                  top_k=req.top_k)
-            if seq.cow is not None:
-                # partial-tail prefix hit: device-copy the matched slice
-                # into the fresh block before any prefill touches it
-                self._apply_cow(seq)
-            if self.config.splitfuse_tokens or seq.cached_len:
-                # SplitFuse: the prompt streams through chunk dispatches
-                # interleaved with decodes — no bucketed prefill here.
-                # Prefix-cache hits take the same path regardless: the
-                # chunk program's start/true_len accounting is what
-                # skips the cached prefix (the bucketed prefill always
-                # starts at 0)
-                self._prefill_q.append(req.uid)
-                continue
-            T = len(req.prompt)
-            T_pad = -(-max(T, 1) // bucket) * bucket
-            ids = np.zeros((1, T_pad), np.int32)
-            ids[0, :T] = req.prompt
-            tb = np.zeros((T_pad,), np.int32)       # scratch for pads
-            to = np.zeros((T_pad,), np.int32)
-            tb[:T], to[:T] = mgr.token_placement(seq)
-            prompt_blocks = seq.blocks[:mgr.blocks_needed(T)]
-            if self.kv_pool is not None:
-                # every prompt block is fully written by this dispatch:
-                # slots only, no garbage H2D (code-review finding)
-                self.cache = self.kv_pool.ensure(
-                    self.cache, prompt_blocks, skip_upload=prompt_blocks)
-                tb = self.kv_pool.translate(tb)
-            self._rng, sub = jax.random.split(self._rng)
-            fn = self._get_prefill()
-            with jax.set_mesh(self.mesh):
-                tok, self.cache = fn(
-                    self.params, self.cache, ids, tb, to, np.int32(T), sub,
-                    np.asarray([seq.temperature], np.float32),
-                    np.asarray([seq.top_k], np.int32),
-                    seq.temperature == 0.0)
-            if self.kv_pool is not None:
-                self.kv_pool.mark_dirty(prompt_blocks)
-            self._post_token(seq, int(np.asarray(tok)[0]))
+            wait_ms = tel.on_admit(req.uid) if tel is not None else 0.0
+            with span("dstpu.engine.admit", uid=req.uid,
+                      prompt_tokens=len(req.prompt),
+                      wait_us=int(wait_ms * 1e3)):
+                self._pending.popleft()
+                slot, seq = mgr.admit(req.uid, req.prompt,
+                                      req.max_new_tokens, req.eos_token_id,
+                                      temperature=req.temperature,
+                                      top_k=req.top_k)
+                if seq.cow is not None:
+                    # partial-tail prefix hit: device-copy the matched
+                    # slice into the fresh block before any prefill
+                    # touches it
+                    self._apply_cow(seq)
+                if self.config.splitfuse_tokens or seq.cached_len:
+                    # SplitFuse: the prompt streams through chunk
+                    # dispatches interleaved with decodes — no bucketed
+                    # prefill here. Prefix-cache hits take the same path
+                    # regardless: the chunk program's start/true_len
+                    # accounting is what skips the cached prefix (the
+                    # bucketed prefill always starts at 0)
+                    self._prefill_q.append(req.uid)
+                else:
+                    self._prefill_bucketed(req, seq)
+
+    def _prefill_bucketed(self, req, seq):
+        """The whole prompt in one program call, padded to the bucket;
+        blocks on the read of its token."""
+        mgr = self.state_mgr
+        bucket = self.config.prompt_bucket
+        T = len(req.prompt)
+        T_pad = -(-max(T, 1) // bucket) * bucket
+        with span("dstpu.engine.prefill", uid=req.uid, tokens=T,
+                  padded=T_pad):
+            with span("dstpu.engine.build"):
+                ids = np.zeros((1, T_pad), np.int32)
+                ids[0, :T] = req.prompt
+                tb = np.zeros((T_pad,), np.int32)       # scratch for pads
+                to = np.zeros((T_pad,), np.int32)
+                tb[:T], to[:T] = mgr.token_placement(seq)
+                prompt_blocks = seq.blocks[:mgr.blocks_needed(T)]
+                if self.kv_pool is not None:
+                    # every prompt block is fully written by this
+                    # dispatch: slots only, no garbage H2D (code-review
+                    # finding)
+                    self.cache = self.kv_pool.ensure(
+                        self.cache, prompt_blocks,
+                        skip_upload=prompt_blocks)
+                    tb = self.kv_pool.translate(tb)
+                self._rng, sub = jax.random.split(self._rng)
+                fn = self._get_prefill()
+            with span("dstpu.engine.fetch"):
+                with jax.set_mesh(self.mesh):
+                    tok, self.cache = fn(
+                        self.params, self.cache, ids, tb, to, np.int32(T),
+                        sub, np.asarray([seq.temperature], np.float32),
+                        np.asarray([seq.top_k], np.int32),
+                        seq.temperature == 0.0)
+                tok = int(np.asarray(tok)[0])
+            with span("dstpu.engine.post"):
+                if self.kv_pool is not None:
+                    self.kv_pool.mark_dirty(prompt_blocks)
+                self._post_token(seq, tok)
 
     def _post_token(self, seq, token):
         seq.generated.append(token)
@@ -1226,41 +1267,48 @@ class InferenceEngineV2:
         mgr = self.state_mgr
         pool = self.kv_pool
         n = max(1, self.config.decode_steps_per_dispatch)
-        batch = mgr.decode_batch(exclude=self._decode_hold)
-        if not batch.active.any():
-            return []
-        groups = self._offload_decode_groups(batch, n)
-        fn = self._get_decode()
+        with span("dstpu.engine.build"):
+            batch = mgr.decode_batch(exclude=self._decode_hold)
+            if not batch.active.any():
+                return []
+            groups = self._offload_decode_groups(batch, n)
+            fn = self._get_decode()
+            prepared = pool.prepare(sorted(groups[0][1])) if groups \
+                else None
         out = []
-        prepared = pool.prepare(sorted(groups[0][1])) if groups else None
         for gi, (slots_g, blocks_g) in enumerate(groups):
-            self.cache = pool.ensure(self.cache, sorted(blocks_g),
-                                     prepared)
-            prepared = (pool.prepare(sorted(groups[gi + 1][1]))
-                        if gi + 1 < len(groups) else None)
-            sub_active = np.zeros_like(batch.active)
-            sub_active[slots_g] = batch.active[slots_g]
-            tables = np.zeros_like(batch.block_tables)
-            tokens = np.where(sub_active, batch.tokens, 0)
-            lengths = np.where(sub_active, batch.lengths, 0)
-            for s in slots_g:
-                tables[s] = pool.translate(batch.block_tables[s])
-            self._rng, sub = jax.random.split(self._rng)
-            with jax.set_mesh(self.mesh):
-                toks, self.cache = fn(
-                    self.params, self.cache, tokens,
-                    lengths, tables, sub, batch.temps, batch.top_ks,
-                    not bool(batch.temps[sub_active].any()))
-            toks = np.asarray(toks)
-            for s in slots_g:
-                seq = mgr.get_sequence(mgr._slots[s])
-                pool.mark_dirty(self._seq_live_blocks(seq, n)[
-                    (batch.lengths[s]) // mgr.block_size:])
-            sub_batch = RaggedBatchWrapper(
-                tokens=tokens, lengths=lengths, block_tables=tables,
-                active=sub_active, temps=batch.temps,
-                top_ks=batch.top_ks)
-            out.extend(self._post_decode_tokens(sub_batch, toks))
+            with span("dstpu.engine.build"):
+                self.cache = pool.ensure(self.cache, sorted(blocks_g),
+                                         prepared)
+                prepared = (pool.prepare(sorted(groups[gi + 1][1]))
+                            if gi + 1 < len(groups) else None)
+                sub_active = np.zeros_like(batch.active)
+                sub_active[slots_g] = batch.active[slots_g]
+                tables = np.zeros_like(batch.block_tables)
+                tokens = np.where(sub_active, batch.tokens, 0)
+                lengths = np.where(sub_active, batch.lengths, 0)
+                for s in slots_g:
+                    tables[s] = pool.translate(batch.block_tables[s])
+                self._rng, sub = jax.random.split(self._rng)
+            with self._dispatch_span("offload", sub_active.sum(), n):
+                with span("dstpu.engine.fetch"):
+                    with jax.set_mesh(self.mesh):
+                        toks, self.cache = fn(
+                            self.params, self.cache, tokens,
+                            lengths, tables, sub, batch.temps,
+                            batch.top_ks,
+                            not bool(batch.temps[sub_active].any()))
+                    toks = np.asarray(toks)
+                with span("dstpu.engine.post"):
+                    for s in slots_g:
+                        seq = mgr.get_sequence(mgr._slots[s])
+                        pool.mark_dirty(self._seq_live_blocks(seq, n)[
+                            (batch.lengths[s]) // mgr.block_size:])
+                    sub_batch = RaggedBatchWrapper(
+                        tokens=tokens, lengths=lengths,
+                        block_tables=tables, active=sub_active,
+                        temps=batch.temps, top_ks=batch.top_ks)
+                    out.extend(self._post_decode_tokens(sub_batch, toks))
         return out
 
     def step(self):
@@ -1268,10 +1316,20 @@ class InferenceEngineV2:
         dispatch boundary is where serving telemetry amortizes this
         dispatch's wall time across the tokens it produced (per-token
         deltas inside one multi-step dispatch are meaningless)."""
-        out = self._step_inner()
-        if self.telemetry is not None:
-            self.telemetry.on_dispatch(active=self.state_mgr.n_active)
-            self.telemetry.maybe_emit()
+        tel = self.telemetry
+        # the counters ride the span so that whoever reads the trace has
+        # them on the profiler's clock (cached floats; 0 with telemetry
+        # off)
+        with span("dstpu.engine.step", pending=len(self._pending),
+                  active=self.state_mgr.n_active,
+                  slots=self.config.max_batch_size,
+                  queue_p50_us=int(tel.queue_ms_p50 * 1e3) if tel else 0,
+                  queue_p90_us=int(tel.queue_ms_p90 * 1e3) if tel else 0,
+                  admitted_total=tel.admitted if tel else 0):
+            out = self._step_inner()
+            if tel is not None:
+                tel.on_dispatch(active=self.state_mgr.n_active)
+                tel.maybe_emit()
         return out
 
     def telemetry_snapshot(self):
@@ -1308,19 +1366,26 @@ class InferenceEngineV2:
         """The pre-speculation decode dispatch, unchanged: n fused
         decode steps over the given slots (all active slots when
         ``uids`` is None)."""
-        mgr = self.state_mgr
-        batch = mgr.decode_batch(uids, exclude=self._decode_hold)
-        if not batch.active.any():
-            return []
-        self._rng, sub = jax.random.split(self._rng)
-        fn = self._get_decode()
-        with jax.set_mesh(self.mesh):
-            toks, self.cache = fn(self.params, self.cache,
-                                  batch.tokens, batch.lengths,
-                                  batch.block_tables, sub,
-                                  batch.temps, batch.top_ks,
-                                  not bool(batch.temps.any()))
-        return self._post_decode_tokens(batch, np.asarray(toks))
+        with span("dstpu.engine.build"):
+            batch = self.state_mgr.decode_batch(
+                uids, exclude=self._decode_hold)
+            if not batch.active.any():
+                return []
+            self._rng, sub = jax.random.split(self._rng)
+            fn = self._get_decode()
+        with self._dispatch_span(
+                "decode", batch.active.sum(),
+                max(1, self.config.decode_steps_per_dispatch)):
+            with span("dstpu.engine.fetch"):
+                with jax.set_mesh(self.mesh):
+                    toks, self.cache = fn(self.params, self.cache,
+                                          batch.tokens, batch.lengths,
+                                          batch.block_tables, sub,
+                                          batch.temps, batch.top_ks,
+                                          not bool(batch.temps.any()))
+                toks = np.asarray(toks)
+            with span("dstpu.engine.post"):
+                return self._post_decode_tokens(batch, toks)
 
     # ------------------------------------------------- speculative decoding
     def _spec_candidate(self, seq):
@@ -1415,7 +1480,20 @@ class InferenceEngineV2:
         keeps greedy streams byte-identical to plain decode."""
         mgr, k = self.state_mgr, self._spec_k
         uid_set = set(uids)
-        pb = mgr.propose_batch(uid_set)
+        with span("dstpu.engine.build"):
+            pb = mgr.propose_batch(uid_set)
+        # one span for the round: its fetch holds both program calls
+        # (propose, verify) and the host work between them
+        with self._dispatch_span("spec", len(uids), k):
+            with span("dstpu.engine.fetch"):
+                proposals, nxt = self._spec_propose_verify(uid_set, pb)
+            with span("dstpu.engine.post"):
+                return self._spec_commit(uid_set, proposals, nxt)
+
+    def _spec_propose_verify(self, uid_set, pb):
+        """Draft proposals and the target's verdict on them, both read
+        back: -> ({uid: (k,) proposals}, (B, k+1) target tokens)."""
+        mgr, k = self.state_mgr, self._spec_k
         with jax.set_mesh(self.mesh):
             props, self.draft_cache = self._get_propose()(
                 self.draft_params, self.draft_cache, pb.tokens,
@@ -1425,7 +1503,7 @@ class InferenceEngineV2:
                      for slot, uid in enumerate(mgr._slots)
                      if uid in uid_set}
         vb = mgr.verify_batch(proposals, k)
-        for uid in uids:
+        for uid in uid_set:
             mgr.begin_spec(mgr.get_sequence(uid), proposals[uid])
         try:
             with jax.set_mesh(self.mesh):
@@ -1437,9 +1515,15 @@ class InferenceEngineV2:
             # an interrupted verify must not leave speculative tokens
             # in ``generated`` — unwind before the failure propagates,
             # or the replica/router retry would replay corrupt state
-            for uid in uids:
+            for uid in uid_set:
                 mgr.rollback_spec(mgr.get_sequence(uid))
             raise
+        return proposals, nxt
+
+    def _spec_commit(self, uid_set, proposals, nxt):
+        """Host acceptance: each sequence commits its accepted prefix
+        plus the bonus token. Returns the (uid, token) pairs."""
+        mgr, k = self.state_mgr, self._spec_k
         from .speculative import (SPEC_EMA_ALPHA, SPEC_MIN_ROUNDS,
                                   longest_accept)
         out = []

@@ -181,15 +181,18 @@ class Replica:
         return fn() if fn is not None else None
 
     def submit(self, uid, prompt, max_new_tokens, eos_token_id=-1,
-               klass=0):
+               klass=0, waited_s=0.0):
         """Hand one admitted request to the engine. ``serve_dispatch``
         fires FIRST (retryable): an injected dispatch failure leaves no
         partial state and the router re-queues the request. ``klass``
         rides through to the engine so serving telemetry can key its
-        acceptance EMAs by request class."""
+        acceptance EMAs by request class; ``waited_s`` is the time the
+        request spent in the router's queue (a duration: the clocks
+        differ)."""
         fault_injection.fire("serve_dispatch")
         self.engine.put(prompt, max_new_tokens=max_new_tokens,
-                        eos_token_id=eos_token_id, uid=uid, klass=klass)
+                        eos_token_id=eos_token_id, uid=uid, klass=klass,
+                        waited_s=waited_s)
         if self._disaggregated:
             # prefill role: the sequence prefills here, posts its first
             # token, then waits for the KV handoff instead of decoding

@@ -45,7 +45,7 @@ import numpy as np
 
 from ...utils import fault_injection
 from ...utils.logging import log_dist
-from ...monitor.telemetry import percentile
+from ...monitor.telemetry import percentile, span
 from . import kv_transfer
 from .replica import Replica, ReplicaDead
 
@@ -182,9 +182,15 @@ class RouterRequest:
                                         # even across replays
 
 
+# latency samples per class: a bounded window like ServingTelemetry's (a
+# server that runs for a day must not append for a day)
+_MAX_SAMPLES = 4096
+
+
 def _new_class_stats():
     return {"admitted": 0, "completed": 0, "shed": 0, "expired": 0,
-            "replayed": 0, "ttft_ms": [], "tpot_ms": []}
+            "replayed": 0, "ttft_ms": deque(maxlen=_MAX_SAMPLES),
+            "tpot_ms": deque(maxlen=_MAX_SAMPLES)}
 
 
 class Router:
@@ -360,6 +366,11 @@ class Router:
         dispatch, step every busy replica (failing dead ones over),
         collect finished requests, complete drains. Returns the
         (uid, token) pairs produced this round."""
+        with span("dstpu.router.step", queued=len(self._queue),
+                  inflight=sum(len(r.inflight) for r in self.replicas)):
+            return self._round()
+
+    def _round(self):
         now = self._now()
         self._disagg = self._disagg_on()
         for rep in self.replicas:
@@ -550,7 +561,8 @@ class Router:
             self._queue.popleft()
             try:
                 rep.submit(req.uid, req.prompt, req.max_new_tokens,
-                           req.eos_token_id, klass=req.klass)
+                           req.eos_token_id, klass=req.klass,
+                           waited_s=now - req.t_submit)
             except fault_injection.FaultError:
                 # retryable dispatch fault: nothing partial happened —
                 # back to the front, re-route next round

@@ -8,6 +8,9 @@ emitted by production code — both directions are linted by
 discipline applied to metrics: a renamed emission site or a stale
 registry entry cannot silently rot the schema dashboards are built on).
 
+``SPAN_SCHEMA`` below is the same contract for the host spans the
+serving path opens on the profiler's clock.
+
 This module deliberately holds NOTHING but the registry: the lint
 collects emitted-tag literals by grepping the package with this file
 excluded, so the registry's own keys never count as "emissions".
@@ -138,6 +141,14 @@ TAG_SCHEMA = {
         "requests completed since engine construction",
     "Serve/Telemetry/active":
         "sequences decoding when the window was emitted",
+    "Serve/Telemetry/queue_ms_p50":
+        "median wait from Router.put (or engine.put) to a batch slot, "
+        "over the admissions of the sample window",
+    "Serve/Telemetry/queue_ms_p90":
+        "p90 of the same wait",
+    "Serve/Telemetry/batch_occupancy_pct":
+        "live slots / batch slots over the decode-bearing dispatches "
+        "since engine construction",
 
     # --- prefix cache (inference/v2/prefix_cache.py radix tree;
     #     emitted only when the engine runs with prefix_cache on) ---
@@ -197,6 +208,49 @@ TAG_SCHEMA = {
     "Serve/Router/decode_inflight":
         "requests in flight on decode-role replicas when the window "
         "was emitted (per-role queue depth)",
+}
+
+# span name -> the stats it carries and what it covers. Spans are
+# ``monitor.telemetry.span(name, **stats)`` (a jax TraceAnnotation on the
+# profiler's clock, free when no capture runs); both directions linted by
+# tests/unit/test_serving_spans.py like the tags above. build / fetch /
+# post are leaves that never nest in or overlap each other, so every idle
+# gap of the device falls in at most one of them.
+SPAN_SCHEMA = {
+    "dstpu.router.step": {
+        "stats": ("queued", "inflight"),
+        "meaning": "one Router.step round; its own bookkeeping is this "
+                   "span less the engine.step spans inside it"},
+    "dstpu.engine.step": {
+        "stats": ("pending", "active", "slots", "queue_p50_us",
+                  "queue_p90_us", "admitted_total"),
+        "meaning": "one InferenceEngineV2.step; carries the queue-wait "
+                   "counters of ServingTelemetry to the trace's reader"},
+    "dstpu.engine.admit": {
+        "stats": ("uid", "prompt_tokens", "wait_us"),
+        "meaning": "one request admitted: pool check passed -> queued "
+                   "for chunks or through its bucketed prefill"},
+    "dstpu.engine.prefill": {
+        "stats": ("uid", "tokens", "padded"),
+        "meaning": "bucketed prefill of one request: arrays, program "
+                   "call, blocking read of its token"},
+    "dstpu.engine.dispatch": {
+        "stats": ("kind", "active", "slots", "steps", "chunk_tokens"),
+        "meaning": "one decode-bearing or chunk program call (kind "
+                   "decode | fused | chunk | spec | offload) from the "
+                   "assembled batch to the last posted token"},
+    "dstpu.engine.build": {
+        "stats": (),
+        "meaning": "leaf: host work before a program call (decode "
+                   "batch, numpy id/table/offset arrays, rng split)"},
+    "dstpu.engine.fetch": {
+        "stats": (),
+        "meaning": "leaf: the program call and the blocking read of "
+                   "its tokens"},
+    "dstpu.engine.post": {
+        "stats": (),
+        "meaning": "leaf: the Python loop feeding the fetched tokens "
+                   "to their sequences"},
 }
 
 

@@ -89,6 +89,16 @@ def percentile(samples, p):
     return float(np.percentile(np.asarray(samples, np.float64), p))
 
 
+def span(name, **stats):
+    """A host span on the profiler's clock: a ``TraceAnnotation`` that
+    lands on the ``/host:CPU`` plane of the same ``.xplane.pb`` as the
+    device's operations. The profiler being on is the only switch — with
+    no capture running this is a no-op ``TraceMe``. Stats are fixed when
+    the span opens. Names live in ``tag_schema.SPAN_SCHEMA`` (linted)."""
+    import jax
+    return jax.profiler.TraceAnnotation(name, **stats)
+
+
 def collective_breakdown(n_collectives, async_pairs):
     """(logical_collectives, exposed_comm_pct) from an
     ``overlap_report``'s entry counts. ``n_collectives`` counts HLO
@@ -769,10 +779,11 @@ class TelemetryCollector:
 
 # -------------------------------------------------------------- serving side
 class _ReqTimes:
-    __slots__ = ("t_put", "t_first", "t_last", "pending")
+    __slots__ = ("t_put", "waited_s", "t_first", "t_last", "pending")
 
-    def __init__(self, t_put):
+    def __init__(self, t_put, waited_s=0.0):
         self.t_put = t_put
+        self.waited_s = waited_s     # queued upstream (router) before put
         self.t_first = None
         self.t_last = None
         self.pending = 0
@@ -801,6 +812,17 @@ class ServingTelemetry:
         self._started = {}
         self._ttft_ms = deque(maxlen=max_samples)
         self._tpot_ms = deque(maxlen=max_samples)
+        # queue wait (router put -> slot), one sample per admission; the
+        # two percentiles are cached every ``interval`` admissions so the
+        # per-step span (dstpu.engine.step) reads two floats
+        self._queue_ms = deque(maxlen=max_samples)
+        self.admitted = 0
+        self.queue_ms_p50 = 0.0
+        self.queue_ms_p90 = 0.0
+        # batch occupancy: live slots / slots, summed over decode-bearing
+        # dispatches since engine construction
+        self._occ_active = 0
+        self._occ_slots = 0
         self.completed = 0
         self.rejected = 0
         self.active = 0
@@ -831,9 +853,35 @@ class ServingTelemetry:
     def attach_prefix_cache(self, cache):
         self._prefix_cache = cache
 
-    def on_submit(self, uid, klass=0):
-        self._live[uid] = _ReqTimes(time.perf_counter())
+    def on_submit(self, uid, klass=0, waited_s=0.0):
+        """``waited_s``: how long the request already queued upstream (the
+        router's queue) — a duration, because the router's clock is not
+        this one."""
+        self._live[uid] = _ReqTimes(time.perf_counter(), float(waited_s))
         self._klass[uid] = int(klass)
+
+    def _refresh_queue(self):
+        self.queue_ms_p50 = percentile(self._queue_ms, 50) or 0.0
+        self.queue_ms_p90 = percentile(self._queue_ms, 90) or 0.0
+
+    def on_admit(self, uid):
+        """The request got its slot: one queue-wait sample (upstream wait
+        + time in the engine's pending queue). Returns the wait in ms."""
+        st = self._live.get(uid)
+        if st is None:
+            return 0.0
+        wait_ms = (st.waited_s + time.perf_counter() - st.t_put) * 1e3
+        self._queue_ms.append(wait_ms)
+        self.admitted += 1
+        if self.admitted % self.interval == 0:
+            self._refresh_queue()
+        return wait_ms
+
+    def on_decode_batch(self, active, slots):
+        """One decode-bearing dispatch ran with ``active`` of ``slots``
+        batch slots live."""
+        self._occ_active += active
+        self._occ_slots += slots
 
     def on_token(self, uid):
         """First token => TTFT sample; later tokens accumulate for the
@@ -968,6 +1016,13 @@ class ServingTelemetry:
             "completed": self.completed,
             "active": self.active,
         }
+        if self.admitted:
+            self._refresh_queue()
+            out["queue_ms_p50"] = self.queue_ms_p50
+            out["queue_ms_p90"] = self.queue_ms_p90
+        if self._occ_slots:
+            out["batch_occupancy_pct"] = round(
+                100.0 * self._occ_active / self._occ_slots, 2)
         if self.rejected:
             # only present once a cancel/shed happened: router-off
             # engine snapshots stay byte-identical to pre-router runs
@@ -1015,6 +1070,10 @@ class ServingTelemetry:
                 ("Serve/Telemetry/ttft_ms_p99", "ttft_ms_p99"),
                 ("Serve/Telemetry/tpot_ms_p50", "tpot_ms_p50"),
                 ("Serve/Telemetry/tpot_ms_p99", "tpot_ms_p99"),
+                ("Serve/Telemetry/queue_ms_p50", "queue_ms_p50"),
+                ("Serve/Telemetry/queue_ms_p90", "queue_ms_p90"),
+                ("Serve/Telemetry/batch_occupancy_pct",
+                 "batch_occupancy_pct"),
                 # prefix-cache effectiveness (only present with an
                 # attached PrefixCache — see attach_prefix_cache)
                 ("Serve/Telemetry/prefix_hit_rate_pct",

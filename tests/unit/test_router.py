@@ -544,9 +544,30 @@ class TestRouterTelemetry:
         eng = _engine(prefix_cache=False)
         eng.generate_all(_prompts(16, 2), max_new_tokens=4)
         snap = eng.telemetry_snapshot()
+        # + the admission counters of ISSUE 24 (present once a request
+        # has been admitted / a decode batch dispatched)
         assert set(snap) == {"ttft_ms_p50", "ttft_ms_p99",
                              "tpot_ms_p50", "tpot_ms_p99",
-                             "completed", "active"}
+                             "completed", "active", "queue_ms_p50",
+                             "queue_ms_p90", "batch_occupancy_pct"}
+
+    def test_per_class_latency_windows_are_bounded(self):
+        """A server that runs for a day must not append for a day: the
+        per-class TTFT/TPOT samples are bounded windows like
+        ServingTelemetry's, and snapshot() keeps its shape."""
+        from deepspeed_tpu.inference.v2 import router as router_mod
+        st = router_mod._new_class_stats()
+        for key in ("ttft_ms", "tpot_ms"):
+            assert st[key].maxlen == router_mod._MAX_SAMPLES == 4096
+            st[key].extend(range(5000))
+            assert len(st[key]) == 4096 and st[key][0] == 904
+        router = Router(list(_fleet()))
+        router._class_stats[0] = st
+        snap = router.snapshot()["classes"][0]
+        assert set(snap) == {"admitted", "completed", "shed", "expired",
+                             "replayed", "ttft_ms_p50", "ttft_ms_p99",
+                             "tpot_ms_p50", "tpot_ms_p99"}
+        assert snap["ttft_ms_p50"] == pytest.approx(904 + 4095 / 2)
 
 
 # replica-handle unit coverage that needs no engine compile

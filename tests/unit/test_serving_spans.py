@@ -1,0 +1,265 @@
+"""The serving timeline inside the engine (ISSUE 24): ``dstpu.*`` spans on
+the profiler's clock from ``Router.step`` down to build / fetch / post, the
+queue-wait and occupancy counters that ride them, the perfbench readers
+that reduce them, and the SPAN_SCHEMA lint (both directions, like the tag
+schema's in test_telemetry.py).
+
+Engines follow test_router.py's fast pattern: tiny GPT2, module-cached
+params; one profiler capture per engine kind, shared by the tests."""
+
+import os
+import re
+import sys
+import types
+
+import numpy as np
+import pytest
+
+import jax
+
+from deepspeed_tpu.autotuning import kernel_dispatch
+from deepspeed_tpu.inference.v2 import InferenceEngineV2, Router
+from deepspeed_tpu.inference.v2 import engine_v2, router as router_mod
+from deepspeed_tpu.inference.v2.replica import Replica
+from deepspeed_tpu.models import GPT2, GPT2Config
+from deepspeed_tpu.monitor.tag_schema import SPAN_SCHEMA
+from deepspeed_tpu.monitor.telemetry import ServingTelemetry
+from deepspeed_tpu.utils import groups
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path.insert(0, os.path.join(REPO, "perfbench"))
+from pbench import common as pb_common, trace as pb_trace  # noqa: E402
+
+_CFG = GPT2Config(n_layer=2, n_head=4, d_model=64, max_seq_len=128,
+                  vocab_size=256, remat=False, dtype="float32")
+_BASE = {"dtype": "float32", "kv_block_size": 8, "prompt_bucket": 16,
+         "max_batch_size": 4, "decode_steps_per_dispatch": 2,
+         # dense-gather attention: in interpret mode the Pallas kernels
+         # put a million thunk events into a CPU trace
+         "paged_kernel": False}
+LEAVES = ("dstpu.engine.build", "dstpu.engine.fetch", "dstpu.engine.post")
+N_REQUESTS = 6
+READERS = ("queue_wait_p50_ms", "batch_occupancy", "decode_dispatch_ms",
+           "fused_dispatch_ms", "prefill_wall_share", "host_build_share",
+           "host_sync_share", "host_post_share")
+
+
+def _router(splitfuse_tokens):
+    kernel_dispatch.reset()
+    groups.reset()
+    model = GPT2(_CFG)
+    engine = InferenceEngineV2(
+        model, params=model.init(jax.random.key(0)),
+        config=dict(_BASE, splitfuse_tokens=splitfuse_tokens))
+    return Router([Replica("r0", engine)]), engine
+
+
+def _serve(router, n=N_REQUESTS, seed=0):
+    """``n`` requests, one more put every other round so that admissions
+    land between decode-only steps."""
+    rng = np.random.RandomState(seed)
+    todo = [rng.randint(1, 250, size=int(k)).astype(np.int32)
+            for k in rng.randint(5, 40, size=n)]
+    while todo or router.has_work:
+        if todo:
+            router.put(todo.pop(), max_new_tokens=6)
+        router.step()
+        router.step()
+
+
+def _captured(tmp_path_factory, splitfuse_tokens):
+    router, engine = _router(splitfuse_tokens)
+    _serve(router, 2, seed=1)                  # compile outside the capture
+    trace_dir = str(tmp_path_factory.mktemp("trace"))
+    with pb_trace.capture(trace_dir):
+        _serve(router)
+    # rehearse=False: XLA:CPU runs these tiny programs inline on the
+    # Python thread, and a rehearsal reads a thread that ran operations as
+    # a device and drops its spans
+    tr = pb_trace.Trace(pb_trace.find_xplane(trace_dir), rehearse=False)
+    return tr, engine
+
+
+@pytest.fixture(scope="module")
+def bucketed(tmp_path_factory):
+    return _captured(tmp_path_factory, 0)
+
+
+@pytest.fixture(scope="module")
+def splitfuse(tmp_path_factory):
+    return _captured(tmp_path_factory, 16)
+
+
+def _inside(e, parents):
+    return any(p.start <= e.start and e.end <= p.end for p in parents)
+
+
+def _check_nesting(tr):
+    spans = {name: tr.host_spans(name) for name in SPAN_SCHEMA}
+    leaves = sorted((e for n in LEAVES for e in spans[n]),
+                    key=lambda e: e.start)
+    for a, b in zip(leaves, leaves[1:]):
+        assert a.end <= b.start, f"{a.name} overlaps {b.name}"
+    boxes = spans["dstpu.engine.prefill"] + spans["dstpu.engine.dispatch"]
+    for name in ("dstpu.engine.fetch", "dstpu.engine.post"):
+        for e in spans[name]:
+            assert _inside(e, boxes), f"{name} outside prefill/dispatch"
+    for name in ("dstpu.engine.build", "dstpu.engine.prefill",
+                 "dstpu.engine.admit", "dstpu.engine.dispatch"):
+        for e in spans[name]:
+            assert _inside(e, spans["dstpu.engine.step"]), name
+    for e in spans["dstpu.engine.step"]:
+        assert _inside(e, spans["dstpu.router.step"])
+    for d in spans["dstpu.engine.dispatch"]:
+        for name in ("dstpu.engine.fetch", "dstpu.engine.post"):
+            assert sum(_inside(e, [d]) for e in spans[name]) == 1
+    return spans
+
+
+def test_bucketed_timeline(bucketed):
+    tr, _ = bucketed
+    spans = _check_nesting(tr)
+    for name in SPAN_SCHEMA:                # this path can emit every span
+        assert spans[name], f"no {name} span in the trace"
+    assert {e.stats["kind"] for e in spans["dstpu.engine.dispatch"]} \
+        == {"decode"}
+    # a prefill is build + fetch + post, inside its admit
+    for p in spans["dstpu.engine.prefill"]:
+        assert _inside(p, spans["dstpu.engine.admit"])
+        for name in LEAVES:
+            assert sum(_inside(e, [p]) for e in spans[name]) == 1
+
+
+def test_splitfuse_timeline(splitfuse):
+    tr, _ = splitfuse
+    spans = _check_nesting(tr)
+    assert not spans["dstpu.engine.prefill"]
+    kinds = {e.stats["kind"] for e in spans["dstpu.engine.dispatch"]}
+    assert {"fused", "chunk"} <= kinds <= {"fused", "chunk", "decode"}
+    for e in spans["dstpu.engine.dispatch"]:
+        if e.stats["kind"] == "chunk":
+            assert e.stats["active"] == 0 and e.stats["steps"] == 0
+        if e.stats["kind"] != "decode":
+            assert 0 < e.stats["chunk_tokens"] <= 16
+
+
+@pytest.mark.parametrize("kind", ["bucketed", "splitfuse"])
+def test_stats_round_trip(kind, request):
+    tr, engine = request.getfixturevalue(kind)
+    for name, entry in SPAN_SCHEMA.items():
+        for e in tr.host_spans(name):
+            assert set(entry["stats"]) <= set(e.stats), (name, e.stats)
+    admits = tr.host_spans("dstpu.engine.admit")
+    assert len(admits) == N_REQUESTS
+    assert len({e.stats["uid"] for e in admits}) == N_REQUESTS
+    for e in admits:
+        assert isinstance(e.stats["wait_us"], int) and e.stats["wait_us"] >= 0
+        assert e.stats["prompt_tokens"] >= 5
+    decoding = [e for e in tr.host_spans("dstpu.engine.dispatch")
+                if e.stats["steps"]]
+    for e in decoding:
+        assert 1 <= e.stats["active"] <= e.stats["slots"] == 4
+        assert e.stats["steps"] == 2
+    steps = tr.host_spans("dstpu.engine.step")
+    assert max(e.stats["admitted_total"] for e in steps) >= N_REQUESTS
+    assert all(e.stats["active"] <= e.stats["slots"] for e in steps)
+    # the occupancy counter agrees with the spans it is opened beside
+    # (the two warm-up requests ran before the capture)
+    snap = engine.telemetry_snapshot()
+    assert 0 < snap["batch_occupancy_pct"] <= 100
+    assert snap["queue_ms_p50"] >= 0 and "queue_ms_p90" in snap
+    assert engine.telemetry.admitted == N_REQUESTS + 2
+
+
+def test_on_admit_queue_wait():
+    st = ServingTelemetry(interval=4, max_samples=8)
+    assert "queue_ms_p50" not in st.percentiles()   # nothing admitted yet
+    st.on_submit(0, waited_s=2.0)
+    assert 2000.0 <= st.on_admit(0) < 2500.0        # the router's share
+    assert st.on_admit("never submitted") == 0.0
+    for uid in (1, 2):
+        st.on_submit(uid)
+        assert 0.0 <= st.on_admit(uid) < 500.0
+    assert st.queue_ms_p50 == 0.0                   # cached: not yet due
+    st.on_submit(3, waited_s=1.0)
+    st.on_admit(3)                                  # 4th admission
+    assert 0.0 < st.queue_ms_p50 < 1500.0 <= st.queue_ms_p90
+    for uid in range(10, 30):
+        st.on_submit(uid)
+        st.on_admit(uid)
+    assert len(st._queue_ms) == 8 and st.admitted == 24
+    p = st.percentiles()
+    assert p["queue_ms_p50"] == st.queue_ms_p50 < 500.0
+    st.on_decode_batch(3, 4)
+    st.on_decode_batch(1, 4)
+    assert st.percentiles()["batch_occupancy_pct"] == 50.0
+
+
+def test_span_budget_without_capture(monkeypatch):
+    """No capture running: a decode-only engine step opens at most 6 spans
+    (router.step, engine.step, build, dispatch, fetch, post), an admission
+    5 more, and per-token code none."""
+    router, engine = _router(0)
+    _serve(router, 2, seed=1)
+    opened = []
+    real = engine_v2.span
+
+    def counting(name, **stats):
+        frame = sys._getframe(1)
+        opened.append((name, frame.f_code.co_name))
+        return real(name, **stats)
+
+    monkeypatch.setattr(engine_v2, "span", counting)
+    monkeypatch.setattr(router_mod, "span", counting)
+    router.put(np.arange(1, 20, dtype=np.int32), max_new_tokens=12)
+    router.step()
+    assert len(opened) == 11, opened        # 6 + the admission's 5
+    while router.has_work:
+        opened.clear()
+        router.step()
+        assert len(opened) <= 6, opened
+        assert len({n for n, _ in opened}) == len(opened)
+    assert not {fn for _, fn in opened} & {
+        "_post_token", "_post_tokens", "_post_decode_tokens", "on_token"}
+
+
+@pytest.mark.parametrize("metric", READERS)
+def test_reader(metric, bucketed, splitfuse):
+    """Each reader gives a number on a trace of the path it is for and
+    None where the program opened no ``dstpu.*`` span (the parent commit,
+    a training trace)."""
+    said = []
+    view = types.SimpleNamespace(
+        say=lambda line, **fields: said.append((line, fields)))
+    reader = pb_common.load_module("layer_metrics", metric)
+    view.trace = pb_trace.Trace(os.path.join(
+        REPO, "perfbench", "fixtures", "tiny4.xplane.pb"), rehearse=True)
+    assert reader.read(view) is None and not said
+    view.trace = None
+    assert reader.read(view) is None
+    view.trace = (splitfuse if metric == "fused_dispatch_ms"
+                  else bucketed)[0]
+    value = reader.read(view)
+    assert isinstance(value, float) and value >= 0.0, value
+    assert said and said[0][0] == metric
+    if metric.endswith("_share") or metric == "batch_occupancy":
+        assert value <= 100.0
+
+
+_SPAN_RE = re.compile(r"""\bspan\(\s*["'](dstpu\.[A-Za-z0-9_.]+)["']""")
+
+
+def test_span_schema_lint_both_directions():
+    opened = set()
+    pkg = os.path.join(REPO, "deepspeed_tpu")
+    for dirpath, _, names in os.walk(pkg):
+        for n in names:
+            if n.endswith(".py"):
+                with open(os.path.join(dirpath, n), encoding="utf-8") as f:
+                    opened.update(_SPAN_RE.findall(f.read()))
+    assert opened - set(SPAN_SCHEMA) == set(), "spans not in SPAN_SCHEMA"
+    assert set(SPAN_SCHEMA) - opened == set(), "registered, never opened"
+    for name, entry in SPAN_SCHEMA.items():
+        assert name.startswith("dstpu.") and entry["meaning"]
+        assert isinstance(entry["stats"], tuple)

@@ -801,31 +801,76 @@ class TestChaosFlightRecorder:
 
 
 class TestOffCriticalPath:
-    def test_dp2_step_time_within_noise(self):
+    def test_dp2_step_time_within_noise(self, monkeypatch):
         """ISSUE-9 acceptance: per-step wall time with telemetry on is
-        within noise of telemetry off (dp=2 virtual mesh). The step
-        path only appends to a ring; flushes (including the one-time
-        cost-analysis compile) land in warmup."""
+        within noise of telemetry off (dp=2 virtual mesh). Two medians of
+        CPU wall times cannot show that on a loaded machine, so the
+        evidence is what the step path DOES: between flushes a
+        train_batch with telemetry on appends to the ring and to the
+        flight recorder and nothing else — no flush, no cost capture
+        (``cost_analysis`` compile), no background work, no device sync
+        that the same steps with telemetry off do not make; the flush and
+        the one-time cost capture land on interval boundaries only."""
+        calls = []
+        for fn in ("block_until_ready", "device_get"):
+            real = getattr(jax, fn)
+            monkeypatch.setattr(
+                jax, fn, lambda *a, _r=real, _n=fn, **k:
+                (calls.append("sync:" + _n), _r(*a, **k))[1])
+
+        def watch(obj, name):
+            real = getattr(obj, name)
+
+            def wrapper(*a, **k):
+                calls.append(name)
+                return real(*a, **k)
+            monkeypatch.setattr(obj, name, wrapper)
+
         def run(telemetry):
             engine, batch = _tiny_engine(telemetry=telemetry, tp=4)
+            tel = engine.telemetry
+            if tel is not None:
+                assert tel.interval == 5
+                for name in ("_flush", "_capture_costs", "_submit",
+                             "_emit"):
+                    watch(tel, name)
+                watch(tel, "_costs_fn")
             # warmup past compile AND past the first flush (the lazy
             # cost capture compiles once at step==interval)
             for _ in range(6):
                 engine.train_batch(batch)
-            times = []
-            for _ in range(12):
-                t0 = time.perf_counter()
+            if tel is not None:
+                assert calls.count("_flush") == 1
+                assert calls.count("_costs_fn") == 1
+            between = []
+            for _ in range(3):                   # steps 7, 8, 9
+                calls.clear()
+                ring = len(tel._step_ms) if tel is not None else 0
+                events = len(tel.flight._events) if tel is not None else 0
                 engine.train_batch(batch)
-                times.append(time.perf_counter() - t0)
-            if engine.telemetry is not None:
-                engine.telemetry.drain()
-                assert engine.telemetry.last, "telemetry never flushed"
-                engine.telemetry.close()
-            return float(np.median(times))
+                if tel is not None:
+                    assert len(tel._step_ms) == ring + 1
+                    assert len(tel.flight._events) == events + 1
+                between.append(sorted(calls))
+            calls.clear()
+            engine.train_batch(batch)            # step 10: a flush
+            flush = sorted(c for c in calls if not c.startswith("sync:"))
+            if tel is not None:
+                tel.drain()
+                assert tel.last, "telemetry never flushed"
+                tel.close()
+            return between, flush
 
-        t_off = run(telemetry={"enabled": False})
-        t_on = run(telemetry={"enabled": True, "interval_steps": 5,
-                              "cluster_agg": False})
-        assert t_on <= t_off * 1.5 + 0.05, (
-            f"telemetry on the critical path: median step "
-            f"{t_on * 1e3:.2f}ms (on) vs {t_off * 1e3:.2f}ms (off)")
+        off, off_flush = run(telemetry={"enabled": False})
+        on, on_flush = run(telemetry={"enabled": True, "interval_steps": 5,
+                                      "cluster_agg": False})
+        assert off_flush == []
+        # between flushes: the same (sync) calls as with telemetry off,
+        # and none of the collector's heavy paths
+        assert on == off, (
+            f"telemetry on the critical path: a step between flushes "
+            f"made {on} (on) vs {off} (off)")
+        # the boundary step flushes once; the cost capture is asked and
+        # answers from its one-time result (no second costs_fn call)
+        assert on_flush.count("_flush") == 1
+        assert "_costs_fn" not in on_flush
