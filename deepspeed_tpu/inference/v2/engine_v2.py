@@ -21,6 +21,7 @@ Counterpart of reference ``inference/v2/engine_v2.py:30 InferenceEngineV2``
     the continuous-batching property.
 """
 
+import math
 from collections import deque
 from dataclasses import dataclass, field
 
@@ -33,6 +34,8 @@ from ...utils import groups
 from ...utils.groups import TopologyConfig
 from ...utils.logging import log_dist
 from ...monitor.telemetry import span
+from ...ops.pallas.paged_attention import (as_pools, like_boundary,
+                                              pool_block_dims)
 from ..utils import shard_params
 from .ragged import DSStateManager, RaggedBatchWrapper
 
@@ -316,26 +319,20 @@ class InferenceEngineV2:
             model, self.mesh, dtype, params=params, seed=config.seed,
             topology=topology,
             quantize=self._weight_quant or config.quantize_weights)
-        cache_sh = jax.tree.map(
-            lambda s: NamedSharding(self.mesh, s), model.paged_cache_specs(),
-            is_leaf=lambda x: isinstance(x, P))
-        self._cache_sh = cache_sh
         self.kv_pool = None
         device_blocks = num_blocks
         if config.kv_host_offload:
             if config.device_kv_blocks < 2:
                 raise ValueError(
                     "kv_host_offload requires device_kv_blocks >= 2")
-            from .kv_offload import OffloadKVPool
             device_blocks = config.device_kv_blocks
+        self.cache, self._cache_sh = self._new_paged_cache(
+            model, device_blocks)
+        if config.kv_host_offload:
+            from .kv_offload import OffloadKVPool
             self.kv_pool = OffloadKVPool(
                 model, num_blocks, device_blocks, BS, dtype,
-                cache_sh, self.mesh)
-        with jax.set_mesh(self.mesh):
-            self.cache = jax.jit(
-                lambda: model.init_paged_cache(device_blocks, BS,
-                                               dtype=dtype),
-                out_shardings=cache_sh)()
+                self._cache_sh, self.mesh)
 
         # --- draft-model speculative decoding (ROADMAP 1(b)) ---
         # own allocator + cache pool over the same block geometry; the
@@ -380,15 +377,8 @@ class InferenceEngineV2:
                 self.draft_params, self._draft_param_sh = shard_params(
                     draft_model, self.mesh, dtype, params=draft_params,
                     seed=config.seed + 1, topology=topology)
-                self._draft_cache_sh = jax.tree.map(
-                    lambda s: NamedSharding(self.mesh, s),
-                    draft_model.paged_cache_specs(),
-                    is_leaf=lambda x: isinstance(x, P))
-                with jax.set_mesh(self.mesh):
-                    self.draft_cache = jax.jit(
-                        lambda: draft_model.init_paged_cache(
-                            num_blocks, BS, dtype=dtype),
-                        out_shardings=self._draft_cache_sh)()
+                self.draft_cache, self._draft_cache_sh = \
+                    self._new_paged_cache(draft_model, num_blocks)
                 self._propose_jit = None
                 self._verify_jit = None
                 self._draft_chunk_jit = None
@@ -585,6 +575,39 @@ class InferenceEngineV2:
             draft._paged_block_c = self.config.paged_block_c
             draft._weight_quant_fused = False
 
+    def _new_paged_cache(self, model, num_blocks):
+        """Allocate ``model``'s paged cache on this engine's mesh ->
+        (cache, the shardings its programs declare for it). Where the
+        model's decode step will run the paged kernel (the question
+        ``apply_paged_decode`` asks at trace time, asked of the same
+        shapes; off-TPU the kernels are interpreted) the pools are
+        born in the shape that keeps them in the kernels' layout
+        (:func:`pool_block_dims`)."""
+        from ...ops.pallas._common import interpret_default
+        from ...ops.pallas.paged_attention import resolve_paged_decode
+        cfg = self.config
+        _, KVH, BS, hd = jax.eval_shape(lambda: model.init_paged_cache(
+            1, cfg.kv_block_size, dtype=self.dtype))["k"][0].shape
+        kernel = not interpret_default() and (
+            getattr(model.config, "alibi", False) or resolve_paged_decode(
+                cfg.paged_kernel, cfg.max_batch_size,
+                self.max_blocks_per_seq, BS, KVH,
+                model.config.n_head // KVH, hd, self.dtype))
+        dims = pool_block_dims(num_blocks, hd, kernel)
+        # the model's own specs, behind the block axis's extra dimensions
+        shardings = jax.tree.map(
+            lambda spec: NamedSharding(
+                self.mesh, P(*(None,) * (len(dims) - 1), *spec)),
+            model.paged_cache_specs(), is_leaf=lambda x: isinstance(x, P))
+        with jax.set_mesh(self.mesh):
+            cache = jax.jit(
+                lambda: jax.tree.map(
+                    lambda p: p.reshape(dims + p.shape[1:]),
+                    model.init_paged_cache(math.prod(dims), BS,
+                                           dtype=self.dtype)),
+                out_shardings=shardings)()
+        return cache, shardings
+
     @staticmethod
     def _sample_per_slot(logits, rng, temps, top_ks, all_greedy=False):
         """Vectorized per-request sampling (FastGen carries sampling
@@ -616,11 +639,11 @@ class InferenceEngineV2:
             def prefill(params, cache, ids, tb, to, length, rng, temp,
                         top_k, all_greedy):
                 self._install_trace_state()
-                logits, cache = model.apply_paged_prefill(
-                    params, ids, cache, tb, to, length)
+                logits, pools = model.apply_paged_prefill(
+                    params, ids, as_pools(cache), tb, to, length)
                 tok = self._sample_per_slot(logits, rng, temp, top_k,
                                             all_greedy)
-                return tok, cache
+                return tok, like_boundary(pools, cache)
 
             self._prefill_jit = jax.jit(
                 prefill, donate_argnums=(1,), static_argnums=(9,),
@@ -644,15 +667,16 @@ class InferenceEngineV2:
                 # must stay per-layer donated buffers updated in place —
                 # carrying them through a scan defensively copies them.
                 all_toks = []
+                pools = as_pools(cache)
                 for t in range(n):
-                    logits, cache = model.apply_paged_decode(
-                        params, tokens, lengths, cache, tables)
+                    logits, pools = model.apply_paged_decode(
+                        params, tokens, lengths, pools, tables)
                     tokens = self._sample_per_slot(
                         logits, jax.random.fold_in(rng, t), temps,
                         top_ks, all_greedy)
                     lengths = lengths + 1
                     all_toks.append(tokens)
-                return jnp.stack(all_toks), cache
+                return jnp.stack(all_toks), like_boundary(pools, cache)
 
             self._decode_jit = jax.jit(
                 decode, donate_argnums=(1,), static_argnums=(8,),
@@ -676,22 +700,22 @@ class InferenceEngineV2:
                       c_table, c_temp, c_topk, d_tokens, d_lengths,
                       d_tables, rng, d_temps, d_topks, all_greedy):
                 self._install_trace_state()
-                c_logits, cache = model.apply_paged_chunk(
-                    params, c_ids, cache, c_tb, c_to, c_start, c_len,
-                    c_table)
+                c_logits, pools = model.apply_paged_chunk(
+                    params, c_ids, as_pools(cache), c_tb, c_to, c_start,
+                    c_len, c_table)
                 c_tok = self._sample_per_slot(
                     c_logits, jax.random.fold_in(rng, 7919), c_temp,
                     c_topk, all_greedy)
                 toks = []
                 for t in range(n):
-                    logits, cache = model.apply_paged_decode(
-                        params, d_tokens, d_lengths, cache, d_tables)
+                    logits, pools = model.apply_paged_decode(
+                        params, d_tokens, d_lengths, pools, d_tables)
                     d_tokens = self._sample_per_slot(
                         logits, jax.random.fold_in(rng, t), d_temps,
                         d_topks, all_greedy)
                     d_lengths = d_lengths + 1
                     toks.append(d_tokens)
-                return c_tok, jnp.stack(toks), cache
+                return c_tok, jnp.stack(toks), like_boundary(pools, cache)
 
             self._splitfuse_jit = jax.jit(
                 fused, donate_argnums=(1,), static_argnums=(16,),
@@ -710,13 +734,13 @@ class InferenceEngineV2:
             def chunk(params, cache, c_ids, c_tb, c_to, c_start, c_len,
                       c_table, c_temp, c_topk, rng, all_greedy):
                 self._install_trace_state()
-                c_logits, cache = model.apply_paged_chunk(
-                    params, c_ids, cache, c_tb, c_to, c_start, c_len,
-                    c_table)
+                c_logits, pools = model.apply_paged_chunk(
+                    params, c_ids, as_pools(cache), c_tb, c_to, c_start,
+                    c_len, c_table)
                 c_tok = self._sample_per_slot(
                     c_logits, jax.random.fold_in(rng, 7919), c_temp,
                     c_topk, all_greedy)
-                return c_tok, cache
+                return c_tok, like_boundary(pools, cache)
 
             self._chunk_jit = jax.jit(
                 chunk, donate_argnums=(1,), static_argnums=(11,),
@@ -738,9 +762,10 @@ class InferenceEngineV2:
 
             def cow(cache, src, dst, plen):
                 keep = (jnp.arange(BS) < plen)[None, :, None]
-                return jax.tree.map(
+                return like_boundary(jax.tree.map(
                     lambda p: p.at[dst].set(
-                        jnp.where(keep, p[src], p[dst])), cache)
+                        jnp.where(keep, p[src], p[dst])),
+                    as_pools(cache)), cache)
 
             self._cow_jit = jax.jit(
                 cow, donate_argnums=(0,),
@@ -761,9 +786,10 @@ class InferenceEngineV2:
 
             def dchunk(params, cache, ids, tb, to, start, tlen, table):
                 self._install_trace_state()
-                _logits, cache = draft.apply_paged_chunk(
-                    params, ids, cache, tb, to, start, tlen, table)
-                return cache
+                _logits, pools = draft.apply_paged_chunk(
+                    params, ids, as_pools(cache), tb, to, start, tlen,
+                    table)
+                return like_boundary(pools, cache)
 
             self._draft_chunk_jit = jax.jit(
                 dchunk, donate_argnums=(1,),
@@ -787,20 +813,21 @@ class InferenceEngineV2:
 
             def propose(params, cache, tokens2, lengths, tables):
                 self._install_trace_state()
-                _lg, cache = draft.apply_paged_decode(
-                    params, tokens2[:, 0], lengths, cache, tables)
+                _lg, pools = draft.apply_paged_decode(
+                    params, tokens2[:, 0], lengths, as_pools(cache),
+                    tables)
                 cur = tokens2[:, 1]
                 lengths = lengths + 1
                 props = []
                 for _ in range(k):
-                    logits, cache = draft.apply_paged_decode(
-                        params, cur, lengths, cache, tables)
+                    logits, pools = draft.apply_paged_decode(
+                        params, cur, lengths, pools, tables)
                     # only greedy sequences speculate, so the draft is
                     # always greedy too
                     cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                     lengths = lengths + 1
                     props.append(cur)
-                return jnp.stack(props, axis=1), cache
+                return jnp.stack(props, axis=1), like_boundary(pools, cache)
 
             self._propose_jit = jax.jit(
                 propose, donate_argnums=(1,),
@@ -820,10 +847,10 @@ class InferenceEngineV2:
 
             def verify(params, cache, tokens, lengths, tables):
                 self._install_trace_state()
-                logits, cache = model.apply_paged_verify(
-                    params, tokens, lengths, cache, tables)
+                logits, pools = model.apply_paged_verify(
+                    params, tokens, lengths, as_pools(cache), tables)
                 return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                        cache)
+                        like_boundary(pools, cache))
 
             self._verify_jit = jax.jit(
                 verify, donate_argnums=(1,),
@@ -868,7 +895,7 @@ class InferenceEngineV2:
         repeatable for stream-failure retries."""
         if self._kv_export_jit is None:
             def gather(cache, src):
-                return jax.tree.map(lambda p: p[src], cache)
+                return jax.tree.map(lambda p: p[src], as_pools(cache))
 
             self._kv_export_jit = jax.jit(
                 gather, in_shardings=(self._cache_sh, None))
@@ -882,8 +909,9 @@ class InferenceEngineV2:
         every dispatch overwrites by design."""
         if self._kv_import_jit is None:
             def scatter(cache, kv, dst):
-                return jax.tree.map(
-                    lambda p, s: p.at[dst].set(s), cache, kv)
+                return like_boundary(jax.tree.map(
+                    lambda p, s: p.at[dst].set(s), as_pools(cache), kv),
+                    cache)
 
             self._kv_import_jit = jax.jit(
                 scatter, donate_argnums=(0,),
@@ -971,7 +999,7 @@ class InferenceEngineV2:
         n_blocks = set()
 
         def _check(p, s):
-            if not hasattr(s, "shape") or s.shape[1:] != p.shape[1:] \
+            if not hasattr(s, "shape") or s.shape[1:] != p.shape[-3:] \
                     or s.dtype != p.dtype:
                 raise KVWireError(
                     f"handoff KV layout mismatch: payload block shape "

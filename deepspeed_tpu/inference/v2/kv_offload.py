@@ -33,6 +33,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ...ops.pallas.paged_attention import as_pools, like_boundary
+
 __all__ = ["OffloadKVPool"]
 
 
@@ -84,11 +86,12 @@ class OffloadKVPool:
         if self._scatter_jit is None:
             def scatter(cache, slots, blk_k, blk_v):
                 # blk_k/blk_v: (L, n, KVH, BS, hd) stacked uploads
+                pools = as_pools(cache)
                 k = [c.at[slots].set(blk_k[i])
-                     for i, c in enumerate(cache["k"])]
+                     for i, c in enumerate(pools["k"])]
                 v = [c.at[slots].set(blk_v[i])
-                     for i, c in enumerate(cache["v"])]
-                return {"k": k, "v": v}
+                     for i, c in enumerate(pools["v"])]
+                return like_boundary({"k": k, "v": v}, cache)
             self._scatter_jit = jax.jit(
                 scatter, donate_argnums=(0,),
                 in_shardings=(self._cache_sh, None, None, None),
@@ -98,8 +101,9 @@ class OffloadKVPool:
     def _get_gather(self):
         if self._gather_jit is None:
             def gather(cache, slots):
-                k = jnp.stack([c[slots] for c in cache["k"]])
-                v = jnp.stack([c[slots] for c in cache["v"]])
+                pools = as_pools(cache)
+                k = jnp.stack([c[slots] for c in pools["k"]])
+                v = jnp.stack([c[slots] for c in pools["v"]])
                 return k, v
             self._gather_jit = jax.jit(
                 gather,

@@ -976,6 +976,7 @@ class GPT2:
         # m*BS's destination block IS table entry m; pads are scratch 0)
         prefill_table = token_blocks[::BS]
         from ..ops.pallas.paged_attention import (paged_chunk_attention,
+                                                  paged_kv_write,
                                                   resolve_paged_chunk)
         use_kernel, block_c = resolve_paged_chunk(
             getattr(self, "_paged_kernel", "auto"),
@@ -990,11 +991,10 @@ class GPT2:
             m = mask & (qp - kp < w) if w else mask
 
             def attn_fn(q, kk, v, kc0=kc0, vc0=vc0, m=m, w=w):
-                # in-place scatter on this layer's own donated pool buffer
-                kc = kc0.at[token_blocks, :, token_offsets].set(
-                    kk[0].astype(kc0.dtype))
-                vc = vc0.at[token_blocks, :, token_offsets].set(
-                    v[0].astype(vc0.dtype))
+                # in-place write into this layer's own donated pools
+                kc, vc = paged_kv_write(
+                    (kc0, vc0), (kk[0], v[0]), token_blocks,
+                    token_offsets, kernel=use_kernel)
                 if use_kernel:
                     attn = paged_chunk_attention(
                         q[0], kc, vc, prefill_table, jnp.int32(0),
@@ -1040,6 +1040,7 @@ class GPT2:
         k_pos = jnp.arange(S)[None, :]
         mask = (k_pos <= q_pos) & (k_pos < start + true_len)
         from ..ops.pallas.paged_attention import (paged_chunk_attention,
+                                                  paged_kv_write,
                                                   resolve_paged_chunk)
         use_kernel, block_c = resolve_paged_chunk(
             getattr(self, "_paged_kernel", "auto"),
@@ -1054,10 +1055,9 @@ class GPT2:
             m = mask & (q_pos - k_pos < w) if w else mask
 
             def attn_fn(q, kk, v, kc0=kc0, vc0=vc0, m=m, w=w):
-                kc = kc0.at[token_blocks, :, token_offsets].set(
-                    kk[0].astype(kc0.dtype))
-                vc = vc0.at[token_blocks, :, token_offsets].set(
-                    v[0].astype(vc0.dtype))
+                kc, vc = paged_kv_write(
+                    (kc0, vc0), (kk[0], v[0]), token_blocks,
+                    token_offsets, kernel=use_kernel)
                 if use_kernel:
                     attn = paged_chunk_attention(
                         q[0], kc, vc, table, start, true_len,
@@ -1100,7 +1100,8 @@ class GPT2:
         dst_block = jnp.take_along_axis(
             block_tables, (lengths // BS)[:, None], axis=1)[:, 0]
         dst_off = lengths % BS
-        from ..ops.pallas.paged_attention import resolve_paged_decode
+        from ..ops.pallas.paged_attention import (paged_kv_write,
+                                                  resolve_paged_decode)
         use_kernel = resolve_paged_decode(
             getattr(self, "_paged_kernel", "auto"), B,
             block_tables.shape[1], BS, cfg.n_head, 1, cfg.d_head,
@@ -1123,10 +1124,9 @@ class GPT2:
                 from ..ops.pallas.paged_attention import (
                     paged_decode_attention,
                     paged_decode_attention_reference)
-                kc = kc0.at[dst_block, :, dst_off].set(
-                    kk[:, 0].astype(kc0.dtype))
-                vc = vc0.at[dst_block, :, dst_off].set(
-                    v[:, 0].astype(vc0.dtype))
+                kc, vc = paged_kv_write(
+                    (kc0, vc0), (kk[:, 0], v[:, 0]), dst_block, dst_off,
+                    kernel=use_kernel)
                 fn = paged_decode_attention if use_kernel \
                     else paged_decode_attention_reference
                 attn = fn(
@@ -1181,6 +1181,7 @@ class GPT2:
         mask = (k_pos <= q_pos) \
             & (k_pos < (lengths + C)[:, None, None])
         from ..ops.pallas.paged_attention import (paged_chunk_attention,
+                                                  paged_kv_write,
                                                   resolve_paged_chunk)
         use_kernel, block_c = resolve_paged_chunk(
             getattr(self, "_paged_kernel", "auto"),
@@ -1195,10 +1196,10 @@ class GPT2:
             m = mask & (q_pos - k_pos < w) if w else mask
 
             def attn_fn(q, kk, v, kc0=kc0, vc0=vc0, m=m, w=w):
-                kc = kc0.at[fb, :, fo].set(
-                    kk.reshape(B * C, H, hd).astype(kc0.dtype))
-                vc = vc0.at[fb, :, fo].set(
-                    v.reshape(B * C, H, hd).astype(vc0.dtype))
+                kc, vc = paged_kv_write(
+                    (kc0, vc0), (kk.reshape(B * C, H, hd),
+                                 v.reshape(B * C, H, hd)),
+                    fb, fo, kernel=use_kernel)
                 if use_kernel:
                     attn = jnp.stack([
                         paged_chunk_attention(

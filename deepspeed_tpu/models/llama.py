@@ -695,6 +695,7 @@ class Llama:
         BS = cache["k"][0].shape[2]
         prefill_table = token_blocks[::BS]   # see GPT2.apply_paged_prefill
         from ..ops.pallas.paged_attention import (paged_chunk_attention,
+                                                  paged_kv_write,
                                                   resolve_paged_chunk)
         # ALiBi stays dense: the chunk kernel has no per-head bias
         # input (forced-off BEFORE dispatch, so no search is paid for
@@ -712,11 +713,10 @@ class Llama:
             q, kk, v = self._attn_proj(x, layer)
             q = self._rope(q, pos)
             kk = self._rope(kk, pos)
-            # in-place scatter on this layer's own donated pool buffer
-            kc = kc0.at[token_blocks, :, token_offsets].set(
-                kk[0].astype(kc0.dtype))
-            vc = vc0.at[token_blocks, :, token_offsets].set(
-                v[0].astype(vc0.dtype))
+            # in-place write into this layer's own donated pools
+            kc, vc = paged_kv_write(
+                (kc0, vc0), (kk[0], v[0]), token_blocks, token_offsets,
+                kernel=use_kernel)
             if use_kernel:
                 # GQA-native blocked stream over the prompt's own
                 # blocks (no repeat_kv, no (T, T) full-score pass)
@@ -781,6 +781,7 @@ class Llama:
         mask = (k_pos <= q_pos) & (k_pos < start + true_len)
         mask = self._window_mask(mask, q_pos, k_pos)
         from ..ops.pallas.paged_attention import (paged_chunk_attention,
+                                                  paged_kv_write,
                                                   resolve_paged_chunk)
         use_kernel, block_c = resolve_paged_chunk(
             False if cfg.alibi else getattr(self, "_paged_kernel",
@@ -795,10 +796,9 @@ class Llama:
             q, kk, v = self._attn_proj(x, layer)
             q = self._rope(q, pos)
             kk = self._rope(kk, pos)
-            kc = kc0.at[token_blocks, :, token_offsets].set(
-                kk[0].astype(kc0.dtype))
-            vc = vc0.at[token_blocks, :, token_offsets].set(
-                v[0].astype(vc0.dtype))
+            kc, vc = paged_kv_write(
+                (kc0, vc0), (kk[0], v[0]), token_blocks, token_offsets,
+                kernel=use_kernel)
             if use_kernel:
                 # blocked-flash chunk kernel: each KV block streams
                 # through VMEM once, located via the block table; the
@@ -850,7 +850,8 @@ class Llama:
         dst_block = jnp.take_along_axis(
             block_tables, (lengths // BS)[:, None], axis=1)[:, 0]
         dst_off = lengths % BS
-        from ..ops.pallas.paged_attention import resolve_paged_decode
+        from ..ops.pallas.paged_attention import (paged_kv_write,
+                                                  resolve_paged_decode)
         # ALiBi families keep the kernel regardless of the mode switch
         # (the dense fallback lacks the falcon bf16-quantized variant)
         use_kernel = cfg.alibi or resolve_paged_decode(
@@ -865,10 +866,9 @@ class Llama:
             q, kk, v = self._attn_proj(x, layer)       # (B, 1, ., hd)
             q = self._rope(q, pos[:, None])
             kk = self._rope(kk, pos[:, None])
-            kc = kc0.at[dst_block, :, dst_off].set(
-                kk[:, 0].astype(kc0.dtype))
-            vc = vc0.at[dst_block, :, dst_off].set(
-                v[:, 0].astype(vc0.dtype))
+            kc, vc = paged_kv_write(
+                (kc0, vc0), (kk[:, 0], v[:, 0]), dst_block, dst_off,
+                kernel=use_kernel)
             # Pallas paged kernel: GQA-native (no repeat_kv copies), K/V
             # read straight through the block table (reference
             # inference/v2/kernels/ragged_ops blocked_flash); dense
@@ -931,6 +931,7 @@ class Llama:
             & (k_pos < (lengths + C)[:, None, None])
         mask = self._window_mask(mask, q_pos, k_pos)
         from ..ops.pallas.paged_attention import (paged_chunk_attention,
+                                                  paged_kv_write,
                                                   resolve_paged_chunk)
         use_kernel, block_c = resolve_paged_chunk(
             False if cfg.alibi else getattr(self, "_paged_kernel",
@@ -945,10 +946,10 @@ class Llama:
             q, kk, v = self._attn_proj(x, layer)       # (B, C, ., hd)
             q = self._rope(q, pos)
             kk = self._rope(kk, pos)
-            kc = kc0.at[fb, :, fo].set(
-                kk.reshape(B * C, KVH, hd).astype(kc0.dtype))
-            vc = vc0.at[fb, :, fo].set(
-                v.reshape(B * C, KVH, hd).astype(vc0.dtype))
+            kc, vc = paged_kv_write(
+                (kc0, vc0), (kk.reshape(B * C, KVH, hd),
+                             v.reshape(B * C, KVH, hd)),
+                fb, fo, kernel=use_kernel)
             if use_kernel:
                 attn = jnp.stack([
                     paged_chunk_attention(

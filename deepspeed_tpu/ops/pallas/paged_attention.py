@@ -24,6 +24,11 @@ Layout: q (B, H, d); cache (NB, KVH, BS, d) — heads-major so the kernel's
 (inactive/overflow entries point at scratch block 0); lengths (B,) int32 =
 the new token's position (the kernel attends cache slots 0..lengths
 inclusive, matching the dense path's semantics).
+
+The pools stay in that layout from allocation to the last dispatch: new
+rows go in through :func:`paged_kv_write` (an aliased Pallas write, not an
+XLA scatter with a layout of its own), and at program boundaries the
+block axis takes the shape :func:`pool_block_dims` gives it.
 """
 
 import functools
@@ -302,6 +307,160 @@ def paged_decode_attention_reference(q, k_cache, v_cache, block_tables,
     s = jnp.where(mask[:, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhs,bshd->bhd", p, gv)
+
+
+# ------------------------------------------------------- new-token write
+
+
+def _kv_write_kernel(blk_ref, off_ref, kn_ref, vn_ref, kp_ref, vp_ref,
+                     ko_ref, vo_ref, *, R):
+    """Grid step n puts row n of the new K/V into the R-row tile of the
+    pools that holds (blk[n], off[n]). Consecutive rows of one tile keep
+    the output block resident (Pallas fetches and writes back a block
+    only when its index changes), so the tile is loaded from the pool on
+    its first row only and the rows accumulate in VMEM."""
+    n = pl.program_id(0)
+    blk, off = blk_ref[n], off_ref[n]
+    prev = jnp.maximum(n - 1, 0)
+    fresh = (n == 0) | (blk != blk_ref[prev]) \
+        | (off // R != off_ref[prev] // R)
+
+    @pl.when(fresh)
+    def _load():
+        ko_ref[...] = kp_ref[...]
+        vo_ref[...] = vp_ref[...]
+
+    row = jax.lax.broadcasted_iota(jnp.int32, ko_ref.shape, 2) == off % R
+    ko_ref[...] = jnp.where(row, kn_ref[...], ko_ref[...])
+    vo_ref[...] = jnp.where(row, vn_ref[...], vo_ref[...])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kv_write_call(blocks, offsets, kn, vn, kp, vp, *, interpret):
+    """The aliased write as one jitted callable: the serving programs
+    unroll layers x steps in Python, and a jitted callee is traced and
+    lowered once per program, not once per call site."""
+    NB, KVH, BS, d = kp.shape
+    N = kn.shape[0]
+    # rows of one packed sublane tile (16 for bf16); a block size the
+    # tile does not divide is rewritten whole
+    R = 32 // kp.dtype.itemsize
+    if BS % R:
+        R = BS
+
+    def tile(n, blk, off):
+        return (blk[n], 0, off[n] // R, 0)
+
+    def row(n, blk, off):
+        return (n, 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(N,),
+        in_specs=[pl.BlockSpec((1, KVH, 1, d), row),
+                  pl.BlockSpec((1, KVH, 1, d), row),
+                  pl.BlockSpec((1, KVH, R, d), tile),
+                  pl.BlockSpec((1, KVH, R, d), tile)],
+        out_specs=[pl.BlockSpec((1, KVH, R, d), tile),
+                   pl.BlockSpec((1, KVH, R, d), tile)],
+    )
+    return tuple(pl.pallas_call(
+        functools.partial(_kv_write_kernel, R=R),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(kp.shape, kp.dtype),
+                   jax.ShapeDtypeStruct(vp.shape, vp.dtype)],
+        # operands 0/1 are the scalar-prefetched ids; the pools are 4/5
+        input_output_aliases={4: 0, 5: 1},
+        interpret=interpret,
+    )(blocks, offsets, kn.reshape(N, KVH, 1, d), vn.reshape(N, KVH, 1, d),
+      kp, vp))
+
+
+def paged_kv_write(pools, new, blocks, offsets, *, kernel=True,
+                   interpret=None):
+    """Write the new tokens' K and V rows into the paged pools:
+    ``pool[blocks[n], :, offsets[n]] = new[n]`` for both pools at once.
+
+    pools: (k_pool, v_pool), each (NB, KVH, BS, hd); new: (k, v), each
+    (N, KVH, hd) — one row per decode slot, or the C rows of a prefill /
+    chunk / verify span; blocks/offsets: (N,) int32 destination block
+    and in-block slot (pads and inactive slots aim at scratch block 0,
+    whose contents are never attended). Returns the updated pools.
+
+    Where the paged attention runs as a kernel (``kernel``, as the
+    caller resolved it, on a TPU) this is one ``pallas_call`` that
+    aliases both pools and rewrites only the sublane tile holding each
+    destination row, found through the scalar-prefetched block ids: the
+    pools stay in the row-major layout the paged kernels read, where
+    the XLA scatter asks for a layout of its own and costs two
+    whole-pool copies a call. Elsewhere it is that scatter: the same
+    values. Rows aimed at one tile must be consecutive (they are: a
+    sequence's positions are, and live sequences never share a
+    destination block); only scratch block 0 is hit out of order, and a
+    lost row there is as good as any other."""
+    (kp, vp), (kn, vn) = pools, new
+    kn, vn = kn.astype(kp.dtype), vn.astype(vp.dtype)
+    if interpret is None:
+        interpret = _interpret_default()
+        kernel = kernel and not interpret
+    if not kernel:
+        return (kp.at[blocks, :, offsets].set(kn),
+                vp.at[blocks, :, offsets].set(vn))
+    return _kv_write_call(blocks.astype(jnp.int32),
+                          offsets.astype(jnp.int32), kn, vn, kp, vp,
+                          interpret=bool(interpret))
+
+
+# ------------------------------------------------ the pools' layout
+
+
+# The largest size a non-minor dimension may have before the TPU compiler
+# moves it into the 128 lanes of an array whose minor dimension is
+# narrower than they are (found by compiling for a described v5e: 64 stays,
+# 127 moves; PERF.md, PR 25).
+_LANE_SAFE = 64
+
+
+def pool_block_dims(num_blocks, head_dim, kernel_layout):
+    """The shape the block axis of a KV pool takes at program boundaries.
+
+    The paged kernels read a pool (NB, KVH, BS, hd) row-major. Left to
+    itself the TPU compiler gives a program argument of that shape
+    another layout when hd is under the 128 lanes — the block axis
+    becomes the minor one — and copies every pool whole into and out of
+    row-major around the kernels, every dispatch. An argument none of
+    whose leading dimensions passes ``_LANE_SAFE`` keeps row-major, and
+    merging leading dimensions of a row-major array is a bitcast. So
+    where the kernels run as kernels (``kernel_layout``) the block axis
+    is split into such factors, padded up by the fewest blocks that
+    allow it; the programs merge it again on the way in
+    (:func:`as_pools`) and split it on the way out
+    (:func:`like_boundary`). (A pinned ``jax.experimental.layout.Format``
+    says the same thing directly, but an executable that comes back from
+    the persistent compilation cache has forgotten it: jax 0.9.0,
+    libtpu 0.0.34; PERF.md, PR 25.) Returns a tuple whose product is
+    >= num_blocks; ``(num_blocks,)`` where nothing is to be done."""
+    n = int(num_blocks)
+    if not kernel_layout or head_dim >= 128 or n <= _LANE_SAFE:
+        return (n,)
+    if n > _LANE_SAFE ** 2:
+        return pool_block_dims(-(-n // _LANE_SAFE), head_dim, True) \
+            + (_LANE_SAFE,)
+    inner = min((a for a in range(1, _LANE_SAFE + 1)
+                 if -(-n // a) <= _LANE_SAFE), key=lambda a: -(-n // a) * a)
+    return (-(-n // inner), inner)
+
+
+def as_pools(cache):
+    """Inside a program: the cache's leaves as the (NB, KVH, BS, hd)
+    pools the model and the kernels take (a bitcast, or nothing)."""
+    return jax.tree.map(lambda p: p.reshape((-1,) + p.shape[-3:]), cache)
+
+
+def like_boundary(pools, cache):
+    """Inside a program, on the way out: ``pools`` in the shape the
+    cache came in with."""
+    return jax.tree.map(lambda p, c: p.reshape(c.shape), pools, cache)
 
 
 # ------------------------------------------------- chunked-prefill kernel

@@ -1,9 +1,11 @@
 """Paged-serving fast path (tier-1): the chunked-prefill Pallas kernel
-vs the dense-gather reference (interpret mode), the compiled chunk
-program's no-dense-gather guarantee, engine split-fuse greedy identity
-with the kernel on vs off, warm/cold winner-cache dispatch HLO identity
-for the serving autotune ops, and mixtral's ragged-EP serving routing."""
+vs the dense-gather reference (interpret mode), the aliased new-token
+write vs the scatter it replaces, the compiled chunk program's
+no-dense-gather guarantee, engine greedy identity with the kernels on
+vs off, warm/cold winner-cache dispatch HLO identity for the serving
+autotune ops, and mixtral's ragged-EP serving routing."""
 
+import functools
 import os
 
 import numpy as np
@@ -16,8 +18,10 @@ from deepspeed_tpu.autotuning import KernelCache, kernel_dispatch
 from deepspeed_tpu.models import GPT2, GPT2Config
 from deepspeed_tpu.ops.pallas._common import (paged_chunk_bucket,
                                               paged_decode_bucket)
+from deepspeed_tpu.ops.pallas import paged_attention
 from deepspeed_tpu.ops.pallas.paged_attention import (
-    paged_chunk_attention, paged_chunk_attention_reference)
+    paged_chunk_attention, paged_chunk_attention_reference, paged_kv_write,
+    pool_block_dims)
 from deepspeed_tpu.utils import groups
 
 
@@ -128,6 +132,125 @@ def _lower_decode(model, B=2, MB=4, BS=16, NB=9):
         jax.ShapeDtypeStruct((B, MB), i32)).as_text()
 
 
+def _decode_rows(rs, NB, BS):
+    """32 slots, one row each: live slots own distinct blocks, the
+    inactive ones (several, scattered) all aim at scratch block 0."""
+    N = 32
+    blocks = rs.permutation(np.arange(1, NB))[:N].astype(np.int32)
+    offsets = rs.randint(0, BS, (N,)).astype(np.int32)
+    offsets[:4] = [0, 15, 16, BS - 1]
+    for dead in (4, 5, 11, 19, 31):
+        blocks[dead], offsets[dead] = 0, 0
+    return blocks, offsets
+
+
+def _chunk_rows(rs, NB, BS):
+    """C = 256 rows of one sequence from a start that is not
+    block-aligned, the tail past true_len padded onto scratch (0, 0) —
+    what ``RaggedBatchWrapper`` hands the chunk program."""
+    C, start, true_len = 256, 2 * BS + 37, 201
+    table = rs.permutation(np.arange(1, NB))[:-(-(start + C) // BS)]
+    pos = start + np.arange(C)
+    real = np.arange(C) < true_len
+    return (np.where(real, table[pos // BS], 0).astype(np.int32),
+            np.where(real, pos % BS, 0).astype(np.int32))
+
+
+class TestKVWriteParity:
+    """paged_kv_write's aliased Pallas write (interpret mode) against
+    the ``.at[blocks, :, offsets].set`` it replaces: bit-equal outside
+    scratch block 0, whose rows nothing attends."""
+
+    @pytest.mark.parametrize("rows", [_decode_rows, _chunk_rows])
+    @pytest.mark.parametrize("KVH,d", [(16, 64), (32, 64), (8, 128)])
+    def test_write_matches_scatter(self, rows, KVH, d):
+        NB, BS = 40, 64
+        blocks, offsets = rows(np.random.RandomState(KVH + d), NB, BS)
+        ks = jax.random.split(jax.random.key(d), 4)
+        pools = tuple(jax.random.normal(k, (NB, KVH, BS, d), jnp.bfloat16)
+                      for k in ks[:2])
+        new = tuple(jax.random.normal(k, (len(blocks), KVH, d),
+                                      jnp.float32) for k in ks[2:])
+        want = paged_kv_write(pools, new, blocks, offsets, kernel=False)
+        got = jax.jit(functools.partial(paged_kv_write, interpret=True))(
+            pools, new, jnp.asarray(blocks), jnp.asarray(offsets))
+        for g, w, p in zip(got, want, pools):
+            assert g.dtype == w.dtype == jnp.bfloat16
+            np.testing.assert_array_equal(
+                np.asarray(g[1:]).view(np.uint16),
+                np.asarray(w[1:]).view(np.uint16))
+            # and it wrote something: the reference differs from the pool
+            assert not np.array_equal(np.asarray(w[1:]).view(np.uint16),
+                                      np.asarray(p[1:]).view(np.uint16))
+
+
+class TestPoolBoundaryShape:
+    """The block axis of a pool at program boundaries: split into
+    factors the TPU compiler leaves out of the lanes, only where the
+    kernels run as kernels and the head dim is under the lanes."""
+
+    @pytest.mark.parametrize("n", [8, 64, 65, 129, 131, 320, 513, 4096,
+                                   4097, 10007])
+    def test_factors_stay_under_the_lane_threshold(self, n):
+        dims = pool_block_dims(n, 64, True)
+        assert all(1 <= d <= 64 for d in dims)
+        # padded up by few blocks: under a row of the split, or 2 %
+        assert 0 <= np.prod(dims) - n < max(64, n // 50)
+        assert np.prod(dims) == n or n in (131, 4097, 10007)
+        assert pool_block_dims(n, 128, True) == (n,)     # already row-major
+        assert pool_block_dims(n, 64, False) == (n,)     # no kernels
+
+    @pytest.mark.parametrize("splitfuse", [0, 16],
+                             ids=["bucketed", "splitfuse"])
+    def test_engine_with_split_block_axis_is_token_identical(
+            self, splitfuse, monkeypatch):
+        """Every program that takes the cache merges the block axis on
+        the way in and splits it on the way out: prefill / chunk /
+        decode / copy-on-write / KV export and import give the tokens
+        of the unsplit engine (the split forced here; off-TPU it is
+        never chosen)."""
+        from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                                engine_v2, kv_transfer)
+        params = GPT2(_CFG).init(jax.random.key(0))
+        rs = np.random.RandomState(2)
+        template = rs.randint(0, 256, (21,)).astype(np.int32)
+        prompts = [np.concatenate([template, rs.randint(0, 256, (n,))
+                                   .astype(np.int32)]) for n in (3, 9, 20)]
+        base = {"dtype": "float32", "kv_block_size": 8,
+                "prompt_bucket": 16, "max_batch_size": 4,
+                "splitfuse_tokens": splitfuse, "prefix_cache": True,
+                "decode_steps_per_dispatch": 2}
+
+        def run():
+            groups.reset()
+            eng = InferenceEngineV2(GPT2(_CFG), params=params, config=base)
+            toks = [eng.generate_all([p], max_new_tokens=5)[0]
+                    for p in prompts]
+            uid = eng.put(prompts[0], max_new_tokens=5, eos_token_id=-1)
+            eng.hold_decode(uid)
+            seqs = eng.state_mgr._seqs      # admitted by the first step
+            while uid not in seqs or not seqs[uid].generated:
+                eng.step()
+            groups.reset()
+            other = InferenceEngineV2(GPT2(_CFG), params=params,
+                                      config=base)
+            kv_transfer.import_sequence(
+                other, kv_transfer.export_sequence(eng, uid))
+            while not other.is_done(uid):
+                other.step()
+            return toks + [other.get(uid)], eng.cache["k"][0].shape
+
+        plain, shape = run()
+        assert len(shape) == 4
+        monkeypatch.setattr(
+            engine_v2, "pool_block_dims",
+            lambda n, hd, kernel_layout: pool_block_dims(n, hd, True))
+        split, shape = run()
+        assert len(shape) == 5 and shape[0] * shape[1] >= shape[0] > 1
+        for a, b in zip(split, plain):
+            np.testing.assert_array_equal(a, b)
+
+
 class TestChunkProgramHLO:
     def test_kernel_path_never_gathers_dense_kv(self):
         """Acceptance: on the kernel path the chunk program no longer
@@ -229,6 +352,36 @@ class TestEngineKernelOnOff:
         on = run(True)
         off = run(False)
         for a, b in zip(on, off):
+            np.testing.assert_array_equal(a, b)
+
+    @pytest.mark.parametrize("splitfuse", [0, 16],
+                             ids=["bucketed", "splitfuse"])
+    def test_aliased_write_greedy_identical_to_dense(self, splitfuse,
+                                                     monkeypatch):
+        """Three decode dispatches of either engine with the whole
+        kernel path — the aliased write too, forced into the
+        interpreter here as it is never chosen off-TPU — give the
+        tokens of the dense-gather path with its XLA scatter."""
+        from deepspeed_tpu.inference.v2 import InferenceEngineV2
+        monkeypatch.setattr(
+            paged_attention, "paged_kv_write",
+            functools.partial(paged_kv_write, interpret=True))
+        params = GPT2(_CFG).init(jax.random.key(0))
+        rs = np.random.RandomState(1)
+        prompts = [rs.randint(0, 256, (n,)).astype(np.int32)
+                   for n in (5, 16, 37)]
+        base = {"dtype": "float32", "kv_block_size": 8,
+                "prompt_bucket": 16, "max_batch_size": 4,
+                "splitfuse_tokens": splitfuse,
+                "decode_steps_per_dispatch": 2}
+
+        def run(pk):
+            groups.reset()
+            eng = InferenceEngineV2(GPT2(_CFG), params=params,
+                                    config=dict(base, paged_kernel=pk))
+            return eng.generate_all(prompts, max_new_tokens=7)
+
+        for a, b in zip(run(True), run(False)):
             np.testing.assert_array_equal(a, b)
 
 

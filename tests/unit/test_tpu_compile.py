@@ -14,6 +14,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
@@ -21,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.ops.pallas.fused_ce import unembed_logits_stats
 from deepspeed_tpu.ops.pallas.paged_attention import (
-    paged_chunk_attention, paged_decode_attention)
+    as_pools, like_boundary, paged_chunk_attention, paged_decode_attention,
+    pool_block_dims)
 
 # GPT-2 350M serving/training geometry (chip_smoke.py FULL)
 B, T, H, HD, D, V = 8, 1024, 16, 64, 1024, 50304
@@ -131,3 +133,80 @@ def test_host_staging_compiles_to_the_host_memory_space(v5e, monkeypatch):
         host_stage.to_host(v * 2)) + 1).lower(x).compile()
     assert "S(5)" in compiled.as_text()
 
+
+
+# ------------------------------------------------- the KV pools' layout
+# The serving programs as engine_v2 builds them (donated cache in its
+# boundary shape, merged on the way in and split on the way out), two
+# layers deep at GPT-2 350M widths: the pools must enter, stay and leave
+# in the row-major layout the paged kernels read. A whole-pool ``copy``
+# here is a relayout the chip pays for every layer of every step
+# (PERF.md, PR 25).
+POOL_NB, SLOTS, STEPS = 320, 32, 2
+
+
+def _serving_model():
+    from deepspeed_tpu.models import GPT2, GPT2Config
+    model = GPT2(GPT2Config(n_layer=2, n_head=H, d_model=D, max_seq_len=T,
+                            vocab_size=V, dtype="bfloat16"))
+    model._paged_kernel, model._paged_block_c = True, 64
+    return model
+
+
+def _decode_dispatch(model):
+    def decode(params, cache, tokens, lengths, tables):
+        toks, pools = [], as_pools(cache)
+        for _ in range(STEPS):
+            logits, pools = model.apply_paged_decode(
+                params, tokens, lengths, pools, tables)
+            tokens = jnp.argmax(logits, axis=-1).astype(i32)
+            lengths = lengths + 1
+            toks.append(tokens)
+        return jnp.stack(toks), like_boundary(pools, cache)
+    return decode, [((SLOTS,), i32), ((SLOTS,), i32), ((SLOTS, MB), i32)]
+
+
+def _chunk_program(model):
+    def chunk(params, cache, ids, tb, to, start, tlen, table):
+        logits, pools = model.apply_paged_chunk(
+            params, ids, as_pools(cache), tb, to, start, tlen, table)
+        return jnp.argmax(logits, axis=-1), like_boundary(pools, cache)
+    return chunk, [((1, 256), i32), ((256,), i32), ((256,), i32),
+                   ((), i32), ((), i32), ((MB,), i32)]
+
+
+@pytest.mark.parametrize("program", [_decode_dispatch, _chunk_program])
+def test_kv_pools_keep_the_kernels_layout(v5e, monkeypatch, program):
+    import re
+    # the code under test asks the backend whether its kernels are
+    # kernels; this process sees the CPU, so the test answers for it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = _serving_model()
+    dims = pool_block_dims(POOL_NB, HD, kernel_layout=True)
+    assert len(dims) > 1 and np.prod(dims) == POOL_NB
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, bf16, sharding=v5e),
+        jax.eval_shape(model.init, jax.random.key(0)))
+    cache = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(dims + x.shape[1:], x.dtype,
+                                       sharding=v5e),
+        jax.eval_shape(lambda: model.init_paged_cache(POOL_NB, BS,
+                                                      dtype=bf16)))
+    cache_sh = jax.tree.map(lambda x: x.sharding, cache)
+    fn, shapes = program(model)
+    rest = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    compiled = jax.jit(
+        fn, donate_argnums=(1,),
+        in_shardings=(None, cache_sh) + (None,) * len(rest),
+        out_shardings=(None, cache_sh)).lower(params, cache, *rest).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    lead = "|".join((str(POOL_NB), ",".join(map(str, dims))))
+    pool = rf"bf16\[(?:{lead}),{H},{BS},{HD}\]"
+    assert not re.findall(rf"= {pool}\S* copy\(", text)
+    entry = text[text.index("entry_computation_layout="):].split("\n")[0]
+    layouts = re.findall(pool + r"\{([\d,]+)", entry)
+    row_major = ",".join(map(str, reversed(range(len(dims) + 3))))
+    assert len(layouts) == 8 and set(layouts) == {row_major}  # in and out
+    one_pool = POOL_NB * H * BS * HD * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_pool
