@@ -18,7 +18,7 @@ Entry points:
   convert_<family>(hf_cfg, sd, dtype) -> (config, params)
 
 Supported model_type values: gpt2, opt, llama, mistral, qwen2, phi,
-falcon, mixtral, bloom, gptj, gpt_neo, gpt_neox, internlm. Weights load
+falcon, mixtral, olmoe, bloom, gptj, gpt_neo, gpt_neox, internlm. Weights load
 from *.safetensors (single or index-sharded) or pytorch_model.bin
 (torch CPU).
 """
@@ -177,7 +177,9 @@ def _llama_like(hf, sd, cfg, dtype, *, pre="model.", qkv_bias=False,
                 fused_qkv=False,
                 shared_ln=False, mlp_names=("gate_proj", "up_proj",
                                             "down_proj"),
-                o_name="o_proj", moe=False, layer_prefix="layers"):
+                o_name="o_proj", moe=False, layer_prefix="layers",
+                moe_names=("block_sparse_moe", "w1", "w3", "w2"),
+                qk_norm=False):
     L = cfg.n_layer
     H, KVH, hd = cfg.n_head, cfg.n_kv_heads, cfg.d_head
     g = lambda k: sd[pre + k]
@@ -208,13 +210,17 @@ def _llama_like(hf, sd, cfg, dtype, *, pre="model.", qkv_bias=False,
             e["bv"] = g(lp + "self_attn.v_proj.bias")
         if proj_bias or o_bias:
             e["bo"] = g(lp + f"self_attn.{o_name}.bias")
+        if qk_norm:                           # olmoe: over the whole q, k
+            e["q_norm"] = g(lp + "self_attn.q_norm.weight")
+            e["k_norm"] = g(lp + "self_attn.k_norm.weight")
         if moe:
             E = cfg.num_experts
-            e["moe_gate"] = g(lp + "block_sparse_moe.gate.weight").T
-            for ours, theirs in (("moe_w1", "w1"), ("moe_w3", "w3"),
-                                 ("moe_w2", "w2")):
+            block = moe_names[0]              # mixtral | olmoe ("mlp")
+            e["moe_gate"] = g(lp + f"{block}.gate.weight").T
+            for ours, theirs in zip(("moe_w1", "moe_w3", "moe_w2"),
+                                    moe_names[1:]):
                 e[ours] = np.stack([
-                    g(lp + f"block_sparse_moe.experts.{j}.{theirs}.weight").T
+                    g(lp + f"{block}.experts.{j}.{theirs}.weight").T
                     for j in range(E)])
         elif gated:
             gate_n, up_n, down_n = mlp_names
@@ -659,6 +665,39 @@ def convert_mixtral(hf, sd, dtype="bfloat16"):
                             fp32_keys=("moe_gate",))
 
 
+def convert_olmoe(hf, sd, dtype="bfloat16"):
+    """HF olmoe: mixtral's block with QK-norm over the whole q and k
+    projections, un-renormalised top-k weights (``norm_topk_prob``) and
+    the experts under ``mlp.experts.{j}.{gate,up,down}_proj``."""
+    from ..models.olmoe import OLMoEConfig
+    if hf.get("clip_qkv") is not None or hf.get("attention_bias"):
+        raise ValueError("olmoe with clip_qkv or attention_bias is not "
+                         "supported (the published 1B-7B has neither)")
+    cfg = OLMoEConfig(
+        vocab_size=hf["vocab_size"],
+        max_seq_len=hf["max_position_embeddings"],
+        n_layer=hf["num_hidden_layers"],
+        n_head=hf["num_attention_heads"],
+        n_kv_heads=hf.get("num_key_value_heads",
+                          hf["num_attention_heads"]),
+        d_model=hf["hidden_size"], d_ff=hf["intermediate_size"],
+        rope_theta=hf.get("rope_theta", 10000.0),
+        rms_eps=hf.get("rms_norm_eps", 1e-5),
+        num_experts=hf["num_experts"],
+        moe_top_k=hf.get("num_experts_per_tok", 8),
+        norm_topk_prob=hf.get("norm_topk_prob", False),
+        tie_embeddings=hf.get("tie_word_embeddings", False),
+        dtype=dtype)
+    params, g, _ = _llama_like(
+        hf, sd, cfg, dtype, moe=True, qk_norm=True,
+        moe_names=("mlp", "gate_proj", "up_proj", "down_proj"))
+    params["wte"] = g("embed_tokens.weight")
+    params["norm_f"] = g("norm.weight")
+    if not cfg.tie_embeddings:
+        params["lm_head"] = sd["lm_head.weight"]
+    return cfg, _model_cast(params, cfg, dtype, fp32_keys=("moe_gate",))
+
+
 def convert_bloom(hf, sd, dtype="bfloat16"):
     """HF bloom: fused query_key_value is INTERLEAVED per head — rows
     group as (H, 3, hd), unlike falcon's [q..., k, v] layout."""
@@ -723,6 +762,7 @@ CONVERTERS = {
     "phi": convert_phi,
     "falcon": convert_falcon,
     "mixtral": convert_mixtral,
+    "olmoe": convert_olmoe,
     "bloom": convert_bloom,
     "gptj": convert_gptj,
     "gpt_neo": convert_gpt_neo,
@@ -739,6 +779,7 @@ _MODEL_CLASSES = {
     "phi": ("..models.phi", "Phi"),
     "falcon": ("..models.falcon", "Falcon"),
     "mixtral": ("..models.mixtral", "Mixtral"),
+    "olmoe": ("..models.olmoe", "OLMoE"),
     "bloom": ("..models.bloom", "Bloom"),
     "gptj": ("..models.gptj", "GPTJ"),
     "gpt_neo": ("..models.gpt_neo", "GPTNeo"),

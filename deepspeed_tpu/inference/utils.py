@@ -19,8 +19,18 @@ def shard_params(model, mesh, dtype, params=None, seed=0, topology=None,
     kernels when the engine sets ``_weight_quant_fused``
     (ops/int8_weights.py; reference inference/quantization/).
 
+    Families whose served tree differs from the training tree (Mixtral:
+    per-layer expert lists, ``Llama._PER_LAYER``) are placed in that
+    form: given params are unstacked (``model.serving_params``), seeded
+    ones are made unstacked (``model.init_served``) so that no stacked
+    copy ever sits beside them on the device.
+
     Returns (params, param_shardings)."""
-    specs = model.partition_specs(topology)
+    served = hasattr(model, "serving_specs")
+    specs = model.serving_specs(topology) if served \
+        else model.partition_specs(topology)
+    if served and params is not None:
+        params = model.serving_params(params)
     if quantize not in (False, None, True, "int8", "int4"):
         raise ValueError(
             f"quantize must be False|True|'int8'|'int4', got "
@@ -35,6 +45,8 @@ def shard_params(model, mesh, dtype, params=None, seed=0, topology=None,
             cpus = jax.local_devices(backend="cpu")
             with jax.default_device(cpus[0]):
                 params = model.init(jax.random.key(seed))
+            if served:
+                params = model.serving_params(params)
         # consume-as-you-quantize: fp32 source leaves free one at a
         # time, so peak host memory is ~the source tree + one leaf
         # (not source + a full quantized copy)
@@ -56,25 +68,48 @@ def shard_params(model, mesh, dtype, params=None, seed=0, topology=None,
                              is_leaf=lambda x: isinstance(x, P))
     with jax.set_mesh(mesh):
         if params is None:
-            params = jax.jit(
-                lambda r: jax.tree.map(lambda x: x.astype(dtype),
-                                       model.init(r)),
-                out_shardings=shardings)(jax.random.key(seed))
+            params = _seeded(model, dtype, shardings, jax.random.key(seed))
         else:
             # leafwise device_put: host (numpy) leaves transfer shard-by-
             # shard straight to their placement — the full tree never
             # materializes on one device (TP serving of > 1-chip models)
-            import jax.numpy as jnp
-
-            def place(x, s):
-                # jnp.issubdtype, not np.: host bf16 (ml_dtypes) is not
-                # a np.floating subdtype
+            def place(path, x, s):
                 if not isinstance(x, jax.Array):
-                    a = np.asarray(x)
-                    if jnp.issubdtype(a.dtype, jnp.floating):
-                        a = a.astype(np.dtype(dtype), copy=False)
-                    return jax.device_put(a, s)
-                return jax.device_put(x.astype(dtype) if jnp.issubdtype(
-                    x.dtype, jnp.floating) else x, s)
-            params = jax.tree.map(place, params, shardings)
+                    x = np.asarray(x)
+                dt = np.dtype(_leaf_dtype(path, x, dtype))
+                return jax.device_put(x.astype(dt, copy=False), s)
+            params = jax.tree_util.tree_map_with_path(place, params,
+                                                      shardings)
     return params, shardings
+
+
+# router weights keep float32 whatever the serving dtype: a bf16 router
+# flips near-ties between the k-th and (k+1)-th expert (the same
+# exclusion ops/int8_weights.quantize_tree / cast_unquantized honor)
+_FP32_KEYS = ("moe_gate",)
+
+
+def _leaf_dtype(path, x, dtype):
+    """The dtype a served leaf is placed in."""
+    import jax.numpy as jnp
+    # jnp.issubdtype, not np.: host bf16 (ml_dtypes) is not a
+    # np.floating subdtype
+    if not jnp.issubdtype(x.dtype, jnp.floating):
+        return x.dtype
+    if any(getattr(p, "key", None) in _FP32_KEYS for p in path):
+        return jnp.float32
+    return dtype
+
+
+def _seeded(model, dtype, shardings, rng):
+    """Seeded weights made on the device in the serving dtype, in one
+    program. A family with a served tree of its own gives it through
+    ``init_served``, so that no stacked array and no second copy of any
+    layer ever exists: OLMoE's 10.5 GB of experts cannot be unstacked
+    next to themselves on a 16 GB chip (compiled for a v5e the program
+    has 3 MB of temporaries beside its 10.48 GB of outputs; sandbox
+    compile, PR 26)."""
+    init = getattr(model, "init_served", model.init)
+    return jax.jit(lambda r: jax.tree_util.tree_map_with_path(
+        lambda p, x: x.astype(_leaf_dtype(p, x, dtype)), init(r)),
+        out_shardings=shardings)(rng)

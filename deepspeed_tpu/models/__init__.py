@@ -5,6 +5,8 @@ from .gpt2_pipe import GPT2Pipe
 from .llama import (Llama, LlamaConfig, LLAMA_PRESETS, LLAMA_TINY,
                     LLAMA2_7B, MISTRAL_7B)
 from .mixtral import Mixtral, MixtralConfig, MIXTRAL_TINY, MIXTRAL_8X7B
+from .olmoe import (OLMoE, OLMoEConfig, OLMOE_PRESETS, OLMOE_TINY,
+                    OLMOE_1B_7B)
 from .bloom import Bloom, BloomConfig, BLOOM_PRESETS
 from .qwen import Qwen, QwenConfig, QWEN_PRESETS
 from .phi import Phi, PhiConfig, PHI_PRESETS

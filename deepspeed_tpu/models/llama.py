@@ -98,6 +98,10 @@ class LlamaConfig:
     # bloom word_embeddings_layernorm: LN applied to the embedding output
     # (adds embed_ln_s/embed_ln_b params)
     embed_norm: bool = False
+    # olmoe QK-norm: RMSNorm with a learned scale over the WHOLE q and k
+    # projections (all heads together), before the split into heads and
+    # before rope (adds q_norm (L,D) / k_norm (L,KVH*hd) params)
+    qk_norm: bool = False
 
     @property
     def flash_on(self):
@@ -136,6 +140,8 @@ class LlamaConfig:
                  + (3 if self.mlp_gated else 2) * D * F)
         if self.qkv_bias:
             block += D + 2 * kvd
+        if self.qk_norm:
+            block += D + kvd
         if self.o_bias_on:
             block += D
         if self.mlp_bias_on:
@@ -258,6 +264,9 @@ class Llama:
             params["blocks"]["bq"] = jnp.zeros((L, D), dt)
             params["blocks"]["bk"] = jnp.zeros((L, kvd), dt)
             params["blocks"]["bv"] = jnp.zeros((L, kvd), dt)
+        if cfg.qk_norm:
+            params["blocks"]["q_norm"] = jnp.ones((L, D), dt)
+            params["blocks"]["k_norm"] = jnp.ones((L, kvd), dt)
         if cfg.o_bias_on:
             params["blocks"]["bo"] = jnp.zeros((L, D), dt)
         if cfg.mlp_bias_on:
@@ -302,6 +311,9 @@ class Llama:
             specs["blocks"]["bq"] = P(None, "tensor")
             specs["blocks"]["bk"] = P(None, "tensor")
             specs["blocks"]["bv"] = P(None, "tensor")
+        if self.config.qk_norm:
+            specs["blocks"]["q_norm"] = P(None, "tensor")
+            specs["blocks"]["k_norm"] = P(None, "tensor")
         if self.config.o_bias_on:
             specs["blocks"]["bo"] = P(None, None)
         if self.config.mlp_bias_on:
@@ -360,6 +372,9 @@ class Llama:
             q = q + layer["bq"]
             kk = kk + layer["bk"]
             v = v + layer["bv"]
+        if cfg.qk_norm:
+            q = _rms_norm(q, layer["q_norm"], cfg.rms_eps)
+            kk = _rms_norm(kk, layer["k_norm"], cfg.rms_eps)
         return (q.reshape(B, T, H, hd), kk.reshape(B, T, KVH, hd),
                 v.reshape(B, T, KVH, hd))
 
@@ -535,8 +550,13 @@ class Llama:
                              None)
             block_fn = jax.checkpoint(block, policy=policy)
 
-        x, _ = lax.scan(lambda c, l: (block_fn(c, l), None), x,
-                        params["blocks"])
+        if self._served(params):
+            # the inference engines' tree: per-layer lists cannot scan
+            for i in range(cfg.n_layer):
+                x = block_fn(x, self._layer_tree(params, i))
+        else:
+            x, _ = lax.scan(lambda c, l: (block_fn(c, l), None), x,
+                            params["blocks"])
         if return_hidden:
             return x
         return self.head(params, x)
@@ -638,8 +658,19 @@ class Llama:
                 x = x + self._mlp(x, layer)
             return x, (kc, vc)
 
-        x, (kc, vc) = lax.scan(body, x,
-                               (params["blocks"], cache["k"], cache["v"]))
+        if self._served(params):
+            # the inference engines' tree: per-layer lists cannot scan,
+            # and each layer's experts stay operands of the program
+            ks, vs = [], []
+            for i in range(cfg.n_layer):
+                x, (kc, vc) = body(x, (self._layer_tree(params, i),
+                                       cache["k"][i], cache["v"][i]))
+                ks.append(kc)
+                vs.append(vc)
+            kc, vc = jnp.stack(ks), jnp.stack(vs)
+        else:
+            x, (kc, vc) = lax.scan(
+                body, x, (params["blocks"], cache["k"], cache["v"]))
         if last_token_only:
             x = x[:, -1:]
         return self.head(params, x), {"k": kc, "v": vc}
@@ -666,9 +697,49 @@ class Llama:
     # wq_matmul / grouped_swiglu_wq)
     _WQ_KEEP = ("wgate", "wup", "wdown")
 
+    # block keys the SERVED tree holds as per-layer lists instead of
+    # stacked on n_layer (Mixtral: the experts, which a grouped product
+    # cannot read in place from a stacked array)
+    _PER_LAYER = ()
+
+    def serving_params(self, params):
+        """Training tree -> served tree: each ``_PER_LAYER`` key becomes a
+        list of its layers (identity for the dense families and on a
+        tree that is served already). Host arrays unstack as views; a
+        stacked array ON THE DEVICE is copied leaf by leaf, so a model
+        near the chip's size must arrive on the host or be made per
+        layer (``shard_params`` does the latter for seeded weights)."""
+        blocks = dict(params["blocks"])
+        for k in self._PER_LAYER:
+            if not isinstance(blocks[k], (list, tuple)):
+                blocks[k] = [jax.tree.map(lambda a: a[i], blocks[k])
+                             for i in range(self.config.n_layer)]
+        return {**params, "blocks": blocks}
+
+    def serving_specs(self, topology=None):
+        """``partition_specs`` of the served tree."""
+        specs = self.partition_specs(topology)
+        for k in self._PER_LAYER:
+            specs["blocks"][k] = [P(*specs["blocks"][k][1:])] \
+                * self.config.n_layer
+        return specs
+
+    @staticmethod
+    def _served(params):
+        """Whether ``params`` is a served tree with per-layer lists."""
+        return any(isinstance(v, (list, tuple))
+                   for v in params["blocks"].values())
+
+    def _layer_tree(self, params, i):
+        """Layer ``i`` of either tree: a per-layer list gives its own
+        array (a program operand), a stacked leaf its slice."""
+        return {k: v[i] if isinstance(v, (list, tuple))
+                else jax.tree.map(lambda a: a[i], v)
+                for k, v in params["blocks"].items()}
+
     def _layer_slice(self, params, i):
         from ..ops.int8_weights import dequant_tree
-        sl = jax.tree.map(lambda a: a[i], params["blocks"])
+        sl = self._layer_tree(params, i)
         # ZeRO-Inference weight-only serving: int8 block weights
         # dequantize one layer at a time (identity on bf16 trees);
         # under the fused path the FFN weights stay quantized and the

@@ -33,6 +33,9 @@ from .llama import Llama, LlamaConfig, _rms_norm
 class MixtralConfig(LlamaConfig):
     num_experts: int = 8
     moe_top_k: int = 2
+    # HF ``norm_topk_prob``: divide the k routing probabilities by their
+    # sum (mixtral) or use them as the softmax gave them (olmoe)
+    norm_topk_prob: bool = True
 
     def num_params(self):
         base = super().num_params()
@@ -53,29 +56,71 @@ MIXTRAL_8X7B = MixtralConfig(n_layer=32, n_head=32, n_kv_heads=8,
 class Mixtral(Llama):
     """Params: Llama attention tensors; blocks swap wgate/wup/wdown for
       moe_gate (L,D,E), moe_w1 (L,E,D,F), moe_w3 (L,E,D,F),
-      moe_w2 (L,E,F,D)   (w1=gate, w3=up, w2=down — Mixtral naming)."""
+      moe_w2 (L,E,F,D)   (w1=gate, w3=up, w2=down — Mixtral naming).
 
-    def init(self, rng):
+    That is the TRAINING tree (``init``; ``apply`` scans the stacked
+    blocks). The SERVED tree (``serving_params``; what the inference
+    engines hold; ``init_served``, which ``inference/utils.shard_params``
+    runs for seeded weights so that the stacked arrays never exist on
+    the device) keeps the three expert arrays as
+    per-layer LISTS of (E,D,F) / (E,F,D): ``lax.ragged_dot`` and the
+    Pallas grouped kernel cannot read a layer's slice of a stacked
+    array in place the way a dense ``dot`` can — XLA:TPU materialises
+    the slice, a weight-sized temporary per layer per step (PERF.md,
+    PR 26) — so each layer's experts are program operands of their
+    own, as the KV pools already are."""
+
+    # served per layer, never stacked (see the class docstring)
+    _PER_LAYER = ("moe_w1", "moe_w3", "moe_w2")
+
+    def init_dense(self, rng):
+        """Everything but the experts: Llama's tensors and the router."""
         cfg = self.config
-        dt = jnp.dtype(cfg.dtype)
         params = super().init(rng)
         blocks = params["blocks"]
         for k in ("wgate", "wup", "wdown"):
             del blocks[k]
-        L, D, F, E = cfg.n_layer, cfg.d_model, cfg.ffn_dim, cfg.num_experts
-        ks = jax.random.split(jax.random.fold_in(rng, 17), 4)
-        std = 0.02
-        res_std = std / math.sqrt(2 * L)
+        # router stays fp32 (routing is precision-sensitive)
+        blocks["moe_gate"] = jax.random.normal(
+            jax.random.fold_in(rng, 17),
+            (cfg.n_layer, cfg.d_model, cfg.num_experts), jnp.float32) * 0.02
+        return params
 
-        def nrm(key, shape, s=std):
+    def init_experts(self, rng, i):
+        """Layer ``i``'s experts, seeded by (rng, i): the stacked and the
+        per-layer trees hold the same values, and a served model never
+        needs the stacked one to exist."""
+        cfg = self.config
+        dt = jnp.dtype(cfg.dtype)
+        D, F, E = cfg.d_model, cfg.ffn_dim, cfg.num_experts
+        ks = jax.random.split(jax.random.fold_in(
+            jax.random.fold_in(rng, 18), i), 3)
+        std = 0.02
+        res_std = std / math.sqrt(2 * cfg.n_layer)
+
+        def nrm(key, shape, s):
             return (jax.random.normal(key, shape, jnp.float32) * s).astype(dt)
 
-        # router stays fp32 (routing is precision-sensitive)
-        blocks["moe_gate"] = (jax.random.normal(
-            ks[0], (L, D, E), jnp.float32) * std)
-        blocks["moe_w1"] = nrm(ks[1], (L, E, D, F))
-        blocks["moe_w3"] = nrm(ks[2], (L, E, D, F))
-        blocks["moe_w2"] = nrm(ks[3], (L, E, F, D), res_std)
+        return {"moe_w1": nrm(ks[0], (E, D, F), std),
+                "moe_w3": nrm(ks[1], (E, D, F), std),
+                "moe_w2": nrm(ks[2], (E, F, D), res_std)}
+
+    def init_served(self, rng):
+        """The served tree from the seed, with no stacked array on the
+        way: what ``shard_params`` runs for seeded weights, and what a
+        compile of the serving programs takes its shapes from
+        (``jax.eval_shape``)."""
+        params = self.init_dense(rng)
+        layers = [self.init_experts(rng, i)
+                  for i in range(self.config.n_layer)]
+        for k in self._PER_LAYER:
+            params["blocks"][k] = [e[k] for e in layers]
+        return params
+
+    def init(self, rng):
+        params = self.init_served(rng)
+        for k in self._PER_LAYER:
+            params["blocks"][k] = jnp.stack(params["blocks"][k])
         return params
 
     # fused weight-quant serving keeps the expert FFN weights quantized
@@ -132,7 +177,8 @@ class Mixtral(Llama):
         cfg = self.config
         B, T, D = x.shape
         E, k = cfg.num_experts, cfg.moe_top_k
-        h = _rms_norm(x, layer["rms2"], cfg.rms_eps)
+        with jax.named_scope("dstpu.moe.route"):
+            h = _rms_norm(x, layer["rms2"], cfg.rms_eps)
         grouped, hier, dcn_q, q8 = self._moe_knobs()
         mesh = jax.sharding.get_abstract_mesh()
         if not mesh.empty and mesh.shape.get("expert", 1) > 1:
@@ -141,34 +187,34 @@ class Mixtral(Llama):
                 h, layer["moe_gate"], layer["moe_w1"], layer["moe_w3"],
                 layer["moe_w2"], k=k, hierarchical=hier,
                 dcn_quantize=dcn_q, grouped_kernel=grouped,
-                int8_matmul=q8)
+                int8_matmul=q8, renormalize=cfg.norm_topk_prob)
             return y.astype(x.dtype)
-        xs = h.reshape(-1, D)
-        S = xs.shape[0]
-
-        logits = xs.astype(jnp.float32) @ layer["moe_gate"]
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, experts = jax.lax.top_k(probs, k)
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-
-        flat_exp = experts.reshape(-1).astype(jnp.int32)
-        flat_w = weights.reshape(-1).astype(x.dtype)
-        x_rep = jnp.repeat(xs, k, axis=0)
-        order = jnp.argsort(flat_exp, stable=True)
-        xr = x_rep[order]
-        group_sizes = jnp.bincount(flat_exp, length=E).astype(jnp.int32)
-
         from ..moe.sharded_moe import (_grouped_swiglu_ffn,
                                        resolve_grouped_params,
-                                       resolve_moe_int8)
+                                       resolve_moe_int8, route_topk)
+        with jax.named_scope("dstpu.moe.route"):
+            xs = h.reshape(-1, D)
+            S = xs.shape[0]
+            weights, experts = route_topk(xs, layer["moe_gate"], k,
+                                          cfg.norm_topk_prob)
+            flat_exp = experts.reshape(-1)
+            flat_w = weights.reshape(-1).astype(x.dtype)
+            x_rep = jnp.repeat(xs, k, axis=0)
+            order = jnp.argsort(flat_exp, stable=True)
+            xr = x_rep[order]
+            group_sizes = jnp.bincount(flat_exp, length=E).astype(jnp.int32)
+
         w1 = layer["moe_w1"]
         F = w1.scale.shape[-1] if hasattr(w1, "scale") else w1.shape[-1]
         gp = resolve_grouped_params(grouped, S * k, E, D, F, xr.dtype)
         if q8:
             gp = dict(gp, int8=resolve_moe_int8(q8, S * k, E, D, F,
                                                 xr.dtype))
-        o = _grouped_swiglu_ffn(xr, w1, layer["moe_w3"],
-                                layer["moe_w2"], group_sizes, gp)
-        unsorted = jnp.zeros_like(o).at[order].set(o)
-        y = jnp.sum((unsorted * flat_w[:, None]).reshape(S, k, D), axis=1)
+        with jax.named_scope("dstpu.moe.experts"):
+            o = _grouped_swiglu_ffn(xr, w1, layer["moe_w3"],
+                                    layer["moe_w2"], group_sizes, gp)
+        with jax.named_scope("dstpu.moe.combine"):
+            unsorted = jnp.zeros_like(o).at[order].set(o)
+            y = jnp.sum((unsorted * flat_w[:, None]).reshape(S, k, D),
+                        axis=1)
         return y.astype(x.dtype).reshape(B, T, D)

@@ -255,6 +255,25 @@ class TopKGate:
             self.top2_2nd_expert_sampling and train and rng is not None)
 
 
+def route_topk(x, gate_w, k, renormalize=True):
+    """The serving MoE models' router, shared by the one-device ``_mlp``
+    and the expert-parallel path: float32 logits (the product itself at
+    HIGHEST precision — on TPU a default float32 matmul multiplies in
+    bf16, enough to flip a near-tie between the k-th and (k+1)-th
+    expert), float32 softmax over ALL experts, top-k. ``renormalize``
+    divides the k probabilities by their sum (mixtral, HF
+    ``norm_topk_prob=True``); False uses them as they are (OLMoE: they
+    sum to ~k/E at random init, not to 1).
+    x (S, M), gate_w (M, E) -> (weights (S, k) f32, experts (S, k) i32)."""
+    logits = jnp.matmul(x.astype(jnp.float32), gate_w.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = lax.top_k(probs, k)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts.astype(jnp.int32)
+
+
 def topk_routing(logits, k=1):
     """Capacity-free top-k routing: (weights (S, k), experts (S, k) int32,
     aux load-balance loss, counts (E,)). The aux term is the GShard/Switch
@@ -397,12 +416,13 @@ def moe_swiglu_ragged_ep(tokens, gate_w, w1, w3, w2, k=2, *,
                          expert_axis="expert", outer_axis="data_outer",
                          hierarchical="auto", dcn_quantize=False,
                          grouped_kernel="auto", int8_matmul=False,
-                         return_counts=False):
+                         return_counts=False, renormalize=True):
     """EXPERT-PARALLEL dropless SwiGLU MoE for the serving models
-    (mixtral): the same pack / all_to_all / per-shard grouped-GEMM /
-    exchange-back machinery as :func:`moe_layer_ragged_ep`, with the
-    SwiGLU expert FFN (w1 gate, w3 up, w2 down, no biases) and mixtral's
-    softmax-then-top-k renormalized combine weights. The expert product
+    (mixtral, olmoe): the same pack / all_to_all / per-shard grouped-GEMM
+    / exchange-back machinery as :func:`moe_layer_ragged_ep`, with the
+    SwiGLU expert FFN (w1 gate, w3 up, w2 down, no biases) and
+    :func:`route_topk`'s softmax-then-top-k combine weights
+    (``renormalize``: mixtral yes, olmoe no). The expert product
     runs the Pallas grouped kernel or ``lax.ragged_dot`` per the
     ``grouped_kernel`` knob ("auto" = the 'moe_grouped_mm' winner cache;
     a cold cache keeps the ragged program).
@@ -493,61 +513,59 @@ def moe_swiglu_ragged_ep(tokens, gate_w, w1, w3, w2, k=2, *,
         valid = (shard * S_loc + jnp.arange(S_loc)) < S
         valid_rep = jnp.repeat(valid, k)
 
-        logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, experts = jax.lax.top_k(probs, k)
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        with jax.named_scope("dstpu.moe.route"):
+            weights, experts = route_topk(x, gate_w, k, renormalize)
 
-        flat_exp = experts.reshape(-1).astype(jnp.int32)
-        flat_w = jnp.where(valid_rep, weights.reshape(-1), 0.0) \
-            .astype(x.dtype)
-        dest = flat_exp // E_loc
-        local_e = jnp.where(valid_rep, flat_exp % E_loc, E_loc)
-        x_rep = jnp.repeat(x, k, axis=0)
+            flat_exp = experts.reshape(-1)
+            flat_w = jnp.where(valid_rep, weights.reshape(-1), 0.0) \
+                .astype(x.dtype)
+            dest = flat_exp // E_loc
+            local_e = jnp.where(valid_rep, flat_exp % E_loc, E_loc)
+            x_rep = jnp.repeat(x, k, axis=0)
 
-        order = jnp.argsort(dest, stable=True)
-        dest_s = dest[order]
-        pos_in_bucket = jnp.arange(cap) - jnp.searchsorted(
-            dest_s, dest_s, side="left")
-        if hier:
-            # buckets keyed (inner rank, outer slice): stage 1 exchanges
-            # over the ICI expert axis, stage 2 moves each token's
-            # aggregated per-slice bucket across DCN once
-            i_dest_s = dest_s % ep
-            o_dest_s = dest_s // ep
-            send_x = jnp.zeros((ep, wo, cap, M), x.dtype)
-            send_e = jnp.full((ep, wo, cap), E_loc, jnp.int32)
-            send_x = send_x.at[i_dest_s, o_dest_s, pos_in_bucket].set(
-                x_rep[order])
-            send_e = send_e.at[i_dest_s, o_dest_s, pos_in_bucket].set(
-                local_e[order])
-            recv_x = lax.all_to_all(send_x, expert_axis, 0, 0,
-                                    tiled=False)
-            recv_e = lax.all_to_all(send_e, expert_axis, 0, 0,
-                                    tiled=False)
-            if dcn_quantize:
-                from ..comm.quantized import dcn_precision_clamp
-                recv_x = dcn_precision_clamp(recv_x)
-            recv_x = lax.all_to_all(recv_x, outer_axis, 1, 1,
-                                    tiled=False)
-            recv_e = lax.all_to_all(recv_e, outer_axis, 1, 1,
-                                    tiled=False)
-        else:
-            send_x = jnp.zeros((ep, cap, M), x.dtype)
-            send_e = jnp.full((ep, cap), E_loc, jnp.int32)
-            send_x = send_x.at[dest_s, pos_in_bucket].set(x_rep[order])
-            send_e = send_e.at[dest_s, pos_in_bucket].set(local_e[order])
-            recv_x = lax.all_to_all(send_x, expert_axis, 0, 0,
-                                    tiled=False)
-            recv_e = lax.all_to_all(send_e, expert_axis, 0, 0,
-                                    tiled=False)
-        rx = recv_x.reshape(ep_total * cap, M)
-        re = recv_e.reshape(ep_total * cap)
+            order = jnp.argsort(dest, stable=True)
+            dest_s = dest[order]
+            pos_in_bucket = jnp.arange(cap) - jnp.searchsorted(
+                dest_s, dest_s, side="left")
+            if hier:
+                # buckets keyed (inner rank, outer slice): stage 1 exchanges
+                # over the ICI expert axis, stage 2 moves each token's
+                # aggregated per-slice bucket across DCN once
+                i_dest_s = dest_s % ep
+                o_dest_s = dest_s // ep
+                send_x = jnp.zeros((ep, wo, cap, M), x.dtype)
+                send_e = jnp.full((ep, wo, cap), E_loc, jnp.int32)
+                send_x = send_x.at[i_dest_s, o_dest_s, pos_in_bucket].set(
+                    x_rep[order])
+                send_e = send_e.at[i_dest_s, o_dest_s, pos_in_bucket].set(
+                    local_e[order])
+                recv_x = lax.all_to_all(send_x, expert_axis, 0, 0,
+                                        tiled=False)
+                recv_e = lax.all_to_all(send_e, expert_axis, 0, 0,
+                                        tiled=False)
+                if dcn_quantize:
+                    from ..comm.quantized import dcn_precision_clamp
+                    recv_x = dcn_precision_clamp(recv_x)
+                recv_x = lax.all_to_all(recv_x, outer_axis, 1, 1,
+                                        tiled=False)
+                recv_e = lax.all_to_all(recv_e, outer_axis, 1, 1,
+                                        tiled=False)
+            else:
+                send_x = jnp.zeros((ep, cap, M), x.dtype)
+                send_e = jnp.full((ep, cap), E_loc, jnp.int32)
+                send_x = send_x.at[dest_s, pos_in_bucket].set(x_rep[order])
+                send_e = send_e.at[dest_s, pos_in_bucket].set(local_e[order])
+                recv_x = lax.all_to_all(send_x, expert_axis, 0, 0,
+                                        tiled=False)
+                recv_e = lax.all_to_all(send_e, expert_axis, 0, 0,
+                                        tiled=False)
+            rx = recv_x.reshape(ep_total * cap, M)
+            re = recv_e.reshape(ep_total * cap)
 
-        g_order = jnp.argsort(re, stable=True)
-        xs = rx[g_order]
-        es = re[g_order]
-        group_sizes = jnp.bincount(re, length=E_loc).astype(jnp.int32)
+            g_order = jnp.argsort(re, stable=True)
+            xs = rx[g_order]
+            es = re[g_order]
+            group_sizes = jnp.bincount(re, length=E_loc).astype(jnp.int32)
         F_dim = w1.scale.shape[-1] if hasattr(w1, "scale") \
             else w1.shape[-1]
         gp = resolve_grouped_params(grouped_kernel, ep_total * cap,
@@ -555,29 +573,31 @@ def moe_swiglu_ragged_ep(tokens, gate_w, w1, w3, w2, k=2, *,
         if int8_matmul:
             gp = dict(gp, int8=resolve_moe_int8(
                 int8_matmul, ep_total * cap, E_loc, M, F_dim, x.dtype))
-        out = _grouped_swiglu_ffn(xs, w1, w3, w2, group_sizes, gp)
-        if tn is not None:
-            # row-parallel down projection: F is 'tensor'-sharded, so
-            # the local grouped product holds partial sums (no-op tp=1)
-            out = lax.psum(out, tn)
-        out = jnp.where((es < E_loc)[:, None], out, 0.0)
+        with jax.named_scope("dstpu.moe.experts"):
+            out = _grouped_swiglu_ffn(xs, w1, w3, w2, group_sizes, gp)
+        with jax.named_scope("dstpu.moe.combine"):
+            if tn is not None:
+                # row-parallel down projection: F is 'tensor'-sharded, so
+                # the local grouped product holds partial sums (no-op tp=1)
+                out = lax.psum(out, tn)
+            out = jnp.where((es < E_loc)[:, None], out, 0.0)
 
-        back = jnp.zeros_like(out).at[g_order].set(out)
-        if hier:
-            back = back.reshape(ep, wo, cap, M)
-            if dcn_quantize:
-                from ..comm.quantized import dcn_precision_clamp
-                back = dcn_precision_clamp(back)
-            ret = lax.all_to_all(back, outer_axis, 1, 1, tiled=False)
-            ret = lax.all_to_all(ret, expert_axis, 0, 0, tiled=False)
-            ret_flat = ret[i_dest_s, o_dest_s, pos_in_bucket]
-        else:
-            back = back.reshape(ep, cap, M)
-            ret = lax.all_to_all(back, expert_axis, 0, 0, tiled=False)
-            ret_flat = ret[dest_s, pos_in_bucket]
-        unsorted = jnp.zeros_like(ret_flat).at[order].set(ret_flat)
-        y = jnp.sum(
-            (unsorted * flat_w[:, None]).reshape(S_loc, k, M), axis=1)
+            back = jnp.zeros_like(out).at[g_order].set(out)
+            if hier:
+                back = back.reshape(ep, wo, cap, M)
+                if dcn_quantize:
+                    from ..comm.quantized import dcn_precision_clamp
+                    back = dcn_precision_clamp(back)
+                ret = lax.all_to_all(back, outer_axis, 1, 1, tiled=False)
+                ret = lax.all_to_all(ret, expert_axis, 0, 0, tiled=False)
+                ret_flat = ret[i_dest_s, o_dest_s, pos_in_bucket]
+            else:
+                back = back.reshape(ep, cap, M)
+                ret = lax.all_to_all(back, expert_axis, 0, 0, tiled=False)
+                ret_flat = ret[dest_s, pos_in_bucket]
+            unsorted = jnp.zeros_like(ret_flat).at[order].set(ret_flat)
+            y = jnp.sum(
+                (unsorted * flat_w[:, None]).reshape(S_loc, k, M), axis=1)
         counts = lax.psum(
             lax.dynamic_update_slice(jnp.zeros((E,), jnp.int32),
                                      group_sizes, (shard * E_loc,)),

@@ -9,7 +9,8 @@ discipline applied to metrics: a renamed emission site or a stale
 registry entry cannot silently rot the schema dashboards are built on).
 
 ``SPAN_SCHEMA`` below is the same contract for the host spans the
-serving path opens on the profiler's clock.
+serving path opens on the profiler's clock, ``SCOPE_SCHEMA`` for the
+``jax.named_scope``s that name device operations.
 
 This module deliberately holds NOTHING but the registry: the lint
 collects emitted-tag literals by grepping the package with this file
@@ -251,6 +252,23 @@ SPAN_SCHEMA = {
         "stats": (),
         "meaning": "leaf: the Python loop feeding the fetched tokens "
                    "to their sequences"},
+}
+
+# jax.named_scope name -> meaning. Scopes name the DEVICE operations of a
+# part of a jitted program (they end up in each operation's op_name, which
+# the profiler keeps as the event's ``tf_op``); they cost nothing at run
+# time and add no host sync. Linted both ways like the spans above.
+SCOPE_SCHEMA = {
+    "dstpu.moe.route":
+        "MoE layer: pre-FFN norm, router product, float32 softmax, top-k, "
+        "sort by expert, gather of the routed rows (and, expert-parallel, "
+        "the all_to_all out)",
+    "dstpu.moe.experts":
+        "MoE layer: the three grouped expert products (gate, up, down) — "
+        "lax.ragged_dot or the Pallas grouped kernel",
+    "dstpu.moe.combine":
+        "MoE layer: unsort, weight by the routing probabilities, sum over "
+        "the k picks (and, expert-parallel, the all_to_all back)",
 }
 
 

@@ -168,6 +168,34 @@ class TestHFIngestion:
             tie_word_embeddings=False)
         _roundtrip(tmp_path, transformers.MixtralForCausalLM(cfg), inputs)
 
+    def test_olmoe(self, tmp_path, inputs):
+        # QK-norm over the whole projection, top-k weights as the softmax
+        # gave them; the same logits through the benchmark's plain
+        # reference, which is thereby held to HF's own implementation
+        cfg = transformers.OlmoeConfig(
+            vocab_size=512, hidden_size=64, intermediate_size=32,
+            num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=4, max_position_embeddings=64,
+            num_experts=8, num_experts_per_tok=2, norm_topk_prob=False,
+            tie_word_embeddings=False)
+        hf_model = transformers.OlmoeForCausalLM(cfg)
+        with torch.no_grad():
+            for layer in hf_model.model.layers:
+                layer.self_attn.q_norm.weight.normal_(1.0, 0.3)
+                layer.self_attn.k_norm.weight.normal_(1.0, 0.3)
+        model, params = _roundtrip(tmp_path, hf_model, inputs)
+        assert not model.config.norm_topk_prob and model.config.qk_norm
+        import os
+        import sys
+        sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__)))), "perfbench"))
+        from pbench import common as pb_common
+        reference = pb_common.load_module("references", "olmoe")
+        want = np.asarray(model.apply(params, jnp.asarray(inputs)))
+        got = np.asarray(reference.logits(
+            params, inputs, n_head=4, activation="silu", top_k=2))
+        np.testing.assert_allclose(got, want, atol=2e-4, rtol=1e-3)
+
     def test_gptj(self, tmp_path, inputs):
         # shared-LN parallel block, interleaved (rotate_every_two)
         # partial rotary, biased fc/lm_head over plain q/k/v/out

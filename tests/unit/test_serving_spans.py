@@ -22,14 +22,15 @@ from deepspeed_tpu.inference.v2 import InferenceEngineV2, Router
 from deepspeed_tpu.inference.v2 import engine_v2, router as router_mod
 from deepspeed_tpu.inference.v2.replica import Replica
 from deepspeed_tpu.models import GPT2, GPT2Config
-from deepspeed_tpu.monitor.tag_schema import SPAN_SCHEMA
+from deepspeed_tpu.monitor.tag_schema import SCOPE_SCHEMA, SPAN_SCHEMA
 from deepspeed_tpu.monitor.telemetry import ServingTelemetry
 from deepspeed_tpu.utils import groups
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, os.path.join(REPO, "perfbench"))
-from pbench import common as pb_common, trace as pb_trace  # noqa: E402
+from pbench import (common as pb_common, moe as pb_moe,  # noqa: E402
+                    trace as pb_trace)
 
 _CFG = GPT2Config(n_layer=2, n_head=4, d_model=64, max_seq_len=128,
                   vocab_size=256, remat=False, dtype="float32")
@@ -247,17 +248,66 @@ def test_reader(metric, bucketed, splitfuse):
         assert value <= 100.0
 
 
+MOE_READERS = ("moe_experts_share", "moe_route_share",
+               "moe_experts_roofline")
+
+
+@pytest.mark.parametrize("metric", MOE_READERS)
+def test_moe_reader(metric, bucketed):
+    """The readers of the expert layer (ISSUE 26) give None where no
+    expert product ran — no trace, the recorded training trace, a dense
+    model's serving trace (cells 1-4, the parent commit) — and the
+    recorded values on the MoE trace ``fixtures/moe1.xplane.pb``
+    (a two-layer OLMoE served on the v5e, ``fixtures/record_moe.py``)."""
+    want = pb_common.load_json("fixtures", "moe1.expected.json")
+    said = []
+    view = types.SimpleNamespace(
+        say=lambda line, **fields: said.append((line, fields)),
+        sizes=want["sizes"], peaks=pb_common.peaks_for("TPU v5 lite"),
+        counters={"traced_prompts": want["traced_prompts"]})
+    reader = pb_common.load_module("layer_metrics", metric)
+    fixtures = os.path.join(REPO, "perfbench", "fixtures")
+    for dense in (None, bucketed[0], pb_trace.Trace(
+            os.path.join(fixtures, "tiny4.xplane.pb"))):
+        view.trace = dense
+        assert reader.read(view) is None and not said
+    view.trace = pb_trace.Trace(os.path.join(fixtures, "moe1.xplane.pb"))
+    value = reader.read(view)
+    assert value == pytest.approx(want["values"][metric], rel=1e-9)
+    assert 0.0 < value <= 100.0
+    assert said[0][0] == "moe_device_seconds"
+    scoped = pb_moe.op_scopes(view.trace.path, "/device:TPU:")
+    assert {sc for name in scoped.values()
+            for sc in SCOPE_SCHEMA if sc in name} == set(SCOPE_SCHEMA)
+
+
 _SPAN_RE = re.compile(r"""\bspan\(\s*["'](dstpu\.[A-Za-z0-9_.]+)["']""")
+_SCOPE_RE = re.compile(
+    r"""\bnamed_scope\(\s*["'](dstpu\.[A-Za-z0-9_.]+)["']""")
 
 
-def test_span_schema_lint_both_directions():
-    opened = set()
+def _opened(rx):
+    found = set()
     pkg = os.path.join(REPO, "deepspeed_tpu")
     for dirpath, _, names in os.walk(pkg):
         for n in names:
             if n.endswith(".py"):
                 with open(os.path.join(dirpath, n), encoding="utf-8") as f:
-                    opened.update(_SPAN_RE.findall(f.read()))
+                    found.update(rx.findall(f.read()))
+    return found
+
+
+def test_scope_schema_lint_both_directions():
+    opened = _opened(_SCOPE_RE)
+    assert opened - set(SCOPE_SCHEMA) == set(), "scopes not in SCOPE_SCHEMA"
+    assert set(SCOPE_SCHEMA) - opened == set(), "registered, never opened"
+    assert all(n.startswith("dstpu.") and m for n, m in SCOPE_SCHEMA.items())
+    # the benchmark's readers look for the same names
+    assert {pb_moe.SCOPE_EXPERTS, *pb_moe.SCOPES_ROUTE} == set(SCOPE_SCHEMA)
+
+
+def test_span_schema_lint_both_directions():
+    opened = _opened(_SPAN_RE)
     assert opened - set(SPAN_SCHEMA) == set(), "spans not in SPAN_SCHEMA"
     assert set(SPAN_SCHEMA) - opened == set(), "registered, never opened"
     for name, entry in SPAN_SCHEMA.items():

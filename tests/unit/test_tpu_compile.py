@@ -210,3 +210,85 @@ def test_kv_pools_keep_the_kernels_layout(v5e, monkeypatch, program):
     assert len(layouts) == 8 and set(layouts) == {row_major}  # in and out
     one_pool = POOL_NB * H * BS * HD * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_pool
+
+
+# OLMoE-1B-7B at the published widths of a layer (hidden 2048, 16 heads of
+# 128, experts of width 1024, 8 per token), two layers deep with 16 of the
+# 64 experts so that it compiles in seconds. ``lax.ragged_dot`` (a Mosaic
+# fusion on the TPU) and the Pallas grouped kernel cannot read a layer's
+# slice of a stacked (L, E, D, F) array in place: the compiled program
+# copies it, a weight-sized temporary per layer per step, and the published
+# model does not fit its chip (ISSUE 26: 9.34 GB of temporaries at 12
+# layers). The served tree keeps each layer's experts as operands of their
+# own; at head dim 128 the pools keep the plain block axis.
+MOE_E, MOE_T = 16, 256
+
+
+def _olmoe():
+    from deepspeed_tpu.models import OLMoE, OLMoEConfig
+    model = OLMoE(OLMoEConfig(n_layer=2, num_experts=MOE_E))
+    model._paged_kernel, model._paged_block_c = True, "auto"
+    return model
+
+
+def _olmoe_decode(model):
+    def decode(params, cache, tokens, lengths, tables):
+        logits, cache = model.apply_paged_decode(
+            params, tokens, lengths, cache, tables)
+        return jnp.argmax(logits, axis=-1).astype(i32), cache
+    mb = model.config.max_seq_len // BS
+    return decode, [((SLOTS,), i32), ((SLOTS,), i32), ((SLOTS, mb), i32)]
+
+
+def _olmoe_prefill(model):
+    def prefill(params, cache, ids, tb, to, length):
+        logits, cache = model.apply_paged_prefill(
+            params, ids, cache, tb, to, length)
+        return jnp.argmax(logits, axis=-1), cache
+    return prefill, [((1, MOE_T), i32), ((MOE_T,), i32), ((MOE_T,), i32),
+                     ((), i32)]
+
+
+def _olmoe_compiled(model, tree, program, v5e):
+    cfg = model.config
+    assert pool_block_dims(POOL_NB, cfg.d_head, kernel_layout=True) \
+        == (POOL_NB,)                      # head dim 128: no split axis
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, bf16, sharding=v5e), tree)
+    cache = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+        jax.eval_shape(lambda: model.init_paged_cache(POOL_NB, BS,
+                                                      dtype=bf16)))
+    fn, shapes = program(model)
+    rest = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    return jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *rest).compile()
+
+
+@pytest.mark.parametrize("program", [_olmoe_decode, _olmoe_prefill])
+def test_olmoe_experts_are_read_in_place(v5e, monkeypatch, program):
+    import re
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = _olmoe()
+    cfg = model.config
+    stacked = jax.eval_shape(model.init, jax.random.key(0))
+    expert_bytes = cfg.n_layer * MOE_E * 3 * cfg.d_model * cfg.ffn_dim * 2
+    served = _olmoe_compiled(
+        model, jax.eval_shape(model.init_served, jax.random.key(0)),
+        program, v5e)
+    text = served.as_text()
+    assert "tpu_custom_call" in text            # the paged kernels
+    temp = served.memory_analysis().temp_size_in_bytes
+    assert temp < 0.1 * expert_bytes, (temp, expert_bytes)
+    weight = rf"bf16\[{MOE_E},(?:{cfg.d_model},{cfg.ffn_dim}" \
+             rf"|{cfg.ffn_dim},{cfg.d_model})\]"
+    assert not re.findall(rf"= {weight}\S* (?:copy|slice|fusion)\(", text)
+    pool = rf"bf16\[{POOL_NB},{cfg.n_kv_heads},{BS},{cfg.d_head}\]"
+    assert not re.findall(rf"= {pool}\S* copy\(", text)
+    # what the guard is for: the same program over the stacked training
+    # tree materialises each layer's experts
+    if program is _olmoe_decode:
+        stacked_temp = _olmoe_compiled(
+            model, stacked, program, v5e).memory_analysis().temp_size_in_bytes
+        assert stacked_temp > 0.3 * expert_bytes, (stacked_temp,
+                                                   expert_bytes)
