@@ -34,7 +34,8 @@ from ...utils import groups
 from ...utils.groups import TopologyConfig
 from ...utils.logging import log_dist
 from ...monitor.telemetry import span
-from ...ops.pallas.paged_attention import (as_pools, like_boundary,
+from ...ops.pallas.paged_attention import (as_pools, decode_grid_steps,
+                                              like_boundary,
                                               pool_block_dims)
 from ..utils import shard_params
 from .ragged import DSStateManager, RaggedBatchWrapper
@@ -1067,16 +1068,35 @@ class InferenceEngineV2:
         if self.telemetry is not None:
             self.telemetry.on_handoff_out(uid)
 
-    def _dispatch_span(self, kind, active, steps, chunk_tokens=0):
+    def _dispatch_span(self, kind, active, steps, chunk_tokens=0,
+                       lengths=None):
         """The ``dstpu.engine.dispatch`` span of one program call, opened
         once the batch is assembled (its stats are fixed here); a
-        decode-bearing dispatch also feeds the occupancy counter."""
-        active = int(active)
+        decode-bearing dispatch also feeds the occupancy counter.
+        ``active``: a count, or — with ``lengths``, where the dispatch
+        runs the paged-decode kernel over the batch — the live slots'
+        mask, and the span then says how much of the block table the
+        kernel's grid walks (host arithmetic, no device read)."""
         slots = self.config.max_batch_size
+        grid_steps = table_entries = 0
+        if lengths is not None:
+            cfg = self.model.config
+            MB, BS = self.max_blocks_per_seq, self.state_mgr.block_size
+            windows = getattr(cfg, "attn_layer_windows", ()) \
+                or (getattr(cfg, "sliding_window", 0),)
+            # a kernel call's steps, the layers' mean where their windows
+            # differ, over the dispatch's decode steps
+            grid_steps = round(sum(decode_grid_steps(
+                lengths, active, MB, BS, w, steps) for w in windows)
+                / len(windows))
+            table_entries = steps * slots * MB
+        active = int(np.sum(active))
         if steps and self.telemetry is not None:
-            self.telemetry.on_decode_batch(active, slots)
+            self.telemetry.on_decode_batch(active, slots, grid_steps,
+                                           table_entries)
         return span("dstpu.engine.dispatch", kind=kind, active=active,
-                    slots=slots, steps=steps, chunk_tokens=chunk_tokens)
+                    slots=slots, steps=steps, chunk_tokens=chunk_tokens,
+                    grid_steps=grid_steps, table_entries=table_entries)
 
     def _step_splitfuse_chunk(self):
         """Run one fused dispatch: the next chunk of the oldest
@@ -1128,8 +1148,9 @@ class InferenceEngineV2:
 
         if fused:
             dispatch = self._dispatch_span(
-                "fused", batch.active.sum(),
-                max(1, self.config.decode_steps_per_dispatch), true_len)
+                "fused", batch.active,
+                max(1, self.config.decode_steps_per_dispatch), true_len,
+                lengths=batch.lengths)
         else:
             dispatch = self._dispatch_span("chunk", 0, 0, true_len)
         with dispatch:
@@ -1318,7 +1339,8 @@ class InferenceEngineV2:
                 for s in slots_g:
                     tables[s] = pool.translate(batch.block_tables[s])
                 self._rng, sub = jax.random.split(self._rng)
-            with self._dispatch_span("offload", sub_active.sum(), n):
+            with self._dispatch_span("offload", sub_active, n,
+                                     lengths=lengths):
                 with span("dstpu.engine.fetch"):
                     with jax.set_mesh(self.mesh):
                         toks, self.cache = fn(
@@ -1402,8 +1424,9 @@ class InferenceEngineV2:
             self._rng, sub = jax.random.split(self._rng)
             fn = self._get_decode()
         with self._dispatch_span(
-                "decode", batch.active.sum(),
-                max(1, self.config.decode_steps_per_dispatch)):
+                "decode", batch.active,
+                max(1, self.config.decode_steps_per_dispatch),
+                lengths=batch.lengths):
             with span("dstpu.engine.fetch"):
                 with jax.set_mesh(self.mesh):
                     toks, self.cache = fn(self.params, self.cache,

@@ -1100,12 +1100,19 @@ class GPT2:
         dst_block = jnp.take_along_axis(
             block_tables, (lengths // BS)[:, None], axis=1)[:, 0]
         dst_off = lengths % BS
-        from ..ops.pallas.paged_attention import (paged_kv_write,
+        from ..ops.pallas.paged_attention import (decode_work_list,
+                                                  paged_kv_write,
                                                   resolve_paged_decode)
+        MB = block_tables.shape[1]
         use_kernel = resolve_paged_decode(
-            getattr(self, "_paged_kernel", "auto"), B,
-            block_tables.shape[1], BS, cfg.n_head, 1, cfg.d_head,
-            _dtype(cfg))
+            getattr(self, "_paged_kernel", "auto"), B, MB, BS,
+            cfg.n_head, 1, cfg.d_head, _dtype(cfg))
+        # the kernel's grid: this step's live (slot, block) pairs, one
+        # list per window size, shared by every layer that has it
+        work = {w: decode_work_list(lengths, MB, BS, w,
+                                    active=block_tables[:, 0] != 0)
+                for w in set(cfg.attn_layer_windows or (0,))} \
+            if use_kernel else {}
 
         ks_out, vs_out = [], []
         for i in range(cfg.n_layer):
@@ -1127,7 +1134,8 @@ class GPT2:
                 kc, vc = paged_kv_write(
                     (kc0, vc0), (kk[:, 0], v[:, 0]), dst_block, dst_off,
                     kernel=use_kernel)
-                fn = paged_decode_attention if use_kernel \
+                fn = partial(paged_decode_attention,
+                             work=work[w]) if use_kernel \
                     else paged_decode_attention_reference
                 attn = fn(
                     q[:, 0], kc, vc, block_tables, lengths,

@@ -921,14 +921,20 @@ class Llama:
         dst_block = jnp.take_along_axis(
             block_tables, (lengths // BS)[:, None], axis=1)[:, 0]
         dst_off = lengths % BS
-        from ..ops.pallas.paged_attention import (paged_kv_write,
+        from ..ops.pallas.paged_attention import (decode_work_list,
+                                                  paged_kv_write,
                                                   resolve_paged_decode)
+        MB = block_tables.shape[1]
         # ALiBi families keep the kernel regardless of the mode switch
         # (the dense fallback lacks the falcon bf16-quantized variant)
         use_kernel = cfg.alibi or resolve_paged_decode(
             getattr(self, "_paged_kernel", "auto"), tokens.shape[0],
-            block_tables.shape[1], BS, cfg.n_kv_heads,
-            H // cfg.n_kv_heads, hd, dt)
+            MB, BS, cfg.n_kv_heads, H // cfg.n_kv_heads, hd, dt)
+        # the kernel's grid: this step's live (slot, block) pairs, made
+        # once and shared by every layer
+        work = decode_work_list(
+            lengths, MB, BS, cfg.sliding_window,
+            active=block_tables[:, 0] != 0) if use_kernel else None
 
         ks_out, vs_out = [], []
         for i in range(cfg.n_layer):
@@ -949,7 +955,7 @@ class Llama:
                 paged_decode_attention_reference)
             if use_kernel:
                 attn = paged_decode_attention(
-                    q[:, 0], kc, vc, block_tables, lengths,
+                    q[:, 0], kc, vc, block_tables, lengths, work=work,
                     window=cfg.sliding_window,
                     alibi_slopes=(alibi_slopes(H) if cfg.alibi
                                   else None),

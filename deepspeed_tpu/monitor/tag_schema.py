@@ -236,10 +236,14 @@ SPAN_SCHEMA = {
         "meaning": "bucketed prefill of one request: arrays, program "
                    "call, blocking read of its token"},
     "dstpu.engine.dispatch": {
-        "stats": ("kind", "active", "slots", "steps", "chunk_tokens"),
+        "stats": ("kind", "active", "slots", "steps", "chunk_tokens",
+                  "grid_steps", "table_entries"),
         "meaning": "one decode-bearing or chunk program call (kind "
                    "decode | fused | chunk | spec | offload) from the "
-                   "assembled batch to the last posted token"},
+                   "assembled batch to the last posted token; grid_steps "
+                   "of table_entries = how much of the block table one "
+                   "paged-decode kernel call walks, over the dispatch's "
+                   "decode steps"},
     "dstpu.engine.build": {
         "stats": (),
         "meaning": "leaf: host work before a program call (decode "

@@ -823,6 +823,10 @@ class ServingTelemetry:
         # dispatches since engine construction
         self._occ_active = 0
         self._occ_slots = 0
+        # the paged-decode kernel's grid steps against the block table's
+        # entries, summed over the same dispatches
+        self._grid_steps = 0
+        self._table_entries = 0
         self.completed = 0
         self.rejected = 0
         self.active = 0
@@ -877,11 +881,16 @@ class ServingTelemetry:
             self._refresh_queue()
         return wait_ms
 
-    def on_decode_batch(self, active, slots):
+    def on_decode_batch(self, active, slots, grid_steps=0,
+                        table_entries=0):
         """One decode-bearing dispatch ran with ``active`` of ``slots``
-        batch slots live."""
+        batch slots live, its decode kernel walking ``grid_steps`` of the
+        block table's ``table_entries`` (a call's, over the dispatch's
+        decode steps)."""
         self._occ_active += active
         self._occ_slots += slots
+        self._grid_steps += grid_steps
+        self._table_entries += table_entries
 
     def on_token(self, uid):
         """First token => TTFT sample; later tokens accumulate for the
@@ -1023,6 +1032,9 @@ class ServingTelemetry:
         if self._occ_slots:
             out["batch_occupancy_pct"] = round(
                 100.0 * self._occ_active / self._occ_slots, 2)
+        if self._table_entries:
+            out["decode_grid_share"] = round(
+                self._grid_steps / self._table_entries, 4)
         if self.rejected:
             # only present once a cancel/shed happened: router-off
             # engine snapshots stay byte-identical to pre-router runs

@@ -12,9 +12,13 @@ traffic in COPIES per layer, then attention over the fully padded length.
 This kernel instead streams each KV block through VMEM exactly once,
 indexed directly by the block table (scalar-prefetch index_map — the block
 id picked per grid step comes from the table in SMEM), with online softmax
-across blocks; blocks past the sequence's length are clamped to the
-scratch block in the index map and fully masked, so padded table tails
-cost no fresh DMA.
+across blocks. Its grid is not the table but the list of (slot, table
+entry) pairs that hold something to attend (:func:`decode_work_list`,
+made on the device from ``lengths`` once a decode step, its length a
+device scalar): a grid step costs its ~0.3 us whether or not its block is
+live, and a table is mostly tail — entries past a sequence's length, and
+whole rows of inactive slots — so a step for every entry cost several
+times the live blocks' DMA (PERF.md, PR 27).
 
 GQA is native: q heads fold to (KVH, G, d) and both dots batch over KVH —
 no repeat_kv materialization.
@@ -36,6 +40,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -123,85 +128,144 @@ def alibi_slopes(n_head):
 alibi_slopes_formula = alibi_slopes
 
 
-def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, BS, KVH, G, scale, window,
-                   alibi, alibi_scale=1.0, alibi_bf16=False):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
+def _attended_entries(xp, lengths, MB, BS, window):
+    """(first, last) table entry each slot's new token attends, in
+    ``xp`` (jax.numpy on the device, numpy on the host)."""
+    last = xp.clip(lengths // BS, 0, MB - 1)
+    if not window:
+        return xp.zeros_like(last), last
+    return xp.minimum(xp.maximum(lengths - window + 1, 0) // BS, last), last
+
+
+def decode_grid_steps(lengths, active, MB, BS, window=0, steps=1):
+    """The count ``n`` of :func:`decode_work_list`, on the host in numpy
+    and summed over ``steps`` consecutive decode steps (a dispatch's:
+    every slot's length grows by one a step): what the engine's
+    telemetry sets against the ``steps * B * MB`` table entries."""
+    lengths, active = np.asarray(lengths), np.asarray(active, bool)
+    first, last = _attended_entries(
+        np, lengths[active][:, None] + np.arange(steps), MB, BS, window)
+    return int(np.sum(last - first + 1))
+
+
+def decode_work_list(lengths, MB, BS, window=0, active=None):
+    """The decode kernel's grid, as data: the (slot, table entry) pairs
+    that hold something the new token attends.
+
+    lengths: (B,) int32, the new token's position per slot; MB: table
+    entries a slot; ``window`` as :func:`paged_decode_attention` takes
+    it; ``active``: (B,) bool, the slots that hold a sequence (all, when
+    not given). Active slot b contributes entries ``first[b] ..
+    lengths[b] // BS`` (``first`` is 0 without a window, else the entry
+    holding position ``lengths[b] - window + 1``); an inactive slot
+    contributes nothing. Returns ``(slot_of, entry_of, n)``: two
+    ``int32[B*MB + 1]`` arrays, slot-major, and the count of pairs;
+    ``slot_of`` reads B from item ``n`` on, so a slot's last item is the
+    one whose successor names another slot.
+
+    A handful of small integer operations: compute it once a decode step
+    and hand it to every layer's call (layers with another ``window``
+    take a list of their own)."""
+    B = lengths.shape[0]
+    first, last = _attended_entries(jnp, lengths, MB, BS, window)
+    n_blocks = last - first + 1
+    if active is not None:
+        n_blocks = jnp.where(active, n_blocks, 0)
+    ends = jnp.cumsum(n_blocks)                          # (B,) running sum
+    item = jnp.arange(B * MB + 1, dtype=jnp.int32)
+    # item i belongs to the first slot whose running sum passes i. As
+    # compares and sums over (items, B): a searchsorted or a gather here
+    # is a loop on the TPU, ~140 us a decode step (PERF.md, PR 27)
+    slot_of = jnp.sum(ends[None, :] <= item[:, None], axis=1,
+                      dtype=jnp.int32)
+    mine = slot_of[:, None] == jnp.arange(B, dtype=jnp.int32)[None, :]
+    entry_of = item + jnp.sum(
+        jnp.where(mine, (last - ends + 1)[None, :], 0), axis=1)
+    return (slot_of, jnp.clip(entry_of, 0, MB - 1).astype(jnp.int32),
+            ends[-1].astype(jnp.int32))
+
+
+def _decode_kernel(tbl_ref, len_ref, slot_ref, entry_ref, q_ref, k_ref,
+                   v_ref, o_ref, m_ref, l_ref, acc_ref, *, BS, KVH, G,
+                   scale, window, alibi, alibi_scale=1.0,
+                   alibi_bf16=False):
+    """Grid step i is item i of the work list: slot ``slot_ref[i]``
+    against the KV block its table's ``entry_ref[i]``-th entry names.
+    A slot's items are consecutive, so its q and o tiles stay resident
+    from its first item to its last."""
+    i = pl.program_id(0)
+    b = slot_ref[i]
+    j = entry_ref[i]
     H = KVH * G
-    d = q_ref.shape[-1]
     L = len_ref[b]
 
-    @pl.when(j == 0)
+    @pl.when((i == 0) | (slot_ref[jnp.maximum(i - 1, 0)] != b))
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    live = j * BS <= L
+    kb = k_ref[0]                                     # (KVH, BS, d)
+    vb = v_ref[0]
+    # q arrives (1, KVH, G, d) — the caller reshaped (B, H, d) to
+    # (B, KVH, G, d) OUTSIDE the kernel (in-kernel singleton reshapes
+    # are unsupported shape casts in Mosaic, and a dot needs a
+    # non-contracting lhs dim, which G provides even when == 1)
+    q = q_ref[0]
+    s = jax.lax.dot_general(
+        q, kb, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * scale   # (KVH, G, BS)
+    pos = j * BS + jax.lax.broadcasted_iota(jnp.int32, (KVH, G, BS), 2)
+    if alibi:
+        # ALiBi: slope_h * k_pos (softmax-shift equivalent to
+        # slope_h * (k_pos - q_pos); matches the dense paths).
+        # Slopes are computed IN-KERNEL from the head index (a
+        # captured constant array is rejected by pallas_call): the
+        # bloom formula splits at the leading power of two cp.
+        h = (jax.lax.broadcasted_iota(jnp.int32, (KVH, G, BS), 0) * G
+             + jax.lax.broadcasted_iota(jnp.int32, (KVH, G, BS), 1)
+             ).astype(jnp.float32)
+        cp = float(2 ** math.floor(math.log2(H)))
+        expo = jnp.where(h < cp, -(h + 1.0) * (8.0 / cp),
+                         -(2.0 * (h - cp) + 1.0) * (4.0 / cp))
+        ab = jnp.exp2(expo) * pos.astype(jnp.float32)
+        if alibi_bf16:
+            # HF falcon quantizes the alibi tensor through bf16 and
+            # adds it pre-scaling (models/llama.py _alibi_bias)
+            ab = ab.astype(jnp.bfloat16).astype(jnp.float32)
+        if alibi_scale != 1.0:
+            ab = ab * alibi_scale
+        s = s + ab
+    ok = pos <= L
     if window:
         # sliding window: the query (at position L) only attends
-        # positions > L - window; blocks entirely below that are dead
-        live = live & (j * BS + BS > L - window + 1)
+        # positions > L - window
+        ok = ok & (pos > L - window)
+    s = jnp.where(ok, s, NEG_INF)
 
-    @pl.when(live)
-    def _step():
-        kb = k_ref[0]                                     # (KVH, BS, d)
-        vb = v_ref[0]
-        # q arrives (1, KVH, G, d) — the caller reshaped (B, H, d) to
-        # (B, KVH, G, d) OUTSIDE the kernel (in-kernel singleton reshapes
-        # are unsupported shape casts in Mosaic, and a dot needs a
-        # non-contracting lhs dim, which G provides even when == 1)
-        q = q_ref[0]
-        s = jax.lax.dot_general(
-            q, kb, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale   # (KVH, G, BS)
-        pos = j * BS + jax.lax.broadcasted_iota(jnp.int32, (KVH, G, BS), 2)
-        if alibi:
-            # ALiBi: slope_h * k_pos (softmax-shift equivalent to
-            # slope_h * (k_pos - q_pos); matches the dense paths).
-            # Slopes are computed IN-KERNEL from the head index (a
-            # captured constant array is rejected by pallas_call): the
-            # bloom formula splits at the leading power of two cp.
-            h = (jax.lax.broadcasted_iota(jnp.int32, (KVH, G, BS), 0) * G
-                 + jax.lax.broadcasted_iota(jnp.int32, (KVH, G, BS), 1)
-                 ).astype(jnp.float32)
-            cp = float(2 ** math.floor(math.log2(H)))
-            expo = jnp.where(h < cp, -(h + 1.0) * (8.0 / cp),
-                             -(2.0 * (h - cp) + 1.0) * (4.0 / cp))
-            ab = jnp.exp2(expo) * pos.astype(jnp.float32)
-            if alibi_bf16:
-                # HF falcon quantizes the alibi tensor through bf16 and
-                # adds it pre-scaling (models/llama.py _alibi_bias)
-                ab = ab.astype(jnp.bfloat16).astype(jnp.float32)
-            if alibi_scale != 1.0:
-                ab = ab * alibi_scale
-            s = s + ab
-        ok = pos <= L
-        if window:
-            ok = ok & (pos > L - window)
-        s = jnp.where(ok, s, NEG_INF)
+    m_prev = m_ref[..., 0]                            # (KVH, G)
+    l_prev = l_ref[..., 0]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new[..., None])                 # (KVH, G, BS) f32
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1)
+    pv = jax.lax.dot_general(
+        p.astype(vb.dtype), vb, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)           # (KVH, G, d)
+    acc = acc_ref[...] * alpha[..., None] + pv
+    acc_ref[...] = acc
+    m_ref[...] = jnp.broadcast_to(m_new[..., None], m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new[..., None], l_ref.shape)
 
-        m_prev = m_ref[..., 0]                            # (KVH, G)
-        l_prev = l_ref[..., 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[..., None])                 # (KVH, G, BS) f32
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-        pv = jax.lax.dot_general(
-            p.astype(vb.dtype), vb, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)           # (KVH, G, d)
-        acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
-        m_ref[...] = jnp.broadcast_to(m_new[..., None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[..., None], l_ref.shape)
-
-    l = jnp.maximum(l_ref[..., 0], 1e-30)                 # (KVH, G)
-    o_ref[0] = (acc_ref[...] / l[..., None]).astype(o_ref.dtype)
+    @pl.when(slot_ref[i + 1] != b)
+    def _store():
+        l = jnp.maximum(l_new, 1e-30)                 # (KVH, G)
+        o_ref[0] = (acc / l[..., None]).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_cache, v_cache, block_tables, lengths, *,
-                           scale=None, interpret=None, window=0,
-                           alibi_slopes=None, alibi_scale=1.0,
+                           work=None, scale=None, interpret=None,
+                           window=0, alibi_slopes=None, alibi_scale=1.0,
                            alibi_bf16=False):
     """One decode step of attention over a paged KV cache.
 
@@ -211,7 +275,13 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, lengths, *,
     already be written to the cache (the callers do the dynamic-slot
     write first). ``window`` > 0 restricts attention to the trailing
     ``window`` positions (mistral); ``alibi_slopes`` (len H floats) adds
-    the bloom per-head linear position bias.
+    the bloom per-head linear position bias. ``work``: the step's
+    :func:`decode_work_list` for this ``window`` (made here when not
+    given, with every slot active; a model makes it once for all its
+    layers). The grid is that list, so its length is a device scalar and
+    the call cannot be ``vmap``ped; a slot the list leaves out takes no
+    grid step, and its output row is its q row (the output aliases q):
+    finite, and read by nobody.
 
     Multi-layer pools: view (L, NB, ...) as (L*NB, ...) (a free reshape)
     and offset the tables by ``layer * NB`` — a lax.scan over layers then
@@ -239,25 +309,27 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, lengths, *,
                 "paged_decode_attention computes bloom-formula ALiBi "
                 "slopes in-kernel; custom per-head slopes are not "
                 "supported")
+    if work is None:
+        work = decode_work_list(lengths, MB, BS, window)
+    slot_of, entry_of, n = work
+
+    # the pipeline may look one item ahead of the last, where ``slot_of``
+    # reads B: keep every index it can form inside its array
+    def qo_index(i, tbl, lens, slot, entry):
+        return (jnp.minimum(slot[i], B - 1), 0, 0, 0)
+
+    def kv_index(i, tbl, lens, slot, entry):
+        return (tbl[jnp.minimum(slot[i], B - 1), entry[i]], 0, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, MB),
+        num_scalar_prefetch=4,
+        grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, KVH, G, d),
-                         lambda b, j, tbl, lens: (b, 0, 0, 0)),
-            pl.BlockSpec(
-                (1, KVH, BS, d),
-                lambda b, j, tbl, lens: (
-                    jnp.where(j * BS <= lens[b], tbl[b, j],
-                              tbl[b, 0]), 0, 0, 0)),
-            pl.BlockSpec(
-                (1, KVH, BS, d),
-                lambda b, j, tbl, lens: (
-                    jnp.where(j * BS <= lens[b], tbl[b, j],
-                              tbl[b, 0]), 0, 0, 0)),
+            pl.BlockSpec((1, KVH, G, d), qo_index),
+            pl.BlockSpec((1, KVH, BS, d), kv_index),
+            pl.BlockSpec((1, KVH, BS, d), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, KVH, G, d),
-                               lambda b, j, tbl, lens: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, KVH, G, d), qo_index),
         scratch_shapes=[
             pltpu.VMEM((KVH, G, 128), jnp.float32),  # running max
             pltpu.VMEM((KVH, G, 128), jnp.float32),  # running denom
@@ -272,8 +344,11 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, lengths, *,
                           alibi_bf16=bool(alibi_bf16)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, d), q.dtype),
+        # operands 0-3 are the scalar-prefetched integers; q is 4
+        input_output_aliases={4: 0},
         interpret=interpret,
-    )(block_tables, lengths, q.reshape(B, KVH, G, d), k_cache, v_cache)
+    )(block_tables, lengths, slot_of, entry_of,
+      q.reshape(B, KVH, G, d), k_cache, v_cache)
     return out.reshape(B, H, d)
 
 

@@ -1,11 +1,13 @@
 """Paged-serving fast path (tier-1): the chunked-prefill Pallas kernel
-vs the dense-gather reference (interpret mode), the aliased new-token
-write vs the scatter it replaces, the compiled chunk program's
+vs the dense-gather reference (interpret mode), the decode kernel's
+work-list grid vs a plain numpy reference, the aliased new-token write
+vs the scatter it replaces, the compiled chunk program's
 no-dense-gather guarantee, engine greedy identity with the kernels on
 vs off, warm/cold winner-cache dispatch HLO identity for the serving
 autotune ops, and mixtral's ragged-EP serving routing."""
 
 import functools
+import math
 import os
 
 import numpy as np
@@ -182,6 +184,157 @@ class TestKVWriteParity:
             # and it wrote something: the reference differs from the pool
             assert not np.array_equal(np.asarray(w[1:]).view(np.uint16),
                                       np.asarray(p[1:]).view(np.uint16))
+
+
+def _paged_decode_numpy(q, kc, vc, tbl, lens, *, window=0, alibi=False,
+                        alibi_scale=1.0, alibi_bf16=False):
+    """Plain numpy paged decode attention, one slot and head at a time,
+    with every knob of the kernel (the jnp reference has no bf16 ALiBi)."""
+    from deepspeed_tpu.ops.pallas.paged_attention import alibi_slopes
+    q, kc, vc = (np.asarray(a, np.float64) for a in (q, kc, vc))
+    B, H, d = q.shape
+    KVH, BS = kc.shape[1], kc.shape[2]
+    G = H // KVH
+    slopes = alibi_slopes(H)
+    out = np.zeros((B, H, d))
+    for b in range(B):
+        L = int(lens[b])
+        pos = np.arange(max(0, L - window + 1) if window else 0, L + 1)
+        blk, off = np.asarray(tbl)[b, pos // BS], pos % BS
+        for h in range(H):
+            k, v = kc[blk, h // G, off], vc[blk, h // G, off]
+            s = k @ q[b, h] / math.sqrt(d)
+            if alibi:
+                ab = np.float32(slopes[h]) * pos.astype(np.float32)
+                if alibi_bf16:
+                    ab = np.asarray(jnp.asarray(ab).astype(jnp.bfloat16)
+                                    .astype(jnp.float32))
+                s = s + ab * alibi_scale
+            w = np.exp(s - s.max())
+            out[b, h] = (w / w.sum()) @ v
+    return out
+
+
+class TestPagedDecodeWorkList:
+    """The decode kernel's grid is the list of live (slot, table entry)
+    pairs (ISSUE 27): same answers as the dense reference on every live
+    slot whatever the mix of lengths, and nothing past a slot's length or
+    in an inactive slot is touched."""
+
+    B, KVH, BS, MB, NB, d = 6, 2, 16, 8, 50, 32
+    # 0 on dead slots; exactly BS-1, BS and MB*BS-1 among the live ones
+    LENGTHS = {
+        "ragged": ([0, 15, 16, 0, 127, 5], [0, 1, 1, 0, 1, 1]),
+        "every_slot_full": ([127] * 6, [1] * 6),
+        "one_live": ([0, 0, 77, 0, 0, 0], [0, 0, 1, 0, 0, 0]),
+    }
+    MODES = {
+        "plain": dict(G=1),
+        "gqa": dict(G=4),
+        "window": dict(G=1, window=20),
+        "gqa_window": dict(G=2, window=40),
+        "alibi": dict(G=1, alibi=True),
+        "alibi_bf16": dict(G=2, alibi=True, alibi_bf16=True,
+                           alibi_scale=1.0 / math.sqrt(32)),
+    }
+
+    def _setup(self, G, lengths, active, seed=0):
+        rng = np.random.RandomState(seed)
+        H = self.KVH * G
+        q = jnp.asarray(rng.randn(self.B, H, self.d), jnp.float32) * 0.5
+        kc, vc = (jnp.asarray(rng.randn(self.NB, self.KVH, self.BS, self.d),
+                              jnp.float32) * 0.5 for _ in range(2))
+        lens = np.asarray(lengths, np.int32)
+        act = np.asarray(active, bool)
+        # the engine's tables: a live slot owns blocks 1.., an inactive
+        # one is all scratch block 0
+        tbl = np.zeros((self.B, self.MB), np.int32)
+        own = rng.permutation(np.arange(1, self.NB))
+        for b in np.flatnonzero(act):
+            nb = lens[b] // self.BS + 1
+            tbl[b, :nb], own = own[:nb], own[nb:]
+        return q, kc, vc, jnp.asarray(tbl), jnp.asarray(lens), act
+
+    def _run(self, q, kc, vc, tbl, lens, act, *, window=0, alibi=False,
+             **kw):
+        from deepspeed_tpu.ops.pallas.paged_attention import (
+            alibi_slopes, decode_work_list, paged_decode_attention)
+        work = decode_work_list(lens, self.MB, self.BS, window,
+                                active=jnp.asarray(act))
+        return paged_decode_attention(
+            q, kc, vc, tbl, lens, work=work, window=window,
+            alibi_slopes=alibi_slopes(q.shape[1]) if alibi else None,
+            **kw), work
+
+    @pytest.mark.parametrize("lengths", sorted(LENGTHS))
+    @pytest.mark.parametrize("mode", sorted(MODES))
+    def test_matches_reference(self, mode, lengths):
+        kw = dict(self.MODES[mode])
+        q, kc, vc, tbl, lens, act = self._setup(kw.pop("G"),
+                                                *self.LENGTHS[lengths])
+        out, (slot_of, _, n) = self._run(q, kc, vc, tbl, lens, act, **kw)
+        want = _paged_decode_numpy(q, kc, vc, tbl, lens, **kw)
+        np.testing.assert_allclose(np.asarray(out)[act], want[act],
+                                   rtol=2e-5, atol=2e-5)
+        # an inactive slot takes no grid step; its row is q's: finite
+        assert set(np.asarray(slot_of)[:int(n)]) == set(np.flatnonzero(act))
+        np.testing.assert_array_equal(np.asarray(out)[~act],
+                                      np.asarray(q)[~act])
+
+    def test_every_slot_active_by_default(self):
+        """Without a list of its own the kernel walks every slot, and a
+        slot at length 0 attends its one position like any other."""
+        from deepspeed_tpu.ops.pallas.paged_attention import (
+            paged_decode_attention, paged_decode_attention_reference)
+        q, kc, vc, tbl, lens, _ = self._setup(2, *self.LENGTHS["ragged"])
+        out = paged_decode_attention(q, kc, vc, tbl, lens)
+        ref = paged_decode_attention_reference(q, kc, vc, tbl, lens)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+        assert np.isfinite(np.asarray(out)).all()
+
+    @pytest.mark.parametrize("window", [0, 20])
+    def test_table_tail_is_never_read(self, window):
+        """Entries past a slot's length (and a whole inactive row) may
+        name any block: scrambling them changes no bit of the output."""
+        q, kc, vc, tbl, lens, act = self._setup(1, *self.LENGTHS["ragged"])
+        out1, _ = self._run(q, kc, vc, tbl, lens, act, window=window)
+        rng = np.random.RandomState(7)
+        tail = np.arange(self.MB)[None, :] > np.asarray(lens)[:, None] \
+            // self.BS
+        tail |= ~act[:, None]
+        tbl2 = jnp.where(tail, rng.randint(0, self.NB, tail.shape), tbl)
+        out2, _ = self._run(q, kc, vc, tbl2, lens, act, window=window)
+        np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
+
+    @pytest.mark.parametrize("window", [0, 20, 100])
+    def test_list_is_the_live_pairs(self, window):
+        """The list names exactly the (slot, entry) pairs that hold a
+        position the new token attends, slot-major, and the host's count
+        (the telemetry's) is the device's."""
+        from deepspeed_tpu.ops.pallas.paged_attention import (
+            decode_grid_steps, decode_work_list)
+        lens, act = self.LENGTHS["ragged"]
+        lens, act = np.asarray(lens, np.int32), np.asarray(act, bool)
+        slot_of, entry_of, n = decode_work_list(
+            jnp.asarray(lens), self.MB, self.BS, window,
+            active=jnp.asarray(act))
+        want = [(b, j) for b in range(self.B) if act[b]
+                for j in range(self.MB)
+                if j * self.BS <= lens[b]
+                and (not window or j * self.BS + self.BS - 1
+                     > lens[b] - window)]
+        n = int(n)
+        assert list(zip(np.asarray(slot_of)[:n].tolist(),
+                        np.asarray(entry_of)[:n].tolist())) == want
+        assert (np.asarray(slot_of)[n:] == self.B).all()
+        assert decode_grid_steps(lens, act, self.MB, self.BS, window) == n
+        # over a dispatch's steps every length grows by one a step
+        assert decode_grid_steps(lens, act, self.MB, self.BS, window,
+                                 steps=3) == sum(
+            int(decode_work_list(jnp.asarray(lens + t), self.MB, self.BS,
+                                 window, active=jnp.asarray(act))[2])
+            for t in range(3))
 
 
 class TestPoolBoundaryShape:

@@ -549,7 +549,9 @@ class TestRouterTelemetry:
         assert set(snap) == {"ttft_ms_p50", "ttft_ms_p99",
                              "tpot_ms_p50", "tpot_ms_p99",
                              "completed", "active", "queue_ms_p50",
-                             "queue_ms_p90", "batch_occupancy_pct"}
+                             "queue_ms_p90", "batch_occupancy_pct",
+                             # + ISSUE 27's, beside the occupancy
+                             "decode_grid_share"}
 
     def test_per_class_latency_windows_are_bounded(self):
         """A server that runs for a day must not append for a day: the

@@ -193,8 +193,47 @@ def test_on_admit_queue_wait():
     p = st.percentiles()
     assert p["queue_ms_p50"] == st.queue_ms_p50 < 500.0
     st.on_decode_batch(3, 4)
-    st.on_decode_batch(1, 4)
+    assert "decode_grid_share" not in st.percentiles()
+    st.on_decode_batch(1, 4, grid_steps=6, table_entries=64)
     assert st.percentiles()["batch_occupancy_pct"] == 50.0
+    assert st.percentiles()["decode_grid_share"] == 0.0938
+
+
+@pytest.mark.parametrize("splitfuse_tokens", [0, 16])
+def test_decode_grid_counter(monkeypatch, splitfuse_tokens):
+    """``grid_steps`` / ``table_entries`` on every decode-bearing dispatch
+    span, and ``decode_grid_share`` of the telemetry, are the numpy
+    formula over the batches the engine dispatched: a live slot's blocks
+    up to its new token, each of the dispatch's steps one token on,
+    against steps x slots x table entries."""
+    router, engine = _router(splitfuse_tokens)
+    batches, stats = [], []
+    real_batch, real_span = engine.state_mgr.decode_batch, engine_v2.span
+
+    def recording_batch(*a, **kw):
+        batch = real_batch(*a, **kw)
+        if batch.active.any():
+            batches.append((batch.lengths.copy(), batch.active.copy()))
+        return batch
+
+    def recording_span(name, **st):
+        if name == "dstpu.engine.dispatch" and st["steps"]:
+            stats.append(st)
+        return real_span(name, **st)
+
+    monkeypatch.setattr(engine.state_mgr, "decode_batch", recording_batch)
+    monkeypatch.setattr(engine_v2, "span", recording_span)
+    _serve(router)
+    BS, MB, slots, steps = 8, 128 // 8, 4, 2
+    assert len(batches) == len(stats) > N_REQUESTS
+    want = [int(sum((lengths[active] + t) // BS + 1
+                    for t in range(steps)).sum())
+            for lengths, active in batches]
+    assert [st["grid_steps"] for st in stats] == want
+    assert {st["table_entries"] for st in stats} == {steps * slots * MB}
+    assert all(st["active"] * steps <= st["grid_steps"] for st in stats)
+    assert engine.telemetry_snapshot()["decode_grid_share"] == round(
+        sum(want) / (len(want) * steps * slots * MB), 4)
 
 
 def test_span_budget_without_capture(monkeypatch):

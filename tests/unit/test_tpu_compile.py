@@ -10,7 +10,9 @@ to a Mosaic custom call. Nothing executes, so nothing is said about
 results or speed. Skipped where the topology cannot be described.
 """
 
+import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,8 +56,9 @@ def v5e():
 def _compile(fn, shapes, sharding):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
             for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
 
 
 bf16, i32 = jnp.bfloat16, jnp.int32
@@ -76,6 +79,24 @@ def _paged_chunk(q, kc, vc, table, start, true_len):
                                  block_c=128, interpret=False)
 
 
+def _paged_decode(q, kc, vc, tb, ln):
+    return paged_decode_attention(q, kc, vc, tb, ln, interpret=False)
+
+
+def _paged_decode_shapes(b, kvh, hd, mb, nb):
+    return [((b, kvh, hd), bf16)] + [((nb, kvh, BS, hd), bf16)] * 2 \
+        + [((b, mb), i32), ((b,), i32)]
+
+
+# the decode kernel at the serving cells' shapes (slots, KV heads, head dim,
+# table entries, pool blocks): GPT-2 medium's, OPT-1.3B's 32 heads, OLMoE's
+# head dim 128 behind a 64-entry table
+PAGED_DECODE_CELLS = {
+    "gpt2m_chat": (32, 16, 64, 16, 320),
+    "opt1.3b_docs": (16, 32, 64, 32, 128),
+    "olmoe_chat": (32, 16, 128, 64, 512),
+}
+
 POOL = [((NB, H, BS, HD), bf16)] * 2
 CASES = {
     # training: the headline's whole-sequence tile, and the config default
@@ -87,10 +108,7 @@ CASES = {
         [((B * 512, D), bf16), ((V, D), bf16), ((B * 512,), i32)]),
     # serving: decode step, split-fuse chunk, whole-prompt prefill buckets
     # (the last not a multiple of the q tile)
-    "paged_decode": (
-        lambda q, kc, vc, tb, ln: paged_decode_attention(
-            q, kc, vc, tb, ln, interpret=False),
-        [((B, H, HD), bf16)] + POOL + [((B, MB), i32), ((B,), i32)]),
+    "paged_decode": (_paged_decode, _paged_decode_shapes(B, H, HD, MB, NB)),
     "paged_chunk_c256": (
         _paged_chunk,
         [((256, H, HD), bf16)] + POOL + [((MB,), i32), ((), i32), ((), i32)]),
@@ -108,6 +126,33 @@ CASES = {
 def test_kernel_compiles_for_v5e(v5e, name):
     fn, shapes = CASES[name]
     _compile(fn, shapes, v5e)
+
+
+@pytest.mark.parametrize("cell", sorted(PAGED_DECODE_CELLS))
+def test_paged_decode_is_the_custom_call_the_trace_reader_finds(v5e, cell):
+    """The decode kernel's grid length is a device scalar: it must still
+    lower to one Mosaic custom call whose output is (slots, KV heads, 1,
+    head dim) and whose first operands are the int32 scalars — what
+    ``perfbench/trace_names.json``'s ``paged_decode`` pattern looks for
+    in a trace, where the event is named by the instruction with its
+    operands' shapes."""
+    b, kvh, hd, mb, nb = PAGED_DECODE_CELLS[cell]
+    text = _compile(_paged_decode, _paged_decode_shapes(b, kvh, hd, mb, nb),
+                    v5e)
+    calls = [ln.strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(calls) == 1, calls
+    head = calls[0].split(" custom-call(")[0]
+    operands = calls[0].split("operand_layout_constraints={")[1]
+    operands = re.sub(r"\{[\d,]*\}", "", operands).split("}")[0]
+    assert head.split(" = ")[1].startswith(f"bf16[{b},{kvh},1,{hd}]")
+    assert operands.split(", ")[:3] == ["s32[]", f"s32[{b},{mb}]",
+                                        f"s32[{b}]"]
+    with open(os.path.join(os.path.dirname(__file__), "..", "..",
+                           "perfbench", "trace_names.json")) as f:
+        pattern = json.load(f)["kernels"]["paged_decode"]["pattern"]
+    assert re.search(pattern, f"{head} custom-call({operands}), "
+                     'custom_call_target="tpu_custom_call"')
 
 
 def test_vmem_refusal_is_what_a_bad_tile_looks_like(v5e):
