@@ -3,7 +3,7 @@
 CI exercises every Pallas kernel in interpreter mode (tests/conftest.py
 provisions a CPU mesh); this module is the real-Mosaic counterpart: tiny
 shapes, compiled for the actual TPU, asserted against the dense
-references — so every driver ``bench.py`` run also validates that
+references — so every ``chip_smoke.py`` run also validates that
 interpreter numerics and Mosaic numerics agree (a divergence would
 otherwise ship silently). The TPU substitute for the reference's
 per-kernel GPU CI (tests/unit/ops/).

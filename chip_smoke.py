@@ -197,8 +197,7 @@ def seeded_batch(size, rows):
 
 
 def compiled_step_hlo(engine, batch):
-    """HLO text of the train-step program the engine runs on ``batch``
-    (the benchmarks/hlo_dump.py recipe)."""
+    """HLO text of the train-step program the engine runs on ``batch``."""
     import jax
     batch = jax.tree.map(engine._add_gas_dim, batch)
     batch = engine._shard_batch(batch, with_gas_dim=True)

@@ -9,7 +9,7 @@ per (device_kind, op, shape-bucket, dtype).
 Timing method: the candidate step (fwd+bwd where the kernel is
 differentiable) is chained data-dependently through ``lax.scan`` inside
 ONE jit, at two chain lengths; the slope between them is the per-step
-time. Rationale (also benchmarks/kernel_microbench.py): a host dispatch
+time. Rationale: a host dispatch
 can cost as much as a kernel step, so anything not measured inside a
 single dispatch measures the dispatch. The slope additionally cancels
 jit constants and scan setup.

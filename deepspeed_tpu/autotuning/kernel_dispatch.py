@@ -206,9 +206,8 @@ def _search_and_store(op, bucket, dtype, defaults, dk, key):
 
 
 def table():
-    """The tuned table for the CURRENT device kind — what bench.py
-    embeds in the artifact so winners travel with the measurements.
-    Reads the cache FILE fresh: searches from earlier engines in this
+    """The tuned table for the CURRENT device kind, for an artifact to
+    embed so winners travel with the measurements. Reads the cache FILE fresh: searches from earlier engines in this
     process have persisted there, and the in-memory view may predate
     them."""
     return KernelCache.load(cache_path()).for_device(device_kind())

@@ -7,8 +7,8 @@ bucket (the strings built by ``ops/pallas/_common``):
                       falls back to on a cache miss, and the baseline
                       candidate every search times first
   ``candidates(b)``   the measured search space (curated, not a full
-                      grid: each candidate is a lever PERF_NOTES has
-                      named, so a search run doubles as a lever A/B)
+                      grid: each candidate is a lever an earlier
+                      round named, so a search run doubles as an A/B)
   ``make_step(b, dtype, params)``
                       -> (step_fn, args): a data-dependent train-shaped
                       step (forward AND backward where the kernel has

@@ -6,7 +6,7 @@ each experiment as its own ``deepspeed`` job, polling for completion and
 parsing metrics from the experiment directory. TPU translation: a slot is
 a host's worth of chips (JAX is one process per host), an experiment runs
 as a subprocess with the reservation exported through env, and results
-come back as one JSON line on stdout (the bench.py convention) or via an
+come back as one JSON line on stdout (the last one printed) or via an
 injectable runner — which is also what the tests fake.
 
 Capacity > 1 runs independent trials concurrently (grid/random search);
@@ -66,8 +66,8 @@ class Reservation:
 class SubprocessRunner:
     """Launch one experiment as ``python script --exp '<json>'`` on the
     reserved host (ssh for remote hosts, direct for local), parse the
-    LAST JSON line of stdout as the result (the bench.py convention;
-    reference scheduler parses the experiment dir instead)."""
+    LAST JSON line of stdout as the result (the reference scheduler
+    parses the experiment dir instead)."""
 
     def __init__(self, script, timeout_s=1800, python=None):
         self.script = script

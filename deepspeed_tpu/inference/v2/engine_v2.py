@@ -256,7 +256,7 @@ class InferenceEngineV2:
         # serving-side telemetry: TTFT/TPOT histograms exported through
         # the same MonitorMaster fan-out as training when ``monitor``
         # (a monitor.Monitor / MonitorMaster) is given; always readable
-        # via telemetry_snapshot() for serve_bench
+        # via telemetry_snapshot()
         self.telemetry = None
         if config.telemetry:
             from ...monitor.telemetry import ServingTelemetry
@@ -378,12 +378,14 @@ class InferenceEngineV2:
                 self.draft_params, self._draft_param_sh = shard_params(
                     draft_model, self.mesh, dtype, params=draft_params,
                     seed=config.seed + 1, topology=topology)
+                # now covers the draft, whose pools are sized by the
+                # kernel setting it carries
+                self._install_trace_state()
                 self.draft_cache, self._draft_cache_sh = \
                     self._new_paged_cache(draft_model, num_blocks)
                 self._propose_jit = None
                 self._verify_jit = None
                 self._draft_chunk_jit = None
-                self._install_trace_state()   # now covers the draft
 
         self._pending = deque()
         self._results = {}            # uid -> generated tokens (finished)
@@ -579,21 +581,19 @@ class InferenceEngineV2:
     def _new_paged_cache(self, model, num_blocks):
         """Allocate ``model``'s paged cache on this engine's mesh ->
         (cache, the shardings its programs declare for it). Where the
-        model's decode step will run the paged kernel (the question
-        ``apply_paged_decode`` asks at trace time, asked of the same
-        shapes; off-TPU the kernels are interpreted) the pools are
-        born in the shape that keeps them in the kernels' layout
-        (:func:`pool_block_dims`)."""
+        model's decode step will run the paged kernel
+        (``models/paged.uses_decode_kernel``, the question its trace
+        asks, of the same shapes; off-TPU the kernels are interpreted)
+        the pools are born in the shape that keeps them in the kernels'
+        layout (:func:`pool_block_dims`)."""
+        from ...models.paged import uses_decode_kernel
         from ...ops.pallas._common import interpret_default
-        from ...ops.pallas.paged_attention import resolve_paged_decode
         cfg = self.config
-        _, KVH, BS, hd = jax.eval_shape(lambda: model.init_paged_cache(
+        _, _, BS, hd = jax.eval_shape(lambda: model.init_paged_cache(
             1, cfg.kv_block_size, dtype=self.dtype))["k"][0].shape
-        kernel = not interpret_default() and (
-            getattr(model.config, "alibi", False) or resolve_paged_decode(
-                cfg.paged_kernel, cfg.max_batch_size,
-                self.max_blocks_per_seq, BS, KVH,
-                model.config.n_head // KVH, hd, self.dtype))
+        kernel = not interpret_default() and uses_decode_kernel(
+            model, cfg.max_batch_size, self.max_blocks_per_seq, BS,
+            self.dtype)
         dims = pool_block_dims(num_blocks, hd, kernel)
         # the model's own specs, behind the block axis's extra dimensions
         shardings = jax.tree.map(

@@ -262,16 +262,14 @@ def parse_args(argv=None):
         help="autotune the script's config before (run) or instead of "
              "(tune) launching it (reference launcher/runner.py:359 "
              "deepspeed --autotuning). The script must accept "
-             "--exp '<json>' and print one JSON result line — bench.py "
-             "does.")
+             "--exp '<json>' and print one JSON result line.")
     parser.add_argument(
         "--autotuning_space", default=None,
         help="JSON file {knob: [values...]}; default: micro-batch + "
-             "remat policy + flash block sizes for bench.py")
+             "remat policy + flash block sizes")
     parser.add_argument(
         "--autotuning_metric", default="value",
-        help="result-JSON key to maximize (bench.py: 'value' = "
-             "tokens/sec/chip)")
+        help="result-JSON key to maximize")
     parser.add_argument("--autotuning_trials", type=int, default=12)
     parser.add_argument("--autotuning_results",
                         default="autotuning_results")

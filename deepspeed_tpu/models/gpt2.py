@@ -33,6 +33,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..utils.groups import BATCH_AXES
+from . import paged
 from .common import (chunked_softmax_xent, constrain_fn, fused_linear_xent,
                      next_token_xent,
                      resolve_remat_policy)
@@ -935,15 +936,20 @@ class GPT2:
     # wq_matmul's fused epilogue)
     _WQ_KEEP = ("wup", "wdown")
 
-    def _layer_slice(self, params, i):
-        """Static per-layer view of the stacked block params (int8
-        serving weights dequantize here, one layer at a time; under the
-        fused weight-quant path the FFN weights stay quantized)."""
-        from ..ops.int8_weights import dequant_tree
-        sl = jax.tree.map(lambda a: a[i], params["blocks"])
-        keep = self._WQ_KEEP \
-            if getattr(self, "_weight_quant_fused", False) else ()
-        return dequant_tree(sl, _dtype(self.config), keep=keep)
+    def _paged_layers(self, params, x, step):
+        """The serving layer loop: every block through ``_block_core``
+        with the ``attn_fn`` the paged ``step`` (models/paged.py) hands
+        out for it. Python-unrolled: each layer's pools are buffers of
+        their own (see init_paged_cache), and ``_block_core`` dequantizes
+        int8 serving weights one layer's slice at a time. Returns
+        (x, cache)."""
+        ks_out, vs_out = [], []
+        for i in range(self.config.n_layer):
+            layer = jax.tree.map(lambda a: a[i], params["blocks"])
+            x, (kc, vc) = self._block_core(x, layer, step.layer(i))
+            ks_out.append(kc)
+            vs_out.append(vc)
+        return x, {"k": ks_out, "v": vs_out}
 
     def apply_paged_prefill(self, params, input_ids, cache, token_blocks,
                             token_offsets, length):
@@ -954,132 +960,33 @@ class GPT2:
         position (pads point at scratch block 0); length: scalar true
         prompt length. Returns (logits (1, V) at position length-1, cache).
 
-        The kernel path (engine ``paged_kernel``) runs the chunked
-        paged kernel with ``start=0`` over the prompt's own blocks
-        (table derived from the per-token destinations): causally-dead
-        and beyond-length blocks are skipped instead of masked after a
-        full (T, T) score matrix.
+        This is the chunk program at ``start = 0``: tokens are laid
+        contiguously from position 0, so position m*BS's destination
+        block IS table entry m (pads are scratch 0) and the prompt's
+        table is read off its per-token placement.
         """
-        cfg = self.config
-        dt = _dtype(cfg)
-        T = input_ids.shape[1]
-        hd = cfg.d_head
-        pos = jnp.arange(T)[None, :]
-        x = (params["wte"][input_ids] + params["wpe"][pos]).astype(dt)
-        valid = (jnp.arange(T) < length)
-        causal = jnp.tril(jnp.ones((T, T), jnp.bool_))
-        mask = causal & valid[None, :]
-        qp, kp = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
         BS = cache["k"][0].shape[2]
-        # every block the prompt touches, from its per-token placement
-        # (tokens are laid contiguously from position 0, so position
-        # m*BS's destination block IS table entry m; pads are scratch 0)
-        prefill_table = token_blocks[::BS]
-        from ..ops.pallas.paged_attention import (paged_chunk_attention,
-                                                  paged_kv_write,
-                                                  resolve_paged_chunk)
-        use_kernel, block_c = resolve_paged_chunk(
-            getattr(self, "_paged_kernel", "auto"),
-            getattr(self, "_paged_block_c", "auto"),
-            T, prefill_table.shape[0], BS, cfg.n_head, 1, hd, dt)
-
-        ks_out, vs_out = [], []
-        for i in range(cfg.n_layer):
-            layer = self._layer_slice(params, i)
-            kc0, vc0 = cache["k"][i], cache["v"][i]
-            w = cfg.attn_layer_windows[i] if cfg.attn_layer_windows else 0
-            m = mask & (qp - kp < w) if w else mask
-
-            def attn_fn(q, kk, v, kc0=kc0, vc0=vc0, m=m, w=w):
-                # in-place write into this layer's own donated pools
-                kc, vc = paged_kv_write(
-                    (kc0, vc0), (kk[0], v[0]), token_blocks,
-                    token_offsets, kernel=use_kernel)
-                if use_kernel:
-                    attn = paged_chunk_attention(
-                        q[0], kc, vc, prefill_table, jnp.int32(0),
-                        length, scale=None if cfg.scale_attn else 1.0,
-                        window=w, block_c=block_c)
-                    return attn[None], (kc, vc)
-                scores = jnp.einsum("bthd,bshd->bhts", q, kk,
-                                    preferred_element_type=jnp.float32)
-                if cfg.scale_attn:
-                    scores = scores / math.sqrt(hd)
-                scores = jnp.where(m[None, None], scores, -1e30)
-                probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-                return jnp.einsum("bhts,bshd->bthd", probs, v), (kc, vc)
-
-            x, (kc, vc) = self._block_core(x, layer, attn_fn)
-            ks_out.append(kc)
-            vs_out.append(vc)
-        last = jnp.take_along_axis(
-            x, jnp.maximum(length - 1, 0)[None, None, None], axis=1)
-        return self.head(params, last)[:, 0], {"k": ks_out, "v": vs_out}
+        return self.apply_paged_chunk(
+            params, input_ids, cache, token_blocks, token_offsets,
+            jnp.int32(0), length, token_blocks[::BS])
 
     def apply_paged_chunk(self, params, input_ids, cache, token_blocks,
                           token_offsets, start, true_len, table):
         """Prefill ONE CHUNK of one sequence into the paged cache (the
         Dynamic SplitFuse chunk program; see Llama.apply_paged_chunk —
-        same contract, GPT-2's learned positions and full-head cache).
-
-        On the kernel path (engine ``paged_kernel``; "auto" = the
-        autotune winner cache's choice, kernel on TPU / dense-gather
-        elsewhere on a cold cache) attention runs the Pallas
-        chunked-prefill paged kernel reading K/V straight through the
-        block table — the full (S, H, hd) gather never materializes."""
+        same contract, GPT-2's learned positions and full-head cache;
+        kernel or dense gather is models/paged.py ``chunk_step``'s)."""
         cfg = self.config
-        dt = _dtype(cfg)
         C = input_ids.shape[1]
-        H, hd = cfg.n_head, cfg.d_head
-        BS = cache["k"][0].shape[2]
         pos = jnp.minimum(start + jnp.arange(C), cfg.max_seq_len - 1)
         x = (params["wte"][input_ids]
-             + params["wpe"][pos][None]).astype(dt)
-        S = table.shape[0] * BS
-        q_pos = (start + jnp.arange(C))[:, None]
-        k_pos = jnp.arange(S)[None, :]
-        mask = (k_pos <= q_pos) & (k_pos < start + true_len)
-        from ..ops.pallas.paged_attention import (paged_chunk_attention,
-                                                  paged_kv_write,
-                                                  resolve_paged_chunk)
-        use_kernel, block_c = resolve_paged_chunk(
-            getattr(self, "_paged_kernel", "auto"),
-            getattr(self, "_paged_block_c", "auto"),
-            C, table.shape[0], BS, H, 1, hd, dt)
-
-        ks_out, vs_out = [], []
-        for i in range(cfg.n_layer):
-            layer = self._layer_slice(params, i)
-            kc0, vc0 = cache["k"][i], cache["v"][i]
-            w = cfg.attn_layer_windows[i] if cfg.attn_layer_windows else 0
-            m = mask & (q_pos - k_pos < w) if w else mask
-
-            def attn_fn(q, kk, v, kc0=kc0, vc0=vc0, m=m, w=w):
-                kc, vc = paged_kv_write(
-                    (kc0, vc0), (kk[0], v[0]), token_blocks,
-                    token_offsets, kernel=use_kernel)
-                if use_kernel:
-                    attn = paged_chunk_attention(
-                        q[0], kc, vc, table, start, true_len,
-                        scale=None if cfg.scale_attn else 1.0,
-                        window=w, block_c=block_c)
-                    return attn[None], (kc, vc)
-                gk = kc[table].transpose(0, 2, 1, 3).reshape(S, H, hd)
-                gv = vc[table].transpose(0, 2, 1, 3).reshape(S, H, hd)
-                scores = jnp.einsum("bthd,shd->bhts", q, gk,
-                                    preferred_element_type=jnp.float32)
-                if cfg.scale_attn:
-                    scores = scores / math.sqrt(hd)
-                scores = jnp.where(m[None, None], scores, -1e30)
-                probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-                return jnp.einsum("bhts,shd->bthd", probs, gv), (kc, vc)
-
-            x, (kc, vc) = self._block_core(x, layer, attn_fn)
-            ks_out.append(kc)
-            vs_out.append(vc)
+             + params["wpe"][pos][None]).astype(_dtype(cfg))
+        x, cache = self._paged_layers(params, x, paged.chunk_step(
+            paged.geometry(self), cache, token_blocks, token_offsets,
+            start, true_len, table))
         last = jnp.take_along_axis(
             x, jnp.maximum(true_len - 1, 0)[None, None, None], axis=1)
-        return self.head(params, last)[:, 0], {"k": ks_out, "v": vs_out}
+        return self.head(params, last)[:, 0], cache
 
     def apply_paged_decode(self, params, tokens, lengths, cache,
                            block_tables):
@@ -1090,62 +997,9 @@ class GPT2:
         (B, MB) int32 block ids (inactive slots point at scratch block 0).
         Returns (logits (B, V), cache).
         """
-        cfg = self.config
-        B = tokens.shape[0]
-        BS = cache["k"][0].shape[2]
-
-        pos = jnp.minimum(lengths, cfg.max_seq_len - 1)
-        x = (params["wte"][tokens[:, None]]
-             + params["wpe"][pos[:, None]]).astype(_dtype(cfg))
-        dst_block = jnp.take_along_axis(
-            block_tables, (lengths // BS)[:, None], axis=1)[:, 0]
-        dst_off = lengths % BS
-        from ..ops.pallas.paged_attention import (decode_work_list,
-                                                  paged_kv_write,
-                                                  resolve_paged_decode)
-        MB = block_tables.shape[1]
-        use_kernel = resolve_paged_decode(
-            getattr(self, "_paged_kernel", "auto"), B, MB, BS,
-            cfg.n_head, 1, cfg.d_head, _dtype(cfg))
-        # the kernel's grid: this step's live (slot, block) pairs, one
-        # list per window size, shared by every layer that has it
-        work = {w: decode_work_list(lengths, MB, BS, w,
-                                    active=block_tables[:, 0] != 0)
-                for w in set(cfg.attn_layer_windows or (0,))} \
-            if use_kernel else {}
-
-        ks_out, vs_out = [], []
-        for i in range(cfg.n_layer):
-            layer = self._layer_slice(params, i)
-            kc0, vc0 = cache["k"][i], cache["v"][i]
-            w = cfg.attn_layer_windows[i] if cfg.attn_layer_windows else 0
-
-            def attn_fn(q, kk, v, kc0=kc0, vc0=vc0, w=w):
-                # q/kk/v: (B, 1, H, hd) — the single new token per slot.
-                # In-place write into this layer's donated pool, then the
-                # Pallas paged kernel reads K/V straight through the block
-                # table (no dense gather; reference
-                # inference/v2/kernels/ragged_ops blocked_flash). The
-                # dense-gather reference stays behind paged_kernel=False
-                # as the parity/A-B fallback.
-                from ..ops.pallas.paged_attention import (
-                    paged_decode_attention,
-                    paged_decode_attention_reference)
-                kc, vc = paged_kv_write(
-                    (kc0, vc0), (kk[:, 0], v[:, 0]), dst_block, dst_off,
-                    kernel=use_kernel)
-                fn = partial(paged_decode_attention,
-                             work=work[w]) if use_kernel \
-                    else paged_decode_attention_reference
-                attn = fn(
-                    q[:, 0], kc, vc, block_tables, lengths,
-                    scale=None if cfg.scale_attn else 1.0, window=w)
-                return attn[:, None], (kc, vc)
-
-            x, (kc, vc) = self._block_core(x, layer, attn_fn)
-            ks_out.append(kc)
-            vs_out.append(vc)
-        return self.head(params, x)[:, 0], {"k": ks_out, "v": vs_out}
+        logits, cache = self.apply_paged_verify(
+            params, tokens[:, None], lengths, cache, block_tables)
+        return logits[:, 0], cache
 
     def apply_paged_verify(self, params, tokens, lengths, cache,
                            block_tables):
@@ -1159,80 +1013,22 @@ class GPT2:
         EVERY position, so the host can take the longest accepted
         prefix plus the bonus token.
 
-        This is the batched split-fuse ride: each slot's C-token span is
-        a chunk with ``start=lengths[b]``/``true_len=C`` through the
-        same ``paged_chunk_attention`` kernel the prefill chunks use;
-        the dense fallback is the batched gather the decode reference
-        uses, with a per-slot causal frontier. Writes beyond a slot's
-        committed frontier land in its already-allocated blocks and are
-        either committed (accepted) or harmlessly overwritten next step
-        (rejected) — callers guarantee every slot has k tokens of block
-        budget left (the engine never speculates inside the tail).
+        Each slot's C-token span is a chunk with ``start=lengths[b]``/
+        ``true_len=C`` (models/paged.py ``batch_step``; C = 1 is the
+        decode step). Writes beyond a slot's committed frontier land in
+        its already-allocated blocks and are either committed (accepted)
+        or harmlessly overwritten next step (rejected) — callers
+        guarantee every slot has k tokens of block budget left (the
+        engine never speculates inside the tail).
         """
         cfg = self.config
-        dt = _dtype(cfg)
-        B, C = tokens.shape
-        H, hd = cfg.n_head, cfg.d_head
-        BS = cache["k"][0].shape[2]
-        MB = block_tables.shape[1]
-        S = MB * BS
-
-        linpos = lengths[:, None] + jnp.arange(C)[None, :]       # (B, C)
-        pos = jnp.minimum(linpos, cfg.max_seq_len - 1)
-        x = (params["wte"][tokens] + params["wpe"][pos]).astype(dt)
-        dst_block = jnp.take_along_axis(
-            block_tables, jnp.minimum(linpos // BS, MB - 1), axis=1)
-        dst_off = linpos % BS
-        fb, fo = dst_block.reshape(-1), dst_off.reshape(-1)
-        q_pos = linpos[:, :, None]                            # (B, C, 1)
-        k_pos = jnp.arange(S)[None, None, :]                  # (1, 1, S)
-        mask = (k_pos <= q_pos) \
-            & (k_pos < (lengths + C)[:, None, None])
-        from ..ops.pallas.paged_attention import (paged_chunk_attention,
-                                                  paged_kv_write,
-                                                  resolve_paged_chunk)
-        use_kernel, block_c = resolve_paged_chunk(
-            getattr(self, "_paged_kernel", "auto"),
-            getattr(self, "_paged_block_c", "auto"),
-            C, MB, BS, H, 1, hd, dt)
-
-        ks_out, vs_out = [], []
-        for i in range(cfg.n_layer):
-            layer = self._layer_slice(params, i)
-            kc0, vc0 = cache["k"][i], cache["v"][i]
-            w = cfg.attn_layer_windows[i] if cfg.attn_layer_windows else 0
-            m = mask & (q_pos - k_pos < w) if w else mask
-
-            def attn_fn(q, kk, v, kc0=kc0, vc0=vc0, m=m, w=w):
-                kc, vc = paged_kv_write(
-                    (kc0, vc0), (kk.reshape(B * C, H, hd),
-                                 v.reshape(B * C, H, hd)),
-                    fb, fo, kernel=use_kernel)
-                if use_kernel:
-                    attn = jnp.stack([
-                        paged_chunk_attention(
-                            q[b], kc, vc, block_tables[b], lengths[b],
-                            jnp.int32(C),
-                            scale=None if cfg.scale_attn else 1.0,
-                            window=w, block_c=block_c)
-                        for b in range(B)])
-                    return attn, (kc, vc)
-                gk = kc[block_tables].transpose(0, 1, 3, 2, 4) \
-                    .reshape(B, S, H, hd)
-                gv = vc[block_tables].transpose(0, 1, 3, 2, 4) \
-                    .reshape(B, S, H, hd)
-                scores = jnp.einsum("bthd,bshd->bhts", q, gk,
-                                    preferred_element_type=jnp.float32)
-                if cfg.scale_attn:
-                    scores = scores / math.sqrt(hd)
-                scores = jnp.where(m[:, None], scores, -1e30)
-                probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-                return jnp.einsum("bhts,bshd->bthd", probs, gv), (kc, vc)
-
-            x, (kc, vc) = self._block_core(x, layer, attn_fn)
-            ks_out.append(kc)
-            vs_out.append(vc)
-        return self.head(params, x), {"k": ks_out, "v": vs_out}
+        C = tokens.shape[1]
+        pos = jnp.minimum(lengths[:, None] + jnp.arange(C)[None, :],
+                          cfg.max_seq_len - 1)
+        x = (params["wte"][tokens] + params["wpe"][pos]).astype(_dtype(cfg))
+        x, cache = self._paged_layers(params, x, paged.batch_step(
+            paged.geometry(self), cache, lengths, block_tables, C))
+        return self.head(params, x), cache
 
     # --- loss ---
     def loss(self, params, batch, *, rng=None, train=True, seq_sharded=False,

@@ -23,6 +23,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..utils.groups import BATCH_AXES
+from . import paged
 from .common import (chunked_softmax_xent, constrain_fn, fused_linear_xent,
                      next_token_xent)
 
@@ -529,11 +530,7 @@ class Llama:
         T = input_ids.shape[1]
         constrain = self._constrain_fn()
         act_spec = P(BATCH_AXES, "seq" if seq_sharded else None, None)
-        x = params["wte"][input_ids].astype(jnp.dtype(cfg.dtype))
-        if cfg.embed_norm:
-            x = _layer_norm(x, params["embed_ln_s"], params["embed_ln_b"],
-                            cfg.rms_eps)
-        x = constrain(x, act_spec)
+        x = constrain(self._embed(params, input_ids), act_spec)
         pos = jnp.broadcast_to(jnp.arange(T)[None, :], input_ids.shape)
         causal = jnp.tril(jnp.ones((T, T), jnp.bool_))
         causal = self._window_mask(causal, jnp.arange(T)[:, None],
@@ -606,57 +603,70 @@ class Llama:
         spec = P(None, batch_axes, None, "tensor", None)
         return {"k": spec, "v": spec}
 
+    def _block_core(self, x, layer, pos, attn_fn):
+        """Shared block scaffolding for every cache-backed inference
+        path: q/k/v projection -> rotary at ``pos`` -> ``attn_fn`` ->
+        output projection -> residual (or falcon/phi's parallel block)
+        -> MLP. ``attn_fn((B,T,H,hd) q, (B,T,KVH,hd) k, v) -> (attn
+        (B,T,H,hd), carry)`` owns masking and any cache reads/writes.
+        Returns (x_out, carry)."""
+        cfg = self.config
+        B, T = x.shape[0], x.shape[1]
+        q, kk, v = self._attn_proj(x, layer)
+        # self._rope honors rotary_pct (phi partial rotary) — the
+        # module-level _rope would silently diverge decode from
+        # training for those families
+        q = self._rope(q, pos)
+        kk = self._rope(kk, pos)
+        attn, carry = attn_fn(q, kk, v)
+        attn_out = self._wo(attn.reshape(B, T, cfg.n_head * cfg.d_head),
+                            layer)
+        if cfg.parallel_block:
+            # falcon/phi: attention and MLP branch from the same input
+            x = x + attn_out + self._mlp(x, layer)
+        else:
+            x = x + attn_out
+            x = x + self._mlp(x, layer)
+        return x, carry
+
     def apply_cached(self, params, input_ids, pos_ids, cache, slot,
                      valid_mask, last_token_only=False):
         """Same contract as GPT2.apply_cached; KV cache stores KV heads
         only (GQA)."""
         cfg = self.config
         dt = jnp.dtype(cfg.dtype)
-        B, T = input_ids.shape
+        T = input_ids.shape[1]
         H, KVH, hd = cfg.n_head, cfg.n_kv_heads, cfg.d_head
-        x = params["wte"][input_ids].astype(dt)
-        if cfg.embed_norm:
-            x = _layer_norm(x, params["embed_ln_s"], params["embed_ln_b"],
-                            cfg.rms_eps)
+        x = self._embed(params, input_ids)
         Tmax = cache["k"].shape[2]
 
-        def body(carry, xs):
-            layer, kc, vc = xs
+        def body(x, xs):
+            layer, kc0, vc0 = xs
             from ..ops.int8_weights import dequant_tree
-            layer = dequant_tree(layer, dt)
-            x = carry
-            q, kk, v = self._attn_proj(x, layer)
-            # self._rope honors rotary_pct (phi partial rotary) — the
-            # module-level _rope would silently diverge v1 decode from
-            # training/prefill/v2 for those families
-            q = self._rope(q, pos_ids)
-            kk = self._rope(kk, pos_ids)
-            kc = lax.dynamic_update_slice(kc, kk.astype(kc.dtype),
-                                          (0, slot, 0, 0))
-            vc = lax.dynamic_update_slice(vc, v.astype(vc.dtype),
-                                          (0, slot, 0, 0))
-            ku = _repeat_kv(kc, H // KVH)
-            vu = _repeat_kv(vc, H // KVH)
-            scores = jnp.einsum("bthd,bshd->bhts", q, ku,
-                                preferred_element_type=jnp.float32)
-            scores = scores / math.sqrt(hd)
-            s_idx = jnp.arange(Tmax)[None, None, None, :]
-            q_idx = (slot + jnp.arange(T))[None, None, :, None]
-            mask = (s_idx <= q_idx) & valid_mask[:, None, None, :]
-            mask = self._window_mask(mask, q_idx, s_idx)
-            if cfg.alibi:
-                scores = scores + self._alibi_bias(
-                    jnp.arange(Tmax))[None, :, None, :]
-            scores = jnp.where(mask, scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-            attn = jnp.einsum("bhts,bshd->bthd", probs, vu)
-            attn_out = self._wo(attn.reshape(B, T, H * hd), layer)
-            if cfg.parallel_block:
-                x = x + attn_out + self._mlp(x, layer)
-            else:
-                x = x + attn_out
-                x = x + self._mlp(x, layer)
-            return x, (kc, vc)
+
+            def attn_fn(q, kk, v):
+                kc = lax.dynamic_update_slice(kc0, kk.astype(kc0.dtype),
+                                              (0, slot, 0, 0))
+                vc = lax.dynamic_update_slice(vc0, v.astype(vc0.dtype),
+                                              (0, slot, 0, 0))
+                ku = _repeat_kv(kc, H // KVH)
+                vu = _repeat_kv(vc, H // KVH)
+                scores = jnp.einsum("bthd,bshd->bhts", q, ku,
+                                    preferred_element_type=jnp.float32)
+                scores = scores / math.sqrt(hd)
+                s_idx = jnp.arange(Tmax)[None, None, None, :]
+                q_idx = (slot + jnp.arange(T))[None, None, :, None]
+                mask = (s_idx <= q_idx) & valid_mask[:, None, None, :]
+                mask = self._window_mask(mask, q_idx, s_idx)
+                if cfg.alibi:
+                    scores = scores + self._alibi_bias(
+                        jnp.arange(Tmax))[None, :, None, :]
+                scores = jnp.where(mask, scores, -1e30)
+                probs = jax.nn.softmax(scores, axis=-1).astype(dt)
+                return jnp.einsum("bhts,bshd->bthd", probs, vu), (kc, vc)
+
+            return self._block_core(x, dequant_tree(layer, dt), pos_ids,
+                                    attn_fn)
 
         if self._served(params):
             # the inference engines' tree: per-layer lists cannot scan,
@@ -748,75 +758,33 @@ class Llama:
             if getattr(self, "_weight_quant_fused", False) else ()
         return dequant_tree(sl, jnp.dtype(self.config.dtype), keep=keep)
 
-    def apply_paged_prefill(self, params, input_ids, cache, token_blocks,
-                            token_offsets, length):
-        cfg = self.config
-        dt = jnp.dtype(cfg.dtype)
-        T = input_ids.shape[1]
-        H, KVH, hd = cfg.n_head, cfg.n_kv_heads, cfg.d_head
-        x = params["wte"][input_ids].astype(dt)
-        if cfg.embed_norm:
+    def _embed(self, params, ids):
+        x = params["wte"][ids].astype(jnp.dtype(self.config.dtype))
+        if self.config.embed_norm:
             x = _layer_norm(x, params["embed_ln_s"], params["embed_ln_b"],
-                            cfg.rms_eps)
-        pos = jnp.arange(T)[None, :]
-        valid = (jnp.arange(T) < length)
-        mask = jnp.tril(jnp.ones((T, T), jnp.bool_)) & valid[None, :]
-        mask = self._window_mask(mask, jnp.arange(T)[:, None],
-                                 jnp.arange(T)[None, :])
-        BS = cache["k"][0].shape[2]
-        prefill_table = token_blocks[::BS]   # see GPT2.apply_paged_prefill
-        from ..ops.pallas.paged_attention import (paged_chunk_attention,
-                                                  paged_kv_write,
-                                                  resolve_paged_chunk)
-        # ALiBi stays dense: the chunk kernel has no per-head bias
-        # input (forced-off BEFORE dispatch, so no search is paid for
-        # a kernel tile the model can never use)
-        use_kernel, block_c = resolve_paged_chunk(
-            False if cfg.alibi else getattr(self, "_paged_kernel",
-                                            "auto"),
-            getattr(self, "_paged_block_c", "auto"),
-            T, prefill_table.shape[0], BS, KVH, H // KVH, hd, dt)
+                            self.config.rms_eps)
+        return x
 
+    def _paged_layers(self, params, x, pos, step):
+        """The serving layer loop (see GPT2._paged_layers); a served
+        tree's per-layer experts stay operands of the program.
+        Returns (x, cache)."""
         ks_out, vs_out = [], []
-        for i in range(cfg.n_layer):
-            layer = self._layer_slice(params, i)
-            kc0, vc0 = cache["k"][i], cache["v"][i]
-            q, kk, v = self._attn_proj(x, layer)
-            q = self._rope(q, pos)
-            kk = self._rope(kk, pos)
-            # in-place write into this layer's own donated pools
-            kc, vc = paged_kv_write(
-                (kc0, vc0), (kk[0], v[0]), token_blocks, token_offsets,
-                kernel=use_kernel)
-            if use_kernel:
-                # GQA-native blocked stream over the prompt's own
-                # blocks (no repeat_kv, no (T, T) full-score pass)
-                attn = paged_chunk_attention(
-                    q[0], kc, vc, prefill_table, jnp.int32(0), length,
-                    window=cfg.sliding_window, block_c=block_c)[None]
-            else:
-                ku = _repeat_kv(kk, H // KVH)
-                vu = _repeat_kv(v, H // KVH)
-                scores = jnp.einsum("bthd,bshd->bhts", q, ku,
-                                    preferred_element_type=jnp.float32)
-                scores = scores / math.sqrt(hd)
-                if cfg.alibi:
-                    scores = scores + self._alibi_bias(
-                        jnp.arange(T))[None, :, None, :]
-                scores = jnp.where(mask[None, None], scores, -1e30)
-                probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-                attn = jnp.einsum("bhts,bshd->bthd", probs, vu)
-            attn_out = self._wo(attn.reshape(1, T, H * hd), layer)
-            if cfg.parallel_block:
-                x = x + attn_out + self._mlp(x, layer)
-            else:
-                x = x + attn_out
-                x = x + self._mlp(x, layer)
+        for i in range(self.config.n_layer):
+            x, (kc, vc) = self._block_core(
+                x, self._layer_slice(params, i), pos, step.layer(i))
             ks_out.append(kc)
             vs_out.append(vc)
-        last = jnp.take_along_axis(
-            x, jnp.maximum(length - 1, 0)[None, None, None], axis=1)
-        return self.head(params, last)[:, 0], {"k": ks_out, "v": vs_out}
+        return x, {"k": ks_out, "v": vs_out}
+
+    def apply_paged_prefill(self, params, input_ids, cache, token_blocks,
+                            token_offsets, length):
+        """Prefill ONE sequence into the paged cache: the chunk program
+        at ``start = 0`` (see GPT2.apply_paged_prefill)."""
+        BS = cache["k"][0].shape[2]
+        return self.apply_paged_chunk(
+            params, input_ids, cache, token_blocks, token_offsets,
+            jnp.int32(0), length, token_blocks[::BS])
 
     def apply_paged_chunk(self, params, input_ids, cache, token_blocks,
                           token_offsets, start, true_len, table):
@@ -832,149 +800,25 @@ class Llama:
         tokens in the chunk; table: (MB,) the sequence's full block
         table (scratch-padded). Queries attend the sequence's PRIOR
         cache plus the in-chunk causal prefix — K/V are scattered first,
-        then gathered back through the table, so the attention sees one
+        then read back through the table, so the attention sees one
         contiguous [0, start + true_len) key range.
         Returns (logits (1, V) at chunk position true_len - 1, cache).
         """
-        cfg = self.config
-        dt = jnp.dtype(cfg.dtype)
-        C = input_ids.shape[1]
-        H, KVH, hd = cfg.n_head, cfg.n_kv_heads, cfg.d_head
-        BS = cache["k"][0].shape[2]
-        x = params["wte"][input_ids].astype(dt)
-        if cfg.embed_norm:
-            x = _layer_norm(x, params["embed_ln_s"], params["embed_ln_b"],
-                            cfg.rms_eps)
-        pos = start + jnp.arange(C)[None, :]
-        S = table.shape[0] * BS
-        q_pos = (start + jnp.arange(C))[:, None]       # (C, 1)
-        k_pos = jnp.arange(S)[None, :]                 # (1, S)
-        mask = (k_pos <= q_pos) & (k_pos < start + true_len)
-        mask = self._window_mask(mask, q_pos, k_pos)
-        from ..ops.pallas.paged_attention import (paged_chunk_attention,
-                                                  paged_kv_write,
-                                                  resolve_paged_chunk)
-        use_kernel, block_c = resolve_paged_chunk(
-            False if cfg.alibi else getattr(self, "_paged_kernel",
-                                            "auto"),   # no bias input
-            getattr(self, "_paged_block_c", "auto"),
-            C, table.shape[0], BS, KVH, H // KVH, hd, dt)
-
-        ks_out, vs_out = [], []
-        for i in range(cfg.n_layer):
-            layer = self._layer_slice(params, i)
-            kc0, vc0 = cache["k"][i], cache["v"][i]
-            q, kk, v = self._attn_proj(x, layer)
-            q = self._rope(q, pos)
-            kk = self._rope(kk, pos)
-            kc, vc = paged_kv_write(
-                (kc0, vc0), (kk[0], v[0]), token_blocks, token_offsets,
-                kernel=use_kernel)
-            if use_kernel:
-                # blocked-flash chunk kernel: each KV block streams
-                # through VMEM once, located via the block table; the
-                # (S, H, hd) gather + repeat_kv copies never exist
-                attn = paged_chunk_attention(
-                    q[0], kc, vc, table, start, true_len,
-                    window=cfg.sliding_window, block_c=block_c)[None]
-            else:
-                # gather the sequence's full K/V range through its
-                # table: (MB, KVH, BS, hd) -> (S, KVH, hd); in-cache
-                # layout is heads-major, so one transpose per row
-                gk = kc[table].transpose(0, 2, 1, 3).reshape(S, KVH, hd)
-                gv = vc[table].transpose(0, 2, 1, 3).reshape(S, KVH, hd)
-                gk = _repeat_kv(gk[None], H // KVH)[0]
-                gv = _repeat_kv(gv[None], H // KVH)[0]
-                scores = jnp.einsum("bthd,shd->bhts", q, gk,
-                                    preferred_element_type=jnp.float32)
-                scores = scores / math.sqrt(hd)
-                if cfg.alibi:
-                    scores = scores + self._alibi_bias(
-                        jnp.arange(S))[None, :, None, :]
-                scores = jnp.where(mask[None, None], scores, -1e30)
-                probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-                attn = jnp.einsum("bhts,shd->bthd", probs, gv)
-            attn_out = self._wo(attn.reshape(1, C, H * hd), layer)
-            if cfg.parallel_block:
-                x = x + attn_out + self._mlp(x, layer)
-            else:
-                x = x + attn_out
-                x = x + self._mlp(x, layer)
-            ks_out.append(kc)
-            vs_out.append(vc)
+        pos = start + jnp.arange(input_ids.shape[1])[None, :]
+        x, cache = self._paged_layers(
+            params, self._embed(params, input_ids), pos, paged.chunk_step(
+                paged.geometry(self), cache, token_blocks, token_offsets,
+                start, true_len, table))
         last = jnp.take_along_axis(
             x, jnp.maximum(true_len - 1, 0)[None, None, None], axis=1)
-        return self.head(params, last)[:, 0], {"k": ks_out, "v": vs_out}
+        return self.head(params, last)[:, 0], cache
 
     def apply_paged_decode(self, params, tokens, lengths, cache,
                            block_tables):
-        cfg = self.config
-        dt = jnp.dtype(cfg.dtype)
-        B = tokens.shape[0]
-        H, hd = cfg.n_head, cfg.d_head
-        BS = cache["k"][0].shape[2]
-        pos = jnp.minimum(lengths, cfg.max_seq_len - 1)
-        x = params["wte"][tokens[:, None]].astype(dt)
-        if cfg.embed_norm:
-            x = _layer_norm(x, params["embed_ln_s"], params["embed_ln_b"],
-                            cfg.rms_eps)
-        dst_block = jnp.take_along_axis(
-            block_tables, (lengths // BS)[:, None], axis=1)[:, 0]
-        dst_off = lengths % BS
-        from ..ops.pallas.paged_attention import (decode_work_list,
-                                                  paged_kv_write,
-                                                  resolve_paged_decode)
-        MB = block_tables.shape[1]
-        # ALiBi families keep the kernel regardless of the mode switch
-        # (the dense fallback lacks the falcon bf16-quantized variant)
-        use_kernel = cfg.alibi or resolve_paged_decode(
-            getattr(self, "_paged_kernel", "auto"), tokens.shape[0],
-            MB, BS, cfg.n_kv_heads, H // cfg.n_kv_heads, hd, dt)
-        # the kernel's grid: this step's live (slot, block) pairs, made
-        # once and shared by every layer
-        work = decode_work_list(
-            lengths, MB, BS, cfg.sliding_window,
-            active=block_tables[:, 0] != 0) if use_kernel else None
-
-        ks_out, vs_out = [], []
-        for i in range(cfg.n_layer):
-            layer = self._layer_slice(params, i)
-            kc0, vc0 = cache["k"][i], cache["v"][i]
-            q, kk, v = self._attn_proj(x, layer)       # (B, 1, ., hd)
-            q = self._rope(q, pos[:, None])
-            kk = self._rope(kk, pos[:, None])
-            kc, vc = paged_kv_write(
-                (kc0, vc0), (kk[:, 0], v[:, 0]), dst_block, dst_off,
-                kernel=use_kernel)
-            # Pallas paged kernel: GQA-native (no repeat_kv copies), K/V
-            # read straight through the block table (reference
-            # inference/v2/kernels/ragged_ops blocked_flash); dense
-            # gather behind paged_kernel=False as the parity fallback
-            from ..ops.pallas.paged_attention import (
-                alibi_slopes, paged_decode_attention,
-                paged_decode_attention_reference)
-            if use_kernel:
-                attn = paged_decode_attention(
-                    q[:, 0], kc, vc, block_tables, lengths, work=work,
-                    window=cfg.sliding_window,
-                    alibi_slopes=(alibi_slopes(H) if cfg.alibi
-                                  else None),
-                    alibi_scale=(1.0 / math.sqrt(hd)
-                                 if cfg.alibi_inv_norm else 1.0),
-                    alibi_bf16=cfg.alibi_inv_norm)
-            else:
-                attn = paged_decode_attention_reference(
-                    q[:, 0], kc, vc, block_tables, lengths,
-                    window=cfg.sliding_window)
-            attn_out = self._wo(attn.reshape(B, 1, H * hd), layer)
-            if cfg.parallel_block:
-                x = x + attn_out + self._mlp(x, layer)
-            else:
-                x = x + attn_out
-                x = x + self._mlp(x, layer)
-            ks_out.append(kc)
-            vs_out.append(vc)
-        return self.head(params, x)[:, 0], {"k": ks_out, "v": vs_out}
+        """One decode step: the verify program at C = 1."""
+        logits, cache = self.apply_paged_verify(
+            params, tokens[:, None], lengths, cache, block_tables)
+        return logits[:, 0], cache
 
     def apply_paged_verify(self, params, tokens, lengths, cache,
                            block_tables):
@@ -985,77 +829,10 @@ class Llama:
 
         tokens: (B, C); lengths: (B,) = first input token's position;
         block_tables: (B, MB). Returns (logits (B, C, V), cache)."""
-        cfg = self.config
-        dt = jnp.dtype(cfg.dtype)
-        B, C = tokens.shape
-        H, KVH, hd = cfg.n_head, cfg.n_kv_heads, cfg.d_head
-        BS = cache["k"][0].shape[2]
-        MB = block_tables.shape[1]
-        S = MB * BS
-        linpos = lengths[:, None] + jnp.arange(C)[None, :]       # (B, C)
-        pos = jnp.minimum(linpos, cfg.max_seq_len - 1)
-        x = params["wte"][tokens].astype(dt)
-        if cfg.embed_norm:
-            x = _layer_norm(x, params["embed_ln_s"], params["embed_ln_b"],
-                            cfg.rms_eps)
-        dst_block = jnp.take_along_axis(
-            block_tables, jnp.minimum(linpos // BS, MB - 1), axis=1)
-        dst_off = linpos % BS
-        fb, fo = dst_block.reshape(-1), dst_off.reshape(-1)
-        q_pos = linpos[:, :, None]                            # (B, C, 1)
-        k_pos = jnp.arange(S)[None, None, :]                  # (1, 1, S)
-        mask = (k_pos <= q_pos) \
-            & (k_pos < (lengths + C)[:, None, None])
-        mask = self._window_mask(mask, q_pos, k_pos)
-        from ..ops.pallas.paged_attention import (paged_chunk_attention,
-                                                  paged_kv_write,
-                                                  resolve_paged_chunk)
-        use_kernel, block_c = resolve_paged_chunk(
-            False if cfg.alibi else getattr(self, "_paged_kernel",
-                                            "auto"),   # no bias input
-            getattr(self, "_paged_block_c", "auto"),
-            C, MB, BS, KVH, H // KVH, hd, dt)
-
-        ks_out, vs_out = [], []
-        for i in range(cfg.n_layer):
-            layer = self._layer_slice(params, i)
-            kc0, vc0 = cache["k"][i], cache["v"][i]
-            q, kk, v = self._attn_proj(x, layer)       # (B, C, ., hd)
-            q = self._rope(q, pos)
-            kk = self._rope(kk, pos)
-            kc, vc = paged_kv_write(
-                (kc0, vc0), (kk.reshape(B * C, KVH, hd),
-                             v.reshape(B * C, KVH, hd)),
-                fb, fo, kernel=use_kernel)
-            if use_kernel:
-                attn = jnp.stack([
-                    paged_chunk_attention(
-                        q[b], kc, vc, block_tables[b], lengths[b],
-                        jnp.int32(C), window=cfg.sliding_window,
-                        block_c=block_c)
-                    for b in range(B)])
-            else:
-                gk = kc[block_tables].transpose(0, 1, 3, 2, 4) \
-                    .reshape(B, S, KVH, hd)
-                gv = vc[block_tables].transpose(0, 1, 3, 2, 4) \
-                    .reshape(B, S, KVH, hd)
-                gk = _repeat_kv(gk, H // KVH)
-                gv = _repeat_kv(gv, H // KVH)
-                scores = jnp.einsum("bthd,bshd->bhts", q, gk,
-                                    preferred_element_type=jnp.float32)
-                scores = scores / math.sqrt(hd)
-                if cfg.alibi:
-                    scores = scores + self._alibi_bias(
-                        jnp.arange(S))[None, :, None, :]
-                scores = jnp.where(mask[:, None], scores, -1e30)
-                probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-                attn = jnp.einsum("bhts,bshd->bthd", probs, gv)
-            attn_out = self._wo(attn.reshape(B, C, H * hd), layer)
-            if cfg.parallel_block:
-                x = x + attn_out + self._mlp(x, layer)
-            else:
-                x = x + attn_out
-                x = x + self._mlp(x, layer)
-            ks_out.append(kc)
-            vs_out.append(vc)
-        return self.head(params, x), {"k": ks_out, "v": vs_out}
+        C = tokens.shape[1]
+        pos = jnp.minimum(lengths[:, None] + jnp.arange(C)[None, :],
+                          self.config.max_seq_len - 1)
+        x, cache = self._paged_layers(
+            params, self._embed(params, tokens), pos, paged.batch_step(
+                paged.geometry(self), cache, lengths, block_tables, C))
+        return self.head(params, x), cache

@@ -82,8 +82,8 @@ def peak_flops_per_chip(device_kind):
 
 
 def percentile(samples, p):
-    """Guarded percentile: None on an empty window (serve_bench _pct
-    discipline — never a NaN in an artifact)."""
+    """Guarded percentile: None on an empty window (never a NaN in an
+    artifact)."""
     if not samples:
         return None
     return float(np.percentile(np.asarray(samples, np.float64), p))

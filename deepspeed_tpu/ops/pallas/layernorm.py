@@ -232,11 +232,9 @@ def _rms_fwd_kernel(x_ref, s_ref, y_ref, *, eps):
 def fused_rmsnorm(x, scale, *, eps=1e-5, block_rows=256, interpret=None):
     """One-pass RMSNorm Pallas kernel over the last dim (the serving
     models' norm; reference csrc/transformer/inference/csrc/rms_norm.cu).
-    Forward-only: the jnp-vs-Pallas decision for the v1 serving tier is
-    measured by benchmarks/kernel_microbench.py and recorded in
-    PERF_NOTES — like fused_layernorm, XLA's fused jnp form wins inside
-    real programs on v5e, so models default to jnp and this kernel
-    documents the measured alternative."""
+    Forward-only: like fused_layernorm, XLA's fused jnp form won inside
+    real programs on v5e when both were timed, so models default to jnp
+    and this kernel documents the measured alternative."""
     if interpret is None:
         interpret = _interpret_default()
     D = x.shape[-1]

@@ -11,9 +11,10 @@ the reconcile CLI share. The pipeline:
      holds the leaf op events (Steps/Modules tracks are whole-step
      envelopes that would double count). On CPU backends there is no
      device track; the XLA CPU client's thunk-executor threads
-     (``tf_XLATfrtCpuClient/*``) carry the op events instead, so they
-     serve as a fallback (``cpu_fallback=True`` in the result) with an
-     HLO-op-name filter that drops the runtime scaffolding frames.
+     (``tf_XLATfrtCpuClient/*``; ``tf_XLAPjRtCpuClient/*`` from jax 0.9)
+     carry the op events instead, so they serve as a fallback
+     (``cpu_fallback=True`` in the result) with an HLO-op-name filter
+     that drops the runtime scaffolding frames.
   2. **Self time** — per track, an event's duration minus its nested
      children (the trace_summary stack walk), so envelopes never double
      count their contents.
@@ -44,9 +45,8 @@ The decomposition's ``terms`` keys are exactly
 from silently diverging.
 
 JSON schema: :meth:`StepDecomposition.to_dict` is versioned
-(:data:`SCHEMA_VERSION`); consumers (``extras.reconcile`` in
-``BENCH_local.json``, the flight recorder, the CLI ``--json`` outputs)
-key off the field names below, so additions bump the version.
+(:data:`SCHEMA_VERSION`); consumers (the flight recorder, the CLI
+``--json`` outputs) key off the field names below, so additions bump the version.
 """
 
 import collections
@@ -179,7 +179,7 @@ def _op_tracks(pid_names, tid_names):
         labels = sorted({pid_names[p] for p in dev_pids})
         return op_tids, labels, False
     op_tids = {k for k, n in tid_names.items()
-               if "XLATfrtCpuClient" in n}
+               if "XLATfrtCpuClient" in n or "XLAPjRtCpuClient" in n}
     labels = sorted({pid_names.get(k[0], "?") for k in op_tids})
     return op_tids, labels, bool(op_tids)
 

@@ -18,8 +18,7 @@ here the SAME schedule is obtained declaratively, in three layers:
    gating are platform-dependent (`overlap_env_var`): ``--xla_tpu_*``
    flags live only in libtpu's own flag registry — host-side
    ``XLA_FLAGS`` parsing FATALs on them (and on any name outside the
-   DebugOptions proto) — so the TPU set rides ``LIBTPU_INIT_ARGS`` (the
-   channel bench.py already uses for ``xla_tpu_scoped_vmem_limit_kib``)
+   DebugOptions proto) — so the TPU set rides ``LIBTPU_INIT_ARGS``
    while the GPU set, whose names are proto-resident, rides
    ``XLA_FLAGS``. Off TPU/GPU no flags are emitted at all.
 

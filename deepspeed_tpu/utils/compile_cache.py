@@ -1,7 +1,7 @@
 """JAX persistent compilation cache, placeable from outside.
 
-Runnable entry points (``chip_smoke.py``, ``bench.py``, the ``benchmarks/``
-scripts) call :func:`enable_compile_cache` before their first compile.
+Runnable entry points (``chip_smoke.py``, ``perfbench/run.py``, the
+``benchmarks/`` scripts) call :func:`enable_compile_cache` before their first compile.
 Nothing in the package calls it — not at import, not from an engine
 constructor — so library users and the test suite keep JAX's own default
 (no persistent cache).

@@ -1,0 +1,145 @@
+"""The paged serving forward's seam (tier-1): ``models/paged.py`` is the
+one place under ``models/`` that knows how K/V rows reach the pool and
+which implementation reads them back; bucketed prefill is the chunk
+program at ``start = 0``; and the engine sizes the pools by the same
+answer the decode trace takes."""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.autotuning import kernel_dispatch
+from deepspeed_tpu.inference.v2 import InferenceEngineV2, engine_v2
+from deepspeed_tpu.models import GPT2, GPT2Config, paged
+from deepspeed_tpu.models.bloom import BLOOM_TINY, Bloom
+from deepspeed_tpu.models.llama import LLAMA_TINY, Llama
+from deepspeed_tpu.models.mixtral import MIXTRAL_TINY, Mixtral
+from deepspeed_tpu.ops.pallas import _common as pallas_common
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+GPT2_TINY = GPT2Config(n_layer=2, n_head=4, d_model=64, max_seq_len=128,
+                       vocab_size=256, remat=False, dtype="float32")
+MODELS = {"gpt2": lambda: GPT2(GPT2_TINY),
+          "llama_gqa": lambda: Llama(LLAMA_TINY),
+          "mixtral": lambda: Mixtral(MIXTRAL_TINY),
+          "bloom_alibi": lambda: Bloom(BLOOM_TINY)}
+
+
+@pytest.fixture(autouse=True)
+def _pristine_dispatch(tmp_path, monkeypatch):
+    """Private winner cache + reset process-global dispatch state."""
+    monkeypatch.setenv("DSTPU_AUTOTUNE_CACHE",
+                       str(tmp_path / "kernel_autotune.json"))
+    monkeypatch.delenv("DSTPU_AUTOTUNE", raising=False)
+    kernel_dispatch.reset()
+    yield
+    kernel_dispatch.reset()
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+@pytest.mark.parametrize("family", ["gpt2", "llama_gqa", "mixtral"])
+def test_prefill_is_the_chunk_program_at_start_zero(family, kernel):
+    """``apply_paged_prefill`` == ``apply_paged_chunk`` at ``start = 0``,
+    ``true_len = length``, ``table = token_blocks[::BS]``: logits and
+    pools bit for bit (scratch block 0 takes the pads' rows in no
+    order, so it is left out)."""
+    T, length, BS, NB = 64, 37, 16, 9
+    model = MODELS[family]()
+    model._paged_kernel, model._paged_block_c = kernel, 16
+    params = model.init(jax.random.key(0))
+    ids = jax.random.randint(jax.random.key(1), (1, T), 0,
+                             model.config.vocab_size, jnp.int32)
+    real = np.arange(T) < length
+    # the sequence owns blocks 3, 5, 2; pads aim at scratch block 0
+    tb = jnp.asarray(np.where(real, np.array([3, 5, 2, 7])[
+        np.arange(T) // BS], 0), jnp.int32)
+    to = jnp.asarray(np.where(real, np.arange(T) % BS, 0), jnp.int32)
+    cache = model.init_paged_cache(NB, BS)
+    n = jnp.int32(length)
+    lp, cp = jax.jit(model.apply_paged_prefill)(params, ids, cache, tb, to,
+                                                n)
+    lc, cc = jax.jit(model.apply_paged_chunk)(params, ids, cache, tb, to,
+                                              jnp.int32(0), n, tb[::BS])
+    np.testing.assert_array_equal(np.asarray(lp), np.asarray(lc))
+    assert np.isfinite(np.asarray(lp, np.float32)).all()
+    for a, b in zip(jax.tree.leaves(cp), jax.tree.leaves(cc)):
+        np.testing.assert_array_equal(np.asarray(a[1:]), np.asarray(b[1:]))
+    # and the prompt's rows did land in its own blocks
+    assert float(jnp.abs(cp["k"][0][3].astype(jnp.float32)).sum()) > 0
+
+
+_SEAM_NAMES = re.compile(
+    r"paged_kv_write|resolve_paged_|decode_work_list"
+    r"|paged_decode_attention|paged_chunk_attention")
+
+
+def test_only_the_seam_names_the_paged_kernels():
+    """Source lint: under ``deepspeed_tpu/models/`` only ``paged.py``
+    names the pool write, the ``resolve_*`` questions, the decode work
+    list or the paged attention calls — and it does name them all."""
+    pkg = os.path.join(REPO, "deepspeed_tpu", "models")
+    naming = {}
+    for dirpath, _, names in os.walk(pkg):
+        for n in names:
+            if n.endswith(".py"):
+                with open(os.path.join(dirpath, n), encoding="utf-8") as f:
+                    found = set(_SEAM_NAMES.findall(f.read()))
+                if found:
+                    naming[os.path.relpath(os.path.join(dirpath, n),
+                                           pkg)] = found
+    assert set(naming) == {"paged.py"}, naming
+    assert naming["paged.py"] == {
+        "paged_kv_write", "resolve_paged_", "decode_work_list",
+        "paged_decode_attention", "paged_chunk_attention"}
+
+
+@pytest.mark.parametrize("family,setting,expect", [
+    ("bloom_alibi", False, True),      # ALiBi keeps the kernel
+    ("bloom_alibi", "auto", True),
+    ("llama_gqa", False, False),
+    ("llama_gqa", True, True),
+    ("gpt2", "auto", True),            # cold cache: the decode default
+])
+def test_pools_are_sized_by_the_decode_traces_answer(
+        monkeypatch, family, setting, expect):
+    """``uses_decode_kernel`` is what ``_new_paged_cache`` hands
+    ``pool_block_dims`` (off the interpreter), and what the decode trace
+    does: kernel call or dense reference."""
+    model = MODELS[family]()
+    engine = InferenceEngineV2(model, config=dict(
+        max_batch_size=4, kv_block_size=16, num_kv_blocks=9,
+        paged_kernel=setting, prompt_bucket=16))
+    B, BS, MB = 4, 16, engine.max_blocks_per_seq
+    assert paged.uses_decode_kernel(model, B, MB, BS,
+                                    engine.dtype) is expect
+
+    asked = []
+    with monkeypatch.context() as m:
+        m.setattr(pallas_common, "interpret_default", lambda: False)
+        m.setattr(
+            engine_v2, "pool_block_dims",
+            lambda n, hd, kernel_layout: asked.append(kernel_layout)
+            or (n,))
+        engine._new_paged_cache(model, 9)
+    assert asked == [expect]
+
+    ran = []
+    for name in ("paged_decode_attention",
+                 "paged_decode_attention_reference"):
+        real = getattr(paged, name)
+        monkeypatch.setattr(
+            paged, name, lambda *a, _real=real, _name=name, **kw:
+            ran.append(_name) or _real(*a, **kw))
+    i32 = jnp.int32
+    jax.eval_shape(
+        model.apply_paged_decode, engine.params,
+        jax.ShapeDtypeStruct((B,), i32), jax.ShapeDtypeStruct((B,), i32),
+        model.init_paged_cache(9, BS, dtype=engine.dtype),
+        jax.ShapeDtypeStruct((B, MB), i32))
+    kernel_ran = "paged_decode_attention" in ran
+    assert kernel_ran is expect and len(set(ran)) == 1

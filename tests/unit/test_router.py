@@ -349,7 +349,7 @@ class TestRouterOverload:
         assert admitted is not None
         # within noise of uncontended (generous CI bound: the shed
         # class never dispatched, so the admitted class saw an idle
-        # engine; SERVE_local rows carry the measured comparison)
+        # engine)
         assert admitted <= max(10 * baseline, baseline + 500), \
             f"admitted-class p99 TPOT {admitted} vs baseline {baseline}"
         assert snap["replicas"]["r0"] == "live"

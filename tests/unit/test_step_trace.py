@@ -232,9 +232,11 @@ class TestKernels:
 
 # ---------------------------------------------------------- track selection
 class TestTracks:
-    def test_cpu_client_fallback_filters_runtime_frames(self):
+    @pytest.mark.parametrize("thread_name", [
+        "tf_XLATfrtCpuClient/5", "tf_XLAPjRtCpuClient/5"])   # jax 0.9's
+    def test_cpu_client_fallback_filters_runtime_frames(self, thread_name):
         events = [
-            proc(2, "/host:CPU"), thread(2, 20, "tf_XLATfrtCpuClient/5"),
+            proc(2, "/host:CPU"), thread(2, 20, thread_name),
             ev("dot.3", 0, 50, pid=2, tid=20),
             ev("TfrtCpuExecutable::Execute", 0, 500, pid=2, tid=20),
             ev("ParseArguments", 60, 10, pid=2, tid=20),
