@@ -35,8 +35,8 @@ from ...utils.groups import TopologyConfig
 from ...utils.logging import log_dist
 from ...monitor.telemetry import span
 from ...ops.pallas.paged_attention import (as_pools, decode_grid_steps,
-                                              like_boundary,
-                                              pool_block_dims)
+                                           kv_write_live_rows,
+                                           like_boundary, pool_block_dims)
 from ..utils import shard_params
 from .ragged import DSStateManager, RaggedBatchWrapper
 
@@ -1069,17 +1069,22 @@ class InferenceEngineV2:
             self.telemetry.on_handoff_out(uid)
 
     def _dispatch_span(self, kind, active, steps, chunk_tokens=0,
-                       lengths=None):
+                       chunk_rows=0, batch=None):
         """The ``dstpu.engine.dispatch`` span of one program call, opened
         once the batch is assembled (its stats are fixed here); a
         decode-bearing dispatch also feeds the occupancy counter.
-        ``active``: a count, or — with ``lengths``, where the dispatch
-        runs the paged-decode kernel over the batch — the live slots'
-        mask, and the span then says how much of the block table the
-        kernel's grid walks (host arithmetic, no device read)."""
+        ``active``: a count, or — with ``batch`` = (lengths, block
+        tables), where the dispatch runs the paged-decode kernel over
+        the batch — the live slots' mask, and the span then says how
+        much of the block table the decode kernel's grid walks and how
+        many of the rows offered to the KV write are live; the chunk's
+        ``chunk_tokens`` of ``chunk_rows`` count with them (host
+        arithmetic, no device read)."""
         slots = self.config.max_batch_size
         grid_steps = table_entries = 0
-        if lengths is not None:
+        write_rows, write_rows_offered = chunk_tokens, chunk_rows
+        if batch is not None:
+            lengths, tables = batch
             cfg = self.model.config
             MB, BS = self.max_blocks_per_seq, self.state_mgr.block_size
             windows = getattr(cfg, "attn_layer_windows", ()) \
@@ -1090,13 +1095,19 @@ class InferenceEngineV2:
                 lengths, active, MB, BS, w, steps) for w in windows)
                 / len(windows))
             table_entries = steps * slots * MB
+            write_rows += kv_write_live_rows(lengths, tables, BS, steps)
+            write_rows_offered += steps * slots
         active = int(np.sum(active))
-        if steps and self.telemetry is not None:
-            self.telemetry.on_decode_batch(active, slots, grid_steps,
-                                           table_entries)
+        if self.telemetry is not None:
+            if steps:
+                self.telemetry.on_decode_batch(active, slots, grid_steps,
+                                               table_entries)
+            self.telemetry.on_kv_write(write_rows, write_rows_offered)
         return span("dstpu.engine.dispatch", kind=kind, active=active,
                     slots=slots, steps=steps, chunk_tokens=chunk_tokens,
-                    grid_steps=grid_steps, table_entries=table_entries)
+                    grid_steps=grid_steps, table_entries=table_entries,
+                    write_rows=write_rows,
+                    write_rows_offered=write_rows_offered)
 
     def _step_splitfuse_chunk(self):
         """Run one fused dispatch: the next chunk of the oldest
@@ -1150,9 +1161,9 @@ class InferenceEngineV2:
             dispatch = self._dispatch_span(
                 "fused", batch.active,
                 max(1, self.config.decode_steps_per_dispatch), true_len,
-                lengths=batch.lengths)
+                C, batch=(batch.lengths, batch.block_tables))
         else:
-            dispatch = self._dispatch_span("chunk", 0, 0, true_len)
+            dispatch = self._dispatch_span("chunk", 0, 0, true_len, C)
         with dispatch:
             with span("dstpu.engine.fetch"):
                 with jax.set_mesh(self.mesh):
@@ -1340,7 +1351,7 @@ class InferenceEngineV2:
                     tables[s] = pool.translate(batch.block_tables[s])
                 self._rng, sub = jax.random.split(self._rng)
             with self._dispatch_span("offload", sub_active, n,
-                                     lengths=lengths):
+                                     batch=(lengths, tables)):
                 with span("dstpu.engine.fetch"):
                     with jax.set_mesh(self.mesh):
                         toks, self.cache = fn(
@@ -1426,7 +1437,7 @@ class InferenceEngineV2:
         with self._dispatch_span(
                 "decode", batch.active,
                 max(1, self.config.decode_steps_per_dispatch),
-                lengths=batch.lengths):
+                batch=(batch.lengths, batch.block_tables)):
             with span("dstpu.engine.fetch"):
                 with jax.set_mesh(self.mesh):
                     toks, self.cache = fn(self.params, self.cache,

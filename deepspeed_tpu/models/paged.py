@@ -22,9 +22,10 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.pallas.paged_attention import (
-    alibi_slopes, decode_work_list, paged_chunk_attention,
-    paged_decode_attention, paged_decode_attention_reference,
-    paged_kv_write, resolve_paged_chunk, resolve_paged_decode)
+    alibi_slopes, decode_work_list, kv_write_row_list,
+    paged_chunk_attention, paged_decode_attention,
+    paged_decode_attention_reference, paged_kv_write, resolve_paged_chunk,
+    resolve_paged_decode)
 
 
 @dataclass(frozen=True)
@@ -126,13 +127,18 @@ def _dense_attention(geom, q, gk, gv, q_pos, frontier, window):
 class _Step:
     """One program's attention: ``layer(i)`` is layer i's ``attn_fn``.
     Row n of the flattened new K/V goes to ``blocks[n]``, ``offsets[n]``
-    (pads and inactive slots aim at scratch block 0), in place in the
-    layer's own donated pools; then ``attend`` reads through the table."""
+    (pads and inactive slots aim at scratch block 0: dead rows, which the
+    write kernel steps over), in place in the layer's own donated pools;
+    then ``attend`` reads through the table."""
 
     def __init__(self, geom, cache, blocks, offsets, use_kernel, attend):
         self.windows, self.cache, self.attend = geom.windows, cache, attend
         self.blocks, self.offsets = blocks, offsets
         self.use_kernel = use_kernel
+        # the write kernel's grid: this step's live rows, one list shared
+        # by every layer
+        self.rows = kv_write_row_list(blocks, offsets) if use_kernel \
+            else None
 
     def layer(self, i):
         pools = (self.cache["k"][i], self.cache["v"][i])
@@ -141,7 +147,8 @@ class _Step:
             kc, vc = paged_kv_write(
                 pools, (k.reshape((-1,) + k.shape[2:]),
                         v.reshape((-1,) + v.shape[2:])),
-                self.blocks, self.offsets, kernel=self.use_kernel)
+                self.blocks, self.offsets, rows=self.rows,
+                kernel=self.use_kernel)
             return self.attend(q, kc, vc, self.windows[i]), (kc, vc)
 
         return attn_fn

@@ -237,13 +237,18 @@ SPAN_SCHEMA = {
                    "call, blocking read of its token"},
     "dstpu.engine.dispatch": {
         "stats": ("kind", "active", "slots", "steps", "chunk_tokens",
-                  "grid_steps", "table_entries"),
+                  "grid_steps", "table_entries", "write_rows",
+                  "write_rows_offered"),
         "meaning": "one decode-bearing or chunk program call (kind "
                    "decode | fused | chunk | spec | offload) from the "
                    "assembled batch to the last posted token; grid_steps "
                    "of table_entries = how much of the block table one "
                    "paged-decode kernel call walks, over the dispatch's "
-                   "decode steps"},
+                   "decode steps; write_rows of write_rows_offered = the "
+                   "live rows (destination not scratch block 0) among "
+                   "those one layer's KV writes are handed: slots x "
+                   "steps, and a chunk's chunk_tokens of its C (0 / 0 on "
+                   "a spec round)"},
     "dstpu.engine.build": {
         "stats": (),
         "meaning": "leaf: host work before a program call (decode "

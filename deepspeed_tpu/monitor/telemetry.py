@@ -827,6 +827,10 @@ class ServingTelemetry:
         # entries, summed over the same dispatches
         self._grid_steps = 0
         self._table_entries = 0
+        # live rows against the rows offered to the paged KV write, summed
+        # over every dispatch (chunk-only ones too)
+        self._write_rows = 0
+        self._write_rows_offered = 0
         self.completed = 0
         self.rejected = 0
         self.active = 0
@@ -891,6 +895,14 @@ class ServingTelemetry:
         self._occ_slots += slots
         self._grid_steps += grid_steps
         self._table_entries += table_entries
+
+    def on_kv_write(self, live, offered):
+        """One dispatch offered ``offered`` rows to the paged KV write
+        (slots x decode steps, and a chunk's C), ``live`` of them aimed
+        at a block other than scratch: the grid steps the write kernel
+        takes against those its one-step-a-row grid took."""
+        self._write_rows += live
+        self._write_rows_offered += offered
 
     def on_token(self, uid):
         """First token => TTFT sample; later tokens accumulate for the
@@ -1035,6 +1047,9 @@ class ServingTelemetry:
         if self._table_entries:
             out["decode_grid_share"] = round(
                 self._grid_steps / self._table_entries, 4)
+        if self._write_rows_offered:
+            out["kv_write_live_share"] = round(
+                self._write_rows / self._write_rows_offered, 4)
         if self.rejected:
             # only present once a cancel/shed happened: router-off
             # engine snapshots stay byte-identical to pre-router runs

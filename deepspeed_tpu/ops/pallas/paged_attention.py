@@ -31,8 +31,10 @@ inclusive, matching the dense path's semantics).
 
 The pools stay in that layout from allocation to the last dispatch: new
 rows go in through :func:`paged_kv_write` (an aliased Pallas write, not an
-XLA scatter with a layout of its own), and at program boundaries the
-block axis takes the shape :func:`pool_block_dims` gives it.
+XLA scatter with a layout of its own; its grid is the rows that go
+somewhere, :func:`kv_write_row_list`, for the decode grid's reason: PERF.md,
+PR 29), and at program boundaries the block axis takes the shape
+:func:`pool_block_dims` gives it.
 """
 
 import functools
@@ -387,17 +389,66 @@ def paged_decode_attention_reference(q, k_cache, v_cache, block_tables,
 # ------------------------------------------------------- new-token write
 
 
-def _kv_write_kernel(blk_ref, off_ref, kn_ref, vn_ref, kp_ref, vp_ref,
-                     ko_ref, vo_ref, *, R):
-    """Grid step n puts row n of the new K/V into the R-row tile of the
-    pools that holds (blk[n], off[n]). Consecutive rows of one tile keep
-    the output block resident (Pallas fetches and writes back a block
-    only when its index changes), so the tile is loaded from the pool on
-    its first row only and the rows accumulate in VMEM."""
-    n = pl.program_id(0)
-    blk, off = blk_ref[n], off_ref[n]
-    prev = jnp.maximum(n - 1, 0)
-    fresh = (n == 0) | (blk != blk_ref[prev]) \
+def kv_write_row_list(blocks, offsets):
+    """The write kernel's grid, as data: the rows of the new K/V that go
+    somewhere, and where.
+
+    blocks / offsets: (N,) int32 destinations. A row aimed at scratch
+    block 0 is dead (a pad of a prefill or chunk, an inactive decode
+    slot, an inactive slot's verify span): nothing attends block 0, so
+    the row needs no write. Returns ``(row_of, block_of, offset_of,
+    n_live)``: three ``int32[N + 1]`` arrays, the live rows' indices and
+    destinations in their order, all 0 from item ``n_live`` on (the
+    pipeline may look one item ahead), and the live rows' count, on the
+    device.
+
+    Compares and sums over (items, rows), as :func:`decode_work_list`
+    and for its reason; the destinations are compacted here, once, so a
+    grid step reads its block id with one scalar load (looked up through
+    ``row_of`` in every index map a step cost 26 ns more, 9 % of a full
+    chunk's write: PERF.md, PR 29). Compute it once a program step and
+    hand it to every layer's :func:`paged_kv_write`."""
+    N = blocks.shape[0]
+    live = blocks != 0
+    ends = jnp.cumsum(live, dtype=jnp.int32)             # (N,) running count
+    item = jnp.arange(N + 1, dtype=jnp.int32)
+    # live item i is the live row whose running count is i + 1: at most
+    # one row an item, none from item n_live on
+    mine = live[None, :] & (ends[None, :] == item[:, None] + 1)
+
+    def of(x):
+        return jnp.sum(jnp.where(mine, x.astype(jnp.int32)[None, :], 0),
+                       axis=1, dtype=jnp.int32)
+
+    return of(item[:N]), of(blocks), of(offsets), ends[-1]
+
+
+def kv_write_live_rows(lengths, block_tables, BS, steps=1):
+    """The ``n_live`` of :func:`kv_write_row_list` for a decode batch, on
+    the host in numpy and summed over ``steps`` consecutive decode steps
+    (every slot's length grows by one a step; a verify span is ``steps``
+    positions of one step): the rows whose table entry names a block
+    other than scratch, as ``models/paged.py`` ``batch_step`` aims them.
+    What the engine's telemetry sets against ``steps * B`` rows."""
+    tables = np.asarray(block_tables)
+    pos = np.asarray(lengths)[:, None] + np.arange(steps)
+    return int(np.count_nonzero(np.take_along_axis(
+        tables, np.minimum(pos // BS, tables.shape[1] - 1), axis=1)))
+
+
+def _kv_write_kernel(row_ref, blk_ref, off_ref, kn_ref, vn_ref, kp_ref,
+                     vp_ref, ko_ref, vo_ref, *, R):
+    """Grid step i puts the i-th live row of the new K/V (row
+    ``row_ref[i]``) into the R-row tile of the pools that holds
+    (blk[i], off[i]); a dead row (one aimed at scratch block 0) is in no
+    step, and block 0 is not written. Consecutive live rows of one tile
+    keep the output block resident (Pallas fetches and writes back a
+    block only when its index changes), so the tile is loaded from the
+    pool on its first row only and the rows accumulate in VMEM."""
+    i = pl.program_id(0)
+    blk, off = blk_ref[i], off_ref[i]
+    prev = jnp.maximum(i - 1, 0)
+    fresh = (i == 0) | (blk != blk_ref[prev]) \
         | (off // R != off_ref[prev] // R)
 
     @pl.when(fresh)
@@ -411,7 +462,8 @@ def _kv_write_kernel(blk_ref, off_ref, kn_ref, vn_ref, kp_ref, vp_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _kv_write_call(blocks, offsets, kn, vn, kp, vp, *, interpret):
+def _kv_write_call(row_of, block_of, offset_of, n_live, kn, vn, kp, vp, *,
+                   interpret):
     """The aliased write as one jitted callable: the serving programs
     unroll layers x steps in Python, and a jitted callee is traced and
     lowered once per program, not once per call site."""
@@ -423,17 +475,17 @@ def _kv_write_call(blocks, offsets, kn, vn, kp, vp, *, interpret):
     if BS % R:
         R = BS
 
-    def tile(n, blk, off):
-        return (blk[n], 0, off[n] // R, 0)
+    def tile(i, row, blk, off):
+        return (blk[i], 0, off[i] // R, 0)
 
-    def row(n, blk, off):
-        return (n, 0, 0, 0)
+    def new_row(i, row, blk, off):
+        return (row[i], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(N,),
-        in_specs=[pl.BlockSpec((1, KVH, 1, d), row),
-                  pl.BlockSpec((1, KVH, 1, d), row),
+        num_scalar_prefetch=3,
+        grid=(n_live,),
+        in_specs=[pl.BlockSpec((1, KVH, 1, d), new_row),
+                  pl.BlockSpec((1, KVH, 1, d), new_row),
                   pl.BlockSpec((1, KVH, R, d), tile),
                   pl.BlockSpec((1, KVH, R, d), tile)],
         out_specs=[pl.BlockSpec((1, KVH, R, d), tile),
@@ -444,14 +496,15 @@ def _kv_write_call(blocks, offsets, kn, vn, kp, vp, *, interpret):
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(kp.shape, kp.dtype),
                    jax.ShapeDtypeStruct(vp.shape, vp.dtype)],
-        # operands 0/1 are the scalar-prefetched ids; the pools are 4/5
-        input_output_aliases={4: 0, 5: 1},
+        # operands 0-2 are the scalar-prefetched integers; the pools
+        # are 5/6
+        input_output_aliases={5: 0, 6: 1},
         interpret=interpret,
-    )(blocks, offsets, kn.reshape(N, KVH, 1, d), vn.reshape(N, KVH, 1, d),
-      kp, vp))
+    )(row_of, block_of, offset_of, kn.reshape(N, KVH, 1, d),
+      vn.reshape(N, KVH, 1, d), kp, vp))
 
 
-def paged_kv_write(pools, new, blocks, offsets, *, kernel=True,
+def paged_kv_write(pools, new, blocks, offsets, *, rows=None, kernel=True,
                    interpret=None):
     """Write the new tokens' K and V rows into the paged pools:
     ``pool[blocks[n], :, offsets[n]] = new[n]`` for both pools at once.
@@ -459,20 +512,27 @@ def paged_kv_write(pools, new, blocks, offsets, *, kernel=True,
     pools: (k_pool, v_pool), each (NB, KVH, BS, hd); new: (k, v), each
     (N, KVH, hd) — one row per decode slot, or the C rows of a prefill /
     chunk / verify span; blocks/offsets: (N,) int32 destination block
-    and in-block slot (pads and inactive slots aim at scratch block 0,
-    whose contents are never attended). Returns the updated pools.
+    and in-block slot. A row aimed at scratch block 0 is **dead**: a pad,
+    an inactive slot, an inactive slot's verify span; block 0's contents
+    are never attended. ``rows``: the step's
+    :func:`kv_write_row_list` of these destinations (made here when not
+    given; a model makes it once for all its layers). Returns the
+    updated pools.
 
     Where the paged attention runs as a kernel (``kernel``, as the
     caller resolved it, on a TPU) this is one ``pallas_call`` that
     aliases both pools and rewrites only the sublane tile holding each
-    destination row, found through the scalar-prefetched block ids: the
-    pools stay in the row-major layout the paged kernels read, where
-    the XLA scatter asks for a layout of its own and costs two
-    whole-pool copies a call. Elsewhere it is that scatter: the same
-    values. Rows aimed at one tile must be consecutive (they are: a
-    sequence's positions are, and live sequences never share a
-    destination block); only scratch block 0 is hit out of order, and a
-    lost row there is as good as any other."""
+    live destination row, found through the scalar-prefetched block
+    ids: the pools stay in the row-major layout the paged kernels read,
+    where the XLA scatter asks for a layout of its own and costs two
+    whole-pool copies a call. Its grid is the live rows (its length a
+    device scalar, as the decode kernel's): a dead row takes no grid
+    step, and **the kernel no longer writes block 0** — with no live
+    row both pools come back untouched. Elsewhere it is that scatter
+    (which does write block 0): the same values wherever anything
+    reads. Live rows aimed at one tile must be consecutive among the
+    live rows (they are: a sequence's positions are, and live sequences
+    never share a destination block)."""
     (kp, vp), (kn, vn) = pools, new
     kn, vn = kn.astype(kp.dtype), vn.astype(vp.dtype)
     if interpret is None:
@@ -481,9 +541,9 @@ def paged_kv_write(pools, new, blocks, offsets, *, kernel=True,
     if not kernel:
         return (kp.at[blocks, :, offsets].set(kn),
                 vp.at[blocks, :, offsets].set(vn))
-    return _kv_write_call(blocks.astype(jnp.int32),
-                          offsets.astype(jnp.int32), kn, vn, kp, vp,
-                          interpret=bool(interpret))
+    if rows is None:
+        rows = kv_write_row_list(blocks, offsets)
+    return _kv_write_call(*rows, kn, vn, kp, vp, interpret=bool(interpret))
 
 
 # ------------------------------------------------ the pools' layout

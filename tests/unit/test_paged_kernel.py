@@ -186,6 +186,130 @@ class TestKVWriteParity:
                                       np.asarray(p[1:]).view(np.uint16))
 
 
+def _interleaved_rows(rs, NB, BS):
+    """Decode: live and dead slots alternate, so every live row's
+    predecessor in the old grid was a dead one."""
+    N = 32
+    blocks = rs.permutation(np.arange(1, NB))[:N].astype(np.int32)
+    offsets = rs.randint(0, BS, (N,)).astype(np.int32)
+    blocks[::2], offsets[::2] = 0, 0
+    return blocks, offsets
+
+
+def _all_dead_rows(rs, NB, BS):
+    """A decode step (or a warm-up) with no live slot: ``n_live == 0``."""
+    return np.zeros((32,), np.int32), np.zeros((32,), np.int32)
+
+
+def _prefill_rows(rs, NB, BS):
+    """A prompt of 75 tokens in a bucket of 128: the padded tail is
+    dead, the live rows a prefix."""
+    T, n = 128, 75
+    table = rs.permutation(np.arange(1, NB))[:-(-T // BS)]
+    real = np.arange(T) < n
+    return (np.where(real, table[np.arange(T) // BS], 0).astype(np.int32),
+            np.where(real, np.arange(T) % BS, 0).astype(np.int32))
+
+
+def _neighbour_rows(rs, NB, BS):
+    """Consecutive live rows sharing a tile (offsets 2, 3), in two tiles
+    of one block (offsets R - 1, R, for R = 16 or BS), and a lone row,
+    with dead rows between the groups and at both ends."""
+    half = min(16, BS) - 1
+    blocks = np.asarray([0, 7, 7, 0, 0, 3, 3, 0, 5, 0], np.int32)
+    offsets = np.asarray([0, 2, 3, 0, 0, half, (half + 1) % BS, 0,
+                          BS - 1, 0], np.int32)
+    blocks[6] = 3 if half + 1 < BS else 4
+    return blocks, offsets
+
+
+def _verify_rows(rs, NB, BS):
+    """A verify span, C = 4 positions a slot for 8 slots, slots 1, 2, 5
+    and 7 inactive (all-scratch tables); slot 3's span crosses a block
+    boundary."""
+    B, C, MB = 8, 4, 3
+    tables = rs.permutation(np.arange(1, NB))[:B * MB].reshape(B, MB)
+    tables[[1, 2, 5, 7]] = 0
+    lengths = rs.randint(0, (MB - 1) * BS, (B,))
+    lengths[3] = BS - 2
+    pos = lengths[:, None] + np.arange(C)
+    blocks = np.take_along_axis(tables, pos // BS, axis=1)
+    return (blocks.reshape(-1).astype(np.int32),
+            np.where(blocks != 0, pos % BS, 0).reshape(-1).astype(np.int32))
+
+
+class TestKVWriteLiveRows:
+    """The write kernel's grid is the live rows (``blocks != 0``), in
+    interpret mode: the scatter's values wherever a live row lands, and
+    every other bit of both pools — scratch block 0 too, which the
+    kernel no longer writes — as it came in."""
+
+    CASES = {"interleaved": _interleaved_rows, "all_dead": _all_dead_rows,
+             "prefill_tail": _prefill_rows, "neighbours": _neighbour_rows,
+             "verify_span": _verify_rows, "decode": _decode_rows,
+             "chunk": _chunk_rows}
+
+    @pytest.mark.parametrize("BS,dtype", [(64, "bfloat16"), (8, "bfloat16"),
+                                          (16, "float32")],
+                             ids=["bs64-bf16", "bs8-bf16-wholeblock",
+                                  "bs16-f32"])
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_live_rows_only(self, case, BS, dtype):
+        from deepspeed_tpu.ops.pallas.paged_attention import (
+            kv_write_row_list)
+        NB, KVH, d = 40, 4, 64
+        blocks, offsets = self.CASES[case](np.random.RandomState(BS), NB,
+                                           BS)
+        offsets = offsets % BS       # ``_decode_rows`` pins 15 and 16
+        live = np.flatnonzero(blocks)
+        ks = jax.random.split(jax.random.key(BS), 4)
+        pools = tuple(jax.random.normal(k, (NB, KVH, BS, d), dtype)
+                      for k in ks[:2])
+        new = tuple(jax.random.normal(k, (len(blocks), KVH, d),
+                                      jnp.float32) for k in ks[2:])
+        *lists, n_live = kv_write_row_list(jnp.asarray(blocks),
+                                           jnp.asarray(offsets))
+        assert int(n_live) == len(live)
+        for got, src in zip(lists, (np.arange(len(blocks)), blocks,
+                                    offsets)):
+            assert got.shape == (len(blocks) + 1,)
+            np.testing.assert_array_equal(np.asarray(got)[:len(live)],
+                                          src[live])
+            assert not np.asarray(got)[len(live):].any()
+        want = paged_kv_write(pools, new, blocks, offsets, kernel=False)
+        got = jax.jit(functools.partial(paged_kv_write, interpret=True))(
+            pools, new, jnp.asarray(blocks), jnp.asarray(offsets))
+        bits = {"bfloat16": np.uint16, "float32": np.uint32}[dtype]
+        for g, w, p in zip(got, want, pools):
+            assert g.dtype == w.dtype == jnp.dtype(dtype)
+            g, w, p = (np.asarray(a).view(bits) for a in (g, w, p))
+            np.testing.assert_array_equal(g[1:], w[1:])
+            np.testing.assert_array_equal(g[0], p[0])
+            assert (len(live) == 0) == np.array_equal(w[1:], p[1:])
+
+    @pytest.mark.parametrize("steps", [1, 4, 8])
+    def test_host_count_is_the_lists(self, steps):
+        """``kv_write_live_rows`` (numpy, the telemetry's) counts what
+        ``batch_step`` + ``kv_write_row_list`` make live on the device,
+        step by step: inactive slots, a sequence running off its
+        allocated blocks, and one off the table's end."""
+        from deepspeed_tpu.ops.pallas.paged_attention import (
+            kv_write_live_rows, kv_write_row_list)
+        BS, MB = 8, 4
+        tables = np.asarray([[3, 4, 0, 0], [0, 0, 0, 0], [5, 6, 7, 8],
+                             [9, 0, 0, 0], [0, 0, 0, 0], [2, 1, 10, 11]],
+                            np.int32)
+        lengths = np.asarray([13, 0, 30, 5, 7, 0], np.int32)
+        n = 0
+        for s in range(steps):
+            pos = lengths + s
+            dst = np.take_along_axis(
+                tables, np.minimum(pos // BS, MB - 1)[:, None], axis=1)
+            n += int(kv_write_row_list(jnp.asarray(dst[:, 0]),
+                                       jnp.asarray(pos % BS))[-1])
+        assert kv_write_live_rows(lengths, tables, BS, steps) == n
+
+
 def _paged_decode_numpy(q, kc, vc, tbl, lens, *, window=0, alibi=False,
                         alibi_scale=1.0, alibi_bf16=False):
     """Plain numpy paged decode attention, one slot and head at a time,

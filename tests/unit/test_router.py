@@ -551,7 +551,9 @@ class TestRouterTelemetry:
                              "completed", "active", "queue_ms_p50",
                              "queue_ms_p90", "batch_occupancy_pct",
                              # + ISSUE 27's, beside the occupancy
-                             "decode_grid_share"}
+                             "decode_grid_share",
+                             # + ISSUE 29's, the KV write's live rows
+                             "kv_write_live_share"}
 
     def test_per_class_latency_windows_are_bounded(self):
         """A server that runs for a day must not append for a day: the

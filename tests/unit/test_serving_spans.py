@@ -197,6 +197,10 @@ def test_on_admit_queue_wait():
     st.on_decode_batch(1, 4, grid_steps=6, table_entries=64)
     assert st.percentiles()["batch_occupancy_pct"] == 50.0
     assert st.percentiles()["decode_grid_share"] == 0.0938
+    assert "kv_write_live_share" not in st.percentiles()
+    st.on_kv_write(3, 8)
+    st.on_kv_write(13, 24)
+    assert st.percentiles()["kv_write_live_share"] == 0.5
 
 
 @pytest.mark.parametrize("splitfuse_tokens", [0, 16])
@@ -234,6 +238,53 @@ def test_decode_grid_counter(monkeypatch, splitfuse_tokens):
     assert all(st["active"] * steps <= st["grid_steps"] for st in stats)
     assert engine.telemetry_snapshot()["decode_grid_share"] == round(
         sum(want) / (len(want) * steps * slots * MB), 4)
+
+
+@pytest.mark.parametrize("splitfuse_tokens", [0, 16])
+def test_kv_write_counter(monkeypatch, splitfuse_tokens):
+    """``write_rows`` / ``write_rows_offered`` on every dispatch span, and
+    ``kv_write_live_share`` of the telemetry: of the slots x steps rows a
+    decode-bearing dispatch hands the KV write (and a chunk's C), those
+    whose table entry names a block other than scratch — counted here
+    slot by slot, step by step, from the batches the engine dispatched."""
+    router, engine = _router(splitfuse_tokens)
+    batches, stats = [], []
+    real_batch, real_span = engine.state_mgr.decode_batch, engine_v2.span
+
+    def recording_batch(*a, **kw):
+        batch = real_batch(*a, **kw)
+        if batch.active.any():
+            batches.append((batch.lengths.copy(),
+                            batch.block_tables.copy()))
+        return batch
+
+    def recording_span(name, **st):
+        if name == "dstpu.engine.dispatch":
+            stats.append(st)
+        return real_span(name, **st)
+
+    monkeypatch.setattr(engine.state_mgr, "decode_batch", recording_batch)
+    monkeypatch.setattr(engine_v2, "span", recording_span)
+    _serve(router)
+    BS, slots, steps, C = 8, 4, 2, splitfuse_tokens
+    decoding = [st for st in stats if st["steps"]]
+    assert len(batches) == len(decoding) > N_REQUESTS
+    assert (len(decoding) < len(stats)) == bool(C)      # chunk-only ones
+    for st, (lengths, tables) in zip(decoding, batches):
+        live = sum(bool(tables[b, min((lengths[b] + t) // BS,
+                                      tables.shape[1] - 1)])
+                   for b in range(slots) for t in range(steps))
+        assert 0 < live <= st["active"] * steps
+        assert st["write_rows"] == live + st["chunk_tokens"]
+        assert st["write_rows_offered"] == steps * slots + (
+            C if st["kind"] == "fused" else 0)
+    for st in stats:
+        if not st["steps"]:
+            assert st["kind"] == "chunk" and st["write_rows_offered"] == C
+            assert 0 < st["write_rows"] == st["chunk_tokens"] <= C
+    assert engine.telemetry_snapshot()["kv_write_live_share"] == round(
+        sum(st["write_rows"] for st in stats)
+        / sum(st["write_rows_offered"] for st in stats), 4)
 
 
 def test_span_budget_without_capture(monkeypatch):

@@ -257,6 +257,65 @@ def test_kv_pools_keep_the_kernels_layout(v5e, monkeypatch, program):
     assert compiled.memory_analysis().temp_size_in_bytes < one_pool
 
 
+def test_cell3_decode_step_writes_live_rows_only(v5e, monkeypatch):
+    """One decode step of ``serve-gpt2m-chat``'s program (24 layers, 32
+    slots, 320 blocks) for the described v5e: a write and a decode kernel
+    a layer, the write's grid length an operand (``s32[]``, the live rows'
+    count) beside the three (N + 1)-entry lists and not the constant 32 it
+    was, and nothing of a pool's size made by anything but the aliased
+    kernels (PERF.md, PR 29)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from deepspeed_tpu.models import GPT2, GPT2Config
+    n_layer = 24
+    model = GPT2(GPT2Config(n_layer=n_layer, n_head=H, d_model=D,
+                            max_seq_len=T, vocab_size=V, dtype="bfloat16"))
+    model._paged_kernel, model._paged_block_c = True, 64
+    dims = pool_block_dims(POOL_NB, HD, kernel_layout=True)
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, bf16, sharding=v5e),
+        jax.eval_shape(model.init, jax.random.key(0)))
+    cache = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(dims + x.shape[1:], x.dtype,
+                                       sharding=v5e),
+        jax.eval_shape(lambda: model.init_paged_cache(POOL_NB, BS,
+                                                      dtype=bf16)))
+    cache_sh = jax.tree.map(lambda x: x.sharding, cache)
+
+    def decode(params, cache, tokens, lengths, tables):
+        logits, pools = model.apply_paged_decode(
+            params, tokens, lengths, as_pools(cache), tables)
+        return (jnp.argmax(logits, axis=-1).astype(i32),
+                like_boundary(pools, cache))
+
+    rest = [jax.ShapeDtypeStruct(s, i32, sharding=v5e)
+            for s in ((SLOTS,), (SLOTS,), (SLOTS, MB))]
+    text = jax.jit(
+        decode, donate_argnums=(1,),
+        in_shardings=(None, cache_sh) + (None,) * len(rest),
+        out_shardings=(None, cache_sh)).lower(params, cache,
+                                              *rest).compile().as_text()
+    calls = [ln for ln in text.split("\n")
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 2 * n_layer
+    pool = rf"bf16\[{POOL_NB},{H},{BS},{HD}\]"
+    writes = [ln for ln in calls
+              if re.search(rf"= \({pool}\S*, {pool}\S*\) custom-call", ln)]
+    assert len(writes) == n_layer
+    for ln in writes:
+        operands = ln[ln.index("operand_layout_constraints={"):]
+        assert operands.startswith(
+            "operand_layout_constraints={"
+            f"s32[], s32[{SLOTS + 1}]{{0}}, s32[{SLOTS + 1}]{{0}}, "
+            f"s32[{SLOTS + 1}]{{0}}, "), operands[:160]
+    lead = "|".join((str(POOL_NB), ",".join(map(str, dims))))
+    made_by = set(re.findall(
+        rf"= \(?bf16\[(?:{lead}),{H},{BS},{HD}\]\S* (?:bf16\S* )?([\w-]+)\(",
+        text))
+    assert "custom-call" in made_by
+    assert made_by <= {"custom-call", "bitcast", "get-tuple-element",
+                       "parameter"}, made_by
+
+
 # OLMoE-1B-7B at the published widths of a layer (hidden 2048, 16 heads of
 # 128, experts of width 1024, 8 per token), two layers deep with 16 of the
 # 64 experts so that it compiles in seconds. ``lax.ragged_dot`` (a Mosaic
