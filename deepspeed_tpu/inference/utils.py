@@ -85,8 +85,10 @@ def shard_params(model, mesh, dtype, params=None, seed=0, topology=None,
 
 # router weights keep float32 whatever the serving dtype: a bf16 router
 # flips near-ties between the k-th and (k+1)-th expert (the same
-# exclusion ops/int8_weights.quantize_tree / cast_unquantized honor)
-_FP32_KEYS = ("moe_gate",)
+# exclusion ops/int8_weights.quantize_tree / cast_unquantized honor).
+# So do a state-space layer's decay, skip and step bias (Mamba's own
+# practice: the recurrence is float32, models/phi4flash.py)
+_FP32_KEYS = ("moe_gate", "A_log", "D_skip", "dt_b")
 
 
 def _leaf_dtype(path, x, dtype):

@@ -264,6 +264,24 @@ class InferenceEngineV2:
                 monitor=monitor, interval=config.telemetry_interval)
         mcfg = model.config
         self.max_seq_len = mcfg.max_seq_len
+        # a model that keeps state by batch slot (a recurrent state, a
+        # window's ring: models/paged.py) is told the slot of every
+        # prefill and chunk, and cannot have what assumes that a cache
+        # is length-masked KV under a block table
+        self._slot_state = bool(getattr(model, "slot_state", False))
+        if self._slot_state:
+            self._refuse_slot_state(config, draft_model)
+            draft_model = None            # spec_draft=False: off
+        # blocks a slot of a window layer's ring: enough for the largest
+        # step this engine's programs take past position 0 (a chunk; a
+        # decode step is 1), so also what a one-shot prefill may hold
+        self._ring_blocks = 0
+        if self._slot_state and getattr(mcfg, "sliding_window", 0):
+            from ...models.paged import ring_blocks
+            self._ring_blocks = ring_blocks(
+                mcfg.sliding_window,
+                config.splitfuse_tokens or config.prompt_bucket,
+                config.kv_block_size)
 
         # fused weight-only quant mode for this engine ("auto" resolves
         # OFF — cold-cache programs byte-identical to weight_quant=False;
@@ -329,6 +347,16 @@ class InferenceEngineV2:
             device_blocks = config.device_kv_blocks
         self.cache, self._cache_sh = self._new_paged_cache(
             model, device_blocks)
+        # what a live sequence holds of it: bytes a block of the pools
+        # under the block tables (every layer's), and bytes a slot of
+        # whatever else the model keeps (rings, recurrent state)
+        self._block_bytes = sum(
+            math.prod(p.shape[-3:]) * p.dtype.itemsize
+            for key in ("k", "v") for p in self.cache[key])
+        self._slot_bytes = sum(
+            p.nbytes for key, sub in self.cache.items()
+            if key not in ("k", "v") for p in jax.tree.leaves(sub)) \
+            // config.max_batch_size
         if config.kv_host_offload:
             from .kv_offload import OffloadKVPool
             self.kv_pool = OffloadKVPool(
@@ -521,6 +549,35 @@ class InferenceEngineV2:
         return bool(self._pending) or self.state_mgr.n_active > 0
 
     # ------------------------------------------------------------- programs
+    @staticmethod
+    def _refuse_slot_state(config, draft_model):
+        """What cannot be right for a model that keeps state by slot,
+        refused by name; every "auto" resolves to off."""
+        why = ("the model keeps recurrent / window state by batch slot "
+               "(slot_state), which ")
+        if config.prefix_cache is True:
+            raise ValueError(
+                "prefix_cache=True: " + why + "a cached block of KV does "
+                "not bring back — a prefix hit would resume from a state "
+                "nobody kept")
+        if config.spec_draft is True or (
+                draft_model is not None and config.spec_draft is not False):
+            # a draft model IS the opt-in to speculation
+            raise ValueError(
+                "spec_draft=True / a draft model: " + why + "rollback_spec "
+                "cannot take back once the rejected tokens have moved it")
+        if config.kv_host_offload:
+            raise ValueError(
+                "kv_host_offload: " + why + "lives outside the block pool "
+                "the offload tier pages")
+
+    def _refuse_kv_transfer(self):
+        if self._slot_state:
+            raise RuntimeError(
+                "disaggregated kv_transfer: the model keeps recurrent / "
+                "window state by batch slot (slot_state), which the block "
+                "payloads of a KV handoff do not carry")
+
     def _resolve_prefix_cache(self, mcfg, num_blocks):
         """Resolve (enabled, min_match_blocks, evict_watermark_pct) for
         the prefix cache. Model/config combinations the cache cannot
@@ -530,6 +587,8 @@ class InferenceEngineV2:
         hand-set values (disabled, min-match 1, on-demand eviction), so
         a cold-cache engine is byte-identical to prefix_cache=False."""
         cfg = self.config
+        if self._slot_state:
+            return False, 1, 0        # True was refused at build
         windows = tuple(getattr(mcfg, "attn_layer_windows", ()) or ())
         if any(windows):
             if cfg.prefix_cache is True:
@@ -565,6 +624,7 @@ class InferenceEngineV2:
             cache_path=self.config.autotune_cache)
         self.model._paged_kernel = self.config.paged_kernel
         self.model._paged_block_c = self.config.paged_block_c
+        self.model._paged_ring_blocks = self._ring_blocks
         # fused W8A16/W4A16: _layer_slice keeps the FFN weights
         # quantized (model._WQ_KEEP) and _mlp routes them through the
         # fused-dequant kernels; False = every path dequantizes whole
@@ -578,34 +638,57 @@ class InferenceEngineV2:
             draft._paged_block_c = self.config.paged_block_c
             draft._weight_quant_fused = False
 
+    def _slot_arg(self, uid):
+        """The slot ``uid``'s prefill / chunk program serves, as that
+        program's last argument, for a model that keeps state by slot;
+        the other families' programs have no such argument."""
+        return (np.int32(self.state_mgr._slots.index(uid)),) \
+            if self._slot_state else ()
+
     def _new_paged_cache(self, model, num_blocks):
         """Allocate ``model``'s paged cache on this engine's mesh ->
         (cache, the shardings its programs declare for it). Where the
         model's decode step will run the paged kernel
         (``models/paged.uses_decode_kernel``, the question its trace
         asks, of the same shapes; off-TPU the kernels are interpreted)
-        the pools are born in the shape that keeps them in the kernels'
-        layout (:func:`pool_block_dims`)."""
+        the pools under the block tables (``k`` / ``v``) are born in the
+        shape that keeps them in the kernels' layout
+        (:func:`pool_block_dims`). A model with slot state sizes the rest
+        of its cache from the slots and ring blocks it is given, and
+        those leaves keep the shape it gives them."""
         from ...models.paged import uses_decode_kernel
         from ...ops.pallas._common import interpret_default
         cfg = self.config
-        _, _, BS, hd = jax.eval_shape(lambda: model.init_paged_cache(
-            1, cfg.kv_block_size, dtype=self.dtype))["k"][0].shape
+        extra = dict(slots=cfg.max_batch_size,
+                     ring_blocks=self._ring_blocks) \
+            if model is self.model and self._slot_state else {}
+
+        def init(n):
+            return model.init_paged_cache(n, cfg.kv_block_size,
+                                          dtype=self.dtype, **extra)
+
+        _, _, BS, hd = jax.eval_shape(lambda: init(1))["k"][0].shape
         kernel = not interpret_default() and uses_decode_kernel(
             model, cfg.max_batch_size, self.max_blocks_per_seq, BS,
             self.dtype)
         dims = pool_block_dims(num_blocks, hd, kernel)
+        lead = {"k": len(dims) - 1, "v": len(dims) - 1}
+
+        def by_key(fn, tree, **kw):
+            return {key: jax.tree.map(
+                lambda x: fn(x, lead.get(key, 0)), sub, **kw)
+                for key, sub in tree.items()}
+
         # the model's own specs, behind the block axis's extra dimensions
-        shardings = jax.tree.map(
-            lambda spec: NamedSharding(
-                self.mesh, P(*(None,) * (len(dims) - 1), *spec)),
+        shardings = by_key(
+            lambda spec, n: NamedSharding(self.mesh,
+                                          P(*(None,) * n, *spec)),
             model.paged_cache_specs(), is_leaf=lambda x: isinstance(x, P))
         with jax.set_mesh(self.mesh):
             cache = jax.jit(
-                lambda: jax.tree.map(
-                    lambda p: p.reshape(dims + p.shape[1:]),
-                    model.init_paged_cache(math.prod(dims), BS,
-                                           dtype=self.dtype)),
+                lambda: by_key(
+                    lambda p, n: p.reshape(dims + p.shape[1:]) if n else p,
+                    init(math.prod(dims))),
                 out_shardings=shardings)()
         return cache, shardings
 
@@ -638,18 +721,18 @@ class InferenceEngineV2:
             model = self.model
 
             def prefill(params, cache, ids, tb, to, length, rng, temp,
-                        top_k, all_greedy):
+                        top_k, all_greedy, *slot):
                 self._install_trace_state()
                 logits, pools = model.apply_paged_prefill(
-                    params, ids, as_pools(cache), tb, to, length)
+                    params, ids, as_pools(cache), tb, to, length, *slot)
                 tok = self._sample_per_slot(logits, rng, temp, top_k,
                                             all_greedy)
                 return tok, like_boundary(pools, cache)
 
             self._prefill_jit = jax.jit(
                 prefill, donate_argnums=(1,), static_argnums=(9,),
-                in_shardings=(self.param_shardings, self._cache_sh,
-                              None, None, None, None, None, None, None),
+                in_shardings=(self.param_shardings, self._cache_sh)
+                + (None,) * (7 + self._slot_state),
                 out_shardings=(None, self._cache_sh))
         return self._prefill_jit
 
@@ -699,11 +782,11 @@ class InferenceEngineV2:
 
             def fused(params, cache, c_ids, c_tb, c_to, c_start, c_len,
                       c_table, c_temp, c_topk, d_tokens, d_lengths,
-                      d_tables, rng, d_temps, d_topks, all_greedy):
+                      d_tables, rng, d_temps, d_topks, all_greedy, *c_slot):
                 self._install_trace_state()
                 c_logits, pools = model.apply_paged_chunk(
                     params, c_ids, as_pools(cache), c_tb, c_to, c_start,
-                    c_len, c_table)
+                    c_len, c_table, *c_slot)
                 c_tok = self._sample_per_slot(
                     c_logits, jax.random.fold_in(rng, 7919), c_temp,
                     c_topk, all_greedy)
@@ -721,7 +804,7 @@ class InferenceEngineV2:
             self._splitfuse_jit = jax.jit(
                 fused, donate_argnums=(1,), static_argnums=(16,),
                 in_shardings=(self.param_shardings, self._cache_sh)
-                + (None,) * 14,
+                + (None,) * (14 + self._slot_state),
                 out_shardings=(None, None, self._cache_sh))
         return self._splitfuse_jit
 
@@ -733,11 +816,11 @@ class InferenceEngineV2:
             model = self.model
 
             def chunk(params, cache, c_ids, c_tb, c_to, c_start, c_len,
-                      c_table, c_temp, c_topk, rng, all_greedy):
+                      c_table, c_temp, c_topk, rng, all_greedy, *c_slot):
                 self._install_trace_state()
                 c_logits, pools = model.apply_paged_chunk(
                     params, c_ids, as_pools(cache), c_tb, c_to, c_start,
-                    c_len, c_table)
+                    c_len, c_table, *c_slot)
                 c_tok = self._sample_per_slot(
                     c_logits, jax.random.fold_in(rng, 7919), c_temp,
                     c_topk, all_greedy)
@@ -746,7 +829,7 @@ class InferenceEngineV2:
             self._chunk_jit = jax.jit(
                 chunk, donate_argnums=(1,), static_argnums=(11,),
                 in_shardings=(self.param_shardings, self._cache_sh)
-                + (None,) * 9,
+                + (None,) * (9 + self._slot_state),
                 out_shardings=(None, self._cache_sh))
         return self._chunk_jit
 
@@ -875,6 +958,7 @@ class InferenceEngineV2:
         chunked prefill to the last prompt token, posts the first
         generated token, and then waits for its KV handoff to a decode
         replica instead of decoding locally."""
+        self._refuse_kv_transfer()
         self._decode_hold.add(uid)
 
     def release_decode_hold(self, uid=None):
@@ -932,6 +1016,7 @@ class InferenceEngineV2:
         colocated decode dispatch would attend, because the last
         generated token's KV is written by the decode step that
         consumes it."""
+        self._refuse_kv_transfer()
         if self.kv_pool is not None:
             raise RuntimeError(
                 "KV handoff is incompatible with kv_host_offload: "
@@ -979,6 +1064,7 @@ class InferenceEngineV2:
         at the ORIGINAL submit stamp. Returns the uid."""
         from ...runtime.checkpoint_engine import serialization as ser
         from .kv_transfer import KVWireError
+        self._refuse_kv_transfer()
         if self.kv_pool is not None:
             raise RuntimeError(
                 "KV handoff is incompatible with kv_host_offload: "
@@ -1121,6 +1207,7 @@ class InferenceEngineV2:
         with span("dstpu.engine.build"):
             uid = self._prefill_q[0]
             seq = mgr.get_sequence(uid)
+            slot = self._slot_arg(uid)
             off = seq.prefill_offset
             true_len = min(C, len(seq.prompt) - off)
             last = off + true_len >= len(seq.prompt)
@@ -1175,12 +1262,13 @@ class InferenceEngineV2:
                             np.int32(off), np.int32(true_len), table,
                             c_temp, c_topk, batch.tokens, batch.lengths,
                             batch.block_tables, sub, batch.temps,
-                            batch.top_ks, all_greedy)
+                            batch.top_ks, all_greedy, *slot)
                     else:
                         c_tok, self.cache = fn(
                             self.params, self.cache, ids, tb, to,
                             np.int32(off), np.int32(true_len), table,
-                            c_temp, c_topk, sub, seq.temperature == 0.0)
+                            c_temp, c_topk, sub, seq.temperature == 0.0,
+                            *slot)
                         toks = np.zeros((0, self.config.max_batch_size),
                                         np.int32)
                 toks = np.asarray(toks)
@@ -1223,16 +1311,26 @@ class InferenceEngineV2:
                     # slice into the fresh block before any prefill
                     # touches it
                     self._apply_cow(seq)
-                if self.config.splitfuse_tokens or seq.cached_len:
+                if self.config.splitfuse_tokens or seq.cached_len \
+                        or self._past_ring(len(req.prompt)):
                     # SplitFuse: the prompt streams through chunk
                     # dispatches interleaved with decodes — no bucketed
                     # prefill here. Prefix-cache hits take the same path
                     # regardless: the chunk program's start/true_len
                     # accounting is what skips the cached prefix (the
-                    # bucketed prefill always starts at 0)
+                    # bucketed prefill always starts at 0). So does a
+                    # prompt whose bucket a window layer's ring cannot
+                    # hold at once
                     self._prefill_q.append(req.uid)
                 else:
                     self._prefill_bucketed(req, seq)
+
+    def _past_ring(self, prompt_tokens):
+        """Whether a prompt's bucket is more than the rings hold."""
+        bucket = self.config.prompt_bucket
+        padded = -(-max(prompt_tokens, 1) // bucket) * bucket
+        return bool(self._ring_blocks) \
+            and padded > self._ring_blocks * self.config.kv_block_size
 
     def _prefill_bucketed(self, req, seq):
         """The whole prompt in one program call, padded to the bucket;
@@ -1266,7 +1364,8 @@ class InferenceEngineV2:
                         self.params, self.cache, ids, tb, to, np.int32(T),
                         sub, np.asarray([seq.temperature], np.float32),
                         np.asarray([seq.top_k], np.int32),
-                        seq.temperature == 0.0)
+                        seq.temperature == 0.0,
+                        *self._slot_arg(req.uid))
                 tok = int(np.asarray(tok)[0])
             with span("dstpu.engine.post"):
                 if self.kv_pool is not None:
@@ -1378,17 +1477,20 @@ class InferenceEngineV2:
         dispatch's wall time across the tokens it produced (per-token
         deltas inside one multi-step dispatch are meaningless)."""
         tel = self.telemetry
+        live, blocks, tokens = self.state_mgr.held()
+        cache_bytes = blocks * self._block_bytes + live * self._slot_bytes
         # the counters ride the span so that whoever reads the trace has
         # them on the profiler's clock (cached floats; 0 with telemetry
         # off)
         with span("dstpu.engine.step", pending=len(self._pending),
-                  active=self.state_mgr.n_active,
-                  slots=self.config.max_batch_size,
+                  active=live, slots=self.config.max_batch_size,
                   queue_p50_us=int(tel.queue_ms_p50 * 1e3) if tel else 0,
                   queue_p90_us=int(tel.queue_ms_p90 * 1e3) if tel else 0,
-                  admitted_total=tel.admitted if tel else 0):
+                  admitted_total=tel.admitted if tel else 0,
+                  cache_bytes=cache_bytes, live_tokens=tokens):
             out = self._step_inner()
             if tel is not None:
+                tel.on_cache_held(cache_bytes, tokens)
                 tel.on_dispatch(active=self.state_mgr.n_active)
                 tel.maybe_emit()
         return out

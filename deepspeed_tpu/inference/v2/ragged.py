@@ -114,6 +114,13 @@ class DSStateManager:
     def get_sequence(self, uid):
         return self._seqs[uid]
 
+    def held(self):
+        """(sequences in a slot, the blocks they hold, the tokens they
+        have seen): what the engine sets its cache's bytes against."""
+        live = [self._seqs[u] for u in self._slots if u is not None]
+        return (len(live), sum(len(s.blocks) for s in live),
+                sum(s.seen_tokens for s in live))
+
     def free_slot(self):
         for i, s in enumerate(self._slots):
             if s is None:

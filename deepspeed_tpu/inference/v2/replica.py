@@ -68,6 +68,10 @@ class Replica:
         if role not in ROLES:
             raise ValueError(
                 f"role must be one of {ROLES}, got {role!r}")
+        if role != "colocated":
+            # a phase role hands KV over the wire: refused at build for
+            # a model whose state the block payloads do not carry
+            getattr(engine, "_refuse_kv_transfer", lambda: None)()
         self.name = name
         self.engine = engine
         self.role = role

@@ -10,6 +10,8 @@ from .olmoe import (OLMoE, OLMoEConfig, OLMOE_PRESETS, OLMOE_TINY,
 from .bloom import Bloom, BloomConfig, BLOOM_PRESETS
 from .qwen import Qwen, QwenConfig, QWEN_PRESETS
 from .phi import Phi, PhiConfig, PHI_PRESETS
+from .phi4flash import (Phi4Flash, Phi4FlashConfig, PHI4FLASH_PRESETS,
+                        PHI4FLASH_TINY, PHI4_MINI_FLASH)
 from .falcon import Falcon, FalconConfig, FALCON_PRESETS
 from .opt import OPT, OPTConfig, OPT_PRESETS
 from .gptj import GPTJ, GPTJConfig, GPTJ_PRESETS

@@ -12,6 +12,29 @@ state) is one more ``attn_fn`` here, not a fork of the four
 ``apply_paged_*`` entry points. The dense fallback stays because it is
 the only path that runs on a CPU at the default setting, and the
 reference the kernel-on/off tests compare against.
+
+A layer's cache is one of four kinds (``Geometry.kinds``):
+
+* ``KV``: a pool of its own under the sequence's block table, the only
+  kind the allocator's blocks pay for (cache keys ``k`` / ``v``);
+* ``RING``: windowed K/V as **slot state** (``ring_k`` / ``ring_v``):
+  ``ring_blocks`` blocks a slot, position p of slot s in block
+  ``1 + s * ring_blocks + (p // BS) % ring_blocks`` (block 0 stays the
+  pool's scratch block). The table is computed here, in the program, so
+  the kernels and their work lists are called as they are: a program
+  writes before it reads, the window mask hides whatever a wrapped block
+  still holds of older positions, and ``ring_blocks * BS >= window +
+  C - 1`` (:func:`ring_blocks`) keeps a C-token step from overwriting a
+  key it still reads;
+* ``(SHARED, j)``: no cache of its own; reads layer j's pools after
+  layer j wrote them, and writes nothing;
+* ``STATE``: a recurrent state a slot (``conv`` / ``ssm``), handed out
+  and taken back by ``_Step.state`` / ``put_state``: a chunk at
+  ``start = 0`` gets zeros whatever the slot held, a decode step keeps
+  the state of every slot that is not live.
+
+``None`` is a layer with no cache. Everything but ``KV`` is indexed by
+the slot, which the chunk and prefill programs are therefore told.
 """
 
 import math
@@ -22,10 +45,21 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.pallas.paged_attention import (
-    alibi_slopes, decode_work_list, kv_write_row_list,
+    PAGED_CHUNK_BLOCK_C, alibi_slopes, decode_work_list, kv_write_row_list,
     paged_chunk_attention, paged_decode_attention,
     paged_decode_attention_reference, paged_kv_write, resolve_paged_chunk,
     resolve_paged_decode)
+
+
+KV, RING, SHARED, STATE = "kv", "ring", "shared", "state"
+# cache keys of each kind's leaves, one list entry a layer of that kind
+_KEYS = {KV: ("k", "v"), RING: ("ring_k", "ring_v"), STATE: ("conv", "ssm")}
+
+
+def ring_blocks(window, chunk, block_size):
+    """Blocks a slot's ring needs so that a step of ``chunk`` tokens can
+    write its rows before it reads the ``window`` keys behind them."""
+    return -(-(window + chunk - 1) // block_size)
 
 
 @dataclass(frozen=True)
@@ -43,18 +77,24 @@ class Geometry:
     alibi_bias: Any        # (S,) key positions -> (H, S) score bias
     kernel: Any            # "auto" | bool: the engine's paged_kernel
     block_c: Any           # "auto" | int: the engine's paged_block_c
+    kinds: tuple           # per layer: KV | RING | (SHARED, j) | STATE | None
+    ring_blocks: int       # blocks a slot of a RING layer; the engine's
 
 
-def geometry(model):
-    """``model``'s attention geometry, read off its config and the two
+def geometry(model, **fields):
+    """``model``'s attention geometry, read off its config and the
     trace-time settings the engine installs on it (``_paged_kernel``,
-    ``_paged_block_c``). A field one family lacks reads as "not there":
-    GPT-2 has no KV-head count, ALiBi or single window; Llama no
-    ``scale_attn`` or per-layer windows."""
+    ``_paged_block_c``, ``_paged_ring_blocks``). A field one family lacks
+    reads as "not there": GPT-2 has no KV-head count, ALiBi or single
+    window; Llama no ``scale_attn`` or per-layer windows. A family whose
+    kernels see other heads than its config names (``paged_geometry``)
+    says so itself, through ``fields``."""
+    if not fields and hasattr(model, "paged_geometry"):
+        return model.paged_geometry()
     cfg = model.config
     windows = getattr(cfg, "attn_layer_windows", None) \
         or (getattr(cfg, "sliding_window", 0),) * cfg.n_layer
-    return Geometry(
+    return Geometry(**{**dict(
         n_head=cfg.n_head,
         n_kv_heads=getattr(cfg, "n_kv_heads", cfg.n_head),
         d_head=cfg.d_head, dtype=jnp.dtype(cfg.dtype),
@@ -63,7 +103,9 @@ def geometry(model):
         alibi_inv_norm=getattr(cfg, "alibi_inv_norm", False),
         alibi_bias=getattr(model, "_alibi_bias", None),
         kernel=getattr(model, "_paged_kernel", "auto"),
-        block_c=getattr(model, "_paged_block_c", "auto"))
+        block_c=getattr(model, "_paged_block_c", "auto"),
+        kinds=(KV,) * cfg.n_layer,
+        ring_blocks=getattr(model, "_paged_ring_blocks", 0)), **fields})
 
 
 def _decode_kernel(geom, B, MB, BS, dtype):
@@ -85,10 +127,17 @@ def _chunk_kernel(geom, C, MB, BS):
     # ALiBi stays dense: the chunk kernel has no per-head bias input
     # (forced off BEFORE dispatch, so no search is paid for a tile the
     # model can never use)
-    return resolve_paged_chunk(
+    G = geom.n_head // geom.n_kv_heads
+    use, block_c = resolve_paged_chunk(
         False if geom.alibi else geom.kernel, geom.block_c, C, MB, BS,
-        geom.n_kv_heads, geom.n_head // geom.n_kv_heads, geom.d_head,
-        geom.dtype)
+        geom.n_kv_heads, G, geom.d_head, geom.dtype)
+    if geom.block_c == "auto" and G > 1:
+        # block_c counts tokens, and the kernel folds a KV head's G query
+        # heads into the tile's rows: keep a tile to the rows a head that
+        # G = 1 gives it (10 heads x 512 rows x 128 lanes ask for more
+        # VMEM than a kernel may have: sandbox compile for a v5e, PR 30)
+        block_c = max(8, min(block_c, PAGED_CHUNK_BLOCK_C // G))
+    return use, block_c
 
 
 def _dense_attention(geom, q, gk, gv, q_pos, frontier, window):
@@ -125,47 +174,98 @@ def _dense_attention(geom, q, gk, gv, q_pos, frontier, window):
 
 
 class _Step:
-    """One program's attention: ``layer(i)`` is layer i's ``attn_fn``.
-    Row n of the flattened new K/V goes to ``blocks[n]``, ``offsets[n]``
-    (pads and inactive slots aim at scratch block 0: dead rows, which the
-    write kernel steps over), in place in the layer's own donated pools;
-    then ``attend`` reads through the table."""
+    """One program's cache traffic: ``layer(i)`` is layer i's ``attn_fn``,
+    ``state(i)`` / ``put_state(i, ...)`` its recurrent state.
 
-    def __init__(self, geom, cache, blocks, offsets, use_kernel, attend):
-        self.windows, self.cache, self.attend = geom.windows, cache, attend
-        self.blocks, self.offsets = blocks, offsets
-        self.use_kernel = use_kernel
-        # the write kernel's grid: this step's live rows, one list shared
-        # by every layer
-        self.rows = kv_write_row_list(blocks, offsets) if use_kernel \
-            else None
+    Row n of the flattened new K/V of a ``KV`` (``RING``) layer goes to
+    ``dest[kind] = (blocks[n], offsets[n], row list)`` (pads and inactive
+    slots aim at scratch block 0: dead rows, which the write kernel steps
+    over), in place in the layer's own donated pools; then ``attend``
+    reads through ``tables[kind]``. ``cache`` is the program's cache with
+    every write so far in it: what the model hands back."""
+
+    def __init__(self, geom, cache, use_kernel, attend, tables, dest,
+                 take, put):
+        self.geom, self.attend, self.use_kernel = geom, attend, use_kernel
+        self.cache = {k: list(v) for k, v in cache.items()}
+        self.tables = tables
+        # the write kernel's grid: this step's live rows, one list a kind,
+        # shared by every layer of it
+        self.dest = {kind: (b, o, kv_write_row_list(b, o)
+                            if use_kernel else None)
+                     for kind, (b, o) in dest.items()}
+        # STATE: ``take(leaf)`` -> the rows of it this program works on,
+        # ``put(leaf, rows)`` -> the leaf with new rows in their place
+        self.take, self.put = take, put
+        kinds = [k[0] if isinstance(k, tuple) else k for k in geom.kinds]
+        self.index = [kinds[:i].count(k) for i, k in enumerate(kinds)]
 
     def layer(self, i):
-        pools = (self.cache["k"][i], self.cache["v"][i])
+        kind, window = self.geom.kinds[i], self.geom.windows[i]
+        if isinstance(kind, tuple):                  # (SHARED, j)
+            j = self.index[kind[1]]
+
+            def read_fn(q, k=None, v=None):
+                return self.attend(q, self.cache["k"][j],
+                                   self.cache["v"][j], window,
+                                   self.tables[KV]), None
+
+            return read_fn
+        (kk, vk), j = _KEYS[kind], self.index[i]
+        blocks, offsets, rows = self.dest[kind]
 
         def attn_fn(q, k, v):
             kc, vc = paged_kv_write(
-                pools, (k.reshape((-1,) + k.shape[2:]),
-                        v.reshape((-1,) + v.shape[2:])),
-                self.blocks, self.offsets, rows=self.rows,
-                kernel=self.use_kernel)
-            return self.attend(q, kc, vc, self.windows[i]), (kc, vc)
+                (self.cache[kk][j], self.cache[vk][j]),
+                (k.reshape((-1,) + k.shape[2:]),
+                 v.reshape((-1,) + v.shape[2:])),
+                blocks, offsets, rows=rows, kernel=self.use_kernel)
+            self.cache[kk][j], self.cache[vk][j] = kc, vc
+            return self.attend(q, kc, vc, window, self.tables[kind]), \
+                (kc, vc)
 
         return attn_fn
 
+    def state(self, i):
+        """Layer i's (conv, ssm) for this program's rows."""
+        j = self.index[i]
+        return tuple(self.take(self.cache[k][j]) for k in _KEYS[STATE])
+
+    def put_state(self, i, *new):
+        j = self.index[i]
+        for k, x in zip(_KEYS[STATE], new):
+            leaf = self.cache[k][j]
+            self.cache[k][j] = self.put(leaf, x.astype(leaf.dtype))
+
+
+def _ring_table(geom, slots, MB):
+    """(len(slots), MB) table of a RING layer: entry j of slot s."""
+    R = geom.ring_blocks
+    return 1 + slots[:, None] * R + jnp.arange(MB, dtype=jnp.int32)[None] % R
+
 
 def chunk_step(geom, cache, token_blocks, token_offsets, start, true_len,
-               table):
+               table, slot=None):
     """The step of a chunk / prefill program: q, k, v are (1, C, ., hd)
     for C tokens of one sequence at positions ``start ..``;
     ``token_blocks`` / ``token_offsets``: (C,) destinations (pads aim at
-    scratch block 0); ``table``: (MB,) the sequence's block table.
-    Queries attend the prior cache plus the in-chunk causal prefix."""
+    scratch block 0); ``table``: (MB,) the sequence's block table;
+    ``slot``: the batch slot the sequence holds (read only where a layer
+    keeps slot state). Queries attend the prior cache plus the in-chunk
+    causal prefix; recurrent state continues the slot's, from zero at
+    ``start = 0``, and stops at token ``true_len - 1``."""
     C, MB = token_blocks.shape[0], table.shape[0]
-    use_kernel, block_c = _chunk_kernel(geom, C, MB,
-                                        cache["k"][0].shape[2])
+    BS = cache["k"][0].shape[2]
+    use_kernel, block_c = _chunk_kernel(geom, C, MB, BS)
+    tables, dest = {KV: table}, {KV: (token_blocks, token_offsets)}
+    if RING in geom.kinds:
+        ring = _ring_table(geom, jnp.reshape(slot, (1,)), MB)[0]
+        entry = jnp.minimum((start + jnp.arange(C)) // BS, MB - 1)
+        tables[RING] = ring
+        dest[RING] = (jnp.where(token_blocks != 0, ring[entry], 0),
+                      token_offsets)
 
-    def attend(q, kc, vc, window):
+    def attend(q, kc, vc, window, table):
         if use_kernel:
             # blocked-flash chunk kernel: each KV block streams through
             # VMEM once, located via the table; GQA-native
@@ -177,27 +277,48 @@ def chunk_step(geom, cache, token_blocks, token_offsets, start, true_len,
             (start + jnp.arange(C))[None],
             jnp.reshape(start + true_len, (1,)), window)
 
-    return _Step(geom, cache, token_blocks, token_offsets, use_kernel,
-                 attend)
+    def at(leaf):
+        return (slot,) + (0,) * (leaf.ndim - 1)
+
+    def take(leaf):
+        row = jax.lax.dynamic_slice(leaf, at(leaf), (1,) + leaf.shape[1:])
+        return jnp.where(start == 0, jnp.zeros_like(row), row)
+
+    def put(leaf, row):
+        return jax.lax.dynamic_update_slice(leaf, row, at(leaf))
+
+    step = _Step(geom, cache, use_kernel, attend, tables, dest, take, put)
+    # which of the C tokens are real: a recurrent layer steps over the pads
+    step.valid = (jnp.arange(C) < true_len)[None]
+    step.n_valid = jnp.reshape(true_len, (1,))
+    return step
 
 
 def batch_step(geom, cache, lengths, block_tables, C):
     """The step of a decode (C = 1) or verify (C > 1) program: q, k, v
     are (B, C, ., hd), slot b's tokens at positions ``lengths[b] ..``;
-    ``block_tables``: (B, MB), inactive slots all-scratch."""
+    ``block_tables``: (B, MB), inactive slots all-scratch. Row b IS slot
+    b: slot state is read and written in place, and only where the slot
+    is live."""
     B, MB = block_tables.shape
     BS = cache["k"][0].shape[2]
+    active = block_tables[:, 0] != 0
     linpos = lengths[:, None] + jnp.arange(C)[None, :]           # (B, C)
-    dst_block = jnp.take_along_axis(
-        block_tables, jnp.minimum(linpos // BS, MB - 1), axis=1).reshape(-1)
+    entry = jnp.minimum(linpos // BS, MB - 1)
     dst_off = (linpos % BS).reshape(-1)
+    tables = {KV: block_tables}
+    if RING in geom.kinds:
+        tables[RING] = jnp.where(
+            active[:, None],
+            _ring_table(geom, jnp.arange(B, dtype=jnp.int32), MB), 0)
+    dest = {kind: (jnp.take_along_axis(t, entry, axis=1).reshape(-1),
+                   dst_off) for kind, t in tables.items()}
 
     if C == 1:
         use_kernel = _decode_kernel(geom, B, MB, BS, geom.dtype)
         # the decode kernel's grid: this step's live (slot, block) pairs,
         # one list per window size, shared by every layer that has it
-        work = {w: decode_work_list(lengths, MB, BS, w,
-                                    active=block_tables[:, 0] != 0)
+        work = {w: decode_work_list(lengths, MB, BS, w, active=active)
                 for w in set(geom.windows)} if use_kernel else {}
         alibi = dict(
             alibi_slopes=alibi_slopes(geom.n_head),
@@ -205,28 +326,36 @@ def batch_step(geom, cache, lengths, block_tables, C):
                          if geom.alibi_inv_norm else 1.0),
             alibi_bf16=geom.alibi_inv_norm) if geom.alibi else {}
 
-        def attend(q, kc, vc, window):
+        def attend(q, kc, vc, window, tables):
             if use_kernel:
                 return paged_decode_attention(
-                    q[:, 0], kc, vc, block_tables, lengths,
+                    q[:, 0], kc, vc, tables, lengths,
                     work=work[window], scale=geom.scale, window=window,
                     **alibi)[:, None]
             return paged_decode_attention_reference(
-                q[:, 0], kc, vc, block_tables, lengths, scale=geom.scale,
+                q[:, 0], kc, vc, tables, lengths, scale=geom.scale,
                 window=window)[:, None]
     else:
         use_kernel, block_c = _chunk_kernel(geom, C, MB, BS)
 
-        def attend(q, kc, vc, window):
+        def attend(q, kc, vc, window, tables):
             if use_kernel:
                 # the batched split-fuse ride: each slot's span is a
                 # chunk with start = lengths[b], true_len = C
                 return jnp.stack([paged_chunk_attention(
-                    q[b], kc, vc, block_tables[b], lengths[b],
+                    q[b], kc, vc, tables[b], lengths[b],
                     jnp.int32(C), scale=geom.scale, window=window,
                     block_c=block_c) for b in range(B)])
             return _dense_attention(
-                geom, q, kc[block_tables], vc[block_tables], linpos,
-                lengths + C, window)
+                geom, q, kc[tables], vc[tables], linpos, lengths + C,
+                window)
 
-    return _Step(geom, cache, dst_block, dst_off, use_kernel, attend)
+    def put(leaf, rows):
+        live = active.reshape((B,) + (1,) * (leaf.ndim - 1))
+        return jnp.where(live, rows, leaf)
+
+    step = _Step(geom, cache, use_kernel, attend, tables, dest,
+                 lambda leaf: leaf, put)
+    step.valid = jnp.ones((B, C), bool)
+    step.n_valid = jnp.full((B,), C, jnp.int32)
+    return step

@@ -224,9 +224,16 @@ SPAN_SCHEMA = {
                    "span less the engine.step spans inside it"},
     "dstpu.engine.step": {
         "stats": ("pending", "active", "slots", "queue_p50_us",
-                  "queue_p90_us", "admitted_total"),
+                  "queue_p90_us", "admitted_total", "cache_bytes",
+                  "live_tokens"),
         "meaning": "one InferenceEngineV2.step; carries the queue-wait "
-                   "counters of ServingTelemetry to the trace's reader"},
+                   "counters of ServingTelemetry to the trace's reader, "
+                   "and cache_bytes = what the sequences in a slot hold "
+                   "of the cache as the step begins (their blocks under "
+                   "the block tables, every layer's, plus a slot's share "
+                   "of whatever the model keeps by slot: window rings, "
+                   "recurrent state) against live_tokens = the tokens "
+                   "they have seen"},
     "dstpu.engine.admit": {
         "stats": ("uid", "prompt_tokens", "wait_us"),
         "meaning": "one request admitted: pool check passed -> queued "
@@ -278,6 +285,25 @@ SCOPE_SCHEMA = {
     "dstpu.moe.combine":
         "MoE layer: unsort, weight by the routing probabilities, sum over "
         "the k picks (and, expert-parallel, the all_to_all back)",
+    "dstpu.ssm.mix":
+        "state-space (Mamba) mixer: in-projection, causal conv, the "
+        "x / dt projections, the selective scan (prefill) or its one-step "
+        "update (decode), gate, out-projection, and the slot state's "
+        "read and write",
+    "dstpu.gmu":
+        "Gated Memory Unit: gate projection, product with the memory "
+        "layer's scan output, out-projection",
+    "dstpu.attn.diff":
+        "differential attention outside the paged read: q/k/v "
+        "projection, the query's padding to the head pair's width, "
+        "A1 V - lambda A2 V, sub-norm, out-projection",
+    "dstpu.attn.window":
+        "a windowed layer's K/V write into its slot's ring and the "
+        "paged read of at most the window's keys",
+    "dstpu.attn.shared_kv":
+        "the one full-length K/V: the full layer's write into its pool "
+        "under the block table and its read, and every cross-decoder "
+        "layer's read of that same pool",
 }
 
 

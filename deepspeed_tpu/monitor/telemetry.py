@@ -831,6 +831,11 @@ class ServingTelemetry:
         # over every dispatch (chunk-only ones too)
         self._write_rows = 0
         self._write_rows_offered = 0
+        # bytes of cache the live sequences hold (blocks under their
+        # tables, and whatever the model keeps a slot) against the tokens
+        # they have seen, summed over the engine's steps
+        self._cache_bytes = 0
+        self._live_tokens = 0
         self.completed = 0
         self.rejected = 0
         self.active = 0
@@ -903,6 +908,12 @@ class ServingTelemetry:
         takes against those its one-step-a-row grid took."""
         self._write_rows += live
         self._write_rows_offered += offered
+
+    def on_cache_held(self, cache_bytes, live_tokens):
+        """One engine step began with ``cache_bytes`` of cache held by
+        live sequences that had seen ``live_tokens`` tokens."""
+        self._cache_bytes += cache_bytes
+        self._live_tokens += live_tokens
 
     def on_token(self, uid):
         """First token => TTFT sample; later tokens accumulate for the
@@ -1050,6 +1061,9 @@ class ServingTelemetry:
         if self._write_rows_offered:
             out["kv_write_live_share"] = round(
                 self._write_rows / self._write_rows_offered, 4)
+        if self._live_tokens:
+            out["cache_bytes_per_live_token"] = round(
+                self._cache_bytes / self._live_tokens)
         if self.rejected:
             # only present once a cancel/shed happened: router-off
             # engine snapshots stay byte-identical to pre-router runs

@@ -588,8 +588,12 @@ def pool_block_dims(num_blocks, head_dim, kernel_layout):
 
 def as_pools(cache):
     """Inside a program: the cache's leaves as the (NB, KVH, BS, hd)
-    pools the model and the kernels take (a bitcast, or nothing)."""
-    return jax.tree.map(lambda p: p.reshape((-1,) + p.shape[-3:]), cache)
+    pools the model and the kernels take (a bitcast, or nothing). A leaf
+    whose block axis was not split (a pool as it is, a slot's state of
+    fewer dimensions) passes as it came."""
+    return jax.tree.map(
+        lambda p: p.reshape((-1,) + p.shape[-3:]) if p.ndim > 4 else p,
+        cache)
 
 
 def like_boundary(pools, cache):

@@ -553,7 +553,9 @@ class TestRouterTelemetry:
                              # + ISSUE 27's, beside the occupancy
                              "decode_grid_share",
                              # + ISSUE 29's, the KV write's live rows
-                             "kv_write_live_share"}
+                             "kv_write_live_share",
+                             # + ISSUE 30's, what live sequences hold
+                             "cache_bytes_per_live_token"}
 
     def test_per_class_latency_windows_are_bounded(self):
         """A server that runs for a day must not append for a day: the

@@ -30,7 +30,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, os.path.join(REPO, "perfbench"))
 from pbench import (common as pb_common, moe as pb_moe,  # noqa: E402
-                    trace as pb_trace)
+                    ssm as pb_ssm, trace as pb_trace)
 
 _CFG = GPT2Config(n_layer=2, n_head=4, d_model=64, max_seq_len=128,
                   vocab_size=256, remat=False, dtype="float32")
@@ -368,7 +368,82 @@ def test_moe_reader(metric, bucketed):
     assert said[0][0] == "moe_device_seconds"
     scoped = pb_moe.op_scopes(view.trace.path, "/device:TPU:")
     assert {sc for name in scoped.values()
-            for sc in SCOPE_SCHEMA if sc in name} == set(SCOPE_SCHEMA)
+            for sc in SCOPE_SCHEMA if sc in name} \
+        == {sc for sc in SCOPE_SCHEMA if sc.startswith("dstpu.moe.")}
+
+
+SSM_READERS = ("ssm_mix_share", "attn_window_share", "attn_shared_kv_share")
+
+
+@pytest.mark.parametrize("metric", SSM_READERS)
+def test_ssm_reader_reads_nothing_without_its_scope(metric, bucketed):
+    """The readers of the hybrid's layers (ISSUE 30) give None, and say
+    nothing, where the traced program opened none of their scopes: no
+    trace, a dense model's serving trace (cells 3, 4, 6 and the parent
+    commit), the recorded training trace, the recorded MoE trace."""
+    said = []
+    view = types.SimpleNamespace(
+        say=lambda line, **fields: said.append((line, fields)))
+    reader = pb_common.load_module("layer_metrics", metric)
+    fixtures = os.path.join(REPO, "perfbench", "fixtures")
+    for other in (None, bucketed[0],
+                  pb_trace.Trace(os.path.join(fixtures, "tiny4.xplane.pb")),
+                  pb_trace.Trace(os.path.join(fixtures, "moe1.xplane.pb"))):
+        view.trace = other
+        assert reader.read(view) is None and not said
+
+
+def test_ssm_reader_shares_by_first_scope_named():
+    """Own time goes to the first of ``pbench.ssm.SCOPES`` an operation's
+    ``tf_op`` names, over the device's busy time."""
+    ev = types.SimpleNamespace
+    ops = {"a": "jit(decode)/dstpu.ssm.mix/dot_general",
+           "b": "jit(decode)/dstpu.attn.window/pallas_call",
+           "c": "jit(decode)/dstpu.attn.shared_kv/jit(_kv_write_call)/x",
+           "d": "jit(decode)/dstpu.gmu/mul", "e": "jit(decode)/argmax"}
+    trace = ev(path="p", devices=[0], ssm_seconds=None, busy_s=lambda: 2.0,
+               in_window=lambda d: [ev(name=n, self_s=0.25) for n in ops])
+    said = []
+    view = ev(trace=trace, say=lambda line, **f: said.append(line))
+    real = pb_moe.op_scopes
+    pb_moe.op_scopes = lambda path, prefix: ops
+    try:
+        got = [pb_common.load_module("layer_metrics", m).read(view)
+               for m in SSM_READERS]
+    finally:
+        pb_moe.op_scopes = real
+    assert got == [12.5, 12.5, 12.5] and said == ["ssm_device_seconds"]
+    assert set(trace.ssm_seconds[0]) == {
+        pb_ssm.SSM_MIX, pb_ssm.ATTN_WINDOW, pb_ssm.ATTN_SHARED_KV,
+        pb_ssm.GMU}
+
+
+@pytest.mark.parametrize("kind", ["bucketed", "splitfuse"])
+def test_cache_bytes_per_live_token_reader(kind, request):
+    """``cache_bytes_per_live_token`` is the step spans' two counters,
+    summed over the traced window: for a family whose whole cache is
+    blocks under the block tables, the blocks its live sequences hold
+    times a block's bytes in every layer's K and V."""
+    tr, engine = request.getfixturevalue(kind)
+    said = []
+    view = types.SimpleNamespace(
+        trace=tr, say=lambda line, **f: said.append((line, f)))
+    reader = pb_common.load_module("layer_metrics",
+                                   "cache_bytes_per_live_token")
+    value = reader.read(view)
+    steps = [e.stats for e in tr.host_spans("dstpu.engine.step")]
+    assert engine._slot_bytes == 0
+    assert engine._block_bytes == 2 * _CFG.n_layer * _CFG.d_model * 8 * 4
+    assert all(int(s["cache_bytes"]) % engine._block_bytes == 0
+               for s in steps)
+    assert value == sum(int(s["cache_bytes"]) for s in steps) \
+        / sum(int(s["live_tokens"]) for s in steps)
+    # a sequence holds its whole budget's blocks from admission: more
+    # than a token's bytes a live token
+    assert value > engine._block_bytes / 8
+    assert said[0][0] == "cache_bytes_per_live_token"
+    view.trace = None
+    assert reader.read(view) is None
 
 
 _SPAN_RE = re.compile(r"""\bspan\(\s*["'](dstpu\.[A-Za-z0-9_.]+)["']""")
@@ -393,7 +468,8 @@ def test_scope_schema_lint_both_directions():
     assert set(SCOPE_SCHEMA) - opened == set(), "registered, never opened"
     assert all(n.startswith("dstpu.") and m for n, m in SCOPE_SCHEMA.items())
     # the benchmark's readers look for the same names
-    assert {pb_moe.SCOPE_EXPERTS, *pb_moe.SCOPES_ROUTE} == set(SCOPE_SCHEMA)
+    assert {pb_moe.SCOPE_EXPERTS, *pb_moe.SCOPES_ROUTE, *pb_ssm.SCOPES} \
+        == set(SCOPE_SCHEMA)
 
 
 def test_span_schema_lint_both_directions():

@@ -396,3 +396,51 @@ def test_olmoe_experts_are_read_in_place(v5e, monkeypatch, program):
             model, stacked, program, v5e).memory_analysis().temp_size_in_bytes
         assert stacked_temp > 0.3 * expert_bytes, (stacked_temp,
                                                    expert_bytes)
+
+
+# ------------------------------------------- Phi-4-mini-flash (ISSUE 30)
+# the published widths: 10 head pairs of 128 lanes, 4 query heads a pair
+P4_KV, P4_G, P4_W = 10, 4, 128
+
+
+@pytest.mark.parametrize("block_c, fits", [(128, False), (32, True)])
+def test_chunk_tile_under_gqa_folds(v5e, block_c, fits):
+    """The chunk kernel folds a KV head's G query heads into its tile's
+    rows: at 10 heads x 128 lanes a 128-token tile is 512 rows a head and
+    asks for more VMEM than a kernel may have, which refused every prefill
+    of the cell's first compile; ``models/paged.py`` caps an "auto" tile
+    at 128 // G, and that tile compiles."""
+    from deepspeed_tpu.models import paged
+    from deepspeed_tpu.models.phi4flash import PHI4_MINI_FLASH, Phi4Flash
+    geom = Phi4Flash(PHI4_MINI_FLASH).paged_geometry()
+    assert (geom.n_kv_heads, geom.n_head // geom.n_kv_heads, geom.d_head) \
+        == (P4_KV, P4_G, P4_W)
+    assert paged._chunk_kernel(geom, 512, 64, BS) == (False, 32)  # on a CPU
+
+    def chunk(q, kc, vc, table, start, true_len):
+        return paged_chunk_attention(q, kc, vc, table, start, true_len,
+                                     scale=1.0, window=512,
+                                     block_c=block_c, interpret=False)
+
+    shapes = [((512, P4_KV * P4_G, P4_W), bf16),
+              ((641, P4_KV, BS, P4_W), bf16), ((641, P4_KV, BS, P4_W), bf16),
+              ((64,), i32), ((), i32), ((), i32)]
+    if fits:
+        _compile(chunk, shapes, v5e)
+    else:
+        with pytest.raises(Exception, match="(?i)vmem|RESOURCE_EXHAUSTED"):
+            _compile(chunk, shapes, v5e)
+
+
+def test_two_piece_product_keeps_its_rounding(v5e):
+    """``phi4flash._mm`` hands a float32 activation to a bfloat16 weight
+    as hi + lo. Written as a cast there and back, ``hi`` is excess
+    precision to this compiler, which takes the round trip out and ``lo``
+    with it (my chip run, PR 30); as ``reduce_precision`` it stays, and
+    the product is over twice the rows."""
+    from deepspeed_tpu.models.phi4flash import _mm
+    args = [jax.ShapeDtypeStruct((64, 1, 2560), jnp.float32, sharding=v5e),
+            jax.ShapeDtypeStruct((2560, 10240), bf16, sharding=v5e)]
+    text = jax.jit(_mm).lower(*args).compile().as_text()
+    assert "reduce-precision(" in text
+    assert re.search(r"bf16\[2,64,(1,)?2560\]", text)
