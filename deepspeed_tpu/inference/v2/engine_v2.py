@@ -21,6 +21,7 @@ Counterpart of reference ``inference/v2/engine_v2.py:30 InferenceEngineV2``
     the continuous-batching property.
 """
 
+import functools
 import math
 from collections import deque
 from dataclasses import dataclass, field
@@ -39,6 +40,12 @@ from ...ops.pallas.paged_attention import (as_pools, decode_grid_steps,
                                            like_boundary, pool_block_dims)
 from ..utils import shard_params
 from .ragged import DSStateManager, RaggedBatchWrapper
+
+# the programs a ``dstpu.engine.dispatch`` span of each kind calls, by the
+# names ``_noting_expert_calls`` keeps their counts under
+_PROGRAMS_OF_KIND = {"decode": ("decode",), "offload": ("decode",),
+                     "fused": ("fused",), "chunk": ("chunk",),
+                     "spec": ("propose", "verify")}
 
 
 @dataclass
@@ -420,6 +427,9 @@ class InferenceEngineV2:
         self._rng = jax.random.key(config.seed + 23)
         self._prefill_jit = None
         self._decode_jit = None
+        # program -> (expert layer calls, those of them through a Pallas
+        # grouped kernel), noted when the program is traced
+        self._expert_calls = {}
         self._splitfuse_jit = None
         self._chunk_jit = None        # chunk-only (no decoders running)
         self._cow_jit = None          # prefix-cache partial-tail copy
@@ -638,6 +648,24 @@ class InferenceEngineV2:
             draft._paged_block_c = self.config.paged_block_c
             draft._weight_quant_fused = False
 
+    def _noting_expert_calls(self, body, key=None):
+        """``body`` — a program's traced function — noting how many expert
+        layer calls (MoE layers x steps) its trace makes and how many of
+        them took a Pallas grouped kernel (moe/sharded_moe.py
+        ``counting_expert_calls``; a dense model makes none), under its
+        name or ``key(*args)``. Known once the program is traced: the
+        dispatch that traces it still reads 0 of 0."""
+        from ...moe.sharded_moe import counting_expert_calls
+
+        @functools.wraps(body)
+        def program(*args):
+            with counting_expert_calls() as counts:
+                out = body(*args)
+            name = body.__name__ if key is None else key(*args)
+            self._expert_calls[name] = tuple(counts)
+            return out
+        return program
+
     def _slot_arg(self, uid):
         """The slot ``uid``'s prefill / chunk program serves, as that
         program's last argument, for a model that keeps state by slot;
@@ -729,8 +757,11 @@ class InferenceEngineV2:
                                             all_greedy)
                 return tok, like_boundary(pools, cache)
 
+            # a bucket's program is a trace of its own: noted by its length
             self._prefill_jit = jax.jit(
-                prefill, donate_argnums=(1,), static_argnums=(9,),
+                self._noting_expert_calls(
+                    prefill, key=lambda *a: ("prefill", a[2].shape[1])),
+                donate_argnums=(1,), static_argnums=(9,),
                 in_shardings=(self.param_shardings, self._cache_sh)
                 + (None,) * (7 + self._slot_state),
                 out_shardings=(None, self._cache_sh))
@@ -763,7 +794,8 @@ class InferenceEngineV2:
                 return jnp.stack(all_toks), like_boundary(pools, cache)
 
             self._decode_jit = jax.jit(
-                decode, donate_argnums=(1,), static_argnums=(8,),
+                self._noting_expert_calls(decode), donate_argnums=(1,),
+                static_argnums=(8,),
                 in_shardings=(self.param_shardings, self._cache_sh,
                               None, None, None, None, None, None),
                 out_shardings=(None, self._cache_sh))
@@ -802,7 +834,8 @@ class InferenceEngineV2:
                 return c_tok, jnp.stack(toks), like_boundary(pools, cache)
 
             self._splitfuse_jit = jax.jit(
-                fused, donate_argnums=(1,), static_argnums=(16,),
+                self._noting_expert_calls(fused), donate_argnums=(1,),
+                static_argnums=(16,),
                 in_shardings=(self.param_shardings, self._cache_sh)
                 + (None,) * (14 + self._slot_state),
                 out_shardings=(None, None, self._cache_sh))
@@ -827,7 +860,8 @@ class InferenceEngineV2:
                 return c_tok, like_boundary(pools, cache)
 
             self._chunk_jit = jax.jit(
-                chunk, donate_argnums=(1,), static_argnums=(11,),
+                self._noting_expert_calls(chunk), donate_argnums=(1,),
+                static_argnums=(11,),
                 in_shardings=(self.param_shardings, self._cache_sh)
                 + (None,) * (9 + self._slot_state),
                 out_shardings=(None, self._cache_sh))
@@ -914,7 +948,7 @@ class InferenceEngineV2:
                 return jnp.stack(props, axis=1), like_boundary(pools, cache)
 
             self._propose_jit = jax.jit(
-                propose, donate_argnums=(1,),
+                self._noting_expert_calls(propose), donate_argnums=(1,),
                 in_shardings=(self._draft_param_sh, self._draft_cache_sh,
                               None, None, None),
                 out_shardings=(None, self._draft_cache_sh))
@@ -937,7 +971,7 @@ class InferenceEngineV2:
                         like_boundary(pools, cache))
 
             self._verify_jit = jax.jit(
-                verify, donate_argnums=(1,),
+                self._noting_expert_calls(verify), donate_argnums=(1,),
                 in_shardings=(self.param_shardings, self._cache_sh,
                               None, None, None),
                 out_shardings=(None, self._cache_sh))
@@ -1184,6 +1218,8 @@ class InferenceEngineV2:
             write_rows += kv_write_live_rows(lengths, tables, BS, steps)
             write_rows_offered += steps * slots
         active = int(np.sum(active))
+        expert_calls, expert_kernel_calls = self._expert_calls_of(
+            *_PROGRAMS_OF_KIND[kind])
         if self.telemetry is not None:
             if steps:
                 self.telemetry.on_decode_batch(active, slots, grid_steps,
@@ -1193,7 +1229,20 @@ class InferenceEngineV2:
                     slots=slots, steps=steps, chunk_tokens=chunk_tokens,
                     grid_steps=grid_steps, table_entries=table_entries,
                     write_rows=write_rows,
-                    write_rows_offered=write_rows_offered)
+                    write_rows_offered=write_rows_offered,
+                    expert_calls=expert_calls,
+                    expert_kernel_calls=expert_kernel_calls)
+
+    def _expert_calls_of(self, *programs):
+        """(expert layer calls, those through a Pallas grouped kernel) of
+        one call of each of ``programs``, as their traces noted them, fed
+        to the telemetry's ``moe_kernel_share``."""
+        counts = [self._expert_calls.get(p, (0, 0)) for p in programs]
+        calls = sum(c for c, _ in counts)
+        kernel = sum(k for _, k in counts)
+        if self.telemetry is not None:
+            self.telemetry.on_expert_calls(calls, kernel)
+        return calls, kernel
 
     def _step_splitfuse_chunk(self):
         """Run one fused dispatch: the next chunk of the oldest
@@ -1339,8 +1388,10 @@ class InferenceEngineV2:
         bucket = self.config.prompt_bucket
         T = len(req.prompt)
         T_pad = -(-max(T, 1) // bucket) * bucket
+        calls, kernel = self._expert_calls_of(("prefill", T_pad))
         with span("dstpu.engine.prefill", uid=req.uid, tokens=T,
-                  padded=T_pad):
+                  padded=T_pad, expert_calls=calls,
+                  expert_kernel_calls=kernel):
             with span("dstpu.engine.build"):
                 ids = np.zeros((1, T_pad), np.int32)
                 ids[0, :T] = req.prompt

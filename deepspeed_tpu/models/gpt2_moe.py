@@ -31,8 +31,8 @@ class GPT2MoEConfig(GPT2Config):
     # 'dense' = GShard capacity dispatch (EP-shaped); 'ragged' = dropless
     # grouped GEMM for DP/TP meshes (EP via the shard_map all_to_all)
     moe_backend: str = "dense"
-    # ragged backend's expert-product engine: "auto" (the
-    # 'moe_grouped_mm' autotune winner cache; cold cache = ragged_dot) |
+    # ragged backend's expert-product engine: "auto" (ragged_dot for
+    # these two-product experts: sharded_moe.resolve_grouped_params) |
     # True (Pallas grouped-GEMM kernel) | False (lax.ragged_dot)
     moe_grouped_kernel: object = "auto"
 

@@ -11,9 +11,10 @@ MoE — with two differences, both configuration (HF ``modeling_olmoe``):
 
 No shared expert, no bias anywhere, untied head, final RMSNorm. Served
 like Mixtral: each layer's experts are operands of the serving programs
-(``Mixtral._PER_LAYER``), the one-device path runs ``lax.ragged_dot`` (or
-the Pallas grouped kernel where the winner cache says so), an ``expert``
-mesh axis > 1 the all_to_all path.
+(``Mixtral._PER_LAYER``), the one-device path runs the forward grouped
+kernel on a TPU at a serving program's few rows a group and
+``lax.ragged_dot`` elsewhere (``sharded_moe.resolve_grouped_params``), an
+``expert`` mesh axis > 1 the all_to_all path.
 """
 
 from dataclasses import dataclass

@@ -29,9 +29,10 @@ class MoE:
         product), single-shard otherwise.
 
         grouped_kernel: the ragged backend's expert-product engine —
-        "auto" (default: the 'moe_grouped_mm' autotune winner cache; a
-        cold cache keeps lax.ragged_dot) | True (the Pallas grouped-GEMM
-        kernel, ops/pallas/grouped_matmul.py) | False (ragged_dot)."""
+        "auto" (default: lax.ragged_dot; only a SwiGLU chain has a
+        forward kernel to resolve to, sharded_moe.resolve_grouped_params)
+        | True (the Pallas grouped-GEMM kernel,
+        ops/pallas/grouped_matmul.py) | False (ragged_dot)."""
         self.hidden_size = hidden_size
         self.ffn_hidden_size = ffn_hidden_size or 4 * hidden_size
         self.num_experts = num_experts

@@ -22,6 +22,8 @@ Counterpart of the reference's ``deepspeed/moe/sharded_moe.py`` (TopKGate
     wasteful under jit).
 """
 
+import contextlib
+import contextvars
 import math
 
 import jax
@@ -39,31 +41,40 @@ def _constrain(x, spec):
 
 
 # ------------------------------------------------- grouped expert FFNs
-# The expert-FFN grouped product has two backends: 'ragged' =
-# lax.ragged_dot (the generic-XLA path, and the parity reference) and
-# 'kernel' = the Pallas grouped-GEMM launch (ops/pallas/
-# grouped_matmul.py: per-group tile maps, each expert's weight tile
-# streamed through VMEM once, fused SwiGLU epilogue, per-group fp32 dw).
-# The choice and the tile sizes resolve per shape bucket through the
-# measured-dispatch winner cache (registry op 'moe_grouped_mm') when the
-# knob is "auto" — a cold cache is byte-identical to the ragged program.
+# The expert-FFN grouped product has three backends: 'ragged' =
+# lax.ragged_dot (the generic-XLA path, and the parity reference);
+# 'kernel' = the differentiable Pallas grouped-GEMM launches (ops/pallas/
+# grouped_matmul.py: per-group tile maps, fused SwiGLU epilogue, per-group
+# fp32 dw) at the tiles the knob gives; and 'forward' = the one-launch
+# forward SwiGLU chain whose tiles come from the shape (each touched
+# expert's weights streamed through VMEM once, in megabytes; an expert
+# with no rows costs nothing). "auto" decides between 'ragged' and
+# 'forward' from what it can observe — platform, dtype, shape — and off
+# the TPU is byte-identical to the ragged program.
 
 def resolve_grouped_params(knob, rows, E_loc, M, F, dtype):
     """Trace-time backend/tile resolution for the grouped expert FFN.
-    ``knob``: "auto" (winner cache) | True (kernel, default tiles) |
-    False (ragged_dot) | dict (explicit params)."""
-    from ..ops.pallas.grouped_matmul import TUNE_DEFAULTS
+    ``knob``: "auto" | True (kernel, default tiles) | False (ragged_dot)
+    | dict (explicit params).
+
+    "auto": on a TPU, in a program GSPMD does not partition (a bare
+    Mosaic call is refused there), a call of at most
+    ``FORWARD_ROWS_PER_GROUP`` rows a group — a serving program's decode
+    step or prefill bucket — whose shape forms tiles (``forward_tiles``)
+    takes the forward kernel; everything else is the ragged program."""
+    from ..ops.pallas import grouped_matmul as gm
     if knob is False or knob is None:
-        return dict(TUNE_DEFAULTS)
+        return dict(gm.TUNE_DEFAULTS)
     if knob is True:
-        return dict(TUNE_DEFAULTS, backend="kernel")
+        return dict(gm.TUNE_DEFAULTS, backend="kernel")
     if isinstance(knob, dict):
-        return {**TUNE_DEFAULTS, **knob}
-    from ..ops.pallas._common import (dispatch, dtype_name,
-                                      moe_grouped_bucket)
-    return dispatch("moe_grouped_mm",
-                    moe_grouped_bucket(rows, E_loc, M, F),
-                    dtype_name(dtype), TUNE_DEFAULTS)
+        return {**gm.TUNE_DEFAULTS, **knob}
+    from ..ops.pallas._common import gspmd_partitioned, interpret_default
+    if not interpret_default() and not gspmd_partitioned() \
+            and rows <= gm.FORWARD_ROWS_PER_GROUP * E_loc \
+            and gm.forward_tiles(rows, M, F, dtype) is not None:
+        return dict(gm.TUNE_DEFAULTS, backend="forward")
+    return dict(gm.TUNE_DEFAULTS)
 
 
 def _grouped_dot(xs, w, group_sizes, params):
@@ -76,9 +87,36 @@ def _grouped_dot(xs, w, group_sizes, params):
     return lax.ragged_dot(xs, w, group_sizes)
 
 
+# the tally a ``counting_expert_calls`` block is filling, if any
+_EXPERT_CALLS = contextvars.ContextVar("dstpu_expert_calls", default=None)
+
+
+@contextlib.contextmanager
+def counting_expert_calls():
+    """Yields ``[calls, kernel_calls]``: the expert SwiGLU layer calls
+    traced inside the block, and those of them whose products are a Pallas
+    grouped kernel (``_grouped_swiglu_ffn`` says which). Trace-time Python:
+    a serving engine puts it round a program's traced body, for its
+    dispatch span (expert_calls / expert_kernel_calls)."""
+    counts = [0, 0]
+    token = _EXPERT_CALLS.set(counts)
+    try:
+        yield counts
+    finally:
+        _EXPERT_CALLS.reset(token)
+
+
 def _grouped_swiglu_ffn(xs, w1, w3, w2, group_sizes, params):
     from ..ops.int8_weights import _is_q
-    if _is_q(w1):
+    backend = params.get("backend")
+    quantized = _is_q(w1)
+    int8 = not quantized and bool(params.get("int8"))
+    counts = _EXPERT_CALLS.get()
+    if counts is not None:
+        counts[0] += 1
+        counts[1] += quantized or (not int8
+                                   and backend in ("kernel", "forward"))
+    if quantized:
         # weight-only quantized experts (serving): dequant fused into
         # the grouped kernel's flush epilogue — int8/int4 bytes stream
         # HBM->VMEM, no dequantized (E, K, N) tensor materializes
@@ -87,7 +125,7 @@ def _grouped_swiglu_ffn(xs, w1, w3, w2, group_sizes, params):
                                  block_m=int(params["block_m"]),
                                  block_n=int(params["block_n"]),
                                  block_k=int(params["block_k"]))
-    if params.get("int8"):
+    if int8:
         # dynamic int8 activation x weight compute (autotune lever
         # 'moe_grouped_int8'): per-row activation scales, int32
         # accumulate, straight-through fp backward
@@ -95,12 +133,12 @@ def _grouped_swiglu_ffn(xs, w1, w3, w2, group_sizes, params):
         g = grouped_int8_matmul(xs, w1, group_sizes)
         u = grouped_int8_matmul(xs, w3, group_sizes)
         return grouped_int8_matmul(jax.nn.silu(g) * u, w2, group_sizes)
-    if params.get("backend") == "kernel":
+    if backend in ("kernel", "forward"):
         from ..ops.pallas.grouped_matmul import grouped_swiglu
-        return grouped_swiglu(xs, w1, w3, w2, group_sizes,
-                              block_m=int(params["block_m"]),
-                              block_n=int(params["block_n"]),
-                              block_k=int(params["block_k"]))
+        # 'forward': no block given, the tiles come from the shape
+        blocks = {} if backend == "forward" else {
+            k: int(params[k]) for k in ("block_m", "block_n", "block_k")}
+        return grouped_swiglu(xs, w1, w3, w2, group_sizes, **blocks)
     g = lax.ragged_dot(xs, w1, group_sizes)
     u = lax.ragged_dot(xs, w3, group_sizes)
     return lax.ragged_dot(jax.nn.silu(g) * u, w2, group_sizes)
@@ -424,8 +462,7 @@ def moe_swiglu_ragged_ep(tokens, gate_w, w1, w3, w2, k=2, *,
     :func:`route_topk`'s softmax-then-top-k combine weights
     (``renormalize``: mixtral yes, olmoe no). The expert product
     runs the Pallas grouped kernel or ``lax.ragged_dot`` per the
-    ``grouped_kernel`` knob ("auto" = the 'moe_grouped_mm' winner cache;
-    a cold cache keeps the ragged program).
+    ``grouped_kernel`` knob ("auto": :func:`resolve_grouped_params`).
 
     Exists because GSPMD cannot partition ``lax.ragged_dot`` over the
     expert (group) dim of the weights: with moe_w* sharded

@@ -239,13 +239,16 @@ SPAN_SCHEMA = {
         "meaning": "one request admitted: pool check passed -> queued "
                    "for chunks or through its bucketed prefill"},
     "dstpu.engine.prefill": {
-        "stats": ("uid", "tokens", "padded"),
+        "stats": ("uid", "tokens", "padded", "expert_calls",
+                  "expert_kernel_calls"),
         "meaning": "bucketed prefill of one request: arrays, program "
-                   "call, blocking read of its token"},
+                   "call, blocking read of its token; expert_calls / "
+                   "expert_kernel_calls as on dstpu.engine.dispatch"},
     "dstpu.engine.dispatch": {
         "stats": ("kind", "active", "slots", "steps", "chunk_tokens",
                   "grid_steps", "table_entries", "write_rows",
-                  "write_rows_offered"),
+                  "write_rows_offered", "expert_calls",
+                  "expert_kernel_calls"),
         "meaning": "one decode-bearing or chunk program call (kind "
                    "decode | fused | chunk | spec | offload) from the "
                    "assembled batch to the last posted token; grid_steps "
@@ -255,7 +258,12 @@ SPAN_SCHEMA = {
                    "live rows (destination not scratch block 0) among "
                    "those one layer's KV writes are handed: slots x "
                    "steps, and a chunk's chunk_tokens of its C (0 / 0 on "
-                   "a spec round)"},
+                   "a spec round); expert_kernel_calls of expert_calls = "
+                   "the expert layer calls (MoE layers x steps) of the "
+                   "dispatch's program whose products are a Pallas "
+                   "grouped kernel, of all: noted when the program is "
+                   "traced, so 0 / 0 on a dense model and on the dispatch "
+                   "that traces it"},
     "dstpu.engine.build": {
         "stats": (),
         "meaning": "leaf: host work before a program call (decode "

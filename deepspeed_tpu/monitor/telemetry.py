@@ -831,6 +831,10 @@ class ServingTelemetry:
         # over every dispatch (chunk-only ones too)
         self._write_rows = 0
         self._write_rows_offered = 0
+        # expert layer calls of every program call, and those of them
+        # whose products were a Pallas grouped kernel
+        self._expert_calls = 0
+        self._expert_kernel_calls = 0
         # bytes of cache the live sequences hold (blocks under their
         # tables, and whatever the model keeps a slot) against the tokens
         # they have seen, summed over the engine's steps
@@ -908,6 +912,13 @@ class ServingTelemetry:
         takes against those its one-step-a-row grid took."""
         self._write_rows += live
         self._write_rows_offered += offered
+
+    def on_expert_calls(self, calls, kernel):
+        """One program call whose trace made ``calls`` expert layer calls
+        (MoE layers x steps), ``kernel`` of them through a Pallas grouped
+        kernel and the rest through ``lax.ragged_dot``."""
+        self._expert_calls += calls
+        self._expert_kernel_calls += kernel
 
     def on_cache_held(self, cache_bytes, live_tokens):
         """One engine step began with ``cache_bytes`` of cache held by
@@ -1061,6 +1072,9 @@ class ServingTelemetry:
         if self._write_rows_offered:
             out["kv_write_live_share"] = round(
                 self._write_rows / self._write_rows_offered, 4)
+        if self._expert_calls:
+            out["moe_kernel_share"] = round(
+                self._expert_kernel_calls / self._expert_calls, 4)
         if self._live_tokens:
             out["cache_bytes_per_live_token"] = round(
                 self._cache_bytes / self._live_tokens)

@@ -196,6 +196,24 @@ def dividing_axes(n, axes):
     return axes if n % size == 0 else None
 
 
+def _auto_axes():
+    """(ambient mesh, its auto axes): the axes GSPMD partitions over."""
+    from jax.sharding import AxisType
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh, () if mesh.empty else tuple(
+        a for a, t in zip(mesh.axis_names, mesh.axis_types)
+        if t == AxisType.Auto)
+
+
+def gspmd_partitioned():
+    """Whether GSPMD partitions the program being traced: an ambient mesh
+    with an auto axis of size > 1. A bare Mosaic call is refused there
+    (``shard_kernel``). False with no mesh, on one device, and inside a
+    fully-manual region."""
+    mesh, auto = _auto_axes()
+    return any(mesh.shape[a] > 1 for a in auto)
+
+
 def shard_kernel(fn, in_specs, out_specs):
     """``fn`` — a function that runs Pallas kernels — made legal in a
     GSPMD-partitioned program. A Mosaic custom call cannot be partitioned
@@ -204,15 +222,11 @@ def shard_kernel(fn, in_specs, out_specs):
     ambient mesh with auto axes of size > 1 the call runs per shard over
     those axes; with no mesh, one device, or inside a fully-manual region
     it is ``fn`` itself."""
-    from jax.sharding import AxisType
-    mesh = jax.sharding.get_abstract_mesh()
-    auto = () if mesh.empty else tuple(
-        a for a, t in zip(mesh.axis_names, mesh.axis_types)
-        if t == AxisType.Auto)
-    if all(mesh.shape[a] == 1 for a in auto):
+    if not gspmd_partitioned():
         return fn
     return jax.shard_map(fn, in_specs=in_specs, out_specs=out_specs,
-                         axis_names=frozenset(auto), check_vma=False)
+                         axis_names=frozenset(_auto_axes()[1]),
+                         check_vma=False)
 
 
 def round_up(n, m):

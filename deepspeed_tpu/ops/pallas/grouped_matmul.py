@@ -6,7 +6,8 @@ Counterpart of the reference's CUTLASS grouped ``moe_gemm``
 rows are sorted by routed expert and ``group_sizes[e]`` rows multiply
 expert ``e``'s weight block. The XLA path for this is ``lax.ragged_dot``
 — one op per projection, each re-streaming the full (E, K, N) weight
-tensor and re-deciding tiling generically. These kernels own the whole
+tensor and re-deciding tiling generically (on a v5e it streams OLMoE's
+experts at 54-57 % of the HBM peak; PERF.md). These kernels own the whole
 grouped product in ONE launch:
 
   * the row dimension is cut into m-tiles and each tile is assigned to
@@ -20,6 +21,12 @@ grouped product in ONE launch:
     sharing one streamed activation tile and the silu*mul epilogue
     applied in-register (the g/u intermediates never hit HBM
     separately);
+  * its forward-only form (``grouped_swiglu`` with no block given) is the
+    serving programs': the three products in one launch whose tiles come
+    from the shape (``forward_tiles``: whole contraction, megabytes of
+    weights a step), over a grid of visits that holds groups with rows
+    only (``forward_visits``) — each touched expert's weights cross
+    HBM -> VMEM once and an expert no row chose costs nothing;
   * the backward accumulates dw PER GROUP in fp32 (``_tgmm``: out block
     keyed by group id, row-masked accumulation over the group's
     m-tiles, weight-dtype cast fused in the epilogue) and emits dx
@@ -31,11 +38,11 @@ contract — MoE transport padding relies on it). Off-TPU the kernels run
 in Pallas interpreter mode; shapes whose dims cannot form tile-aligned
 blocks fall back to ``lax.ragged_dot`` with identical semantics.
 
-The kernel-vs-ragged choice and the tile sizes are autotunable: the MoE
-layers resolve ``"auto"`` against the persistent winner cache (registry
-op ``"moe_grouped_mm"``, bucketed by tokens-per-shard | experts | model
-dims) with ``TUNE_DEFAULTS`` — backend ``"ragged"`` — on a cold cache,
-so a miss is byte-identical to the pre-kernel program.
+Which of them a model's ``grouped_kernel="auto"`` takes is decided from
+platform, dtype and shape in ``moe/sharded_moe.py:resolve_grouped_params``
+(off the TPU: the ragged program, byte for byte); ``TUNE_DEFAULTS`` is
+what an explicit knob starts from, and the baseline of the autotune
+registry's op ``"moe_grouped_mm"``, which no model asks any more.
 """
 
 import functools
@@ -50,9 +57,9 @@ from ._common import interpret_default as _interpret_default
 from ._common import round_up as _round_up
 from ._common import sds as _sds
 
-# cold-cache dispatch default: the XLA ragged_dot path (current
-# behavior); the kernel backend and its tile sweep are the measured
-# candidates (autotuning/kernel_registry.py 'moe_grouped_mm')
+# what an explicit knob starts from: the XLA ragged_dot path, and the
+# tiles of the differentiable kernels (the autotune registry's baseline
+# for 'moe_grouped_mm', autotuning/kernel_registry.py)
 TUNE_DEFAULTS = {"backend": "ragged",
                  "block_m": 128, "block_n": 128, "block_k": 128}
 
@@ -301,6 +308,214 @@ def _tgmm(x, dy, group_sizes, E, *, tm, tn, tk, out_dtype, interpret):
     )(gids, mtids, starts, ends, num, x, dy)
 
 
+# ------------------------------------- forward SwiGLU, tiles from the shape
+# A serving program takes no gradient and its groups are small: a decode
+# step of OLMoE is 256 routed rows over 64 experts, and what it costs is
+# the touched experts' weights crossing HBM -> VMEM. The forward chain below
+# is ONE launch that streams them once, in tiles of megabytes chosen from
+# the shape, and visits no expert that has no rows.
+
+# what the tiles of one launch may fill of a v5e core's 128 MiB of VMEM:
+# the budget ``forward_tiles`` chooses under and the limit the launch is
+# given (Mosaic's default scoped limit, 16 MiB, holds no two tiles of
+# megabytes)
+FORWARD_VMEM_BYTES = 48 << 20
+_VMEM_SLACK = 4 << 20
+
+# the most rows a group at which "auto" takes the forward kernel: the
+# largest the chip has timed it at, and it won at every one below (PERF.md,
+# PR 32: kernel alone, OLMoE's widths, 4 ... 512 rows a group)
+FORWARD_ROWS_PER_GROUP = 512
+
+
+def forward_tiles(rows, D, F, dtype, vmem_bytes=FORWARD_VMEM_BYTES):
+    """(tm, tf, tk) of the forward SwiGLU chain x (rows, D) -> (rows, F)
+    -> (rows, D) from its shape alone, or None where none forms (the
+    caller's ragged products then).
+
+    ``tk`` is D whole: a visit contracts in one step and keeps no partial
+    sum across steps of the up products. ``tf`` is the widest slice of F
+    (a multiple of 128 that divides it) whose three weight tiles — w1 and
+    w3 (D, tf), w2 (tf, D) — fit ``vmem_bytes`` double-buffered beside the
+    row tiles and the float32 intermediates. ``tm`` is the rows rounded to
+    the dtype's sublane multiple, 128 at most: a masked product over more
+    rows than that takes the MXU longer than its weights take to arrive."""
+    dt = jnp.dtype(dtype)
+    if dt not in (jnp.bfloat16, jnp.float32) or D % 128 or F % 128 \
+            or rows < 1:
+        return None
+    tm = min(128, _round_up(rows, 32 // dt.itemsize))
+    # x and out row tiles (double-buffered) and the float32 accumulator
+    fixed = tm * D * (4 * dt.itemsize + 4)
+    # a column of the slice: three weight tiles twice, g / u / h in float32
+    per_column = 6 * D * dt.itemsize + 12 * tm
+    tf = _pick_block(F, (vmem_bytes - fixed - _VMEM_SLACK) // per_column)
+    return None if tf is None else (tm, tf, D)
+
+
+def _running_sum(x):
+    """Inclusive running sum of a short int32 vector as one compare and
+    one sum: a single fusion on the TPU, where ``cumsum`` is a
+    reduce-window between two copies."""
+    i = jnp.arange(x.shape[0], dtype=jnp.int32)
+    return jnp.sum(jnp.where(i[None, :] <= i[:, None], x[None, :], 0),
+                   axis=1, dtype=jnp.int32)
+
+
+def forward_visits(group_sizes, m_pad, tm):
+    """The forward launch's grid, as data: one visit for every (group that
+    has rows, row tile its rows touch) pair and none for an empty group —
+    ``lax.ragged_dot`` reads no weight of an expert no row chose, and
+    neither does this (``_group_metadata`` keeps one masked visit for an
+    empty group, which ``_tgmm`` needs to write its dw block and a forward
+    pays 3 x D x F weights for).
+
+    Returns ``(wid, mtid, lo, hi, n)``: visit i multiplies row tile
+    ``mtid[i]`` by expert ``wid[i]``'s weights and keeps rows ``lo[i] <=
+    row < hi[i]``; ``n`` visits, of a static ``m_pad // tm + E`` at most.
+    The rows past ``sum(group_sizes)`` are a last group of their own with
+    nothing to keep (``lo = hi = 0``: its visits multiply nothing) on the
+    weights of the last group that has rows, so their tiles are written —
+    as zeros — and nothing is fetched for them. A few compares and sums
+    over (visits, groups), non-negative integers throughout: a ``repeat``,
+    a ``searchsorted`` or a gather here is a loop on the TPU (PERF.md,
+    PR 27), and every operation is one a layer call makes."""
+    E = group_sizes.shape[0]
+    tiles_m = m_pad // tm
+    sizes = group_sizes.astype(jnp.int32)
+    # entry E: the rows past the groups, up to m_pad
+    sizes = jnp.concatenate([sizes, m_pad - jnp.sum(sizes, keepdims=True)])
+    ends = _running_sum(sizes)
+    starts = ends - sizes
+    live = sizes > 0
+    first = lax.div(starts, tm)
+    tiles_per = jnp.where(live, lax.div(ends - 1, tm) - first + 1, 0)
+    cum = _running_sum(tiles_per)
+    entry = jnp.arange(E + 1, dtype=jnp.int32)
+    item = jnp.arange(tiles_m + E, dtype=jnp.int32)
+    # visit i belongs to the first entry whose running sum passes i
+    gid = jnp.sum(cum[None, :] <= item[:, None], axis=1, dtype=jnp.int32)
+    mine = gid[:, None] == entry[None, :]
+
+    def of_visit(per_entry):
+        return jnp.sum(jnp.where(mine, per_entry[None, :], 0), axis=1,
+                       dtype=jnp.int32)
+
+    real = entry < E
+    mtid = jnp.clip(item + of_visit(first - (cum - tiles_per)),
+                    0, tiles_m - 1)
+    last = jnp.max(jnp.where(live & real, entry, 0))
+    return (jnp.minimum(gid, last), mtid,
+            of_visit(jnp.where(real, starts, 0)),
+            of_visit(jnp.where(real, ends, 0)), cum[-1])
+
+
+def _swiglu_forward_kernel(wid_ref, mtid_ref, lo_ref, hi_ref,
+                           x_ref, w1_ref, w3_ref, w2_ref, o_ref, acc, *,
+                           tm, nf):
+    """Grid step (i, f): visit i's row tile against slice f of its
+    expert's F. Visits of a row tile are consecutive, so its x and o tiles
+    stay resident from its first visit to its last."""
+    i = pl.program_id(0)
+    f = pl.program_id(1)
+    mt = mtid_ref[i]
+    lo, hi = lo_ref[i], hi_ref[i]
+
+    @pl.when(hi > lo)
+    def _products():
+        x = x_ref[...]
+        dims = (((1,), (0,)), ((), ()))
+        g = lax.dot_general(x, w1_ref[0], dims,
+                            preferred_element_type=jnp.float32)
+        u = lax.dot_general(x, w3_ref[0], dims,
+                            preferred_element_type=jnp.float32)
+        # silu * mul in float32, one round to the down product's operand
+        h = ((g * jax.nn.sigmoid(g)) * u).astype(x.dtype)
+        part = lax.dot_general(h, w2_ref[0], dims,
+                               preferred_element_type=jnp.float32)
+
+        if nf == 1:
+            acc[...] = part
+        else:
+            @pl.when(f == 0)
+            def _first():
+                acc[...] = part
+
+            @pl.when(f > 0)
+            def _rest():
+                acc[...] += part
+
+    @pl.when(f == nf - 1)
+    def _write():
+        # the visit's rows; the others keep what an earlier visit of the
+        # tile wrote, or are zero on the tile's first visit
+        rows = mt * tm + lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+        prev_mt = jnp.where(i == 0, -1, mtid_ref[jnp.maximum(i - 1, 0)])
+        prev = jnp.where(mt != prev_mt, jnp.zeros_like(o_ref[...]),
+                         o_ref[...])
+        o_ref[...] = jnp.where((rows >= lo) & (rows < hi),
+                               acc[...].astype(o_ref.dtype), prev)
+
+
+@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
+def _swiglu_forward(x, w1, w3, w2, group_sizes, *, tiles, interpret):
+    """The whole chain in one launch: a visit fetches its expert's w1, w3
+    and w2 slices side by side, the row tile crosses them with ``o``
+    accumulated in VMEM over the slices of F, and g, u and h never leave
+    it. Jitted on its own: a serving program makes one call a layer and
+    step (96 in OLMoE's decode dispatch), and an inner jit is traced once
+    a shape and lowered once a program."""
+    tm, tf, _ = tiles
+    x, M = _pad_rows(x, tm)
+    Mp, D = x.shape
+    F, Do = w2.shape[1:]
+    *maps, n = forward_visits(group_sizes, Mp, tm)
+    w_up = pl.BlockSpec((1, D, tf), lambda i, f, wid, *_: (wid[i], 0, f))
+    row = lambda i, f, wid, mtid, *_: (mtid[i], 0)
+    out = pl.pallas_call(
+        functools.partial(_swiglu_forward_kernel, tm=tm, nf=F // tf),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n, F // tf),
+            in_specs=[pl.BlockSpec((tm, D), row), w_up, w_up,
+                      pl.BlockSpec((1, tf, Do),
+                                   lambda i, f, wid, *_: (wid[i], f, 0))],
+            out_specs=pl.BlockSpec((tm, Do), row),
+            scratch_shapes=[pltpu.VMEM((tm, Do), jnp.float32)],
+        ),
+        out_shape=_sds((Mp, Do), x.dtype, x),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=FORWARD_VMEM_BYTES),
+        interpret=interpret,
+    )(*maps, x, w1, w3, w2)
+    return out[:M]
+
+
+def _ragged_swiglu(x, w1, w3, w2, group_sizes):
+    g = lax.ragged_dot(x, w1, group_sizes)
+    u = lax.ragged_dot(x, w3, group_sizes)
+    return lax.ragged_dot(jax.nn.silu(g) * u, w2, group_sizes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _swiglu_forward_diff(x, w1, w3, w2, group_sizes, tiles, interpret):
+    """The forward launch wherever no gradient is taken; a differentiated
+    call is ``lax.ragged_dot``'s program, forward and backward (the chip
+    has never timed a backward of these kernels)."""
+    return _swiglu_forward(x, w1, w3, w2, group_sizes, tiles=tiles,
+                           interpret=interpret)
+
+
+def _swiglu_forward_diff_fwd(x, w1, w3, w2, group_sizes, tiles, interpret):
+    return jax.vjp(lambda *ops: _ragged_swiglu(*ops, group_sizes),
+                   x, w1, w3, w2)
+
+
+_swiglu_forward_diff.defvjp(
+    _swiglu_forward_diff_fwd, lambda tiles, interpret, vjp, dy:
+    (*vjp(dy), None))
+
+
 # ---------------------------------------------------------------- public
 def _blocks_fit(M, K, N, bm, bn, bk):
     """Resolve (tm, tn, tk) or None — K/N must form 128-aligned divisor
@@ -424,13 +639,19 @@ def _swiglu_diff_bwd(tm, tn, tk, interpret, res, dy):
 _swiglu_diff.defvjp(_swiglu_diff_fwd, _swiglu_diff_bwd)
 
 
-def grouped_swiglu(x, w1, w3, w2, group_sizes, *, block_m=128,
-                   block_n=128, block_k=128, interpret=None):
+def grouped_swiglu(x, w1, w3, w2, group_sizes, *, block_m=None,
+                   block_n=None, block_k=None, interpret=None):
     """The whole SwiGLU expert chain as grouped kernels:
-    ``gmm(silu(gmm(x, w1)) * gmm(x, w3), w2)`` with the gate/up products
-    fused into one launch (shared x tiles, in-register silu*mul
-    epilogue). x (S, K); w1/w3 (E, K, F); w2 (E, F, K'); -> (S, K').
-    Same fallback/zero-tail/backward contract as ``grouped_matmul``.
+    ``gmm(silu(gmm(x, w1)) * gmm(x, w3), w2)``. x (S, K); w1/w3 (E, K, F);
+    w2 (E, F, K'); -> (S, K'); rows beyond ``sum(group_sizes)`` are zero.
+
+    With no block given it is the forward launch with tiles from the shape
+    (``forward_tiles``; non-empty groups only; a differentiated call is
+    ``lax.ragged_dot``'s program). With any block given (the others 128)
+    it is the differentiable pair of launches — gate/up fused (shared x
+    tiles, in-register silu*mul epilogue), then down — with the grouped
+    backward. Shapes whose dims cannot form tile-aligned blocks fall back
+    to ``lax.ragged_dot`` with identical math.
     """
     E, K, F = w1.shape
     if x.ndim != 2 or x.shape[1] != K or w3.shape != w1.shape or \
@@ -438,19 +659,26 @@ def grouped_swiglu(x, w1, w3, w2, group_sizes, *, block_m=128,
         raise ValueError(
             f"grouped_swiglu shape mismatch: x {x.shape}, w1 {w1.shape}, "
             f"w3 {w3.shape}, w2 {w2.shape}")
+    if interpret is None:
+        interpret = _interpret_default()
+    gs = group_sizes.astype(jnp.int32)
+    if block_m is None and block_n is None and block_k is None:
+        tiles = forward_tiles(x.shape[0], K, F, x.dtype) \
+            if w2.shape[2] == K else None
+        if tiles is None:
+            return _ragged_swiglu(x, w1, w3, w2, group_sizes)
+        return _swiglu_forward_diff(x, w1, w3, w2, gs, tiles,
+                                    bool(interpret))
+    block_m, block_n, block_k = (128 if b is None else b
+                                 for b in (block_m, block_n, block_k))
     fit = _blocks_fit(x.shape[0], K, F, block_m, block_n, block_k)
     # the down projection re-uses the same tiles with roles swapped, so
     # its output dim (w2's last) must form blocks too
     fit_dn = fit and _pick_block(w2.shape[2], block_k)
     if fit is None or fit_dn is None or fit_dn != fit[2]:
-        g = lax.ragged_dot(x, w1, group_sizes)
-        u = lax.ragged_dot(x, w3, group_sizes)
-        return lax.ragged_dot(jax.nn.silu(g) * u, w2, group_sizes)
+        return _ragged_swiglu(x, w1, w3, w2, group_sizes)
     tm, tn, tk = fit
-    if interpret is None:
-        interpret = _interpret_default()
-    return _swiglu_diff(x, w1, w3, w2, group_sizes.astype(jnp.int32),
-                        tm, tn, tk, bool(interpret))
+    return _swiglu_diff(x, w1, w3, w2, gs, tm, tn, tk, bool(interpret))
 
 
 # ----------------------------------------- weight-only quantized forward

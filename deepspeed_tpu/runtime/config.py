@@ -497,12 +497,15 @@ class MoEConfig:
     ``grouped_kernel`` here overrides the model-config knob):
 
       grouped_kernel   expert-FFN engine for the ragged (dropless)
-                       paths: "auto" (default — resolve kernel-vs-
-                       ragged_dot and tile sizes per shape bucket from
-                       the 'moe_grouped_mm' autotune winner cache; a
-                       cold cache keeps the lax.ragged_dot program
-                       byte-identical) | true (Pallas grouped-GEMM
-                       kernel, default tiles) | false (ragged_dot).
+                       paths: "auto" (default — from platform, dtype
+                       and shape: on a TPU a SwiGLU call of few rows a
+                       group, a decode step or a prefill bucket, takes
+                       the forward grouped kernel with tiles chosen
+                       from the shape; everything else, and every call
+                       off the TPU, the lax.ragged_dot program:
+                       sharded_moe.resolve_grouped_params) | true
+                       (Pallas grouped-GEMM kernel, default tiles) |
+                       false (ragged_dot).
       hierarchical_a2a "auto" (default — the EP all_to_all stages
                        ICI -> DCN iff the mesh has a data_outer axis
                        > 1 and the experts divide the combined

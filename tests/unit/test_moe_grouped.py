@@ -22,9 +22,9 @@ from deepspeed_tpu.autotuning import kernel_dispatch
 from deepspeed_tpu.moe.sharded_moe import (moe_swiglu_ragged_ep,
                                            resolve_grouped_params,
                                            resolve_hierarchical_a2a)
-from deepspeed_tpu.ops.pallas.grouped_matmul import (TUNE_DEFAULTS,
-                                                     grouped_matmul,
-                                                     grouped_swiglu)
+from deepspeed_tpu.ops.pallas.grouped_matmul import (
+    FORWARD_ROWS_PER_GROUP, FORWARD_VMEM_BYTES, TUNE_DEFAULTS,
+    forward_tiles, forward_visits, grouped_matmul, grouped_swiglu)
 from deepspeed_tpu.utils import groups
 from deepspeed_tpu.utils.groups import TopologyConfig
 
@@ -140,23 +140,182 @@ class TestGroupedKernelParity:
             np.asarray(jax.lax.ragged_dot(x, w, gs)), rtol=1e-6)
 
 
+def _decode_shaped(rows, sizes, E=64, D=128, F=256, dtype=jnp.float32,
+                   seed=0):
+    rs = np.random.RandomState(seed)
+    gs = np.zeros((E,), np.int32)
+    for g, n in sizes.items():
+        gs[g] = n
+    assert gs.sum() <= rows
+    mk = lambda *shape: jnp.asarray(rs.randn(*shape) * 0.1, dtype)
+    return (mk(rows, D) * 3, mk(E, D, F), mk(E, D, F), mk(E, F, D),
+            jnp.asarray(gs))
+
+
+def _spread(rows, E=64):
+    """``rows`` rows over E groups as evenly as they go (4 a group at a
+    decode step's 256 over 64)."""
+    return {g: rows // E + (g < rows % E) for g in range(E)}
+
+
+class TestForwardKernel:
+    """``grouped_swiglu`` with no block given: the one-launch forward
+    chain, tiles from the shape, non-empty groups only (interpreter mode;
+    tests/unit/test_tpu_compile.py compiles it for the chip)."""
+
+    @pytest.mark.parametrize("case, rows, sizes", [
+        ("rows8", 8, {3: 5, 60: 3}),
+        ("rows16_one_a_group", 16, {g: 1 for g in range(0, 64, 4)}),
+        ("rows64", 64, _spread(64)),
+        ("rows256_even", 256, _spread(256)),
+        ("rows256_empty_at_the_start", 256,
+         {g: 8 for g in range(32, 64)}),
+        ("rows256_empty_in_the_middle", 256,
+         {**{g: 8 for g in range(16)}, **{g: 8 for g in range(48, 64)}}),
+        ("rows256_empty_at_the_end", 256, {g: 8 for g in range(32)}),
+        ("rows256_one_group", 256, {17: 256}),
+        ("rows256_dead_slots", 256,
+         {**{g: 28 for g in range(5, 64, 8)}, **{g: 1 for g in range(32)}}),
+        ("rows256_tail_past_the_groups", 256, {0: 3, 9: 120, 63: 20}),
+        ("rows200_padded_to_the_tile", 200, _spread(190)),
+        ("rows256_no_rows_at_all", 256, {}),
+    ])
+    def test_decode_shapes_match_ragged_dot(self, case, rows, sizes):
+        x, w1, w3, w2, gs = _decode_shaped(rows, sizes)
+        got = np.asarray(jax.jit(grouped_swiglu)(x, w1, w3, w2, gs))
+        want = np.asarray(_swiglu_ref(x, w1, w3, w2, gs))
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+        assert not got[int(gs.sum()):].any()
+
+    @pytest.mark.parametrize("rows", [64, 256])
+    def test_bf16_matches_ragged_dot(self, rows):
+        x, w1, w3, w2, gs = _decode_shaped(rows, _spread(rows - 9),
+                                           dtype=jnp.bfloat16)
+        got = grouped_swiglu(x, w1, w3, w2, gs)
+        assert got.dtype == jnp.bfloat16 and got.shape == x.shape
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32),
+            np.asarray(_swiglu_ref(x, w1, w3, w2, gs), np.float32),
+            rtol=5e-2, atol=5e-2)
+
+    def test_slices_of_f_accumulate(self):
+        """A VMEM limit that holds half of F a step: two slices a visit,
+        summed in the float32 accumulator."""
+        from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+        x, w1, w3, w2, gs = _decode_shaped(64, _spread(60))
+        tiles = forward_tiles(64, 128, 256, jnp.float32,
+                              vmem_bytes=(4 << 20) + 700_000)
+        assert tiles == (64, 128, 128)
+        got = gm._swiglu_forward(x, w1, w3, w2, gs, tiles=tiles,
+                                 interpret=True)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(_swiglu_ref(x, w1, w3, w2, gs)),
+            rtol=1e-4, atol=1e-4)
+
+    def test_a_differentiated_call_is_the_ragged_program(self):
+        """No backward of the forward launch exists: under jax.grad the
+        call is lax.ragged_dot's, forward and backward."""
+        x, w1, w3, w2, gs = _decode_shaped(64, _spread(64, 8), E=8)
+        loss = lambda f: lambda *a: jnp.sum(f(*a, gs) ** 2)
+        ga = jax.grad(loss(grouped_swiglu), (0, 1, 2, 3))(x, w1, w3, w2)
+        gr = jax.grad(loss(_swiglu_ref), (0, 1, 2, 3))(x, w1, w3, w2)
+        for a, b in zip(ga, gr):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        text = str(jax.make_jaxpr(jax.grad(loss(grouped_swiglu)))(
+            x, w1, w3, w2))
+        assert "ragged_dot" in text and "pallas_call" not in text
+        assert "pallas_call" in str(jax.make_jaxpr(loss(grouped_swiglu))(
+            x, w1, w3, w2))
+
+    @pytest.mark.parametrize("case, rows, D, F, dtype, want", [
+        ("olmoe_decode", 256, 2048, 1024, jnp.bfloat16, (128, 1024, 2048)),
+        ("olmoe_one_slot", 8, 2048, 1024, jnp.bfloat16, (16, 1024, 2048)),
+        ("olmoe_prefill", 8192, 2048, 1024, jnp.bfloat16,
+         (128, 1024, 2048)),
+        ("mixtral_decode", 64, 4096, 14336, jnp.bfloat16, (64, 512, 4096)),
+        ("float32_rows", 20, 128, 256, jnp.float32, (24, 256, 128)),
+        ("f_not_a_lane_multiple", 256, 2048, 1000, jnp.bfloat16, None),
+        ("d_not_a_lane_multiple", 256, 100, 1024, jnp.bfloat16, None),
+        ("tiny", 12, 16, 24, jnp.float32, None),
+        ("float16", 256, 2048, 1024, jnp.float16, None),
+        ("no_slice_fits", 256, 2048, 1024, jnp.bfloat16, None),
+    ])
+    def test_tiles_from_the_shape(self, case, rows, D, F, dtype, want):
+        """Whole contraction, the widest slice of F that fits the VMEM
+        limit double-buffered, rows to the sublane multiple and 128 at
+        most: megabytes a weight tile at published widths; None (the
+        ragged products) where no tile forms."""
+        limit = (5 << 20) if case == "no_slice_fits" else FORWARD_VMEM_BYTES
+        tiles = forward_tiles(rows, D, F, dtype, limit)
+        assert tiles == want
+        if want is not None and D >= 2048:
+            tm, tf, tk = tiles
+            item = jnp.dtype(dtype).itemsize
+            assert tk * tf * item >= 1 << 20        # a weight tile
+            held = 2 * 3 * tk * tf * item + 2 * 2 * tm * D * item \
+                + 4 * tm * (D + 3 * tf)
+            assert held <= FORWARD_VMEM_BYTES
+
+    @pytest.mark.parametrize("case, rows, tm, sizes, visits", [
+        # groups with rows + those that straddle a row tile's edge + the
+        # row tiles that hold rows past the groups
+        ("even_4_a_group", 256, 128, _spread(256), 64),
+        ("a_straddle", 256, 128, {0: 100, 1: 56, 2: 100}, 4),
+        ("empty_groups_cost_nothing", 256, 128, {5: 3, 40: 2}, 2 + 2),
+        ("one_group_two_tiles", 256, 128, {17: 256}, 2),
+        ("small_tiles_more_straddles", 256, 32, {0: 100, 1: 56, 2: 100},
+         4 + 2 + 4),
+        ("untouched_tiles_are_zeroed", 512, 128, {3: 10}, 1 + 4),
+        ("nothing_routed", 256, 128, {}, 2),
+    ])
+    def test_visit_list_holds_non_empty_groups_only(self, case, rows, tm,
+                                                    sizes, visits):
+        gs = np.zeros((64,), np.int32)
+        for g, n in sizes.items():
+            gs[g] = n
+        wid, mtid, lo, hi, n = map(np.asarray, forward_visits(
+            jnp.asarray(gs), rows, tm))
+        assert n == visits and len(wid) == rows // tm + 64
+        ends = np.cumsum(gs)
+        kept = 0
+        for i in range(n):
+            if hi[i] > lo[i]:               # a group's visit of a tile
+                g = wid[i]
+                assert gs[g] > 0 and (lo[i], hi[i]) == (ends[g] - gs[g],
+                                                        ends[g])
+                a, b = max(lo[i], mtid[i] * tm), min(hi[i],
+                                                     (mtid[i] + 1) * tm)
+                assert b > a                # it keeps rows of that tile
+                kept += b - a
+            else:                   # a tile with rows past the groups
+                assert (mtid[i] + 1) * tm > ends[-1]
+                assert wid[i] == max([g for g in sizes if sizes[g]] or [0])
+        assert kept == gs.sum()
+        # visits of a row tile are consecutive, tiles in order
+        assert (np.diff(mtid[:n]) >= 0).all()
+        assert set(mtid[:n]) == set(range(rows // tm))
+
+
 class TestGroupedDispatch:
-    """The 'moe_grouped_mm' knob/winner-cache contract."""
+    """The ``grouped_kernel`` knob: True / False / a dict force a path,
+    "auto" decides from platform, dtype and shape."""
 
     def test_knob_resolution(self):
         assert resolve_grouped_params(False, 256, 4, 128, 256,
                                       jnp.float32)["backend"] == "ragged"
         p = resolve_grouped_params(True, 256, 4, 128, 256, jnp.float32)
         assert p["backend"] == "kernel"
-        # "auto" on a cold cache = the ragged defaults (current behavior)
-        kernel_dispatch.configure(mode="cache_only")
+        p = resolve_grouped_params({"backend": "kernel", "block_m": 64},
+                                   256, 4, 128, 256, jnp.float32)
+        assert p == dict(TUNE_DEFAULTS, backend="kernel", block_m=64)
+        # "auto" off the TPU = the ragged defaults
         assert resolve_grouped_params("auto", 256, 4, 128, 256,
                                       jnp.float32) == TUNE_DEFAULTS
 
-    def test_warm_cache_steers_auto(self):
-        """A cached kernel winner flips the "auto" resolution — proven
-        at the jaxpr level (the kernel program contains a pallas call,
-        the ragged program contains ragged_dot)."""
+    def test_warm_cache_no_longer_steers_auto(self):
+        """The 'moe_grouped_mm' winner cache is not asked any more: a
+        cached kernel winner for the very bucket leaves "auto" where
+        platform, dtype and shape put it (off the TPU: ragged)."""
         from deepspeed_tpu.autotuning import KernelCache
         from deepspeed_tpu.ops.pallas._common import moe_grouped_bucket
         path = os.environ["DSTPU_AUTOTUNE_CACHE"]
@@ -168,13 +327,49 @@ class TestGroupedDispatch:
                           "block_n": 128, "block_k": 128})
         c.save(path)
         kernel_dispatch.configure(mode="cache_only")
-        p = resolve_grouped_params("auto", S, E, M, F, jnp.float32)
-        assert p["backend"] == "kernel" and p["block_m"] == 64
+        assert resolve_grouped_params("auto", S, E, M, F,
+                                      jnp.float32) == TUNE_DEFAULTS
+
+    @pytest.mark.parametrize("case, rows, E, D, F, dtype, want", [
+        ("olmoe_decode", 256, 64, 2048, 1024, jnp.bfloat16, "forward"),
+        ("olmoe_prefill_1024", 8192, 64, 2048, 1024, jnp.bfloat16,
+         "forward"),
+        ("mixtral_decode", 64, 8, 4096, 14336, jnp.bfloat16, "forward"),
+        ("float32", 256, 64, 2048, 1024, jnp.float32, "forward"),
+        ("at_the_threshold", FORWARD_ROWS_PER_GROUP * 64, 64, 2048, 1024,
+         jnp.bfloat16, "forward"),
+        ("past_the_threshold", FORWARD_ROWS_PER_GROUP * 64 + 8, 64, 2048,
+         1024, jnp.bfloat16, "ragged"),
+        ("float16", 256, 64, 2048, 1024, jnp.float16, "ragged"),
+        ("no_tile", 12, 2, 16, 24, jnp.float32, "ragged"),
+        ("off_tpu", 256, 64, 2048, 1024, jnp.bfloat16, "ragged"),
+        ("partitioned", 256, 64, 2048, 1024, jnp.bfloat16, "ragged"),
+    ])
+    def test_auto_decides_from_platform_dtype_and_shape(
+            self, monkeypatch, case, rows, E, D, F, dtype, want):
+        """On a TPU a SwiGLU call of at most FORWARD_ROWS_PER_GROUP rows
+        a group whose shape tiles takes the forward kernel (the explicit
+        knob's 128 tiles stay in the dict for the quantised experts);
+        another dtype, a shape that forms no tile, more rows a group, a
+        program GSPMD partitions, and every call off the TPU are the
+        ragged program."""
+        if case != "off_tpu":
+            monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        if case == "partitioned":
+            groups.reset()
+            groups.initialize(TopologyConfig(tensor_parallel_size=2),
+                              devices=jax.devices()[:2])
+            with jax.set_mesh(groups.get_mesh()):
+                p = resolve_grouped_params("auto", rows, E, D, F, dtype)
+            groups.reset()
+        else:
+            p = resolve_grouped_params("auto", rows, E, D, F, dtype)
+        assert p == dict(TUNE_DEFAULTS, backend=want)
 
     def test_cold_cache_hlo_identical_to_ragged(self):
-        """moe_layer_ragged with grouped_kernel="auto" on a COLD cache
-        lowers to the byte-identical program of grouped_kernel=False —
-        the established cold-cache contract."""
+        """moe_layer_ragged with grouped_kernel="auto" off the TPU lowers
+        to the byte-identical program of grouped_kernel=False, and so
+        does a SwiGLU call: tier-1's "auto" is today's program."""
         from deepspeed_tpu.moe.sharded_moe import moe_layer_ragged
         kernel_dispatch.configure(mode="cache_only")
         rs = np.random.RandomState(0)
@@ -194,6 +389,20 @@ class TestGroupedDispatch:
         assert lower("auto") == lower(False)
         # and the kernel knob produces a genuinely different program
         assert lower(True) != lower(False)
+
+        from deepspeed_tpu.moe.sharded_moe import _grouped_swiglu_ffn
+        xs = jnp.asarray(rs.randn(64, 128), jnp.float32)
+        gs = jnp.asarray([20, 0, 30, 14], jnp.int32)
+
+        def swiglu(knob, text=lambda f, *a: jax.jit(f).lower(*a).as_text()):
+            gp = resolve_grouped_params(knob, 64, 4, 128, 256, jnp.float32)
+            return text(lambda *a: _grouped_swiglu_ffn(*a, gs, gp),
+                        xs, wi, wi, wo)
+
+        jaxpr = lambda f, *a: str(jax.make_jaxpr(f)(*a))
+        assert swiglu("auto") == swiglu(False)
+        assert "ragged_dot" in swiglu("auto", jaxpr)
+        assert "pallas_call" in swiglu({"backend": "forward"}, jaxpr)
 
 
 def _swiglu_params(M=16, F=32, E=8, seed=0):

@@ -158,6 +158,49 @@ def test_paged_logits_match_reference(model, params):
         assert _rel(logits[0], want[n]) <= TOL, n
 
 
+def test_forward_kernel_path_matches_reference():
+    """The same prefill and decode steps with the expert products through
+    the forward grouped kernel (interpret mode; what "auto" takes on a TPU
+    at a serving program's few rows a group) at widths that form its
+    tiles: system = reference as through the ragged products, and every
+    expert layer call is counted as the kernel's."""
+    from types import SimpleNamespace
+    from deepspeed_tpu.moe import sharded_moe
+    cfg = replace(CFG, d_model=128, d_ff=128, n_head=2, n_kv_heads=2)
+    model = OLMoE(cfg)
+    model._moe_cfg = SimpleNamespace(
+        grouped_kernel={"backend": "forward"}, hierarchical_a2a="auto",
+        dcn_quantize=False)
+    served = model.init_served(jax.random.key(5))
+    BS, NB, T = 8, 12, 32
+    seq = np.random.RandomState(4).randint(
+        0, cfg.vocab_size, (PROMPT + NEW,)).astype(np.int32)
+    want = np.asarray(reference.logits(served, seq[None],
+                                       **dict(REF, n_head=2)))[0]
+    cache = model.init_paged_cache(NB, BS, dtype=jnp.float32)
+    table = np.arange(1, 1 + -(-(PROMPT + NEW) // BS), dtype=np.int32)
+    pos = np.arange(T)
+    tb = np.where(pos < PROMPT, table[np.minimum(pos // BS,
+                                                 len(table) - 1)], 0)
+    padded = np.zeros((1, T), np.int32)
+    padded[0, :PROMPT] = seq[:PROMPT]
+    with sharded_moe.counting_expert_calls() as counts:
+        logits, cache = jax.jit(model.apply_paged_prefill)(
+            served, padded, cache, tb.astype(np.int32),
+            np.where(pos < PROMPT, pos % BS, 0).astype(np.int32),
+            np.int32(PROMPT))
+        assert _rel(logits[0], want[PROMPT - 1]) <= TOL
+        decode = jax.jit(model.apply_paged_decode)
+        # two slots, the second dead: 8 routed rows, one row tile
+        tables = np.zeros((2, 6), np.int32)
+        tables[0, :len(table)] = table
+        for n in range(PROMPT, PROMPT + 3):
+            logits, cache = decode(served, np.asarray([seq[n], 0], np.int32),
+                                   np.asarray([n, 0], np.int32), cache, tables)
+            assert _rel(logits[0], want[n]) <= TOL, n
+    assert counts == [2 * cfg.n_layer] * 2      # two programs traced
+
+
 def test_expert_parallel_equals_one_device(model, params):
     """``expert_parallel=2`` (the shard_map all_to_all path, which shares
     ``route_topk`` and its ``renormalize=False``) emits what one device

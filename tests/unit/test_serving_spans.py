@@ -201,6 +201,11 @@ def test_on_admit_queue_wait():
     st.on_kv_write(3, 8)
     st.on_kv_write(13, 24)
     assert st.percentiles()["kv_write_live_share"] == 0.5
+    assert "moe_kernel_share" not in st.percentiles()
+    st.on_expert_calls(0, 0)                        # a program's first call
+    st.on_expert_calls(96, 96)
+    st.on_expert_calls(32, 0)
+    assert st.percentiles()["moe_kernel_share"] == 0.75
 
 
 @pytest.mark.parametrize("splitfuse_tokens", [0, 16])
@@ -285,6 +290,64 @@ def test_kv_write_counter(monkeypatch, splitfuse_tokens):
     assert engine.telemetry_snapshot()["kv_write_live_share"] == round(
         sum(st["write_rows"] for st in stats)
         / sum(st["write_rows_offered"] for st in stats), 4)
+
+
+@pytest.mark.parametrize("knob, splitfuse_tokens", [
+    ("auto", 0), (True, 0), (True, 16), (False, 0), ("dense", 0)])
+def test_expert_kernel_counter(monkeypatch, knob, splitfuse_tokens):
+    """``expert_calls`` / ``expert_kernel_calls`` on every dispatch and
+    prefill span, and ``moe_kernel_share`` of the telemetry: the expert
+    layer calls (MoE layers x steps, and a chunk's or a prefill's one a
+    layer) of the span's program, and those of them whose products are a
+    Pallas grouped kernel — noted when the program is traced, so the call
+    that traces a program still reads 0 of 0, as a dense model's always
+    do. Off the TPU "auto" is ``lax.ragged_dot``: share 0;
+    ``grouped_kernel=True``: share 1."""
+    from deepspeed_tpu.models import Mixtral, MixtralConfig
+    router, engine = _router(splitfuse_tokens)
+    if knob != "dense":
+        model = Mixtral(MixtralConfig(
+            n_layer=2, n_head=2, n_kv_heads=2, d_model=128, d_ff=128,
+            max_seq_len=128, vocab_size=256, num_experts=4, moe_top_k=2,
+            remat=False, dtype="float32"))
+        model._moe_cfg = types.SimpleNamespace(
+            grouped_kernel=knob, hierarchical_a2a="auto",
+            dcn_quantize=False)
+        groups.reset()
+        engine = InferenceEngineV2(
+            model, config=dict(_BASE, splitfuse_tokens=splitfuse_tokens))
+        router = Router([Replica("r0", engine)])
+    stats, real_span = [], engine_v2.span
+
+    def recording_span(name, **st):
+        if name in ("dstpu.engine.dispatch", "dstpu.engine.prefill"):
+            stats.append(dict(st, name=name))
+        return real_span(name, **st)
+
+    monkeypatch.setattr(engine_v2, "span", recording_span)
+    _serve(router)
+    layers, steps = 2, 2
+    calls_of = {"decode": layers * steps, "chunk": layers,
+                "fused": layers * (steps + 1), "prefill": layers}
+    seen, kinds = set(), set()
+    for st in stats:
+        kind = st.get("kind", "prefill")
+        program = (kind, st.get("padded"))      # a bucket is a program
+        first = program not in seen
+        seen.add(program)
+        kinds.add(kind)
+        want = 0 if first or knob == "dense" else calls_of[kind]
+        assert st["expert_calls"] == want, st
+        assert st["expert_kernel_calls"] == (want if knob is True else 0), st
+    # split-fuse: chunks, fused with the decode steps while any slot
+    # decodes, and plain decode dispatches between prompts
+    assert kinds - {"decode"} == ({"fused", "chunk"} if splitfuse_tokens
+                                  else {"prefill"}) and "decode" in kinds
+    snapshot = engine.telemetry_snapshot()
+    if knob == "dense":
+        assert "moe_kernel_share" not in snapshot
+    else:
+        assert snapshot["moe_kernel_share"] == float(knob is True)
 
 
 def test_span_budget_without_capture(monkeypatch):

@@ -353,7 +353,7 @@ def _olmoe_prefill(model):
                      ((), i32)]
 
 
-def _olmoe_compiled(model, tree, program, v5e):
+def _olmoe_compiled(model, tree, program, v5e, POOL_NB=POOL_NB):
     cfg = model.config
     assert pool_block_dims(POOL_NB, cfg.d_head, kernel_layout=True) \
         == (POOL_NB,)                      # head dim 128: no split axis
@@ -396,6 +396,87 @@ def test_olmoe_experts_are_read_in_place(v5e, monkeypatch, program):
             model, stacked, program, v5e).memory_analysis().temp_size_in_bytes
         assert stacked_temp > 0.3 * expert_bytes, (stacked_temp,
                                                    expert_bytes)
+
+
+# The cell's own programs (ISSUE 32): all 64 experts, two layers deep, 32
+# slots and the cell's 512-block pool. "auto" on a TPU takes the grouped
+# products from their shape (``sharded_moe.resolve_grouped_params``): a
+# decode step's 4 rows a group and every prefill bucket's 32-128 are the
+# forward kernel, one Mosaic launch a layer call whose weight tiles are the
+# expert's whole (2048, 1024) matrices.
+OLMOE_E, OLMOE_NB, OLMOE_STEPS, OLMOE_LAYERS = 64, 512, 8, 12
+OLMOE_WHOLE_GB = 13.9       # PR 26 compiled 13.88 with the ragged products
+
+
+def _olmoe_decode_x8(model):
+    step, shapes = _olmoe_decode(model)
+
+    def decode(params, cache, tokens, lengths, tables):
+        toks = []
+        for _ in range(OLMOE_STEPS):
+            tokens, cache = step(params, cache, tokens, lengths, tables)
+            lengths = lengths + 1
+            toks.append(tokens)
+        return jnp.stack(toks), cache
+    return decode, shapes
+
+
+def _olmoe_prefill_of(T):
+    def program(model):
+        fn, _ = _olmoe_prefill(model)
+        return fn, [((1, T), i32), ((T,), i32), ((T,), i32), ((), i32)]
+    return program
+
+
+def _nbytes(tree, dtype=None):
+    return sum(x.size * jnp.dtype(dtype or x.dtype).itemsize
+               for x in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("T", [0, 256, 512, 768, 1024],
+                         ids=lambda T: f"prefill{T}" if T else "decode_x8")
+def test_olmoe_programs_take_the_expert_kernel(v5e, monkeypatch, T):
+    from deepspeed_tpu.models import OLMoE, OLMoEConfig
+    from deepspeed_tpu.moe import sharded_moe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = OLMoE(OLMoEConfig(n_layer=2, num_experts=OLMOE_E))
+    model._paged_kernel, model._paged_block_c = True, "auto"
+    cfg = model.config
+    steps = 1 if T else OLMOE_STEPS
+    with sharded_moe.counting_expert_calls() as counts:
+        served = _olmoe_compiled(
+            model, jax.eval_shape(model.init_served, jax.random.key(0)),
+            _olmoe_prefill_of(T) if T else _olmoe_decode_x8, v5e, OLMOE_NB)
+    assert counts == [cfg.n_layer * steps] * 2      # every call the kernel
+    text = served.as_text()
+    weight = rf"bf16\[{OLMOE_E},(?:{cfg.d_model},{cfg.ffn_dim}" \
+             rf"|{cfg.ffn_dim},{cfg.d_model})\]"
+    # the expert products: one Mosaic call a layer call over the three
+    # weight arrays in place, under the experts' scope, its output the
+    # routed rows by the model width — two-dimensional, so that the trace
+    # readers' patterns for the paged kernels (three and four dimensions,
+    # perfbench/trace_names.json) cannot take it for one of theirs
+    rows = (T or SLOTS) * cfg.moe_top_k
+    calls = [ln for ln in text.split("\n")
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and len(re.findall(weight, ln)) == 3]
+    assert len(calls) == cfg.n_layer * steps
+    for ln in calls:
+        assert re.search(rf"= bf16\[{rows},{cfg.d_model}\]\S* custom-call\(",
+                         ln), ln[:200]
+        assert "dstpu.moe.experts" in ln
+    assert not re.search(r"ragged[-_]dot", text)
+    assert not re.findall(rf"= {weight}\S* (?:copy|slice|fusion)\(", text)
+    temp = served.memory_analysis().temp_size_in_bytes
+    layer_bytes = OLMOE_E * 3 * cfg.d_model * cfg.ffn_dim * 2
+    assert temp < 0.1 * cfg.n_layer * layer_bytes, temp
+    # the cell's 12 layers by arithmetic: a further layer is arguments
+    # (its weights, its pools), not temporaries
+    deep = OLMoE(OLMoEConfig(n_layer=OLMOE_LAYERS, num_experts=OLMOE_E))
+    whole = temp + _nbytes(jax.eval_shape(
+        deep.init_served, jax.random.key(0)), bf16) + _nbytes(jax.eval_shape(
+            lambda: deep.init_paged_cache(OLMOE_NB, BS, dtype=bf16)))
+    assert whole <= OLMOE_WHOLE_GB * 1e9, whole
 
 
 # ------------------------------------------- Phi-4-mini-flash (ISSUE 30)
