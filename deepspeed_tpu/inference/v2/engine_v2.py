@@ -18,7 +18,10 @@ Counterpart of reference ``inference/v2/engine_v2.py:30 InferenceEngineV2``
     mii/ragged batching): admit pending requests while slots+blocks allow,
     prefill them, then batched decode steps; sequences retire on EOS or
     max_new_tokens and their blocks return to the free list immediately —
-    the continuous-batching property.
+    the continuous-batching property. Plain decode dispatches are
+    chained: the next one is enqueued before the host reads the last
+    (``_plain_decode``), so the host's work between two of them is not on
+    the device's path.
 """
 
 import functools
@@ -65,8 +68,9 @@ class RaggedInferenceEngineConfig:
     temperature: float = 0.0         # 0 = greedy
     top_k: int = 0
     seed: int = 0
-    # decode steps fused into one device program (host sync + dispatch
-    # amortize over this many tokens; scheduling granularity coarsens)
+    # decode steps fused into one device program: one launch and one
+    # read of tokens for this many a sequence. Scheduling granularity
+    # coarsens with it: a prompt's prefill waits behind a dispatch
     decode_steps_per_dispatch: int = 8
     # Dynamic SplitFuse (reference blogs/deepspeed-fastgen §3B): > 0 =
     # prompts stream through fixed-size chunks of this many tokens,
@@ -439,6 +443,12 @@ class InferenceEngineV2:
         # a decode replica, plus the export gather / donated import
         # scatter programs (lazy, the _get_cow_copy idiom)
         self._decode_hold = set()
+        # the plain decode dispatch that is enqueued and whose tokens the
+        # host has not read, as (batch, device tokens); the (uid, token)
+        # pairs of one that _settle read outside _plain_decode, until
+        # step() returns them
+        self._unread = None
+        self._settled = []
         self._kv_export_jit = None
         self._kv_import_jit = None
         self._uid_next = 0
@@ -524,6 +534,9 @@ class InferenceEngineV2:
         TTFT/TPOT windows (``on_reject``): a cancelled request has no
         dispatch boundary to amortize against and would poison the
         percentiles. Returns True when the uid was known."""
+        self._settle()
+        # read, but no step() has returned them: they never surface
+        self._settled = [pair for pair in self._settled if pair[0] != uid]
         self._decode_hold.discard(uid)
         for i, r in enumerate(self._pending):
             if r.uid == uid:
@@ -556,7 +569,10 @@ class InferenceEngineV2:
 
     @property
     def has_work(self):
-        return bool(self._pending) or self.state_mgr.n_active > 0
+        # an unread dispatch, or pairs no step() has returned yet, are
+        # work: whoever steps while this is true strands no token
+        return bool(self._pending) or self.state_mgr.n_active > 0 \
+            or self._unread is not None or bool(self._settled)
 
     # ------------------------------------------------------------- programs
     @staticmethod
@@ -773,14 +789,22 @@ class InferenceEngineV2:
             n = max(1, self.config.decode_steps_per_dispatch)
 
             def decode(params, cache, tokens, lengths, tables, rng,
-                       temps, top_ks, all_greedy):
+                       temps, top_ks, all_greedy, prev, from_host):
                 self._install_trace_state()
                 # n decode steps in ONE program: the sampled token feeds
-                # the next step in-trace, so the host round trip (token
-                # sync + batch re-upload + dispatch latency) amortizes
-                # over n tokens. Unrolled (not lax.scan): the cache pools
+                # the next step in-trace, and the last of them feeds the
+                # next dispatch the same way — ``prev`` is the (n, B)
+                # tokens of the dispatch before, still on the device, and
+                # a slot takes the host's token only where ``from_host``
+                # (its first token came from a prefill since, or no
+                # dispatch came before). So the host's round trip (token
+                # sync, batch upload, launch) is off the device's path
+                # wherever _plain_decode can enqueue this call before it
+                # reads the last one, and costs one launch where it
+                # cannot. Unrolled (not lax.scan): the cache pools
                 # must stay per-layer donated buffers updated in place —
                 # carrying them through a scan defensively copies them.
+                tokens = jnp.where(from_host, tokens, prev[-1])
                 all_toks = []
                 pools = as_pools(cache)
                 for t in range(n):
@@ -793,12 +817,20 @@ class InferenceEngineV2:
                     all_toks.append(tokens)
                 return jnp.stack(all_toks), like_boundary(pools, cache)
 
+            # the tokens go out as they come back in as ``prev``: one
+            # sharding for both, and the first call's zeros an array on
+            # the device like them (jax keys a trace on that too), so
+            # that every call shares one executable
+            whole = NamedSharding(self.mesh, P())
             self._decode_jit = jax.jit(
                 self._noting_expert_calls(decode), donate_argnums=(1,),
                 static_argnums=(8,),
                 in_shardings=(self.param_shardings, self._cache_sh,
-                              None, None, None, None, None, None),
-                out_shardings=(None, self._cache_sh))
+                              None, None, None, None, None, None, whole,
+                              None),
+                out_shardings=(whole, self._cache_sh))
+            self._no_prev = jax.device_put(
+                np.zeros((n, self.config.max_batch_size), np.int32), whole)
         return self._decode_jit
 
     def _get_splitfuse(self):
@@ -978,6 +1010,7 @@ class InferenceEngineV2:
         return self._verify_jit
 
     def _apply_cow(self, seq):
+        self._settle()
         fn = self._get_cow_copy()
         src, dst, plen = seq.cow
         with jax.set_mesh(self.mesh):
@@ -993,6 +1026,7 @@ class InferenceEngineV2:
         generated token, and then waits for its KV handoff to a decode
         replica instead of decoding locally."""
         self._refuse_kv_transfer()
+        self._settle()
         self._decode_hold.add(uid)
 
     def release_decode_hold(self, uid=None):
@@ -1056,6 +1090,7 @@ class InferenceEngineV2:
                 "KV handoff is incompatible with kv_host_offload: "
                 "block payloads live in the host pool, not the device "
                 "cache — run prefill-role replicas without offload")
+        self._settle()
         mgr = self.state_mgr
         seq = mgr.get_sequence(uid)
         if not seq.generated:
@@ -1104,6 +1139,7 @@ class InferenceEngineV2:
                 "KV handoff is incompatible with kv_host_offload: "
                 "imported blocks would bypass residency tracking — run "
                 "decode-role replicas without offload")
+        self._settle()
         mgr = self.state_mgr
         uid = int(state["uid"])
         if uid in mgr._seqs or uid in self._results:
@@ -1189,10 +1225,11 @@ class InferenceEngineV2:
             self.telemetry.on_handoff_out(uid)
 
     def _dispatch_span(self, kind, active, steps, chunk_tokens=0,
-                       chunk_rows=0, batch=None):
+                       chunk_rows=0, batch=None, chained=0, late_steps=0):
         """The ``dstpu.engine.dispatch`` span of one program call, opened
         once the batch is assembled (its stats are fixed here); a
         decode-bearing dispatch also feeds the occupancy counter.
+        ``chained`` / ``late_steps``: see :meth:`_plain_decode`.
         ``active``: a count, or — with ``batch`` = (lengths, block
         tables), where the dispatch runs the paged-decode kernel over
         the batch — the live slots' mask, and the span then says how
@@ -1225,13 +1262,16 @@ class InferenceEngineV2:
                 self.telemetry.on_decode_batch(active, slots, grid_steps,
                                                table_entries)
             self.telemetry.on_kv_write(write_rows, write_rows_offered)
+            if kind == "decode":
+                self.telemetry.on_plain_decode(chained, steps * active)
         return span("dstpu.engine.dispatch", kind=kind, active=active,
                     slots=slots, steps=steps, chunk_tokens=chunk_tokens,
                     grid_steps=grid_steps, table_entries=table_entries,
                     write_rows=write_rows,
                     write_rows_offered=write_rows_offered,
                     expert_calls=expert_calls,
-                    expert_kernel_calls=expert_kernel_calls)
+                    expert_kernel_calls=expert_kernel_calls,
+                    chained=chained, late_steps=late_steps)
 
     def _expert_calls_of(self, *programs):
         """(expert layer calls, those through a Pallas grouped kernel) of
@@ -1251,6 +1291,7 @@ class InferenceEngineV2:
         ride this path even with SplitFuse off (chunk accounting already
         handles a nonzero start offset); the chunk size then falls back
         to the prompt bucket."""
+        self._settle()      # the decode slots' tokens come from the host
         mgr = self.state_mgr
         C = self.config.splitfuse_tokens or self.config.prompt_bucket
         with span("dstpu.engine.build"):
@@ -1438,6 +1479,14 @@ class InferenceEngineV2:
             if self.kv_pool is not None:
                 # drop residency before the allocator recycles the ids
                 self.kv_pool.release(seq.blocks)
+            # an EOS is seen one dispatch late: the dispatch enqueued
+            # behind the one that held it still writes this sequence's
+            # tail blocks (and its slot's ring / state) after they are
+            # given back here. Safe because the device runs its queue in
+            # order: whatever the next owner runs (prefill, chunk, CoW
+            # copy, KV import) is enqueued after that dispatch. The
+            # prefix cache takes prompt + generated[:-1], and every such
+            # late write lands past it
             self.state_mgr.retire(seq.uid)
             self.state_mgr.flush(seq.uid)
 
@@ -1474,6 +1523,7 @@ class InferenceEngineV2:
         made device-resident (next group's H2D prefetched under the
         current group's compute), tables are translated to device slots,
         and tail blocks are marked dirty."""
+        self._settle()
         mgr = self.state_mgr
         pool = self.kv_pool
         n = max(1, self.config.decode_steps_per_dispatch)
@@ -1508,7 +1558,8 @@ class InferenceEngineV2:
                             self.params, self.cache, tokens,
                             lengths, tables, sub, batch.temps,
                             batch.top_ks,
-                            not bool(batch.temps[sub_active].any()))
+                            not bool(batch.temps[sub_active].any()),
+                            self._no_prev, batch.from_host)
                     toks = np.asarray(toks)
                 with span("dstpu.engine.post"):
                     for s in slots_g:
@@ -1518,7 +1569,9 @@ class InferenceEngineV2:
                     sub_batch = RaggedBatchWrapper(
                         tokens=tokens, lengths=lengths,
                         block_tables=tables, active=sub_active,
-                        temps=batch.temps, top_ks=batch.top_ks)
+                        temps=batch.temps, top_ks=batch.top_ks,
+                        seqs=[q if on else None for q, on
+                              in zip(batch.seqs, sub_active)])
                     out.extend(self._post_decode_tokens(sub_batch, toks))
         return out
 
@@ -1540,6 +1593,10 @@ class InferenceEngineV2:
                   admitted_total=tel.admitted if tel else 0,
                   cache_bytes=cache_bytes, live_tokens=tokens):
             out = self._step_inner()
+            if self._settled:
+                # read outside _plain_decode, before whatever else this
+                # step (or a call between two steps) went on to run
+                out, self._settled = self._settled + out, []
             if tel is not None:
                 tel.on_cache_held(cache_bytes, tokens)
                 tel.on_dispatch(active=self.state_mgr.n_active)
@@ -1555,20 +1612,28 @@ class InferenceEngineV2:
     def _step_inner(self):
         """One scheduler iteration: admit+prefill pending, then up to
         ``decode_steps_per_dispatch`` decode steps for every active
-        sequence in one device program. Returns list of (uid, token)
-        pairs produced this step.
+        sequence in one device program. Returns the (uid, token) pairs
+        the host read this step: where plain decodes follow one another
+        (:meth:`_plain_decode`) those of the dispatch BEFORE the one
+        this step enqueued, so [] on the step that enqueues the first.
 
         A sequence that hits EOS or its budget mid-dispatch keeps
         decoding until the dispatch ends (its extra tokens are discarded
         and its over-writes land in its own tail slots / the scratch
         block) — the FastGen trade of scheduling granularity for
-        amortized launch overhead.
+        amortized launch overhead. A budget is host arithmetic, so such
+        a sequence is in no later dispatch; an EOS is seen only when its
+        dispatch is read, one dispatch late: the sequence rides the next
+        one too, and that one's tokens for it are discarded the same way.
         """
         self._admit_pending()
         mgr = self.state_mgr
         if self._prefill_q:
             return self._step_splitfuse_chunk()
         if mgr.n_active == 0:
+            # the last live sequence may have ended by an EOS read while
+            # the dispatch behind it, which carried it too, went out
+            self._settle()
             return []
         if self.kv_pool is not None:
             return self._step_offload_decode()
@@ -1577,30 +1642,87 @@ class InferenceEngineV2:
         return self._plain_decode()
 
     def _plain_decode(self, uids=None):
-        """The pre-speculation decode dispatch, unchanged: n fused
-        decode steps over the given slots (all active slots when
-        ``uids`` is None)."""
+        """The plain decode dispatch: n fused decode steps over the given
+        slots (all active slots when ``uids`` is None), chained. The
+        program call of this dispatch is enqueued BEFORE the host reads
+        the tokens of the one before (``self._unread``): the batch is
+        built without them (:meth:`DSStateManager.decode_batch`), the
+        program takes them device to device, and the device goes from
+        one dispatch to the next with nothing between. Returns the pairs
+        of the dispatch it read — the one before — and leaves its own
+        unread for the next call, or for :meth:`_settle` where anything
+        else runs next. The span says ``chained`` (1: enqueued behind an
+        unread one) and ``late_steps`` (decode steps x slots which the
+        dispatch READ under it ran for sequences that an EOS in the one
+        before had ended: known when the span opens).
+
+        The speculative scheduler's plain set (``uids``) is read at once:
+        a speculative round follows it, which takes tokens from the host."""
+        n = max(1, self.config.decode_steps_per_dispatch)
+        prev = self._unread
         with span("dstpu.engine.build"):
             batch = self.state_mgr.decode_batch(
-                uids, exclude=self._decode_hold)
-            if not batch.active.any():
-                return []
-            self._rng, sub = jax.random.split(self._rng)
-            fn = self._get_decode()
+                uids, exclude=self._decode_hold,
+                unread=prev and prev[0], ahead=n)
+            live = bool(batch.active.any())
+            if live:
+                self._rng, sub = jax.random.split(self._rng)
+                fn = self._get_decode()
+        if not live:
+            self._settle()      # what is left ends in the unread one
+            return []
         with self._dispatch_span(
-                "decode", batch.active,
-                max(1, self.config.decode_steps_per_dispatch),
-                batch=(batch.lengths, batch.block_tables)):
+                "decode", batch.active, n,
+                batch=(batch.lengths, batch.block_tables),
+                chained=int(prev is not None),
+                late_steps=self._late_steps(prev)):
             with span("dstpu.engine.fetch"):
                 with jax.set_mesh(self.mesh):
-                    toks, self.cache = fn(self.params, self.cache,
-                                          batch.tokens, batch.lengths,
-                                          batch.block_tables, sub,
-                                          batch.temps, batch.top_ks,
-                                          not bool(batch.temps.any()))
+                    toks, self.cache = fn(
+                        self.params, self.cache, batch.tokens,
+                        batch.lengths, batch.block_tables, sub,
+                        batch.temps, batch.top_ks,
+                        not bool(batch.temps.any()),
+                        self._no_prev if prev is None else prev[1],
+                        batch.from_host)
+                self._unread = (batch, toks)
+                if prev is None:
+                    if uids is None:
+                        return []   # the first of a run: none to read yet
+                    prev, self._unread = self._unread, None
+                toks = np.asarray(prev[1])
+            with span("dstpu.engine.post"):
+                return self._post_decode_tokens(prev[0], toks)
+
+    def _late_steps(self, unread):
+        """Decode steps x slots a dispatch ((batch, tokens); None: 0) runs
+        for sequences that have ended since its batch was built."""
+        if unread is None:
+            return 0
+        batch, toks = unread
+        return toks.shape[0] * sum(
+            q is not None and not self._is_live(q) for q in batch.seqs)
+
+    def _is_live(self, seq):
+        """Whether this descriptor is still the one its uid names: not
+        once retired or cancelled, whoever took its slot or its uid."""
+        return self.state_mgr._seqs.get(seq.uid) is seq
+
+    def _settle(self):
+        """Read and post the decode dispatch that is enqueued and unread,
+        if there is one. Whatever is not the next plain decode calls this
+        before it builds anything: it takes the decode slots' tokens from
+        the host, or frees, parks, copies or moves what that dispatch
+        holds. The pairs go out with the next return of :meth:`step`."""
+        unread, self._unread = self._unread, None
+        if unread is None:
+            return
+        batch, toks = unread
+        with span("dstpu.engine.settle"):
+            with span("dstpu.engine.fetch"):
                 toks = np.asarray(toks)
             with span("dstpu.engine.post"):
-                return self._post_decode_tokens(batch, toks)
+                self._settled.extend(self._post_decode_tokens(batch, toks))
 
     # ------------------------------------------------- speculative decoding
     def _spec_candidate(self, seq):
@@ -1636,6 +1758,7 @@ class InferenceEngineV2:
         acceptance; the plain set runs the UNCHANGED decode program in
         its own dispatch — adversarial (low-acceptance) traffic latches
         off per sequence and pays exactly the plain-decode cost."""
+        self._settle()
         mgr = self.state_mgr
         spec, plain = [], []
         for uid in list(mgr._slots):
@@ -1792,20 +1915,24 @@ class InferenceEngineV2:
 
     def _post_decode_tokens(self, batch, toks):
         """Feed (n, B) decode outputs to their sequences; returns the
-        accepted (uid, token) pairs."""
-        mgr = self.state_mgr
+        accepted (uid, token) pairs. The sequences are those the batch
+        was built over (``batch.seqs``), whoever holds their slots now: a
+        dispatch may be read after a later one was built, and a slot may
+        have changed hands between."""
+        late = self._late_steps((batch, toks))
+        if late and self.telemetry is not None:
+            self.telemetry.on_late_steps(late)
         out = []
-        slots = list(mgr._slots)  # snapshot: retire mutates
-        for slot, uid in enumerate(slots):
-            if uid is None or not batch.active[slot]:
+        for slot, seq in enumerate(batch.seqs):
+            if seq is None:
                 continue
-            seq = mgr.get_sequence(uid)
             for t in range(toks.shape[0]):
-                if uid in self._results:
-                    break                            # finished mid-dispatch
+                if not self._is_live(seq):
+                    # finished mid-dispatch, or in the dispatch before
+                    break
                 tok = int(toks[t, slot])
                 self._post_token(seq, tok)
-                out.append((uid, tok))
+                out.append((seq.uid, tok))
         return out
 
     def generate_all(self, prompts, max_new_tokens=32, eos_token_id=-1):

@@ -78,6 +78,13 @@ class RaggedBatchWrapper:
     active: np.ndarray        # (B,) bool
     temps: np.ndarray = None  # (B,) f32 — per-slot temperature (0=greedy)
     top_ks: np.ndarray = None  # (B,) int32 — per-slot top-k (0=off)
+    # slot -> the descriptor the slot held when the batch was built (None
+    # where not active): a dispatch read after a later one was built posts
+    # by this list, never by who holds the slot at the time of the read
+    seqs: list = None
+    # (B,) bool — False where the slot's input token is the last row of
+    # the unread dispatch's tokens, still on the device
+    from_host: np.ndarray = None
 
 
 class DSStateManager:
@@ -319,13 +326,20 @@ class DSStateManager:
         offs = (idx % self.block_size).astype(np.int32)
         return blocks, offs
 
-    def decode_batch(self, uids=None, exclude=None):
+    def decode_batch(self, uids=None, exclude=None, unread=None, ahead=0):
         """RaggedBatchWrapper for one decode step over all active slots.
         ``uids``: optional subset — the speculative scheduler splits a
         step into a spec set and a plain set, and the plain set's decode
         dispatch must carry only its own slots. ``exclude``: uids parked
         out of decode entirely — a prefill-role replica holds finished
-        prefills here until their KV handoff lands on a decode replica."""
+        prefills here until their KV handoff lands on a decode replica.
+        ``unread``: the batch of a dispatch of ``ahead`` decode steps that
+        is enqueued and whose tokens the host has not read. A sequence in
+        it stands ``ahead`` tokens further than its descriptor says: it is
+        left out if that reaches its budget (it ends in that dispatch),
+        else its length advances by ``ahead`` and its input token is the
+        device's (``from_host`` False). An EOS among the unread tokens is
+        not known here: such a sequence rides this batch too."""
         B, MB = self.max_batch, self.max_blocks_per_seq
         tokens = np.zeros((B,), np.int32)
         lengths = np.zeros((B,), np.int32)
@@ -333,6 +347,8 @@ class DSStateManager:
         active = np.zeros((B,), bool)
         temps = np.zeros((B,), np.float32)
         top_ks = np.zeros((B,), np.int32)
+        seqs = [None] * B
+        from_host = np.ones((B,), bool)
         for slot, uid in enumerate(self._slots):
             if uid is None or (uids is not None and uid not in uids) \
                     or (exclude is not None and uid in exclude):
@@ -342,19 +358,29 @@ class DSStateManager:
                 # still prefilling (SplitFuse chunks in flight): no
                 # first token yet, nothing to decode
                 continue
+            in_flight = ahead if unread is not None \
+                and unread.seqs[slot] is seq else 0
+            if in_flight and len(seq.generated) + in_flight \
+                    >= seq.max_new_tokens:
+                continue
             active[slot] = True
+            seqs[slot] = seq
             temps[slot] = seq.temperature
             top_ks[slot] = seq.top_k
             # input token = last generated (prefill produced the first);
             # it is not yet in the cache, so its write position is
             # seen_tokens - 1
-            tokens[slot] = seq.generated[-1]
-            lengths[slot] = seq.seen_tokens - 1
+            if in_flight:
+                from_host[slot] = False
+            else:
+                tokens[slot] = seq.generated[-1]
+            lengths[slot] = seq.seen_tokens - 1 + in_flight
             nb = len(seq.blocks)
             tables[slot, :nb] = seq.blocks
         return RaggedBatchWrapper(tokens=tokens, lengths=lengths,
                                   block_tables=tables, active=active,
-                                  temps=temps, top_ks=top_ks)
+                                  temps=temps, top_ks=top_ks, seqs=seqs,
+                                  from_host=from_host)
 
     def propose_batch(self, uids):
         """Draft-side metadata for one propose dispatch over the spec

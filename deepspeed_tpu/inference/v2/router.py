@@ -347,7 +347,12 @@ class Router:
 
     @property
     def has_work(self):
-        return bool(self._queue) or any(r.inflight for r in self.replicas)
+        # a live engine may still hold a decode dispatch nobody has read
+        # after its last request is done (an EOS is seen one dispatch
+        # late): one more round reads it
+        return bool(self._queue) or any(
+            r.inflight or (not r.dead and r.engine.has_work)
+            for r in self.replicas)
 
     def drain(self, replica):
         """Scale-down: stop admitting to ``replica`` (name or handle);

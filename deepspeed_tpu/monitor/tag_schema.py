@@ -248,10 +248,22 @@ SPAN_SCHEMA = {
         "stats": ("kind", "active", "slots", "steps", "chunk_tokens",
                   "grid_steps", "table_entries", "write_rows",
                   "write_rows_offered", "expert_calls",
-                  "expert_kernel_calls"),
+                  "expert_kernel_calls", "chained", "late_steps"),
         "meaning": "one decode-bearing or chunk program call (kind "
                    "decode | fused | chunk | spec | offload) from the "
-                   "assembled batch to the last posted token; grid_steps "
+                   "assembled batch to the last posted token — of kind "
+                   "decode, where plain decodes follow one another, the "
+                   "tokens read and posted inside the span are those of "
+                   "the dispatch BEFORE the one it enqueues (chained = 1: "
+                   "the call went out while that one was unread, so the "
+                   "device passes from one to the other with no host work "
+                   "between; none are read under the first of a run, so "
+                   "that span is a launch alone, and the last of a run is "
+                   "read under dstpu.engine.settle); "
+                   "late_steps = decode steps x slots which the dispatch "
+                   "read under the span ran for sequences that an EOS in "
+                   "the one before it had ended (an EOS is seen one "
+                   "dispatch late; a budget never is); grid_steps "
                    "of table_entries = how much of the block table one "
                    "paged-decode kernel call walks, over the dispatch's "
                    "decode steps; write_rows of write_rows_offered = the "
@@ -271,11 +283,21 @@ SPAN_SCHEMA = {
     "dstpu.engine.fetch": {
         "stats": (),
         "meaning": "leaf: the program call and the blocking read of "
-                   "its tokens"},
+                   "tokens: its own, or under a chained decode dispatch "
+                   "those of the dispatch before; under "
+                   "dstpu.engine.settle the read alone"},
     "dstpu.engine.post": {
         "stats": (),
         "meaning": "leaf: the Python loop feeding the fetched tokens "
                    "to their sequences"},
+    "dstpu.engine.settle": {
+        "stats": (),
+        "meaning": "the read (fetch) and posting (post) of a decode "
+                   "dispatch that was enqueued and left unread, once "
+                   "something other than a plain decode comes next or "
+                   "nothing does: the last of a run of chained "
+                   "dispatches. Inside dstpu.engine.step, except from "
+                   "cancel, hold_decode and the KV handoff calls"},
 }
 
 # jax.named_scope name -> meaning. Scopes name the DEVICE operations of a

@@ -831,6 +831,14 @@ class ServingTelemetry:
         # over every dispatch (chunk-only ones too)
         self._write_rows = 0
         self._write_rows_offered = 0
+        # plain decode dispatches, those of them enqueued behind an
+        # unread one, the decode steps x live slots they ran, and those
+        # of them run for a sequence that had already ended (an EOS is
+        # seen one dispatch late)
+        self._plain_dispatches = 0
+        self._chained_dispatches = 0
+        self._slot_steps = 0
+        self._late_steps = 0
         # expert layer calls of every program call, and those of them
         # whose products were a Pallas grouped kernel
         self._expert_calls = 0
@@ -912,6 +920,20 @@ class ServingTelemetry:
         takes against those its one-step-a-row grid took."""
         self._write_rows += live
         self._write_rows_offered += offered
+
+    def on_plain_decode(self, chained, slot_steps):
+        """One plain decode dispatch was enqueued, ``chained`` (behind one
+        whose tokens the host had not read: the device goes from that one
+        to this with no host work between) or not, to run ``slot_steps``
+        decode steps x live slots."""
+        self._plain_dispatches += 1
+        self._chained_dispatches += bool(chained)
+        self._slot_steps += slot_steps
+
+    def on_late_steps(self, late):
+        """A dispatch that was read had run ``late`` decode steps x slots
+        for sequences that the dispatch before it had ended."""
+        self._late_steps += late
 
     def on_expert_calls(self, calls, kernel):
         """One program call whose trace made ``calls`` expert layer calls
@@ -1072,6 +1094,11 @@ class ServingTelemetry:
         if self._write_rows_offered:
             out["kv_write_live_share"] = round(
                 self._write_rows / self._write_rows_offered, 4)
+        if self._plain_dispatches:
+            out["decode_chain_share"] = round(
+                self._chained_dispatches / self._plain_dispatches, 4)
+            out["late_stop_share"] = round(
+                self._late_steps / max(1, self._slot_steps), 4)
         if self._expert_calls:
             out["moe_kernel_share"] = round(
                 self._expert_kernel_calls / self._expert_calls, 4)

@@ -72,7 +72,16 @@ def reference_rows(params, prompt, tokens, **variant):
     return rows[len(prompt) - 1:]
 
 
-TapEngine = importlib.import_module("pbench.tap").tap_engine()
+class TapEngine(importlib.import_module("pbench.tap").tap_engine()):
+    """The tap picks a dispatch's rows out as the NEWEST it has seen, so it
+    reads every decode dispatch before the next goes out (plain decodes
+    are chained since ISSUE 35; ``pbench/tap.py`` itself still needs these
+    lines: PERF.md section 7)."""
+
+    def _plain_decode(self, uids=None):
+        out = super()._plain_decode(uids)
+        self._settle()
+        return out
 
 
 def engine_of(model, params, **engine):

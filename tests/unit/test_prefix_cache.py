@@ -439,7 +439,7 @@ def _lower_step_programs(eng):
         dec = eng._get_decode().lower(
             eng.params, eng.cache, z((B,), i32), z((B,), i32),
             z((B, MB), i32), rng, z((B,), f32), z((B,), i32),
-            True).as_text()
+            True, eng._no_prev, z((B,), bool)).as_text()
         C = eng.config.splitfuse_tokens
         chk = eng._get_chunk_only().lower(
             eng.params, eng.cache, z((1, C), i32), z((C,), i32),

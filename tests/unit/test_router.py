@@ -426,10 +426,13 @@ class TestDeadlines:
         router, eng = self._router()
         uid = router.put(_prompts(14, 1)[0], max_new_tokens=32,
                          deadline_ms=5000)
-        for _ in range(3):
-            router.step()                        # genuinely decoding
         req = router._reqs[uid]
+        for _ in range(6):                       # prefill chunks, then the
+            router.step()                        # first decode dispatch is
+            if req.n_tokens:                     # read one step after it
+                break                            # is enqueued
         assert req.state == "inflight" and req.n_tokens > 0
+        assert eng._unread is not None           # genuinely decoding
         self.clock["t"] = 10.0                   # 10s > 5s deadline
         router.step()
         assert router.is_done(uid)
@@ -555,7 +558,9 @@ class TestRouterTelemetry:
                              # + ISSUE 29's, the KV write's live rows
                              "kv_write_live_share",
                              # + ISSUE 30's, what live sequences hold
-                             "cache_bytes_per_live_token"}
+                             "cache_bytes_per_live_token",
+                             # + ISSUE 35's, the chained decode dispatch
+                             "decode_chain_share", "late_stop_share"}
 
     def test_per_class_latency_windows_are_bounded(self):
         """A server that runs for a day must not append for a day: the

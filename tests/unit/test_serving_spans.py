@@ -102,19 +102,42 @@ def _check_nesting(tr):
                     key=lambda e: e.start)
     for a, b in zip(leaves, leaves[1:]):
         assert a.end <= b.start, f"{a.name} overlaps {b.name}"
-    boxes = spans["dstpu.engine.prefill"] + spans["dstpu.engine.dispatch"]
+    settles = spans["dstpu.engine.settle"]
+    boxes = spans["dstpu.engine.prefill"] + spans["dstpu.engine.dispatch"] \
+        + settles
     for name in ("dstpu.engine.fetch", "dstpu.engine.post"):
         for e in spans[name]:
-            assert _inside(e, boxes), f"{name} outside prefill/dispatch"
+            assert _inside(e, boxes), f"{name} outside prefill/dispatch/settle"
     for name in ("dstpu.engine.build", "dstpu.engine.prefill",
-                 "dstpu.engine.admit", "dstpu.engine.dispatch"):
+                 "dstpu.engine.admit", "dstpu.engine.dispatch",
+                 "dstpu.engine.settle"):
         for e in spans[name]:
             assert _inside(e, spans["dstpu.engine.step"]), name
     for e in spans["dstpu.engine.step"]:
         assert _inside(e, spans["dstpu.router.step"])
+    unposted = []
     for d in spans["dstpu.engine.dispatch"]:
-        for name in ("dstpu.engine.fetch", "dstpu.engine.post"):
-            assert sum(_inside(e, [d]) for e in spans[name]) == 1
+        assert not _inside(d, settles)
+        assert sum(_inside(e, [d]) for e in spans["dstpu.engine.fetch"]) == 1
+        posts = sum(_inside(e, [d]) for e in spans["dstpu.engine.post"])
+        assert posts <= 1
+        if not posts:
+            unposted.append(d)
+    # one dispatch span a program call: the first plain decode of a run
+    # posts nothing (there is none before it to read), every chained one
+    # posts the one before, and the last of the run is read under a
+    # settle span: one fetch and one post, in that order (ISSUE 35)
+    for d in unposted:
+        assert d.stats["kind"] == "decode" and not d.stats["chained"]
+    assert len(settles) == len(unposted)
+    for box in settles:
+        f, p = ([e for e in spans[name] if _inside(e, [box])] for name in
+                ("dstpu.engine.fetch", "dstpu.engine.post"))
+        assert len(f) == len(p) == 1 and f[0].end <= p[0].start
+    for d in spans["dstpu.engine.dispatch"]:
+        assert d.stats["chained"] in (0, 1)
+        assert d.stats["chained"] <= (d.stats["kind"] == "decode")
+        assert d.stats["late_steps"] == 0       # no request sets an EOS
     return spans
 
 
@@ -125,6 +148,10 @@ def test_bucketed_timeline(bucketed):
         assert spans[name], f"no {name} span in the trace"
     assert {e.stats["kind"] for e in spans["dstpu.engine.dispatch"]} \
         == {"decode"}
+    # max_new_tokens=6 at 2 steps a dispatch: three dispatches a run at
+    # the least, all but the first enqueued behind an unread one
+    chained = sum(e.stats["chained"] for e in spans["dstpu.engine.dispatch"])
+    assert 2 * chained >= len(spans["dstpu.engine.dispatch"])
     # a prefill is build + fetch + post, inside its admit
     for p in spans["dstpu.engine.prefill"]:
         assert _inside(p, spans["dstpu.engine.admit"])
@@ -206,6 +233,72 @@ def test_on_admit_queue_wait():
     st.on_expert_calls(96, 96)
     st.on_expert_calls(32, 0)
     assert st.percentiles()["moe_kernel_share"] == 0.75
+
+
+def test_chain_counters_are_host_arithmetic():
+    """``decode_chain_share`` / ``late_stop_share`` (ISSUE 35) from the
+    counts alone; absent until a plain decode dispatch has gone out."""
+    st = ServingTelemetry()
+    st.on_late_steps(0)
+    assert not {"decode_chain_share", "late_stop_share"} & set(
+        st.percentiles())
+    st.on_plain_decode(0, 8)        # the first of a run: nothing unread
+    st.on_plain_decode(1, 8)
+    st.on_plain_decode(1, 12)
+    st.on_plain_decode(True, 4)
+    st.on_late_steps(4)             # one slot of four steps had ended
+    snap = st.percentiles()
+    assert snap["decode_chain_share"] == 0.75
+    assert snap["late_stop_share"] == 0.125
+
+
+def test_late_steps_ride_the_span_that_reads_them(monkeypatch):
+    """A ends by an EOS in the middle of a dispatch; the dispatch behind it
+    ran for A too. The span under which THAT one is read says so, every
+    dispatch but the first of the run is chained, and the telemetry's
+    counts are the spans' sums."""
+    kernel_dispatch.reset()
+    groups.reset()
+    model = GPT2(_CFG)
+    # 4x init's matrices: at std 0.02 greedy decoding repeats one token
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, x: x * 4.0 if x.ndim >= 2 and not any(
+            k in jax.tree_util.keystr(path) for k in ("wte", "wpe")) else x,
+        model.init(jax.random.key(0)))
+    engine = InferenceEngineV2(model, params=params,
+                               config=dict(_BASE, splitfuse_tokens=0))
+    a, b = (np.arange(3, 14, dtype=np.int32),
+            np.arange(40, 49, dtype=np.int32))
+    ref = engine.generate_all([a], 14)[0].tolist()
+    # generated[0] is the prefill's; dispatch d holds generated[1 + 2d :
+    # 3 + 2d]: an odd j is the first of its two
+    j = next(j for j in range(3, 12, 2) if ref.index(ref[j]) == j)
+    tel = engine.telemetry
+    calls0, chained0 = tel._plain_dispatches, tel._chained_dispatches
+    said = []
+    real = engine_v2.span
+
+    def recording(name, **stats):
+        if name == "dstpu.engine.dispatch":
+            said.append(stats)
+        return real(name, **stats)
+
+    monkeypatch.setattr(engine_v2, "span", recording)
+    uids = [engine.put(a, 14, eos_token_id=ref[j]), engine.put(b, 14)]
+    while engine.has_work:
+        engine.step()
+    assert engine.get(uids[0]).tolist() == ref[:j + 1]
+    assert len(engine.get(uids[1])) == 14
+    assert [d["chained"] for d in said] == [0] + [1] * (len(said) - 1)
+    # A's EOS is read under span (j - 1) // 2 + 1; the dispatch that span
+    # enqueued is the late one, read under the span after it
+    late = [d["late_steps"] for d in said]
+    assert late[(j - 1) // 2 + 2] == 2 and sum(late) == 2
+    assert said[(j - 1) // 2 + 1]["active"] == 2    # it rode along
+    assert said[(j - 1) // 2 + 2]["active"] == 1
+    assert tel._late_steps == 2
+    assert tel._plain_dispatches - calls0 == len(said)
+    assert tel._chained_dispatches - chained0 == len(said) - 1
 
 
 @pytest.mark.parametrize("splitfuse_tokens", [0, 16])
@@ -353,7 +446,8 @@ def test_expert_kernel_counter(monkeypatch, knob, splitfuse_tokens):
 def test_span_budget_without_capture(monkeypatch):
     """No capture running: a decode-only engine step opens at most 6 spans
     (router.step, engine.step, build, dispatch, fetch, post), an admission
-    5 more, and per-token code none."""
+    5 more, and per-token code none. The first decode dispatch of a run
+    reads nothing, so it posts nothing."""
     router, engine = _router(0)
     _serve(router, 2, seed=1)
     opened = []
@@ -368,7 +462,7 @@ def test_span_budget_without_capture(monkeypatch):
     monkeypatch.setattr(router_mod, "span", counting)
     router.put(np.arange(1, 20, dtype=np.int32), max_new_tokens=12)
     router.step()
-    assert len(opened) == 11, opened        # 6 + the admission's 5
+    assert len(opened) == 10, opened        # 6 - post + the admission's 5
     while router.has_work:
         opened.clear()
         router.step()
