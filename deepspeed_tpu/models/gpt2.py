@@ -504,8 +504,9 @@ class GPT2:
     def head(self, params, x):
         """Final LN + tied-embedding unembed: (B, T, D) -> fp32 logits."""
         x = self._ln(x, params["lnf_scale"], params["lnf_bias"])
-        return jnp.einsum("btd,vd->btv", x, params["wte"],
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("dstpu.mm.unembed"):
+            return jnp.einsum("btd,vd->btv", x, params["wte"],
+                              preferred_element_type=jnp.float32)
 
     def block_qkv(self, x, layer, *, constrain, act_spec,
                   heads_major=False):
@@ -520,24 +521,25 @@ class GPT2:
         B, T = x.shape[0], x.shape[1]
         H, hd = cfg.n_head, cfg.d_head
         h = self._ln(x, layer["ln1_scale"], layer["ln1_bias"])
-        if heads_major:
-            w = layer["wqkv"].reshape(x.shape[-1], 3, H, hd)
-            b = layer["bqkv"].reshape(3, H, hd)
-            if cfg.flash_qkv_t:
-                # (B, H, hd, T): T-minor — the layout XLA prefers for the
-                # einsum output (hd=64 fills only half a lane register),
-                # consumed by the flash kernel with no relayout copy.
-                # Three separate projections (not one (3, ...) einsum):
-                # the fused form pays ~16 ms/step of repack fusions
-                # splitting its output into q/k/v
-                return tuple(
-                    jnp.einsum("btd,dhe->bhet", h, w[:, i])
-                    + b[i][:, :, None]
-                    for i in range(3))
-            qkv = jnp.einsum("btd,dshe->sbhte", h, w) \
-                + b[:, None, :, None, :]
-            return qkv[0], qkv[1], qkv[2]
-        qkv = h @ layer["wqkv"] + layer["bqkv"]
+        with jax.named_scope("dstpu.mm.qkv"):
+            if heads_major:
+                w = layer["wqkv"].reshape(x.shape[-1], 3, H, hd)
+                b = layer["bqkv"].reshape(3, H, hd)
+                if cfg.flash_qkv_t:
+                    # (B, H, hd, T): T-minor — the layout XLA prefers for
+                    # the einsum output (hd=64 fills only half a lane
+                    # register), consumed by the flash kernel with no
+                    # relayout copy. Three separate projections (not one
+                    # (3, ...) einsum): the fused form pays ~16 ms/step of
+                    # repack fusions splitting its output into q/k/v
+                    return tuple(
+                        jnp.einsum("btd,dhe->bhet", h, w[:, i])
+                        + b[i][:, :, None]
+                        for i in range(3))
+                qkv = jnp.einsum("btd,dshe->sbhte", h, w) \
+                    + b[:, None, :, None, :]
+                return qkv[0], qkv[1], qkv[2]
+            qkv = h @ layer["wqkv"] + layer["bqkv"]
         qkv = qkv.reshape(B, T, 3, H, hd)
         return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
 
@@ -632,11 +634,14 @@ class GPT2:
         B, T = x.shape[0], x.shape[1]
         if heads_major:
             wo = layer["wo"].reshape(cfg.n_head, cfg.d_head, cfg.d_model)
-            x = x + jnp.einsum("bhte,hed->btd", attn, wo) + layer["bo"]
+            with jax.named_scope("dstpu.mm.attn_out"):
+                out = jnp.einsum("bhte,hed->btd", attn, wo)
         else:
             attn = attn.reshape(B, T, cfg.n_head * cfg.d_head)
             attn = constrain(attn, act_spec)
-            x = x + attn @ layer["wo"] + layer["bo"]
+            with jax.named_scope("dstpu.mm.attn_out"):
+                out = attn @ layer["wo"]
+        x = x + out + layer["bo"]
         x = constrain(x, act_spec)
         from jax.ad_checkpoint import checkpoint_name
         # named so remat policies can keep the post-attention residual
@@ -706,6 +711,12 @@ class GPT2:
     def _mlp(self, h, layer, rng, *, train, seq_sharded, constrain):
         """Dense MLP; overridden by GPT2MoE with an expert-parallel MoE.
         Returns (output, aux_loss)."""
+        with jax.named_scope("dstpu.mm.mlp"):
+            return self._dense_mlp(h, layer, rng, train=train,
+                                   seq_sharded=seq_sharded,
+                                   constrain=constrain)
+
+    def _dense_mlp(self, h, layer, rng, *, train, seq_sharded, constrain):
         from jax.ad_checkpoint import checkpoint_name
         acts = {"gelu": jax.nn.gelu, "relu": jax.nn.relu}
         if self.config.activation not in acts:
@@ -820,9 +831,13 @@ class GPT2:
         B, T = x.shape[0], x.shape[1]
         H, hd = cfg.n_head, cfg.d_head
         h = self._ln(x, layer["ln1_scale"], layer["ln1_bias"])
-        qkv = (h @ layer["wqkv"] + layer["bqkv"]).reshape(B, T, 3, H, hd)
+        with jax.named_scope("dstpu.mm.qkv"):
+            qkv = h @ layer["wqkv"] + layer["bqkv"]
+        qkv = qkv.reshape(B, T, 3, H, hd)
         attn, carry = attn_fn(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2])
-        x = x + attn.reshape(B, T, H * hd) @ layer["wo"] + layer["bo"]
+        with jax.named_scope("dstpu.mm.attn_out"):
+            out = attn.reshape(B, T, H * hd) @ layer["wo"]
+        x = x + out + layer["bo"]
         h = self._ln(x, layer["ln2_scale"], layer["ln2_bias"])
         mlp_out, _ = self._mlp(h, layer, None, train=False,
                                seq_sharded=False,
@@ -1081,9 +1096,10 @@ class GPT2:
                 return self._ln(x, np_["lnf_scale"], np_["lnf_bias"])
 
             np_ = {k: params[k] for k in ("lnf_scale", "lnf_bias")}
-            return fused_linear_xent_kernel(norm, chunk, np_,
-                                            params["wte"], hidden,
-                                            targets)
+            with jax.named_scope("dstpu.mm.unembed"):
+                return fused_linear_xent_kernel(norm, chunk, np_,
+                                                params["wte"], hidden,
+                                                targets)
         if self.config.fused_loss:
             hp = {k: params[k] for k in self._head_keys}
             return fused_linear_xent(self.head, chunk, hp, hidden, targets)

@@ -355,8 +355,9 @@ class Llama:
             x = _rms_norm(x, params["norm_f"], self.config.rms_eps)
         w = params["wte"] if self.config.tie_embeddings else \
             params["lm_head"]
-        logits = jnp.einsum("btd,vd->btv", x, w,
-                            preferred_element_type=jnp.float32)
+        with jax.named_scope("dstpu.mm.unembed"):
+            logits = jnp.einsum("btd,vd->btv", x, w,
+                                preferred_element_type=jnp.float32)
         if self.config.head_bias_on:
             logits = logits + params["lm_head_b"].astype(jnp.float32)
         return logits
@@ -366,9 +367,10 @@ class Llama:
         B, T = x.shape[0], x.shape[1]
         H, KVH, hd = cfg.n_head, cfg.n_kv_heads, cfg.d_head
         h = self._norm(x, layer, 1)
-        q = h @ layer["wq"]
-        kk = h @ layer["wk"]
-        v = h @ layer["wv"]
+        with jax.named_scope("dstpu.mm.qkv"):
+            q = h @ layer["wq"]
+            kk = h @ layer["wk"]
+            v = h @ layer["wv"]
         if cfg.qkv_bias:                      # qwen-style attention bias
             q = q + layer["bq"]
             kk = kk + layer["bk"]
@@ -422,14 +424,21 @@ class Llama:
 
     def _wo(self, attn, layer):
         """Output projection (+ bias when proj_bias/o_bias)."""
-        out = attn @ layer["wo"]
+        with jax.named_scope("dstpu.mm.attn_out"):
+            out = attn @ layer["wo"]
         if self.config.o_bias_on:
             out = out + layer["bo"]
         return out
 
     def _mlp(self, x, layer):
-        cfg = self.config
+        """The dense MLP after its norm; Mixtral overrides it with the
+        routed experts."""
         h = self._norm(x, layer, 2)
+        with jax.named_scope("dstpu.mm.mlp"):
+            return self._dense_mlp(h, layer)
+
+    def _dense_mlp(self, h, layer):
+        cfg = self.config
         pb = cfg.mlp_bias_on
         from ..ops.int8_weights import _is_q
         if _is_q(layer["wup"]):

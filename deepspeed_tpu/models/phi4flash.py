@@ -158,12 +158,14 @@ def _pieces(x, dtype):
     return jnp.stack([hi, x - hi]).astype(dtype)
 
 
-def _mm(x, w):
+def _mm(x, w, scope):
     """float32 ``x @ w`` for a weight kept in a narrower dtype: x goes in
     as its pieces of that dtype, one product over all of them so that the
-    weight is read once; float32 out."""
-    return jnp.dot(_pieces(x, w.dtype), w,
-                   preferred_element_type=x.dtype).sum(axis=0)
+    weight is read once; float32 out. ``scope`` names the product's device
+    operations (``monitor/tag_schema.py:SCOPE_SCHEMA``)."""
+    with jax.named_scope(scope):
+        return jnp.dot(_pieces(x, w.dtype), w,
+                       preferred_element_type=x.dtype).sum(axis=0)
 
 
 def _dense_diff_reads(q, k, v, window):
@@ -305,15 +307,16 @@ class Phi4Flash:
         cfg = self.config
         B, C, _ = x.shape
         Din, N, K, R = cfg.d_inner, cfg.ssm_state, cfg.ssm_conv, cfg.dt_rank
-        uz = _mm(x, p["in_proj"])
+        uz = _mm(x, p["in_proj"], "dstpu.mm.in_proj")
         u, z = uz[..., :Din], uz[..., Din:]
         win = jnp.concatenate([conv0, u], axis=1)          # (B, K-1+C, Din)
         w = p["conv_w"].astype(x.dtype)
         conv = sum(win[:, k:k + C] * w[:, k] for k in range(K)) \
             + p["conv_b"].astype(x.dtype)
         u1 = jax.nn.silu(conv)                                # (B, C, Din)
-        dbc = _mm(u1, p["x_proj"])
-        step = jax.nn.softplus(_mm(dbc[..., :R], p["dt_w"]) + p["dt_b"])
+        dbc = _mm(u1, p["x_proj"], "dstpu.mm.x_proj")
+        step = jax.nn.softplus(
+            _mm(dbc[..., :R], p["dt_w"], "dstpu.mm.dt") + p["dt_b"])
         step = jnp.where(valid[..., None], step, 0.0)
         Bm, Cm = dbc[..., R:R + N], dbc[..., R + N:]
         A = -jnp.exp(p["A_log"])                              # (N, Din)
@@ -336,7 +339,8 @@ class Phi4Flash:
             conv1 = jax.vmap(lambda rows, n: lax.dynamic_slice(
                 rows, (n, 0), (K - 1, Din)))(win, n_valid)
         y = y + p["D_skip"] * u1
-        return _mm(y * jax.nn.silu(z), p["out_proj"]), y, (conv1, ssm)
+        return _mm(y * jax.nn.silu(z), p["out_proj"],
+                   "dstpu.mm.out_proj"), y, (conv1, ssm)
 
     def _attention(self, x, p, i, attn_fn):
         """Differential attention of layer i; ``attn_fn`` owns the cache
@@ -349,9 +353,10 @@ class Phi4Flash:
         shared = cfg.mixers[i] == "cross"
         with jax.named_scope("dstpu.attn.diff"):
             if shared:
-                q, k, v = _mm(x, p["wq"]) + p["bq"], None, None
+                q = _mm(x, p["wq"], "dstpu.mm.qkv") + p["bq"]
+                k = v = None
             else:
-                qkv = _mm(x, p["wqkv"]) + p["bqkv"]
+                qkv = _mm(x, p["wqkv"], "dstpu.mm.qkv") + p["bqkv"]
                 q = qkv[..., :D]
                 k, v = (a.reshape(B, C, KV // 2, 2 * hd).astype(dt)
                         for a in jnp.split(qkv[..., D:], 2, axis=-1))
@@ -375,8 +380,8 @@ class Phi4Flash:
             a = out[:, :, 0::2] - lam * out[:, :, 1::2]       # (B, C, H/2, .)
             a = a * lax.rsqrt(jnp.mean(a * a, axis=-1, keepdims=True)
                               + cfg.ln_eps) * p["subln"].astype(f32)
-            return _mm((a * (1.0 - lam0)).reshape(B, C, D), p["wo"]) \
-                + p["bo"]
+            return _mm((a * (1.0 - lam0)).reshape(B, C, D), p["wo"],
+                       "dstpu.mm.attn_out") + p["bo"]
 
     def _layers(self, params, x, step):
         """The one layer loop: ``step`` is a ``models/paged.py`` step (or
@@ -394,15 +399,16 @@ class Phi4Flash:
                     memory = y
             elif mixer == "gmu":
                 with jax.named_scope("dstpu.gmu"):
-                    mix = _mm(memory * jax.nn.silu(_mm(h, p["g_in"])),
-                              p["g_out"])
+                    gate = _mm(h, p["g_in"], "dstpu.mm.gmu")
+                    mix = _mm(memory * jax.nn.silu(gate), p["g_out"],
+                              "dstpu.mm.gmu")
             else:
                 mix = self._attention(h, p, i, step.layer(i))
             x = x + mix
             gu = _mm(_layer_norm(x, p["ln2_s"], p["ln2_b"], cfg.ln_eps),
-                     p["w1"])
+                     p["w1"], "dstpu.mm.mlp")
             x = x + _mm(jax.nn.silu(gu[..., :cfg.d_ff]) * gu[..., cfg.d_ff:],
-                        p["w2"])
+                        p["w2"], "dstpu.mm.mlp")
         return x
 
     def _embed(self, params, ids):
@@ -412,8 +418,9 @@ class Phi4Flash:
         x = _layer_norm(x, params["ln_f_s"], params["ln_f_b"],
                         self.config.ln_eps)
         w = params["wte"]
-        return jnp.einsum("pbtd,vd->pbtv", _pieces(x, w.dtype), w,
-                          preferred_element_type=x.dtype).sum(axis=0)
+        with jax.named_scope("dstpu.mm.unembed"):
+            return jnp.einsum("pbtd,vd->pbtv", _pieces(x, w.dtype), w,
+                              preferred_element_type=x.dtype).sum(axis=0)
 
     def apply(self, params, input_ids, **_):
         """(B, T) ids -> (B, T, V) float32 logits, no cache."""

@@ -334,6 +334,33 @@ SCOPE_SCHEMA = {
         "the one full-length K/V: the full layer's write into its pool "
         "under the block table and its read, and every cross-decoder "
         "layer's read of that same pool",
+    # --- the weight products: a dense weight against the step's rows. A
+    #     fusion carries one tf_op, so what the compiler fuses in (a bias,
+    #     a residual add, the next norm's statistics) rides under the
+    #     name; the benchmark's metrics sum every dstpu.mm.* (pbench/
+    #     weights.py). They nest inside dstpu.ssm.mix / attn.diff / gmu.
+    "dstpu.mm.qkv":
+        "the q / k / v projection of an attention layer (gpt2, llama, "
+        "phi4flash; a cross-decoder layer's q alone), forward and, in a "
+        "training step, its backward and recomputation",
+    "dstpu.mm.attn_out":
+        "an attention layer's output projection",
+    "dstpu.mm.mlp":
+        "a dense MLP's products (up / gate and down) and the activation "
+        "between them; not the routed experts (dstpu.moe.experts)",
+    "dstpu.mm.unembed":
+        "the logits: hidden states against the (tied) embedding, in a "
+        "training step the fused cross-entropy kernel with it",
+    "dstpu.mm.in_proj":
+        "Mamba mixer: the input projection to u and the gate z",
+    "dstpu.mm.x_proj":
+        "Mamba mixer: the projection to dt's rank and B, C",
+    "dstpu.mm.dt":
+        "Mamba mixer: dt's rank up to the inner width",
+    "dstpu.mm.out_proj":
+        "Mamba mixer: the gated scan output back to the model width",
+    "dstpu.mm.gmu":
+        "Gated Memory Unit: its gate and its output projection",
 }
 
 

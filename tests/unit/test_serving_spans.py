@@ -7,6 +7,7 @@ schema's in test_telemetry.py).
 Engines follow test_router.py's fast pattern: tiny GPT2, module-cached
 params; one profiler capture per engine kind, shared by the tests."""
 
+import copy
 import os
 import re
 import sys
@@ -30,7 +31,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, os.path.join(REPO, "perfbench"))
 from pbench import (common as pb_common, moe as pb_moe,  # noqa: E402
-                    ssm as pb_ssm, trace as pb_trace)
+                    ssm as pb_ssm, trace as pb_trace,
+                    weights as pb_weights)
 
 _CFG = GPT2Config(n_layer=2, n_head=4, d_model=64, max_seq_len=128,
                   vocab_size=256, remat=False, dtype="float32")
@@ -603,9 +605,327 @@ def test_cache_bytes_per_live_token_reader(kind, request):
     assert reader.read(view) is None
 
 
+WEIGHT_READERS = ("weights_roofline", "weights_share",
+                  "train_weights_roofline")
+_FIXTURES = os.path.join(REPO, "perfbench", "fixtures")
+
+
+def _cell_sizes(config):
+    """The ``sizes`` a cell of this configuration hands its readers."""
+    cfg = pb_common.load_json("configs", config + ".json")
+    return pb_common.load_module("builders", cfg["builder"]).sizes(cfg)
+
+
+def _weights_view(trace, sizes, said=None):
+    return types.SimpleNamespace(
+        trace=trace, sizes=sizes, chips=1, workload="a-cell",
+        peaks=pb_common.peaks_for("TPU v5 lite"),
+        counters={"tokens_traced": 4096, "traced_prompts": [40]},
+        say=(lambda line, **f: said.append((line, f))) if said is not None
+        else (lambda line, **f: None))
+
+
+def _restated(tr, **changes):
+    """A shallow copy of a Trace with some attributes replaced."""
+    out = copy.copy(tr)
+    for k, v in changes.items():
+        setattr(out, k, v)
+    return out
+
+
+def _unscoped_traces(bucketed, splitfuse):
+    """Traces of programs that open no ``dstpu.mm.*`` scope on a device, as
+    the parent commit's are to this PR's readers, with what a cell's slice
+    can lack: any dispatch at all, the spans' stats, the family's sizes."""
+    tr = bucketed[0]
+    bare = [(line, pb_trace.Event(e.name, e.start, e.end, {
+        k: v for k, v in e.stats.items()
+        if k not in ("kind", "active", "steps", "tokens", "chunk_tokens")}))
+        for line, e in tr.host]
+    return {
+        "tiny4-gpt2m": (pb_trace.Trace(os.path.join(
+            _FIXTURES, "tiny4.xplane.pb")), _cell_sizes("gpt2-medium")),
+        "tiny4-opt": (pb_trace.Trace(os.path.join(
+            _FIXTURES, "tiny4.xplane.pb")), _cell_sizes("opt-1.3b")),
+        "moe1-phi4": (pb_trace.Trace(os.path.join(
+            _FIXTURES, "moe1.xplane.pb")), _cell_sizes("phi-4-mini-flash")),
+        "moe1-olmoe": (pb_trace.Trace(os.path.join(
+            _FIXTURES, "moe1.xplane.pb")), _cell_sizes("olmoe-1b-7b")),
+        "no-trace": (None, _cell_sizes("gpt2-medium")),
+        "no-dispatch": (_restated(tr, t0=tr.t0, t1=tr.t0 + 1e-9),
+                        _cell_sizes("gpt2-medium")),
+        "splitfuse": (splitfuse[0], _cell_sizes("opt-1.3b")),
+        "bare-spans": (_restated(tr, host=bare), _cell_sizes("gpt2-medium")),
+        "olmoe-sizes": (tr, _cell_sizes("olmoe-1b-7b")),
+        "no-sizes": (tr, {}),
+    }
+
+
+@pytest.mark.parametrize("metric", WEIGHT_READERS)
+@pytest.mark.parametrize("case", [
+    "tiny4-gpt2m", "tiny4-opt", "moe1-phi4", "moe1-olmoe", "no-trace",
+    "no-dispatch", "splitfuse", "bare-spans", "olmoe-sizes", "no-sizes"])
+def test_weights_reader_stands_on_a_program_without_its_scopes(
+        metric, case, bucketed, splitfuse):
+    """ISSUE 38's first criterion, on the CPU: each reader of the dense
+    weight products returns None, says nothing and does not raise on a
+    trace that names no ``dstpu.mm.*`` (PR 37 was refused because one of
+    its readers raised on the parent's program in cell 4, whose slice often
+    holds no fused dispatch)."""
+    trace, sizes = _unscoped_traces(bucketed, splitfuse)[case]
+    said = []
+    reader = pb_common.load_module("layer_metrics", metric)
+    assert reader.read(_weights_view(trace, sizes, said)) is None
+    assert not said
+    assert reader.read(types.SimpleNamespace(trace=trace)) is None
+
+
+def _device_event(name, start):
+    return types.SimpleNamespace(name=name, start=float(start),
+                                 end=start + 1.0, self_s=1.0)
+
+
+def _host_span(start, end, **stats):
+    return types.SimpleNamespace(start=float(start), end=float(end),
+                                 dur=float(end - start), stats=stats)
+
+
+_SYNTH_SIZES = dict(n_layer=2, n_head=4, n_kv_head=4, d_head=64, d_model=256,
+                    d_ff=1024, vocab_size=512, vocab_rows=512)
+
+
+def _synthetic(monkeypatch, order, spans=None, **changes):
+    """One device whose operations, a second each, are ``order`` (q a qkv
+    product, m a Mamba in-projection nested in ``dstpu.ssm.mix``, u an
+    unembed, a a paged read, x an operation under no scope), a window of
+    [3, 11.5) and 9 busy seconds."""
+    ops = {"q": "jit(decode)/dstpu.mm.qkv/dot_general",
+           "m": "jit(decode)/dstpu.ssm.mix/dstpu.mm.in_proj/dot_general",
+           "u": "jit(decode)/jvp(dstpu.mm.unembed)/dot_general",
+           "a": "jit(decode)/dstpu.attn.window/pallas_call",
+           "x": "jit(decode)/argmax"}
+    monkeypatch.setattr(pb_moe, "op_scopes", lambda path, prefix: ops)
+    trace = types.SimpleNamespace(
+        path="p", t0=3.0, t1=11.5, mm_walk=None, busy_s=lambda: 9.0,
+        devices={"d0": [_device_event(n, i) for i, n in enumerate(order)]},
+        host_spans=lambda name: (spans or {}).get(name, []))
+    for k, v in changes.items():
+        setattr(trace, k, v)
+    return trace
+
+
+def test_weights_walk_counts_passes_by_runs_of_unembed(monkeypatch):
+    """The arithmetic of ``pbench/weights.py`` on a made-up device: own
+    seconds under any ``dstpu.mm.*`` inside the window; a pass is closed by
+    a RUN of unembed events, counts by the share of its seconds inside the
+    window, and not at all where the trace lacks its beginning (the first)
+    or its unembed (the last); a prefill's or chunk's operations count
+    only beyond one read of the weights, by the span's share of the
+    window."""
+    order = "qu" "qmau" "x" "quu" "qmu" "q"
+    spans = {
+        "dstpu.engine.prefill": [_host_span(4, 6, tokens=1000, padded=1024)],
+        "dstpu.engine.dispatch": [
+            _host_span(11, 12, kind="chunk", chunk_tokens=300, steps=0),
+            _host_span(7, 8, kind="decode", chunk_tokens=0, steps=2),
+            _host_span(8, 9, kind="fused", chunk_tokens=100, steps=2)]}
+    said = []
+    view = _weights_view(_synthetic(monkeypatch, order, spans),
+                         _SYNTH_SIZES, said)
+    got = pb_weights.walk(view)
+    assert got["mm_s"] == 7.0 and got["busy_s"] == 9.0
+    assert got["passes"] == pytest.approx(2 / 3 + 1 + 2 / 3)
+    assert got["by_scope"] == {"dstpu.mm.in_proj": 2.0, "dstpu.mm.qkv": 2.0,
+                               "dstpu.mm.unembed": 3.0}
+    assert said[0][0] == "weights_device_seconds"
+    assert said[0][1]["under_no_dstpu_scope_s"] == 1.0
+    layers, unembed = pb_weights.matmul_params(_SYNTH_SIZES)
+    assert (layers, unembed) == (2 * 12 * 256 * 256, 512 * 256)
+    peak, bw = view.peaks["bf16_flops_per_s"], view.peaks["hbm_bytes_per_s"]
+    read = 2 * layers / bw
+    least = got["passes"] * 2 * (layers + unembed) / bw \
+        + (1000 * 2 * layers / peak - read) \
+        + 0.5 * (300 * 2 * layers / peak - read)    # 100 tokens: under it
+    reads = {m: pb_common.load_module("layer_metrics", m).read(view)
+             for m in WEIGHT_READERS}
+    assert reads["weights_share"] == pytest.approx(100 * 7 / 9)
+    assert reads["weights_roofline"] == pytest.approx(100 * least / 7)
+    assert reads["train_weights_roofline"] == pytest.approx(
+        100 * 6 * (layers + unembed) * 4096 / peak / 7)
+    assert [line for line, _ in said].count("weights_device_seconds") == 1
+
+
+@pytest.mark.parametrize("lacks", ["spans", "stats", "host_spans", "unembed",
+                                   "sizes", "peaks", "counters"])
+def test_weights_reader_on_scopes_without_the_rest(monkeypatch, lacks):
+    """With the scopes in the trace and something else missing, a reader
+    gives the number that does not need it, or None; it never raises."""
+    order = "qu" "qmau" "x" "quu" "qmu" "q"
+    bare = {"dstpu.engine.prefill": [_host_span(4, 6)],
+            "dstpu.engine.dispatch": [_host_span(11, 12),
+                                      _host_span(8, 9, kind="fused",
+                                                 chunk_tokens="many")]}
+    trace = _synthetic(monkeypatch,
+                       order.replace("u", "x") if lacks == "unembed"
+                       else order, bare if lacks == "stats" else None)
+    if lacks == "host_spans":
+        del trace.host_spans
+    view = _weights_view(trace, {"d_model": 256} if lacks == "sizes"
+                         else _SYNTH_SIZES)
+    if lacks == "peaks":
+        del view.peaks
+    if lacks == "counters":
+        view.counters = {}
+    reads = {m: pb_common.load_module("layer_metrics", m).read(view)
+             for m in WEIGHT_READERS}
+    assert reads["weights_share"] == pytest.approx(
+        100 * (4 if lacks == "unembed" else 7) / 9)
+    if lacks in ("unembed", "sizes", "peaks"):
+        assert reads["weights_roofline"] is None
+    else:       # the passes alone: no span adds a prefill's operations
+        layers, unembed = pb_weights.matmul_params(_SYNTH_SIZES)
+        assert reads["weights_roofline"] == pytest.approx(
+            100 * (7 / 3) * 2 * (layers + unembed)
+            / view.peaks["hbm_bytes_per_s"] / 7)
+    assert (reads["train_weights_roofline"] is None) == (
+        lacks in ("sizes", "peaks", "counters"))
+
+
+def test_weights_count_of_the_cells():
+    """The dense weights of one pass, from the cells' own ``sizes``: the
+    block and head of GPT-2 medium and OPT-1.3B, Phi-4-mini-flash's whole
+    stack against the program's own parameter count, nothing for OLMoE."""
+    layers, unembed = pb_weights.matmul_params(_cell_sizes("gpt2-medium"))
+    assert (layers, unembed) == (24 * 12 * 1024 ** 2, 50304 * 1024)
+    layers, unembed = pb_weights.matmul_params(_cell_sizes("opt-1.3b"))
+    assert layers == 24 * 12 * 2048 ** 2 and unembed % 2048 == 0
+    from deepspeed_tpu.models.phi4flash import PHI4_MINI_FLASH
+    layers, unembed = pb_weights.matmul_params(
+        _cell_sizes("phi-4-mini-flash"))
+    assert unembed == 200064 * 2560
+    # what the program holds beyond its products: norms, biases, the conv,
+    # A, D, dt's bias and the lambda vectors, under a thousandth of it
+    rest = PHI4_MINI_FLASH.num_params() - layers - unembed
+    assert 0 < rest < 1e-3 * PHI4_MINI_FLASH.num_params()
+    assert pb_weights.matmul_params(_cell_sizes("olmoe-1b-7b")) is None
+    assert pb_weights.matmul_params(None) is None
+
+
+@pytest.mark.parametrize("metric", WEIGHT_READERS)
+def test_weights_reader_on_the_recorded_trace(metric):
+    """The recorded values on ``fixtures/dense1.xplane.pb`` (a two-layer
+    GPT-2 served on the v5e, ``fixtures/record_dense.py``): the device's own
+    ``tf_op`` table, the fusions under the names the TPU compiler gives
+    them, and a roofline under 100 %."""
+    want = pb_common.load_json("fixtures", "dense1.expected.json")
+    said = []
+    view = _weights_view(pb_trace.Trace(os.path.join(
+        _FIXTURES, "dense1.xplane.pb")), want["sizes"], said)
+    view.counters = want["counters"]
+    value = pb_common.load_module("layer_metrics", metric).read(view)
+    assert value == pytest.approx(want["values"][metric], rel=1e-9)
+    assert 0.0 < value < 100.0
+    assert said[0][0] == "weights_device_seconds"
+    assert said[0][1]["passes"] == pytest.approx(want["walk"]["passes"])
+    scoped = pb_moe.op_scopes(view.trace.path, "/device:TPU:")
+    assert {sc for name in scoped.values()
+            for sc in SCOPE_SCHEMA if sc in name} \
+        == {pb_weights.PREFIX + n
+            for n in ("qkv", "attn_out", "mlp", "unembed")}
+    # and the readers of the other scopes find nothing of theirs in it
+    for other in MOE_READERS + SSM_READERS:
+        assert pb_common.load_module("layer_metrics", other).read(
+            types.SimpleNamespace(trace=view.trace, say=view.say)) is None
+
+
+def _scopes_named(text):
+    return {sc for sc in SCOPE_SCHEMA if sc.startswith(pb_weights.PREFIX)
+            and sc in text}
+
+
+def test_gpt2_decode_trace_names_the_weight_scopes(bucketed, splitfuse):
+    """The served GPT-2's programs carry the four ``dstpu.mm.*`` names of
+    its family into the profiler's file (on the CPU the names are in the
+    programs' metadata the trace keeps; the device's ``tf_op`` table that
+    ``op_scopes`` reads exists on the chip alone: ``fixtures/dense1``)."""
+    want = {pb_weights.PREFIX + n
+            for n in ("qkv", "attn_out", "mlp", "unembed")}
+    for tr, _ in (bucketed, splitfuse):
+        with open(tr.path, "rb") as f:
+            assert _scopes_named(f.read().decode("latin-1")) == want
+
+
+def test_phi4flash_decode_trace_names_every_weight_scope(tmp_path):
+    """A served tiny Phi-4-mini-flash names all nine: its family opens the
+    five of the Mamba mixer and the GMU too."""
+    import dataclasses
+    from deepspeed_tpu.models.phi4flash import PHI4FLASH_TINY, Phi4Flash
+    groups.reset()
+    model = Phi4Flash(dataclasses.replace(PHI4FLASH_TINY, dtype="float32"))
+    engine = InferenceEngineV2(model, dict(
+        dtype="float32", max_batch_size=2, kv_block_size=4, prompt_bucket=8,
+        num_kv_blocks=48, decode_steps_per_dispatch=1))
+    prompt = np.arange(1, 7, dtype=np.int32)
+    engine.generate_all([prompt], 2)            # compile outside the capture
+    with pb_trace.capture(str(tmp_path)):
+        engine.generate_all([prompt], 3)
+    with open(pb_trace.find_xplane(str(tmp_path)), "rb") as f:
+        named = _scopes_named(f.read().decode("latin-1"))
+    assert named == set(pb_weights.SCOPES) == {
+        sc for sc in SCOPE_SCHEMA if sc.startswith(pb_weights.PREFIX)}
+
+
+def _op_names(compiled):
+    return set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
+
+
+def test_train_step_names_the_weight_scopes_forward_and_backward():
+    """Forward, backward and rematerialised operations of a tiny GPT-2's
+    loss gradient keep the scope inside ``jvp(...)`` / ``transpose(jvp(...))``
+    / ``checkpoint/rematted_computation`` paths, which is why the readers
+    match by substring."""
+    model = GPT2(GPT2Config(n_layer=2, n_head=4, d_model=64, max_seq_len=64,
+                            vocab_size=256, remat=True, dtype="float32"))
+    ids = np.zeros((2, 64), np.int32)
+    names = _op_names(jax.jit(jax.grad(lambda p: model.loss(
+        p, {"input_ids": ids}, train=True, rng=jax.random.key(1)))).lower(
+            model.init(jax.random.key(0))).compile())
+    for scope in ("qkv", "attn_out", "mlp", "unembed"):
+        mine = {n for n in names if pb_weights.PREFIX + scope in n}
+        assert any("transpose(jvp(" in n for n in mine), scope
+        assert any("transpose(" not in n for n in mine), scope
+    assert any("rematted_computation/dstpu.mm.mlp" in n for n in names)
+
+
+def test_ssm_selection_is_unchanged_by_the_nested_weight_scopes():
+    """``pbench/ssm.py`` gives an operation to the FIRST of its ``SCOPES``
+    the ``tf_op`` names: with ``dstpu.mm.*`` nested inside ``dstpu.ssm.mix``
+    / ``attn.diff`` / ``gmu`` every operation of the tiny Phi-4's program
+    is selected as it was with the nested names taken out again."""
+    import dataclasses
+    from deepspeed_tpu.models.phi4flash import PHI4FLASH_TINY, Phi4Flash
+    model = Phi4Flash(dataclasses.replace(PHI4FLASH_TINY, dtype="float32"))
+    names = _op_names(jax.jit(model.apply).lower(
+        model.init(jax.random.key(0)), np.zeros((1, 16), np.int32)).compile())
+
+    def select(name):
+        return next((sc for sc in pb_ssm.SCOPES if sc in name), None)
+
+    nested = [n for n in names if pb_weights.PREFIX in n and select(n)]
+    assert {select(n) for n in nested} == {
+        pb_ssm.SSM_MIX, pb_ssm.ATTN_DIFF, pb_ssm.GMU}
+    for n in names:
+        assert select(n) == select(re.sub(r"dstpu\.mm\.[a-z_]+/?", "", n))
+    # the MLP and the unembed are under no scope of pbench/ssm.py
+    assert any(select(n) is None for n in names
+               if pb_weights.PREFIX + "mlp" in n)
+
+
 _SPAN_RE = re.compile(r"""\bspan\(\s*["'](dstpu\.[A-Za-z0-9_.]+)["']""")
+# a scope is opened by its literal, or handed to phi4flash's ``_mm`` as the
+# last argument of the call
 _SCOPE_RE = re.compile(
-    r"""\bnamed_scope\(\s*["'](dstpu\.[A-Za-z0-9_.]+)["']""")
+    r"""(?:\bnamed_scope\(\s*|,\s*)["'](dstpu\.[A-Za-z0-9_.]+)["']\s*\)""")
 
 
 def _opened(rx):
@@ -625,8 +945,8 @@ def test_scope_schema_lint_both_directions():
     assert set(SCOPE_SCHEMA) - opened == set(), "registered, never opened"
     assert all(n.startswith("dstpu.") and m for n, m in SCOPE_SCHEMA.items())
     # the benchmark's readers look for the same names
-    assert {pb_moe.SCOPE_EXPERTS, *pb_moe.SCOPES_ROUTE, *pb_ssm.SCOPES} \
-        == set(SCOPE_SCHEMA)
+    assert {pb_moe.SCOPE_EXPERTS, *pb_moe.SCOPES_ROUTE, *pb_ssm.SCOPES,
+            *pb_weights.SCOPES} == set(SCOPE_SCHEMA)
 
 
 def test_span_schema_lint_both_directions():

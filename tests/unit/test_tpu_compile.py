@@ -522,6 +522,7 @@ def test_two_piece_product_keeps_its_rounding(v5e):
     from deepspeed_tpu.models.phi4flash import _mm
     args = [jax.ShapeDtypeStruct((64, 1, 2560), jnp.float32, sharding=v5e),
             jax.ShapeDtypeStruct((2560, 10240), bf16, sharding=v5e)]
-    text = jax.jit(_mm).lower(*args).compile().as_text()
+    text = jax.jit(lambda x, w: _mm(x, w, "dstpu.mm.mlp")).lower(
+        *args).compile().as_text()
     assert "reduce-precision(" in text
     assert re.search(r"bf16\[2,64,(1,)?2560\]", text)
