@@ -345,6 +345,12 @@ class InferenceEngineV2:
 
         dtype = jnp.dtype(config.dtype)
         self.dtype = dtype
+        # (table entries a grid step of the decode kernel takes, the
+        # layers that call it by their window): what the dispatch spans
+        # count its steps by
+        from ...models.paged import decode_kernel_calls
+        self._decode_calls = decode_kernel_calls(
+            self.model, self.max_blocks_per_seq, BS, dtype)
         self.params, self.param_shardings = shard_params(
             model, self.mesh, dtype, params=params, seed=config.seed,
             topology=topology,
@@ -1233,24 +1239,29 @@ class InferenceEngineV2:
         ``active``: a count, or — with ``batch`` = (lengths, block
         tables), where the dispatch runs the paged-decode kernel over
         the batch — the live slots' mask, and the span then says how
-        much of the block table the decode kernel's grid walks and how
-        many of the rows offered to the KV write are live; the chunk's
-        ``chunk_tokens`` of ``chunk_rows`` count with them (host
-        arithmetic, no device read)."""
+        much of the block table the decode kernel visits, in how many
+        grid steps, and how many of the rows offered to the KV write are
+        live; the chunk's ``chunk_tokens`` of ``chunk_rows`` count with
+        them (host arithmetic, no device read)."""
         slots = self.config.max_batch_size
-        grid_steps = table_entries = 0
+        grid_steps = kernel_steps = table_entries = 0
         write_rows, write_rows_offered = chunk_tokens, chunk_rows
         if batch is not None:
             lengths, tables = batch
-            cfg = self.model.config
             MB, BS = self.max_blocks_per_seq, self.state_mgr.block_size
-            windows = getattr(cfg, "attn_layer_windows", ()) \
-                or (getattr(cfg, "sliding_window", 0),)
-            # a kernel call's steps, the layers' mean where their windows
-            # differ, over the dispatch's decode steps
-            grid_steps = round(sum(decode_grid_steps(
-                lengths, active, MB, BS, w, steps) for w in windows)
-                / len(windows))
+            entries_per_step, windows = self._decode_calls
+
+            def per_call(per_step):
+                # a kernel call's, the layers' mean where their windows
+                # differ, over the dispatch's decode steps
+                return round(sum(layers * decode_grid_steps(
+                    lengths, active, MB, BS, w, steps, per_step)
+                    for w, layers in windows.items())
+                    / sum(windows.values()))
+
+            grid_steps = per_call(1)
+            kernel_steps = grid_steps if entries_per_step == 1 \
+                else per_call(entries_per_step)
             table_entries = steps * slots * MB
             write_rows += kv_write_live_rows(lengths, tables, BS, steps)
             write_rows_offered += steps * slots
@@ -1260,14 +1271,14 @@ class InferenceEngineV2:
         if self.telemetry is not None:
             if steps:
                 self.telemetry.on_decode_batch(active, slots, grid_steps,
-                                               table_entries)
+                                               table_entries, kernel_steps)
             self.telemetry.on_kv_write(write_rows, write_rows_offered)
             if kind == "decode":
                 self.telemetry.on_plain_decode(chained, steps * active)
         return span("dstpu.engine.dispatch", kind=kind, active=active,
                     slots=slots, steps=steps, chunk_tokens=chunk_tokens,
                     grid_steps=grid_steps, table_entries=table_entries,
-                    write_rows=write_rows,
+                    kernel_steps=kernel_steps, write_rows=write_rows,
                     write_rows_offered=write_rows_offered,
                     expert_calls=expert_calls,
                     expert_kernel_calls=expert_kernel_calls,

@@ -38,6 +38,7 @@ the slot, which the chunk and prefill programs are therefore told.
 """
 
 import math
+from collections import Counter
 from dataclasses import dataclass
 from typing import Any
 
@@ -45,8 +46,9 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.pallas.paged_attention import (
-    PAGED_CHUNK_BLOCK_C, alibi_slopes, decode_work_list, kv_write_row_list,
-    paged_chunk_attention, paged_decode_attention,
+    PAGED_CHUNK_BLOCK_C, alibi_slopes, decode_entries_per_step,
+    decode_work_list, kv_write_row_list, paged_chunk_attention,
+    paged_decode_attention,
     paged_decode_attention_reference, paged_kv_write, resolve_paged_chunk,
     resolve_paged_decode)
 
@@ -121,6 +123,19 @@ def uses_decode_kernel(model, B, MB, BS, dtype):
     BS-token blocks runs ``paged_decode_attention``: the answer the
     decode trace takes, and the one the engine sizes the pools by."""
     return _decode_kernel(geometry(model), B, MB, BS, dtype)
+
+
+def decode_kernel_calls(model, MB, BS, dtype):
+    """What the engine's telemetry counts the kernel calls of ``model``'s
+    decode step by: the table entries of a slot one grid step takes
+    (``decode_entries_per_step`` of its geometry), and ``{window: the
+    layers that call it with that window}`` (layers with a paged table:
+    not one of recurrent state, nor one with no cache)."""
+    geom = geometry(model)
+    windows = Counter(w for w, kind in zip(geom.windows, geom.kinds)
+                      if kind in (KV, RING) or isinstance(kind, tuple))
+    return decode_entries_per_step(geom.n_kv_heads, BS, geom.d_head, dtype,
+                                   MB), dict(windows)
 
 
 def _chunk_kernel(geom, C, MB, BS):
@@ -316,9 +331,12 @@ def batch_step(geom, cache, lengths, block_tables, C):
 
     if C == 1:
         use_kernel = _decode_kernel(geom, B, MB, BS, geom.dtype)
-        # the decode kernel's grid: this step's live (slot, block) pairs,
-        # one list per window size, shared by every layer that has it
-        work = {w: decode_work_list(lengths, MB, BS, w, active=active)
+        # the decode kernel's grid: this step's runs of live blocks a
+        # slot, one list per window size, shared by every layer that has it
+        per_step = decode_entries_per_step(geom.n_kv_heads, BS, geom.d_head,
+                                           cache["k"][0].dtype, MB)
+        work = {w: decode_work_list(lengths, MB, BS, w, active=active,
+                                    per_step=per_step)
                 for w in set(geom.windows)} if use_kernel else {}
         alibi = dict(
             alibi_slopes=alibi_slopes(geom.n_head),

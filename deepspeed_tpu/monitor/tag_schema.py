@@ -246,7 +246,8 @@ SPAN_SCHEMA = {
                    "expert_kernel_calls as on dstpu.engine.dispatch"},
     "dstpu.engine.dispatch": {
         "stats": ("kind", "active", "slots", "steps", "chunk_tokens",
-                  "grid_steps", "table_entries", "write_rows",
+                  "grid_steps", "table_entries", "kernel_steps",
+                  "write_rows",
                   "write_rows_offered", "expert_calls",
                   "expert_kernel_calls", "chained", "late_steps"),
         "meaning": "one decode-bearing or chunk program call (kind "
@@ -265,8 +266,11 @@ SPAN_SCHEMA = {
                    "the one before it had ended (an EOS is seen one "
                    "dispatch late; a budget never is); grid_steps "
                    "of table_entries = how much of the block table one "
-                   "paged-decode kernel call walks, over the dispatch's "
-                   "decode steps; write_rows of write_rows_offered = the "
+                   "paged-decode kernel call visits, over the dispatch's "
+                   "decode steps, and kernel_steps = the grid steps it "
+                   "takes them in, several entries of a slot a step (a "
+                   "grid step took one entry when grid_steps was named); "
+                   "write_rows of write_rows_offered = the "
                    "live rows (destination not scratch block 0) among "
                    "those one layer's KV writes are handed: slots x "
                    "steps, and a chunk's chunk_tokens of its C (0 / 0 on "

@@ -823,10 +823,12 @@ class ServingTelemetry:
         # dispatches since engine construction
         self._occ_active = 0
         self._occ_slots = 0
-        # the paged-decode kernel's grid steps against the block table's
-        # entries, summed over the same dispatches
+        # the table entries the paged-decode kernel visits against the
+        # block table's, and the grid steps it takes them in, summed over
+        # the same dispatches
         self._grid_steps = 0
         self._table_entries = 0
+        self._kernel_steps = 0
         # live rows against the rows offered to the paged KV write, summed
         # over every dispatch (chunk-only ones too)
         self._write_rows = 0
@@ -903,15 +905,16 @@ class ServingTelemetry:
         return wait_ms
 
     def on_decode_batch(self, active, slots, grid_steps=0,
-                        table_entries=0):
+                        table_entries=0, kernel_steps=0):
         """One decode-bearing dispatch ran with ``active`` of ``slots``
-        batch slots live, its decode kernel walking ``grid_steps`` of the
-        block table's ``table_entries`` (a call's, over the dispatch's
-        decode steps)."""
+        batch slots live, its decode kernel visiting ``grid_steps`` of
+        the block table's ``table_entries`` in ``kernel_steps`` grid
+        steps (a call's, over the dispatch's decode steps)."""
         self._occ_active += active
         self._occ_slots += slots
         self._grid_steps += grid_steps
         self._table_entries += table_entries
+        self._kernel_steps += kernel_steps
 
     def on_kv_write(self, live, offered):
         """One dispatch offered ``offered`` rows to the paged KV write
@@ -1091,6 +1094,9 @@ class ServingTelemetry:
         if self._table_entries:
             out["decode_grid_share"] = round(
                 self._grid_steps / self._table_entries, 4)
+        if self._kernel_steps:
+            out["decode_entries_per_step"] = round(
+                self._grid_steps / self._kernel_steps, 4)
         if self._write_rows_offered:
             out["kv_write_live_share"] = round(
                 self._write_rows / self._write_rows_offered, 4)

@@ -10,15 +10,17 @@ The jnp fallback path gathers every slot's blocks into a dense
 (B, S, H, d) copy and runs masked-dense attention — O(B * MB * BS) HBM
 traffic in COPIES per layer, then attention over the fully padded length.
 This kernel instead streams each KV block through VMEM exactly once,
-indexed directly by the block table (scalar-prefetch index_map — the block
-id picked per grid step comes from the table in SMEM), with online softmax
-across blocks. Its grid is not the table but the list of (slot, table
-entry) pairs that hold something to attend (:func:`decode_work_list`,
-made on the device from ``lengths`` once a decode step, its length a
-device scalar): a grid step costs its ~0.3 us whether or not its block is
-live, and a table is mostly tail — entries past a sequence's length, and
-whole rows of inactive slots — so a step for every entry cost several
-times the live blocks' DMA (PERF.md, PR 27).
+located through the block table in SMEM, with online softmax across
+blocks. Its grid is not the table but the list of runs of consecutive
+table entries of one slot that hold something to attend
+(:func:`decode_work_list`, made on the device from ``lengths`` once a
+decode step, its length a device scalar): a grid step costs its ~0.3 us
+whether or not its block is live, and a table is mostly tail — entries
+past a sequence's length, and whole rows of inactive slots — so a step
+for every entry cost several times the live blocks' DMA (PERF.md, PR 27);
+and a live step costs that 0.3 us beside its DMA however little it moves,
+so a step takes as many entries as make its DMA worth the step
+(:func:`decode_entries_per_step`, from the block's bytes; PERF.md, PR 39).
 
 GQA is native: q heads fold to (KVH, G, d) and both dots batch over KVH —
 no repeat_kv materialization.
@@ -39,6 +41,8 @@ PR 29), and at program boundaries the block axis takes the shape
 
 import functools
 import math
+import operator
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -139,67 +143,162 @@ def _attended_entries(xp, lengths, MB, BS, window):
     return xp.minimum(xp.maximum(lengths - window + 1, 0) // BS, last), last
 
 
-def decode_grid_steps(lengths, active, MB, BS, window=0, steps=1):
-    """The count ``n`` of :func:`decode_work_list`, on the host in numpy
-    and summed over ``steps`` consecutive decode steps (a dispatch's:
-    every slot's length grows by one a step): what the engine's
-    telemetry sets against the ``steps * B * MB`` table entries."""
+# A grid step of the decode kernel costs ~0.3 us beside its DMA whatever
+# it moves, so a step takes table entries until its K + V bytes reach
+# _STEP_BYTES (at 1.0-1.3 MB a step a block visit reads 87-89 % of the HBM
+# peak, at 0.33-0.52 MB 55-70 %), and no more than leave its buffers
+# inside _STEP_VMEM. _AHEAD items' blocks are on their way while one is
+# computed on, each in a buffer of its own: with one, a slot's short last
+# run (a window's ninth ring entry) drained the queue, -16 % a block visit
+# at nine entries a slot; a third wins nothing (the kernel alone on a
+# v5e, benchmarks/paged_decode_sweep.py: PERF.md, PR 39).
+_STEP_BYTES = 1 << 20
+_STEP_VMEM = 8 << 20
+_AHEAD = 2
+
+
+def decode_entries_per_step(KVH, BS, d, dtype, MB):
+    """N, the table entries of one slot a grid step of
+    :func:`paged_decode_attention` takes: from the bytes a block's K and V
+    hold in the pools and nothing else. A pool whose rows are narrower
+    than the 128 lanes gives 1: several entries a step are fetched by
+    copies the kernel issues itself, and Mosaic cannot cut a block out
+    of an array in HBM whose minor dimension is padded ("Slice shape
+    along dimension 3 must be aligned to tiling (128), but is 64": jax
+    0.9.0; PERF.md, PR 39)."""
+    if d % 128:
+        return 1
+    block = 2 * KVH * BS * d * jnp.dtype(dtype).itemsize
+    n = min(-(-_STEP_BYTES // block), _STEP_VMEM // ((_AHEAD + 1) * block))
+    return max(1, min(n, MB))
+
+
+def decode_grid_steps(lengths, active, MB, BS, window=0, steps=1,
+                      per_step=1):
+    """The count ``n`` of :func:`decode_work_list` at ``per_step`` entries
+    an item, on the host in numpy and summed over ``steps`` consecutive
+    decode steps (a dispatch's: every slot's length grows by one a step).
+    At ``per_step`` 1 it is the entries the kernel visits, which the
+    engine's telemetry sets against the ``steps * B * MB`` table entries;
+    at the kernel's own N, its grid steps."""
     lengths, active = np.asarray(lengths), np.asarray(active, bool)
     first, last = _attended_entries(
         np, lengths[active][:, None] + np.arange(steps), MB, BS, window)
-    return int(np.sum(last - first + 1))
+    return int(np.sum(-(-(last - first + 1) // per_step)))
 
 
-def decode_work_list(lengths, MB, BS, window=0, active=None):
-    """The decode kernel's grid, as data: the (slot, table entry) pairs
-    that hold something the new token attends.
+class DecodeWork(NamedTuple):
+    """:func:`decode_work_list`'s items: ``int32[items + _AHEAD]`` arrays,
+    the items' count ``n`` on the device, and the static ``per_step``
+    they were cut by."""
+    slot_of: jax.Array
+    entry_of: jax.Array
+    count_of: jax.Array
+    n: jax.Array
+    per_step: int
+
+
+def decode_work_list(lengths, MB, BS, window=0, active=None, per_step=1):
+    """The decode kernel's grid, as data: the runs of up to ``per_step``
+    consecutive table entries of one slot that hold something the new
+    token attends.
 
     lengths: (B,) int32, the new token's position per slot; MB: table
     entries a slot; ``window`` as :func:`paged_decode_attention` takes
     it; ``active``: (B,) bool, the slots that hold a sequence (all, when
-    not given). Active slot b contributes entries ``first[b] ..
-    lengths[b] // BS`` (``first`` is 0 without a window, else the entry
-    holding position ``lengths[b] - window + 1``); an inactive slot
-    contributes nothing. Returns ``(slot_of, entry_of, n)``: two
-    ``int32[B*MB + 1]`` arrays, slot-major, and the count of pairs;
-    ``slot_of`` reads B from item ``n`` on, so a slot's last item is the
-    one whose successor names another slot.
+    not given). Active slot b attends entries ``first[b] .. lengths[b]
+    // BS`` (``first`` is 0 without a window, else the entry holding
+    position ``lengths[b] - window + 1``), ``e`` of them, and
+    contributes ``ceil(e / per_step)`` items, each full but the last; an
+    inactive slot contributes nothing. Returns a :class:`DecodeWork`,
+    slot-major: item i is entries ``entry_of[i] .. entry_of[i] +
+    count_of[i] - 1`` of slot ``slot_of[i]``. From item ``n`` on
+    ``slot_of`` reads B, so a slot's last item is the one whose
+    successor names another slot, and ``count_of`` reads 0 (for
+    ``_AHEAD`` items), so the kernel fetches nothing past the last item.
 
     A handful of small integer operations: compute it once a decode step
     and hand it to every layer's call (layers with another ``window``
     take a list of their own)."""
-    B = lengths.shape[0]
+    B, N = lengths.shape[0], int(per_step)
     first, last = _attended_entries(jnp, lengths, MB, BS, window)
-    n_blocks = last - first + 1
+    n_items = (last - first + N) // N
     if active is not None:
-        n_blocks = jnp.where(active, n_blocks, 0)
-    ends = jnp.cumsum(n_blocks)                          # (B,) running sum
-    item = jnp.arange(B * MB + 1, dtype=jnp.int32)
+        n_items = jnp.where(active, n_items, 0)
+    ends = jnp.cumsum(n_items)                           # (B,) running sum
+    item = jnp.arange(B * -(-MB // N) + _AHEAD, dtype=jnp.int32)
     # item i belongs to the first slot whose running sum passes i. As
     # compares and sums over (items, B): a searchsorted or a gather here
     # is a loop on the TPU, ~140 us a decode step (PERF.md, PR 27)
     slot_of = jnp.sum(ends[None, :] <= item[:, None], axis=1,
                       dtype=jnp.int32)
     mine = slot_of[:, None] == jnp.arange(B, dtype=jnp.int32)[None, :]
-    entry_of = item + jnp.sum(
-        jnp.where(mine, (last - ends + 1)[None, :], 0), axis=1)
-    return (slot_of, jnp.clip(entry_of, 0, MB - 1).astype(jnp.int32),
-            ends[-1].astype(jnp.int32))
+
+    def of(x):
+        return jnp.sum(jnp.where(mine, x[None, :], 0), axis=1,
+                       dtype=jnp.int32)
+
+    # a slot's k-th item starts at entry first + k * N
+    entry_of = N * item + of(first - N * (ends - n_items))
+    count_of = jnp.clip(of(last) - entry_of + 1, 0, N)
+    count_of = jnp.where(item < ends[-1], count_of, 0)
+    return DecodeWork(slot_of, jnp.clip(entry_of, 0, MB - 1),
+                      count_of.astype(jnp.int32), ends[-1].astype(jnp.int32),
+                      N)
 
 
-def _decode_kernel(tbl_ref, len_ref, slot_ref, entry_ref, q_ref, k_ref,
-                   v_ref, o_ref, m_ref, l_ref, acc_ref, *, BS, KVH, G,
-                   scale, window, alibi, alibi_scale=1.0,
+def _decode_kernel(tbl_ref, len_ref, slot_ref, entry_ref, count_ref, q_ref,
+                   k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *bufs, N, BS,
+                   KVH, G, scale, window, alibi, alibi_scale=1.0,
                    alibi_bf16=False):
     """Grid step i is item i of the work list: slot ``slot_ref[i]``
-    against the KV block its table's ``entry_ref[i]``-th entry names.
-    A slot's items are consecutive, so its q and o tiles stay resident
-    from its first item to its last."""
+    against the ``count_ref[i]`` KV blocks its table names from entry
+    ``entry_ref[i]`` on, as one run of ``count * BS`` keys. A slot's
+    items are consecutive, so its q and o tiles stay resident from its
+    first item to its last.
+
+    At N = 1 ``k_ref`` / ``v_ref`` are the item's block, brought by the
+    pipeline. At N > 1 they are the pools, in HBM, and ``bufs`` are
+    ``_AHEAD + 1`` buffers of N blocks each with their DMA semaphores: a
+    step waits for its own blocks, asked for ``_AHEAD`` steps before (the
+    first step asks for the first items'), and asks for those of the
+    item ``_AHEAD`` on before it computes. Only live entries are ever
+    moved."""
     i = pl.program_id(0)
     b = slot_ref[i]
     j = entry_ref[i]
+    B, MB = tbl_ref.shape
     H = KVH * G
     L = len_ref[b]
+
+    if N > 1:
+        k_buf, v_buf, sem = bufs
+        D = _AHEAD + 1
+
+        def each_block(item, do):
+            """``do`` (start or wait) the copies of ``item``'s run: K
+            and V of each entry it has, into buffer ``item % D``."""
+            for t in range(N):
+                @pl.when(t < count_ref[item])
+                def _(t=t):
+                    blk = tbl_ref[jnp.minimum(slot_ref[item], B - 1),
+                                  jnp.minimum(entry_ref[item] + t, MB - 1)]
+                    for p, (pool, buf) in enumerate(((k_ref, k_buf),
+                                                     (v_ref, v_buf))):
+                        do(pltpu.make_async_copy(
+                            pool.at[blk],
+                            buf.at[item % D, :, pl.ds(t * BS, BS), :],
+                            sem.at[p, item % D]))
+
+        start, wait = (operator.methodcaller(m) for m in ("start", "wait"))
+
+        @pl.when(i == 0)
+        def _first():
+            for item in range(_AHEAD):
+                each_block(item, start)
+
+        each_block(i + _AHEAD, start)
+        each_block(i, wait)
 
     @pl.when((i == 0) | (slot_ref[jnp.maximum(i - 1, 0)] != b))
     def _init():
@@ -207,62 +306,123 @@ def _decode_kernel(tbl_ref, len_ref, slot_ref, entry_ref, q_ref, k_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    kb = k_ref[0]                                     # (KVH, BS, d)
-    vb = v_ref[0]
-    # q arrives (1, KVH, G, d) — the caller reshaped (B, H, d) to
-    # (B, KVH, G, d) OUTSIDE the kernel (in-kernel singleton reshapes
-    # are unsupported shape casts in Mosaic, and a dot needs a
-    # non-contracting lhs dim, which G provides even when == 1)
-    q = q_ref[0]
-    s = jax.lax.dot_general(
-        q, kb, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * scale   # (KVH, G, BS)
-    pos = j * BS + jax.lax.broadcasted_iota(jnp.int32, (KVH, G, BS), 2)
-    if alibi:
-        # ALiBi: slope_h * k_pos (softmax-shift equivalent to
-        # slope_h * (k_pos - q_pos); matches the dense paths).
-        # Slopes are computed IN-KERNEL from the head index (a
-        # captured constant array is rejected by pallas_call): the
-        # bloom formula splits at the leading power of two cp.
-        h = (jax.lax.broadcasted_iota(jnp.int32, (KVH, G, BS), 0) * G
-             + jax.lax.broadcasted_iota(jnp.int32, (KVH, G, BS), 1)
-             ).astype(jnp.float32)
-        cp = float(2 ** math.floor(math.log2(H)))
-        expo = jnp.where(h < cp, -(h + 1.0) * (8.0 / cp),
-                         -(2.0 * (h - cp) + 1.0) * (4.0 / cp))
-        ab = jnp.exp2(expo) * pos.astype(jnp.float32)
-        if alibi_bf16:
-            # HF falcon quantizes the alibi tensor through bf16 and
-            # adds it pre-scaling (models/llama.py _alibi_bias)
-            ab = ab.astype(jnp.bfloat16).astype(jnp.float32)
-        if alibi_scale != 1.0:
-            ab = ab * alibi_scale
-        s = s + ab
-    ok = pos <= L
-    if window:
-        # sliding window: the query (at position L) only attends
-        # positions > L - window
-        ok = ok & (pos > L - window)
-    s = jnp.where(ok, s, NEG_INF)
+    def attend(c):
+        """The online-softmax update over the run's c blocks."""
+        S = c * BS
+        if N > 1:
+            kb = k_buf[i % D, :, :S, :]               # (KVH, S, d)
+            vb = v_buf[i % D, :, :S, :]
+        else:
+            kb, vb = k_ref[0], v_ref[0]
+        # q arrives (1, KVH, G, d) — the caller reshaped (B, H, d) to
+        # (B, KVH, G, d) OUTSIDE the kernel (in-kernel singleton reshapes
+        # are unsupported shape casts in Mosaic, and a dot needs a
+        # non-contracting lhs dim, which G provides even when == 1)
+        q = q_ref[0]
+        s = jax.lax.dot_general(
+            q, kb, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale   # (KVH, G, S)
+        pos = j * BS + jax.lax.broadcasted_iota(jnp.int32, (KVH, G, S), 2)
+        if alibi:
+            # ALiBi: slope_h * k_pos (softmax-shift equivalent to
+            # slope_h * (k_pos - q_pos); matches the dense paths).
+            # Slopes are computed IN-KERNEL from the head index (a
+            # captured constant array is rejected by pallas_call): the
+            # bloom formula splits at the leading power of two cp.
+            h = (jax.lax.broadcasted_iota(jnp.int32, (KVH, G, S), 0) * G
+                 + jax.lax.broadcasted_iota(jnp.int32, (KVH, G, S), 1)
+                 ).astype(jnp.float32)
+            cp = float(2 ** math.floor(math.log2(H)))
+            expo = jnp.where(h < cp, -(h + 1.0) * (8.0 / cp),
+                             -(2.0 * (h - cp) + 1.0) * (4.0 / cp))
+            ab = jnp.exp2(expo) * pos.astype(jnp.float32)
+            if alibi_bf16:
+                # HF falcon quantizes the alibi tensor through bf16 and
+                # adds it pre-scaling (models/llama.py _alibi_bias)
+                ab = ab.astype(jnp.bfloat16).astype(jnp.float32)
+            if alibi_scale != 1.0:
+                ab = ab * alibi_scale
+            s = s + ab
+        ok = pos <= L
+        if window:
+            # sliding window: the query (at position L) only attends
+            # positions > L - window
+            ok = ok & (pos > L - window)
+        s = jnp.where(ok, s, NEG_INF)
 
-    m_prev = m_ref[..., 0]                            # (KVH, G)
-    l_prev = l_ref[..., 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[..., None])                 # (KVH, G, BS) f32
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-    pv = jax.lax.dot_general(
-        p.astype(vb.dtype), vb, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)           # (KVH, G, d)
-    acc = acc_ref[...] * alpha[..., None] + pv
-    acc_ref[...] = acc
-    m_ref[...] = jnp.broadcast_to(m_new[..., None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new[..., None], l_ref.shape)
+        m_prev = m_ref[..., 0]                            # (KVH, G)
+        l_prev = l_ref[..., 0]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[..., None])                 # (KVH, G, S) f32
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
+        pv = jax.lax.dot_general(
+            p.astype(vb.dtype), vb, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)           # (KVH, G, d)
+        acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
+        m_ref[...] = jnp.broadcast_to(m_new[..., None], m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new[..., None], l_ref.shape)
+
+    # one copy of the update a run length: a short run (a slot's last
+    # item, a sequence of one block) computes over its own keys alone
+    if N == 1:
+        attend(1)
+    else:
+        for c in range(1, N + 1):
+            pl.when(count_ref[i] == c)(functools.partial(attend, c))
 
     @pl.when(slot_ref[i + 1] != b)
     def _store():
-        l = jnp.maximum(l_new, 1e-30)                 # (KVH, G)
-        o_ref[0] = (acc / l[..., None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[..., 0], 1e-30)             # (KVH, G)
+        o_ref[0] = (acc_ref[...] / l[..., None]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "N", "scale", "window", "alibi", "alibi_scale", "alibi_bf16",
+    "interpret"))
+def _decode_call(tables, lengths, slot_of, entry_of, count_of, n, q, kp, vp,
+                 *, N, scale, window, alibi, alibi_scale, alibi_bf16,
+                 interpret):
+    """The decode kernel as one jitted callable, for
+    :func:`_kv_write_call`'s reason: a decode program holds layers x
+    steps of it."""
+    B, KVH, G, d = q.shape
+    BS = kp.shape[2]
+
+    # the pipeline may look one item ahead of the last, where ``slot_of``
+    # reads B: keep every index it can form inside its array
+    def qo_index(i, tbl, lens, slot, entry, count):
+        return (jnp.minimum(slot[i], B - 1), 0, 0, 0)
+
+    state = [pltpu.VMEM((KVH, G, 128), jnp.float32),     # running max
+             pltpu.VMEM((KVH, G, 128), jnp.float32),     # running denom
+             pltpu.VMEM((KVH, G, d), jnp.float32)]       # output accumulator
+    if N > 1:
+        kv_spec = pl.BlockSpec(memory_space=pl.ANY)
+        state += [pltpu.VMEM((_AHEAD + 1, KVH, N * BS, d), kp.dtype),
+                  pltpu.VMEM((_AHEAD + 1, KVH, N * BS, d), vp.dtype),
+                  pltpu.SemaphoreType.DMA((2, _AHEAD + 1))]  # (K | V, buffer)
+    else:
+        kv_spec = pl.BlockSpec(
+            (1, KVH, BS, d), lambda i, tbl, lens, slot, entry, count:
+            (tbl[jnp.minimum(slot[i], B - 1), entry[i]], 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(n,),
+        in_specs=[pl.BlockSpec((1, KVH, G, d), qo_index), kv_spec, kv_spec],
+        out_specs=pl.BlockSpec((1, KVH, G, d), qo_index),
+        scratch_shapes=state,
+    )
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, N=N, BS=BS, KVH=KVH, G=G,
+                          scale=scale, window=window, alibi=alibi,
+                          alibi_scale=alibi_scale, alibi_bf16=alibi_bf16),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KVH, G, d), q.dtype),
+        # operands 0-4 are the scalar-prefetched integers; q is 5
+        input_output_aliases={5: 0},
+        interpret=interpret,
+    )(tables, lengths, slot_of, entry_of, count_of, q, kp, vp)
 
 
 def paged_decode_attention(q, k_cache, v_cache, block_tables, lengths, *,
@@ -312,45 +472,15 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, lengths, *,
                 "slopes in-kernel; custom per-head slopes are not "
                 "supported")
     if work is None:
-        work = decode_work_list(lengths, MB, BS, window)
-    slot_of, entry_of, n = work
-
-    # the pipeline may look one item ahead of the last, where ``slot_of``
-    # reads B: keep every index it can form inside its array
-    def qo_index(i, tbl, lens, slot, entry):
-        return (jnp.minimum(slot[i], B - 1), 0, 0, 0)
-
-    def kv_index(i, tbl, lens, slot, entry):
-        return (tbl[jnp.minimum(slot[i], B - 1), entry[i]], 0, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, KVH, G, d), qo_index),
-            pl.BlockSpec((1, KVH, BS, d), kv_index),
-            pl.BlockSpec((1, KVH, BS, d), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, KVH, G, d), qo_index),
-        scratch_shapes=[
-            pltpu.VMEM((KVH, G, 128), jnp.float32),  # running max
-            pltpu.VMEM((KVH, G, 128), jnp.float32),  # running denom
-            pltpu.VMEM((KVH, G, d), jnp.float32),    # output accumulator
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, BS=BS, KVH=KVH, G=G,
-                          scale=float(scale), window=int(window),
-                          alibi=alibi_slopes is not None,
-                          alibi_scale=float(alibi_scale),
-                          alibi_bf16=bool(alibi_bf16)),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KVH, G, d), q.dtype),
-        # operands 0-3 are the scalar-prefetched integers; q is 4
-        input_output_aliases={4: 0},
-        interpret=interpret,
-    )(block_tables, lengths, slot_of, entry_of,
-      q.reshape(B, KVH, G, d), k_cache, v_cache)
+        work = decode_work_list(
+            lengths, MB, BS, window,
+            per_step=decode_entries_per_step(KVH, BS, d, k_cache.dtype, MB))
+    out = _decode_call(
+        block_tables, lengths, work.slot_of, work.entry_of, work.count_of,
+        work.n, q.reshape(B, KVH, G, d), k_cache, v_cache, N=work.per_step,
+        scale=float(scale), window=int(window),
+        alibi=alibi_slopes is not None, alibi_scale=float(alibi_scale),
+        alibi_bf16=bool(alibi_bf16), interpret=bool(interpret))
     return out.reshape(B, H, d)
 
 

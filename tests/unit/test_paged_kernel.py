@@ -362,11 +362,11 @@ class TestPagedDecodeWorkList:
                            alibi_scale=1.0 / math.sqrt(32)),
     }
 
-    def _setup(self, G, lengths, active, seed=0):
+    def _setup(self, G, lengths, active, seed=0, d=None):
         rng = np.random.RandomState(seed)
-        H = self.KVH * G
-        q = jnp.asarray(rng.randn(self.B, H, self.d), jnp.float32) * 0.5
-        kc, vc = (jnp.asarray(rng.randn(self.NB, self.KVH, self.BS, self.d),
+        H, d = self.KVH * G, d or self.d
+        q = jnp.asarray(rng.randn(self.B, H, d), jnp.float32) * 0.5
+        kc, vc = (jnp.asarray(rng.randn(self.NB, self.KVH, self.BS, d),
                               jnp.float32) * 0.5 for _ in range(2))
         lens = np.asarray(lengths, np.int32)
         act = np.asarray(active, bool)
@@ -380,30 +380,57 @@ class TestPagedDecodeWorkList:
         return q, kc, vc, jnp.asarray(tbl), jnp.asarray(lens), act
 
     def _run(self, q, kc, vc, tbl, lens, act, *, window=0, alibi=False,
-             **kw):
+             per_step=1, **kw):
         from deepspeed_tpu.ops.pallas.paged_attention import (
             alibi_slopes, decode_work_list, paged_decode_attention)
         work = decode_work_list(lens, self.MB, self.BS, window,
-                                active=jnp.asarray(act))
+                                active=jnp.asarray(act), per_step=per_step)
         return paged_decode_attention(
             q, kc, vc, tbl, lens, work=work, window=window,
             alibi_slopes=alibi_slopes(q.shape[1]) if alibi else None,
             **kw), work
 
+    # entries a grid step: one (the pipeline brings the block), a count
+    # that divides no slot's eight entries, and a whole table's
+    @pytest.mark.parametrize("per_step", [1, 3, 8])
     @pytest.mark.parametrize("lengths", sorted(LENGTHS))
     @pytest.mark.parametrize("mode", sorted(MODES))
-    def test_matches_reference(self, mode, lengths):
+    def test_matches_reference(self, mode, lengths, per_step):
         kw = dict(self.MODES[mode])
         q, kc, vc, tbl, lens, act = self._setup(kw.pop("G"),
                                                 *self.LENGTHS[lengths])
-        out, (slot_of, _, n) = self._run(q, kc, vc, tbl, lens, act, **kw)
+        out, work = self._run(q, kc, vc, tbl, lens, act, per_step=per_step,
+                              **kw)
         want = _paged_decode_numpy(q, kc, vc, tbl, lens, **kw)
         np.testing.assert_allclose(np.asarray(out)[act], want[act],
                                    rtol=2e-5, atol=2e-5)
         # an inactive slot takes no grid step; its row is q's: finite
-        assert set(np.asarray(slot_of)[:int(n)]) == set(np.flatnonzero(act))
+        assert set(np.asarray(work.slot_of)[:int(work.n)]) \
+            == set(np.flatnonzero(act))
         np.testing.assert_array_equal(np.asarray(out)[~act],
                                       np.asarray(q)[~act])
+
+    @pytest.mark.parametrize("per_step", [2, 4])
+    @pytest.mark.parametrize("window", [0, 40])
+    @pytest.mark.parametrize("G", [1, 4])
+    @pytest.mark.parametrize("d", [64, 128])
+    def test_runs_of_entries_at_the_served_widths(self, d, G, window,
+                                                  per_step):
+        """The jnp reference at the head dims and group sizes the served
+        families have: runs of 2 and 4 entries over slots of 1, 2 and 8
+        (one an inactive slot apart), and a window whose first attended
+        entry is neither the table's first nor a run's."""
+        from deepspeed_tpu.ops.pallas.paged_attention import (
+            paged_decode_attention_reference)
+        q, kc, vc, tbl, lens, act = self._setup(
+            G, *self.LENGTHS["ragged"], d=d)
+        out, _ = self._run(q, kc, vc, tbl, lens, act, window=window,
+                           per_step=per_step)
+        ref = paged_decode_attention_reference(q, kc, vc, tbl, lens,
+                                               window=window)
+        np.testing.assert_allclose(np.asarray(out)[act],
+                                   np.asarray(ref)[act],
+                                   rtol=2e-5, atol=2e-5)
 
     def test_every_slot_active_by_default(self):
         """Without a list of its own the kernel walks every slot, and a
@@ -417,48 +444,85 @@ class TestPagedDecodeWorkList:
                                    rtol=2e-5, atol=2e-5)
         assert np.isfinite(np.asarray(out)).all()
 
+    @pytest.mark.parametrize("per_step", [1, 3])
     @pytest.mark.parametrize("window", [0, 20])
-    def test_table_tail_is_never_read(self, window):
+    def test_table_tail_is_never_read(self, window, per_step):
         """Entries past a slot's length (and a whole inactive row) may
         name any block: scrambling them changes no bit of the output."""
         q, kc, vc, tbl, lens, act = self._setup(1, *self.LENGTHS["ragged"])
-        out1, _ = self._run(q, kc, vc, tbl, lens, act, window=window)
+        out1, _ = self._run(q, kc, vc, tbl, lens, act, window=window,
+                            per_step=per_step)
         rng = np.random.RandomState(7)
         tail = np.arange(self.MB)[None, :] > np.asarray(lens)[:, None] \
             // self.BS
         tail |= ~act[:, None]
         tbl2 = jnp.where(tail, rng.randint(0, self.NB, tail.shape), tbl)
-        out2, _ = self._run(q, kc, vc, tbl2, lens, act, window=window)
+        out2, _ = self._run(q, kc, vc, tbl2, lens, act, window=window,
+                            per_step=per_step)
         np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
 
+    @pytest.mark.parametrize("per_step", [1, 2, 3, 8])
     @pytest.mark.parametrize("window", [0, 20, 100])
-    def test_list_is_the_live_pairs(self, window):
+    def test_list_is_the_live_pairs(self, window, per_step):
         """The list names exactly the (slot, entry) pairs that hold a
-        position the new token attends, slot-major, and the host's count
-        (the telemetry's) is the device's."""
+        position the new token attends, slot-major and cut into runs of
+        ``per_step`` a slot, and the host's count (the telemetry's) is
+        the device's."""
         from deepspeed_tpu.ops.pallas.paged_attention import (
             decode_grid_steps, decode_work_list)
         lens, act = self.LENGTHS["ragged"]
         lens, act = np.asarray(lens, np.int32), np.asarray(act, bool)
-        slot_of, entry_of, n = decode_work_list(
+        work = decode_work_list(
             jnp.asarray(lens), self.MB, self.BS, window,
-            active=jnp.asarray(act))
-        want = [(b, j) for b in range(self.B) if act[b]
-                for j in range(self.MB)
-                if j * self.BS <= lens[b]
-                and (not window or j * self.BS + self.BS - 1
-                     > lens[b] - window)]
-        n = int(n)
-        assert list(zip(np.asarray(slot_of)[:n].tolist(),
-                        np.asarray(entry_of)[:n].tolist())) == want
-        assert (np.asarray(slot_of)[n:] == self.B).all()
-        assert decode_grid_steps(lens, act, self.MB, self.BS, window) == n
+            active=jnp.asarray(act), per_step=per_step)
+        want = []
+        for b in np.flatnonzero(act):
+            mine = [j for j in range(self.MB) if j * self.BS <= lens[b]
+                    and (not window or j * self.BS + self.BS - 1
+                         > lens[b] - window)]
+            want += [(b, run[0], len(run)) for run in (
+                mine[k:k + per_step]
+                for k in range(0, len(mine), per_step))]
+        n = int(work.n)
+        got = [np.asarray(x) for x in work[:3]]
+        assert work.per_step == per_step
+        assert list(zip(*(x[:n].tolist() for x in got))) == want
+        assert (got[0][n:] == self.B).all() and not got[2][n:].any()
+        assert len(got[0]) == self.B * -(-self.MB // per_step) + 2
+
+        def count(lengths, per_step):
+            return int(decode_work_list(
+                jnp.asarray(lengths), self.MB, self.BS, window,
+                active=jnp.asarray(act), per_step=per_step).n)
+
+        # the host's count, for every N the shape rule can return
+        for N in range(1, self.MB + 1):
+            assert decode_grid_steps(lens, act, self.MB, self.BS, window,
+                                     per_step=N) == count(lens, N)
         # over a dispatch's steps every length grows by one a step
         assert decode_grid_steps(lens, act, self.MB, self.BS, window,
-                                 steps=3) == sum(
-            int(decode_work_list(jnp.asarray(lens + t), self.MB, self.BS,
-                                 window, active=jnp.asarray(act))[2])
-            for t in range(3))
+                                 steps=3, per_step=per_step) == sum(
+            count(lens + t, per_step) for t in range(3))
+
+    @pytest.mark.parametrize("shape,want", [
+        # (KV heads, block size, head dim, dtype, table entries) -> N:
+        # phi-4-mini-flash's ten 128-lane head pairs, 0.33 MB a block
+        ((10, 64, 128, "bfloat16", 64), 4),
+        ((10, 64, 128, "bfloat16", 3), 3),          # a table of three
+        ((16, 64, 128, "bfloat16", 64), 2),         # olmoe-1b-7b, 0.52 MB
+        ((32, 64, 128, "bfloat16", 64), 1),         # 1.05 MB: one entry
+        ((16, 64, 128, "float32", 64), 1),
+        ((2, 16, 128, "bfloat16", 1024), 64),       # tiny blocks: to 1 MB
+        ((64, 256, 256, "bfloat16", 64), 1),        # past the VMEM cap
+        # rows narrower than the lanes cannot be cut out of HBM by the
+        # kernel's own copies: gpt2-medium, opt-1.3b
+        ((16, 64, 64, "bfloat16", 16), 1),
+        ((32, 64, 64, "bfloat16", 32), 1),
+    ])
+    def test_entries_a_step_come_from_the_shape(self, shape, want):
+        from deepspeed_tpu.ops.pallas.paged_attention import (
+            decode_entries_per_step)
+        assert decode_entries_per_step(*shape) == want
 
 
 class TestPoolBoundaryShape:

@@ -4,6 +4,7 @@ which implementation reads them back; bucketed prefill is the chunk
 program at ``start = 0``; and the engine sizes the pools by the same
 answer the decode trace takes."""
 
+import dataclasses
 import os
 import re
 
@@ -18,6 +19,7 @@ from deepspeed_tpu.models import GPT2, GPT2Config, paged
 from deepspeed_tpu.models.bloom import BLOOM_TINY, Bloom
 from deepspeed_tpu.models.llama import LLAMA_TINY, Llama
 from deepspeed_tpu.models.mixtral import MIXTRAL_TINY, Mixtral
+from deepspeed_tpu.models.phi4flash import PHI4FLASH_TINY, Phi4Flash
 from deepspeed_tpu.ops.pallas import _common as pallas_common
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -27,7 +29,8 @@ GPT2_TINY = GPT2Config(n_layer=2, n_head=4, d_model=64, max_seq_len=128,
 MODELS = {"gpt2": lambda: GPT2(GPT2_TINY),
           "llama_gqa": lambda: Llama(LLAMA_TINY),
           "mixtral": lambda: Mixtral(MIXTRAL_TINY),
-          "bloom_alibi": lambda: Bloom(BLOOM_TINY)}
+          "bloom_alibi": lambda: Bloom(BLOOM_TINY),
+          "phi4flash": lambda: Phi4Flash(PHI4FLASH_TINY)}
 
 
 @pytest.fixture(autouse=True)
@@ -71,6 +74,52 @@ def test_prefill_is_the_chunk_program_at_start_zero(family, kernel):
         np.testing.assert_array_equal(np.asarray(a[1:]), np.asarray(b[1:]))
     # and the prompt's rows did land in its own blocks
     assert float(jnp.abs(cp["k"][0][3].astype(jnp.float32)).sum()) > 0
+
+
+@pytest.mark.parametrize("per_step", [2, 3])
+@pytest.mark.parametrize("family", ["gpt2", "llama_gqa", "bloom_alibi",
+                                    "phi4flash"])
+def test_decode_step_in_runs_of_entries(monkeypatch, family, per_step):
+    """One decode step of each family through ``batch_step`` with the
+    decode kernel taking ``per_step`` table entries of a slot a grid step
+    gives the logits it gives at one (the tiny models' rows are narrower
+    than the lanes, so their own N is 1: the test answers for the shape
+    rule). Slots of 3, 7 and 1 attended entries, one inactive between
+    them; phi-4's window layers read rings of 3 blocks a slot whose first
+    attended entry lies mid-ring (position 100 of a window of 8: entry 5
+    of a ring table that repeats 0, 1, 2)."""
+    B, BS, MB, NB = 4, 16, 8, 24
+    model = MODELS[family]()
+    # float32, so that the order of a softmax's sums shows nowhere
+    model = type(model)(dataclasses.replace(model.config, dtype="float32"))
+    model._paged_kernel, model._paged_ring_blocks = True, 3
+    params = model.init(jax.random.key(0))
+    kw = dict(slots=B, ring_blocks=3) if family == "phi4flash" else {}
+    cache = model.init_paged_cache(NB, BS, **kw)
+    leaves, tree = jax.tree.flatten(cache)
+    cache = jax.tree.unflatten(tree, [
+        0.5 * jax.random.normal(k, x.shape, x.dtype) for k, x in zip(
+            jax.random.split(jax.random.key(1), len(leaves)), leaves)])
+    lengths = jnp.asarray([37, 0, 100, 5], jnp.int32)
+    tables = np.zeros((B, MB), np.int32)
+    tables[0, :3], tables[2, :7], tables[3, :1] = (1, 2, 3), range(4, 11), 11
+    tokens = jnp.asarray([5, 0, 7, 9], jnp.int32)
+    calls = []
+    real = paged.paged_decode_attention
+    monkeypatch.setattr(
+        paged, "paged_decode_attention",
+        lambda *a, **k: calls.append(k["work"].per_step) or real(*a, **k))
+
+    def logits(n):
+        monkeypatch.setattr(paged, "decode_entries_per_step", lambda *a: n)
+        out, _ = model.apply_paged_decode(params, tokens, lengths, cache,
+                                          jnp.asarray(tables))
+        return np.asarray(out, np.float32)[[0, 2, 3]]
+
+    one, runs = logits(1), logits(per_step)
+    assert set(calls) == {1, per_step}
+    assert np.isfinite(one).all() and one.std() > 1e-3
+    np.testing.assert_allclose(runs, one, rtol=2e-5, atol=2e-5)
 
 
 _SEAM_NAMES = re.compile(

@@ -555,6 +555,8 @@ class TestRouterTelemetry:
                              "queue_ms_p90", "batch_occupancy_pct",
                              # + ISSUE 27's, beside the occupancy
                              "decode_grid_share",
+                             # + ISSUE 39's, the entries a grid step takes
+                             "decode_entries_per_step",
                              # + ISSUE 29's, the KV write's live rows
                              "kv_write_live_share",
                              # + ISSUE 30's, what live sequences hold

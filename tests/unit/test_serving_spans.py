@@ -226,6 +226,10 @@ def test_on_admit_queue_wait():
     st.on_decode_batch(1, 4, grid_steps=6, table_entries=64)
     assert st.percentiles()["batch_occupancy_pct"] == 50.0
     assert st.percentiles()["decode_grid_share"] == 0.0938
+    assert "decode_entries_per_step" not in st.percentiles()
+    st.on_decode_batch(2, 4, grid_steps=9, table_entries=64, kernel_steps=4)
+    assert st.percentiles()["decode_grid_share"] == round(15 / 128, 4)
+    assert st.percentiles()["decode_entries_per_step"] == 3.75
     assert "kv_write_live_share" not in st.percentiles()
     st.on_kv_write(3, 8)
     st.on_kv_write(13, 24)
@@ -303,13 +307,20 @@ def test_late_steps_ride_the_span_that_reads_them(monkeypatch):
     assert tel._chained_dispatches - chained0 == len(said) - 1
 
 
+@pytest.mark.parametrize("per_step", [1, 3])
 @pytest.mark.parametrize("splitfuse_tokens", [0, 16])
-def test_decode_grid_counter(monkeypatch, splitfuse_tokens):
-    """``grid_steps`` / ``table_entries`` on every decode-bearing dispatch
-    span, and ``decode_grid_share`` of the telemetry, are the numpy
-    formula over the batches the engine dispatched: a live slot's blocks
-    up to its new token, each of the dispatch's steps one token on,
-    against steps x slots x table entries."""
+def test_decode_grid_counter(monkeypatch, splitfuse_tokens, per_step):
+    """``grid_steps`` / ``table_entries`` / ``kernel_steps`` on every
+    decode-bearing dispatch span, and ``decode_grid_share`` and
+    ``decode_entries_per_step`` of the telemetry, are the numpy formula
+    over the batches the engine dispatched: a live slot's blocks up to
+    its new token, each of the dispatch's steps one token on, against
+    steps x slots x table entries, and in runs of ``per_step`` a slot
+    (the tiny model's rows are narrower than the lanes, so its own N is
+    1: the test says 3 for it)."""
+    from deepspeed_tpu.models import paged
+    monkeypatch.setattr(paged, "decode_entries_per_step",
+                        lambda *a: per_step)
     router, engine = _router(splitfuse_tokens)
     batches, stats = [], []
     real_batch, real_span = engine.state_mgr.decode_batch, engine_v2.span
@@ -333,11 +344,20 @@ def test_decode_grid_counter(monkeypatch, splitfuse_tokens):
     want = [int(sum((lengths[active] + t) // BS + 1
                     for t in range(steps)).sum())
             for lengths, active in batches]
+    runs = [int(sum(-(-((lengths[active] + t) // BS + 1) // per_step)
+                    for t in range(steps)).sum())
+            for lengths, active in batches]
     assert [st["grid_steps"] for st in stats] == want
+    assert [st["kernel_steps"] for st in stats] == runs
     assert {st["table_entries"] for st in stats} == {steps * slots * MB}
-    assert all(st["active"] * steps <= st["grid_steps"] for st in stats)
-    assert engine.telemetry_snapshot()["decode_grid_share"] == round(
+    assert all(st["active"] * steps <= st["kernel_steps"]
+               <= st["grid_steps"] for st in stats)
+    snap = engine.telemetry_snapshot()
+    assert snap["decode_grid_share"] == round(
         sum(want) / (len(want) * steps * slots * MB), 4)
+    assert snap["decode_entries_per_step"] == round(
+        sum(want) / sum(runs), 4)
+    assert (want == runs) == (per_step == 1)
 
 
 @pytest.mark.parametrize("splitfuse_tokens", [0, 16])

@@ -24,8 +24,8 @@ from jax.sharding import SingleDeviceSharding
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.ops.pallas.fused_ce import unembed_logits_stats
 from deepspeed_tpu.ops.pallas.paged_attention import (
-    as_pools, like_boundary, paged_chunk_attention, paged_decode_attention,
-    pool_block_dims)
+    as_pools, decode_entries_per_step, decode_work_list, like_boundary,
+    paged_chunk_attention, paged_decode_attention, pool_block_dims)
 
 # GPT-2 350M serving/training geometry (chip_smoke.py FULL)
 B, T, H, HD, D, V = 8, 1024, 16, 64, 1024, 50304
@@ -153,6 +153,58 @@ def test_paged_decode_is_the_custom_call_the_trace_reader_finds(v5e, cell):
         pattern = json.load(f)["kernels"]["paged_decode"]["pattern"]
     assert re.search(pattern, f"{head} custom-call({operands}), "
                      'custom_call_target="tpu_custom_call"')
+
+
+# the decode kernel where a grid step takes several table entries (ISSUE
+# 39): (slots, KV heads, query heads a KV head, head dim, table entries,
+# pool blocks, window) -> the entries a step the shape rule gives
+DECODE_RUNS = {
+    # cell 7: layer 17's pool under the block tables, and a window ring
+    "phi4flash_shared_kv": ((64, 10, 4, 128, 64, 2048, 0), 4),
+    "phi4flash_window": ((64, 10, 4, 128, 64, 641, 512), 4),
+    "olmoe": ((32, 16, 1, 128, 64, 512, 0), 2),
+    # 32 heads of 64, rows padded to the lanes: one entry, by the pipeline
+    "opt1.3b": ((16, 32, 1, 64, 32, 128, 0), 1),
+}
+
+
+def _decode_runs(b, kvh, g, hd, mb, nb, window, per_step):
+    def fn(q, kc, vc, tb, ln):
+        work = decode_work_list(ln, mb, BS, window, per_step=per_step)
+        return paged_decode_attention(q, kc, vc, tb, ln, work=work,
+                                      window=window, interpret=False)
+    return fn, [((b, kvh * g, hd), bf16)] + [((nb, kvh, BS, hd), bf16)] * 2 \
+        + [((b, mb), i32), ((b,), i32)]
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_RUNS))
+def test_decode_kernel_takes_runs_of_entries(v5e, case):
+    """At the rule's N the kernel lowers and its two buffers of N blocks
+    fit VMEM (the chunk kernel's 32-head fault, ROADMAP S2, is what a
+    buffer too many looks like), it is still one custom call with one
+    output, and a pool left in HBM for the kernel's own copies is not
+    copied on its way in (head dim 64 as a bare argument is
+    ``test_kv_pools_keep_the_kernels_layout``'s)."""
+    (b, kvh, g, hd, mb, nb, window), n = DECODE_RUNS[case]
+    assert decode_entries_per_step(kvh, BS, hd, bf16, mb) == n
+    text = _compile(*_decode_runs(b, kvh, g, hd, mb, nb, window, n), v5e)
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(calls) == 1
+    assert calls[0].split(" = ")[1].startswith(f"bf16[{b},{kvh},{g},{hd}]")
+    if n > 1:
+        assert not re.findall(
+            rf"= bf16\[{nb},{kvh},{BS},{hd}\]\S* copy\(", text)
+
+
+def test_rows_under_the_lanes_cannot_be_cut_out_of_hbm(v5e):
+    """Why ``decode_entries_per_step`` gives head dim 64 one entry a step:
+    the copies that bring several are the kernel's own, out of a pool left
+    in HBM, and Mosaic hands such an array over padded to whole tiles and
+    refuses a slice of it that is not (jax 0.9.0). When this stops
+    raising, the rule's ``d % 128`` line can go."""
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(*_decode_runs(32, 16, 1, 64, 16, 320, 0, 2), v5e)
 
 
 def test_vmem_refusal_is_what_a_bad_tile_looks_like(v5e):
