@@ -348,9 +348,12 @@ class InferenceEngineV2:
         # (table entries a grid step of the decode kernel takes, the
         # layers that call it by their window): what the dispatch spans
         # count its steps by
-        from ...models.paged import decode_kernel_calls
+        from ...models.paged import STATE, decode_kernel_calls, geometry
         self._decode_calls = decode_kernel_calls(
             self.model, self.max_blocks_per_seq, BS, dtype)
+        # layers that keep a recurrent state a slot: what the dispatch
+        # spans count state updates and scanned rows by
+        self._state_layers = geometry(self.model).kinds.count(STATE)
         self.params, self.param_shardings = shard_params(
             model, self.mesh, dtype, params=params, seed=config.seed,
             topology=topology,
@@ -1266,6 +1269,8 @@ class InferenceEngineV2:
             write_rows += kv_write_live_rows(lengths, tables, BS, steps)
             write_rows_offered += steps * slots
         active = int(np.sum(active))
+        state_updates = active * steps * self._state_layers
+        rule_rows = chunk_rows * self._state_layers
         expert_calls, expert_kernel_calls = self._expert_calls_of(
             *_PROGRAMS_OF_KIND[kind])
         if self.telemetry is not None:
@@ -1282,7 +1287,8 @@ class InferenceEngineV2:
                     write_rows_offered=write_rows_offered,
                     expert_calls=expert_calls,
                     expert_kernel_calls=expert_kernel_calls,
-                    chained=chained, late_steps=late_steps)
+                    chained=chained, late_steps=late_steps,
+                    state_updates=state_updates, rule_rows=rule_rows)
 
     def _expert_calls_of(self, *programs):
         """(expert layer calls, those through a Pallas grouped kernel) of
@@ -1443,7 +1449,8 @@ class InferenceEngineV2:
         calls, kernel = self._expert_calls_of(("prefill", T_pad))
         with span("dstpu.engine.prefill", uid=req.uid, tokens=T,
                   padded=T_pad, expert_calls=calls,
-                  expert_kernel_calls=kernel):
+                  expert_kernel_calls=kernel,
+                  rule_rows=T_pad * self._state_layers):
             with span("dstpu.engine.build"):
                 ids = np.zeros((1, T_pad), np.int32)
                 ids[0, :T] = req.prompt

@@ -12,6 +12,8 @@ from .qwen import Qwen, QwenConfig, QWEN_PRESETS
 from .phi import Phi, PhiConfig, PHI_PRESETS
 from .phi4flash import (Phi4Flash, Phi4FlashConfig, PHI4FLASH_PRESETS,
                         PHI4FLASH_TINY, PHI4_MINI_FLASH)
+from .olmo_hybrid import (OlmoHybrid, OlmoHybridConfig, OLMO_HYBRID_PRESETS,
+                          OLMO_HYBRID_TINY, OLMO_HYBRID_7B)
 from .falcon import Falcon, FalconConfig, FALCON_PRESETS
 from .opt import OPT, OPTConfig, OPT_PRESETS
 from .gptj import GPTJ, GPTJConfig, GPTJ_PRESETS

@@ -54,6 +54,8 @@ from ..ops.pallas.paged_attention import (
 
 
 KV, RING, SHARED, STATE = "kv", "ring", "shared", "state"
+# KV heads x rows x lanes of the chunk kernel's largest "auto" tile
+_CHUNK_TILE = 16 * 128 * 128
 # cache keys of each kind's leaves, one list entry a layer of that kind
 _KEYS = {KV: ("k", "v"), RING: ("ring_k", "ring_v"), STATE: ("conv", "ssm")}
 
@@ -146,12 +148,20 @@ def _chunk_kernel(geom, C, MB, BS):
     use, block_c = resolve_paged_chunk(
         False if geom.alibi else geom.kernel, geom.block_c, C, MB, BS,
         geom.n_kv_heads, G, geom.d_head, geom.dtype)
-    if geom.block_c == "auto" and G > 1:
+    if geom.block_c == "auto":
         # block_c counts tokens, and the kernel folds a KV head's G query
-        # heads into the tile's rows: keep a tile to the rows a head that
-        # G = 1 gives it (10 heads x 512 rows x 128 lanes ask for more
-        # VMEM than a kernel may have: sandbox compile for a v5e, PR 30)
-        block_c = max(8, min(block_c, PAGED_CHUNK_BLOCK_C // G))
+        # heads into the tile's rows. The tile's q, out and three float32
+        # accumulators are KV heads x rows x lanes each: keep them to the
+        # largest the served shapes compile with, 16 heads x 128 rows x
+        # 128 lanes, in rows of a power of two (30 x 128 x 128 run out of
+        # VMEM as 32 heads of 64 do: sandbox compile for a v5e, PR 41),
+        # and under GQA to the rows a head that G = 1 gives it (10 heads x
+        # 512 rows x 128 lanes: PR 30)
+        rows = 2 ** int(math.log2(max(1, _CHUNK_TILE // (
+            geom.n_kv_heads * max(geom.d_head, 128)))))
+        if G > 1:
+            rows = min(rows, PAGED_CHUNK_BLOCK_C)
+        block_c = max(8, min(block_c, rows // G))
     return use, block_c
 
 

@@ -240,16 +240,18 @@ SPAN_SCHEMA = {
                    "for chunks or through its bucketed prefill"},
     "dstpu.engine.prefill": {
         "stats": ("uid", "tokens", "padded", "expert_calls",
-                  "expert_kernel_calls"),
+                  "expert_kernel_calls", "rule_rows"),
         "meaning": "bucketed prefill of one request: arrays, program "
                    "call, blocking read of its token; expert_calls / "
-                   "expert_kernel_calls as on dstpu.engine.dispatch"},
+                   "expert_kernel_calls / rule_rows as on "
+                   "dstpu.engine.dispatch"},
     "dstpu.engine.dispatch": {
         "stats": ("kind", "active", "slots", "steps", "chunk_tokens",
                   "grid_steps", "table_entries", "kernel_steps",
                   "write_rows",
                   "write_rows_offered", "expert_calls",
-                  "expert_kernel_calls", "chained", "late_steps"),
+                  "expert_kernel_calls", "chained", "late_steps",
+                  "state_updates", "rule_rows"),
         "meaning": "one decode-bearing or chunk program call (kind "
                    "decode | fused | chunk | spec | offload) from the "
                    "assembled batch to the last posted token — of kind "
@@ -279,7 +281,13 @@ SPAN_SCHEMA = {
                    "dispatch's program whose products are a Pallas "
                    "grouped kernel, of all: noted when the program is "
                    "traced, so 0 / 0 on a dense model and on the dispatch "
-                   "that traces it"},
+                   "that traces it; state_updates = live slots x decode "
+                   "steps x the layers that keep a recurrent state a slot "
+                   "(a Mamba scan's, a delta rule's matrix): the one-token "
+                   "updates the dispatch makes, and rule_rows = the rows "
+                   "its chunk runs the scan or the chunkwise rule on, "
+                   "padding included, x those layers (both 0 on a model "
+                   "without such a layer)"},
     "dstpu.engine.build": {
         "stats": (),
         "meaning": "leaf: host work before a program call (decode "
@@ -327,6 +335,20 @@ SCOPE_SCHEMA = {
     "dstpu.gmu":
         "Gated Memory Unit: gate projection, product with the memory "
         "layer's scan output, out-projection",
+    "dstpu.gdn.mix":
+        "gated delta-rule mixer (olmo_hybrid): q / k / v / z and gate "
+        "projections, causal conv, L2 norms, gates, the rule, the gated "
+        "norm a head, out-projection, and the slot state's read and write",
+    "dstpu.gdn.chunk":
+        "inside dstpu.gdn.mix: the chunkwise-parallel rule of a prefill "
+        "or chunk program (ops/gated_delta_rule.py:chunk_rule), from the "
+        "slot's state to the state after the last real token",
+    "dstpu.gdn.step":
+        "inside dstpu.gdn.mix: the rule's one-token update of a decode "
+        "step, every slot's state read once and written once",
+    "dstpu.attn.full":
+        "a full-attention layer of olmo_hybrid: the K/V write into its "
+        "pool under the block table and the paged read",
     "dstpu.attn.diff":
         "differential attention outside the paged read: q/k/v "
         "projection, the query's padding to the head pair's width, "
@@ -356,13 +378,16 @@ SCOPE_SCHEMA = {
         "the logits: hidden states against the (tied) embedding, in a "
         "training step the fused cross-entropy kernel with it",
     "dstpu.mm.in_proj":
-        "Mamba mixer: the input projection to u and the gate z",
+        "Mamba mixer: the input projection to u and the gate z; gated "
+        "delta-rule mixer: the q / k / v / z projection and the a / b "
+        "gates' beside it",
     "dstpu.mm.x_proj":
         "Mamba mixer: the projection to dt's rank and B, C",
     "dstpu.mm.dt":
         "Mamba mixer: dt's rank up to the inner width",
     "dstpu.mm.out_proj":
-        "Mamba mixer: the gated scan output back to the model width",
+        "Mamba or gated delta-rule mixer: the gated output back to the "
+        "model width",
     "dstpu.mm.gmu":
         "Gated Memory Unit: its gate and its output projection",
 }

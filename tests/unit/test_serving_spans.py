@@ -30,8 +30,8 @@ from deepspeed_tpu.utils import groups
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, os.path.join(REPO, "perfbench"))
-from pbench import (common as pb_common, moe as pb_moe,  # noqa: E402
-                    ssm as pb_ssm, trace as pb_trace,
+from pbench import (common as pb_common, gdn as pb_gdn,  # noqa: E402
+                    moe as pb_moe, ssm as pb_ssm, trace as pb_trace,
                     weights as pb_weights)
 
 _CFG = GPT2Config(n_layer=2, n_head=4, d_model=64, max_seq_len=128,
@@ -597,6 +597,76 @@ def test_ssm_reader_shares_by_first_scope_named():
         pb_ssm.GMU}
 
 
+GDN_READERS = ("gdn_mix_share", "attn_full_share", "gdn_chunk_roofline",
+               "gdn_state_roofline")
+
+
+@pytest.mark.parametrize("metric", GDN_READERS)
+def test_gdn_reader_reads_nothing_without_its_scope(metric, bucketed):
+    """The readers of the delta-rule hybrid's layers (ISSUE 41) give None,
+    and say nothing, where the traced program opened none of their scopes:
+    no trace, a dense model's serving trace (whose dispatch spans carry
+    ``rule_rows`` = 0), the recorded training and MoE traces (a program
+    before PR 41: no such counter at all)."""
+    said = []
+    view = types.SimpleNamespace(
+        say=lambda line, **fields: said.append((line, fields)),
+        sizes=_cell_sizes("olmo-hybrid-7b"),
+        peaks=pb_common.peaks_for("TPU v5 lite"))
+    reader = pb_common.load_module("layer_metrics", metric)
+    fixtures = os.path.join(REPO, "perfbench", "fixtures")
+    for other in (None, bucketed[0],
+                  pb_trace.Trace(os.path.join(fixtures, "tiny4.xplane.pb")),
+                  pb_trace.Trace(os.path.join(fixtures, "moe1.xplane.pb"))):
+        view.trace = other
+        assert reader.read(view) is None and not said
+
+
+def test_gdn_readers_count_nested_scopes_and_the_rules_floors():
+    """Own time goes to EVERY one of ``pbench.gdn.SCOPES`` an operation's
+    ``tf_op`` names (the rule's two forms are inside the mixer); the floors
+    are the rule's own count for what the window's spans say was asked."""
+    ev = types.SimpleNamespace
+    ops = {"a": "jit(fused)/dstpu.gdn.mix/dstpu.mm.in_proj/dot_general",
+           "b": "jit(fused)/dstpu.gdn.mix/dstpu.gdn.chunk/while/body/dot",
+           "c": "jit(fused)/dstpu.gdn.mix/dstpu.gdn.step/mul",
+           "d": "jit(fused)/dstpu.attn.full/jit(_kv_write_call)/x",
+           "e": "jit(fused)/dstpu.mm.mlp/dot_general"}
+    spans = {"dstpu.engine.dispatch": [
+        ev(start=1.0, end=2.0, dur=1.0, stats={
+            "kind": "fused", "chunk_tokens": 1000, "rule_rows": 12288,
+            "state_updates": 96}),
+        # half inside the window
+        ev(start=9.5, end=10.5, dur=1.0, stats={
+            "kind": "decode", "chunk_tokens": 0, "rule_rows": 0,
+            "state_updates": 192})], "dstpu.engine.prefill": []}
+    trace = ev(path="p", devices=[0], gdn_seconds=None, busy_s=lambda: 2.0,
+               t0=0.0, t1=10.0, host_spans=lambda name: spans[name],
+               in_window=lambda d: [ev(name=n, self_s=0.25) for n in ops])
+    said = []
+    sizes = _cell_sizes("olmo-hybrid-7b")
+    peaks = pb_common.peaks_for("TPU v5 lite")
+    view = ev(trace=trace, sizes=sizes, peaks=peaks,
+              say=lambda line, **f: said.append(line))
+    real = pb_moe.op_scopes
+    pb_moe.op_scopes = lambda path, prefix: ops
+    try:
+        got = [pb_common.load_module("layer_metrics", m).read(view)
+               for m in GDN_READERS]
+    finally:
+        pb_moe.op_scopes = real
+    assert got[:2] == [37.5, 12.5]
+    assert (sizes["n_linear"], sizes["n_full"]) == (12, 4)
+    token = max(6 * 30 * 96 * 192 / peaks["bf16_flops_per_s"],
+                (2 * 2880 + 2 * 5760) * 2 / peaks["hbm_bytes_per_s"])
+    assert got[2] == pytest.approx(100 * 1000 * 12 * token / 0.25)
+    state = 2 * 30 * 96 * 192 * 4 / peaks["hbm_bytes_per_s"]
+    assert got[3] == pytest.approx(100 * (96 + 96) * state / 0.25)
+    assert all(0 < x < 100 for x in got)
+    assert said == ["gdn_device_seconds", "gdn_chunk_roofline",
+                    "gdn_state_roofline"]
+
+
 @pytest.mark.parametrize("kind", ["bucketed", "splitfuse"])
 def test_cache_bytes_per_live_token_reader(kind, request):
     """``cache_bytes_per_live_token`` is the step spans' two counters,
@@ -966,7 +1036,7 @@ def test_scope_schema_lint_both_directions():
     assert all(n.startswith("dstpu.") and m for n, m in SCOPE_SCHEMA.items())
     # the benchmark's readers look for the same names
     assert {pb_moe.SCOPE_EXPERTS, *pb_moe.SCOPES_ROUTE, *pb_ssm.SCOPES,
-            *pb_weights.SCOPES} == set(SCOPE_SCHEMA)
+            *pb_gdn.SCOPES, *pb_weights.SCOPES} == set(SCOPE_SCHEMA)
 
 
 def test_span_schema_lint_both_directions():

@@ -578,3 +578,144 @@ def test_two_piece_product_keeps_its_rounding(v5e):
         *args).compile().as_text()
     assert "reduce-precision(" in text
     assert re.search(r"bf16\[2,64,(1,)?2560\]", text)
+
+
+# ------------------------------------------------ Olmo-Hybrid (ISSUE 41)
+# the published widths: 30 K/V heads of 128 lanes, as many query heads;
+# the cell's sizes (perfbench/traffic/longdoc-sat.json)
+OH_KV, OH_W, OH_C, OH_SLOTS, OH_NB, OH_MB = 30, 128, 1024, 16, 1024, 136
+V5E_GB = 15.75
+
+
+def _geometry(n_head, n_kv_heads, d_head, block_c="auto"):
+    from deepspeed_tpu.models import paged
+    return paged.Geometry(
+        n_head=n_head, n_kv_heads=n_kv_heads, d_head=d_head, dtype=bf16,
+        scale=None, windows=(0,), alibi=False, alibi_inv_norm=False,
+        alibi_bias=None, kernel="auto", block_c=block_c, kinds=(paged.KV,),
+        ring_blocks=0)
+
+
+@pytest.mark.parametrize("name, heads, C, tile", [
+    ("cells-3-6-gpt2-medium", (16, 16, 64), 128, 128),
+    ("cell-4-opt-1.3b-pinned", (32, 32, 64, 64), 256, 64),
+    ("cells-5-8-olmoe", (16, 16, 128), 1024, 128),
+    ("cell-7-phi-4-mini-flash", (40, 10, 128), 512, 32),
+    ("cell-9-olmo-hybrid", (OH_KV, OH_KV, OH_W), OH_C, 64),
+], ids=lambda x: x if isinstance(x, str) else "")
+def test_auto_chunk_tile_of_the_served_shapes(monkeypatch, name, heads, C,
+                                              tile):
+    """``"auto"`` resolves the chunk kernel's tile from KV heads x G x head
+    dim: what it gives every shape the benchmark ran before ISSUE 41 is
+    what it gave (cell 4 pins its own), and 30 x 128 gets the largest
+    power of two that keeps KV heads x rows x lanes inside the 16 x 128 x
+    128 the served shapes compile with."""
+    from deepspeed_tpu.models import paged
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert paged._chunk_kernel(_geometry(*heads), C, 64, BS) == (True, tile)
+
+
+@pytest.mark.parametrize("block_c, fits", [(128, False), (64, True)])
+def test_chunk_tile_at_thirty_heads_of_128(v5e, monkeypatch, block_c, fits):
+    """30 heads x 128 rows x 128 lanes ask for more VMEM than a kernel may
+    have (the tile a cold winner cache gives); the 64 rows ``"auto"``
+    resolves to from the shape compile, at the cell's C = 1024 over a
+    table of 136 blocks."""
+    from deepspeed_tpu.models import paged
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if fits:
+        assert paged._chunk_kernel(
+            _geometry(OH_KV, OH_KV, OH_W), OH_C, OH_MB, BS) == (True, block_c)
+
+    def chunk(q, kc, vc, table, start, true_len):
+        return paged_chunk_attention(q, kc, vc, table, start, true_len,
+                                     block_c=block_c, interpret=False)
+
+    shapes = [((OH_C, OH_KV, OH_W), bf16)] \
+        + [((OH_NB, OH_KV, BS, OH_W), bf16)] * 2 \
+        + [((OH_MB,), i32), ((), i32), ((), i32)]
+    if fits:
+        _compile(chunk, shapes, v5e)
+    else:
+        with pytest.raises(Exception, match="(?i)vmem|RESOURCE_EXHAUSTED"):
+            _compile(chunk, shapes, v5e)
+
+
+def _olmo_hybrid_programs(model):
+    """The cell's three programs as the engine composes them: a chunk into
+    one slot, 8 decode steps over every slot, and both in one (fused)."""
+    def chunk(params, cache, ids, tb, to, start, n, table, slot):
+        logits, cache = model.apply_paged_chunk(
+            params, ids, cache, tb, to, start, n, table, slot)
+        return jnp.argmax(logits, axis=-1), cache
+
+    def decode(params, cache, tokens, lengths, tables):
+        toks = []
+        for _ in range(8):
+            logits, cache = model.apply_paged_decode(
+                params, tokens, lengths, cache, tables)
+            tokens = jnp.argmax(logits, axis=-1).astype(i32)
+            lengths = lengths + 1
+            toks.append(tokens)
+        return jnp.stack(toks), cache
+
+    def fused(params, cache, ids, tb, to, start, n, table, slot, tokens,
+              lengths, tables):
+        c_tok, cache = chunk(params, cache, ids, tb, to, start, n, table,
+                             slot)
+        toks, cache = decode(params, cache, tokens, lengths, tables)
+        return c_tok, toks, cache
+
+    c = [((1, OH_C), i32), ((OH_C,), i32), ((OH_C,), i32), ((), i32),
+         ((), i32), ((OH_MB,), i32), ((), i32)]
+    d = [((OH_SLOTS,), i32), ((OH_SLOTS,), i32), ((OH_SLOTS, OH_MB), i32)]
+    return {"chunk": (chunk, c), "decode_x8": (decode, d),
+            "fused": (fused, c + d)}
+
+
+@pytest.mark.parametrize("program", ["chunk", "decode_x8", "fused"])
+def test_olmo_hybrid_programs_fit_the_chip(v5e, monkeypatch, program):
+    """One period (three gated delta-rule layers and a full-attention
+    layer) of the published widths, the cell's 16 slots, 1,024-block pool
+    and 1,024-token chunk: the program compiles for a v5e with the paged
+    kernels in it (the chunk kernel at the "auto" tile), and its
+    temporaries beside the whole cell's arguments (16 layers' weights and
+    cache: a further layer is arguments, not temporaries) stay inside the
+    chip. The 16-layer programs themselves compiled to 13.5 / 13.0 / 13.6
+    GB (sandbox compile, PR 41: PERF.md section 4)."""
+    import dataclasses
+    from deepspeed_tpu.models.olmo_hybrid import OLMO_HYBRID_7B, OlmoHybrid
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = dataclasses.replace(
+        OLMO_HYBRID_7B, layer_types=OLMO_HYBRID_7B.layer_types[:16],
+        max_seq_len=OH_MB * BS)
+    period = dataclasses.replace(cell, layer_types=cell.layer_types[:4])
+
+    def trees(cfg):
+        model = OlmoHybrid(cfg)
+        model._paged_kernel, model._paged_block_c = "auto", "auto"
+        params = jax.eval_shape(model.init, jax.random.key(0))
+        params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, bf16 if x.ndim > 1 else x.dtype, sharding=v5e), params)
+        cache = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+            jax.eval_shape(lambda: model.init_paged_cache(
+                OH_NB, BS, dtype=bf16, slots=OH_SLOTS)))
+        return model, params, cache
+
+    model, params, cache = trees(period)
+    fn, shapes = _olmo_hybrid_programs(model)[program]
+    rest = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *rest).compile()
+    calls = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    # the full layer's K/V write and paged read, a program pass
+    assert calls == {"chunk": 2, "decode_x8": 16, "fused": 18}[program]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    _, whole_params, whole_cache = trees(cell)
+    assert abs(_nbytes(whole_params) - 8.20e9) < 0.01e9
+    # a slot's state is 12 x (2.21 MB + the conv tail's 69 KB) as counted,
+    # a third more as the chip tiles 192 lanes
+    assert abs(_nbytes(whole_cache) - 4.46e9) < 0.01e9
+    whole = temp + _nbytes(whole_params) + 1.05 * _nbytes(whole_cache)
+    assert whole <= (V5E_GB - 1.5) * 1e9, (temp, whole)
