@@ -45,7 +45,7 @@ from ..utils import shard_params
 from .ragged import DSStateManager, RaggedBatchWrapper
 
 # the programs a ``dstpu.engine.dispatch`` span of each kind calls, by the
-# names ``_noting_expert_calls`` keeps their counts under
+# names ``_noting_calls`` keeps their counts under
 _PROGRAMS_OF_KIND = {"decode": ("decode",), "offload": ("decode",),
                      "fused": ("fused",), "chunk": ("chunk",),
                      "spec": ("propose", "verify")}
@@ -443,6 +443,7 @@ class InferenceEngineV2:
         # program -> (expert layer calls, those of them through a Pallas
         # grouped kernel), noted when the program is traced
         self._expert_calls = {}
+        self._rule_calls = {}
         self._splitfuse_jit = None
         self._chunk_jit = None        # chunk-only (no decoders running)
         self._cow_jit = None          # prefix-cache partial-tail copy
@@ -673,21 +674,26 @@ class InferenceEngineV2:
             draft._paged_block_c = self.config.paged_block_c
             draft._weight_quant_fused = False
 
-    def _noting_expert_calls(self, body, key=None):
+    def _noting_calls(self, body, key=None):
         """``body`` — a program's traced function — noting how many expert
         layer calls (MoE layers x steps) its trace makes and how many of
         them took a Pallas grouped kernel (moe/sharded_moe.py
-        ``counting_expert_calls``; a dense model makes none), under its
-        name or ``key(*args)``. Known once the program is traced: the
-        dispatch that traces it still reads 0 of 0."""
+        ``counting_expert_calls``; a dense model makes none), and the same
+        of the gated delta rule's calls (linear layers x calls;
+        ops/gated_delta_rule.py ``counting_rule_calls``), under its name
+        or ``key(*args)``. Known once the program is traced: the dispatch
+        that traces it still reads 0 of 0."""
         from ...moe.sharded_moe import counting_expert_calls
+        from ...ops.gated_delta_rule import counting_rule_calls
 
         @functools.wraps(body)
         def program(*args):
-            with counting_expert_calls() as counts:
+            with counting_expert_calls() as experts, \
+                    counting_rule_calls() as rules:
                 out = body(*args)
             name = body.__name__ if key is None else key(*args)
-            self._expert_calls[name] = tuple(counts)
+            self._expert_calls[name] = tuple(experts)
+            self._rule_calls[name] = tuple(rules)
             return out
         return program
 
@@ -784,7 +790,7 @@ class InferenceEngineV2:
 
             # a bucket's program is a trace of its own: noted by its length
             self._prefill_jit = jax.jit(
-                self._noting_expert_calls(
+                self._noting_calls(
                     prefill, key=lambda *a: ("prefill", a[2].shape[1])),
                 donate_argnums=(1,), static_argnums=(9,),
                 in_shardings=(self.param_shardings, self._cache_sh)
@@ -832,7 +838,7 @@ class InferenceEngineV2:
             # that every call shares one executable
             whole = NamedSharding(self.mesh, P())
             self._decode_jit = jax.jit(
-                self._noting_expert_calls(decode), donate_argnums=(1,),
+                self._noting_calls(decode), donate_argnums=(1,),
                 static_argnums=(8,),
                 in_shardings=(self.param_shardings, self._cache_sh,
                               None, None, None, None, None, None, whole,
@@ -875,7 +881,7 @@ class InferenceEngineV2:
                 return c_tok, jnp.stack(toks), like_boundary(pools, cache)
 
             self._splitfuse_jit = jax.jit(
-                self._noting_expert_calls(fused), donate_argnums=(1,),
+                self._noting_calls(fused), donate_argnums=(1,),
                 static_argnums=(16,),
                 in_shardings=(self.param_shardings, self._cache_sh)
                 + (None,) * (14 + self._slot_state),
@@ -901,7 +907,7 @@ class InferenceEngineV2:
                 return c_tok, like_boundary(pools, cache)
 
             self._chunk_jit = jax.jit(
-                self._noting_expert_calls(chunk), donate_argnums=(1,),
+                self._noting_calls(chunk), donate_argnums=(1,),
                 static_argnums=(11,),
                 in_shardings=(self.param_shardings, self._cache_sh)
                 + (None,) * (9 + self._slot_state),
@@ -989,7 +995,7 @@ class InferenceEngineV2:
                 return jnp.stack(props, axis=1), like_boundary(pools, cache)
 
             self._propose_jit = jax.jit(
-                self._noting_expert_calls(propose), donate_argnums=(1,),
+                self._noting_calls(propose), donate_argnums=(1,),
                 in_shardings=(self._draft_param_sh, self._draft_cache_sh,
                               None, None, None),
                 out_shardings=(None, self._draft_cache_sh))
@@ -1012,7 +1018,7 @@ class InferenceEngineV2:
                         like_boundary(pools, cache))
 
             self._verify_jit = jax.jit(
-                self._noting_expert_calls(verify), donate_argnums=(1,),
+                self._noting_calls(verify), donate_argnums=(1,),
                 in_shardings=(self.param_shardings, self._cache_sh,
                               None, None, None),
                 out_shardings=(None, self._cache_sh))
@@ -1271,8 +1277,8 @@ class InferenceEngineV2:
         active = int(np.sum(active))
         state_updates = active * steps * self._state_layers
         rule_rows = chunk_rows * self._state_layers
-        expert_calls, expert_kernel_calls = self._expert_calls_of(
-            *_PROGRAMS_OF_KIND[kind])
+        (expert_calls, expert_kernel_calls), (rule_calls, rule_kernel_calls) \
+            = self._calls_of(*_PROGRAMS_OF_KIND[kind])
         if self.telemetry is not None:
             if steps:
                 self.telemetry.on_decode_batch(active, slots, grid_steps,
@@ -1288,18 +1294,25 @@ class InferenceEngineV2:
                     expert_calls=expert_calls,
                     expert_kernel_calls=expert_kernel_calls,
                     chained=chained, late_steps=late_steps,
-                    state_updates=state_updates, rule_rows=rule_rows)
+                    state_updates=state_updates, rule_rows=rule_rows,
+                    rule_calls=rule_calls,
+                    rule_kernel_calls=rule_kernel_calls)
 
-    def _expert_calls_of(self, *programs):
-        """(expert layer calls, those through a Pallas grouped kernel) of
-        one call of each of ``programs``, as their traces noted them, fed
-        to the telemetry's ``moe_kernel_share``."""
-        counts = [self._expert_calls.get(p, (0, 0)) for p in programs]
-        calls = sum(c for c, _ in counts)
-        kernel = sum(k for _, k in counts)
+    def _calls_of(self, *programs):
+        """((expert layer calls, those through a Pallas grouped kernel),
+        (calls of the gated delta rule, those that are a Pallas kernel))
+        of one call of each of ``programs``, as their traces noted them,
+        fed to the telemetry's ``moe_kernel_share`` and
+        ``rule_kernel_share``."""
+        def total(noted):
+            counts = [noted.get(p, (0, 0)) for p in programs]
+            return sum(c for c, _ in counts), sum(k for _, k in counts)
+
+        experts, rules = total(self._expert_calls), total(self._rule_calls)
         if self.telemetry is not None:
-            self.telemetry.on_expert_calls(calls, kernel)
-        return calls, kernel
+            self.telemetry.on_expert_calls(*experts)
+            self.telemetry.on_rule_calls(*rules)
+        return experts, rules
 
     def _step_splitfuse_chunk(self):
         """Run one fused dispatch: the next chunk of the oldest
@@ -1446,11 +1459,14 @@ class InferenceEngineV2:
         bucket = self.config.prompt_bucket
         T = len(req.prompt)
         T_pad = -(-max(T, 1) // bucket) * bucket
-        calls, kernel = self._expert_calls_of(("prefill", T_pad))
+        (calls, kernel), (rule_calls, rule_kernel_calls) = self._calls_of(
+            ("prefill", T_pad))
         with span("dstpu.engine.prefill", uid=req.uid, tokens=T,
                   padded=T_pad, expert_calls=calls,
                   expert_kernel_calls=kernel,
-                  rule_rows=T_pad * self._state_layers):
+                  rule_rows=T_pad * self._state_layers,
+                  rule_calls=rule_calls,
+                  rule_kernel_calls=rule_kernel_calls):
             with span("dstpu.engine.build"):
                 ids = np.zeros((1, T_pad), np.int32)
                 ids[0, :T] = req.prompt

@@ -24,10 +24,21 @@ chunk programs their slot.
 
 **A prompt's tokens go through the chunkwise rule** (``ops/gated_delta_rule
 .py``): a prefill or chunk program of C tokens is C / 64 chunks, products
-on the MXU within each and one scan step between them, from the slot's
+on the MXU within each and the state carried between them, from the slot's
 state (zeros at ``start = 0``) to the state after token ``true_len - 1``;
 the padding behind it has ``a = 1, b = 0`` and moves nothing. A decode step
 is the rule's one-token update on every live slot.
+
+**The rule is a Pallas kernel wherever the step's attention is** (``step
+.use_kernel``, ``models/paged.py``'s answer: Mosaic on a TPU, the Pallas
+interpreter off it; ``ops/pallas/gated_delta_rule.py``): the chunk kernel
+keeps S in VMEM over a call's chunks, and the step kernel's grid is the
+step's live slots (``step.active``), the ``ssm`` leaf aliased and written
+in place, a dead slot's row neither read nor written. A step that runs no
+kernels, and ``apply``, run the XLA forms, which are the kernels'
+reference. No setting chooses; the programs count what they took
+(``note_rule_call``: ``rule_calls`` / ``rule_kernel_calls`` on the engine's
+spans).
 
 **The residual stream is float32**; every weight product takes bfloat16
 rows and the bfloat16 weight and accumulates in float32, as the other
@@ -50,7 +61,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..ops.gated_delta_rule import chunk_rule, step_rule
+from ..ops.gated_delta_rule import chunk_rule, note_rule_call, step_rule
+from ..ops.pallas.gated_delta_rule import (chunk_rule_kernel, live_slot_list,
+                                           step_rule_kernel)
 from . import paged
 from .llama import _rms_norm
 
@@ -144,7 +157,7 @@ class _DenseStep:
                 jnp.zeros((B, cfg.linear_heads, cfg.linear_dk,
                            cfg.linear_dv), jnp.float32))
 
-    def put_state(self, i, *new):
+    def put_state(self, i, *new, in_place=()):
         pass
 
     def layer(self, i):
@@ -247,12 +260,15 @@ class OlmoHybrid:
                             jax.eval_shape(self.init, jax.random.key(0)))
 
     # -------------------------------------------------------------- mixers
-    def _delta(self, x, p, conv0, S0, valid, n_valid):
+    def _delta(self, x, p, conv0, S0, valid, n_valid, kernel=False,
+               live=None):
         """The gated delta-rule mixer: x (B, C, D) from state (conv0 (B,
         K-1, channels), S0 (B, H, dk, dv) float32); pads (``~valid``) do
         not move the state, and the conv tail is that of each row's last
-        ``n_valid`` token. -> (Mix (B, C, D), (conv, S) after the last
-        real token)."""
+        ``n_valid`` token. ``kernel``: the rule as a Pallas kernel;
+        ``live``: a decode step's ``live_slot_list``, with which S0 is the
+        layer's whole leaf and comes back written in place, live rows
+        only. -> (Mix (B, C, D), (conv, S) after the last real token)."""
         cfg = self.config
         B, C, _ = x.shape
         H, dk, dv = cfg.linear_heads, cfg.linear_dk, cfg.linear_dv
@@ -272,14 +288,21 @@ class OlmoHybrid:
                                            else 1.0)
         log_a = jnp.where(valid[..., None], log_a, 0.0)
         b = jnp.where(valid[..., None], b, 0.0)
+        # a one-token step that is not a decode step over the slots (a
+        # dense forward of one token, a chunk program of one) has no list
+        # of live slots: the XLA form
+        kernel = kernel and (C > 1 or live is not None)
+        note_rule_call(kernel)
         if C == 1:
             with jax.named_scope("dstpu.gdn.step"):
-                o, S = step_rule(q[:, 0], k[:, 0], v[:, 0], log_a[:, 0],
-                                 b[:, 0], S0)
+                rows = (q[:, 0], k[:, 0], v[:, 0], log_a[:, 0], b[:, 0], S0)
+                o, S = step_rule_kernel(*rows, live) if kernel \
+                    else step_rule(*rows)
             o, conv1 = o[:, None], win[:, 1:]
         else:
             with jax.named_scope("dstpu.gdn.chunk"):
-                o, S = chunk_rule(q, k, v, log_a, b, S0)
+                o, S = (chunk_rule_kernel if kernel else chunk_rule)(
+                    q, k, v, log_a, b, S0)
             conv1 = jax.vmap(lambda rows, n: lax.dynamic_slice(
                 rows, (n, 0), (K - 1, ch)))(win, n_valid)
         o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps) \
@@ -307,13 +330,23 @@ class OlmoHybrid:
         """The one layer loop: ``step`` is a ``models/paged.py`` step (or
         ``apply``'s stand-in) and owns every cache."""
         cfg = self.config
+        # the rule follows the step's attention: a kernel where that is
+        # one. A decode step's kernel takes the live slots alone, from one
+        # list for every layer, and writes the ``ssm`` leaf in place
+        kernel = getattr(step, "use_kernel", False)
+        live = live_slot_list(step.active) \
+            if kernel and x.shape[1] == 1 and hasattr(step, "active") \
+            else None
         for i, (kind, p) in enumerate(zip(cfg.layer_types,
                                           params["layers"])):
             if kind == LINEAR:
                 with jax.named_scope("dstpu.gdn.mix"):
                     mix, state = self._delta(
-                        x, p, *step.state(i), step.valid, step.n_valid)
-                    step.put_state(i, *state)
+                        x, p, *step.state(i), step.valid, step.n_valid,
+                        kernel, live)
+                    step.put_state(
+                        i, *state,
+                        in_place=("ssm",) if live is not None else ())
             else:
                 mix = self._attention(x, p, step.layer(i))
             x = x + _rms_norm(mix, p["norm1"], cfg.rms_eps)

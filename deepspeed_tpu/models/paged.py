@@ -31,7 +31,10 @@ A layer's cache is one of four kinds (``Geometry.kinds``):
 * ``STATE``: a recurrent state a slot (``conv`` / ``ssm``), handed out
   and taken back by ``_Step.state`` / ``put_state``: a chunk at
   ``start = 0`` gets zeros whatever the slot held, a decode step keeps
-  the state of every slot that is not live.
+  the state of every slot that is not live. The step says which slots
+  those are (``active``) and whether its program runs kernels
+  (``use_kernel``), so a model whose update is a kernel over the live
+  slots alone writes the leaf in place and says so (``in_place``).
 
 ``None`` is a layer with no cache. Everything but ``KV`` is indexed by
 the slot, which the chunk and prefill programs are therefore told.
@@ -256,11 +259,16 @@ class _Step:
         j = self.index[i]
         return tuple(self.take(self.cache[k][j]) for k in _KEYS[STATE])
 
-    def put_state(self, i, *new):
+    def put_state(self, i, *new, in_place=()):
+        """Layer i's new (conv, ssm) rows back into their leaves. A key
+        in ``in_place`` names a leaf that IS the new leaf already: a
+        kernel took the whole of it, aliased, and wrote this program's
+        live rows only."""
         j = self.index[i]
         for k, x in zip(_KEYS[STATE], new):
             leaf = self.cache[k][j]
-            self.cache[k][j] = self.put(leaf, x.astype(leaf.dtype))
+            self.cache[k][j] = x if k in in_place \
+                else self.put(leaf, x.astype(leaf.dtype))
 
 
 def _ring_table(geom, slots, MB):
@@ -384,6 +392,7 @@ def batch_step(geom, cache, lengths, block_tables, C):
 
     step = _Step(geom, cache, use_kernel, attend, tables, dest,
                  lambda leaf: leaf, put)
+    step.active = active
     step.valid = jnp.ones((B, C), bool)
     step.n_valid = jnp.full((B,), C, jnp.int32)
     return step

@@ -240,18 +240,20 @@ SPAN_SCHEMA = {
                    "for chunks or through its bucketed prefill"},
     "dstpu.engine.prefill": {
         "stats": ("uid", "tokens", "padded", "expert_calls",
-                  "expert_kernel_calls", "rule_rows"),
+                  "expert_kernel_calls", "rule_rows", "rule_calls",
+                  "rule_kernel_calls"),
         "meaning": "bucketed prefill of one request: arrays, program "
                    "call, blocking read of its token; expert_calls / "
-                   "expert_kernel_calls / rule_rows as on "
-                   "dstpu.engine.dispatch"},
+                   "expert_kernel_calls / rule_rows / rule_calls / "
+                   "rule_kernel_calls as on dstpu.engine.dispatch"},
     "dstpu.engine.dispatch": {
         "stats": ("kind", "active", "slots", "steps", "chunk_tokens",
                   "grid_steps", "table_entries", "kernel_steps",
                   "write_rows",
                   "write_rows_offered", "expert_calls",
                   "expert_kernel_calls", "chained", "late_steps",
-                  "state_updates", "rule_rows"),
+                  "state_updates", "rule_rows", "rule_calls",
+                  "rule_kernel_calls"),
         "meaning": "one decode-bearing or chunk program call (kind "
                    "decode | fused | chunk | spec | offload) from the "
                    "assembled batch to the last posted token — of kind "
@@ -287,7 +289,16 @@ SPAN_SCHEMA = {
                    "updates the dispatch makes, and rule_rows = the rows "
                    "its chunk runs the scan or the chunkwise rule on, "
                    "padding included, x those layers (both 0 on a model "
-                   "without such a layer)"},
+                   "without such a layer); "
+                   "rule_calls = the calls of the gated delta rule the "
+                   "dispatch's program makes, chunkwise or one-token "
+                   "(linear layers x its chunk call and decode steps), "
+                   "noted when the program is traced as the expert calls "
+                   "are, so 0 on a model without the rule and on the "
+                   "dispatch that traces it; "
+                   "rule_kernel_calls = those of rule_calls that are a "
+                   "Pallas kernel (ops/pallas/gated_delta_rule.py), the "
+                   "rest the XLA form"},
     "dstpu.engine.build": {
         "stats": (),
         "meaning": "leaf: host work before a program call (decode "
@@ -341,11 +352,15 @@ SCOPE_SCHEMA = {
         "norm a head, out-projection, and the slot state's read and write",
     "dstpu.gdn.chunk":
         "inside dstpu.gdn.mix: the chunkwise-parallel rule of a prefill "
-        "or chunk program (ops/gated_delta_rule.py:chunk_rule), from the "
-        "slot's state to the state after the last real token",
+        "or chunk program, from the slot's state to the state after the "
+        "last real token: the Pallas kernel that keeps the state in VMEM "
+        "over the call's chunks (ops/pallas/gated_delta_rule.py) where the "
+        "step runs kernels, else ops/gated_delta_rule.py:chunk_rule",
     "dstpu.gdn.step":
         "inside dstpu.gdn.mix: the rule's one-token update of a decode "
-        "step, every slot's state read once and written once",
+        "step: the Pallas kernel over the step's live slots, their state "
+        "read once and written once in place, where the step runs "
+        "kernels, else step_rule over every slot",
     "dstpu.attn.full":
         "a full-attention layer of olmo_hybrid: the K/V write into its "
         "pool under the block table and the paged read",

@@ -845,6 +845,10 @@ class ServingTelemetry:
         # whose products were a Pallas grouped kernel
         self._expert_calls = 0
         self._expert_kernel_calls = 0
+        # calls of the gated delta rule of every program call, and those
+        # of them that were a Pallas kernel
+        self._rule_calls = 0
+        self._rule_kernel_calls = 0
         # bytes of cache the live sequences hold (blocks under their
         # tables, and whatever the model keeps a slot) against the tokens
         # they have seen, summed over the engine's steps
@@ -944,6 +948,13 @@ class ServingTelemetry:
         kernel and the rest through ``lax.ragged_dot``."""
         self._expert_calls += calls
         self._expert_kernel_calls += kernel
+
+    def on_rule_calls(self, calls, kernel):
+        """One program call whose trace made ``calls`` calls of the gated
+        delta rule (linear layers x chunk calls and decode steps),
+        ``kernel`` of them a Pallas kernel and the rest the XLA form."""
+        self._rule_calls += calls
+        self._rule_kernel_calls += kernel
 
     def on_cache_held(self, cache_bytes, live_tokens):
         """One engine step began with ``cache_bytes`` of cache held by
@@ -1108,6 +1119,9 @@ class ServingTelemetry:
         if self._expert_calls:
             out["moe_kernel_share"] = round(
                 self._expert_kernel_calls / self._expert_calls, 4)
+        if self._rule_calls:
+            out["rule_kernel_share"] = round(
+                self._rule_kernel_calls / self._rule_calls, 4)
         if self._live_tokens:
             out["cache_bytes_per_live_token"] = round(
                 self._cache_bytes / self._live_tokens)

@@ -6,11 +6,15 @@ one layer, a head at a time:
 
 ``chunk_rule`` is the chunkwise-parallel form (arXiv 2406.06484, section 3,
 with the gate of 2412.06464, section 3.3) for the T tokens of a prefill or
-a chunk program, ``step_rule`` the one-token update of a decode step. Both
-are plain XLA: batched products over (batch, head, chunk) and one
+a chunk program, ``step_rule`` the one-token update of a decode step. The
+two here are plain XLA: batched products over (batch, head, chunk) and one
 ``lax.scan`` over the chunks, in which only what depends on the state is
-left. A token whose ``log_a`` is 0 and ``b`` is 0 (a chunk's padding) leaves
-the state as it was.
+left. They are what a dense forward runs and the reference of the two
+Pallas kernels of ``ops/pallas/gated_delta_rule.py``, which a paged step
+that runs kernels takes instead (``models/olmo_hybrid.py``): the same
+mathematics at the same precision with the state kept in VMEM. A token
+whose ``log_a`` is 0 and ``b`` is 0 (a chunk's padding) leaves the state as
+it was.
 
 The chunkwise form is exact algebra. With ``u_t = b_t (v_t - a_t S_{t-1}^T
 k_t)`` the rule is ``S_t = a_t S_{t-1} + k_t u_t^T``; inside a chunk of L
@@ -31,6 +35,9 @@ solved halves join as ``[[X11, 0], [-X22 A21 X11, X22]]``, two products a
 level, L = 64 in two levels.
 """
 
+import contextlib
+import contextvars
+
 import jax.numpy as jnp
 from jax import lax
 
@@ -42,6 +49,34 @@ _BASE = 16          # rows of a diagonal block solved by substitution
 # wrong rule for the whole chunk, a rounded S one that later chunks
 # inherit); see PERF.md, PR 41, for what each costs on the chip.
 EXACT = lax.Precision.HIGHEST
+
+# the tally a ``counting_rule_calls`` block is filling, if any
+_RULE_CALLS = contextvars.ContextVar("dstpu_rule_calls", default=None)
+
+
+@contextlib.contextmanager
+def counting_rule_calls():
+    """Yields ``[calls, kernel_calls]``: the calls of the rule (either
+    form; linear layers x calls) traced inside the block, and those of them
+    that are a Pallas kernel (:func:`note_rule_call` says which).
+    Trace-time Python, as ``moe/sharded_moe.py:counting_expert_calls``: a
+    serving engine puts it round a program's traced body, for its dispatch
+    span (rule_calls / rule_kernel_calls)."""
+    counts = [0, 0]
+    token = _RULE_CALLS.set(counts)
+    try:
+        yield counts
+    finally:
+        _RULE_CALLS.reset(token)
+
+
+def note_rule_call(kernel):
+    """A model's word that it traces one call of the rule here, through
+    a Pallas kernel or not."""
+    counts = _RULE_CALLS.get()
+    if counts is not None:
+        counts[0] += 1
+        counts[1] += bool(kernel)
 
 
 def _unit_lower_inverse(A):

@@ -40,6 +40,8 @@ from deepspeed_tpu.models.olmo_hybrid import (FULL, LINEAR,  # noqa: E402
                                               OLMO_HYBRID_TINY, OlmoHybrid)
 from deepspeed_tpu.ops.gated_delta_rule import (CHUNK,  # noqa: E402
                                                 chunk_rule, step_rule)
+from deepspeed_tpu.ops.pallas.gated_delta_rule import (  # noqa: E402
+    chunk_rule_kernel, head_group, live_slot_list, step_rule_kernel)
 
 ref = importlib.import_module("references.olmo_hybrid")
 
@@ -213,6 +215,109 @@ def test_padding_leaves_the_state_untouched():
     assert np.array_equal(np.asarray(S[0]), S0)
 
 
+# -------------------------------------------------------------- the kernels
+# ``ops/pallas/gated_delta_rule.py`` against the XLA forms above, in the
+# Pallas interpreter: the same products in the same precision, so they
+# differ by the order of a few sums (measured 2e-7 to 5e-6 of the largest
+# entry; the XLA form is itself 2e-5 from the sequential rule)
+KERNEL_TOL = 2e-5
+
+
+def batched(x):
+    return tuple(a[None] for a in x)
+
+
+@pytest.mark.parametrize("T", [1, 5, CHUNK, 100, 2 * CHUNK, 200, 1024])
+def test_chunk_kernel_equals_xla_form(T):
+    """o AND the state, from a state that is not zero, at lengths that
+    are and are not whole 64-token chunks (sixteen of them at 1,024)."""
+    x = batched(rule_inputs(T, seed=4))
+    want_o, want_S = chunk_rule(*x)
+    o, S = chunk_rule_kernel(*x)
+    assert o.shape == (1, T, H, DV) and S.shape == (1, H, DK, DV)
+    assert np.abs(o - want_o).max() < KERNEL_TOL * np.abs(want_o).max()
+    assert np.abs(S - want_S).max() < KERNEL_TOL * np.abs(want_S).max()
+
+
+def test_chunk_kernel_takes_rows_and_head_groups():
+    """Two rows of a batch, each from its own state, and heads that are
+    more than one group (12 heads: two groups of 6, as 30 are five)."""
+    assert [head_group(h) for h in (4, 12, 30, 32)] == [4, 6, 6, 8]
+    parts = [rule_inputs(70, seed=s) for s in (6, 7, 8)]
+    # heads are axis 1 of q, k, v, log_a, b and axis 0 of the state
+    x = [np.concatenate(leaves, axis=0 if i == 5 else 1)
+         for i, leaves in enumerate(zip(*parts))]
+    x = [np.stack([a, a[::-1]]) for a in x]        # the second row: other data
+    want_o, want_S = chunk_rule(*x)
+    o, S = chunk_rule_kernel(*x)
+    assert o.shape == (2, 70, 3 * H, DV)
+    assert np.abs(o - want_o).max() < KERNEL_TOL * np.abs(want_o).max()
+    assert np.abs(S - want_S).max() < KERNEL_TOL * np.abs(want_S).max()
+
+
+def test_chunk_kernel_survives_repeated_keys():
+    """``test_chunkwise_rule_survives_repeated_keys``' data: the kernel's
+    inverse is the triangular solve's algebra too, never a series."""
+    q, k, v, log_a, b, S0 = rule_inputs(2 * CHUNK, repeat=True)
+    want_o, want_S = ref.delta_rule(q, k, v, np.exp(log_a), b, S0)
+    o, S = chunk_rule_kernel(*batched((q, k, v, log_a, b, S0)))
+    assert np.abs(o[0] - want_o).max() < 1e-4 * np.abs(want_o).max()
+    assert np.abs(S[0] - want_S).max() < 1e-4 * np.abs(want_S).max()
+    xo, xS = chunk_rule(*batched((q, k, v, log_a, b, S0)))
+    assert np.abs(o - xo).max() < KERNEL_TOL * np.abs(xo).max()
+    assert np.abs(S - xS).max() < KERNEL_TOL * np.abs(xS).max()
+
+
+@pytest.mark.parametrize("real", [0, 37, 64], ids=lambda n: f"{n}-real")
+def test_chunk_kernel_padded_tail_leaves_the_state(real):
+    """Rows with ``log_a = 0, b = 0`` behind ``real`` real ones: the
+    state is that after the last real row, and a chunk (or a call) that
+    is padding alone hands S back bit for bit."""
+    q, k, v, log_a, b, S0 = rule_inputs(3 * CHUNK, seed=3)
+    log_a[real:], b[real:] = 0.0, 0.0
+    _, S = chunk_rule_kernel(*batched((q, k, v, log_a, b, S0)))
+    if not real:
+        assert np.array_equal(np.asarray(S[0]), S0)
+        return
+    _, stop = chunk_rule_kernel(*batched(
+        tuple(a[:real] for a in (q, k, v, log_a, b)) + (S0,)))
+    # the padding's two whole chunks (and the rest of a first one that is
+    # partly real) moved nothing
+    want = np.asarray(chunk_rule(*batched(
+        tuple(a[:real] for a in (q, k, v, log_a, b)) + (S0,)))[1])
+    assert np.abs(S - want).max() < KERNEL_TOL * np.abs(want).max()
+    if real == CHUNK:
+        assert np.array_equal(np.asarray(S), np.asarray(stop))
+
+
+LIVE_SETS = {"none": [], "one": [3], "some": [0, 2, 3], "all": [0, 1, 2, 3, 4]}
+
+
+@pytest.mark.parametrize("live", sorted(LIVE_SETS))
+def test_step_kernel_touches_live_slots_only(live):
+    """The step kernel = ``step_rule`` on the live slots, and every dead
+    slot's ``ssm`` row is bit for bit what it was."""
+    slots = 5
+    q, k, v, log_a, b, _ = rule_inputs(slots, seed=7)
+    ssm = np.random.default_rng(8).normal(
+        size=(slots, H, DK, DV)).astype(np.float32)
+    active = np.zeros(slots, bool)
+    active[LIVE_SETS[live]] = True
+    slot_of, n = live_slot_list(jnp.asarray(active))
+    assert int(n) == active.sum()
+    assert list(np.asarray(slot_of)[:int(n)]) == LIVE_SETS[live]
+    assert not np.asarray(slot_of)[int(n):].any()
+    want_o, want_S = step_rule(q, k, v, log_a, b, ssm)
+    o, S = step_rule_kernel(q, k, v, log_a, b, jnp.asarray(ssm),
+                            (slot_of, n))
+    o, S = np.asarray(o), np.asarray(S)
+    assert np.array_equal(S[~active], ssm[~active])
+    assert np.isfinite(o).all()
+    if active.any():
+        assert np.abs(o[active] - np.asarray(want_o)[active]).max() < 1e-5
+        assert np.abs(S[active] - np.asarray(want_S)[active]).max() < 1e-5
+
+
 # --------------------------------------------------------------- the engine
 @pytest.fixture(scope="module")
 def mixed(model, params):
@@ -247,6 +352,22 @@ def test_engine_equals_reference(params, mixed, which):
         far = reference_rows(params, prompts[which], tokens, **variant)
         assert np.abs(far - want).max() > 50 * TOL
     assert {"chunk", "fused", "decode"} <= set(kinds)
+
+
+def test_engine_on_the_kernel_path_equals_reference(model, params, mixed):
+    """``paged_kernel=True``: chunks through the chunk kernel (off the
+    TPU "auto" keeps a chunk dense, and the rule follows the attention),
+    decode steps through the step kernel as in ``mixed``."""
+    _, prompts, _, _ = mixed
+    eng = engine_of(model, params, paged_kernel=True)
+    out = serve(eng, prompts, [12] * 3)
+    for prompt, (tokens, rows) in zip(prompts, out):
+        want = reference_rows(params, prompt, tokens)
+        assert np.abs(rows - want).max() < TOL
+    snap = eng.telemetry_snapshot()
+    assert snap["rule_kernel_share"] == 1.0
+    # and ``mixed``, at "auto" on a CPU: the steps' kernel, the chunks' XLA
+    assert 0.5 < mixed[0].telemetry_snapshot()["rule_kernel_share"] < 1.0
 
 
 def test_cache_is_two_kinds_and_only_full_layers_are_paged(mixed):
@@ -301,9 +422,16 @@ def test_live_slot_unmoved_by_dead_and_new_ones(mixed):
     assert np.abs(got[0][1] - out[2][1]).max() < TOL
 
 
-def test_dispatch_spans_count_the_rule(model, params, monkeypatch):
+@pytest.mark.parametrize("paged_kernel", [True, False],
+                         ids=["kernels", "xla"])
+def test_dispatch_spans_count_the_rule(model, params, monkeypatch,
+                                       paged_kernel):
     """``state_updates`` and ``rule_rows`` on every dispatch span: live
-    slots x steps x 6 linear layers, and the chunk's padded rows x 6."""
+    slots x steps x 6 linear layers, and the chunk's padded rows x 6; and
+    ``rule_calls`` / ``rule_kernel_calls``: the calls of the rule the
+    span's program makes (6 a chunk call, 6 a decode step), noted when
+    the program is traced (so 0 on the dispatch that traces it), all of
+    them kernels where the step runs kernels and none where it does not."""
     from deepspeed_tpu.inference.v2 import engine_v2
     said = []
     real = engine_v2.span
@@ -314,17 +442,27 @@ def test_dispatch_spans_count_the_rule(model, params, monkeypatch):
         return real(name, **stats)
 
     monkeypatch.setattr(engine_v2, "span", recording)
-    eng = InferenceEngineV2(model, ENGINE, params=params)
+    eng = InferenceEngineV2(model, {**ENGINE, "paged_kernel": paged_kernel},
+                            params=params)
     for p in prompts_of(5, 21):
         eng.put(p, 6)
     while eng.has_work:
         eng.step()
     assert {st["kind"] for st in said} >= {"chunk", "fused"}
+    traced = set()
     for st in said:
         assert st["state_updates"] == st["active"] * st["steps"] * 6
         assert st["rule_rows"] == (0 if st["kind"] == "decode" else C * 6)
+        calls = 6 * (st["steps"] + (st["kind"] != "decode"))
+        want = calls if st["kind"] in traced else 0
+        traced.add(st["kind"])
+        assert st["rule_calls"] == want
+        assert st["rule_kernel_calls"] == (want if paged_kernel else 0)
+    assert sum(st["rule_calls"] for st in said) > 0
     assert sum(st["chunk_tokens"] for st in said) == 26
     assert sum(st["rule_rows"] for st in said) == (1 + 3) * C * 6
+    assert eng.telemetry_snapshot()["rule_kernel_share"] \
+        == float(paged_kernel)
 
 
 def test_cache_bytes_counter(mixed):

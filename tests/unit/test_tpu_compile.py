@@ -23,6 +23,8 @@ from jax.sharding import SingleDeviceSharding
 
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.ops.pallas.fused_ce import unembed_logits_stats
+from deepspeed_tpu.ops.pallas.gated_delta_rule import (
+    chunk_rule_kernel, live_slot_list, step_rule_kernel)
 from deepspeed_tpu.ops.pallas.paged_attention import (
     as_pools, decode_entries_per_step, decode_work_list, like_boundary,
     paged_chunk_attention, paged_decode_attention, pool_block_dims)
@@ -97,6 +99,23 @@ PAGED_DECODE_CELLS = {
     "olmoe_chat": (32, 16, 128, 64, 512),
 }
 
+def _gdn_step(q, k, v, log_a, b, ssm, active):
+    return step_rule_kernel(q, k, v, log_a, b, ssm, live_slot_list(active),
+                            interpret=False)
+
+
+# the gated delta rule at Olmo-Hybrid-7B's widths (30 heads, dk 96, dv 192,
+# float32): the cell's 1,024-token chunk call and its 16 slots' decode step
+GDN_H, GDN_DK, GDN_DV, GDN_T, GDN_SLOTS = 30, 96, 192, 1024, 16
+f32 = jnp.float32
+
+
+def _gdn_shapes(*lead):
+    return [(lead + (GDN_H, GDN_DK), f32)] * 2 \
+        + [(lead + (GDN_H, GDN_DV), f32)] + [(lead + (GDN_H,), f32)] * 2 \
+        + [((lead[0], GDN_H, GDN_DK, GDN_DV), f32)]
+
+
 POOL = [((NB, H, BS, HD), bf16)] * 2
 CASES = {
     # training: the headline's whole-sequence tile, and the config default
@@ -119,6 +138,12 @@ CASES = {
     "paged_prefill_c320": (
         _paged_chunk,
         [((320, H, HD), bf16)] + POOL + [((5,), i32), ((), i32), ((), i32)]),
+    # the gated delta rule's two kernels at cell 9's shapes
+    "gdn_chunk_1024x30x96x192": (
+        lambda *a: chunk_rule_kernel(*a, interpret=False),
+        _gdn_shapes(1, GDN_T)),
+    "gdn_step_16_slots": (
+        _gdn_step, _gdn_shapes(GDN_SLOTS) + [((GDN_SLOTS,), jnp.bool_)]),
 }
 
 
@@ -673,6 +698,61 @@ def _olmo_hybrid_programs(model):
             "fused": (fused, c + d)}
 
 
+def test_cell9_decode_step_updates_live_states_in_place(v5e, monkeypatch):
+    """One decode step of one period of ``serve-olmohybrid-longdoc-sat``
+    (three gated delta-rule layers and a full layer, 16 slots) for the
+    described v5e: each linear layer's ``ssm`` leaf goes through the step
+    kernel aliased (the program's donated argument is its output), and
+    nothing of a leaf's size is made by anything else: no ``select`` puts
+    dead slots' rows back, no copy (ISSUE 42; the pattern of
+    ``test_cell3_decode_step_writes_live_rows_only``)."""
+    import dataclasses
+    from deepspeed_tpu.models.olmo_hybrid import OLMO_HYBRID_7B, OlmoHybrid
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = OlmoHybrid(dataclasses.replace(
+        OLMO_HYBRID_7B, layer_types=OLMO_HYBRID_7B.layer_types[:4],
+        max_seq_len=OH_MB * BS))
+    model._paged_kernel, model._paged_block_c = "auto", "auto"
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, bf16 if x.ndim > 1 else x.dtype, sharding=v5e),
+        jax.eval_shape(model.init, jax.random.key(0)))
+    cache = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+        jax.eval_shape(lambda: model.init_paged_cache(
+            OH_NB, BS, dtype=bf16, slots=OH_SLOTS)))
+
+    def decode(params, cache, tokens, lengths, tables):
+        logits, cache = model.apply_paged_decode(
+            params, tokens, lengths, cache, tables)
+        return jnp.argmax(logits, axis=-1).astype(i32), cache
+
+    rest = [jax.ShapeDtypeStruct(s, i32, sharding=v5e)
+            for s in ((OH_SLOTS,), (OH_SLOTS,), (OH_SLOTS, OH_MB))]
+    text = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, cache, *rest).compile().as_text()
+    leaf = rf"f32\[{OH_SLOTS},(?:{GDN_H}|5,6),{GDN_DK},{GDN_DV}\]"
+    calls = [ln for ln in text.split("\n")
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    steps = [ln for ln in calls if re.search(rf"{leaf}\S*\) custom-call", ln)]
+    assert len(steps) == 3 and len(calls) == 3 + 2
+    for ln in steps:
+        # the grid: the live slots' list and its length, made on the device
+        operands = ln[ln.index("operand_layout_constraints={"):]
+        assert operands.startswith(
+            f"operand_layout_constraints={{s32[], s32[{OH_SLOTS + 1}]{{0}}, "
+        ), operands[:120]
+        assert "output_to_operand_aliasing={" in ln
+    made_by = set(re.findall(
+        rf"= \(?(?:f32\S* )?{leaf}\S* (?:f32\S* )?([\w-]+)\(", text))
+    assert "custom-call" in made_by
+    assert made_by <= {"custom-call", "bitcast", "get-tuple-element",
+                       "parameter"}, made_by
+    # every leaf of the donated cache is its own output
+    header = text[:text.index("\n")]
+    assert header.count("-alias)") == len(jax.tree.leaves(cache))
+
+
 @pytest.mark.parametrize("program", ["chunk", "decode_x8", "fused"])
 def test_olmo_hybrid_programs_fit_the_chip(v5e, monkeypatch, program):
     """One period (three gated delta-rule layers and a full-attention
@@ -709,8 +789,9 @@ def test_olmo_hybrid_programs_fit_the_chip(v5e, monkeypatch, program):
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, cache, *rest).compile()
     calls = compiled.as_text().count('custom_call_target="tpu_custom_call"')
-    # the full layer's K/V write and paged read, a program pass
-    assert calls == {"chunk": 2, "decode_x8": 16, "fused": 18}[program]
+    # a program pass: the full layer's K/V write and paged read, and the
+    # three linear layers' rule (the chunk kernel, the step kernel)
+    assert calls == {"chunk": 5, "decode_x8": 40, "fused": 45}[program]
     temp = compiled.memory_analysis().temp_size_in_bytes
     _, whole_params, whole_cache = trees(cell)
     assert abs(_nbytes(whole_params) - 8.20e9) < 0.01e9
