@@ -46,6 +46,9 @@ from .ragged import DSStateManager, RaggedBatchWrapper
 
 # the programs a ``dstpu.engine.dispatch`` span of each kind calls, by the
 # names ``_noting_calls`` keeps their counts under
+# cache keys of the pools under the block tables, which the allocator's
+# blocks pay for: K / V, and a latent layer's two (models/paged.py)
+_BLOCK_KEYS = ("k", "v", "lat", "idx")
 _PROGRAMS_OF_KIND = {"decode": ("decode",), "offload": ("decode",),
                      "fused": ("fused",), "chunk": ("chunk",),
                      "spec": ("propose", "verify")}
@@ -280,8 +283,17 @@ class InferenceEngineV2:
         # prefill and chunk, and cannot have what assumes that a cache
         # is length-masked KV under a block table
         self._slot_state = bool(getattr(model, "slot_state", False))
-        if self._slot_state:
-            self._refuse_slot_state(config, draft_model)
+        # a model whose layers cache a compressed latent read through a
+        # per-query selection (models/paged.py, LATENT) is under the block
+        # tables as K/V is, but nothing that handles blocks as K and V
+        # pools (prefix cache, a draft's rollback, offload, transfer) has
+        # learnt its leaves
+        from ...models.paged import LATENT, STATE, decode_kernel_calls, \
+            geometry
+        self._latent_layers = geometry(model).kinds.count(LATENT)
+        if self._slot_state or self._latent_layers:
+            self._refuse_by_cache_kind(config, draft_model,
+                                       bool(self._latent_layers))
             draft_model = None            # spec_draft=False: off
         # blocks a slot of a window layer's ring: enough for the largest
         # step this engine's programs take past position 0 (a chunk; a
@@ -348,7 +360,6 @@ class InferenceEngineV2:
         # (table entries a grid step of the decode kernel takes, the
         # layers that call it by their window): what the dispatch spans
         # count its steps by
-        from ...models.paged import STATE, decode_kernel_calls, geometry
         self._decode_calls = decode_kernel_calls(
             self.model, self.max_blocks_per_seq, BS, dtype)
         # layers that keep a recurrent state a slot: what the dispatch
@@ -372,10 +383,12 @@ class InferenceEngineV2:
         # whatever else the model keeps (rings, recurrent state)
         self._block_bytes = sum(
             math.prod(p.shape[-3:]) * p.dtype.itemsize
-            for key in ("k", "v") for p in self.cache[key])
+            for key in ("k", "v") for p in self.cache.get(key, ())) \
+            + sum(math.prod(p.shape[-2:]) * p.dtype.itemsize
+                  for key in ("lat", "idx") for p in self.cache.get(key, ()))
         self._slot_bytes = sum(
             p.nbytes for key, sub in self.cache.items()
-            if key not in ("k", "v") for p in jax.tree.leaves(sub)) \
+            if key not in _BLOCK_KEYS for p in jax.tree.leaves(sub)) \
             // config.max_batch_size
         if config.kv_host_offload:
             from .kv_offload import OffloadKVPool
@@ -586,32 +599,49 @@ class InferenceEngineV2:
 
     # ------------------------------------------------------------- programs
     @staticmethod
-    def _refuse_slot_state(config, draft_model):
-        """What cannot be right for a model that keeps state by slot,
-        refused by name; every "auto" resolves to off."""
-        why = ("the model keeps recurrent / window state by batch slot "
-               "(slot_state), which ")
+    def _refuse_by_cache_kind(config, draft_model, latent):
+        """What cannot be right for a model that keeps state by slot, or
+        whose blocks hold a latent cache (``latent``), refused by name;
+        every "auto" resolves to off."""
+        if latent:
+            why = ("the model's blocks hold a latent cache read through a "
+                   "per-query selection (models/paged.py, LATENT), which ")
+            refusals = (
+                "the prefix cache's copy-on-write and block reuse, written "
+                "for K and V pools, have not learnt",
+                "a draft model's verify pass and rollback_spec have no "
+                "program for",
+                "the offload tier, which pages K and V pools, does not "
+                "page")
+        else:
+            why = ("the model keeps recurrent / window state by batch slot "
+                   "(slot_state), which ")
+            refusals = (
+                "a cached block of KV does not bring back — a prefix hit "
+                "would resume from a state nobody kept",
+                "rollback_spec cannot take back once the rejected tokens "
+                "have moved it",
+                "lives outside the block pool the offload tier pages")
         if config.prefix_cache is True:
-            raise ValueError(
-                "prefix_cache=True: " + why + "a cached block of KV does "
-                "not bring back — a prefix hit would resume from a state "
-                "nobody kept")
+            raise ValueError("prefix_cache=True: " + why + refusals[0])
         if config.spec_draft is True or (
                 draft_model is not None and config.spec_draft is not False):
             # a draft model IS the opt-in to speculation
-            raise ValueError(
-                "spec_draft=True / a draft model: " + why + "rollback_spec "
-                "cannot take back once the rejected tokens have moved it")
+            raise ValueError("spec_draft=True / a draft model: " + why
+                             + refusals[1])
         if config.kv_host_offload:
-            raise ValueError(
-                "kv_host_offload: " + why + "lives outside the block pool "
-                "the offload tier pages")
+            raise ValueError("kv_host_offload: " + why + refusals[2])
 
     def _refuse_kv_transfer(self):
         if self._slot_state:
             raise RuntimeError(
                 "disaggregated kv_transfer: the model keeps recurrent / "
                 "window state by batch slot (slot_state), which the block "
+                "payloads of a KV handoff do not carry")
+        if self._latent_layers:
+            raise RuntimeError(
+                "disaggregated kv_transfer: the model's blocks hold a "
+                "latent cache (models/paged.py, LATENT), which the K / V "
                 "payloads of a KV handoff do not carry")
 
     def _resolve_prefix_cache(self, mcfg, num_blocks):
@@ -623,7 +653,7 @@ class InferenceEngineV2:
         hand-set values (disabled, min-match 1, on-demand eviction), so
         a cold-cache engine is byte-identical to prefix_cache=False."""
         cfg = self.config
-        if self._slot_state:
+        if self._slot_state or self._latent_layers:
             return False, 1, 0        # True was refused at build
         windows = tuple(getattr(mcfg, "attn_layer_windows", ()) or ())
         if any(windows):
@@ -726,7 +756,11 @@ class InferenceEngineV2:
             return model.init_paged_cache(n, cfg.kv_block_size,
                                           dtype=self.dtype, **extra)
 
-        _, _, BS, hd = jax.eval_shape(lambda: init(1))["k"][0].shape
+        pools = jax.eval_shape(lambda: init(1)).get("k")
+        # a latent cache's pools (lat / idx) are read by XLA and keep the
+        # shape the model gives them
+        _, _, BS, hd = pools[0].shape if pools \
+            else (0, 0, cfg.kv_block_size, 128)
         kernel = not interpret_default() and uses_decode_kernel(
             model, cfg.max_batch_size, self.max_blocks_per_seq, BS,
             self.dtype)
@@ -1240,7 +1274,8 @@ class InferenceEngineV2:
             self.telemetry.on_handoff_out(uid)
 
     def _dispatch_span(self, kind, active, steps, chunk_tokens=0,
-                       chunk_rows=0, batch=None, chained=0, late_steps=0):
+                       chunk_rows=0, batch=None, chained=0, late_steps=0,
+                       chunk_start=0):
         """The ``dstpu.engine.dispatch`` span of one program call, opened
         once the batch is assembled (its stats are fixed here); a
         decode-bearing dispatch also feeds the occupancy counter.
@@ -1255,7 +1290,15 @@ class InferenceEngineV2:
         slots = self.config.max_batch_size
         grid_steps = kernel_steps = table_entries = 0
         write_rows, write_rows_offered = chunk_tokens, chunk_rows
-        if batch is not None:
+        index_keys, attended_keys = self._selected_read(
+            chunk_start, chunk_tokens)
+        if batch is not None and self._latent_layers:
+            # each live slot's token a decode step, from its length on
+            live = np.asarray(batch[0])[np.asarray(active, bool)]
+            decode = self._selected_read(live, steps)
+            index_keys += decode[0]
+            attended_keys += decode[1]
+        if batch is not None and self._decode_calls[1]:
             lengths, tables = batch
             MB, BS = self.max_blocks_per_seq, self.state_mgr.block_size
             entries_per_step, windows = self._decode_calls
@@ -1288,7 +1331,8 @@ class InferenceEngineV2:
                 self.telemetry.on_plain_decode(chained, steps * active)
         return span("dstpu.engine.dispatch", kind=kind, active=active,
                     slots=slots, steps=steps, chunk_tokens=chunk_tokens,
-                    grid_steps=grid_steps, table_entries=table_entries,
+                    chunk_start=chunk_start, grid_steps=grid_steps,
+                    table_entries=table_entries,
                     kernel_steps=kernel_steps, write_rows=write_rows,
                     write_rows_offered=write_rows_offered,
                     expert_calls=expert_calls,
@@ -1296,7 +1340,22 @@ class InferenceEngineV2:
                     chained=chained, late_steps=late_steps,
                     state_updates=state_updates, rule_rows=rule_rows,
                     rule_calls=rule_calls,
-                    rule_kernel_calls=rule_kernel_calls)
+                    rule_kernel_calls=rule_kernel_calls,
+                    index_keys=index_keys, attended_keys=attended_keys)
+
+    def _selected_read(self, start, tokens):
+        """(index_keys, attended_keys) of ``tokens`` consecutive real
+        query tokens from position ``start`` (an array: one run a live
+        slot) in every latent layer: the causal keys the indexer scores,
+        position + 1 a query, and the keys attended after the selection,
+        ``min(position + 1, index_topk)``. Host arithmetic; (0, 0) on a
+        model with no such layer."""
+        if not self._latent_layers or not tokens:
+            return 0, 0
+        ctx = np.asarray(start, np.int64)[..., None] + 1 + np.arange(tokens)
+        topk = self.model.config.index_topk
+        return (int(ctx.sum()) * self._latent_layers,
+                int(np.minimum(ctx, topk).sum()) * self._latent_layers)
 
     def _calls_of(self, *programs):
         """((expert layer calls, those through a Pallas grouped kernel),
@@ -1368,9 +1427,11 @@ class InferenceEngineV2:
             dispatch = self._dispatch_span(
                 "fused", batch.active,
                 max(1, self.config.decode_steps_per_dispatch), true_len,
-                C, batch=(batch.lengths, batch.block_tables))
+                C, batch=(batch.lengths, batch.block_tables),
+                chunk_start=off)
         else:
-            dispatch = self._dispatch_span("chunk", 0, 0, true_len, C)
+            dispatch = self._dispatch_span("chunk", 0, 0, true_len, C,
+                                           chunk_start=off)
         with dispatch:
             with span("dstpu.engine.fetch"):
                 with jax.set_mesh(self.mesh):
@@ -1461,12 +1522,14 @@ class InferenceEngineV2:
         T_pad = -(-max(T, 1) // bucket) * bucket
         (calls, kernel), (rule_calls, rule_kernel_calls) = self._calls_of(
             ("prefill", T_pad))
+        index_keys, attended_keys = self._selected_read(0, T)
         with span("dstpu.engine.prefill", uid=req.uid, tokens=T,
                   padded=T_pad, expert_calls=calls,
                   expert_kernel_calls=kernel,
                   rule_rows=T_pad * self._state_layers,
                   rule_calls=rule_calls,
-                  rule_kernel_calls=rule_kernel_calls):
+                  rule_kernel_calls=rule_kernel_calls,
+                  index_keys=index_keys, attended_keys=attended_keys):
             with span("dstpu.engine.build"):
                 ids = np.zeros((1, T_pad), np.int32)
                 ids[0, :T] = req.prompt

@@ -14,6 +14,9 @@ from .phi4flash import (Phi4Flash, Phi4FlashConfig, PHI4FLASH_PRESETS,
                         PHI4FLASH_TINY, PHI4_MINI_FLASH)
 from .olmo_hybrid import (OlmoHybrid, OlmoHybridConfig, OLMO_HYBRID_PRESETS,
                           OLMO_HYBRID_TINY, OLMO_HYBRID_7B)
+from .deepseek_v32 import (DeepseekV32, DeepseekV32Config,
+                           DEEPSEEK_V32_PRESETS, DEEPSEEK_V32_TINY,
+                           DEEPSEEK_V32)
 from .falcon import Falcon, FalconConfig, FALCON_PRESETS
 from .opt import OPT, OPTConfig, OPT_PRESETS
 from .gptj import GPTJ, GPTJConfig, GPTJ_PRESETS

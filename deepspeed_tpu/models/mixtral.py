@@ -176,7 +176,7 @@ class Mixtral(Llama):
         ('tensor') sharding stays on the dense path — GSPMD handles it."""
         cfg = self.config
         B, T, D = x.shape
-        E, k = cfg.num_experts, cfg.moe_top_k
+        k = cfg.moe_top_k
         with jax.named_scope("dstpu.moe.route"):
             h = _rms_norm(x, layer["rms2"], cfg.rms_eps)
         grouped, hier, dcn_q, q8 = self._moe_knobs()
@@ -189,32 +189,11 @@ class Mixtral(Llama):
                 dcn_quantize=dcn_q, grouped_kernel=grouped,
                 int8_matmul=q8, renormalize=cfg.norm_topk_prob)
             return y.astype(x.dtype)
-        from ..moe.sharded_moe import (_grouped_swiglu_ffn,
-                                       resolve_grouped_params,
-                                       resolve_moe_int8, route_topk)
+        from ..moe.sharded_moe import moe_swiglu_routed, route_topk
         with jax.named_scope("dstpu.moe.route"):
             xs = h.reshape(-1, D)
-            S = xs.shape[0]
             weights, experts = route_topk(xs, layer["moe_gate"], k,
                                           cfg.norm_topk_prob)
-            flat_exp = experts.reshape(-1)
-            flat_w = weights.reshape(-1).astype(x.dtype)
-            x_rep = jnp.repeat(xs, k, axis=0)
-            order = jnp.argsort(flat_exp, stable=True)
-            xr = x_rep[order]
-            group_sizes = jnp.bincount(flat_exp, length=E).astype(jnp.int32)
-
-        w1 = layer["moe_w1"]
-        F = w1.scale.shape[-1] if hasattr(w1, "scale") else w1.shape[-1]
-        gp = resolve_grouped_params(grouped, S * k, E, D, F, xr.dtype)
-        if q8:
-            gp = dict(gp, int8=resolve_moe_int8(q8, S * k, E, D, F,
-                                                xr.dtype))
-        with jax.named_scope("dstpu.moe.experts"):
-            o = _grouped_swiglu_ffn(xr, w1, layer["moe_w3"],
-                                    layer["moe_w2"], group_sizes, gp)
-        with jax.named_scope("dstpu.moe.combine"):
-            unsorted = jnp.zeros_like(o).at[order].set(o)
-            y = jnp.sum((unsorted * flat_w[:, None]).reshape(S, k, D),
-                        axis=1)
+        y = moe_swiglu_routed(xs, weights, experts, layer["moe_w1"],
+                              layer["moe_w3"], layer["moe_w2"], grouped, q8)
         return y.astype(x.dtype).reshape(B, T, D)

@@ -13,7 +13,7 @@ state) is one more ``attn_fn`` here, not a fork of the four
 the only path that runs on a CPU at the default setting, and the
 reference the kernel-on/off tests compare against.
 
-A layer's cache is one of four kinds (``Geometry.kinds``):
+A layer's cache is one of five kinds (``Geometry.kinds``):
 
 * ``KV``: a pool of its own under the sequence's block table, the only
   kind the allocator's blocks pay for (cache keys ``k`` / ``v``);
@@ -36,8 +36,23 @@ A layer's cache is one of four kinds (``Geometry.kinds``):
   (``use_kernel``), so a model whose update is a kernel over the live
   slots alone writes the leaf in place and says so (``in_place``).
 
-``None`` is a layer with no cache. Everything but ``KV`` is indexed by
-the slot, which the chunk and prefill programs are therefore told.
+* ``LATENT``: a compressed cache under the sequence's block table, paid
+  for by the allocator's blocks as ``KV`` is (cache keys ``lat`` /
+  ``idx``): a token's row of ``lat`` is whatever the model attends
+  through (MLA: the normalised latent and the one rotary key every head
+  shares), its row of ``idx`` the key a learned indexer scores. The read
+  is **chosen per query by the model**: :meth:`_Step.latent` scores every
+  causal key with the model's ``index_fn``, finds each query's exact
+  k-th largest score, and attends the keys that reach it — through the
+  model's ``read_fn``, which turns a block of latent rows into scores
+  and values in whichever form the program wants (expanded to heads for
+  a prompt's chunk, absorbed into the query for a decode step). XLA
+  throughout: a flash-style pass over blocks of keys gathered through
+  the table, no kernel yet.
+
+``None`` is a layer with no cache. Everything but ``KV`` and ``LATENT`` is
+indexed by the slot, which the chunk and prefill programs are therefore
+told.
 """
 
 import math
@@ -56,11 +71,28 @@ from ..ops.pallas.paged_attention import (
     resolve_paged_decode)
 
 
-KV, RING, SHARED, STATE = "kv", "ring", "shared", "state"
+KV, RING, SHARED, STATE, LATENT = "kv", "ring", "shared", "state", "latent"
 # KV heads x rows x lanes of the chunk kernel's largest "auto" tile
 _CHUNK_TILE = 16 * 128 * 128
 # cache keys of each kind's leaves, one list entry a layer of that kind
-_KEYS = {KV: ("k", "v"), RING: ("ring_k", "ring_v"), STATE: ("conv", "ssm")}
+_KEYS = {KV: ("k", "v"), RING: ("ring_k", "ring_v"), STATE: ("conv", "ssm"),
+         LATENT: ("lat", "idx")}
+# keys a pass of a latent read takes at once: a chunk's scores are heads x
+# C x keys in float32, a decode step's heads x slots x keys
+_LATENT_KEYS = {"chunk": 512, "decode": 2048}
+
+
+def block_size(cache):
+    """Tokens a block of the pools under the block tables."""
+    if cache.get("k"):
+        return cache["k"][0].shape[2]
+    return cache["lat"][0].shape[1]
+
+
+def _paged_attention(geom):
+    """Whether any layer reads K/V through the paged kernels' entry points
+    (a model of latent layers alone asks them nothing)."""
+    return any(k in (KV, RING) or isinstance(k, tuple) for k in geom.kinds)
 
 
 def ring_blocks(window, chunk, block_size):
@@ -118,9 +150,9 @@ def geometry(model, **fields):
 def _decode_kernel(geom, B, MB, BS, dtype):
     # ALiBi families keep the kernel regardless of the mode switch (the
     # dense reference lacks the falcon bf16-quantized variant)
-    return geom.alibi or resolve_paged_decode(
+    return _paged_attention(geom) and (geom.alibi or resolve_paged_decode(
         geom.kernel, B, MB, BS, geom.n_kv_heads,
-        geom.n_head // geom.n_kv_heads, geom.d_head, dtype)
+        geom.n_head // geom.n_kv_heads, geom.d_head, dtype))
 
 
 def uses_decode_kernel(model, B, MB, BS, dtype):
@@ -147,6 +179,8 @@ def _chunk_kernel(geom, C, MB, BS):
     # ALiBi stays dense: the chunk kernel has no per-head bias input
     # (forced off BEFORE dispatch, so no search is paid for a tile the
     # model can never use)
+    if not _paged_attention(geom):
+        return False, None
     G = geom.n_head // geom.n_kv_heads
     use, block_c = resolve_paged_chunk(
         False if geom.alibi else geom.kernel, geom.block_c, C, MB, BS,
@@ -199,6 +233,90 @@ def _dense_attention(geom, q, gk, gv, q_pos, frontier, window):
     scores = jnp.where(mask[:, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(geom.dtype)
     return jnp.einsum("bhts,bshd->bthd", probs, rows(gv))
+
+
+def _kth_largest(x, k):
+    """Exact k-th largest of each row of x (..., S) float32, with no sort:
+    a binary search over the 32 bits of the order-preserving integer image
+    of a float, one compare and one count a bit. ``k`` >= 1 broadcasts
+    against the rows; a row has to hold k values (-inf counts)."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    # negative floats order backwards and below every positive one
+    u = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    k = jnp.broadcast_to(k, x.shape[:-1]).astype(jnp.int32)
+
+    def bit(i, prefix):
+        cand = prefix | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        n = jnp.sum(u >= cand[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(n >= k, cand, prefix)
+
+    kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros(x.shape[:-1], jnp.uint32))
+    kth = jnp.where(kth >> 31 == 1, kth & jnp.uint32((1 << 31) - 1), ~kth)
+    return jax.lax.bitcast_convert_type(kth, jnp.float32)
+
+
+def _latent_read(lat_pool, idx_pool, tables, q_pos, frontier, index_fn,
+                 read_fn, topk, out_shape, keys):
+    """The selected read of a latent layer, every query of the step.
+
+    lat_pool (NB, BS, W), idx_pool (NB, BS, Wi), new rows written;
+    tables (B, MB); q_pos (B, C) the queries' positions; frontier (B,) the
+    first position past each row's written range. ``index_fn(idx rows (B,
+    n, Wi)) -> (B, C, n)`` float32 index scores; ``read_fn(lat rows (B, n,
+    W)) -> (scores (B, H, C, n) float32, pv)`` with ``pv(p (B, H, C, n))
+    -> (B, C, H, dv)``. A query reads the ``min(topk, position + 1)``
+    causal keys of largest index score, exactly: those that reach the
+    k-th largest (ties with it included). Two passes over the table in
+    blocks of ``keys`` keys, as far as the longest row's frontier: the
+    index scores of every causal key, kept whole (B, C, MB x BS) float32
+    because they decide a set; then scores, a running softmax and the
+    value product over the selected keys. -> (B, C, H, dv) float32."""
+    B, MB = tables.shape
+    BS = lat_pool.shape[1]
+    C = q_pos.shape[1]
+    per = max(1, min(keys // BS, MB))           # table entries a pass
+    KB = per * BS
+    passes = -(-MB // per)
+    tables = jnp.pad(tables, ((0, 0), (0, passes * per - MB)))
+    n = jnp.clip((jnp.max(frontier) + KB - 1) // KB, 1, passes)
+    neg = -jnp.inf
+
+    def rows(pool, j):
+        entries = jax.lax.dynamic_slice(tables, (0, j * per), (B, per))
+        return pool[entries].reshape(B, KB, pool.shape[-1])
+
+    def index_pass(j, scores):
+        k_pos = j * KB + jnp.arange(KB)
+        sc = index_fn(rows(idx_pool, j))
+        ok = (k_pos <= q_pos[:, :, None]) \
+            & (k_pos < frontier[:, None, None])
+        return jax.lax.dynamic_update_slice(
+            scores, jnp.where(ok, sc, neg), (0, 0, j * KB))
+
+    scores = jax.lax.fori_loop(
+        0, n, index_pass, jnp.full((B, C, passes * KB), neg, jnp.float32))
+    # a query at position t < topk reads every causal key
+    thr = jnp.where(q_pos < topk, neg, _kth_largest(scores, topk))
+
+    def read_pass(j, carry):
+        m, l, o = carry
+        sc, pv = read_fn(rows(lat_pool, j))
+        mine = jax.lax.dynamic_slice(scores, (0, 0, j * KB), (B, C, KB))
+        sel = ((mine >= thr[..., None]) & (mine > neg))[:, None]
+        sc = jnp.where(sel, sc, -1e30)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+        a = jnp.exp(m - m_new)
+        p = jnp.where(sel, jnp.exp(sc - m_new[..., None]), 0.0)
+        return (m_new, l * a + jnp.sum(p, axis=-1),
+                o * a.transpose(0, 2, 1)[..., None] + pv(p))
+
+    H = out_shape[2]
+    m, l, o = jax.lax.fori_loop(
+        0, n, read_pass,
+        (jnp.full((B, H, C), -1e30, jnp.float32),
+         jnp.zeros((B, H, C), jnp.float32),
+         jnp.zeros(out_shape, jnp.float32)))
+    return o / jnp.maximum(l, 1e-30).transpose(0, 2, 1)[..., None]
 
 
 class _Step:
@@ -254,6 +372,32 @@ class _Step:
 
         return attn_fn
 
+    def latent(self, i):
+        """Layer i's ``attn_fn`` of the ``LATENT`` kind: ``(lat (B, C, W),
+        idx (B, C, Wi), index_fn, read_fn, topk, dv) -> (B, C, H, dv)``
+        float32. Writes the step's new rows into the layer's two
+        pools (pads and inactive slots aim at scratch block 0), then
+        :func:`_latent_read` through the block table."""
+        j = self.index[i]
+        blocks, offsets, _ = self.dest[KV]
+        tables = self.tables[KV]
+        tables = tables[None] if tables.ndim == 1 else tables
+        keys = _LATENT_KEYS["decode" if self.q_pos.shape[1] == 1
+                            else "chunk"]
+
+        def attn_fn(lat, idx, index_fn, read_fn, topk, dv):
+            for key, new in zip(_KEYS[LATENT], (lat, idx)):
+                pool = self.cache[key][j]
+                self.cache[key][j] = pool.at[blocks, offsets].set(
+                    new.reshape(-1, new.shape[-1]).astype(pool.dtype))
+            B, C = self.q_pos.shape
+            return _latent_read(
+                self.cache["lat"][j], self.cache["idx"][j], tables,
+                self.q_pos, self.frontier, index_fn, read_fn, topk,
+                (B, C, self.geom.n_head, dv), keys)
+
+        return attn_fn
+
     def state(self, i):
         """Layer i's (conv, ssm) for this program's rows."""
         j = self.index[i]
@@ -288,7 +432,7 @@ def chunk_step(geom, cache, token_blocks, token_offsets, start, true_len,
     causal prefix; recurrent state continues the slot's, from zero at
     ``start = 0``, and stops at token ``true_len - 1``."""
     C, MB = token_blocks.shape[0], table.shape[0]
-    BS = cache["k"][0].shape[2]
+    BS = block_size(cache)
     use_kernel, block_c = _chunk_kernel(geom, C, MB, BS)
     tables, dest = {KV: table}, {KV: (token_blocks, token_offsets)}
     if RING in geom.kinds:
@@ -324,6 +468,8 @@ def chunk_step(geom, cache, token_blocks, token_offsets, start, true_len,
     # which of the C tokens are real: a recurrent layer steps over the pads
     step.valid = (jnp.arange(C) < true_len)[None]
     step.n_valid = jnp.reshape(true_len, (1,))
+    step.q_pos = (start + jnp.arange(C))[None]
+    step.frontier = jnp.reshape(start + true_len, (1,))
     return step
 
 
@@ -334,7 +480,7 @@ def batch_step(geom, cache, lengths, block_tables, C):
     b: slot state is read and written in place, and only where the slot
     is live."""
     B, MB = block_tables.shape
-    BS = cache["k"][0].shape[2]
+    BS = block_size(cache)
     active = block_tables[:, 0] != 0
     linpos = lengths[:, None] + jnp.arange(C)[None, :]           # (B, C)
     entry = jnp.minimum(linpos // BS, MB - 1)
@@ -351,8 +497,9 @@ def batch_step(geom, cache, lengths, block_tables, C):
         use_kernel = _decode_kernel(geom, B, MB, BS, geom.dtype)
         # the decode kernel's grid: this step's runs of live blocks a
         # slot, one list per window size, shared by every layer that has it
-        per_step = decode_entries_per_step(geom.n_kv_heads, BS, geom.d_head,
-                                           cache["k"][0].dtype, MB)
+        per_step = decode_entries_per_step(
+            geom.n_kv_heads, BS, geom.d_head, cache["k"][0].dtype, MB) \
+            if use_kernel else 1
         work = {w: decode_work_list(lengths, MB, BS, w, active=active,
                                     per_step=per_step)
                 for w in set(geom.windows)} if use_kernel else {}
@@ -395,4 +542,5 @@ def batch_step(geom, cache, lengths, block_tables, C):
     step.active = active
     step.valid = jnp.ones((B, C), bool)
     step.n_valid = jnp.full((B,), C, jnp.int32)
+    step.q_pos, step.frontier = linpos, lengths + C
     return step

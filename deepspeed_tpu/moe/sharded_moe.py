@@ -293,23 +293,111 @@ class TopKGate:
             self.top2_2nd_expert_sampling and train and rng is not None)
 
 
-def route_topk(x, gate_w, k, renormalize=True):
+def route_topk(x, gate_w, k, renormalize=True, *, scoring="softmax",
+               bias=None, n_group=1, topk_group=1, scale=1.0):
     """The serving MoE models' router, shared by the one-device ``_mlp``
     and the expert-parallel path: float32 logits (the product itself at
     HIGHEST precision — on TPU a default float32 matmul multiplies in
     bf16, enough to flip a near-tie between the k-th and (k+1)-th
-    expert), float32 softmax over ALL experts, top-k. ``renormalize``
-    divides the k probabilities by their sum (mixtral, HF
+    expert), float32 scores over ALL experts, top-k. ``renormalize``
+    divides the k scores by their sum (mixtral, HF
     ``norm_topk_prob=True``); False uses them as they are (OLMoE: they
     sum to ~k/E at random init, not to 1).
+
+    ``scoring`` "softmax" (mixtral, OLMoE) or "sigmoid" (DeepSeek-V3's
+    ``noaux_tc``). ``bias`` (E,): a correction added to the scores for
+    CHOOSING and left out of the weights. ``n_group`` > 1: group-limited
+    choice — the experts lie in ``n_group`` equal groups, a group's score
+    is the sum of its two largest (biased) scores, and only the
+    ``topk_group`` best groups' experts can be chosen. ``scale``
+    multiplies the weights last. The defaults are the softmax router as
+    it was, operation for operation.
     x (S, M), gate_w (M, E) -> (weights (S, k) f32, experts (S, k) i32)."""
     logits = jnp.matmul(x.astype(jnp.float32), gate_w.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = lax.top_k(probs, k)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"scoring is 'softmax' or 'sigmoid', got "
+                         f"{scoring!r}")
+    if bias is None and n_group == 1:
+        weights, experts = lax.top_k(probs, k)
+    else:
+        choose = probs if bias is None \
+            else probs + bias.astype(jnp.float32)
+        if n_group > 1:
+            S, E = choose.shape
+            group = jnp.sum(lax.top_k(choose.reshape(S, n_group, -1), 2)[0],
+                            axis=-1)
+            kept = lax.top_k(group, topk_group)[1]               # (S, tg)
+            keep = jnp.any(kept[:, :, None] == jnp.arange(n_group),
+                           axis=1)                                # (S, G)
+            choose = jnp.where(jnp.repeat(keep, E // n_group, axis=1),
+                               choose, -jnp.inf)
+        experts = lax.top_k(choose, k)[1]
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
     if renormalize:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, experts.astype(jnp.int32)
+
+
+def moe_swiglu_routed(xs, weights, experts, w1, w3, w2, grouped="auto",
+                      int8=False, held=None, out_dtype=None):
+    """The one-device dropless expert layer once the router has spoken:
+    the routed rows sorted by expert, the three grouped products
+    (``lax.ragged_dot`` or the Pallas grouped kernel, as
+    :func:`resolve_grouped_params` answers), unsorted and summed by the
+    routing weights, under the ``dstpu.moe.*`` scopes. xs (S, D),
+    weights / experts (S, k) -> (S, D) ``out_dtype`` (xs's).
+
+    ``held`` = (offset, count): THE SHARE of an expert-parallel
+    deployment this device holds — w1 / w3 / w2 are experts ``offset ..
+    offset + count - 1`` of those the router chose among. A routed row
+    whose expert lies elsewhere sorts behind every held expert's rows and
+    past the groups' sum: the grouped products fetch no weight for it and
+    write zeros, and it adds nothing. What the absent experts would have
+    added is left out: the partial sum another device's exchange would
+    complete."""
+    S, D = xs.shape
+    k = experts.shape[1]
+    E = w1.scale.shape[0] if hasattr(w1, "scale") else w1.shape[0]
+    out_dtype = xs.dtype if out_dtype is None else out_dtype
+    with jax.named_scope("dstpu.moe.route"):
+        flat_exp = experts.reshape(-1)
+        flat_w = weights.reshape(-1).astype(out_dtype)
+        if held is not None:
+            offset, count = held
+            local = flat_exp - offset
+            mine = (local >= 0) & (local < count)
+            # absent experts' rows: one group behind the held ones, which
+            # the grouped products are not told of
+            flat_exp = jnp.where(mine, local, count)
+            flat_w = jnp.where(mine, flat_w, 0)
+        x_rep = jnp.repeat(xs, k, axis=0)
+        order = jnp.argsort(flat_exp, stable=True)
+        xr = x_rep[order]
+        group_sizes = jnp.bincount(
+            flat_exp, length=E + (held is not None))[:E].astype(jnp.int32)
+
+    F = w1.scale.shape[-1] if hasattr(w1, "scale") else w1.shape[-1]
+    gp = resolve_grouped_params(grouped, S * k, E, D, F, xr.dtype)
+    if int8:
+        gp = dict(gp, int8=resolve_moe_int8(int8, S * k, E, D, F, xr.dtype))
+    with jax.named_scope("dstpu.moe.experts"):
+        o = _grouped_swiglu_ffn(xr, w1, w3, w2, group_sizes, gp)
+    with jax.named_scope("dstpu.moe.combine"):
+        unsorted = jnp.zeros_like(o).at[order].set(o)
+        if held is not None:
+            # a row past the groups is zeros by the products' contract;
+            # nothing of it may reach the sum whatever a backend left there
+            unsorted = jnp.where(mine[:, None], unsorted, 0)
+        y = jnp.sum((unsorted.astype(out_dtype)
+                     * flat_w[:, None]).reshape(S, k, D), axis=1)
+    return y
 
 
 def topk_routing(logits, k=1):

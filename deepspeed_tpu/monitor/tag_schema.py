@@ -241,19 +241,21 @@ SPAN_SCHEMA = {
     "dstpu.engine.prefill": {
         "stats": ("uid", "tokens", "padded", "expert_calls",
                   "expert_kernel_calls", "rule_rows", "rule_calls",
-                  "rule_kernel_calls"),
+                  "rule_kernel_calls", "index_keys", "attended_keys"),
         "meaning": "bucketed prefill of one request: arrays, program "
                    "call, blocking read of its token; expert_calls / "
                    "expert_kernel_calls / rule_rows / rule_calls / "
-                   "rule_kernel_calls as on dstpu.engine.dispatch"},
+                   "rule_kernel_calls / index_keys / attended_keys as on "
+                   "dstpu.engine.dispatch"},
     "dstpu.engine.dispatch": {
         "stats": ("kind", "active", "slots", "steps", "chunk_tokens",
-                  "grid_steps", "table_entries", "kernel_steps",
+                  "chunk_start", "grid_steps", "table_entries",
+                  "kernel_steps",
                   "write_rows",
                   "write_rows_offered", "expert_calls",
                   "expert_kernel_calls", "chained", "late_steps",
                   "state_updates", "rule_rows", "rule_calls",
-                  "rule_kernel_calls"),
+                  "rule_kernel_calls", "index_keys", "attended_keys"),
         "meaning": "one decode-bearing or chunk program call (kind "
                    "decode | fused | chunk | spec | offload) from the "
                    "assembled batch to the last posted token — of kind "
@@ -298,7 +300,19 @@ SPAN_SCHEMA = {
                    "dispatch that traces it; "
                    "rule_kernel_calls = those of rule_calls that are a "
                    "Pallas kernel (ops/pallas/gated_delta_rule.py), the "
-                   "rest the XLA form"},
+                   "rest the XLA form; "
+                   "index_keys = the causal keys a learned indexer scores "
+                   "for the span's real query tokens (a chunk's "
+                   "chunk_tokens from position chunk_start, the "
+                   "sequence's tokens before the chunk, 0 with no chunk; "
+                   "a live slot's token a decode step), "
+                   "position + 1 a query, x the layers that cache a "
+                   "latent read through a per-query selection "
+                   "(models/paged.py, LATENT), and attended_keys = the "
+                   "keys those queries attend after the selection, "
+                   "min(position + 1, index_topk) a query, x those layers: "
+                   "the model's work whatever implements the read (both "
+                   "0 on a model without such a layer)"},
     "dstpu.engine.build": {
         "stats": (),
         "meaning": "leaf: host work before a program call (decode "
@@ -364,6 +378,19 @@ SCOPE_SCHEMA = {
     "dstpu.attn.full":
         "a full-attention layer of olmo_hybrid: the K/V write into its "
         "pool under the block table and the paged read",
+    "dstpu.attn.latent":
+        "a latent (MLA) layer's attention outside its weight products: "
+        "rotary, the latent's norm, the write of the new rows into the "
+        "latent pool, the read of the selected keys through the block "
+        "table, scores, the running softmax and the value product, in "
+        "the expanded form of a prompt's chunk (a block of latent rows "
+        "to every head's key and value first) and the absorbed form of a "
+        "decode step (the two per-head products round the read)",
+    "dstpu.attn.index":
+        "a latent layer's learned selection (DeepSeek sparse attention): "
+        "rotary and LayerNorm of the index queries and key, the index "
+        "scores of every causal key and, where the trace shows them "
+        "under it, the search for each query's k-th largest",
     "dstpu.attn.diff":
         "differential attention outside the paged read: q/k/v "
         "projection, the query's padding to the head pair's width, "

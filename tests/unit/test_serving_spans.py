@@ -30,7 +30,8 @@ from deepspeed_tpu.utils import groups
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, os.path.join(REPO, "perfbench"))
-from pbench import (common as pb_common, gdn as pb_gdn,  # noqa: E402
+from pbench import (common as pb_common, dsa as pb_dsa,  # noqa: E402
+                    gdn as pb_gdn,
                     moe as pb_moe, ssm as pb_ssm, trace as pb_trace,
                     weights as pb_weights)
 
@@ -675,6 +676,92 @@ def test_gdn_readers_count_nested_scopes_and_the_rules_floors():
                     "gdn_state_roofline"]
 
 
+DSA_READERS = ("mla_attn_share", "dsa_index_share", "mla_latent_roofline",
+               "dsa_index_roofline")
+
+
+@pytest.mark.parametrize("metric", DSA_READERS)
+def test_dsa_reader_reads_nothing_without_its_scope(metric, bucketed):
+    """The readers of the latent layers' selected read (ISSUE 43) give None,
+    and say nothing, where the traced program opened none of their scopes:
+    no trace, a dense model's serving trace (whose dispatch spans carry
+    ``index_keys`` = 0), the recorded training and MoE traces (a program
+    before PR 43: no such counter at all)."""
+    said = []
+    view = types.SimpleNamespace(
+        say=lambda line, **fields: said.append((line, fields)),
+        sizes=_cell_sizes("deepseek-v3.2-exp"),
+        peaks=pb_common.peaks_for("TPU v5 lite"))
+    reader = pb_common.load_module("layer_metrics", metric)
+    fixtures = os.path.join(REPO, "perfbench", "fixtures")
+    for other in (None, bucketed[0],
+                  pb_trace.Trace(os.path.join(fixtures, "tiny4.xplane.pb")),
+                  pb_trace.Trace(os.path.join(fixtures, "moe1.xplane.pb"))):
+        view.trace = other
+        assert reader.read(view) is None and not said
+
+
+def test_dsa_readers_count_the_innermost_scope_and_the_models_floors():
+    """Own time goes to the INNERMOST of ``pbench.dsa.SCOPES`` an
+    operation's ``tf_op`` names (the index scores are traced from inside
+    the read's loop); the floors are the model's own count for what the
+    window's spans say was asked: a prompt's pairs at the expanded form's
+    count, a decode step's at the absorbed form's and its cache rows."""
+    ev = types.SimpleNamespace
+    ops = {"a": "jit(fused)/dstpu.attn.latent/while/body/dot_general",
+           "b": "jit(fused)/dstpu.attn.latent/while/body/dstpu.attn.index/dot",
+           "c": "jit(fused)/dstpu.attn.index/cos",
+           "d": "jit(fused)/dstpu.mm.qkv/dot_general",
+           "e": "jit(fused)/dstpu.moe.experts/x"}
+    # a chunk of 1,000 tokens from position 3,000 beside 8 decode steps of
+    # 10 slots at contexts of ~5,000, five latent layers
+    chunk_idx, chunk_att = 5 * 1000 * 3500.5, 5 * 1000 * 2048
+    dec_idx, dec_att = 5 * 80 * 5000, 5 * 80 * 2048
+    spans = {"dstpu.engine.dispatch": [
+        ev(start=1.0, end=2.0, dur=1.0, stats={
+            "kind": "fused", "chunk_tokens": 1000, "chunk_start": 3000,
+            "steps": 8, "active": 10, "index_keys": chunk_idx + dec_idx,
+            "attended_keys": chunk_att + dec_att}),
+        # half inside the window, all decode
+        ev(start=9.5, end=10.5, dur=1.0, stats={
+            "kind": "decode", "chunk_tokens": 0, "chunk_start": 0,
+            "steps": 8, "active": 16,
+            "index_keys": 8.0e6, "attended_keys": 1.0e6})],
+        "dstpu.engine.prefill": []}
+    trace = ev(path="p", devices=[0], dsa_seconds=None, busy_s=lambda: 2.0,
+               t0=0.0, t1=10.0, host_spans=lambda name: spans[name],
+               in_window=lambda d: [ev(name=n, self_s=0.25) for n in ops])
+    said = []
+    sizes = _cell_sizes("deepseek-v3.2-exp")
+    peaks = pb_common.peaks_for("TPU v5 lite")
+    view = ev(trace=trace, sizes=sizes, peaks=peaks,
+              say=lambda line, **f: said.append(line))
+    real = pb_moe.op_scopes
+    pb_moe.op_scopes = lambda path, prefix: ops
+    try:
+        got = [pb_common.load_module("layer_metrics", m).read(view)
+               for m in DSA_READERS]
+    finally:
+        pb_moe.op_scopes = real
+    assert got[:2] == [12.5, 25.0]
+    # the decode part is the span's total less the chunk's own pairs, by
+    # keys: a decode token at context 5,000 brings far more than its share
+    # of the dispatch's tokens
+    att, att_d = chunk_att + dec_att + 0.5e6, dec_att + 0.5e6
+    ops_read = 2 * 128 * ((att - att_d) * (192 + 128) + att_d * (576 + 512))
+    floor = max(ops_read / peaks["bf16_flops_per_s"],
+                att_d * 1152 / peaks["hbm_bytes_per_s"])
+    assert got[2] == pytest.approx(100 * floor / 0.25)
+    # index keys lie in the pool as float32: 512 bytes a decode pair
+    idx, idx_d = chunk_idx + dec_idx + 4.0e6, dec_idx + 4.0e6
+    floor = max(idx * 2 * 64 * 128 / peaks["bf16_flops_per_s"],
+                idx_d * 512 / peaks["hbm_bytes_per_s"])
+    assert got[3] == pytest.approx(100 * floor / 0.5)
+    assert all(0 < x < 100 for x in got)
+    assert said == ["dsa_device_seconds", "mla_latent_roofline",
+                    "dsa_index_roofline"]
+
+
 @pytest.mark.parametrize("kind", ["bucketed", "splitfuse"])
 def test_cache_bytes_per_live_token_reader(kind, request):
     """``cache_bytes_per_live_token`` is the step spans' two counters,
@@ -1044,7 +1131,8 @@ def test_scope_schema_lint_both_directions():
     assert all(n.startswith("dstpu.") and m for n, m in SCOPE_SCHEMA.items())
     # the benchmark's readers look for the same names
     assert {pb_moe.SCOPE_EXPERTS, *pb_moe.SCOPES_ROUTE, *pb_ssm.SCOPES,
-            *pb_gdn.SCOPES, *pb_weights.SCOPES} == set(SCOPE_SCHEMA)
+            *pb_gdn.SCOPES, *pb_dsa.SCOPES, *pb_weights.SCOPES} \
+        == set(SCOPE_SCHEMA)
 
 
 def test_span_schema_lint_both_directions():

@@ -800,3 +800,91 @@ def test_olmo_hybrid_programs_fit_the_chip(v5e, monkeypatch, program):
     assert abs(_nbytes(whole_cache) - 4.46e9) < 0.01e9
     whole = temp + _nbytes(whole_params) + 1.05 * _nbytes(whole_cache)
     assert whole <= (V5E_GB - 1.5) * 1e9, (temp, whole)
+
+
+# serve-dsv32-longctx-sat: 16 slots, 4,096 blocks of 64 tokens, 264 table
+# entries a sequence (16,896 served positions), 1,024-token chunks
+DS_SLOTS, DS_NB, DS_MB, DS_C = 16, 4096, 264, 1024
+
+
+def _deepseek_programs(model):
+    """The cell's three programs as the engine composes them: a chunk, 8
+    decode steps over every slot, and both in one (fused)."""
+    def chunk(params, cache, ids, tb, to, start, n, table):
+        logits, cache = model.apply_paged_chunk(
+            params, ids, cache, tb, to, start, n, table)
+        return jnp.argmax(logits, axis=-1), cache
+
+    def decode(params, cache, tokens, lengths, tables):
+        toks = []
+        for _ in range(8):
+            logits, cache = model.apply_paged_decode(
+                params, tokens, lengths, cache, tables)
+            tokens = jnp.argmax(logits, axis=-1).astype(i32)
+            lengths = lengths + 1
+            toks.append(tokens)
+        return jnp.stack(toks), cache
+
+    def fused(params, cache, ids, tb, to, start, n, table, tokens, lengths,
+              tables):
+        c_tok, cache = chunk(params, cache, ids, tb, to, start, n, table)
+        toks, cache = decode(params, cache, tokens, lengths, tables)
+        return c_tok, toks, cache
+
+    c = [((1, DS_C), i32), ((DS_C,), i32), ((DS_C,), i32), ((), i32),
+         ((), i32), ((DS_MB,), i32)]
+    d = [((DS_SLOTS,), i32), ((DS_SLOTS,), i32), ((DS_SLOTS, DS_MB), i32)]
+    return {"chunk": (chunk, c), "decode_x8": (decode, d),
+            "fused": (fused, c + d)}
+
+
+@pytest.mark.parametrize("program", ["chunk", "decode_x8", "fused"])
+def test_deepseek_share_programs_fit_the_chip(v5e, monkeypatch, program):
+    """The dense layer and one expert layer of the published widths (16 of
+    256 experts held, an eighth of the vocabulary), the cell's 16 slots,
+    4,096-block latent pools and 1,024-token chunk: the program compiles for
+    a v5e with the forward grouped kernel in its expert layer, keeps the
+    latent pools in place (a pool whose rows were 576 wide was copied whole
+    into and out of every program: its rows are 640), and its temporaries
+    beside the whole cell's arguments (five layers' weights and pools: a
+    further layer is arguments, not temporaries) stay inside the chip. The
+    five-layer chunk and fused programs themselves compiled to 12.29 / 12.58 GB
+    (sandbox compile, PR 43: PERF.md section 4)."""
+    import dataclasses
+    from deepspeed_tpu.models.deepseek_v32 import DEEPSEEK_V32, DeepseekV32
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = dataclasses.replace(
+        DEEPSEEK_V32, n_layer=5, first_k_dense=1, experts_held=16,
+        vocab_size=16160, max_seq_len=DS_MB * BS)
+    two = dataclasses.replace(cell, n_layer=2)
+
+    def trees(cfg):
+        model = DeepseekV32(cfg)
+        model._paged_kernel, model._paged_block_c = "auto", "auto"
+        params = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+            jax.eval_shape(model.init, jax.random.key(0)))
+        cache = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+            jax.eval_shape(lambda: model.init_paged_cache(DS_NB, BS,
+                                                          dtype=bf16)))
+        return model, params, cache
+
+    model, params, cache = trees(two)
+    fn, shapes = _deepseek_programs(model)[program]
+    rest = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *rest).compile()
+    text = compiled.as_text()
+    # one expert layer a pass through the forward grouped kernel
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == {"chunk": 1, "decode_x8": 8, "fused": 9}[program]
+    # no whole-pool copy of a latent pool
+    assert not re.search(
+        r"bf16\[%d,%d,640\]\{[^}]*\} copy\(" % (DS_NB, BS), text)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    _, whole_params, whole_cache = trees(cell)
+    assert abs(_nbytes(whole_params) - 9.286e9) < 0.01e9
+    assert abs(_nbytes(whole_cache) - 2.349e9) < 0.01e9
+    whole = temp + _nbytes(whole_params) + _nbytes(whole_cache)
+    assert whole <= (V5E_GB - 1.5) * 1e9, (temp, whole)
