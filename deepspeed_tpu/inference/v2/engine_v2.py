@@ -457,6 +457,7 @@ class InferenceEngineV2:
         # grouped kernel), noted when the program is traced
         self._expert_calls = {}
         self._rule_calls = {}
+        self._latent_reads = {}
         self._splitfuse_jit = None
         self._chunk_jit = None        # chunk-only (no decoders running)
         self._cow_jit = None          # prefix-cache partial-tail copy
@@ -710,20 +711,25 @@ class InferenceEngineV2:
         them took a Pallas grouped kernel (moe/sharded_moe.py
         ``counting_expert_calls``; a dense model makes none), and the same
         of the gated delta rule's calls (linear layers x calls;
-        ops/gated_delta_rule.py ``counting_rule_calls``), under its name
+        ops/gated_delta_rule.py ``counting_rule_calls``) and of the
+        selected reads of a latent cache (latent layers x steps;
+        models/paged.py ``counting_latent_reads``), under its name
         or ``key(*args)``. Known once the program is traced: the dispatch
         that traces it still reads 0 of 0."""
+        from ...models.paged import counting_latent_reads
         from ...moe.sharded_moe import counting_expert_calls
         from ...ops.gated_delta_rule import counting_rule_calls
 
         @functools.wraps(body)
         def program(*args):
             with counting_expert_calls() as experts, \
-                    counting_rule_calls() as rules:
+                    counting_rule_calls() as rules, \
+                    counting_latent_reads() as reads:
                 out = body(*args)
             name = body.__name__ if key is None else key(*args)
             self._expert_calls[name] = tuple(experts)
             self._rule_calls[name] = tuple(rules)
+            self._latent_reads[name] = tuple(reads)
             return out
         return program
 
@@ -1320,8 +1326,7 @@ class InferenceEngineV2:
         active = int(np.sum(active))
         state_updates = active * steps * self._state_layers
         rule_rows = chunk_rows * self._state_layers
-        (expert_calls, expert_kernel_calls), (rule_calls, rule_kernel_calls) \
-            = self._calls_of(*_PROGRAMS_OF_KIND[kind])
+        calls = self._calls_of(*_PROGRAMS_OF_KIND[kind])
         if self.telemetry is not None:
             if steps:
                 self.telemetry.on_decode_batch(active, slots, grid_steps,
@@ -1335,13 +1340,10 @@ class InferenceEngineV2:
                     table_entries=table_entries,
                     kernel_steps=kernel_steps, write_rows=write_rows,
                     write_rows_offered=write_rows_offered,
-                    expert_calls=expert_calls,
-                    expert_kernel_calls=expert_kernel_calls,
                     chained=chained, late_steps=late_steps,
                     state_updates=state_updates, rule_rows=rule_rows,
-                    rule_calls=rule_calls,
-                    rule_kernel_calls=rule_kernel_calls,
-                    index_keys=index_keys, attended_keys=attended_keys)
+                    index_keys=index_keys, attended_keys=attended_keys,
+                    **calls)
 
     def _selected_read(self, start, tokens):
         """(index_keys, attended_keys) of ``tokens`` consecutive real
@@ -1358,20 +1360,29 @@ class InferenceEngineV2:
                 int(np.minimum(ctx, topk).sum()) * self._latent_layers)
 
     def _calls_of(self, *programs):
-        """((expert layer calls, those through a Pallas grouped kernel),
-        (calls of the gated delta rule, those that are a Pallas kernel))
-        of one call of each of ``programs``, as their traces noted them,
-        fed to the telemetry's ``moe_kernel_share`` and
-        ``rule_kernel_share``."""
+        """What one call of each of ``programs`` makes, as their traces
+        noted it, under the names the dispatch and prefill spans say it by:
+        ``expert_calls`` (expert layer calls) / ``expert_kernel_calls``
+        (those through a Pallas grouped kernel), ``rule_calls`` (calls of
+        the gated delta rule) / ``rule_kernel_calls``, ``latent_read_calls``
+        (selected reads of a latent cache) / ``latent_read_kernel_calls``;
+        fed to the telemetry's ``moe_kernel_share``, ``rule_kernel_share``
+        and ``latent_kernel_share``."""
         def total(noted):
             counts = [noted.get(p, (0, 0)) for p in programs]
             return sum(c for c, _ in counts), sum(k for _, k in counts)
 
-        experts, rules = total(self._expert_calls), total(self._rule_calls)
+        experts, rules, reads = (total(self._expert_calls),
+                                 total(self._rule_calls),
+                                 total(self._latent_reads))
         if self.telemetry is not None:
             self.telemetry.on_expert_calls(*experts)
             self.telemetry.on_rule_calls(*rules)
-        return experts, rules
+            self.telemetry.on_latent_reads(*reads)
+        return dict(zip(
+            ("expert_calls", "expert_kernel_calls", "rule_calls",
+             "rule_kernel_calls", "latent_read_calls",
+             "latent_read_kernel_calls"), experts + rules + reads))
 
     def _step_splitfuse_chunk(self):
         """Run one fused dispatch: the next chunk of the oldest
@@ -1520,16 +1531,12 @@ class InferenceEngineV2:
         bucket = self.config.prompt_bucket
         T = len(req.prompt)
         T_pad = -(-max(T, 1) // bucket) * bucket
-        (calls, kernel), (rule_calls, rule_kernel_calls) = self._calls_of(
-            ("prefill", T_pad))
+        calls = self._calls_of(("prefill", T_pad))
         index_keys, attended_keys = self._selected_read(0, T)
         with span("dstpu.engine.prefill", uid=req.uid, tokens=T,
-                  padded=T_pad, expert_calls=calls,
-                  expert_kernel_calls=kernel,
-                  rule_rows=T_pad * self._state_layers,
-                  rule_calls=rule_calls,
-                  rule_kernel_calls=rule_kernel_calls,
-                  index_keys=index_keys, attended_keys=attended_keys):
+                  padded=T_pad, rule_rows=T_pad * self._state_layers,
+                  index_keys=index_keys, attended_keys=attended_keys,
+                  **calls):
             with span("dstpu.engine.build"):
                 ids = np.zeros((1, T_pad), np.int32)
                 ids[0, :T] = req.prompt

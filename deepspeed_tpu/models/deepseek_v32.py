@@ -29,7 +29,12 @@ rows to every head's key and value (``wk_b``, ``wv_b``) and attends with
 ``wv_b`` into the output and attends the 576-wide rows as they lie in the
 cache. Both read exactly the selected keys: ``paged._latent_read`` keeps
 every causal key's float32 index score, finds each query's k-th largest
-and masks the rest.
+and masks the rest. The expanded form's read is written twice: ``read_fn``
+(XLA; ``apply``, and the serving programs off a TPU) and the Pallas kernel
+of ``ops/pallas/latent_attention.py``, which a paged step runs instead
+where its program runs kernels, from what ``read_fn`` is made from
+(``expand``: the rounded queries, ``wk_b``, ``wv_b``); the rounding points
+are the same.
 
 **Numerics, forced by two discrete choices a layer.** The selection keeps
 2,048 keys of thousands and the gate 8 experts of 256: a rounding of 2^-9
@@ -231,7 +236,7 @@ class _DenseStep:
         self.frontier = jnp.full((B,), T, jnp.int32)
 
     def latent(self, i):
-        def attn_fn(lat, idx, index_fn, read_fn, topk, dv):
+        def attn_fn(lat, idx, index_fn, read_fn, topk, dv, expand=None):
             B, T = self.q_pos.shape
             return paged._latent_read(
                 lat, idx, jnp.arange(B, dtype=jnp.int32)[:, None],
@@ -404,7 +409,7 @@ class DeepseekV32:
                         rows[..., :R],
                         preferred_element_type=jnp.float32)[:, None]
 
-            width = R
+            width, expand = R, None
         else:
             with jax.named_scope("dstpu.attn.latent"):
                 qc = jnp.concatenate([q_nope, q_pe], axis=-1).astype(dt)
@@ -426,10 +431,13 @@ class DeepseekV32:
                         "bhcs,bshd->bchd", pr.astype(dt), v,
                         preferred_element_type=jnp.float32)
 
-            width = dv
+            # what ``read_fn`` is made from, for a step that reads through
+            # the kernel of ``ops/pallas/latent_attention.py`` instead
+            width, expand = dv, (qc, p["wk_b"], p["wv_b"])
 
         with jax.named_scope("dstpu.attn.latent"):
-            o = attn_fn(lat, ki, index_fn, read_fn, cfg.index_topk, width)
+            o = attn_fn(lat, ki, index_fn, read_fn, cfg.index_topk, width,
+                        expand)
             if C == 1:
                 o = jnp.einsum("bhr,hrd->hbd",
                                _pieces(o[:, 0], dt).reshape(-1, H, R),

@@ -46,15 +46,20 @@ A layer's cache is one of five kinds (``Geometry.kinds``):
   k-th largest score, and attends the keys that reach it — through the
   model's ``read_fn``, which turns a block of latent rows into scores
   and values in whichever form the program wants (expanded to heads for
-  a prompt's chunk, absorbed into the query for a decode step). XLA
-  throughout: a flash-style pass over blocks of keys gathered through
-  the table, no kernel yet.
+  a prompt's chunk, absorbed into the query for a decode step): a
+  flash-style pass over blocks of keys gathered through the table. The
+  selection is XLA throughout; the read after it of a step of more than
+  one query a row is the Pallas kernel of ``ops/pallas/latent_attention.py``
+  where the program runs kernels (:func:`_latent_kernel`), given what the
+  expanded form is made from (``expand``), and ``read_fn`` everywhere else.
 
 ``None`` is a layer with no cache. Everything but ``KV`` and ``LATENT`` is
 indexed by the slot, which the chunk and prefill programs are therefore
 told.
 """
 
+import contextlib
+import contextvars
 import math
 from collections import Counter
 from dataclasses import dataclass
@@ -63,6 +68,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas.latent_attention import latent_chunk_attention
 from ..ops.pallas.paged_attention import (
     PAGED_CHUNK_BLOCK_C, alibi_slopes, decode_entries_per_step,
     decode_work_list, kv_write_row_list, paged_chunk_attention,
@@ -202,6 +208,40 @@ def _chunk_kernel(geom, C, MB, BS):
     return use, block_c
 
 
+def _latent_kernel(geom, C):
+    """Whether a ``LATENT`` layer's read of a step of C queries a row is
+    the Pallas kernel: never a decode step's (one query a row is the
+    absorbed form, which has none), else the engine's ``paged_kernel`` by
+    the paged kernels' rule: "auto" is Mosaic on a TPU and the XLA form off
+    it, True the kernel anywhere (the interpreter off a TPU), False the
+    XLA form."""
+    if C == 1:
+        return False
+    if geom.kernel == "auto":
+        return jax.default_backend() == "tpu"
+    return bool(geom.kernel)
+
+
+# the tally a ``counting_latent_reads`` block is filling, if any
+_LATENT_READS = contextvars.ContextVar("dstpu_latent_reads", default=None)
+
+
+@contextlib.contextmanager
+def counting_latent_reads():
+    """Yields ``[reads, kernel_reads]``: the selected reads (latent layers x
+    steps, a chunk's or a decode step's) traced inside the block, and those
+    of them that are the Pallas kernel. Trace-time Python, as
+    ``ops/gated_delta_rule.py:counting_rule_calls``: a serving engine puts
+    it round a program's traced body, for its dispatch span
+    (latent_read_calls / latent_read_kernel_calls)."""
+    counts = [0, 0]
+    token = _LATENT_READS.set(counts)
+    try:
+        yield counts
+    finally:
+        _LATENT_READS.reset(token)
+
+
 def _dense_attention(geom, q, gk, gv, q_pos, frontier, window):
     """The dense fallback: masked attention over each slot's whole key
     range, gathered through its table.
@@ -256,7 +296,7 @@ def _kth_largest(x, k):
 
 
 def _latent_read(lat_pool, idx_pool, tables, q_pos, frontier, index_fn,
-                 read_fn, topk, out_shape, keys):
+                 read_fn, topk, out_shape, keys, expand=None):
     """The selected read of a latent layer, every query of the step.
 
     lat_pool (NB, BS, W), idx_pool (NB, BS, Wi), new rows written;
@@ -270,7 +310,14 @@ def _latent_read(lat_pool, idx_pool, tables, q_pos, frontier, index_fn,
     blocks of ``keys`` keys, as far as the longest row's frontier: the
     index scores of every causal key, kept whole (B, C, MB x BS) float32
     because they decide a set; then scores, a running softmax and the
-    value product over the selected keys. -> (B, C, H, dv) float32."""
+    value product over the selected keys. -> (B, C, H, dv) float32.
+
+    ``expand``: ``(queries (B, C, H, d), wk_b (H, dn, R), wv_b (H, R,
+    dv))``, what the model's expanded ``read_fn`` is made from, or None.
+    Given, the second pass is the Pallas kernel of
+    ``ops/pallas/latent_attention.py`` over the same key blocks, told the
+    selection as a mask; the first pass and the threshold are the same
+    code either way."""
     B, MB = tables.shape
     BS = lat_pool.shape[1]
     C = q_pos.shape[1]
@@ -297,6 +344,11 @@ def _latent_read(lat_pool, idx_pool, tables, q_pos, frontier, index_fn,
         0, n, index_pass, jnp.full((B, C, passes * KB), neg, jnp.float32))
     # a query at position t < topk reads every causal key
     thr = jnp.where(q_pos < topk, neg, _kth_largest(scores, topk))
+    if expand is not None:
+        return latent_chunk_attention(
+            expand[0], lat_pool[tables].reshape(B, passes * KB, -1),
+            (scores >= thr[..., None]) & (scores > neg), *expand[1:],
+            q_pos, frontier, key_tile=KB)
 
     def read_pass(j, carry):
         m, l, o = carry
@@ -374,27 +426,35 @@ class _Step:
 
     def latent(self, i):
         """Layer i's ``attn_fn`` of the ``LATENT`` kind: ``(lat (B, C, W),
-        idx (B, C, Wi), index_fn, read_fn, topk, dv) -> (B, C, H, dv)``
-        float32. Writes the step's new rows into the layer's two
-        pools (pads and inactive slots aim at scratch block 0), then
-        :func:`_latent_read` through the block table."""
+        idx (B, C, Wi), index_fn, read_fn, topk, dv, expand=None) -> (B,
+        C, H, dv)`` float32. Writes the step's new rows into the layer's
+        two pools (pads and inactive slots aim at scratch block 0), then
+        :func:`_latent_read` through the block table: through the kernel
+        where the step runs it (:func:`_latent_kernel`) and the model
+        handed ``expand``, and says which to whoever counts."""
         j = self.index[i]
         blocks, offsets, _ = self.dest[KV]
         tables = self.tables[KV]
         tables = tables[None] if tables.ndim == 1 else tables
-        keys = _LATENT_KEYS["decode" if self.q_pos.shape[1] == 1
-                            else "chunk"]
+        B, C = self.q_pos.shape
+        keys = _LATENT_KEYS["decode" if C == 1 else "chunk"]
+        kernel = _latent_kernel(self.geom, C)
 
-        def attn_fn(lat, idx, index_fn, read_fn, topk, dv):
+        def attn_fn(lat, idx, index_fn, read_fn, topk, dv, expand=None):
             for key, new in zip(_KEYS[LATENT], (lat, idx)):
                 pool = self.cache[key][j]
                 self.cache[key][j] = pool.at[blocks, offsets].set(
                     new.reshape(-1, new.shape[-1]).astype(pool.dtype))
-            B, C = self.q_pos.shape
+            if not kernel:
+                expand = None
+            counts = _LATENT_READS.get()
+            if counts is not None:
+                counts[0] += 1
+                counts[1] += expand is not None
             return _latent_read(
                 self.cache["lat"][j], self.cache["idx"][j], tables,
                 self.q_pos, self.frontier, index_fn, read_fn, topk,
-                (B, C, self.geom.n_head, dv), keys)
+                (B, C, self.geom.n_head, dv), keys, expand)
 
         return attn_fn
 

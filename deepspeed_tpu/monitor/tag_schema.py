@@ -241,11 +241,13 @@ SPAN_SCHEMA = {
     "dstpu.engine.prefill": {
         "stats": ("uid", "tokens", "padded", "expert_calls",
                   "expert_kernel_calls", "rule_rows", "rule_calls",
-                  "rule_kernel_calls", "index_keys", "attended_keys"),
+                  "rule_kernel_calls", "index_keys", "attended_keys",
+                  "latent_read_calls", "latent_read_kernel_calls"),
         "meaning": "bucketed prefill of one request: arrays, program "
                    "call, blocking read of its token; expert_calls / "
                    "expert_kernel_calls / rule_rows / rule_calls / "
-                   "rule_kernel_calls / index_keys / attended_keys as on "
+                   "rule_kernel_calls / index_keys / attended_keys / "
+                   "latent_read_calls / latent_read_kernel_calls as on "
                    "dstpu.engine.dispatch"},
     "dstpu.engine.dispatch": {
         "stats": ("kind", "active", "slots", "steps", "chunk_tokens",
@@ -255,7 +257,8 @@ SPAN_SCHEMA = {
                   "write_rows_offered", "expert_calls",
                   "expert_kernel_calls", "chained", "late_steps",
                   "state_updates", "rule_rows", "rule_calls",
-                  "rule_kernel_calls", "index_keys", "attended_keys"),
+                  "rule_kernel_calls", "index_keys", "attended_keys",
+                  "latent_read_calls", "latent_read_kernel_calls"),
         "meaning": "one decode-bearing or chunk program call (kind "
                    "decode | fused | chunk | spec | offload) from the "
                    "assembled batch to the last posted token — of kind "
@@ -312,7 +315,18 @@ SPAN_SCHEMA = {
                    "keys those queries attend after the selection, "
                    "min(position + 1, index_topk) a query, x those layers: "
                    "the model's work whatever implements the read (both "
-                   "0 on a model without such a layer)"},
+                   "0 on a model without such a layer); "
+                   "latent_read_calls = the selected reads the dispatch's "
+                   "program makes (those layers x its chunk and its "
+                   "decode steps: 5 x (1 + 8) in a fused dispatch of five "
+                   "such layers), noted when the program is traced as the "
+                   "expert calls are, so 0 on a model without such a "
+                   "layer and on the dispatch that traces it, and "
+                   "latent_read_kernel_calls = those of them whose read "
+                   "after the selection is the Pallas kernel "
+                   "(ops/pallas/latent_attention.py: a chunk's, where the "
+                   "program runs kernels), the rest the XLA form (a "
+                   "decode step's always)"},
     "dstpu.engine.build": {
         "stats": (),
         "meaning": "leaf: host work before a program call (decode "

@@ -849,6 +849,8 @@ class ServingTelemetry:
         # of them that were a Pallas kernel
         self._rule_calls = 0
         self._rule_kernel_calls = 0
+        self._latent_reads = 0
+        self._latent_kernel_reads = 0
         # bytes of cache the live sequences hold (blocks under their
         # tables, and whatever the model keeps a slot) against the tokens
         # they have seen, summed over the engine's steps
@@ -955,6 +957,13 @@ class ServingTelemetry:
         ``kernel`` of them a Pallas kernel and the rest the XLA form."""
         self._rule_calls += calls
         self._rule_kernel_calls += kernel
+
+    def on_latent_reads(self, reads, kernel):
+        """One program call whose trace made ``reads`` selected reads of a
+        latent cache (latent layers x its chunk and decode steps),
+        ``kernel`` of them the Pallas kernel and the rest the XLA form."""
+        self._latent_reads += reads
+        self._latent_kernel_reads += kernel
 
     def on_cache_held(self, cache_bytes, live_tokens):
         """One engine step began with ``cache_bytes`` of cache held by
@@ -1122,6 +1131,9 @@ class ServingTelemetry:
         if self._rule_calls:
             out["rule_kernel_share"] = round(
                 self._rule_kernel_calls / self._rule_calls, 4)
+        if self._latent_reads:
+            out["latent_kernel_share"] = round(
+                self._latent_kernel_reads / self._latent_reads, 4)
         if self._live_tokens:
             out["cache_bytes_per_live_token"] = round(
                 self._cache_bytes / self._live_tokens)

@@ -130,15 +130,44 @@ def test_apply_equals_reference(model, params):
     assert want.std() > 0.1
 
 
-def test_selection_is_exact_and_per_token(params):
+def _one_chunk(model, params, p, n, cache, width=None):
+    """Tokens 0 .. n - 1 of ``p`` through the chunk program at start 0, in
+    a chunk of ``width`` (n rounded up to blocks) over table 1 .."""
+    width = width or -(-n // BS) * BS
+    table = np.arange(1, 1 + width // BS, dtype=np.int32)
+    ids = np.zeros((1, width), np.int32)
+    ids[0, :n] = p[:n]
+    pos = np.arange(width)
+    tb = np.where(pos < n, table[pos // BS], 0)
+    return model.apply_paged_chunk(
+        params, ids, cache, tb.astype(np.int32),
+        (pos % BS * (pos < n)).astype(np.int32), np.int32(0), np.int32(n),
+        table)
+
+
+@pytest.mark.parametrize("paged_kernel", [None, True], ids=["apply", "kernel"])
+def test_selection_is_exact_and_per_token(params, paged_kernel):
     """The program reads the set the reference reads, every query of every
     layer: min(index_topk, t + 1) causal keys (more only where keys tie
-    with the k-th, which a four-head indexer's relu does make)."""
+    with the k-th, which a four-head indexer's relu does make): ``apply``,
+    and a 96-token chunk whose read is the Pallas kernel, which is told
+    the set and has to answer as the reference does."""
     seen = {}
     ids = np.stack(prompts_of(96, seed=2))
+    model = DeepseekV32(CFG)
     with dsa.tapped_selection(CFG.n_layer, lambda i, q, sel:
-                              seen.__setitem__(i, np.asarray(sel))):
-        DeepseekV32(CFG).apply(params, ids)
+                              seen.__setitem__(i, np.asarray(sel)[..., :96])):
+        if paged_kernel is None:
+            model.apply(params, ids)
+        else:
+            model._paged_kernel = paged_kernel
+            with paged.counting_latent_reads() as reads:
+                logits, _ = _one_chunk(
+                    model, params, ids[0], 96,
+                    model.init_paged_cache(16, BS, dtype=jnp.float32))
+            assert reads == [CFG.n_layer] * 2
+            assert np.abs(np.asarray(logits)[0] - np.asarray(ref.logits(
+                params, ids, **KW))[0, -1]).max() < TOL
         jax.effects_barrier()
     want = ref.selection_masks(params, ids[0], **KW)
     assert sorted(seen) == [0, 1, 2]
@@ -166,30 +195,26 @@ def test_kth_largest_is_exact():
         assert (got == -np.sort(-x, axis=-1)[..., k - 1]).all()
 
 
-def test_prefill_form_equals_decode_form(model, params):
+@pytest.mark.parametrize("paged_kernel", [False, True], ids=["xla", "kernel"])
+def test_prefill_form_equals_decode_form(params, paged_kernel):
     """Token 39's logits from a 40-token chunk (the latent expanded to
-    heads) and from a decode step after a 39-token chunk (the projections
-    absorbed into query and output): one attention, two forms."""
+    heads: the XLA read, or the Pallas kernel) and from a decode step after
+    a 39-token chunk (the projections absorbed into query and output): one
+    attention, two forms."""
     p = prompts_of(40, seed=3)[0]
-    table = np.arange(1, 9, dtype=np.int32)
+    model = DeepseekV32(CFG)
+    model._paged_kernel = paged_kernel
 
     def cache():
         return model.init_paged_cache(16, BS, dtype=jnp.float32)
 
     def chunk(n, cache):
-        ids = np.zeros((1, 48), np.int32)
-        ids[0, :n] = p[:n]
-        pos = np.arange(48)
-        tb = np.where(pos < n, table[np.minimum(pos // BS, 7)], 0)
-        return model.apply_paged_chunk(
-            params, ids, cache, tb.astype(np.int32),
-            (pos % BS * (pos < n)).astype(np.int32), np.int32(0),
-            np.int32(n), table)
+        return _one_chunk(model, params, p, n, cache, 48)
 
     expanded, _ = chunk(40, cache())
     _, held = chunk(39, cache())
-    tables = np.zeros((2, 8), np.int32)
-    tables[1] = table                       # slot 0 stays dead
+    tables = np.zeros((2, 6), np.int32)
+    tables[1] = np.arange(1, 7)             # slot 0 stays dead
     absorbed, _ = model.apply_paged_decode(
         params, np.array([0, p[39]], np.int32), np.array([0, 39], np.int32),
         held, tables)
@@ -220,6 +245,95 @@ def test_reference_tells_its_neighbours_apart(params, variant, monkeypatch):
             x.astype(jnp.float8_e5m2)) if x.ndim >= 2 else f32(x))
     near = np.asarray(ref.logits(params, ids, **{**KW, **variant}))
     assert np.abs(near - want).max() > 50 * TOL
+
+
+# ------------------------------------------------ the chunk's read, a kernel
+def _a_read(model, params, q_pos, seed=0):
+    """What layer 0's ``_attention`` hands the step for queries at
+    ``q_pos`` (B, C) of a random stream: ``index_fn``, the expanded
+    ``read_fn`` and what it is made from (``expand``), beside two random
+    pools of 16 blocks and each row's table over them."""
+    B, C = q_pos.shape
+    ks = jax.random.split(jax.random.key(seed), 3)
+    got = {}
+
+    def attn_fn(lat, idx, index_fn, read_fn, topk, dv, expand=None):
+        got.update(index_fn=index_fn, read_fn=read_fn, expand=expand,
+                   out_shape=(B, C, CFG.n_head, dv))
+        return jnp.zeros(got["out_shape"], jnp.float32)
+
+    model._attention(jax.random.normal(ks[0], (B, C, CFG.d_model)),
+                     params["layers"][0], attn_fn, jnp.asarray(q_pos))
+    assert got["expand"] is not None
+    got["lat"] = jax.random.normal(ks[1], (1 + 16 * B, BS, CFG.lat_row))
+    got["idx"] = jax.random.normal(ks[2], (1 + 16 * B, BS,
+                                           CFG.index_head_dim))
+    got["tables"] = 1 + np.arange(16 * B, dtype=np.int32).reshape(B, 16)
+    return got
+
+
+# name: (first position a row, queries a row, real queries of them (None:
+# a decode-like step, every query real and the frontier behind the last),
+# index_topk, index scores rounded to halves, the kernel's query tile)
+READS = {
+    "one-row": ([24], 16, 16, TOPK, False, None),
+    "ragged-rows": ([0, 13, 37], 16, None, TOPK, False, None),
+    "under-topk": ([0], 16, 16, 64, False, None),
+    "ties-with-the-kth": ([40], 16, 16, TOPK, True, None),
+    "pads-past-true-len": ([16], 16, 5, TOPK, False, None),
+    "query-tiles-and-padding": ([8], 80, 80, TOPK, False, 32),
+    "frontier-inside-a-key-tile": ([3, 50], 16, 7, TOPK, False, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(READS))
+def test_kernel_read_equals_xla_read(model, params, monkeypatch, case):
+    """``_latent_read`` through the Pallas kernel (interpreted) against its
+    XLA read, the same selection: key blocks of 32 in a 128-key table, so
+    a read crosses tiles and stops inside one."""
+    from deepspeed_tpu.ops.pallas import latent_attention
+    starts, C, real, topk, ties, tile = READS[case]
+    if tile:
+        monkeypatch.setattr(latent_attention, "_QUERY_TILE", tile)
+        assert latent_attention.read_tiles(
+            C, 4, 16, 16, 24, 128, 32, jnp.float32)[0] == tile < C
+    starts = np.asarray(starts, np.int32)
+    q_pos = starts[:, None] + np.arange(C, dtype=np.int32)[None]
+    frontier = starts + (C if real is None else real)
+    r = _a_read(model, params, q_pos, seed=len(case))
+    index_fn = r["index_fn"]
+    if ties:
+        index_fn = lambda keys: jnp.round(r["index_fn"](keys) * 2.0) / 2.0
+    taken = []
+    real_kth = paged._kth_largest
+
+    def kth(scores, k):
+        thr = real_kth(scores, k)
+        taken.append(np.asarray((scores >= thr[..., None])
+                                & (scores > -jnp.inf)).sum(-1))
+        return thr
+
+    monkeypatch.setattr(paged, "_kth_largest", kth)
+    args = (r["lat"], r["idx"], jnp.asarray(r["tables"]), jnp.asarray(q_pos),
+            jnp.asarray(frontier), index_fn, r["read_fn"], topk,
+            r["out_shape"], 32)
+    want = np.asarray(paged._latent_read(*args))
+    got = np.asarray(paged._latent_read(*args, r["expand"]))
+    assert want.shape == got.shape == r["out_shape"]
+    live = q_pos < frontier[:, None]
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-6)
+    assert np.abs(want[live]).max() > 0.1
+    # what the case is named for
+    count = taken[0][live]
+    causal = (q_pos + 1)[live]
+    if case == "under-topk":
+        assert (count == causal).all()
+    elif ties:
+        assert (count > np.minimum(causal, topk)).any()
+    else:
+        assert (count >= np.minimum(causal, topk)).all() \
+            and (count < causal).any()
+
 
 
 # ----------------------------------------------------------------- the share
@@ -301,31 +415,37 @@ def test_rows_of_absent_experts_never_reach_the_products(backend):
 
 
 # --------------------------------------------------------------- the engine
-@pytest.fixture(scope="module")
-def mixed(model, params):
+@pytest.fixture(scope="module", params=["auto", True], ids=["xla", "kernel"])
+def mixed(request, params):
     """Three prompts at once through 16-token chunks: 5 tokens (one padded
     chunk, all keys selected to the end), 21 (two chunks, past index_topk
     in the second), 70 (five chunks across block boundaries), the later
     ones chunked while the earlier decode (fused dispatches), then 8-step
-    decode dispatches to position 110."""
+    decode dispatches to position 110. ``paged_kernel`` "auto" is the XLA
+    read off a TPU, True the Pallas kernel (interpreted) in every chunk."""
     prompts = prompts_of(5, 21, 70)
-    eng = TapEngine(model, ENGINE, params=params)
-    kinds = []
-    real = eng._dispatch_span
+    eng = TapEngine(DeepseekV32(CFG), {**ENGINE, "paged_kernel": request.param},
+                    params=params)
+    from deepspeed_tpu.inference.v2 import engine_v2
+    spans, real = [], engine_v2.span
 
-    def noting(kind, *a, **kw):
-        kinds.append(kind)
-        return real(kind, *a, **kw)
+    def recording(name, **stats):
+        if name == "dstpu.engine.dispatch":
+            spans.append((stats["kind"], stats))
+        return real(name, **stats)
 
-    eng._dispatch_span = noting
-    out = serve(eng, prompts, [40, 40, 40])
-    return eng, prompts, out, kinds
+    engine_v2.span = recording
+    try:
+        out = serve(eng, prompts, [40, 40, 40])
+    finally:
+        engine_v2.span = real
+    return eng, prompts, out, spans
 
 
 @pytest.mark.parametrize("which", [0, 1, 2],
                          ids=["one-chunk", "two-chunks", "five-chunks"])
 def test_engine_equals_reference(params, mixed, which):
-    _, prompts, out, kinds = mixed
+    _, prompts, out, spans = mixed
     tokens, rows = out[which]
     assert len(tokens) == 40 and rows.shape == (40, CFG.vocab_size)
     want = reference_rows(params, prompts[which], tokens)
@@ -334,14 +454,16 @@ def test_engine_equals_reference(params, mixed, which):
     for variant in ({"select": False}, {"index_topk": TOPK // 2}):
         far = reference_rows(params, prompts[which], tokens, **variant)
         assert np.abs(far - want).max() > 50 * TOL
-    assert {"chunk", "fused", "decode"} <= set(kinds)
+    assert {"chunk", "fused", "decode"} <= {kind for kind, _ in spans}
 
 
-def test_bucketed_prefill_equals_reference(model, params):
+@pytest.mark.parametrize("paged_kernel", ["auto", True], ids=["xla", "kernel"])
+def test_bucketed_prefill_equals_reference(params, paged_kernel):
     """No split-fuse: a prompt goes through the bucketed prefill program,
     the chunk program at start 0 (70 tokens in a 96-token bucket)."""
-    eng = TapEngine(model, {**ENGINE, "splitfuse_tokens": 0,
-                            "prompt_bucket": 32}, params=params)
+    eng = TapEngine(DeepseekV32(CFG),
+                    {**ENGINE, "splitfuse_tokens": 0, "prompt_bucket": 32,
+                     "paged_kernel": paged_kernel}, params=params)
     prompts = prompts_of(70, 9, seed=4)
     for (tokens, rows), p in zip(serve(eng, prompts, [12, 12]), prompts):
         assert np.abs(rows - reference_rows(params, p, tokens)).max() < TOL
@@ -363,10 +485,17 @@ def test_cache_is_latent_blocks_under_the_tables(mixed):
                                         jnp.float32)
 
 
-def test_dispatch_spans_count_the_selected_read(model, params, monkeypatch):
+@pytest.mark.parametrize("paged_kernel", ["auto", True], ids=["xla", "kernel"])
+def test_dispatch_spans_count_the_selected_read(params, monkeypatch,
+                                                paged_kernel):
     """``index_keys`` and ``attended_keys`` on every dispatch span, against
     a count made a query at a time: the causal keys of each real query
-    token, and min(that, index_topk), x 3 latent layers."""
+    token, and min(that, index_topk), x 3 latent layers; and
+    ``latent_read_calls`` / ``latent_read_kernel_calls``: the selected reads
+    the span's program makes, 3 a chunk or a prefill and 3 a decode step (0
+    on the dispatch that traces the program), of which the chunk's are the
+    Pallas kernel where the engine's ``paged_kernel`` gives one
+    (``test_fused_dispatch_counts_its_reads`` has the fused dispatch)."""
     from deepspeed_tpu.inference.v2 import engine_v2
     said = []
     real = engine_v2.span
@@ -377,32 +506,72 @@ def test_dispatch_spans_count_the_selected_read(model, params, monkeypatch):
         return real(name, **stats)
 
     monkeypatch.setattr(engine_v2, "span", recording)
-    eng = InferenceEngineV2(model, ENGINE, params=params)
+    kernel = paged_kernel is True
+    config = {**ENGINE, "paged_kernel": paged_kernel}
+    eng = InferenceEngineV2(DeepseekV32(CFG), config, params=params)
     eng.put(prompts_of(37, seed=6)[0], 11)
     while eng.has_work:
         eng.step()
-    L = CFG.n_layer
-    assert [st["kind"] for _, st in said][:3] == ["chunk"] * 3
+    L, steps = CFG.n_layer, ENGINE["decode_steps_per_dispatch"]
+    assert [st["kind"] for _, st in said] == ["chunk"] * 3 + ["decode"] * 2
     # one sequence: prompt tokens 0 .. 36 in chunks, then decode steps at
     # positions 37 .. (a dispatch runs all 8 steps; the last runs past
     # the budget, and the span counts what the device does)
-    contexts = []
+    contexts, traced = [], set()
     for _, st in said:
         contexts += [len(contexts) + 1 + j
                      for j in range(st["chunk_tokens"] + st["steps"])]
         mine = contexts[-(st["chunk_tokens"] + st["steps"]):]
         assert st["index_keys"] == L * sum(mine)
         assert st["attended_keys"] == L * sum(min(c, TOPK) for c in mine)
+        want = {"chunk": (L, L * kernel), "decode": (L * steps, 0)}[
+            st["kind"]] if st["kind"] in traced else (0, 0)
+        traced.add(st["kind"])
+        assert (st["latent_read_calls"],
+                st["latent_read_kernel_calls"]) == want, st
     assert sum(st["chunk_tokens"] for _, st in said) == 37
-    bucketed = InferenceEngineV2(model, {**ENGINE, "splitfuse_tokens": 0,
-                                         "prompt_bucket": 32}, params=params)
+    # 2 chunks' and 1 decode dispatch's reads were counted
+    assert eng.telemetry_snapshot()["latent_kernel_share"] \
+        == round(2 * L * kernel / (2 * L + L * steps), 4)
+    bucketed = InferenceEngineV2(
+        DeepseekV32(CFG), {**config, "splitfuse_tokens": 0,
+                           "prompt_bucket": 32}, params=params)
     del said[:]
-    bucketed.put(prompts_of(37, seed=6)[0], 2)
+    for _ in range(2):                      # the second finds it traced
+        bucketed.put(prompts_of(37, seed=6)[0], 2)
     while bucketed.has_work:
         bucketed.step()
-    (name, st), = [x for x in said if x[0] == "dstpu.engine.prefill"]
-    assert st["index_keys"] == L * 37 * 38 // 2
-    assert st["attended_keys"] == L * sum(min(c, TOPK) for c in range(1, 38))
+    first, second = [st for name, st in said
+                     if name == "dstpu.engine.prefill"]
+    for st in (first, second):
+        assert st["index_keys"] == L * 37 * 38 // 2
+        assert st["attended_keys"] == L * sum(min(c, TOPK)
+                                              for c in range(1, 38))
+    assert (first["latent_read_calls"], second["latent_read_calls"],
+            second["latent_read_kernel_calls"]) == (0, L, L * kernel)
+
+
+def test_fused_dispatch_counts_its_reads(mixed):
+    """A fused dispatch's program holds a chunk and 8 decode steps: 3 x (1
+    + 8) selected reads (45 with the cell's five latent layers), of which
+    the chunk's 3 (5) are the kernel where the engine runs kernels; the
+    telemetry's ``latent_kernel_share`` is their share of all the engine's
+    reads."""
+    eng, _, _, spans = mixed
+    L, steps = CFG.n_layer, ENGINE["decode_steps_per_dispatch"]
+    kernel = eng.config.paged_kernel is True
+    fused = [st for kind, st in spans if kind == "fused"]
+    assert len(fused) > 1
+    assert (fused[0]["latent_read_calls"],
+            fused[0]["latent_read_kernel_calls"]) == (0, 0)   # it traces
+    for st in fused[1:]:
+        assert (st["latent_read_calls"], st["latent_read_kernel_calls"]) \
+            == (L * (1 + steps), L * kernel)
+    reads = sum(st["latent_read_calls"] for _, st in spans)
+    mine = sum(st["latent_read_kernel_calls"] for _, st in spans)
+    assert eng.telemetry_snapshot()["latent_kernel_share"] \
+        == round(mine / reads, 4)
+    assert (mine > 0) == kernel
 
 
 # ------------------------------------------------------------- the refusals

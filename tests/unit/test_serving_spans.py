@@ -245,6 +245,11 @@ def test_on_admit_queue_wait():
     st.on_rule_calls(108, 108)                      # fused: 12 x (1 + 8)
     st.on_rule_calls(12, 0)
     assert st.percentiles()["rule_kernel_share"] == 0.9
+    assert "latent_kernel_share" not in st.percentiles()
+    st.on_latent_reads(0, 0)                        # a program's first call
+    st.on_latent_reads(45, 5)                       # fused: 5 x (1 + 8)
+    st.on_latent_reads(5, 5)                        # a chunk alone
+    assert st.percentiles()["latent_kernel_share"] == 0.2
 
 
 def test_chain_counters_are_host_arithmetic():
@@ -462,12 +467,16 @@ def test_expert_kernel_counter(monkeypatch, knob, splitfuse_tokens):
         assert st["expert_kernel_calls"] == (want if knob is True else 0), st
         # no gated delta rule in either family: 0 of 0 (ISSUE 42)
         assert (st["rule_calls"], st["rule_kernel_calls"]) == (0, 0), st
+        # nor a latent layer read through a selection (ISSUE 44)
+        assert (st["latent_read_calls"],
+                st["latent_read_kernel_calls"]) == (0, 0), st
     # split-fuse: chunks, fused with the decode steps while any slot
     # decodes, and plain decode dispatches between prompts
     assert kinds - {"decode"} == ({"fused", "chunk"} if splitfuse_tokens
                                   else {"prefill"}) and "decode" in kinds
     snapshot = engine.telemetry_snapshot()
     assert "rule_kernel_share" not in snapshot
+    assert "latent_kernel_share" not in snapshot
     if knob == "dense":
         assert "moe_kernel_share" not in snapshot
     else:

@@ -25,6 +25,7 @@ from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.ops.pallas.fused_ce import unembed_logits_stats
 from deepspeed_tpu.ops.pallas.gated_delta_rule import (
     chunk_rule_kernel, live_slot_list, step_rule_kernel)
+from deepspeed_tpu.ops.pallas.latent_attention import latent_chunk_attention
 from deepspeed_tpu.ops.pallas.paged_attention import (
     as_pools, decode_entries_per_step, decode_work_list, like_boundary,
     paged_chunk_attention, paged_decode_attention, pool_block_dims)
@@ -116,6 +117,19 @@ def _gdn_shapes(*lead):
         + [((lead[0], GDN_H, GDN_DK, GDN_DV), f32)]
 
 
+# a latent layer's selected read at DeepSeek-V3.2-Exp's widths: cell 10's
+# 1,024-token chunk of 128 heads (128 + 64 wide scores, 128 wide values)
+# over the 264 x 64 keys of a table, latent rows of 512 + 64 in 640, the
+# XLA read's 512-key passes; (rows, queries a row) lead the shapes
+MLA_H, MLA_K = 128, 264 * 64
+
+
+def _latent_read_shapes(b, c):
+    return [((b, c, MLA_H, 192), bf16), ((b, MLA_K, 640), bf16),
+            ((b, c, MLA_K), jnp.int8), ((MLA_H, 128, 512), bf16),
+            ((MLA_H, 512, 128), bf16), ((b, c), i32), ((b,), i32)]
+
+
 POOL = [((NB, H, BS, HD), bf16)] * 2
 CASES = {
     # training: the headline's whole-sequence tile, and the config default
@@ -144,6 +158,14 @@ CASES = {
         _gdn_shapes(1, GDN_T)),
     "gdn_step_16_slots": (
         _gdn_step, _gdn_shapes(GDN_SLOTS) + [((GDN_SLOTS,), jnp.bool_)]),
+    # the latent read's kernel at cell 10's chunk, and at a prompt bucket
+    # that two query tiles share (1,536 -> 2 x 768)
+    "latent_read_c1024": (
+        lambda *a: latent_chunk_attention(*a, key_tile=512, interpret=False),
+        _latent_read_shapes(1, 1024)),
+    "latent_read_c1536": (
+        lambda *a: latent_chunk_attention(*a, key_tile=512, interpret=False),
+        _latent_read_shapes(1, 1536)),
 }
 
 
@@ -849,7 +871,10 @@ def test_deepseek_share_programs_fit_the_chip(v5e, monkeypatch, program):
     beside the whole cell's arguments (five layers' weights and pools: a
     further layer is arguments, not temporaries) stay inside the chip. The
     five-layer chunk and fused programs themselves compiled to 12.29 / 12.58 GB
-    (sandbox compile, PR 43: PERF.md section 4)."""
+    (sandbox compile, PR 43: PERF.md section 4). A chunk's selected read
+    is the kernel of ``ops/pallas/latent_attention.py``, a layer (PR 44):
+    no array of heads x queries x a block of keys is left in the program,
+    and its temporaries are under what the XLA read's were."""
     import dataclasses
     from deepspeed_tpu.models.deepseek_v32 import DEEPSEEK_V32, DeepseekV32
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -876,13 +901,19 @@ def test_deepseek_share_programs_fit_the_chip(v5e, monkeypatch, program):
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, cache, *rest).compile()
     text = compiled.as_text()
-    # one expert layer a pass through the forward grouped kernel
+    # one expert layer a pass through the forward grouped kernel, and a
+    # chunk's two latent layers through the selected read's
     assert text.count('custom_call_target="tpu_custom_call"') \
-        == {"chunk": 1, "decode_x8": 8, "fused": 9}[program]
+        == {"chunk": 1 + 2, "decode_x8": 8, "fused": 9 + 2}[program]
+    # the XLA read's scores, (128 heads, 1,024 queries, 512 keys) float32
+    assert not re.search(r"f32\[(1,)?128,1024,512\]", text)
     # no whole-pool copy of a latent pool
     assert not re.search(
         r"bf16\[%d,%d,640\]\{[^}]*\} copy\(" % (DS_NB, BS), text)
     temp = compiled.memory_analysis().temp_size_in_bytes
+    # what 6e5a04b (PR 43, the XLA read) compiled to, two layers
+    assert temp <= {"chunk": 568273408, "decode_x8": 82927616,
+                    "fused": 778774016}[program]
     _, whole_params, whole_cache = trees(cell)
     assert abs(_nbytes(whole_params) - 9.286e9) < 0.01e9
     assert abs(_nbytes(whole_cache) - 2.349e9) < 0.01e9
