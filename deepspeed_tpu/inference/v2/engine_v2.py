@@ -53,6 +53,16 @@ _PROGRAMS_OF_KIND = {"decode": ("decode",), "offload": ("decode",),
                      "fused": ("fused",), "chunk": ("chunk",),
                      "spec": ("propose", "verify")}
 
+# the decode steps a FUSED dispatch carries beside its chunk. Not the
+# plain decode's ``decode_steps_per_dispatch``: that count amortises a
+# launch and a read, and a fused dispatch is read at once either way, so
+# here each further step is one more pass over every weight for the few
+# rows that decode, and the count only sets the ratio in which the
+# waiting prompt and the running answers share the chip. Timed at 1 / 2 /
+# 4 / 8 and 2-or-4 by the live slots in the long-prompt cells (PERF.md
+# section 6, PR 45)
+_FUSED_STEPS = 2
+
 
 @dataclass
 class RaggedInferenceEngineConfig:
@@ -71,9 +81,11 @@ class RaggedInferenceEngineConfig:
     temperature: float = 0.0         # 0 = greedy
     top_k: int = 0
     seed: int = 0
-    # decode steps fused into one device program: one launch and one
+    # decode steps of one plain decode dispatch: one launch and one
     # read of tokens for this many a sequence. Scheduling granularity
-    # coarsens with it: a prompt's prefill waits behind a dispatch
+    # coarsens with it: a prompt's prefill waits behind a dispatch. A
+    # FUSED dispatch (a prompt chunk beside the running decodes) carries
+    # ``_FUSED_STEPS`` instead, whatever this is
     decode_steps_per_dispatch: int = 8
     # Dynamic SplitFuse (reference blogs/deepspeed-fastgen §3B): > 0 =
     # prompts stream through fixed-size chunks of this many tokens,
@@ -890,14 +902,15 @@ class InferenceEngineV2:
 
     def _get_splitfuse(self):
         """ONE fused fixed-shape program per dispatch: a C-token prompt
-        chunk for the head-of-queue prefilling sequence PLUS n decode
-        steps for every running sequence — the Dynamic SplitFuse
-        composition (reference blogs/deepspeed-fastgen §3B; the ragged
-        kernels' role). Shapes are static (C, B, MB), so exactly one
-        compilation serves every prompt length and batch mix."""
+        chunk for the head-of-queue prefilling sequence PLUS
+        ``_FUSED_STEPS`` decode steps for every running sequence — the
+        Dynamic SplitFuse composition (reference blogs/deepspeed-fastgen
+        §3B; the ragged kernels' role). Shapes are static (C, B, MB), so
+        exactly one compilation serves every prompt length and batch
+        mix."""
         if self._splitfuse_jit is None:
             model = self.model
-            n = max(1, self.config.decode_steps_per_dispatch)
+            n = _FUSED_STEPS
 
             def fused(params, cache, c_ids, c_tb, c_to, c_start, c_len,
                       c_table, c_temp, c_topk, d_tokens, d_lengths,
@@ -1334,6 +1347,8 @@ class InferenceEngineV2:
             self.telemetry.on_kv_write(write_rows, write_rows_offered)
             if kind == "decode":
                 self.telemetry.on_plain_decode(chained, steps * active)
+            elif kind == "fused":
+                self.telemetry.on_fused_dispatch()
         return span("dstpu.engine.dispatch", kind=kind, active=active,
                     slots=slots, steps=steps, chunk_tokens=chunk_tokens,
                     chunk_start=chunk_start, grid_steps=grid_steps,
@@ -1386,11 +1401,12 @@ class InferenceEngineV2:
 
     def _step_splitfuse_chunk(self):
         """Run one fused dispatch: the next chunk of the oldest
-        prefilling sequence + n decode steps (chunk-only when nothing is
-        decoding). Returns decode (uid, token) pairs. Prefix-cache hits
-        ride this path even with SplitFuse off (chunk accounting already
-        handles a nonzero start offset); the chunk size then falls back
-        to the prompt bucket."""
+        prefilling sequence + ``_FUSED_STEPS`` decode steps (not the
+        plain decode's ``decode_steps_per_dispatch``; chunk-only when
+        nothing is decoding). Returns decode (uid, token) pairs.
+        Prefix-cache hits ride this path even with SplitFuse off (chunk
+        accounting already handles a nonzero start offset); the chunk
+        size then falls back to the prompt bucket."""
         self._settle()      # the decode slots' tokens come from the host
         mgr = self.state_mgr
         C = self.config.splitfuse_tokens or self.config.prompt_bucket
@@ -1436,10 +1452,8 @@ class InferenceEngineV2:
 
         if fused:
             dispatch = self._dispatch_span(
-                "fused", batch.active,
-                max(1, self.config.decode_steps_per_dispatch), true_len,
-                C, batch=(batch.lengths, batch.block_tables),
-                chunk_start=off)
+                "fused", batch.active, _FUSED_STEPS, true_len, C,
+                batch=(batch.lengths, batch.block_tables), chunk_start=off)
         else:
             dispatch = self._dispatch_span("chunk", 0, 0, true_len, C,
                                            chunk_start=off)
@@ -1714,10 +1728,13 @@ class InferenceEngineV2:
             self.telemetry.percentiles()
 
     def _step_inner(self):
-        """One scheduler iteration: admit+prefill pending, then up to
+        """One scheduler iteration: admit+prefill pending, then one
+        device program: while a prompt is streaming in chunks its next
+        chunk, fused with ``_FUSED_STEPS`` decode steps for every active
+        sequence (:meth:`_step_splitfuse_chunk`); else
         ``decode_steps_per_dispatch`` decode steps for every active
-        sequence in one device program. Returns the (uid, token) pairs
-        the host read this step: where plain decodes follow one another
+        sequence. Returns the (uid, token) pairs the host read this
+        step: where plain decodes follow one another
         (:meth:`_plain_decode`) those of the dispatch BEFORE the one
         this step enqueued, so [] on the step that enqueues the first.
 

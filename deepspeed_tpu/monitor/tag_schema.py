@@ -261,7 +261,12 @@ SPAN_SCHEMA = {
                   "latent_read_calls", "latent_read_kernel_calls"),
         "meaning": "one decode-bearing or chunk program call (kind "
                    "decode | fused | chunk | spec | offload) from the "
-                   "assembled batch to the last posted token — of kind "
+                   "assembled batch to the last posted token; steps = the "
+                   "decode steps it ran: decode_steps_per_dispatch of kind "
+                   "decode, and of kind fused the engine's fixed count "
+                   "beside a chunk (engine_v2._FUSED_STEPS; "
+                   "ServingTelemetry's fused_dispatches counts those "
+                   "dispatches) — of kind "
                    "decode, where plain decodes follow one another, the "
                    "tokens read and posted inside the span are those of "
                    "the dispatch BEFORE the one it enqueues (chained = 1: "
@@ -318,10 +323,11 @@ SPAN_SCHEMA = {
                    "0 on a model without such a layer); "
                    "latent_read_calls = the selected reads the dispatch's "
                    "program makes (those layers x its chunk and its "
-                   "decode steps: 5 x (1 + 8) in a fused dispatch of five "
-                   "such layers), noted when the program is traced as the "
-                   "expert calls are, so 0 on a model without such a "
-                   "layer and on the dispatch that traces it, and "
+                   "decode steps: 5 x (1 + 2) in a fused dispatch of five "
+                   "such layers and two steps), noted when the program is "
+                   "traced as the expert calls are, so 0 on a model "
+                   "without such a layer and on the dispatch that traces "
+                   "it, and "
                    "latent_read_kernel_calls = those of them whose read "
                    "after the selection is the Pallas kernel "
                    "(ops/pallas/latent_attention.py: a chunk's, where the "

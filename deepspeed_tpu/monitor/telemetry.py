@@ -841,6 +841,8 @@ class ServingTelemetry:
         self._chained_dispatches = 0
         self._slot_steps = 0
         self._late_steps = 0
+        # fused dispatches: a prompt chunk beside the decode steps
+        self._fused_dispatches = 0
         # expert layer calls of every program call, and those of them
         # whose products were a Pallas grouped kernel
         self._expert_calls = 0
@@ -938,6 +940,11 @@ class ServingTelemetry:
         self._plain_dispatches += 1
         self._chained_dispatches += bool(chained)
         self._slot_steps += slot_steps
+
+    def on_fused_dispatch(self):
+        """One fused dispatch went out: a prompt chunk and, beside it, its
+        own count of decode steps for the slots that decode."""
+        self._fused_dispatches += 1
 
     def on_late_steps(self, late):
         """A dispatch that was read had run ``late`` decode steps x slots
@@ -1125,6 +1132,8 @@ class ServingTelemetry:
                 self._chained_dispatches / self._plain_dispatches, 4)
             out["late_stop_share"] = round(
                 self._late_steps / max(1, self._slot_steps), 4)
+        if self._fused_dispatches:
+            out["fused_dispatches"] = self._fused_dispatches
         if self._expert_calls:
             out["moe_kernel_share"] = round(
                 self._expert_kernel_calls / self._expert_calls, 4)

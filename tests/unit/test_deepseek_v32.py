@@ -457,6 +457,32 @@ def test_engine_equals_reference(params, mixed, which):
     assert {"chunk", "fused", "decode"} <= {kind for kind, _ in spans}
 
 
+@pytest.mark.parametrize("count", [1, 8])
+def test_streams_equal_whatever_the_fused_count(params, mixed, monkeypatch,
+                                                count):
+    """The decode steps a fused dispatch carries change no token: a single
+    step, and the eight every fused dispatch once took from the config,
+    give the streams of ``mixed`` (the engine's own count), here beside a
+    budget that ends inside a fused dispatch: the steps left over run for
+    a sequence that is gone, and nobody reads them."""
+    from deepspeed_tpu.inference.v2 import engine_v2
+    monkeypatch.setattr(engine_v2, "_FUSED_STEPS", count)
+    base, prompts, want, spans = mixed
+    eng = TapEngine(DeepseekV32(CFG),
+                    {**ENGINE, "paged_kernel": base.config.paged_kernel},
+                    params=params)
+    steps, real = set(), eng._dispatch_span
+    eng._dispatch_span = lambda kind, active, n, *a, **kw: (
+        steps.add((kind, n)), real(kind, active, n, *a, **kw))[1]
+    out = serve(eng, prompts, [40, 4, 40])
+    for (tokens, _), (whole, _), n in zip(out, want, (40, 4, 40)):
+        assert np.array_equal(tokens, whole[:n])
+    mgr = eng.state_mgr
+    assert mgr.allocator.free_blocks == mgr.allocator.total_blocks
+    assert steps == {("chunk", 0), ("fused", count), ("decode", 8)}
+    assert eng.telemetry_snapshot()["fused_dispatches"] > 4
+
+
 @pytest.mark.parametrize("paged_kernel", ["auto", True], ids=["xla", "kernel"])
 def test_bucketed_prefill_equals_reference(params, paged_kernel):
     """No split-fuse: a prompt goes through the bucketed prefill program,
@@ -552,21 +578,24 @@ def test_dispatch_spans_count_the_selected_read(params, monkeypatch,
 
 
 def test_fused_dispatch_counts_its_reads(mixed):
-    """A fused dispatch's program holds a chunk and 8 decode steps: 3 x (1
-    + 8) selected reads (45 with the cell's five latent layers), of which
-    the chunk's 3 (5) are the kernel where the engine runs kernels; the
-    telemetry's ``latent_kernel_share`` is their share of all the engine's
-    reads."""
+    """A fused dispatch's program holds a chunk and the engine's count of
+    decode steps for its company: 3 x (1 + steps) selected reads (5 x (1 +
+    2) = 15 with the cell's five latent layers), of which the chunk's 3
+    (5) are the kernel where the engine runs kernels; the telemetry's
+    ``latent_kernel_share`` is their share of all the engine's reads."""
+    from deepspeed_tpu.inference.v2.engine_v2 import _FUSED_STEPS
     eng, _, _, spans = mixed
-    L, steps = CFG.n_layer, ENGINE["decode_steps_per_dispatch"]
+    L = CFG.n_layer
     kernel = eng.config.paged_kernel is True
     fused = [st for kind, st in spans if kind == "fused"]
     assert len(fused) > 1
     assert (fused[0]["latent_read_calls"],
             fused[0]["latent_read_kernel_calls"]) == (0, 0)   # it traces
     for st in fused[1:]:
+        assert st["steps"] == _FUSED_STEPS != ENGINE[
+            "decode_steps_per_dispatch"]
         assert (st["latent_read_calls"], st["latent_read_kernel_calls"]) \
-            == (L * (1 + steps), L * kernel)
+            == (L * (1 + _FUSED_STEPS), L * kernel)
     reads = sum(st["latent_read_calls"] for _, st in spans)
     mine = sum(st["latent_read_kernel_calls"] for _, st in spans)
     assert eng.telemetry_snapshot()["latent_kernel_share"] \

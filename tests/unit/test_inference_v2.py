@@ -10,7 +10,7 @@ import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.inference.v2 import (BlockedAllocator, DSStateManager,
-                                        InferenceEngineV2)
+                                        InferenceEngineV2, engine_v2)
 from deepspeed_tpu.models import GPT2, GPT2Config
 from deepspeed_tpu.utils import groups
 from deepspeed_tpu.utils.groups import TopologyConfig
@@ -866,3 +866,60 @@ class TestChainedHazards:
         want = _greedy("gpt2", _prompt(70, 8), 14)
         assert runs[0][0] == want.tolist()
         assert len({tuple(r) for r in runs[0]}) == 3
+
+
+# ---------------------------------------------------------------------------
+# The decode steps of a FUSED dispatch (ISSUE 45): the engine's own count
+# (``engine_v2._FUSED_STEPS``), not the plain decode's. Tier-1; the
+# reference is ``_greedy`` as above.
+# ---------------------------------------------------------------------------
+
+def _chunked_engine(slots):
+    model, params, sizes = _family("gpt2")
+    groups.reset()
+    return InferenceEngineV2(model, params=params, config=dict(
+        sizes, splitfuse_tokens=16, max_batch_size=slots, num_kv_blocks=64))
+
+
+class TestFusedStepCount:
+    @pytest.mark.parametrize("count", [1, 2, 4, 8])
+    def test_streams_are_the_models_forward_whatever_the_count(
+            self, monkeypatch, count):
+        """Prompts of one to four chunks arrive while others decode: no
+        token depends on the decode steps a fused dispatch carries (the
+        counts the engine's own was timed against, the eight every fused
+        dispatch once took from the config among them). The first
+        request's budget ends inside a fused dispatch at every count but
+        1: it is retired once and the dispatch's later tokens for it are
+        dropped."""
+        assert engine_v2._FUSED_STEPS in (1, 2, 4, 8)
+        monkeypatch.setattr(engine_v2, "_FUSED_STEPS", count)
+        eng = _chunked_engine(4)
+        arrivals = [(0, _prompt(81, 7), 4, -1), (0, _prompt(82, 60), 12, -1),
+                    (1, _prompt(83, 9), 30, -1), (2, _prompt(84, 37), 9, -1),
+                    (4, _prompt(85, 20), 17, -1), (9, _prompt(86, 33), 5, -1)]
+        want = [_greedy("gpt2", a[1], a[2]) for a in arrivals]
+        said, real_span = [], eng._dispatch_span
+
+        def noting(kind, active, steps, *a, **kw):
+            said.append((kind, steps))
+            return real_span(kind, active, steps, *a, **kw)
+
+        eng._dispatch_span = noting
+        retired, real_retire = [], eng.state_mgr.retire
+        eng.state_mgr.retire = lambda uid: (retired.append(uid),
+                                            real_retire(uid))[1]
+        got, pairs = _drive(eng, arrivals)
+        for g, w, pr in zip(got, want, pairs):
+            np.testing.assert_array_equal(g, w)
+            assert pr == g[1:].tolist()
+        assert len(retired) == len(set(retired)) == len(arrivals)
+        _assert_empty(eng)
+        # the fused dispatches ran the count, the plain ones the config's
+        assert {steps for kind, steps in said if kind == "fused"} == {count}
+        assert {steps for kind, steps in said if kind == "decode"} \
+            == {STEPS}
+        assert eng.telemetry_snapshot()["fused_dispatches"] \
+            == sum(kind == "fused" for kind, _ in said) > 4
+        # one fused program, whatever the prompt lengths and the live slots
+        assert eng._get_splitfuse()._cache_size() == 1

@@ -354,6 +354,34 @@ def test_engine_equals_reference(params, mixed, which):
     assert {"chunk", "fused", "decode"} <= set(kinds)
 
 
+@pytest.mark.parametrize("count", ["kept", 1, 8])
+def test_streams_equal_whatever_the_fused_count(model, params, mixed,
+                                                monkeypatch, count):
+    """The decode steps a fused dispatch carries change no token: the
+    engine's own count (``mixed``, and here beside a budget that ends
+    inside a fused dispatch), a single step, and the eight every fused
+    dispatch once took from the config. The second sequence ends with
+    steps of its dispatch left over: they run into its own state and
+    nobody reads them."""
+    from deepspeed_tpu.inference.v2 import engine_v2
+    if count == "kept":
+        count = engine_v2._FUSED_STEPS
+    monkeypatch.setattr(engine_v2, "_FUSED_STEPS", count)
+    _, prompts, want, _ = mixed
+    eng = engine_of(model, params)
+    steps, real = set(), eng._dispatch_span
+    eng._dispatch_span = lambda kind, active, n, *a, **kw: (
+        steps.add((kind, n)), real(kind, active, n, *a, **kw))[1]
+    out = serve(eng, prompts, [40, 4, 40])
+    for (tokens, _), (whole, _), n in zip(out, want, (40, 4, 40)):
+        assert np.array_equal(tokens, whole[:n])
+    mgr = eng.state_mgr
+    assert mgr.allocator.free_blocks == mgr.allocator.total_blocks
+    assert steps == {("chunk", 0), ("fused", count),
+                     ("decode", ENGINE["decode_steps_per_dispatch"])}
+    assert eng.telemetry_snapshot()["fused_dispatches"] > 4
+
+
 def test_engine_on_the_kernel_path_equals_reference(model, params, mixed):
     """``paged_kernel=True``: chunks through the chunk kernel (off the
     TPU "auto" keeps a chunk dense, and the rule follows the attention),

@@ -562,7 +562,10 @@ class TestRouterTelemetry:
                              # + ISSUE 30's, what live sequences hold
                              "cache_bytes_per_live_token",
                              # + ISSUE 35's, the chained decode dispatch
-                             "decode_chain_share", "late_stop_share"}
+                             "decode_chain_share", "late_stop_share",
+                             # + ISSUE 45's, the dispatches that hold a
+                             # chunk beside decode steps of their own
+                             "fused_dispatches"}
 
     def test_per_class_latency_windows_are_bounded(self):
         """A server that runs for a day must not append for a day: the

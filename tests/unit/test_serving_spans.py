@@ -269,6 +269,61 @@ def test_chain_counters_are_host_arithmetic():
     assert snap["late_stop_share"] == 0.125
 
 
+@pytest.mark.parametrize("count", [engine_v2._FUSED_STEPS, 3])
+def test_fused_span_says_the_steps_it_ran(monkeypatch, count):
+    """A fused dispatch's ``steps`` is the engine's count for a chunk's
+    company (ISSUE 45), not the config's: ``active`` x ``steps`` is the
+    tokens its decode part returned plus those it dropped (a budget that
+    ended inside it), and ``fused_dispatches`` of the telemetry counts
+    such dispatches."""
+    st = ServingTelemetry()
+    assert "fused_dispatches" not in st.percentiles()
+    st.on_fused_dispatch()
+    st.on_fused_dispatch()
+    assert st.percentiles()["fused_dispatches"] == 2
+    monkeypatch.setattr(engine_v2, "_FUSED_STEPS", count)
+    router, engine = _router(16)
+    events, real_span = [], engine_v2.span
+    real_post = engine._post_decode_tokens
+
+    def recording_span(name, **stats):
+        if name == "dstpu.engine.dispatch":
+            events.append(("span", stats))
+        return real_span(name, **stats)
+
+    def recording_post(batch, toks):
+        left = [q.max_new_tokens - len(q.generated)
+                for q in batch.seqs if q is not None]
+        out = real_post(batch, toks)
+        events.append(("post", dict(
+            steps=toks.shape[0], returned=len(out),
+            dropped=sum(max(0, toks.shape[0] - n) for n in left))))
+        return out
+
+    monkeypatch.setattr(engine_v2, "span", recording_span)
+    monkeypatch.setattr(engine, "_post_decode_tokens", recording_post)
+    # all at once: prompts stream in beside one, two and three decoders
+    rng = np.random.RandomState(3)
+    for n, new in zip((7, 30, 12, 40, 9, 25), (9, 5, 14, 6, 11, 7)):
+        router.put(rng.randint(1, 250, size=n).astype(np.int32),
+                   max_new_tokens=new)
+    while router.has_work:
+        router.step()
+    # a fused dispatch settles what was unread before its span opens, so
+    # the post that follows the span is its own
+    fused = [(st, events[i + 1][1]) for i, (what, st) in enumerate(events)
+             if what == "span" and st["kind"] == "fused"]
+    assert {st["active"] for st, _ in fused} >= {1, 2, 3}
+    for st, post in fused:
+        assert st["steps"] == post["steps"] == count
+        assert st["active"] * count == post["returned"] + post["dropped"]
+    assert sum(post["dropped"] for _, post in fused) > 0
+    assert engine.telemetry_snapshot()["fused_dispatches"] == len(fused)
+    plain = {st["steps"] for what, st in events
+             if what == "span" and st["kind"] == "decode"}
+    assert plain == {_BASE["decode_steps_per_dispatch"]}
+
+
 def test_late_steps_ride_the_span_that_reads_them(monkeypatch):
     """A ends by an EOS in the middle of a dispatch; the dispatch behind it
     ran for A too. The span under which THAT one is read says so, every
@@ -397,11 +452,15 @@ def test_kv_write_counter(monkeypatch, splitfuse_tokens):
     monkeypatch.setattr(engine.state_mgr, "decode_batch", recording_batch)
     monkeypatch.setattr(engine_v2, "span", recording_span)
     _serve(router)
-    BS, slots, steps, C = 8, 4, 2, splitfuse_tokens
+    BS, slots, C = 8, 4, splitfuse_tokens
     decoding = [st for st in stats if st["steps"]]
     assert len(batches) == len(decoding) > N_REQUESTS
     assert (len(decoding) < len(stats)) == bool(C)      # chunk-only ones
     for st, (lengths, tables) in zip(decoding, batches):
+        # the config's of a plain decode, the engine's own of a fused one
+        steps = st["steps"]
+        assert steps == (engine_v2._FUSED_STEPS if st["kind"] == "fused"
+                         else _BASE["decode_steps_per_dispatch"])
         live = sum(bool(tables[b, min((lengths[b] + t) // BS,
                                       tables.shape[1] - 1)])
                    for b in range(slots) for t in range(steps))
@@ -452,9 +511,7 @@ def test_expert_kernel_counter(monkeypatch, knob, splitfuse_tokens):
 
     monkeypatch.setattr(engine_v2, "span", recording_span)
     _serve(router)
-    layers, steps = 2, 2
-    calls_of = {"decode": layers * steps, "chunk": layers,
-                "fused": layers * (steps + 1), "prefill": layers}
+    layers = 2
     seen, kinds = set(), set()
     for st in stats:
         kind = st.get("kind", "prefill")
@@ -462,7 +519,8 @@ def test_expert_kernel_counter(monkeypatch, knob, splitfuse_tokens):
         first = program not in seen
         seen.add(program)
         kinds.add(kind)
-        want = 0 if first or knob == "dense" else calls_of[kind]
+        want = 0 if first or knob == "dense" else layers * (
+            st.get("steps", 0) + (kind != "decode"))
         assert st["expert_calls"] == want, st
         assert st["expert_kernel_calls"] == (want if knob is True else 0), st
         # no gated delta rule in either family: 0 of 0 (ISSUE 42)

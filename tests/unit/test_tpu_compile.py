@@ -21,6 +21,7 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from deepspeed_tpu.inference.v2.engine_v2 import _FUSED_STEPS
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.ops.pallas.fused_ce import unembed_logits_stats
 from deepspeed_tpu.ops.pallas.gated_delta_rule import (
@@ -690,15 +691,16 @@ def test_chunk_tile_at_thirty_heads_of_128(v5e, monkeypatch, block_c, fits):
 
 def _olmo_hybrid_programs(model):
     """The cell's three programs as the engine composes them: a chunk into
-    one slot, 8 decode steps over every slot, and both in one (fused)."""
+    one slot, 8 decode steps over every slot, and a chunk beside the
+    engine's count of decode steps for its company in one (fused)."""
     def chunk(params, cache, ids, tb, to, start, n, table, slot):
         logits, cache = model.apply_paged_chunk(
             params, ids, cache, tb, to, start, n, table, slot)
         return jnp.argmax(logits, axis=-1), cache
 
-    def decode(params, cache, tokens, lengths, tables):
+    def decode(params, cache, tokens, lengths, tables, steps=8):
         toks = []
-        for _ in range(8):
+        for _ in range(steps):
             logits, cache = model.apply_paged_decode(
                 params, tokens, lengths, cache, tables)
             tokens = jnp.argmax(logits, axis=-1).astype(i32)
@@ -710,7 +712,8 @@ def _olmo_hybrid_programs(model):
               lengths, tables):
         c_tok, cache = chunk(params, cache, ids, tb, to, start, n, table,
                              slot)
-        toks, cache = decode(params, cache, tokens, lengths, tables)
+        toks, cache = decode(params, cache, tokens, lengths, tables,
+                             _FUSED_STEPS)
         return c_tok, toks, cache
 
     c = [((1, OH_C), i32), ((OH_C,), i32), ((OH_C,), i32), ((), i32),
@@ -813,7 +816,8 @@ def test_olmo_hybrid_programs_fit_the_chip(v5e, monkeypatch, program):
     calls = compiled.as_text().count('custom_call_target="tpu_custom_call"')
     # a program pass: the full layer's K/V write and paged read, and the
     # three linear layers' rule (the chunk kernel, the step kernel)
-    assert calls == {"chunk": 5, "decode_x8": 40, "fused": 45}[program]
+    assert calls == {"chunk": 5, "decode_x8": 40,
+                     "fused": 5 * (1 + _FUSED_STEPS)}[program]
     temp = compiled.memory_analysis().temp_size_in_bytes
     _, whole_params, whole_cache = trees(cell)
     assert abs(_nbytes(whole_params) - 8.20e9) < 0.01e9
@@ -831,15 +835,16 @@ DS_SLOTS, DS_NB, DS_MB, DS_C = 16, 4096, 264, 1024
 
 def _deepseek_programs(model):
     """The cell's three programs as the engine composes them: a chunk, 8
-    decode steps over every slot, and both in one (fused)."""
+    decode steps over every slot, and a chunk beside the engine's count of
+    decode steps for its company in one (fused)."""
     def chunk(params, cache, ids, tb, to, start, n, table):
         logits, cache = model.apply_paged_chunk(
             params, ids, cache, tb, to, start, n, table)
         return jnp.argmax(logits, axis=-1), cache
 
-    def decode(params, cache, tokens, lengths, tables):
+    def decode(params, cache, tokens, lengths, tables, steps=8):
         toks = []
-        for _ in range(8):
+        for _ in range(steps):
             logits, cache = model.apply_paged_decode(
                 params, tokens, lengths, cache, tables)
             tokens = jnp.argmax(logits, axis=-1).astype(i32)
@@ -850,7 +855,8 @@ def _deepseek_programs(model):
     def fused(params, cache, ids, tb, to, start, n, table, tokens, lengths,
               tables):
         c_tok, cache = chunk(params, cache, ids, tb, to, start, n, table)
-        toks, cache = decode(params, cache, tokens, lengths, tables)
+        toks, cache = decode(params, cache, tokens, lengths, tables,
+                             _FUSED_STEPS)
         return c_tok, toks, cache
 
     c = [((1, DS_C), i32), ((DS_C,), i32), ((DS_C,), i32), ((), i32),
@@ -904,7 +910,8 @@ def test_deepseek_share_programs_fit_the_chip(v5e, monkeypatch, program):
     # one expert layer a pass through the forward grouped kernel, and a
     # chunk's two latent layers through the selected read's
     assert text.count('custom_call_target="tpu_custom_call"') \
-        == {"chunk": 1 + 2, "decode_x8": 8, "fused": 9 + 2}[program]
+        == {"chunk": 1 + 2, "decode_x8": 8,
+            "fused": 1 + _FUSED_STEPS + 2}[program]
     # the XLA read's scores, (128 heads, 1,024 queries, 512 keys) float32
     assert not re.search(r"f32\[(1,)?128,1024,512\]", text)
     # no whole-pool copy of a latent pool
