@@ -37,18 +37,15 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ...utils import groups
 from ...utils.groups import TopologyConfig
 from ...utils.logging import log_dist
+from ...monitor.tag_schema import KERNEL_SHARES
 from ...monitor.telemetry import span
-from ...ops.pallas.paged_attention import (as_pools, decode_grid_steps,
-                                           kv_write_live_rows,
-                                           like_boundary, pool_block_dims)
+from ...ops.pallas.paged_attention import (as_pools, like_boundary,
+                                           pool_block_dims)
 from ..utils import shard_params
 from .ragged import DSStateManager, RaggedBatchWrapper
 
 # the programs a ``dstpu.engine.dispatch`` span of each kind calls, by the
 # names ``_noting_calls`` keeps their counts under
-# cache keys of the pools under the block tables, which the allocator's
-# blocks pay for: K / V, and a latent layer's two (models/paged.py)
-_BLOCK_KEYS = ("k", "v", "lat", "idx")
 _PROGRAMS_OF_KIND = {"decode": ("decode",), "offload": ("decode",),
                      "fused": ("fused",), "chunk": ("chunk",),
                      "spec": ("propose", "verify")}
@@ -290,22 +287,30 @@ class InferenceEngineV2:
                 monitor=monitor, interval=config.telemetry_interval)
         mcfg = model.config
         self.max_seq_len = mcfg.max_seq_len
+        BS = config.kv_block_size
+        self.max_blocks_per_seq = -(-self.max_seq_len // BS)
+        dtype = jnp.dtype(config.dtype)
+        self.dtype = dtype
         # a model that keeps state by batch slot (a recurrent state, a
         # window's ring: models/paged.py) is told the slot of every
-        # prefill and chunk, and cannot have what assumes that a cache
-        # is length-masked KV under a block table
+        # prefill and chunk
         self._slot_state = bool(getattr(model, "slot_state", False))
-        # a model whose layers cache a compressed latent read through a
-        # per-query selection (models/paged.py, LATENT) is under the block
-        # tables as K/V is, but nothing that handles blocks as K and V
-        # pools (prefix cache, a draft's rollback, offload, transfer) has
-        # learnt its leaves
-        from ...models.paged import LATENT, STATE, decode_kernel_calls, \
-            geometry
-        self._latent_layers = geometry(model).kinds.count(LATENT)
-        if self._slot_state or self._latent_layers:
-            self._refuse_by_cache_kind(config, draft_model,
-                                       bool(self._latent_layers))
+        # what the model's cache holds, counts and cannot have, whatever
+        # its kinds
+        from ...models.paged import Account
+        self._account = Account(model, config.max_batch_size,
+                                self.max_blocks_per_seq, BS, dtype)
+        # refused by name before anything is built; every "auto" resolves
+        # to off
+        if config.prefix_cache is True:
+            self._refuse("prefix_cache")
+        if config.spec_draft is True or (
+                draft_model is not None and config.spec_draft is not False):
+            # a draft model IS the opt-in to speculation
+            self._refuse("spec_draft")
+        if config.kv_host_offload:
+            self._refuse("kv_host_offload")
+        if self._account.refusal("spec_draft"):
             draft_model = None            # spec_draft=False: off
         # blocks a slot of a window layer's ring: enough for the largest
         # step this engine's programs take past position 0 (a chunk; a
@@ -341,8 +346,6 @@ class InferenceEngineV2:
         self.topology = topology
         self.mesh = topology.mesh
 
-        BS = config.kv_block_size
-        self.max_blocks_per_seq = -(-self.max_seq_len // BS)
         num_blocks = config.num_kv_blocks or (
             1 + config.max_batch_size * self.max_blocks_per_seq)
         self.state_mgr = DSStateManager(
@@ -355,7 +358,7 @@ class InferenceEngineV2:
         # disabled == byte-identical to the pre-cache engine)
         self.prefix_cache = None
         pc_on, pc_min_match, pc_watermark = self._resolve_prefix_cache(
-            mcfg, num_blocks)
+            num_blocks)
         if pc_on:
             from .prefix_cache import PrefixCache
             self.prefix_cache = PrefixCache(
@@ -367,16 +370,6 @@ class InferenceEngineV2:
             if self.telemetry is not None:
                 self.telemetry.attach_prefix_cache(self.prefix_cache)
 
-        dtype = jnp.dtype(config.dtype)
-        self.dtype = dtype
-        # (table entries a grid step of the decode kernel takes, the
-        # layers that call it by their window): what the dispatch spans
-        # count its steps by
-        self._decode_calls = decode_kernel_calls(
-            self.model, self.max_blocks_per_seq, BS, dtype)
-        # layers that keep a recurrent state a slot: what the dispatch
-        # spans count state updates and scanned rows by
-        self._state_layers = geometry(self.model).kinds.count(STATE)
         self.params, self.param_shardings = shard_params(
             model, self.mesh, dtype, params=params, seed=config.seed,
             topology=topology,
@@ -390,18 +383,7 @@ class InferenceEngineV2:
             device_blocks = config.device_kv_blocks
         self.cache, self._cache_sh = self._new_paged_cache(
             model, device_blocks)
-        # what a live sequence holds of it: bytes a block of the pools
-        # under the block tables (every layer's), and bytes a slot of
-        # whatever else the model keeps (rings, recurrent state)
-        self._block_bytes = sum(
-            math.prod(p.shape[-3:]) * p.dtype.itemsize
-            for key in ("k", "v") for p in self.cache.get(key, ())) \
-            + sum(math.prod(p.shape[-2:]) * p.dtype.itemsize
-                  for key in ("lat", "idx") for p in self.cache.get(key, ()))
-        self._slot_bytes = sum(
-            p.nbytes for key, sub in self.cache.items()
-            if key not in _BLOCK_KEYS for p in jax.tree.leaves(sub)) \
-            // config.max_batch_size
+        self._account.size(self.cache)
         if config.kv_host_offload:
             from .kv_offload import OffloadKVPool
             self.kv_pool = OffloadKVPool(
@@ -465,11 +447,9 @@ class InferenceEngineV2:
         self._rng = jax.random.key(config.seed + 23)
         self._prefill_jit = None
         self._decode_jit = None
-        # program -> (expert layer calls, those of them through a Pallas
-        # grouped kernel), noted when the program is traced
-        self._expert_calls = {}
-        self._rule_calls = {}
-        self._latent_reads = {}
+        # program -> {mechanism: (its calls, those of them through a Pallas
+        # kernel)}, noted when the program is traced
+        self._calls = {}
         self._splitfuse_jit = None
         self._chunk_jit = None        # chunk-only (no decoders running)
         self._cow_jit = None          # prefix-cache partial-tail copy
@@ -611,53 +591,15 @@ class InferenceEngineV2:
             or self._unread is not None or bool(self._settled)
 
     # ------------------------------------------------------------- programs
-    @staticmethod
-    def _refuse_by_cache_kind(config, draft_model, latent):
-        """What cannot be right for a model that keeps state by slot, or
-        whose blocks hold a latent cache (``latent``), refused by name;
-        every "auto" resolves to off."""
-        if latent:
-            why = ("the model's blocks hold a latent cache read through a "
-                   "per-query selection (models/paged.py, LATENT), which ")
-            refusals = (
-                "the prefix cache's copy-on-write and block reuse, written "
-                "for K and V pools, have not learnt",
-                "a draft model's verify pass and rollback_spec have no "
-                "program for",
-                "the offload tier, which pages K and V pools, does not "
-                "page")
-        else:
-            why = ("the model keeps recurrent / window state by batch slot "
-                   "(slot_state), which ")
-            refusals = (
-                "a cached block of KV does not bring back — a prefix hit "
-                "would resume from a state nobody kept",
-                "rollback_spec cannot take back once the rejected tokens "
-                "have moved it",
-                "lives outside the block pool the offload tier pages")
-        if config.prefix_cache is True:
-            raise ValueError("prefix_cache=True: " + why + refusals[0])
-        if config.spec_draft is True or (
-                draft_model is not None and config.spec_draft is not False):
-            # a draft model IS the opt-in to speculation
-            raise ValueError("spec_draft=True / a draft model: " + why
-                             + refusals[1])
-        if config.kv_host_offload:
-            raise ValueError("kv_host_offload: " + why + refusals[2])
+    def _refuse(self, feature, error=ValueError):
+        """Raise where the model's cache cannot have ``feature``, with
+        the reason its kinds give (models/paged.py): ``ValueError`` at
+        build, ``RuntimeError`` from a handoff call."""
+        why = self._account.refusal(feature)
+        if why is not None:
+            raise error(why)
 
-    def _refuse_kv_transfer(self):
-        if self._slot_state:
-            raise RuntimeError(
-                "disaggregated kv_transfer: the model keeps recurrent / "
-                "window state by batch slot (slot_state), which the block "
-                "payloads of a KV handoff do not carry")
-        if self._latent_layers:
-            raise RuntimeError(
-                "disaggregated kv_transfer: the model's blocks hold a "
-                "latent cache (models/paged.py, LATENT), which the K / V "
-                "payloads of a KV handoff do not carry")
-
-    def _resolve_prefix_cache(self, mcfg, num_blocks):
+    def _resolve_prefix_cache(self, num_blocks):
         """Resolve (enabled, min_match_blocks, evict_watermark_pct) for
         the prefix cache. Model/config combinations the cache cannot
         serve correctly refuse LOUDLY when forced on and resolve off
@@ -666,18 +608,8 @@ class InferenceEngineV2:
         hand-set values (disabled, min-match 1, on-demand eviction), so
         a cold-cache engine is byte-identical to prefix_cache=False."""
         cfg = self.config
-        if self._slot_state or self._latent_layers:
+        if self._account.refusal("prefix_cache"):
             return False, 1, 0        # True was refused at build
-        windows = tuple(getattr(mcfg, "attn_layer_windows", ()) or ())
-        if any(windows):
-            if cfg.prefix_cache is True:
-                raise ValueError(
-                    "prefix_cache=True on a sliding-window model "
-                    "(attn_layer_windows set): a cached block's KV is "
-                    "position-valid only inside each layer's window, so "
-                    "reusing it under a shifted suffix serves wrong "
-                    "attention — disable prefix_cache for this model")
-            return False, 1, 0
         if cfg.prefix_cache is False or cfg.kv_host_offload:
             # explicit off, or offload (True+offload raised in config
             # validation; "auto" resolves off)
@@ -718,30 +650,23 @@ class InferenceEngineV2:
             draft._weight_quant_fused = False
 
     def _noting_calls(self, body, key=None):
-        """``body`` — a program's traced function — noting how many expert
-        layer calls (MoE layers x steps) its trace makes and how many of
-        them took a Pallas grouped kernel (moe/sharded_moe.py
-        ``counting_expert_calls``; a dense model makes none), and the same
-        of the gated delta rule's calls (linear layers x calls;
-        ops/gated_delta_rule.py ``counting_rule_calls``) and of the
-        selected reads of a latent cache (latent layers x steps;
-        models/paged.py ``counting_latent_reads``), under its name
-        or ``key(*args)``. Known once the program is traced: the dispatch
-        that traces it still reads 0 of 0."""
-        from ...models.paged import counting_latent_reads
-        from ...moe.sharded_moe import counting_expert_calls
-        from ...ops.gated_delta_rule import counting_rule_calls
+        """``body`` — a program's traced function — noting the calls its
+        trace makes of each mechanism that has a Pallas form and another,
+        and how many of them took the kernel (ops/pallas/_common.py
+        ``counting_calls``: expert layer calls, MoE layers x steps; the
+        gated delta rule's, linear layers x calls; the selected reads of
+        a latent cache, latent layers x steps; a model with none of them
+        notes nothing), under its name or ``key(*args)``. Known once the
+        program is traced: the dispatch that traces it still reads 0 of
+        0."""
+        from ...ops.pallas._common import counting_calls
 
         @functools.wraps(body)
         def program(*args):
-            with counting_expert_calls() as experts, \
-                    counting_rule_calls() as rules, \
-                    counting_latent_reads() as reads:
+            with counting_calls() as counts:
                 out = body(*args)
-            name = body.__name__ if key is None else key(*args)
-            self._expert_calls[name] = tuple(experts)
-            self._rule_calls[name] = tuple(rules)
-            self._latent_reads[name] = tuple(reads)
+            self._calls[body.__name__ if key is None else key(*args)] = \
+                counts
             return out
         return program
 
@@ -758,12 +683,12 @@ class InferenceEngineV2:
         model's decode step will run the paged kernel
         (``models/paged.uses_decode_kernel``, the question its trace
         asks, of the same shapes; off-TPU the kernels are interpreted)
-        the pools under the block tables (``k`` / ``v``) are born in the
-        shape that keeps them in the kernels' layout
+        the pools those kernels read (``models/paged.KERNEL_POOLS``) are
+        born in the shape that keeps them in the kernels' layout
         (:func:`pool_block_dims`). A model with slot state sizes the rest
         of its cache from the slots and ring blocks it is given, and
         those leaves keep the shape it gives them."""
-        from ...models.paged import uses_decode_kernel
+        from ...models.paged import KERNEL_POOLS, uses_decode_kernel
         from ...ops.pallas._common import interpret_default
         cfg = self.config
         extra = dict(slots=cfg.max_batch_size,
@@ -774,16 +699,16 @@ class InferenceEngineV2:
             return model.init_paged_cache(n, cfg.kv_block_size,
                                           dtype=self.dtype, **extra)
 
-        pools = jax.eval_shape(lambda: init(1)).get("k")
-        # a latent cache's pools (lat / idx) are read by XLA and keep the
-        # shape the model gives them
+        pools = jax.eval_shape(lambda: init(1)).get(KERNEL_POOLS[0])
+        # a cache with no such pool is read by XLA and keeps the shape
+        # the model gives it
         _, _, BS, hd = pools[0].shape if pools \
             else (0, 0, cfg.kv_block_size, 128)
         kernel = not interpret_default() and uses_decode_kernel(
             model, cfg.max_batch_size, self.max_blocks_per_seq, BS,
             self.dtype)
         dims = pool_block_dims(num_blocks, hd, kernel)
-        lead = {"k": len(dims) - 1, "v": len(dims) - 1}
+        lead = dict.fromkeys(KERNEL_POOLS, len(dims) - 1)
 
         def by_key(fn, tree, **kw):
             return {key: jax.tree.map(
@@ -1093,7 +1018,7 @@ class InferenceEngineV2:
         chunked prefill to the last prompt token, posts the first
         generated token, and then waits for its KV handoff to a decode
         replica instead of decoding locally."""
-        self._refuse_kv_transfer()
+        self._refuse("kv_transfer", RuntimeError)
         self._settle()
         self._decode_hold.add(uid)
 
@@ -1152,7 +1077,7 @@ class InferenceEngineV2:
         colocated decode dispatch would attend, because the last
         generated token's KV is written by the decode step that
         consumes it."""
-        self._refuse_kv_transfer()
+        self._refuse("kv_transfer", RuntimeError)
         if self.kv_pool is not None:
             raise RuntimeError(
                 "KV handoff is incompatible with kv_host_offload: "
@@ -1201,7 +1126,7 @@ class InferenceEngineV2:
         at the ORIGINAL submit stamp. Returns the uid."""
         from ...runtime.checkpoint_engine import serialization as ser
         from .kv_transfer import KVWireError
-        self._refuse_kv_transfer()
+        self._refuse("kv_transfer", RuntimeError)
         if self.kv_pool is not None:
             raise RuntimeError(
                 "KV handoff is incompatible with kv_host_offload: "
@@ -1300,104 +1225,51 @@ class InferenceEngineV2:
         decode-bearing dispatch also feeds the occupancy counter.
         ``chained`` / ``late_steps``: see :meth:`_plain_decode`.
         ``active``: a count, or — with ``batch`` = (lengths, block
-        tables), where the dispatch runs the paged-decode kernel over
-        the batch — the live slots' mask, and the span then says how
-        much of the block table the decode kernel visits, in how many
-        grid steps, and how many of the rows offered to the KV write are
-        live; the chunk's ``chunk_tokens`` of ``chunk_rows`` count with
-        them (host arithmetic, no device read)."""
+        tables), where the dispatch runs the decode programs over the
+        batch — the live slots' mask. What the call does to the cache
+        (the decode kernel's grid, the KV write's rows, state updates,
+        a selection's keys) is the account's to count
+        (models/paged.py ``Account.dispatch``)."""
         slots = self.config.max_batch_size
-        grid_steps = kernel_steps = table_entries = 0
-        write_rows, write_rows_offered = chunk_tokens, chunk_rows
-        index_keys, attended_keys = self._selected_read(
-            chunk_start, chunk_tokens)
-        if batch is not None and self._latent_layers:
-            # each live slot's token a decode step, from its length on
-            live = np.asarray(batch[0])[np.asarray(active, bool)]
-            decode = self._selected_read(live, steps)
-            index_keys += decode[0]
-            attended_keys += decode[1]
-        if batch is not None and self._decode_calls[1]:
-            lengths, tables = batch
-            MB, BS = self.max_blocks_per_seq, self.state_mgr.block_size
-            entries_per_step, windows = self._decode_calls
-
-            def per_call(per_step):
-                # a kernel call's, the layers' mean where their windows
-                # differ, over the dispatch's decode steps
-                return round(sum(layers * decode_grid_steps(
-                    lengths, active, MB, BS, w, steps, per_step)
-                    for w, layers in windows.items())
-                    / sum(windows.values()))
-
-            grid_steps = per_call(1)
-            kernel_steps = grid_steps if entries_per_step == 1 \
-                else per_call(entries_per_step)
-            table_entries = steps * slots * MB
-            write_rows += kv_write_live_rows(lengths, tables, BS, steps)
-            write_rows_offered += steps * slots
+        cache = self._account.dispatch(
+            *(batch or (None, None)), active, steps, chunk_start,
+            chunk_tokens, chunk_rows)
         active = int(np.sum(active))
-        state_updates = active * steps * self._state_layers
-        rule_rows = chunk_rows * self._state_layers
         calls = self._calls_of(*_PROGRAMS_OF_KIND[kind])
         if self.telemetry is not None:
             if steps:
-                self.telemetry.on_decode_batch(active, slots, grid_steps,
-                                               table_entries, kernel_steps)
-            self.telemetry.on_kv_write(write_rows, write_rows_offered)
+                self.telemetry.on_decode_batch(
+                    active, slots, cache["grid_steps"],
+                    cache["table_entries"], cache["kernel_steps"])
+            self.telemetry.on_kv_write(cache["write_rows"],
+                                       cache["write_rows_offered"])
             if kind == "decode":
                 self.telemetry.on_plain_decode(chained, steps * active)
             elif kind == "fused":
                 self.telemetry.on_fused_dispatch()
         return span("dstpu.engine.dispatch", kind=kind, active=active,
                     slots=slots, steps=steps, chunk_tokens=chunk_tokens,
-                    chunk_start=chunk_start, grid_steps=grid_steps,
-                    table_entries=table_entries,
-                    kernel_steps=kernel_steps, write_rows=write_rows,
-                    write_rows_offered=write_rows_offered,
-                    chained=chained, late_steps=late_steps,
-                    state_updates=state_updates, rule_rows=rule_rows,
-                    index_keys=index_keys, attended_keys=attended_keys,
-                    **calls)
-
-    def _selected_read(self, start, tokens):
-        """(index_keys, attended_keys) of ``tokens`` consecutive real
-        query tokens from position ``start`` (an array: one run a live
-        slot) in every latent layer: the causal keys the indexer scores,
-        position + 1 a query, and the keys attended after the selection,
-        ``min(position + 1, index_topk)``. Host arithmetic; (0, 0) on a
-        model with no such layer."""
-        if not self._latent_layers or not tokens:
-            return 0, 0
-        ctx = np.asarray(start, np.int64)[..., None] + 1 + np.arange(tokens)
-        topk = self.model.config.index_topk
-        return (int(ctx.sum()) * self._latent_layers,
-                int(np.minimum(ctx, topk).sum()) * self._latent_layers)
+                    chunk_start=chunk_start, chained=chained,
+                    late_steps=late_steps, **cache, **calls)
 
     def _calls_of(self, *programs):
         """What one call of each of ``programs`` makes, as their traces
-        noted it, under the names the dispatch and prefill spans say it by:
-        ``expert_calls`` (expert layer calls) / ``expert_kernel_calls``
-        (those through a Pallas grouped kernel), ``rule_calls`` (calls of
-        the gated delta rule) / ``rule_kernel_calls``, ``latent_read_calls``
-        (selected reads of a latent cache) / ``latent_read_kernel_calls``;
-        fed to the telemetry's ``moe_kernel_share``, ``rule_kernel_share``
-        and ``latent_kernel_share``."""
-        def total(noted):
-            counts = [noted.get(p, (0, 0)) for p in programs]
-            return sum(c for c, _ in counts), sum(k for _, k in counts)
-
-        experts, rules, reads = (total(self._expert_calls),
-                                 total(self._rule_calls),
-                                 total(self._latent_reads))
+        noted it, under the names the dispatch and prefill spans say it
+        by: ``<mechanism>_calls`` / ``<mechanism>_kernel_calls``, of each
+        mechanism of ``monitor/tag_schema.py`` ``KERNEL_SHARES`` (0 / 0
+        where no trace noted it), which the telemetry's
+        ``*_kernel_share`` keys are fed from."""
+        counts = {name: [0, 0] for name in KERNEL_SHARES}
+        for program in programs:
+            for name, (calls, kernel) in self._calls.get(
+                    program, {}).items():
+                pair = counts.setdefault(name, [0, 0])
+                pair[0] += calls
+                pair[1] += kernel
         if self.telemetry is not None:
-            self.telemetry.on_expert_calls(*experts)
-            self.telemetry.on_rule_calls(*rules)
-            self.telemetry.on_latent_reads(*reads)
-        return dict(zip(
-            ("expert_calls", "expert_kernel_calls", "rule_calls",
-             "rule_kernel_calls", "latent_read_calls",
-             "latent_read_kernel_calls"), experts + rules + reads))
+            self.telemetry.on_calls(counts)
+        return {f"{name}{stat}": n for name, pair in counts.items()
+                for stat, n in zip(("_calls", "_kernel_calls"), pair)}
 
     def _step_splitfuse_chunk(self):
         """Run one fused dispatch: the next chunk of the oldest
@@ -1545,12 +1417,9 @@ class InferenceEngineV2:
         bucket = self.config.prompt_bucket
         T = len(req.prompt)
         T_pad = -(-max(T, 1) // bucket) * bucket
-        calls = self._calls_of(("prefill", T_pad))
-        index_keys, attended_keys = self._selected_read(0, T)
         with span("dstpu.engine.prefill", uid=req.uid, tokens=T,
-                  padded=T_pad, rule_rows=T_pad * self._state_layers,
-                  index_keys=index_keys, attended_keys=attended_keys,
-                  **calls):
+                  padded=T_pad, **self._account.prefill(T, T_pad),
+                  **self._calls_of(("prefill", T_pad))):
             with span("dstpu.engine.build"):
                 ids = np.zeros((1, T_pad), np.int32)
                 ids[0, :T] = req.prompt
@@ -1700,7 +1569,8 @@ class InferenceEngineV2:
         deltas inside one multi-step dispatch are meaningless)."""
         tel = self.telemetry
         live, blocks, tokens = self.state_mgr.held()
-        cache_bytes = blocks * self._block_bytes + live * self._slot_bytes
+        cache_bytes = blocks * self._account.block_bytes \
+            + live * self._account.slot_bytes
         # the counters ride the span so that whoever reads the trace has
         # them on the profiler's clock (cached floats; 0 with telemetry
         # off)

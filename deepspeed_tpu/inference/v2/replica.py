@@ -71,7 +71,8 @@ class Replica:
         if role != "colocated":
             # a phase role hands KV over the wire: refused at build for
             # a model whose state the block payloads do not carry
-            getattr(engine, "_refuse_kv_transfer", lambda: None)()
+            getattr(engine, "_refuse", lambda *a: None)(
+                "kv_transfer", RuntimeError)
         self.name = name
         self.engine = engine
         self.role = role

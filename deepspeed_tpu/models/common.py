@@ -15,6 +15,19 @@ def resolve_flash(value):
     return bool(value)
 
 
+def _pieces(x, dtype):
+    """float32 x as a stack of pieces of ``dtype`` that sum to it: itself
+    where the dtype is its own, else (hi, lo), x to ~16 bits. ``hi`` is
+    rounded by ``reduce_precision``: a cast there and back is excess
+    precision to the TPU compiler, which takes it out, and ``lo`` with it
+    (my chip run, PR 30: the products then saw ``hi`` alone)."""
+    if dtype == x.dtype:
+        return x[None]
+    info = jnp.finfo(dtype)
+    hi = lax.reduce_precision(x, info.nexp, info.nmant)
+    return jnp.stack([hi, x - hi]).astype(dtype)
+
+
 def constrain_fn():
     """Sharding constraints are advisory: no-ops without an active mesh
     (single-device tests / eager use) and under fully-manual meshes

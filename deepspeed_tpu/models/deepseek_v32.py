@@ -45,7 +45,7 @@ logit by a large part of what the mechanism itself contributes: a bfloat16
 program read 0.7 - 0.8 standard deviations from the float32 reference on
 the chip, as far as the wrong models (PR 43, PERF.md section 4). So the
 residual stream is float32 and every weight product takes it as TWO
-bfloat16 pieces (``phi4flash._pieces``: x to ~16 bits; one product, the
+bfloat16 pieces (``common._pieces``: x to ~16 bits; one product, the
 weight read once); the index queries, the cached index keys (``idx`` is a
 float32 pool) and their products (three bfloat16 passes) keep that
 precision, and the router, the index scores and the selection are float32.
@@ -67,8 +67,8 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from . import paged
+from .common import _pieces
 from .llama import _rms_norm
-from .phi4flash import _pieces
 
 
 @dataclass(frozen=True)

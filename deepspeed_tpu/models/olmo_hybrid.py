@@ -37,8 +37,8 @@ step's live slots (``step.active``), the ``ssm`` leaf aliased and written
 in place, a dead slot's row neither read nor written. A step that runs no
 kernels, and ``apply``, run the XLA forms, which are the kernels'
 reference. No setting chooses; the programs count what they took
-(``note_rule_call``: ``rule_calls`` / ``rule_kernel_calls`` on the engine's
-spans).
+(``note_call("rule", ...)``: ``rule_calls`` / ``rule_kernel_calls`` on the
+engine's spans).
 
 **The residual stream is float32**; every weight product takes bfloat16
 rows and the bfloat16 weight and accumulates in float32, as the other
@@ -61,7 +61,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..ops.gated_delta_rule import chunk_rule, note_rule_call, step_rule
+from ..ops.gated_delta_rule import chunk_rule, step_rule
+from ..ops.pallas._common import note_call
 from ..ops.pallas.gated_delta_rule import (chunk_rule_kernel, live_slot_list,
                                            step_rule_kernel)
 from . import paged
@@ -292,7 +293,7 @@ class OlmoHybrid:
         # dense forward of one token, a chunk program of one) has no list
         # of live slots: the XLA form
         kernel = kernel and (C > 1 or live is not None)
-        note_rule_call(kernel)
+        note_call("rule", kernel)
         if C == 1:
             with jax.named_scope("dstpu.gdn.step"):
                 rows = (q[:, 0], k[:, 0], v[:, 0], log_a[:, 0], b[:, 0], S0)

@@ -56,10 +56,14 @@ A layer's cache is one of five kinds (``Geometry.kinds``):
 ``None`` is a layer with no cache. Everything but ``KV`` and ``LATENT`` is
 indexed by the slot, which the chunk and prefill programs are therefore
 told.
+
+A serving engine names none of these. What it has to know of a model's
+cache (what a live sequence holds of it, what a program call counts over
+it, which serving features it cannot have) it asks :class:`Account`, whose
+tables (``_KEYS``, ``_BLOCK_AXES``, ``_REFUSALS``) are where a new kind
+says so.
 """
 
-import contextlib
-import contextvars
 import math
 from collections import Counter
 from dataclasses import dataclass
@@ -67,12 +71,14 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ..ops.pallas._common import note_call
 from ..ops.pallas.latent_attention import latent_chunk_attention
 from ..ops.pallas.paged_attention import (
     PAGED_CHUNK_BLOCK_C, alibi_slopes, decode_entries_per_step,
-    decode_work_list, kv_write_row_list, paged_chunk_attention,
-    paged_decode_attention,
+    decode_grid_steps, decode_work_list, kv_write_live_rows,
+    kv_write_row_list, paged_chunk_attention, paged_decode_attention,
     paged_decode_attention_reference, paged_kv_write, resolve_paged_chunk,
     resolve_paged_decode)
 
@@ -83,6 +89,9 @@ _CHUNK_TILE = 16 * 128 * 128
 # cache keys of each kind's leaves, one list entry a layer of that kind
 _KEYS = {KV: ("k", "v"), RING: ("ring_k", "ring_v"), STATE: ("conv", "ssm"),
          LATENT: ("lat", "idx")}
+# the pools the paged kernels read: what a serving engine allocates in the
+# kernels' layout (``pool_block_dims``)
+KERNEL_POOLS = _KEYS[KV]
 # keys a pass of a latent read takes at once: a chunk's scores are heads x
 # C x keys in float32, a decode step's heads x slots x keys
 _LATENT_KEYS = {"chunk": 512, "decode": 2048}
@@ -168,19 +177,6 @@ def uses_decode_kernel(model, B, MB, BS, dtype):
     return _decode_kernel(geometry(model), B, MB, BS, dtype)
 
 
-def decode_kernel_calls(model, MB, BS, dtype):
-    """What the engine's telemetry counts the kernel calls of ``model``'s
-    decode step by: the table entries of a slot one grid step takes
-    (``decode_entries_per_step`` of its geometry), and ``{window: the
-    layers that call it with that window}`` (layers with a paged table:
-    not one of recurrent state, nor one with no cache)."""
-    geom = geometry(model)
-    windows = Counter(w for w, kind in zip(geom.windows, geom.kinds)
-                      if kind in (KV, RING) or isinstance(kind, tuple))
-    return decode_entries_per_step(geom.n_kv_heads, BS, geom.d_head, dtype,
-                                   MB), dict(windows)
-
-
 def _chunk_kernel(geom, C, MB, BS):
     # ALiBi stays dense: the chunk kernel has no per-head bias input
     # (forced off BEFORE dispatch, so no search is paid for a tile the
@@ -220,26 +216,6 @@ def _latent_kernel(geom, C):
     if geom.kernel == "auto":
         return jax.default_backend() == "tpu"
     return bool(geom.kernel)
-
-
-# the tally a ``counting_latent_reads`` block is filling, if any
-_LATENT_READS = contextvars.ContextVar("dstpu_latent_reads", default=None)
-
-
-@contextlib.contextmanager
-def counting_latent_reads():
-    """Yields ``[reads, kernel_reads]``: the selected reads (latent layers x
-    steps, a chunk's or a decode step's) traced inside the block, and those
-    of them that are the Pallas kernel. Trace-time Python, as
-    ``ops/gated_delta_rule.py:counting_rule_calls``: a serving engine puts
-    it round a program's traced body, for its dispatch span
-    (latent_read_calls / latent_read_kernel_calls)."""
-    counts = [0, 0]
-    token = _LATENT_READS.set(counts)
-    try:
-        yield counts
-    finally:
-        _LATENT_READS.reset(token)
 
 
 def _dense_attention(geom, q, gk, gv, q_pos, frontier, window):
@@ -447,10 +423,7 @@ class _Step:
                     new.reshape(-1, new.shape[-1]).astype(pool.dtype))
             if not kernel:
                 expand = None
-            counts = _LATENT_READS.get()
-            if counts is not None:
-                counts[0] += 1
-                counts[1] += expand is not None
+            note_call("latent_read", expand is not None)
             return _latent_read(
                 self.cache["lat"][j], self.cache["idx"][j], tables,
                 self.q_pos, self.frontier, index_fn, read_fn, topk,
@@ -604,3 +577,177 @@ def batch_step(geom, cache, lengths, block_tables, C):
     step.n_valid = jnp.full((B,), C, jnp.int32)
     step.q_pos, step.frontier = linpos, lengths + C
     return step
+
+
+# ----------------------------------------------------------- the account
+# the kinds whose pools lie under the block tables, paid for by the
+# allocator's blocks, and the trailing axes of such a pool that are one block
+_BLOCK_AXES = {KV: 3, LATENT: 2}
+_BY_SLOT = ("the model keeps recurrent / window state by batch slot "
+            "(slot_state), which ")
+_BY_SELECTION = ("the model's blocks hold a latent cache read through a "
+                 "per-query selection (models/paged.py, LATENT), which ")
+# what the serving features that handle blocks as K and V pools cannot do
+# for a cache of another kind, kind x feature -> the reason a serving engine
+# raises with: "slot" is a model with a RING or STATE layer, "latent" one
+# with a LATENT layer, "window" one whose KV layers have windows of their own
+# (``attn_layer_windows``). A pair that is not here is served
+_REFUSALS = {
+    ("slot", "prefix_cache"):
+        "prefix_cache=True: " + _BY_SLOT + "a cached block of KV does not "
+        "bring back — a prefix hit would resume from a state nobody kept",
+    ("slot", "spec_draft"):
+        "spec_draft=True / a draft model: " + _BY_SLOT + "rollback_spec "
+        "cannot take back once the rejected tokens have moved it",
+    ("slot", "kv_host_offload"):
+        "kv_host_offload: " + _BY_SLOT + "lives outside the block pool the "
+        "offload tier pages",
+    ("slot", "kv_transfer"):
+        "disaggregated kv_transfer: " + _BY_SLOT + "the block payloads of a "
+        "KV handoff do not carry",
+    ("latent", "prefix_cache"):
+        "prefix_cache=True: " + _BY_SELECTION + "the prefix cache's "
+        "copy-on-write and block reuse, written for K and V pools, have not "
+        "learnt",
+    ("latent", "spec_draft"):
+        "spec_draft=True / a draft model: " + _BY_SELECTION + "a draft "
+        "model's verify pass and rollback_spec have no program for",
+    ("latent", "kv_host_offload"):
+        "kv_host_offload: " + _BY_SELECTION + "the offload tier, which pages "
+        "K and V pools, does not page",
+    ("latent", "kv_transfer"):
+        "disaggregated kv_transfer: the model's blocks hold a latent cache "
+        "(models/paged.py, LATENT), which the K / V payloads of a KV handoff "
+        "do not carry",
+    ("window", "prefix_cache"):
+        "prefix_cache=True on a sliding-window model (attn_layer_windows "
+        "set): a cached block's KV is position-valid only inside each "
+        "layer's window, so reusing it under a shifted suffix serves wrong "
+        "attention — disable prefix_cache for this model",
+}
+
+
+class Account:
+    """What a serving engine asks of ``model``'s cache without knowing its
+    kinds: what a live sequence holds of it (:meth:`size`), what a program
+    call counts over it (:meth:`dispatch`, :meth:`prefill`: host arithmetic
+    for the engine's spans, no device read) and which serving features it
+    cannot have (:meth:`refusal`). Built once an engine, from the model's
+    :class:`Geometry` and the engine's sizes: ``slots`` batch slots,
+    ``table_len`` entries a block table, ``block_size`` tokens a block,
+    the cache's ``dtype``."""
+
+    def __init__(self, model, slots, table_len, block_size, dtype):
+        geom = geometry(model)
+        self.slots, self.table_len, self.block_size = \
+            slots, table_len, block_size
+        # layers of each kind (a ``(SHARED, j)`` layer under ``SHARED``)
+        self.layers = Counter(k[0] if isinstance(k, tuple) else k
+                              for k in geom.kinds)
+        # the decode kernel's calls: {window: the layers that call it with
+        # that window} (layers with a paged table: not one of recurrent
+        # state or a latent, nor one with no cache), and the table entries
+        # of a slot one grid step of it takes
+        self._windows = dict(Counter(
+            w for w, kind in zip(geom.windows, geom.kinds)
+            if kind in (KV, RING) or isinstance(kind, tuple)))
+        self._entries_per_step = decode_entries_per_step(
+            geom.n_kv_heads, block_size, geom.d_head, dtype, table_len)
+        # keys a query of a LATENT layer attends at most
+        self._topk = model.config.index_topk if self.layers[LATENT] else 0
+        self._kinds = [name for name, there in (
+            ("latent", self.layers[LATENT]),
+            ("slot", self.layers[RING] + self.layers[STATE]),
+            ("window", any(getattr(model.config, "attn_layer_windows",
+                                   None) or ()))) if there]
+        self.block_bytes = self.slot_bytes = 0
+
+    def size(self, cache):
+        """Told the allocated ``cache``: ``block_bytes`` = bytes a block of
+        the pools under the block tables, every layer's, and ``slot_bytes``
+        = bytes a slot of whatever else the model keeps (rings, recurrent
+        state)."""
+        self.block_bytes = sum(
+            math.prod(p.shape[-axes:]) * p.dtype.itemsize
+            for kind, axes in _BLOCK_AXES.items() for key in _KEYS[kind]
+            for p in cache.get(key, ()))
+        by_block = {key for kind in _BLOCK_AXES for key in _KEYS[kind]}
+        self.slot_bytes = sum(
+            p.nbytes for key, sub in cache.items() if key not in by_block
+            for p in jax.tree.leaves(sub)) // self.slots
+
+    def refusal(self, feature):
+        """Why the model's cache cannot have ``feature`` (``prefix_cache``
+        | ``spec_draft`` | ``kv_host_offload`` | ``kv_transfer``), or None
+        where it can."""
+        for kind in self._kinds:
+            if (kind, feature) in _REFUSALS:
+                return _REFUSALS[kind, feature]
+        return None
+
+    def _selected_read(self, start, tokens):
+        """(index_keys, attended_keys) of ``tokens`` consecutive real
+        query tokens from position ``start`` (an array: one run a live
+        slot) in every latent layer: the causal keys the indexer scores,
+        position + 1 a query, and the keys attended after the selection,
+        ``min(position + 1, index_topk)``. (0, 0) on a model with no such
+        layer."""
+        layers = self.layers[LATENT]
+        if not layers or not tokens:
+            return 0, 0
+        ctx = np.asarray(start, np.int64)[..., None] + 1 + np.arange(tokens)
+        return (int(ctx.sum()) * layers,
+                int(np.minimum(ctx, self._topk).sum()) * layers)
+
+    def dispatch(self, lengths, tables, active, steps, chunk_start=0,
+                 chunk_tokens=0, chunk_rows=0):
+        """The stats of a ``dstpu.engine.dispatch`` span that are the
+        cache's (``monitor/tag_schema.py``), of one program call: ``steps``
+        decode steps over the batch ``lengths`` / ``tables`` with the live
+        slots' mask ``active`` (where the call runs the decode programs
+        over the batch; else ``lengths`` is None and ``active`` a count),
+        and a chunk's ``chunk_tokens`` real tokens of ``chunk_rows`` from
+        position ``chunk_start``."""
+        grid_steps = kernel_steps = table_entries = 0
+        write_rows, write_rows_offered = chunk_tokens, chunk_rows
+        index_keys, attended_keys = self._selected_read(
+            chunk_start, chunk_tokens)
+        if lengths is not None and self.layers[LATENT]:
+            # each live slot's token a decode step, from its length on
+            decode = self._selected_read(
+                np.asarray(lengths)[np.asarray(active, bool)], steps)
+            index_keys += decode[0]
+            attended_keys += decode[1]
+        if lengths is not None and self._windows:
+            MB, BS = self.table_len, self.block_size
+
+            def per_call(per_step):
+                # a kernel call's, the layers' mean where their windows
+                # differ, over the dispatch's decode steps
+                return round(sum(layers * decode_grid_steps(
+                    lengths, active, MB, BS, w, steps, per_step)
+                    for w, layers in self._windows.items())
+                    / sum(self._windows.values()))
+
+            grid_steps = per_call(1)
+            kernel_steps = grid_steps if self._entries_per_step == 1 \
+                else per_call(self._entries_per_step)
+            table_entries = steps * self.slots * MB
+            write_rows += kv_write_live_rows(lengths, tables, BS, steps)
+            write_rows_offered += steps * self.slots
+        state = self.layers[STATE]
+        return dict(
+            grid_steps=grid_steps, table_entries=table_entries,
+            kernel_steps=kernel_steps, write_rows=write_rows,
+            write_rows_offered=write_rows_offered,
+            state_updates=int(np.sum(active)) * steps * state if state
+            else 0,
+            rule_rows=chunk_rows * state, index_keys=index_keys,
+            attended_keys=attended_keys)
+
+    def prefill(self, tokens, padded):
+        """The same of a ``dstpu.engine.prefill`` span: a bucketed prefill
+        of ``tokens`` real tokens in ``padded`` rows."""
+        index_keys, attended_keys = self._selected_read(0, tokens)
+        return dict(rule_rows=padded * self.layers[STATE],
+                    index_keys=index_keys, attended_keys=attended_keys)

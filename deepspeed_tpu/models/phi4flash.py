@@ -62,6 +62,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from . import paged
+from .common import _pieces
 from .llama import _layer_norm
 
 @dataclass(frozen=True)
@@ -143,19 +144,6 @@ PHI4FLASH_TINY = Phi4FlashConfig(
     d_model=64, d_ff=128, sliding_window=8)
 PHI4FLASH_PRESETS = {"tiny": PHI4FLASH_TINY,
                      "phi-4-mini-flash": PHI4_MINI_FLASH}
-
-
-def _pieces(x, dtype):
-    """float32 x as a stack of pieces of ``dtype`` that sum to it: itself
-    where the dtype is its own, else (hi, lo), x to ~16 bits. ``hi`` is
-    rounded by ``reduce_precision``: a cast there and back is excess
-    precision to the TPU compiler, which takes it out, and ``lo`` with it
-    (my chip run, PR 30: the products then saw ``hi`` alone)."""
-    if dtype == x.dtype:
-        return x[None]
-    info = jnp.finfo(dtype)
-    hi = lax.reduce_precision(x, info.nexp, info.nmant)
-    return jnp.stack([hi, x - hi]).astype(dtype)
 
 
 def _mm(x, w, scope):

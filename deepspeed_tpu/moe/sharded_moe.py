@@ -22,8 +22,6 @@ Counterpart of the reference's ``deepspeed/moe/sharded_moe.py`` (TopKGate
     wasteful under jit).
 """
 
-import contextlib
-import contextvars
 import math
 
 import jax
@@ -87,35 +85,14 @@ def _grouped_dot(xs, w, group_sizes, params):
     return lax.ragged_dot(xs, w, group_sizes)
 
 
-# the tally a ``counting_expert_calls`` block is filling, if any
-_EXPERT_CALLS = contextvars.ContextVar("dstpu_expert_calls", default=None)
-
-
-@contextlib.contextmanager
-def counting_expert_calls():
-    """Yields ``[calls, kernel_calls]``: the expert SwiGLU layer calls
-    traced inside the block, and those of them whose products are a Pallas
-    grouped kernel (``_grouped_swiglu_ffn`` says which). Trace-time Python:
-    a serving engine puts it round a program's traced body, for its
-    dispatch span (expert_calls / expert_kernel_calls)."""
-    counts = [0, 0]
-    token = _EXPERT_CALLS.set(counts)
-    try:
-        yield counts
-    finally:
-        _EXPERT_CALLS.reset(token)
-
-
 def _grouped_swiglu_ffn(xs, w1, w3, w2, group_sizes, params):
     from ..ops.int8_weights import _is_q
+    from ..ops.pallas._common import note_call
     backend = params.get("backend")
     quantized = _is_q(w1)
     int8 = not quantized and bool(params.get("int8"))
-    counts = _EXPERT_CALLS.get()
-    if counts is not None:
-        counts[0] += 1
-        counts[1] += quantized or (not int8
-                                   and backend in ("kernel", "forward"))
+    note_call("expert", quantized or (
+        not int8 and backend in ("kernel", "forward")))
     if quantized:
         # weight-only quantized experts (serving): dequant fused into
         # the grouped kernel's flush epilogue — int8/int4 bytes stream

@@ -211,6 +211,22 @@ TAG_SCHEMA = {
         "was emitted (per-role queue depth)",
 }
 
+# a mechanism that has a Pallas form and another, by the name its traced
+# body notes its calls under (``ops/pallas/_common.py`` ``note_call``) -> the
+# ``telemetry_snapshot()`` key of the share of its calls that took the kernel
+KERNEL_SHARES = {"expert": "moe_kernel_share", "rule": "rule_kernel_share",
+                 "latent_read": "latent_kernel_share"}
+# what a program call's trace noted of them (``counting_calls``), as the
+# dispatch and prefill spans say it
+_TALLY_STATS = tuple(f"{name}{stat}" for name in KERNEL_SHARES
+                     for stat in ("_calls", "_kernel_calls"))
+# what ``models/paged.py``'s ``Account`` counts of a program call over the
+# model's cache: of a bucketed prefill, and of a dispatch
+_ACCOUNT_PREFILL_STATS = ("rule_rows", "index_keys", "attended_keys")
+_ACCOUNT_STATS = ("grid_steps", "table_entries", "kernel_steps", "write_rows",
+                  "write_rows_offered", "state_updates") \
+    + _ACCOUNT_PREFILL_STATS
+
 # span name -> the stats it carries and what it covers. Spans are
 # ``monitor.telemetry.span(name, **stats)`` (a jax TraceAnnotation on the
 # profiler's clock, free when no capture runs); both directions linted by
@@ -239,26 +255,16 @@ SPAN_SCHEMA = {
         "meaning": "one request admitted: pool check passed -> queued "
                    "for chunks or through its bucketed prefill"},
     "dstpu.engine.prefill": {
-        "stats": ("uid", "tokens", "padded", "expert_calls",
-                  "expert_kernel_calls", "rule_rows", "rule_calls",
-                  "rule_kernel_calls", "index_keys", "attended_keys",
-                  "latent_read_calls", "latent_read_kernel_calls"),
+        "stats": ("uid", "tokens", "padded") + _ACCOUNT_PREFILL_STATS
+        + _TALLY_STATS,
         "meaning": "bucketed prefill of one request: arrays, program "
-                   "call, blocking read of its token; expert_calls / "
-                   "expert_kernel_calls / rule_rows / rule_calls / "
-                   "rule_kernel_calls / index_keys / attended_keys / "
-                   "latent_read_calls / latent_read_kernel_calls as on "
-                   "dstpu.engine.dispatch"},
+                   "call, blocking read of its token; "
+                   + " / ".join(_ACCOUNT_PREFILL_STATS + _TALLY_STATS)
+                   + " as on dstpu.engine.dispatch"},
     "dstpu.engine.dispatch": {
         "stats": ("kind", "active", "slots", "steps", "chunk_tokens",
-                  "chunk_start", "grid_steps", "table_entries",
-                  "kernel_steps",
-                  "write_rows",
-                  "write_rows_offered", "expert_calls",
-                  "expert_kernel_calls", "chained", "late_steps",
-                  "state_updates", "rule_rows", "rule_calls",
-                  "rule_kernel_calls", "index_keys", "attended_keys",
-                  "latent_read_calls", "latent_read_kernel_calls"),
+                  "chunk_start", "chained", "late_steps") + _ACCOUNT_STATS
+        + _TALLY_STATS,
         "meaning": "one decode-bearing or chunk program call (kind "
                    "decode | fused | chunk | spec | offload) from the "
                    "assembled batch to the last posted token; steps = the "

@@ -49,7 +49,7 @@ import numpy as np
 
 from ..utils.logging import logger
 from .flight_recorder import FlightRecorder
-from .tag_schema import TAG_SCHEMA
+from .tag_schema import KERNEL_SHARES, TAG_SCHEMA
 
 # --------------------------------------------------------------- peak flops
 # bf16 peak per chip by device_kind substring (first match wins; order
@@ -843,16 +843,9 @@ class ServingTelemetry:
         self._late_steps = 0
         # fused dispatches: a prompt chunk beside the decode steps
         self._fused_dispatches = 0
-        # expert layer calls of every program call, and those of them
-        # whose products were a Pallas grouped kernel
-        self._expert_calls = 0
-        self._expert_kernel_calls = 0
-        # calls of the gated delta rule of every program call, and those
-        # of them that were a Pallas kernel
-        self._rule_calls = 0
-        self._rule_kernel_calls = 0
-        self._latent_reads = 0
-        self._latent_kernel_reads = 0
+        # mechanism -> [its calls of every program call, those of them
+        # that were a Pallas kernel] (tag_schema.KERNEL_SHARES)
+        self._calls = {}
         # bytes of cache the live sequences hold (blocks under their
         # tables, and whatever the model keeps a slot) against the tokens
         # they have seen, summed over the engine's steps
@@ -951,26 +944,17 @@ class ServingTelemetry:
         for sequences that the dispatch before it had ended."""
         self._late_steps += late
 
-    def on_expert_calls(self, calls, kernel):
-        """One program call whose trace made ``calls`` expert layer calls
-        (MoE layers x steps), ``kernel`` of them through a Pallas grouped
-        kernel and the rest through ``lax.ragged_dot``."""
-        self._expert_calls += calls
-        self._expert_kernel_calls += kernel
-
-    def on_rule_calls(self, calls, kernel):
-        """One program call whose trace made ``calls`` calls of the gated
-        delta rule (linear layers x chunk calls and decode steps),
-        ``kernel`` of them a Pallas kernel and the rest the XLA form."""
-        self._rule_calls += calls
-        self._rule_kernel_calls += kernel
-
-    def on_latent_reads(self, reads, kernel):
-        """One program call whose trace made ``reads`` selected reads of a
-        latent cache (latent layers x its chunk and decode steps),
-        ``kernel`` of them the Pallas kernel and the rest the XLA form."""
-        self._latent_reads += reads
-        self._latent_kernel_reads += kernel
+    def on_calls(self, counts):
+        """One program call whose trace made ``counts[name][0]`` calls of
+        each mechanism ``name`` (``tag_schema.KERNEL_SHARES``: an MoE
+        layer's expert products, the gated delta rule, a latent cache's
+        selected read; layers x the call's chunk and decode steps),
+        ``counts[name][1]`` of them a Pallas kernel and the rest the XLA
+        form."""
+        for name, (calls, kernel) in counts.items():
+            pair = self._calls.setdefault(name, [0, 0])
+            pair[0] += calls
+            pair[1] += kernel
 
     def on_cache_held(self, cache_bytes, live_tokens):
         """One engine step began with ``cache_bytes`` of cache held by
@@ -1134,15 +1118,10 @@ class ServingTelemetry:
                 self._late_steps / max(1, self._slot_steps), 4)
         if self._fused_dispatches:
             out["fused_dispatches"] = self._fused_dispatches
-        if self._expert_calls:
-            out["moe_kernel_share"] = round(
-                self._expert_kernel_calls / self._expert_calls, 4)
-        if self._rule_calls:
-            out["rule_kernel_share"] = round(
-                self._rule_kernel_calls / self._rule_calls, 4)
-        if self._latent_reads:
-            out["latent_kernel_share"] = round(
-                self._latent_kernel_reads / self._latent_reads, 4)
+        for name, key in KERNEL_SHARES.items():
+            calls, kernel = self._calls.get(name, (0, 0))
+            if calls:
+                out[key] = round(kernel / calls, 4)
         if self._live_tokens:
             out["cache_bytes_per_live_token"] = round(
                 self._cache_bytes / self._live_tokens)

@@ -35,9 +35,6 @@ solved halves join as ``[[X11, 0], [-X22 A21 X11, X22]]``, two products a
 level, L = 64 in two levels.
 """
 
-import contextlib
-import contextvars
-
 import jax.numpy as jnp
 from jax import lax
 
@@ -49,34 +46,6 @@ _BASE = 16          # rows of a diagonal block solved by substitution
 # wrong rule for the whole chunk, a rounded S one that later chunks
 # inherit); see PERF.md, PR 41, for what each costs on the chip.
 EXACT = lax.Precision.HIGHEST
-
-# the tally a ``counting_rule_calls`` block is filling, if any
-_RULE_CALLS = contextvars.ContextVar("dstpu_rule_calls", default=None)
-
-
-@contextlib.contextmanager
-def counting_rule_calls():
-    """Yields ``[calls, kernel_calls]``: the calls of the rule (either
-    form; linear layers x calls) traced inside the block, and those of them
-    that are a Pallas kernel (:func:`note_rule_call` says which).
-    Trace-time Python, as ``moe/sharded_moe.py:counting_expert_calls``: a
-    serving engine puts it round a program's traced body, for its dispatch
-    span (rule_calls / rule_kernel_calls)."""
-    counts = [0, 0]
-    token = _RULE_CALLS.set(counts)
-    try:
-        yield counts
-    finally:
-        _RULE_CALLS.reset(token)
-
-
-def note_rule_call(kernel):
-    """A model's word that it traces one call of the rule here, through
-    a Pallas kernel or not."""
-    counts = _RULE_CALLS.get()
-    if counts is not None:
-        counts[0] += 1
-        counts[1] += bool(kernel)
 
 
 def _unit_lower_inverse(A):

@@ -7,11 +7,45 @@ TRACE time — the chosen variant is baked into the jitted program, so a
 warm cache costs zero per-step host work.
 """
 
+import contextlib
+import contextvars
+
 import jax
 
 # sentinel a kernel tunable takes to mean "resolve via the autotune
 # winner cache" (models pass their config knobs through verbatim)
 AUTO = "auto"
+
+
+# the tally a ``counting_calls`` block is filling, if any
+_CALLS = contextvars.ContextVar("dstpu_calls", default=None)
+
+
+@contextlib.contextmanager
+def counting_calls():
+    """Yields ``{name: [calls, kernel_calls]}``: the calls traced inside the
+    block of each mechanism that has a Pallas form and another (``"expert"``:
+    an MoE layer's SwiGLU chain; ``"rule"``: the gated delta rule, either
+    form; ``"latent_read"``: a latent cache's selected read), and those of
+    them that took the kernel (:func:`note_call` says which). Trace-time
+    Python: a serving engine puts it round a program's traced body, for its
+    dispatch span (``<name>_calls`` / ``<name>_kernel_calls``)."""
+    counts = {}
+    token = _CALLS.set(counts)
+    try:
+        yield counts
+    finally:
+        _CALLS.reset(token)
+
+
+def note_call(name, kernel):
+    """A traced body's word that it makes one call of ``name`` here, through
+    a Pallas kernel or not."""
+    counts = _CALLS.get()
+    if counts is not None:
+        pair = counts.setdefault(name, [0, 0])
+        pair[0] += 1
+        pair[1] += bool(kernel)
 
 
 def dispatch(op, bucket, dtype, defaults):

@@ -165,7 +165,7 @@ def test_forward_kernel_path_matches_reference():
     tiles: system = reference as through the ragged products, and every
     expert layer call is counted as the kernel's."""
     from types import SimpleNamespace
-    from deepspeed_tpu.moe import sharded_moe
+    from deepspeed_tpu.ops.pallas._common import counting_calls
     cfg = replace(CFG, d_model=128, d_ff=128, n_head=2, n_kv_heads=2)
     model = OLMoE(cfg)
     model._moe_cfg = SimpleNamespace(
@@ -184,7 +184,7 @@ def test_forward_kernel_path_matches_reference():
                                                  len(table) - 1)], 0)
     padded = np.zeros((1, T), np.int32)
     padded[0, :PROMPT] = seq[:PROMPT]
-    with sharded_moe.counting_expert_calls() as counts:
+    with counting_calls() as counts:
         logits, cache = jax.jit(model.apply_paged_prefill)(
             served, padded, cache, tb.astype(np.int32),
             np.where(pos < PROMPT, pos % BS, 0).astype(np.int32),
@@ -198,7 +198,7 @@ def test_forward_kernel_path_matches_reference():
             logits, cache = decode(served, np.asarray([seq[n], 0], np.int32),
                                    np.asarray([n, 0], np.int32), cache, tables)
             assert _rel(logits[0], want[n]) <= TOL, n
-    assert counts == [2 * cfg.n_layer] * 2      # two programs traced
+    assert counts == {"expert": [2 * cfg.n_layer] * 2}  # two programs traced
 
 
 def test_expert_parallel_equals_one_device(model, params):

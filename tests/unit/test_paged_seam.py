@@ -17,6 +17,8 @@ from deepspeed_tpu.autotuning import kernel_dispatch
 from deepspeed_tpu.inference.v2 import InferenceEngineV2, engine_v2
 from deepspeed_tpu.models import GPT2, GPT2Config, paged
 from deepspeed_tpu.models.bloom import BLOOM_TINY, Bloom
+from deepspeed_tpu.models.deepseek_v32 import DEEPSEEK_V32_TINY, DeepseekV32
+from deepspeed_tpu.models.gpt_neo import GPTNEO_TINY, GPTNeo
 from deepspeed_tpu.models.llama import LLAMA_TINY, Llama
 from deepspeed_tpu.models.mixtral import MIXTRAL_TINY, Mixtral
 from deepspeed_tpu.models.phi4flash import PHI4FLASH_TINY, Phi4Flash
@@ -30,7 +32,9 @@ MODELS = {"gpt2": lambda: GPT2(GPT2_TINY),
           "llama_gqa": lambda: Llama(LLAMA_TINY),
           "mixtral": lambda: Mixtral(MIXTRAL_TINY),
           "bloom_alibi": lambda: Bloom(BLOOM_TINY),
-          "phi4flash": lambda: Phi4Flash(PHI4FLASH_TINY)}
+          "phi4flash": lambda: Phi4Flash(PHI4FLASH_TINY),
+          "deepseek_v32": lambda: DeepseekV32(DEEPSEEK_V32_TINY),
+          "gpt_neo": lambda: GPTNeo(GPTNEO_TINY)}
 
 
 @pytest.fixture(autouse=True)
@@ -145,6 +149,131 @@ def test_only_the_seam_names_the_paged_kernels():
     assert naming["paged.py"] == {
         "paged_kv_write", "resolve_paged_", "decode_work_list",
         "paged_decode_attention", "paged_chunk_attention"}
+
+
+def _sources(*parts):
+    """{path under deepspeed_tpu/: text} of the .py files under ``parts``."""
+    found = {}
+    pkg = os.path.join(REPO, "deepspeed_tpu")
+    for dirpath, _, names in os.walk(os.path.join(pkg, *parts)):
+        for n in names:
+            if n.endswith(".py"):
+                path = os.path.join(dirpath, n)
+                with open(path, encoding="utf-8") as f:
+                    found[os.path.relpath(path, pkg)] = f.read()
+    return found
+
+
+def test_the_engine_names_no_cache_kind():
+    """Source lint: the scheduler knows no cache key, no kind, no model
+    config field of one family and no mechanism's own tally (the one
+    tally, ``counting_calls``, it opens round every program); exactly one
+    trace-time tally exists under ``deepspeed_tpu/``, and every mechanism
+    that notes its calls there has its row in the schema, which is where
+    the spans' and the snapshot's names come from."""
+    from deepspeed_tpu.monitor.tag_schema import KERNEL_SHARES
+    engine = _sources("inference", "v2")[os.path.join(
+        "inference", "v2", "engine_v2.py")]
+    named = re.findall(
+        r"\"(?:k|v|lat|idx)\"|index_topk|LATENT|STATE|_BLOCK_KEYS"
+        r"|counting_(?!calls\b)\w*|sharded_moe|gated_delta_rule", engine)
+    assert named == []
+    everything = _sources()
+    tallies = {path: n for path, text in everything.items()
+               if (n := len(re.findall(r"ContextVar\(", text)))}
+    assert tallies == {os.path.join("ops", "pallas", "_common.py"): 1}
+    noted = {name for text in everything.values()
+             for name in re.findall(r"note_call\(\s*\"(\w+)\"", text)}
+    assert noted == set(KERNEL_SHARES) == {"expert", "rule", "latent_read"}
+
+
+@pytest.mark.parametrize("family", ["gpt2", "phi4flash", "deepseek_v32"])
+def test_the_account_counts_what_the_schema_names(family):
+    """The cache's stats of a dispatch and of a prefill span are the
+    account's dicts, key for key the schema's two tuples."""
+    from deepspeed_tpu.monitor import tag_schema
+    account = paged.Account(MODELS[family](), 3, 16, 8, jnp.float32)
+    lengths, tables = np.array([9, 0, 30]), np.zeros((3, 16), np.int32)
+    tables[0, :2], tables[2, :4] = (1, 2), (3, 4, 5, 6)
+    for got in (account.dispatch(None, None, 0, 0, 8, 5, 16),
+                account.dispatch(lengths, tables, tables[:, 0] != 0, 2)):
+        assert tuple(sorted(got)) == tuple(sorted(tag_schema._ACCOUNT_STATS))
+        assert all(type(v) is int and v >= 0 for v in got.values())
+    assert tuple(sorted(account.prefill(5, 16))) \
+        == tuple(sorted(tag_schema._ACCOUNT_PREFILL_STATS))
+
+
+_BY_SLOT = ("the model keeps recurrent / window state by batch slot "
+            "(slot_state), which ")
+_BY_SELECTION = ("the model's blocks hold a latent cache read through a "
+                 "per-query selection (models/paged.py, LATENT), which ")
+# kind -> (a tiny model of it, feature -> the sentence the engine raised
+# with before the kinds answered for themselves; a feature not there is
+# served)
+REFUSALS = {
+    "slot-state": ("phi4flash", {
+        "prefix_cache": "prefix_cache=True: " + _BY_SLOT + "a cached block "
+        "of KV does not bring back — a prefix hit would resume from a state "
+        "nobody kept",
+        "spec_draft": "spec_draft=True / a draft model: " + _BY_SLOT
+        + "rollback_spec cannot take back once the rejected tokens have "
+        "moved it",
+        "kv_host_offload": "kv_host_offload: " + _BY_SLOT + "lives outside "
+        "the block pool the offload tier pages",
+        "kv_transfer": "disaggregated kv_transfer: " + _BY_SLOT + "the "
+        "block payloads of a KV handoff do not carry"}),
+    "latent": ("deepseek_v32", {
+        "prefix_cache": "prefix_cache=True: " + _BY_SELECTION + "the prefix "
+        "cache's copy-on-write and block reuse, written for K and V pools, "
+        "have not learnt",
+        "spec_draft": "spec_draft=True / a draft model: " + _BY_SELECTION
+        + "a draft model's verify pass and rollback_spec have no program "
+        "for",
+        "kv_host_offload": "kv_host_offload: " + _BY_SELECTION + "the "
+        "offload tier, which pages K and V pools, does not page",
+        "kv_transfer": "disaggregated kv_transfer: the model's blocks hold "
+        "a latent cache (models/paged.py, LATENT), which the K / V payloads "
+        "of a KV handoff do not carry"}),
+    "windowed-kv": ("gpt_neo", {
+        "prefix_cache": "prefix_cache=True on a sliding-window model "
+        "(attn_layer_windows set): a cached block's KV is position-valid "
+        "only inside each layer's window, so reusing it under a shifted "
+        "suffix serves wrong attention — disable prefix_cache for this "
+        "model"}),
+}
+_BUILD_KNOBS = {"prefix_cache": dict(prefix_cache=True),
+                "spec_draft": dict(spec_draft=True),
+                "kv_host_offload": dict(kv_host_offload=True,
+                                        device_kv_blocks=8)}
+
+
+@pytest.mark.parametrize("feature", ["prefix_cache", "spec_draft",
+                                     "kv_host_offload", "kv_transfer"])
+@pytest.mark.parametrize("kind", sorted(REFUSALS))
+def test_refusals_come_from_the_kinds(kind, feature):
+    """What a cache kind cannot have is one table in ``models/paged.py``,
+    kind x feature; the engine keeps when it refuses and with which
+    exception: ``ValueError`` at build (before anything is allocated),
+    ``RuntimeError`` from a handoff call."""
+    family, sentences = REFUSALS[kind]
+    model = MODELS[family]()
+    sentence = sentences.get(feature)
+    account = paged.Account(model, 3, 16, 8, jnp.float32)
+    assert account.refusal(feature) == sentence
+    if sentence is None:
+        return
+    config = dict(dtype="float32", max_batch_size=3, kv_block_size=8,
+                  num_kv_blocks=32)
+    if feature == "kv_transfer":
+        engine = InferenceEngineV2(model, config)
+        with pytest.raises(RuntimeError) as e:
+            engine.hold_decode(0)
+    else:
+        extra = dict(draft_model=model) if feature == "spec_draft" else {}
+        with pytest.raises(ValueError) as e:
+            InferenceEngineV2(model, {**config, **_BUILD_KNOBS[feature]},
+                              **extra)
+    assert str(e.value) == sentence
 
 
 @pytest.mark.parametrize("family,setting,expect", [

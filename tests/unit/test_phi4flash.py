@@ -194,11 +194,12 @@ def test_cache_is_three_kinds_and_only_the_full_layer_is_paged(mixed):
     assert mgr.can_admit(10, 10 * W)
     _, seq = mgr.admit(99, np.zeros((10,), np.int32), 10 * W)
     assert len(seq.blocks) == -(-(10 + 10 * W) // BS) == 23
-    assert eng._block_bytes == 2 * np.prod(pair) * 4
+    assert eng._account.block_bytes == 2 * np.prod(pair) * 4
     ring = 2 * 2 * R * np.prod(pair) * 4
     state = 3 * (CFG.ssm_conv - 1 + CFG.ssm_state) * CFG.d_inner * 4
     # the scratch block's third is the rounding of a slot's share
-    assert 0 <= eng._slot_bytes - (ring + state) <= ring // (SLOTS * R)
+    assert 0 <= eng._account.slot_bytes - (ring + state) \
+        <= ring // (SLOTS * R)
     mgr.retire(99)
     mgr.flush(99)
 
@@ -259,7 +260,7 @@ def test_cache_bytes_counter(mixed):
     held = snap["cache_bytes_per_live_token"]
     # a sequence holds its whole budget's blocks and a slot's rings and
     # state from its first step: more than a block's bytes a token
-    assert held > eng._block_bytes / BS
+    assert held > eng._account.block_bytes / BS
     assert held == round(eng.telemetry._cache_bytes
                          / eng.telemetry._live_tokens)
 

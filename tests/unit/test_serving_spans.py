@@ -236,19 +236,19 @@ def test_on_admit_queue_wait():
     st.on_kv_write(13, 24)
     assert st.percentiles()["kv_write_live_share"] == 0.5
     assert "moe_kernel_share" not in st.percentiles()
-    st.on_expert_calls(0, 0)                        # a program's first call
-    st.on_expert_calls(96, 96)
-    st.on_expert_calls(32, 0)
+    st.on_calls({"expert": [0, 0]})                 # a program's first call
+    st.on_calls({"expert": [96, 96]})
+    st.on_calls({"expert": [32, 0]})
     assert st.percentiles()["moe_kernel_share"] == 0.75
     assert "rule_kernel_share" not in st.percentiles()
-    st.on_rule_calls(0, 0)                          # a program's first call
-    st.on_rule_calls(108, 108)                      # fused: 12 x (1 + 8)
-    st.on_rule_calls(12, 0)
+    st.on_calls({"rule": [0, 0]})                   # a program's first call
+    st.on_calls({"rule": [108, 108]})               # fused: 12 x (1 + 8)
+    st.on_calls({"rule": [12, 0]})
     assert st.percentiles()["rule_kernel_share"] == 0.9
     assert "latent_kernel_share" not in st.percentiles()
-    st.on_latent_reads(0, 0)                        # a program's first call
-    st.on_latent_reads(45, 5)                       # fused: 5 x (1 + 8)
-    st.on_latent_reads(5, 5)                        # a chunk alone
+    st.on_calls({"latent_read": [0, 0]})            # a program's first call
+    st.on_calls({"latent_read": [45, 5]})           # fused: 5 x (1 + 8)
+    st.on_calls({"latent_read": [5, 5]})            # a chunk alone
     assert st.percentiles()["latent_kernel_share"] == 0.2
 
 
@@ -843,15 +843,16 @@ def test_cache_bytes_per_live_token_reader(kind, request):
                                    "cache_bytes_per_live_token")
     value = reader.read(view)
     steps = [e.stats for e in tr.host_spans("dstpu.engine.step")]
-    assert engine._slot_bytes == 0
-    assert engine._block_bytes == 2 * _CFG.n_layer * _CFG.d_model * 8 * 4
-    assert all(int(s["cache_bytes"]) % engine._block_bytes == 0
+    assert engine._account.slot_bytes == 0
+    assert engine._account.block_bytes \
+        == 2 * _CFG.n_layer * _CFG.d_model * 8 * 4
+    assert all(int(s["cache_bytes"]) % engine._account.block_bytes == 0
                for s in steps)
     assert value == sum(int(s["cache_bytes"]) for s in steps) \
         / sum(int(s["live_tokens"]) for s in steps)
     # a sequence holds its whole budget's blocks from admission: more
     # than a token's bytes a live token
-    assert value > engine._block_bytes / 8
+    assert value > engine._account.block_bytes / 8
     assert said[0][0] == "cache_bytes_per_live_token"
     view.trace = None
     assert reader.read(view) is None

@@ -537,17 +537,18 @@ def _nbytes(tree, dtype=None):
                          ids=lambda T: f"prefill{T}" if T else "decode_x8")
 def test_olmoe_programs_take_the_expert_kernel(v5e, monkeypatch, T):
     from deepspeed_tpu.models import OLMoE, OLMoEConfig
-    from deepspeed_tpu.moe import sharded_moe
+    from deepspeed_tpu.ops.pallas._common import counting_calls
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     model = OLMoE(OLMoEConfig(n_layer=2, num_experts=OLMOE_E))
     model._paged_kernel, model._paged_block_c = True, "auto"
     cfg = model.config
     steps = 1 if T else OLMOE_STEPS
-    with sharded_moe.counting_expert_calls() as counts:
+    with counting_calls() as counts:
         served = _olmoe_compiled(
             model, jax.eval_shape(model.init_served, jax.random.key(0)),
             _olmoe_prefill_of(T) if T else _olmoe_decode_x8, v5e, OLMOE_NB)
-    assert counts == [cfg.n_layer * steps] * 2      # every call the kernel
+    # every call the kernel
+    assert counts == {"expert": [cfg.n_layer * steps] * 2}
     text = served.as_text()
     weight = rf"bf16\[{OLMOE_E},(?:{cfg.d_model},{cfg.ffn_dim}" \
              rf"|{cfg.ffn_dim},{cfg.d_model})\]"
