@@ -17,6 +17,8 @@ from .olmo_hybrid import (OlmoHybrid, OlmoHybridConfig, OLMO_HYBRID_PRESETS,
 from .deepseek_v32 import (DeepseekV32, DeepseekV32Config,
                            DEEPSEEK_V32_PRESETS, DEEPSEEK_V32_TINY,
                            DEEPSEEK_V32)
+from .deepseek_v3 import (DeepseekV3, DeepseekV3Config, DEEPSEEK_V3_PRESETS,
+                          DEEPSEEK_V3_TINY, KANANA_2_30B_A3B)
 from .falcon import Falcon, FalconConfig, FALCON_PRESETS
 from .opt import OPT, OPTConfig, OPT_PRESETS
 from .gptj import GPTJ, GPTJConfig, GPTJ_PRESETS

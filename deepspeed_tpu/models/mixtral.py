@@ -14,9 +14,13 @@ apply_paged_decode call ``_mlp`` per layer, so the engine's
 ragged EP all_to_all below; attention rides Llama's paged Pallas
 kernels under the same engine ``paged_kernel`` knob).
 
-Training note: the router's load-balance aux loss is not threaded through
-Llama's apply (serving-first model); use GPT2MoE for aux-loss-supervised
-MoE training parity tests.
+Training note: ``_mlp`` and the grouped products are differentiable and
+``Mixtral`` / ``OLMoE`` train through ``deepspeed_tpu.initialize`` like
+Llama, but the router's load-balance aux loss is not threaded through
+Llama's apply: use GPT2MoE for aux-loss-supervised MoE training parity
+tests. The sparse family that is trained on the chip is
+``models/deepseek_v3.py`` (the same ``route_topk`` / ``moe_swiglu_routed``,
+forward and backward; its gate balances by a correction bias, not a loss).
 """
 
 import math

@@ -272,11 +272,12 @@ class TopKGate:
 
 def route_topk(x, gate_w, k, renormalize=True, *, scoring="softmax",
                bias=None, n_group=1, topk_group=1, scale=1.0):
-    """The serving MoE models' router, shared by the one-device ``_mlp``
-    and the expert-parallel path: float32 logits (the product itself at
-    HIGHEST precision — on TPU a default float32 matmul multiplies in
-    bf16, enough to flip a near-tie between the k-th and (k+1)-th
-    expert), float32 scores over ALL experts, top-k. ``renormalize``
+    """The MoE models' router, serving and training (``models/mixtral.py``,
+    ``deepseek_v32.py``, ``deepseek_v3.py``), shared by the one-device
+    ``_mlp`` and the expert-parallel path: float32 logits (the product
+    itself at HIGHEST precision — on TPU a default float32 matmul
+    multiplies in bf16, enough to flip a near-tie between the k-th and
+    (k+1)-th expert), float32 scores over ALL experts, top-k. ``renormalize``
     divides the k scores by their sum (mixtral, HF
     ``norm_topk_prob=True``); False uses them as they are (OLMoE: they
     sum to ~k/E at random init, not to 1).
@@ -324,21 +325,27 @@ def route_topk(x, gate_w, k, renormalize=True, *, scoring="softmax",
 
 def moe_swiglu_routed(xs, weights, experts, w1, w3, w2, grouped="auto",
                       int8=False, held=None, out_dtype=None):
-    """The one-device dropless expert layer once the router has spoken:
-    the routed rows sorted by expert, the three grouped products
-    (``lax.ragged_dot`` or the Pallas grouped kernel, as
-    :func:`resolve_grouped_params` answers), unsorted and summed by the
-    routing weights, under the ``dstpu.moe.*`` scopes. xs (S, D),
-    weights / experts (S, k) -> (S, D) ``out_dtype`` (xs's).
+    """The one-device dropless expert layer once the router has spoken,
+    of a serving step and, under ``jax.grad``, of a training step
+    (``models/deepseek_v3.py``): the routed rows sorted by expert, the
+    three grouped products (``lax.ragged_dot`` or the Pallas grouped
+    kernel, as :func:`resolve_grouped_params` answers), unsorted and
+    summed by the routing weights, under the ``dstpu.moe.*`` scopes. xs
+    (S, D), weights / experts (S, k) -> (S, D) ``out_dtype`` (xs's).
+    Differentiable in xs, weights and the expert arrays (the sort's
+    gather transposes to a scatter-add; ``experts`` are indices).
 
     ``held`` = (offset, count): THE SHARE of an expert-parallel
     deployment this device holds — w1 / w3 / w2 are experts ``offset ..
     offset + count - 1`` of those the router chose among. A routed row
     whose expert lies elsewhere sorts behind every held expert's rows and
     past the groups' sum: the grouped products fetch no weight for it and
-    write zeros, and it adds nothing. What the absent experts would have
-    added is left out: the partial sum another device's exchange would
-    complete."""
+    write nothing there (zeros on the CPU, whatever the buffer held on the
+    chip: both ends of the products select it away), and it adds nothing
+    and takes no gradient. What the absent experts would have added is
+    left out: the partial sum another device's exchange would complete.
+    (The absent rows are still sorted and gathered: S * k rows exist
+    whatever share is held.)"""
     S, D = xs.shape
     k = experts.shape[1]
     E = w1.scale.shape[0] if hasattr(w1, "scale") else w1.shape[0]
@@ -359,6 +366,14 @@ def moe_swiglu_routed(xs, weights, experts, w1, w3, w2, grouped="auto",
         xr = x_rep[order]
         group_sizes = jnp.bincount(
             flat_exp, length=E + (held is not None))[:E].astype(jnp.int32)
+        if held is not None:
+            # the products neither read nor WRITE a row past the groups:
+            # on the chip lax.ragged_dot leaves there what the buffer
+            # held, in the backward too (dx), where the gather's
+            # scatter-add would carry it into xs's gradient. The select
+            # transposes to a select: nothing of it gets through.
+            xr = jnp.where((jnp.arange(S * k) < jnp.sum(group_sizes))[:, None],
+                           xr, 0)
 
     F = w1.scale.shape[-1] if hasattr(w1, "scale") else w1.shape[-1]
     gp = resolve_grouped_params(grouped, S * k, E, D, F, xr.dtype)

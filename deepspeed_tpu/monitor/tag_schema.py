@@ -417,6 +417,13 @@ SCOPE_SCHEMA = {
         "rotary and LayerNorm of the index queries and key, the index "
         "scores of every causal key and, where the trace shows them "
         "under it, the search for each query's k-th largest",
+    "dstpu.attn.mla":
+        "a TRAINING latent (MLA) layer's attention outside its weight "
+        "products (models/deepseek_v3.py): rotary on the queries and the "
+        "one shared key, the assembly of 192-wide keys, the flash forward "
+        "and backward (or the dense softmax off a TPU) and the padding of "
+        "V to the key width and the slicing of the output around them; a "
+        "backward or recomputed operation's tf_op carries the scope too",
     "dstpu.attn.diff":
         "differential attention outside the paged read: q/k/v "
         "projection, the query's padding to the head pair's width, "

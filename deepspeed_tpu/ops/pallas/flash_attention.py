@@ -62,6 +62,30 @@ def _block_sizes(T, block_q, block_k):
 
 NEG_INF = -1e30
 
+# The standard-layout kernels keep a whole sequence of an instance in VMEM
+# (forward: K and V; backward: q, dO, o, lse and the float32 dq), double
+# buffered. Mosaic's scoped default (16 MB) holds that at GPT-2's shapes
+# (T 1024 - 2048, d 64); a long sequence at a wide head does not fit it
+# (T 8192 at d 256: 17.5 MB forward, ~50 MB backward), so such a call asks
+# for what its blocks need, of the 128 MiB a v5e core has. A call that fits
+# the default is compiled as it always was.
+_VMEM_DEFAULT = 16 << 20
+
+
+def _vmem_params(*block_bytes):
+    """``pallas_call`` keywords for a call whose resident blocks take
+    ``block_bytes`` each: nothing while two buffers of each and the score
+    tiles' room fit Mosaic's default."""
+    need = 2 * sum(block_bytes) + (8 << 20)
+    if need <= _VMEM_DEFAULT:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=min(need + (8 << 20), 112 << 20))}
+
+
+def _lanes(d):
+    return _round_up(d, 128)
+
 # Trailing lane dim for per-row scalar tensors (lse, delta). Per-row
 # scalars are not 2D-tileable at head-group sizes < 8, so they carry a
 # small replicated lane dim. 8 lanes (not 128): the value lives in
@@ -352,6 +376,9 @@ def _fwd(q, k, v, scale, causal, bq, bk, bh, t_real, interpret, window=0,
             _sds((BH, T, LSE_LANES), jnp.float32, q),
         ],
         interpret=interpret,
+        **_vmem_params(*(2 * [bh * T * _lanes(d) * q.dtype.itemsize]
+                         + 2 * [bh * bq * _lanes(d) * q.dtype.itemsize]
+                         + [bh * bq * 128 * 4])),
     )(q, k, v, *biases)
     return o, lse
 
@@ -700,6 +727,13 @@ def _bwd(q, k, v, o, lse_t, do, scale, causal, bq, bk, bh, t_real,
             _sds((BH, T, d), q.dtype, q),
         ] + db_shapes,
         interpret=interpret,
+        # q, dO, o (or delta), lse whole; dq whole in float32 unless one
+        # key block; the k / v / dk / dv blocks
+        **_vmem_params(*(3 * [bh * T * _lanes(d) * q.dtype.itemsize]
+                         + [bh * T * 128 * 4]
+                         + [bh * T * _lanes(d)
+                            * (q.dtype.itemsize if single_k else 4)]
+                         + 4 * [bh * bk * _lanes(d) * q.dtype.itemsize])),
     )(q, k, v, do, lse, od, *biases)
     dq, dk, dv = outs[:3]
     dbiases = _scatter_dbias(biases, bias_cfgs, outs[3:])

@@ -392,10 +392,47 @@ class DeepSpeedEngine:
             f"gas={self.config.gradient_accumulation_steps} "
             f"overlap={self._overlap_on}", ranks=[0])
 
+    # ---------------------------------------------------------------- buffers
+    # A model may declare leaves of its tree that are BUFFERS, not
+    # parameters (``model.buffer_names()``: the leaves' keys; the reference
+    # fork's frozen parameters): they stay in ``state["params"]`` and
+    # ``state["master"]`` as ``init`` made them, dtype and all, and the
+    # optimizer never sees them: no cast, no gradient, no decay, no
+    # moments, no update. A model that declares none (``_buffers`` None)
+    # runs the programs it always ran, operation for operation.
+    def _cast(self, tree, dtype):
+        """The parameter tree in ``dtype``, a buffer leaf as it is."""
+        if self._buffers is None:
+            return _tree_cast(tree, dtype)
+        return jax.tree.map(lambda b, x: x if b else x.astype(dtype),
+                            self._buffers, tree)
+
+    def _owned(self, tree):
+        """What the optimizer owns of a tree shaped like the parameters
+        (arrays, specs or shardings): a buffer's place is None, an empty
+        node that ``jax.tree.map`` passes over."""
+        if self._buffers is None:
+            return tree
+        return jax.tree.map(lambda b, x: None if b else x, self._buffers,
+                            tree)
+
+    def _with_buffers(self, owned, full):
+        """``owned`` (see ``_owned``) with ``full``'s buffer leaves back in
+        their places."""
+        if self._buffers is None:
+            return owned
+        rest = iter(jax.tree.leaves(owned))
+        return jax.tree.map(lambda b, x: x if b else next(rest),
+                            self._buffers, full)
+
     # ------------------------------------------------------------------ state
     def _build_state(self, seed):
         rng = jax.random.key(seed)
         abstract = jax.eval_shape(self.model.init, rng)
+        names = getattr(self.model, "buffer_names", frozenset)()
+        self._buffers = jax.tree_util.tree_map_with_path(
+            lambda path, _: getattr(path[-1], "key", None) in names,
+            abstract) if names else None
         shapes = jax.tree.map(lambda l: l.shape, abstract)
         tp_specs = self.model.partition_specs(self.topology)
         self._tp_specs = tp_specs
@@ -422,7 +459,7 @@ class DeepSpeedEngine:
         master_sh = self.plan.shardings("master")
         self.param_shardings = param_sh
         self.master_shardings = master_sh
-        self.grad_shardings = self.plan.shardings("grad")
+        self.grad_shardings = self._owned(self.plan.shardings("grad"))
 
         self.use_master = self.param_dtype != jnp.float32
 
@@ -434,6 +471,10 @@ class DeepSpeedEngine:
         self.offload_param_cfg = self.config.zero.offload_param
         self.offload_enabled = (self.offload_opt_cfg.enabled
                                 or self.offload_param_cfg.enabled)
+        if self.offload_enabled and self._buffers is not None:
+            raise NotImplementedError(
+                "ZeRO-Offload's host optimizer owns every leaf: a model "
+                "with buffer leaves trains with the optimizer on the device")
         self.host_optimizer = None
         # multi-process offload: each process device_gets and host-steps
         # ONLY its addressable master shards (reference
@@ -467,11 +508,11 @@ class DeepSpeedEngine:
                 opt_sh = None
             else:
                 params = jax.jit(
-                    lambda r: _tree_cast(self.model.init(r),
+                    lambda r: self._cast(self.model.init(r),
                                          self.param_dtype),
                     out_shardings=param_sh)(rng)
                 if self.use_master:
-                    master = jax.jit(lambda p: _tree_cast(p, jnp.float32),
+                    master = jax.jit(lambda p: self._cast(p, jnp.float32),
                                      out_shardings=master_sh)(params)
                 else:
                     # fp32 training: master IS params (sharded per master
@@ -479,9 +520,10 @@ class DeepSpeedEngine:
                     # specs)
                     master = jax.jit(lambda p: p,
                                      out_shardings=master_sh)(params)
-                opt_sh = self._opt_state_shardings(master)
+                opt_sh = self._opt_state_shardings(self._owned(master))
                 opt_state = jax.jit(self.optimizer.init,
-                                    out_shardings=opt_sh)(master)
+                                    out_shardings=opt_sh)(
+                                        self._owned(master))
         self.opt_shardings = opt_sh
 
         # replicated scalars are CREATED by a jitted program rather than
@@ -532,11 +574,11 @@ class DeepSpeedEngine:
         master_def = jax.tree.structure(master)
         state_shape = jax.eval_shape(self.optimizer.init, master)
         repl = NamedSharding(self.mesh, P())
-        moment_sh = self.master_shardings
+        moment_sh = self._owned(self.master_shardings)
         if getattr(self._pipe, "offload_moments", False):
             from .swap_tensor import host_stage
             moment_sh = jax.tree.map(host_stage.with_host_memory_kind,
-                                     self.master_shardings)
+                                     moment_sh)
         out = {}
         for key, sub in state_shape.items():
             if jax.tree.structure(sub) == master_def:
@@ -578,8 +620,9 @@ class DeepSpeedEngine:
         clip = self.config.gradient_clipping
         opt = self.optimizer
         scaler = self.loss_scaler
-        grad_specs = self.plan.grad_specs
-        param_specs = self.plan.param_specs
+        owned, with_buffers = self._owned, self._with_buffers
+        grad_specs = owned(self.plan.grad_specs)
+        param_specs = owned(self.plan.param_specs)
         pdtype = self.param_dtype
         use_master = self.use_master
         constrain = jax.lax.with_sharding_constraint
@@ -603,7 +646,7 @@ class DeepSpeedEngine:
                                         step=step, ltd_keep=ltd_keep) \
                     * scale
             loss_scaled, grads = jax.value_and_grad(scaled)(params)
-            grads = _tree_cast(grads, gdtype)
+            grads = _tree_cast(owned(grads), gdtype)
             return loss_scaled / scale, grads
 
         def unscale_clip_grads(grads, scale):
@@ -631,17 +674,20 @@ class DeepSpeedEngine:
             """grads: fp32 tree, already averaged over GAS; scale included."""
             scale = state["scale"]["scale"]
             grads, finite, gnorm = unscale_clip_grads(grads, scale)
-            new_master, new_opt = opt.update(grads, state["opt"],
-                                             state["master"], lr=lr)
+            master = owned(state["master"])
+            new_master, new_opt = opt.update(grads, state["opt"], master,
+                                             lr=lr)
             # skip-on-overflow: keep old state where not finite
             sel = lambda new, old: jax.tree.map(
                 lambda n, o: jnp.where(finite, n, o), new, old)
-            new_master = sel(new_master, state["master"])
+            new_master = sel(new_master, master)
             new_opt = sel(new_opt, state["opt"])
             new_params = jax.tree.map(
                 lambda m, s: constrain(m.astype(pdtype), s),
                 new_master, param_specs) if use_master else jax.tree.map(
                 lambda m, s: constrain(m, s), new_master, param_specs)
+            new_master = with_buffers(new_master, state["master"])
+            new_params = with_buffers(new_params, state["params"])
             new_scale = scaler.update(state["scale"], ~finite)
             new_state = dict(state)
             new_state.update(params=new_params, master=new_master,
@@ -686,7 +732,7 @@ class DeepSpeedEngine:
 
             zero_grads = jax.tree.map(
                 lambda s: jnp.zeros(s.shape, gdtype),
-                jax.eval_shape(lambda p: _tree_cast(p, gdtype),
+                jax.eval_shape(lambda p: _tree_cast(owned(p), gdtype),
                                state["params"]))
             zero_grads = jax.tree.map(lambda g, s: constrain(g, s),
                                       zero_grads, grad_specs)
@@ -2041,7 +2087,7 @@ class DeepSpeedEngine:
             else:
                 new_master = jax.device_put(master, self.master_shardings)
                 new_params = jax.jit(
-                    lambda m: _tree_cast(m, self.param_dtype),
+                    lambda m: self._cast(m, self.param_dtype),
                     out_shardings=self.param_shardings)(new_master)
                 state["master"] = new_master
                 state["params"] = new_params

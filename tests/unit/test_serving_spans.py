@@ -31,7 +31,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, os.path.join(REPO, "perfbench"))
 from pbench import (common as pb_common, dsa as pb_dsa,  # noqa: E402
-                    gdn as pb_gdn,
+                    gdn as pb_gdn, mla_moe as pb_mla_moe,
                     moe as pb_moe, ssm as pb_ssm, trace as pb_trace,
                     weights as pb_weights)
 
@@ -1199,7 +1199,8 @@ def test_scope_schema_lint_both_directions():
     assert all(n.startswith("dstpu.") and m for n, m in SCOPE_SCHEMA.items())
     # the benchmark's readers look for the same names
     assert {pb_moe.SCOPE_EXPERTS, *pb_moe.SCOPES_ROUTE, *pb_ssm.SCOPES,
-            *pb_gdn.SCOPES, *pb_dsa.SCOPES, *pb_weights.SCOPES} \
+            *pb_gdn.SCOPES, *pb_dsa.SCOPES, *pb_mla_moe.SCOPES,
+            *pb_weights.SCOPES} \
         == set(SCOPE_SCHEMA)
 
 

@@ -927,3 +927,83 @@ def test_deepseek_share_programs_fit_the_chip(v5e, monkeypatch, program):
     assert abs(_nbytes(whole_cache) - 2.349e9) < 0.01e9
     whole = temp + _nbytes(whole_params) + _nbytes(whole_cache)
     assert whole <= (V5E_GB - 1.5) * 1e9, (temp, whole)
+
+
+# train-kanana2-share-8k: one chip's share of Kanana-2-30B-A3B, 2 x 8,192
+# tokens a step (perfbench/traffic/train-z2-micro2-8k.json)
+def test_kanana_share_step_fits_the_chip(v5e, monkeypatch):
+    """The cell's training step at the published widths, the job file's own
+    ``model_overrides``, one dense and ONE sparse layer (16 of 128 experts
+    held, an eighth of the vocabulary), 2 x 8,192 tokens: loss, gradients,
+    clipping and AdamW compile for a v5e with the flash kernel forward and
+    backward under ``dstpu.attn.mla`` in every layer (a whole sequence's K
+    and V in VMEM: the call asks for more than Mosaic's 16 MB default), and
+    its temporaries beside the whole cell's state (five layers: float32
+    master and moments, bfloat16 parameters and gradients) stay inside the
+    chip. The five-layer step itself compiled to 14.86 GB (sandbox compile,
+    PR 47: PERF.md section 4); with the flash residuals kept (``save_flash``)
+    to 16.06 GB, which is why the job file says ``nothing_saveable``."""
+    import dataclasses
+    from deepspeed_tpu.models.deepseek_v3 import (KANANA_2_30B_A3B,
+                                                  DeepseekV3)
+    from deepspeed_tpu.ops.optimizers import FusedAdam
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(os.path.dirname(__file__), "..", "..",
+                           "perfbench", "traffic",
+                           "train-z2-micro2-8k.json")) as f:
+        job = json.load(f)
+    micro, T = job["micro_batch_per_chip"], job["seq_len"]
+    cell = dataclasses.replace(
+        KANANA_2_30B_A3B, n_layer=5, experts_held=16, vocab_size=16032,
+        max_seq_len=T, dtype="bfloat16", **job["model_overrides"])
+    model = DeepseekV3(dataclasses.replace(cell, n_layer=2))
+    opt = FusedAdam(lr=2e-4, weight_decay=0.01)
+    buffers = model.buffer_names()
+
+    def on_chip(tree, dtype, buffer):
+        """``tree``'s leaves as ``dtype`` shapes on the chip, a buffer leaf
+        as ``buffer`` says."""
+        return jax.tree_util.tree_map_with_path(
+            lambda path, x: buffer(x) if path[-1].key in buffers
+            else jax.ShapeDtypeStruct(x.shape, dtype, sharding=v5e), tree)
+
+    shapes = jax.eval_shape(model.init, jax.random.key(0))
+    params = on_chip(shapes, bf16, lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=v5e))
+    master = on_chip(shapes, jnp.float32, lambda x: None)
+
+    def step(params, master, m, v, n, ids):
+        loss, grads = jax.value_and_grad(
+            lambda p: model.loss(p, {"input_ids": ids}))(params)
+        grads = jax.tree_util.tree_map_with_path(
+            lambda path, g: None if path[-1].key in buffers
+            else g.astype(jnp.float32), grads)
+        norm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
+                            for g in jax.tree.leaves(grads)))
+        grads = jax.tree.map(lambda g: g * jnp.minimum(1.0, 1.0 / norm),
+                             grads)
+        new_master, state = opt.update(grads, {"step": n, "m": m, "v": v},
+                                       master, lr=2e-4)
+        return (jax.tree.map(lambda x: x.astype(bf16), new_master),
+                new_master, state["m"], state["v"], loss)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2, 3)).lower(
+        params, master, master, master,
+        jax.ShapeDtypeStruct((), i32, sharding=v5e),
+        jax.ShapeDtypeStruct((micro, T), i32, sharding=v5e)).compile()
+    text = compiled.as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
+                       r'op_name="([^"]*)"', text)
+    mla = [c for c in calls if "dstpu.attn.mla" in c]
+    # a layer: the forward, its recomputation, the backward
+    assert len(mla) == 3 * 2, calls
+    assert sum("transpose(jvp(" in c for c in mla) == 2 * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    state = 16 * cell.num_params()       # 2 + 2 + 4 + 4 + 4 bytes each
+    assert abs(state - 9.215e9) < 0.01e9
+    # what 5 layers add to the 2-layer step's temporaries: three more sparse
+    # layers' gradients and the inputs a step keeps of them
+    sparse = sum(cell.layer_params()[::2])
+    more = 3 * (2 * sparse + micro * T * cell.d_model * 2)
+    assert temp <= 5.6e9, temp
+    assert 14 * cell.num_params() + temp + more <= V5E_GB * 1e9, (temp, more)
