@@ -17,14 +17,16 @@ def dispatches(v, *kinds):
             if not kinds or e.stats.get("kind") in kinds]
 
 
-def median_dispatch_ms(v, kind, metric):
-    """Median duration in ms of the window's dispatch spans of ``kind``;
-    says how many it read."""
-    hit = dispatches(v, kind)
+def median_dispatch_ms(v, metric, *kinds):
+    """Median duration in ms of the window's dispatch spans of ``kinds``;
+    says how many it read, by kind."""
+    hit = dispatches(v, *kinds)
     if not hit:
         return None
     ms = [1e3 * e.dur for e in hit]
-    v.say(metric, spans=len(ms), min_ms=min(ms), max_ms=max(ms))
+    v.say(metric, spans=len(ms), min_ms=min(ms), max_ms=max(ms),
+          by_kind={k: sum(1 for e in hit if e.stats.get("kind") == k)
+                   for k in kinds})
     return common.percentile(ms, 50)
 
 

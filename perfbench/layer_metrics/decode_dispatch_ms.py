@@ -7,4 +7,4 @@ from pbench import common
 
 def read(v):
     return common.load_module("layer_metrics", "batch_occupancy") \
-        .median_dispatch_ms(v, "decode", "decode_dispatch_ms")
+        .median_dispatch_ms(v, "decode_dispatch_ms", "decode")

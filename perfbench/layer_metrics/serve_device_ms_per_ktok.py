@@ -1,6 +1,7 @@
 """Device-busy milliseconds per 1000 tokens processed in the traced window:
-prompt tokens of the requests whose first token appeared there plus the
-decode tokens the steps returned (the same count as serve_tok_s)."""
+prompt tokens the traced steps brought, chunk by chunk as the prefills
+advanced (``runners/serve.py:Driver.count_prompt``), plus the decode tokens
+the steps returned (the same count as serve_tok_s)."""
 
 
 def read(v):

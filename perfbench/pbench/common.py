@@ -87,14 +87,18 @@ class CheckFailed(RuntimeError):
 
 
 class Checks:
-    """Collects what decides ``correct``; every check is printed."""
+    """Collects what decides ``correct``; every check is printed as it is
+    made, and ``made`` keeps each with the numbers it compared for the
+    result line's last key and the last lines of standard error."""
 
     def __init__(self):
         self.failed = []
+        self.made = []
 
     def require(self, ok, what, **fields):
         ok = bool(ok)
         say("check", ok=ok, what=what, **fields)
+        self.made.append({"ok": ok, "what": what, **fields})
         if not ok:
             self.failed.append(what)
         return ok

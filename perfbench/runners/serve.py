@@ -31,7 +31,7 @@ SERVE_GAP_TOL = 0.1
 
 class Rec:
     __slots__ = ("due", "prompt", "max_new", "uid", "t_put", "t_first",
-                 "t_done", "n_out", "tokens", "failed")
+                 "t_done", "n_out", "tokens", "failed", "counted")
 
     def __init__(self, r):
         self.due, self.prompt = r["due_s"], r["prompt"]
@@ -39,6 +39,7 @@ class Rec:
         self.uid = self.t_put = self.t_first = self.t_done = None
         self.tokens = None
         self.n_out = 0
+        self.counted = 0        # prompt tokens a step has brought so far
         self.failed = None
 
 
@@ -59,12 +60,21 @@ def build(ctx):
     return router, engine, builder.sizes(ctx.cfg)
 
 
-def tokens_so_far(router, uid):
-    """How many tokens of an unfinished request a client could hold."""
+def progress(router, uid):
+    """-> (prompt tokens the program has taken in, tokens a client could
+    hold) of an unfinished request. The first is the host-side
+    ``prefill_offset`` of the request's sequence (``inference/v2/ragged.py``),
+    which a split-fuse chunk dispatch advances by its chunk and a bucketed
+    prefill not before its token is readable; 0 while the request waits
+    for a slot."""
     for rep in router.replicas:
         if uid in rep.inflight:
-            return len(rep.engine.get(uid, flush=False))
-    return 0
+            try:
+                seq = rep.engine.state_mgr.get_sequence(uid)
+            except KeyError:        # queued in the engine, or just retired
+                return 0, len(rep.engine.get(uid, flush=False))
+            return seq.prefill_offset, len(seq.generated)
+    return 0, 0
 
 
 class Driver:
@@ -74,9 +84,14 @@ class Driver:
     def __init__(self, router):
         self.router = router
         self.live = {}          # uid -> Rec, put and not done
-        # (t_start, t_end, pairs, kv_tokens_read, prompt tokens whose first
-        # token this step brought)
+        # (t_start, t_end, pairs, kv_tokens_read, prompt tokens this step
+        # brought: see count_prompt)
         self.steps = []
+        # the PLAIN decode dispatch's steps (8). A FUSED dispatch, a chunk
+        # beside running decodes, carries engine_v2._FUSED_STEPS = 2 since
+        # PR 45, so kv_tokens_read over-counts where chunks fuse (cells 4,
+        # 9, 10); only cell 3 reads it (paged_decode_roofline), and its
+        # engine is bucketed and never fuses
         self.decode_steps = router.replicas[0].engine.config \
             .decode_steps_per_dispatch
 
@@ -102,7 +117,7 @@ class Driver:
         with tracing.span("perfbench.router_step"):
             pairs = self.router.step()
         t = clock()
-        prompts_done = 0
+        brought = 0
         with tracing.span("perfbench.client_read"):
             for uid, _tok in pairs:
                 rec = self.live.get(uid)
@@ -119,16 +134,34 @@ class Driver:
                     rec.t_done = t
                     if rec.t_first is None:
                         rec.t_first = t
-                        prompts_done += len(rec.prompt) + 1
+                        brought += self.count_prompt(
+                            rec, len(rec.prompt) + 1)
                     del self.live[uid]
                 elif rec.t_first is None:
-                    seen = tokens_so_far(self.router, uid)
+                    taken_in, seen = progress(self.router, uid)
                     if seen:
                         rec.t_first = t
                         rec.n_out = max(rec.n_out, seen)
-                        prompts_done += len(rec.prompt) + 1
-        self.steps.append((t0, t, len(pairs), kv, prompts_done))
+                    brought += self.count_prompt(
+                        rec, len(rec.prompt) + 1 if seen else taken_in)
+        self.steps.append((t0, t, len(pairs), kv, brought))
         return t
+
+    @staticmethod
+    def count_prompt(rec, upto):
+        """Prompt tokens of ``rec`` this step brought, its count now
+        standing at ``upto``. A prompt's tokens count as the program takes
+        them in: each chunk with the step that advanced the prefill past it
+        (``upto`` = the sequence's ``prefill_offset``), and whatever is not
+        yet counted when the first token becomes readable with that step,
+        beside the +1 for the token the prefill emits (``upto`` =
+        ``len(prompt) + 1``). So a request counts ``len(prompt) + 1`` over
+        its life: a chunk at a time where its prefill is streamed
+        (``splitfuse_tokens``), whole with its first token where it is one
+        bucketed program."""
+        new = max(0, upto - rec.counted)
+        rec.counted += new
+        return new
 
     def run_until(self, recs, clock, stop_s, on_tick=None):
         """Put each of ``recs`` when it is due and step the router until
@@ -148,6 +181,14 @@ class Driver:
                 time.sleep(max(0.0, min(0.002, nxt - now)))
                 continue
             self.step(clock)
+
+
+def tokens_processed(steps, seconds):
+    """Tokens the system processed inside the window [0, seconds): the
+    decode pairs and the prompt tokens (``Driver.count_prompt``) of the
+    steps that returned inside it. ``serve_tok_s`` is this over
+    ``seconds``."""
+    return sum(st[2] + st[4] for st in steps if 0.0 <= st[1] < seconds)
 
 
 def warm_up(ctx, router, sizes, rng):
@@ -235,6 +276,7 @@ def check_against_reference(ctx, engine, sizes, done, rng):
         worst <= SERVE_GAP_TOL,
         "sampled requests: every emitted token within 0.1 std of the "
         "float32 reference maximum", worst_gap_in_std=worst,
+        limit_in_std=SERVE_GAP_TOL,
         reference_argmax_share=float(np.mean(argmax_share)),
         requests=len(pick))
 
@@ -326,12 +368,7 @@ def run(ctx):
         check_against_reference(ctx, engine, sizes, s["done"],
                                 np.random.default_rng(ctx.seed + 1))
 
-    # tokens the system processed inside the window, each counted at the
-    # Router.step whose return made it readable: a prompt's tokens (and the
-    # token its prefill emits) with the request's first token, a decode
-    # token with the pair that carries it
-    whole = [st for st in driver.steps if 0.0 <= st[1] < seconds]
-    processed = sum(st[2] + st[4] for st in whole)
+    processed = tokens_processed(driver.steps, seconds)
     in_trace = [st for st in driver.steps
                 if "t1" in marks and st[0] >= marks["t0"]
                 and st[1] <= marks["t1"]]
@@ -347,7 +384,8 @@ def run(ctx):
         "traced_prompt_tokens": sum(st[4] for st in in_trace),
         "traced_kv_tokens_read": sum(st[3] for st in in_trace),
         # requests whose prefill ended inside the traced slice: the chunk
-        # and prefill kernels' work (edge effects at both ends cancel)
+        # and prefill kernels' work (edge effects at both ends cancel).
+        # Whole prompts, unlike traced_prompt_tokens, which counts chunks
         "traced_prompts": [len(r.prompt) for r in recs
                            if r.t_first is not None and "t1" in marks
                            and marks["t0"] <= r.t_first <= marks["t1"]],

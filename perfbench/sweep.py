@@ -14,11 +14,23 @@ closes no more than as it opens, within ``--backlog-slack``) and at least
 ``--attain`` of the requests due in the window meet the traffic file's
 ``limits`` (time to first token and time per output token, from the due
 time; a failed request misses). Rates are offered, never searched for.
+
+    python3 perfbench/sweep.py --workload <name> --repeat 6 --seconds 45 \\
+        --seed 2147490000 [--rates 0.8]
+
+``--repeat N`` is the other question a cell is asked when it is defined or
+measured anew: N seeds (--seed, --seed + 1, ...) at ONE rate, the traffic
+file's unless --rates names one. It prints each window's ``serve_tok_s``,
+their quartile spread, (q3 - q1) / median by ``statistics.quantiles(n=4)``,
+and whether that is under half the metric's bound (README, "Spread").
+One engine serves all N windows, so this costs a set-up once; the sets a PR
+records are still made through ``run.py``, a process a seed.
 """
 
 import argparse
 import json
 import os
+import statistics
 import sys
 import time
 
@@ -31,10 +43,36 @@ sys.path.insert(0, ROOT)
 from pbench import common      # noqa: E402
 
 
+def quartile_spread(values):
+    """(q3 - q1) / median, the quartiles as ``statistics.quantiles(n=4)``
+    gives them: the spread the driver judges a bound by."""
+    q1, _, q3 = statistics.quantiles(values, n=4)
+    return (q3 - q1) / statistics.median(values)
+
+
+def repeat_summary(bench, workload, rows, device):
+    """The last line of a ``--repeat`` call."""
+    out = {"workload": workload, "rate_per_s": rows[0]["rate_per_s"],
+           "seeds": [r["seed"] for r in rows],
+           "backlog": [[r["live_at_open"], r["live_at_close"]]
+                       for r in rows], "device": device}
+    values = [r["serve_tok_s"] for r in rows if "serve_tok_s" in r]
+    if len(values) >= 2:        # a rehearsal has counts and no rate
+        bound = next(m["bound"] for m in common.cell_metrics(
+            bench, "end_to_end", workload) if m["name"] == "serve_tok_s")
+        spread = quartile_spread(values)
+        out.update(serve_tok_s=values, median=statistics.median(values),
+                   spread=spread, half_bound=bound / 2,
+                   under_half_bound=spread <= bound / 2)
+    return out
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
-    ap.add_argument("--rates", required=True)
+    ap.add_argument("--rates", default=None)
+    ap.add_argument("--repeat", type=int, default=0, metavar="N",
+                    help="N seeds at one rate: the spread of serve_tok_s")
     ap.add_argument("--seconds", type=float, default=20.0)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--attain", type=float, default=0.9)
@@ -48,7 +86,15 @@ def main():
 
     import types
     import numpy as np
-    _, cell, cfg, job = common.load_cell(args.workload, args.rehearse)
+    bench, cell, cfg, job = common.load_cell(args.workload, args.rehearse)
+    rates = [float(r) for r in args.rates.split(",")] if args.rates \
+        else [job["arrivals"]["rate_per_s"]]
+    if args.repeat:
+        if len(rates) != 1:
+            ap.error("--repeat takes one rate")
+        rates *= args.repeat
+    elif not args.rates:
+        ap.error("--rates or --repeat")
     for kv in args.engine:
         key, value = kv.split("=")
         job["engine"][key] = int(value)
@@ -67,9 +113,11 @@ def main():
                **ctx.meter.snapshot())
 
     rows = []
-    for k, rate in enumerate(float(r) for r in args.rates.split(",")):
+    for k, rate in enumerate(rates):
         job_r = dict(job, arrivals=dict(job["arrivals"], rate_per_s=rate),
-                     drain_s=max(job["drain_s"], 120))
+                     # a full drain: what a rate leaves behind would eat
+                     # the next rate's window uncounted
+                     drain_s=max(job["drain_s"], 600))
         ctx.seed = args.seed + k
         recs, driver, marks = serve.run_window(ctx, router, sizes, job_r,
                                                args.seconds)
@@ -78,7 +126,8 @@ def main():
         _, compiles_open, live_open = marks["open"]
         compiles_close, live_close, (peak, _) = marks["close"]
         row = {
-            "engine": job["engine"], "rate_per_s": rate, "seed": ctx.seed, "seconds": args.seconds,
+            "engine": job["engine"], "rate_per_s": rate, "seed": ctx.seed,
+            "seconds": args.seconds,
             "measured": s["measured"], "completed": s["completed"],
             "failed": s["failed"], "unfinished": s["unfinished"],
             "live_at_open": live_open, "live_at_close": live_close,
@@ -91,8 +140,8 @@ def main():
         }
         if not args.rehearse:       # times and rates: chip runs only
             row.update(
-                serve_tok_s=sum(st[2] + st[4] for st in driver.steps
-                                if 0 <= st[1] < args.seconds) / args.seconds,
+                serve_tok_s=serve.tokens_processed(
+                    driver.steps, args.seconds) / args.seconds,
                 completed_tok_s=s["tokens_in_window"] / args.seconds,
                 ttft_p50_ms=common.percentile(s["ttft_ms"], 50),
                 ttft_p90_ms=common.percentile(s["ttft_ms"], 90),
@@ -108,6 +157,9 @@ def main():
             with open(os.path.join(ROOT, args.out), "a") as f:
                 f.write(json.dumps({"workload": args.workload, **row})
                         + "\n")
+    if args.repeat:
+        print(json.dumps(repeat_summary(bench, args.workload, rows, device)))
+        return 0
     sustained = [r["rate_per_s"] for r in rows if r["sustained"]]
     print(json.dumps({"workload": args.workload,
                       "knee_rate_per_s": max(sustained) if sustained
