@@ -1258,12 +1258,13 @@ class InferenceEngineV2:
         by: ``<mechanism>_calls`` / ``<mechanism>_kernel_calls``, of each
         mechanism of ``monitor/tag_schema.py`` ``KERNEL_SHARES`` (0 / 0
         where no trace noted it), which the telemetry's
-        ``*_kernel_share`` keys are fed from."""
+        ``*_kernel_share`` keys are fed from. What else a trace noted (a
+        prefill's flash calls) is not the spans'."""
         counts = {name: [0, 0] for name in KERNEL_SHARES}
         for program in programs:
-            for name, (calls, kernel) in self._calls.get(
-                    program, {}).items():
-                pair = counts.setdefault(name, [0, 0])
+            noted = self._calls.get(program, {})
+            for name, pair in counts.items():
+                calls, kernel = noted.get(name, (0, 0))
                 pair[0] += calls
                 pair[1] += kernel
         if self.telemetry is not None:

@@ -21,9 +21,9 @@ logits (the product at ``HIGHEST``), softmax and loss.
 **Attention** is the expanded form: every position's latent to every head's
 192-wide key (128 no-position dims + the ONE 64-wide rotary key all heads
 share) and 128-wide value, then causal flash attention
-(``ops/pallas/flash_attention.py``) forward and backward. That kernel has
-one head width: V is padded with zeros to the key width and the output
-sliced (exact; the padding's operations are waste, PERF.md section 7).
+(``ops/pallas/flash_attention.py``) forward and backward. That kernel
+takes the values at their own width: V goes in 128 wide beside the
+192-wide keys and the output comes back 128 wide, nothing padded or sliced.
 
 **One chip's share of an expert-parallel group.** The router keeps the
 published ``n_routed_experts`` outputs; the layer holds ``experts_held`` of
@@ -275,13 +275,10 @@ class DeepseekV3:
                 k_pe.astype(x.dtype)[:, :, None], (B, T, H, dr))], axis=-1)
             if resolve_flash(cfg.use_flash_attention):
                 from ..ops.pallas.flash_attention import flash_attention
-                # the kernel has one head width: zero value columns give
-                # zero output columns and take zero cotangents
                 o = flash_attention(
-                    q, k, jnp.pad(v, ((0, 0),) * 3 + ((0, dn + dr - dv),)),
-                    causal=True, scale=cfg.softmax_scale,
+                    q, k, v, causal=True, scale=cfg.softmax_scale,
                     block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
-                    block_h=1)[..., :dv]
+                    block_h=1)
             else:
                 s = jnp.einsum("bthd,bshd->bhts", q, k,
                                preferred_element_type=jnp.float32) \

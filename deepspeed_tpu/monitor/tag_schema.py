@@ -216,6 +216,13 @@ TAG_SCHEMA = {
 # ``telemetry_snapshot()`` key of the share of its calls that took the kernel
 KERNEL_SHARES = {"expert": "moe_kernel_share", "rule": "rule_kernel_share",
                  "latent_read": "latent_kernel_share"}
+# a mechanism that is a Pallas kernel either way and whose pair counts a
+# choice its operands' shapes make, by the name it notes its calls under in
+# the same tally -> the word for the second count where the training engine
+# says a traced step's tally (``runtime/engine.py`` ``_noting_calls``:
+# "flash: 2 calls, 2 two-width", the calls whose values have a width of
+# their own, ``ops/pallas/flash_attention.py``). No span or tag carries it.
+SHAPE_PATHS = {"flash": "two-width"}
 # what a program call's trace noted of them (``counting_calls``), as the
 # dispatch and prefill spans say it
 _TALLY_STATS = tuple(f"{name}{stat}" for name in KERNEL_SHARES

@@ -27,9 +27,12 @@ def counting_calls():
     block of each mechanism that has a Pallas form and another (``"expert"``:
     an MoE layer's SwiGLU chain; ``"rule"``: the gated delta rule, either
     form; ``"latent_read"``: a latent cache's selected read), and those of
-    them that took the kernel (:func:`note_call` says which). Trace-time
-    Python: a serving engine puts it round a program's traced body, for its
-    dispatch span (``<name>_calls`` / ``<name>_kernel_calls``)."""
+    them that took the kernel (:func:`note_call` says which); and of
+    ``"flash"``, a kernel either way, the calls and those whose values have
+    a width of their own. Trace-time Python: a serving engine puts it round
+    a program's traced body, for its dispatch span (``<name>_calls`` /
+    ``<name>_kernel_calls``), the training engine round its step's, for
+    its log."""
     counts = {}
     token = _CALLS.set(counts)
     try:
@@ -40,7 +43,7 @@ def counting_calls():
 
 def note_call(name, kernel):
     """A traced body's word that it makes one call of ``name`` here, through
-    a Pallas kernel or not."""
+    a Pallas kernel or not (``"flash"``: with two head widths or one)."""
     counts = _CALLS.get()
     if counts is not None:
         pair = counts.setdefault(name, [0, 0])

@@ -37,6 +37,7 @@ from ._common import dispatch as _dispatch
 from ._common import dtype_name as _dtype_name
 from ._common import flash_bucket as _flash_bucket
 from ._common import interpret_default as _interpret_default
+from ._common import note_call as _note_call
 from ._common import round_up as _round_up
 from ._common import sds as _sds
 
@@ -66,9 +67,10 @@ NEG_INF = -1e30
 # (forward: K and V; backward: q, dO, o, lse and the float32 dq), double
 # buffered. Mosaic's scoped default (16 MB) holds that at GPT-2's shapes
 # (T 1024 - 2048, d 64); a long sequence at a wide head does not fit it
-# (T 8192 at d 256: 17.5 MB forward, ~50 MB backward), so such a call asks
-# for what its blocks need, of the 128 MiB a v5e core has. A call that fits
-# the default is compiled as it always was.
+# (T 8192 at 256 lanes of keys and 128 of values: 15 MB forward, ~45 MB
+# backward, with two buffers of each), so such a call asks for what its
+# blocks need, of the 128 MiB a v5e core has. A call that fits the default
+# is compiled as it always was.
 _VMEM_DEFAULT = 16 << 20
 
 
@@ -338,8 +340,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, bq, bk, scale,
             return acc, m_new, l
         return body
 
-    d = q_ref.shape[-1]
-    acc = jnp.zeros((G, bq, d), jnp.float32)
+    acc = jnp.zeros((G, bq, v_ref.shape[-1]), jnp.float32)
     m = jnp.full((G, bq), NEG_INF, jnp.float32)
     l = jnp.zeros((G, bq), jnp.float32)
     carry = jax.lax.fori_loop(kmin, kfull, make_body(False), (acc, m, l))
@@ -354,6 +355,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, bq, bk, scale,
 def _fwd(q, k, v, scale, causal, bq, bk, bh, t_real, interpret, window=0,
          biases=(), bias_cfgs=(), alibi_cfg=None):
     BH, T, d = q.shape
+    dv = v.shape[-1]        # the values', and the output's, own width
     grid = (BH // bh, T // bq)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, bq=bq, bk=bk, scale=scale,
@@ -363,21 +365,24 @@ def _fwd(q, k, v, scale, causal, bq, bk, bh, t_real, interpret, window=0,
         in_specs=[
             pl.BlockSpec((bh, bq, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((bh, T, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((bh, T, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((bh, T, dv), lambda b, i: (b, 0, 0)),
         ] + _fwd_bias_specs(bias_cfgs, biases, bq, T, bh)
           + ([pl.BlockSpec((1, bq, T), lambda b, i: (0, i, 0))]
              if alibi_cfg else []),
         out_specs=[
-            pl.BlockSpec((bh, bq, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((bh, bq, dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((bh, bq, LSE_LANES), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
-            _sds((BH, T, d), q.dtype, q),
+            _sds((BH, T, dv), q.dtype, q),
             _sds((BH, T, LSE_LANES), jnp.float32, q),
         ],
         interpret=interpret,
-        **_vmem_params(*(2 * [bh * T * _lanes(d) * q.dtype.itemsize]
-                         + 2 * [bh * bq * _lanes(d) * q.dtype.itemsize]
+        # K and V whole; the q and o blocks; the lse block
+        **_vmem_params(*([bh * T * _lanes(w) * q.dtype.itemsize
+                          for w in (d, dv)]
+                         + [bh * bq * _lanes(w) * q.dtype.itemsize
+                            for w in (d, dv)]
                          + [bh * bq * 128 * 4])),
     )(q, k, v, *biases)
     return o, lse
@@ -632,9 +637,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, od_ref,
             return dk, dv
         return body
 
-    d = q_ref.shape[-1]
-    dk = jnp.zeros((G, bk, d), jnp.float32)
-    dv = jnp.zeros((G, bk, d), jnp.float32)
+    dk = jnp.zeros(kb.shape, jnp.float32)
+    dv = jnp.zeros(vb.shape, jnp.float32)
     dk, dv = jax.lax.fori_loop(qmin, qfull, make_body(True), (dk, dv))
     dk, dv = jax.lax.fori_loop(qfull, qend, make_body(False), (dk, dv))
     # ds was computed from unscaled-q dots (scale applied to s post-dot),
@@ -680,6 +684,7 @@ def _bwd(q, k, v, o, lse_t, do, scale, causal, bq, bk, bh, t_real,
          interpret, dlse=None, window=0, biases=(), bias_cfgs=(),
          alibi_cfg=None):
     BH, T, d = q.shape
+    dv = v.shape[-1]        # v, o, dO and dv's width; q, k, dq, dk keep d
     # (BH, T, 1) -> LSE_LANES lanes for the operand block; XLA lowers
     # this to one small relayout/broadcast per layer (~8 ms/step total)
     lse = jnp.broadcast_to(lse_t, (BH, T, LSE_LANES))
@@ -705,10 +710,10 @@ def _bwd(q, k, v, o, lse_t, do, scale, causal, bq, bk, bh, t_real,
         in_specs=[
             pl.BlockSpec((bh, T, d), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((bh, bk, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((bh, bk, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((bh, T, d), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((bh, bk, dv), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((bh, T, dv), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((bh, T, LSE_LANES), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((bh, T, LSE_LANES if dlse is not None else d),
+            pl.BlockSpec((bh, T, LSE_LANES if dlse is not None else dv),
                          lambda b, j: (b, 0, 0)),
         ] + _bwd_bias_specs(bias_cfgs, biases, bk, T, bh)
           + ([pl.BlockSpec((1, T, bk), lambda b, j: (0, 0, j))]
@@ -716,7 +721,7 @@ def _bwd(q, k, v, o, lse_t, do, scale, causal, bq, bk, bh, t_real,
         out_specs=[
             pl.BlockSpec((bh, T, d), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((bh, bk, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((bh, bk, d), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((bh, bk, dv), lambda b, j: (b, j, 0)),
         ] + db_specs,
         out_shape=[
             # dq accumulates fp32 across key-block grid steps; with a
@@ -724,16 +729,18 @@ def _bwd(q, k, v, o, lse_t, do, scale, causal, bq, bk, bh, t_real,
             # emitted in the model dtype with no cast copy
             _sds((BH, T, d), q.dtype if single_k else jnp.float32, q),
             _sds((BH, T, d), q.dtype, q),
-            _sds((BH, T, d), q.dtype, q),
+            _sds((BH, T, dv), q.dtype, q),
         ] + db_shapes,
         interpret=interpret,
         # q, dO, o (or delta), lse whole; dq whole in float32 unless one
-        # key block; the k / v / dk / dv blocks
-        **_vmem_params(*(3 * [bh * T * _lanes(d) * q.dtype.itemsize]
+        # key block; the k / dk and the v / dv blocks
+        **_vmem_params(*([bh * T * _lanes(w) * q.dtype.itemsize
+                          for w in (d, dv, dv)]
                          + [bh * T * 128 * 4]
                          + [bh * T * _lanes(d)
                             * (q.dtype.itemsize if single_k else 4)]
-                         + 4 * [bh * bk * _lanes(d) * q.dtype.itemsize])),
+                         + [bh * bk * _lanes(w) * q.dtype.itemsize
+                            for w in (d, d, dv, dv)])),
     )(q, k, v, do, lse, od, *biases)
     dq, dk, dv = outs[:3]
     dbiases = _scatter_dbias(biases, bias_cfgs, outs[3:])
@@ -1115,10 +1122,15 @@ def _block_bh(block_h, BH):
     return bh
 
 
+def _head_pad(d):
+    """A head width as the kernels carry it: up to 64, or to a multiple of
+    the 128 lanes (``flash_attention_with_lse`` says why)."""
+    return _round_up(d, 64) if d <= 64 else _round_up(d, 128)
+
+
 def _block_pads(T, d, block_q, block_k):
     bq, bk, T_pad = _block_sizes(T, block_q, block_k)
-    d_pad = _round_up(d, 64) if d <= 64 else _round_up(d, 128)
-    return bq, bk, T_pad, d_pad
+    return bq, bk, T_pad, _head_pad(d)
 
 
 def flash_block_state(BH, T, d):
@@ -1367,6 +1379,18 @@ def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
     caller's matmuls (XLA otherwise warps the producing matmul's output
     layout to feed the custom call, costing ~2x on its emitter).
 
+    **The values may have a width of their own**: ``v`` (..., dv) beside
+    ``q`` and ``k`` (..., d), as MLA's 128 beside 192; ``o`` is then dv
+    wide. The kernels carry V, the accumulator, o, dO and dv at
+    ``_lanes(dv)`` and q, k, dq, dk at ``_lanes(d)``, so P·V, dP = dO·Vᵀ
+    and dV = Pᵀ·dO run over the values' columns and nothing is padded to
+    the keys' width or sliced back. Read off the shapes: a call of one
+    width is the program it always was, letter for letter
+    (``tests/unit/test_deepseek_v3.py``). The standard layout's kernels
+    alone take two widths; with ``qkv_t``, ``window``, ``bias`` or
+    ``alibi`` such a call raises. Each call is noted in the trace-time
+    tally, ``_common.note_call("flash", dv != d)``.
+
     Additive score biases (counterpart of the reference's bias-taking
     attention kernels — evoformer_attn kernel_forward.h:986 bias1/bias2,
     inference softmax.cu:562 alibi+mask):
@@ -1408,6 +1432,13 @@ def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
         B, H, T, d = q.shape
     else:
         B, T, H, d = q.shape
+    dv = v.shape[2 if qkv_t else 3]
+    if dv != d and (qkv_t or window or bias is not None or alibi is not None
+                    or _folded_biases):
+        raise NotImplementedError(
+            f"values of their own width ({dv} beside keys of {d}) are the "
+            "standard-layout kernels' alone: qkv_t, a window, a bias and "
+            "ALiBi take one head width (pad v to the keys' and slice o)")
     if _AUTO in (block_q, block_k, block_h, block_q_bwd, block_k_bwd,
                  bwd_qmajor):
         # measured dispatch: tunables set to "auto" take the cached
@@ -1456,6 +1487,7 @@ def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
             bwd_qmajor=False, _folded_biases=_folded_biases,
             _with_lse=_with_lse)
 
+    _note_call("flash", dv != d)
     # -------- bias descriptors -> bh constraints (before bh is picked)
     descs = []                                  # (arr4d, grad)
     alibi_cfg = None
@@ -1519,8 +1551,9 @@ def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
     # to 64, kept native: the smaller DMA footprint beats the MXU's
     # preference for 128 (evoformer's d=32 pays 2x, not 4x). The rule
     # applies under qkv_t too: d moves to sublanes for q/k/v but stays
-    # the lane dim of the o output block.
-    d_pad = _round_up(d, 64) if d <= 64 else _round_up(d, 128)
+    # the lane dim of the o output block. Values of their own width, and
+    # the output with them, are padded from that width by the same rule.
+    d_pad, dv_pad = _head_pad(d), _head_pad(dv)
 
     def fold(x):
         if qkv_t:
@@ -1535,9 +1568,11 @@ def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
             return x
         if not heads_major:
             x = x.transpose(0, 2, 1, 3)
-        x = x.reshape(B * H, T, d)
-        if T_pad != T or d_pad != d:
-            x = jnp.pad(x, ((0, 0), (0, T_pad - T), (0, d_pad - d)))
+        w = x.shape[-1]
+        w_pad = _head_pad(w)
+        x = x.reshape(B * H, T, w)
+        if T_pad != T or w_pad != w:
+            x = jnp.pad(x, ((0, 0), (0, T_pad - T), (0, w_pad - w)))
         return x
 
     # -------- fold + pad biases; build their static cfgs
@@ -1621,8 +1656,8 @@ def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
         # remat closed-call, so the dropped lse (and its lane-trim
         # slice, ~6 ms/step at 350M) must never be emitted at all
         o, lse = _flash_o(*args), None
-    if T_pad != T or d_pad != d:
-        o = o[:, :T, :d]
+    if T_pad != T or dv_pad != dv:
+        o = o[:, :T, :dv]
         lse = lse[:, :T] if lse is not None else None
     if qkv_t:
         # (H, B, ...) is the kernel's fold order; swap back to the
@@ -1632,7 +1667,7 @@ def flash_attention_with_lse(q, k, v, *, causal=True, scale=None,
         o = o.reshape(H, B, T, d).swapaxes(0, 1)
         return o, (lse.reshape(H, B, T).swapaxes(0, 1)
                    if lse is not None else None)
-    o = o.reshape(B, H, T, d)
+    o = o.reshape(B, H, T, dv)
     if not heads_major:
         o = o.transpose(0, 2, 1, 3)
     return o, lse.reshape(B, H, T) if lse is not None else None

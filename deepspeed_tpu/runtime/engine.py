@@ -256,6 +256,8 @@ class DeepSpeedEngine:
         # --- sharding plan + state materialization (reference zero.Init +
         #     _configure_zero_optimizer) ---
         self._build_state(seed)
+        # program name -> what its trace noted (_noting_calls)
+        self.traced_calls = {}
         self._build_programs()
 
         from .checkpoint_engine.engines import create_checkpoint_engine
@@ -615,6 +617,30 @@ class DeepSpeedEngine:
             kwargs["ltd_keep"] = ltd_keep
         return self.model.loss(params, batch, rng=rng, train=True, **kwargs)
 
+    def _noting_calls(self, step):
+        """``step`` — a program that traces the model's loss — under the
+        one trace-time tally (``ops/pallas/_common.py`` ``counting_calls``:
+        a mechanism's calls, and those of them that took the path its pair
+        counts), said once it is traced, remat's re-traces included, and
+        kept in ``self.traced_calls`` under the program's name. A model that
+        notes nothing says nothing."""
+        from ..monitor.tag_schema import SHAPE_PATHS
+        from ..ops.pallas._common import counting_calls
+
+        @functools.wraps(step)
+        def program(*args):
+            with counting_calls() as counts:
+                out = step(*args)
+            self.traced_calls[step.__name__] = counts
+            if counts:
+                log_dist(f"{step.__name__} traced: " + "; ".join(
+                    f"{name}: {calls} calls, {taken} "
+                    f"{SHAPE_PATHS.get(name, 'kernel')}"
+                    for name, (calls, taken) in sorted(counts.items())),
+                    ranks=[0])
+            return out
+        return program
+
     def _build_programs(self):
         gas = self.config.gradient_accumulation_steps
         clip = self.config.gradient_clipping
@@ -816,7 +842,7 @@ class DeepSpeedEngine:
         with jax.set_mesh(self.mesh):
             if self.offload_enabled:
                 self._grad_step_jit = jax.jit(
-                    grad_step, static_argnums=(2,),
+                    self._noting_calls(grad_step), static_argnums=(2,),
                     in_shardings=(st_sh(), None),
                     out_shardings=(self.grad_shardings, None))
                 self._offload_finalize_jit = jax.jit(
@@ -836,11 +862,13 @@ class DeepSpeedEngine:
                     in_shardings=(self.master_shardings,),
                     out_shardings=self.param_shardings)
             self._train_step_jit = None if self.offload_enabled else jax.jit(
-                train_step, donate_argnums=(0,), static_argnums=(3,),
+                self._noting_calls(train_step), donate_argnums=(0,),
+                static_argnums=(3,),
                 in_shardings=(st_sh(), None, None),
                 out_shardings=(st_sh(), None))
             self._micro_step_jit = jax.jit(
-                micro_step, in_shardings=(st_sh(), None, None),
+                self._noting_calls(micro_step),
+                in_shardings=(st_sh(), None, None),
                 out_shardings=(None, self.grad_shardings))
             eval_kwargs = {}
             if self.topology.get_sequence_parallel_world_size() > 1:

@@ -392,23 +392,38 @@ def test_a_dense_model_steps_as_it_did_at_the_parent(stage, gas):
 
 
 # ------------------------------------------------------------------- flash
-def test_flash_with_192_wide_keys_and_128_wide_values():
-    """The flash kernel (interpreted) at the published head widths, V padded
-    to the key width as ``DeepseekV3._attention`` pads it, against the dense
-    softmax: forward and the gradients of q, k and v."""
-    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-    B, T, H, dk, dv = 1, 256, 2, 192, 128
+def _mla_operands(T, dk=192, dv=128, B=1, H=2):
     ks = jax.random.split(jax.random.key(7), 4)
     q, k = (jax.random.normal(ks[i], (B, T, H, dk), jnp.float32)
             for i in range(2))
-    v = jax.random.normal(ks[2], (B, T, H, dv), jnp.float32)
-    ct = jax.random.normal(ks[3], (B, T, H, dv), jnp.float32)
+    v, ct = (jax.random.normal(ks[i], (B, T, H, dv), jnp.float32)
+             for i in (2, 3))
+    return q, k, v, ct
 
-    def flash(q, k, v):
-        o = flash_attention(q, k, jnp.pad(v, ((0, 0),) * 3 + ((0, dk - dv),)),
-                            causal=True, scale=dk ** -0.5, block_q=128,
-                            block_k=128, block_h=1, interpret=True)
+
+def _flash_loss(ct, pad=0, **kw):
+    """sum(o * ct) of an interpreted flash call, V as it is or with ``pad``
+    zero columns that the output is sliced of."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    kw = {"causal": True, "block_q": 128, "block_k": 128, "block_h": 1,
+          "interpret": True, **kw}
+
+    def loss(q, k, v):
+        dv = v.shape[-1]
+        if pad:
+            v = jnp.pad(v, ((0, 0),) * 3 + ((0, pad),))
+        o = flash_attention(q, k, v, scale=q.shape[-1] ** -0.5, **kw)
+        assert o.shape == v.shape
         return jnp.sum(o[..., :dv] * ct)
+    return loss
+
+
+def test_flash_with_192_wide_keys_and_128_wide_values():
+    """The flash kernel (interpreted) at the published head widths, V at its
+    own width as ``DeepseekV3._attention`` hands it over, against the dense
+    float32 softmax: forward and the gradients of q, k and v."""
+    T, dk = 256, 192
+    q, k, v, ct = _mla_operands(T)
 
     def dense(q, k, v):
         s = jnp.einsum("bthd,bshd->bhts", q, k, precision="highest") \
@@ -418,11 +433,136 @@ def test_flash_with_192_wide_keys_and_128_wide_values():
                                   v, precision="highest") * ct)
 
     with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.value_and_grad(flash, argnums=(0, 1, 2)))(q, k, v)
+        got = jax.jit(jax.value_and_grad(_flash_loss(ct),
+                                         argnums=(0, 1, 2)))(q, k, v)
     want = jax.jit(jax.value_and_grad(dense, argnums=(0, 1, 2)))(q, k, v)
     assert abs(float(got[0]) - float(want[0])) < 1e-3 * abs(float(want[0]))
     for g, w in zip(got[1], want[1]):
-        assert _rel(g, w) < TOL
+        assert g.shape == w.shape and _rel(g, w) < TOL
+
+
+def _equals_the_padded_call(T, block_h, dk=192, dv=128):
+    """Two widths against V padded to the keys' width and the output sliced,
+    which is what the model did: the zero columns gave zeros and took zero
+    cotangents, so leaving them out changes no bit, forward or backward."""
+    q, k, v, ct = _mla_operands(T, dk, dv)
+    got, want = (
+        jax.jit(jax.value_and_grad(_flash_loss(ct, pad, block_h=block_h),
+                                   argnums=(0, 1, 2)))(q, k, v)
+        for pad in (0, dk - dv))
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def _refuses(what):
+    """The transposed-operand kernels, a window, a bias and ALiBi take one
+    head width: with two the call says so and computes nothing."""
+    T, H = 128, 2
+    q, k, v, ct = _mla_operands(T, 24, 16, H=H)
+    kw = {"qkv_t": True, "window": 64,
+          "bias": jnp.zeros((1, H, T, T), jnp.float32),
+          "alibi": [2.0 ** -4, 2.0 ** -8]}[what]
+    if what == "qkv_t":
+        q, k, v = (x.transpose(0, 2, 3, 1) for x in (q, k, v))
+    with pytest.raises(NotImplementedError, match="one head width"):
+        jax.eval_shape(_flash_loss(ct, **{what: kw}), q, k, v)
+
+
+# An equal-width call as PR 51's tree (117a64c) lowered it, by the sha256 of
+# the text: ``lower().as_text()`` of the interpreted call (the kernels'
+# bodies are in it) and, for ``compiled``, the jaxpr of the call as the chip
+# compiles it (its blocks and the VMEM it asks for are in it). A PR that
+# changes these kernels on purpose regenerates them (run the test, the
+# assertion message holds the new value).
+PARENT_TEXT = {
+    ("std", "fwd", "plain"): "ad4072fd427876c1",
+    ("std", "fwd", "bias"): "11f48e4cd245807f",
+    ("std", "bwd", "plain"): "73964dd08344f320",
+    ("std", "bwd", "bias"): "895bae0ea0fe37b5",
+    ("qkv_t", "fwd", "plain"): "377cd5c0440b6dc2",
+    ("qkv_t", "fwd", "bias"): "1476c4e1b436c536",
+    ("qkv_t", "bwd", "plain"): "05aef29b64dfe5be",
+    ("qkv_t", "bwd", "bias"): "b30dfa6f10d56a94",
+    # the model's old call: 192 wide on both sides, 256 lanes in the kernels
+    ("std", "bwd", "d192"): "3e58b1ee455e7fe2",
+    # the same at the cell's size, bfloat16, as the chip compiles it
+    ("std", "bwd", "compiled"): "fbb9972189581fec",
+}
+
+
+def _lowers_to_the_parents_text(layout, passes, what):
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    B, T, H, d, dt, blocks = 2, 256, 2, 64, jnp.float32, 128
+    if what == "d192":
+        d = 192
+    if what == "compiled":
+        B, T, H, d, dt, blocks = 2, 8192, 32, 192, jnp.bfloat16, 1024
+    x = jax.ShapeDtypeStruct(
+        (B, H, d, T) if layout == "qkv_t" else (B, T, H, d), dt)
+    args = [x] * 3 + [jax.ShapeDtypeStruct((1, H, T, T), jnp.float32)] \
+        * (what == "bias")
+
+    def loss(q, k, v, *bias):
+        o = flash_attention(
+            q, k, v, causal=True, qkv_t=layout == "qkv_t", block_q=blocks,
+            block_k=blocks, block_h=1 if what == "compiled" else 2,
+            bias=bias[0] if bias else None, interpret=what != "compiled")
+        return jnp.sum(o.astype(jnp.float32))
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if passes == "bwd" else loss
+    text = str(jax.make_jaxpr(fn)(*args)) if what == "compiled" \
+        else jax.jit(fn).lower(*args).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == PARENT_TEXT[layout, passes, what]
+
+
+TWO_WIDTHS = {
+    **{f"equals_the_padded_call-T{T}-block_h{bh}":
+       (_equals_the_padded_call, (T, bh))
+       for T in (256, 200) for bh in (1, 2)},
+    # 24 and 16 both go to 64 lanes: two widths at the call, one inside
+    "equals_the_padded_call-under_the_lanes":
+        (_equals_the_padded_call, (96, 2, 24, 16)),
+    **{f"refuses-{what}": (_refuses, (what,))
+       for what in ("qkv_t", "window", "bias", "alibi")},
+    **{"one_width_is_the_parents_text-" + "-".join(key):
+       (_lowers_to_the_parents_text, key) for key in PARENT_TEXT},
+}
+
+
+@pytest.mark.parametrize("case", TWO_WIDTHS)
+def test_a_value_width_of_its_own(case):
+    """ISSUE 53: the standard-layout flash kernels take V, and give o, at
+    the values' own width; what cannot take two widths says so; a call of
+    one width is the program it was."""
+    check, args = TWO_WIDTHS[case]
+    check(*args)
+
+
+def test_the_engine_says_what_its_step_traced(monkeypatch):
+    """The training engine puts the one trace-time tally round its step:
+    the flash calls this model's trace makes (keys of 24, values of 16: two
+    widths each), one for the dense layer's block and one for the two
+    sparse layers', which are alike and so traced once under
+    ``jax.checkpoint``, with their one expert chain (``lax.ragged_dot`` on
+    the CPU, no kernel); said once in the log when the step is traced and
+    kept on the engine; a step that is not traced again says no more."""
+    from deepspeed_tpu.runtime import engine as engine_module
+    said = []
+    monkeypatch.setattr(engine_module, "log_dist",
+                        lambda text, **_: said.append(text))
+    model = DeepseekV3(dataclasses.replace(
+        CFG, dtype="bfloat16", use_flash_attention=True, flash_block_q=32,
+        flash_block_k=32))
+    engine, _, _, _ = deepspeed_tpu.initialize(model=model, seed=0,
+                                               config=ENGINE)
+    batch = {"input_ids": np.zeros((2 * len(jax.devices()), 48), np.int32)}
+    for _ in range(2):
+        engine.train_batch(batch)
+    assert engine.traced_calls == {
+        "train_step": {"flash": [2, 2], "expert": [1, 0]}}
+    assert [t for t in said if "traced" in t] == [
+        "train_step traced: expert: 1 calls, 0 kernel; "
+        "flash: 2 calls, 2 two-width"]
 
 
 def test_the_models_flash_path_equals_its_dense_path(params, ids, program):

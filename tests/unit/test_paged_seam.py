@@ -170,8 +170,10 @@ def test_the_engine_names_no_cache_kind():
     tally, ``counting_calls``, it opens round every program); exactly one
     trace-time tally exists under ``deepspeed_tpu/``, and every mechanism
     that notes its calls there has its row in the schema, which is where
-    the spans' and the snapshot's names come from."""
-    from deepspeed_tpu.monitor.tag_schema import KERNEL_SHARES
+    the spans' and the snapshot's names come from (``KERNEL_SHARES``) or,
+    for a choice that is no kernel-or-not and that no span carries, the
+    training engine's word for it (``SHAPE_PATHS``)."""
+    from deepspeed_tpu.monitor.tag_schema import KERNEL_SHARES, SHAPE_PATHS
     engine = _sources("inference", "v2")[os.path.join(
         "inference", "v2", "engine_v2.py")]
     named = re.findall(
@@ -184,7 +186,9 @@ def test_the_engine_names_no_cache_kind():
     assert tallies == {os.path.join("ops", "pallas", "_common.py"): 1}
     noted = {name for text in everything.values()
              for name in re.findall(r"note_call\(\s*\"(\w+)\"", text)}
-    assert noted == set(KERNEL_SHARES) == {"expert", "rule", "latent_read"}
+    assert set(KERNEL_SHARES) == {"expert", "rule", "latent_read"}
+    assert set(SHAPE_PATHS) == {"flash"}
+    assert noted == set(KERNEL_SHARES) | set(SHAPE_PATHS)
 
 
 @pytest.mark.parametrize("family", ["gpt2", "phi4flash", "deepseek_v32"])
