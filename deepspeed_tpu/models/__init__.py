@@ -19,6 +19,8 @@ from .deepseek_v32 import (DeepseekV32, DeepseekV32Config,
                            DEEPSEEK_V32)
 from .deepseek_v3 import (DeepseekV3, DeepseekV3Config, DEEPSEEK_V3_PRESETS,
                           DEEPSEEK_V3_TINY, KANANA_2_30B_A3B)
+from .solar_open2 import (SolarOpen2, SolarOpen2Config, SOLAR_OPEN2_PRESETS,
+                          SOLAR_OPEN2_TINY, SOLAR_OPEN2_250B)
 from .falcon import Falcon, FalconConfig, FALCON_PRESETS
 from .opt import OPT, OPTConfig, OPT_PRESETS
 from .gptj import GPTJ, GPTJConfig, GPTJ_PRESETS

@@ -314,14 +314,16 @@ SPAN_SCHEMA = {
                    "padding included, x those layers (both 0 on a model "
                    "without such a layer); "
                    "rule_calls = the calls of the gated delta rule the "
-                   "dispatch's program makes, chunkwise or one-token "
+                   "dispatch's program makes, chunkwise or one-token, "
+                   "with a gate a head or a key channel "
                    "(linear layers x its chunk call and decode steps), "
                    "noted when the program is traced as the expert calls "
                    "are, so 0 on a model without the rule and on the "
                    "dispatch that traces it; "
                    "rule_kernel_calls = those of rule_calls that are a "
                    "Pallas kernel (ops/pallas/gated_delta_rule.py), the "
-                   "rest the XLA form; "
+                   "rest the XLA form (a chunk whose gate is one a key "
+                   "channel always is: solar_open2); "
                    "index_keys = the causal keys a learned indexer scores "
                    "for the span's real query tokens (a chunk's "
                    "chunk_tokens from position chunk_start, the "
@@ -376,7 +378,9 @@ SPAN_SCHEMA = {
 # time and add no host sync. Linted both ways like the spans above.
 SCOPE_SCHEMA = {
     "dstpu.moe.route":
-        "MoE layer: pre-FFN norm, router product, float32 softmax, top-k, "
+        "MoE layer: pre-FFN norm, router product, float32 softmax (or "
+        "sigmoid scores and a correction bias: deepseek_v32, deepseek_v3, "
+        "solar_open2), top-k, "
         "sort by expert, gather of the routed rows (and, expert-parallel, "
         "the all_to_all out)",
     "dstpu.moe.experts":
@@ -394,23 +398,29 @@ SCOPE_SCHEMA = {
         "Gated Memory Unit: gate projection, product with the memory "
         "layer's scan output, out-projection",
     "dstpu.gdn.mix":
-        "gated delta-rule mixer (olmo_hybrid): q / k / v / z and gate "
-        "projections, causal conv, L2 norms, gates, the rule, the gated "
-        "norm a head, out-projection, and the slot state's read and write",
+        "delta-rule mixer of the two families that have one (olmo_hybrid: "
+        "a gate a head; solar_open2, KDA: a gate a key channel, low-rank "
+        "gates): q / k / v (/ z) and gate projections, causal conv, L2 "
+        "norms, gates, the rule, the gated norm a head, out-projection, "
+        "and the slot state's read and write",
     "dstpu.gdn.chunk":
         "inside dstpu.gdn.mix: the chunkwise-parallel rule of a prefill "
         "or chunk program, from the slot's state to the state after the "
         "last real token: the Pallas kernel that keeps the state in VMEM "
         "over the call's chunks (ops/pallas/gated_delta_rule.py) where the "
-        "step runs kernels, else ops/gated_delta_rule.py:chunk_rule",
+        "step runs kernels and the gate is one a head (olmo_hybrid), else "
+        "ops/gated_delta_rule.py:chunk_rule, which is all a gate a key "
+        "channel has (solar_open2): one scope, both families",
     "dstpu.gdn.step":
         "inside dstpu.gdn.mix: the rule's one-token update of a decode "
         "step: the Pallas kernel over the step's live slots, their state "
         "read once and written once in place, where the step runs "
-        "kernels, else step_rule over every slot",
+        "kernels, else step_rule over every slot; either gate (a head, "
+        "olmo_hybrid; a key channel, solar_open2)",
     "dstpu.attn.full":
-        "a full-attention layer of olmo_hybrid: the K/V write into its "
-        "pool under the block table and the paged read",
+        "a full-attention layer of olmo_hybrid or a GQA layer of "
+        "solar_open2: the K/V write into its pool under the block table "
+        "and the paged read (solar_open2: and the sigmoid output gate)",
     "dstpu.attn.latent":
         "a latent (MLA) layer's attention outside its weight products: "
         "rotary, the latent's norm, the write of the new rows into the "
@@ -462,7 +472,8 @@ SCOPE_SCHEMA = {
     "dstpu.mm.in_proj":
         "Mamba mixer: the input projection to u and the gate z; gated "
         "delta-rule mixer: the q / k / v / z projection and the a / b "
-        "gates' beside it",
+        "gates' beside it (olmo_hybrid); the q / k / v projection, beta's "
+        "and the two low-rank gates' pairs (solar_open2)",
     "dstpu.mm.x_proj":
         "Mamba mixer: the projection to dt's rank and B, C",
     "dstpu.mm.dt":

@@ -26,6 +26,24 @@ exp(c_t - c_s)`` (t >= s: never over 1, so no decay is ever divided by):
     O = diag(exp c) Q S + tril(Q K^T * G) U                    (L, dv)
     S <- exp(c_L) S + (diag(exp(c_L - c)) K)^T U
 
+**A gate a key channel** (Kimi Delta Attention, arXiv 2510.26692): ``a_t``
+a (dk,) vector, ``S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t
+v_t^T``. ``log_a``'s trailing axis says which rule it is, at trace time: 1
+(or no such axis) is the scalar gate above, dk this one; nothing else
+chooses. ``c_t`` is then a (dk,) vector and the algebra holds with two
+replacements: ``exp(c)`` scales K's or Q's row channel by channel
+wherever it stood, and ``(X K^T) * G`` (X = K and X = Q), which no longer
+factors, becomes the pairwise-decayed product
+
+    P[X]_ts = sum_d X_td K_sd exp(c_td - c_sd)             (t >= s)
+
+made with no decay divided by (:func:`_pairwise_decayed`): the chunk's
+rows in sub-blocks of ``_BASE``; a row block i against the rows before it,
+with r the block's first row, is the MXU product ``(X_i * exp(c_i - c_r))
+(K_<i * exp(c_r - c_<i))^T``, every exponent <= 0; a diagonal block is
+summed pair by pair, (16, 16, dk). The inverse, U, O and the state's
+update are shared.
+
 ``I + A`` is unit lower triangular. Its inverse is taken as a triangular
 solve would take it, never as the series ``sum_i (-A)^i``: keys that repeat
 make entries of A near ``b`` and the series' terms grow as ``(b L)^i / i!``
@@ -69,11 +87,41 @@ def _unit_lower_inverse(A):
          jnp.concatenate([X21, X22], axis=-1)], axis=-2)
 
 
+def _pairwise_decayed(x, k, c):
+    """``P[X]_ts = sum_d X_td K_sd exp(c_td - c_sd)`` for t >= s, 0 above
+    the diagonal: x, k, c (..., L, dk), L a multiple of ``_BASE``, c
+    non-increasing along L. -> (..., L, L)."""
+    L = x.shape[-2]
+    t, s = jnp.arange(_BASE)[:, None], jnp.arange(_BASE)[None, :]
+    rows = []
+    for r in range(0, L, _BASE):
+        xi, ci = x[..., r:r + _BASE, :], c[..., r:r + _BASE, :]
+        # the diagonal block, pair by pair: exp only where t >= s
+        gap = ci[..., :, None, :] - ci[..., None, :, :]
+        keep = (t >= s)[..., None]
+        diag = jnp.sum(jnp.where(keep, jnp.exp(jnp.where(keep, gap, 0.0))
+                                 * xi[..., :, None, :]
+                                 * k[..., None, r:r + _BASE, :], 0.0),
+                       axis=-1)
+        cr = c[..., r:r + 1, :]
+        left = jnp.einsum("...td,...sd->...ts", xi * jnp.exp(ci - cr),
+                          k[..., :r, :] * jnp.exp(cr - c[..., :r, :]),
+                          precision=EXACT)
+        rows.append(jnp.concatenate(
+            [left, diag, jnp.zeros(x.shape[:-2] + (_BASE, L - r - _BASE),
+                                   x.dtype)], axis=-1))
+    return jnp.concatenate(rows, axis=-2)
+
+
 def chunk_rule(q, k, v, log_a, b, state):
-    """q, k (B, T, H, dk), v (B, T, H, dv), log_a, b (B, T, H), state
-    (B, H, dk, dv); all float32. -> (o (B, T, H, dv), the state after
-    token T - 1)."""
+    """q, k (B, T, H, dk), v (B, T, H, dv), b (B, T, H), state (B, H, dk,
+    dv); log_a (B, T, H, 1) (or (B, T, H)): a gate a head, (B, T, H, dk):
+    a gate a key channel; all float32. -> (o (B, T, H, dv), the state
+    after token T - 1)."""
     B, T, H, _ = q.shape
+    if log_a.ndim == b.ndim:
+        log_a = log_a[..., None]
+    per_channel = log_a.shape[-1] != 1
     L = min(CHUNK, -(-T // _BASE) * _BASE)
     N = -(-T // L)
     pad = N * L - T
@@ -85,30 +133,34 @@ def chunk_rule(q, k, v, log_a, b, state):
         return jnp.moveaxis(x, 3, 1)
 
     q, k, v, log_a, b = (chunks(x) for x in (q, k, v, log_a, b))
-    c = jnp.cumsum(log_a, axis=-1)                          # (B, H, N, L)
+    c = jnp.cumsum(log_a, axis=-2)                  # (B, H, N, L, 1 | dk)
     t, s = jnp.arange(L)[:, None], jnp.arange(L)[None, :]
-    # exp only where t >= s: above the diagonal c_t - c_s is positive and
-    # may overflow
-    G = jnp.where(t >= s, jnp.exp(jnp.where(
-        t >= s, c[..., :, None] - c[..., None, :], 0.0)), 0.0)
-    kk = jnp.einsum("bhnld,bhnmd->bhnlm", k, k, precision=EXACT)
-    A = jnp.where(t > s, b[..., None] * kk * G, 0.0)
+    if per_channel:
+        qk = _pairwise_decayed(q, k, c)
+        A = jnp.where(t > s, b[..., None] * _pairwise_decayed(k, k, c), 0.0)
+    else:
+        # exp only where t >= s: above the diagonal c_t - c_s is positive
+        # and may overflow
+        G = jnp.where(t >= s, jnp.exp(jnp.where(
+            t >= s, c - jnp.swapaxes(c, -1, -2), 0.0)), 0.0)
+        kk = jnp.einsum("bhnld,bhnmd->bhnlm", k, k, precision=EXACT)
+        A = jnp.where(t > s, b[..., None] * kk * G, 0.0)
+        qk = jnp.einsum("bhnld,bhnmd->bhnlm", q, k, precision=EXACT) * G
     Tm = _unit_lower_inverse(A)
-    ec = jnp.exp(c)[..., None]
+    ec = jnp.exp(c)
     rhs = jnp.concatenate([k * (b[..., None] * ec), v * b[..., None]],
                           axis=-1)
     wu = jnp.matmul(Tm, rhs, precision=EXACT)       # [T b e^c K | T b V]
     W, U0 = wu[..., :k.shape[-1]], wu[..., k.shape[-1]:]
-    qk = jnp.einsum("bhnld,bhnmd->bhnlm", q, k, precision=EXACT) * G
-    k_out = k * jnp.exp(c[..., -1:] - c)[..., None]
-    a_chunk = jnp.exp(c[..., -1])                           # (B, H, N)
+    k_out = k * jnp.exp(c[..., -1:, :] - c)
+    a_chunk = jnp.exp(c[..., -1, :])                # (B, H, N, 1 | dk)
 
     def advance(S, xs):
         W, U0, qk, q_in, k_out, a_chunk = xs
         U = U0 - jnp.matmul(W, S, precision=EXACT)
         o = jnp.matmul(q_in, S, precision=EXACT) \
             + jnp.matmul(qk, U, precision=EXACT)
-        S = a_chunk[..., None, None] * S + jnp.einsum(
+        S = a_chunk[..., None] * S + jnp.einsum(
             "bhld,bhlv->bhdv", k_out, U, precision=EXACT)
         return S, o
 
@@ -122,10 +174,18 @@ def chunk_rule(q, k, v, log_a, b, state):
 
 
 def step_rule(q, k, v, log_a, b, state):
-    """One token a row: q, k (B, H, dk), v (B, H, dv), log_a, b (B, H),
-    state (B, H, dk, dv) -> (o (B, H, dv), state). Elementwise and sums,
-    float32 throughout: the state is read once and written once."""
-    a = jnp.exp(log_a)[..., None]
+    """One token a row: q, k (B, H, dk), v (B, H, dv), b (B, H), log_a (B,
+    H, 1) (or (B, H)) or, a gate a key channel, (B, H, dk); state (B, H,
+    dk, dv) -> (o (B, H, dv), state). Elementwise and sums, float32
+    throughout: the state is read once and written once."""
+    if log_a.ndim == b.ndim:
+        log_a = log_a[..., None]
+    a = jnp.exp(log_a)
+    if a.shape[-1] != 1:
+        state = a[..., None] * state
+        u = b[..., None] * (v - jnp.sum(k[..., None] * state, axis=-2))
+        state = state + k[..., None] * u[..., None, :]
+        return jnp.sum(q[..., None] * state, axis=-2), state
     u = b[..., None] * (v - a * jnp.sum(k[..., None] * state, axis=-2))
     state = a[..., None] * state + k[..., None] * u[..., None, :]
     return jnp.sum(q[..., None] * state, axis=-2), state

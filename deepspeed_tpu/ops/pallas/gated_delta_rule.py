@@ -190,11 +190,20 @@ def _chunk_call(q, k, v, log_a, b, state, *, interpret):
 
 def chunk_rule_kernel(q, k, v, log_a, b, state, *, interpret=None):
     """``chunk_rule`` as a Pallas kernel: q, k (B, T, H, dk), v (B, T, H,
-    dv), log_a, b (B, T, H), state (B, H, dk, dv); all float32. -> (o (B,
-    T, H, dv), the state after token T - 1). T is padded to whole 64-token
-    chunks with rows that move nothing (``log_a`` 0, ``b`` 0)."""
+    dv), log_a (B, T, H, 1) (or (B, T, H)), b (B, T, H), state (B, H, dk,
+    dv); all float32. -> (o (B, T, H, dv), the state after token T - 1). T
+    is padded to whole 64-token chunks with rows that move nothing
+    (``log_a`` 0, ``b`` 0). A gate a head only: a gate a key channel,
+    (B, T, H, dk), has no chunk kernel yet and raises; its chunks run
+    ``chunk_rule`` (ROADMAP R4)."""
     if interpret is None:
         interpret = interpret_default()
+    if log_a.ndim == 4:
+        if log_a.shape[-1] != 1:
+            raise NotImplementedError(
+                "chunk_rule_kernel takes a gate a head; a gate a key "
+                "channel runs ops/gated_delta_rule.py:chunk_rule")
+        log_a = log_a[..., 0]
     return _chunk_call(q, k, v, log_a, b, state, interpret=bool(interpret))
 
 
@@ -218,8 +227,9 @@ def live_slot_list(active):
 def _step_kernel(slot_ref, q_ref, k_ref, la_ref, b_ref, v_ref, s_ref, o_ref,
                  so_ref):
     """One live slot: q, k (H, dk), v, o (H, dv) and the state (H, dk, dv)
-    are the slot's; log_a, b (B, H) every slot's, the slot's row read
-    here. Everything comes as the model has it; what the sums over dk
+    are the slot's; b (B, H) every slot's, the slot's row read here, and
+    so log_a where it is a gate a head; a gate a key channel is the
+    slot's own (H, dk) block. Everything comes as the model has it; what the sums over dk
     need on the sublanes (k, q as (dk, H)) and along dv lanes (a head's
     gates) is made by products with 0 / 1 matrices, one nonzero term a
     sum: exact."""
@@ -230,13 +240,26 @@ def _step_kernel(slot_ref, q_ref, k_ref, la_ref, b_ref, v_ref, s_ref, o_ref,
     kT, qT = _dot(eye, k_ref[...], nt), _dot(eye, q_ref[...], nt)
     heads = (_iota((H, H), 0) == _iota((H, H), 1)).astype(jnp.float32)
     ones = jnp.ones((dv, H), jnp.float32)
-    a = jnp.exp(_dot(heads * la_ref[pl.ds(slot, 1), :], ones, nt))  # (H, dv)
+    per_channel = la_ref.shape == (H, dk)
+    if per_channel:
+        # the slot's (H, dk) gates as (dk, H): a head's column scales its
+        # S's sublanes
+        a = jnp.exp(_dot(eye, la_ref[...], nt))
+    else:
+        a = jnp.exp(_dot(heads * la_ref[pl.ds(slot, 1), :], ones, nt))
     b = _dot(heads * b_ref[pl.ds(slot, 1), :], ones, nt)
     for h in range(H):
-        S, k, ah = s_ref[h], kT[:, h:h + 1], a[h:h + 1, :]
-        u = b[h:h + 1, :] * (v_ref[h:h + 1, :] - ah * jnp.sum(
-            k * S, axis=0, keepdims=True))
-        S = ah * S + k * u
+        S, k = s_ref[h], kT[:, h:h + 1]
+        if per_channel:
+            S = a[:, h:h + 1] * S
+            u = b[h:h + 1, :] * (v_ref[h:h + 1, :] - jnp.sum(
+                k * S, axis=0, keepdims=True))
+            S = S + k * u
+        else:
+            ah = a[h:h + 1, :]                                  # (1, dv)
+            u = b[h:h + 1, :] * (v_ref[h:h + 1, :] - ah * jnp.sum(
+                k * S, axis=0, keepdims=True))
+            S = ah * S + k * u
         so_ref[h] = S
         o_ref[h:h + 1, :] = jnp.sum(qT[:, h:h + 1] * S, axis=0,
                                     keepdims=True)
@@ -256,7 +279,9 @@ def _step_call(slot_of, n_live, q, k, v, log_a, b, ssm, *, interpret):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_live,),
-        in_specs=[row(H, dk), row(H, dk), gates, gates, row(H, dv),
+        in_specs=[row(H, dk), row(H, dk),
+                  row(H, dk) if log_a.ndim == 3 else gates, gates,
+                  row(H, dv),
                   row(H, dk, dv)],
         out_specs=[row(H, dv), row(H, dk, dv)],
     )
@@ -278,12 +303,15 @@ def _step_call(slot_of, n_live, q, k, v, log_a, b, ssm, *, interpret):
 
 def step_rule_kernel(q, k, v, log_a, b, ssm, live, *, interpret=None):
     """``step_rule`` on the live slots only, in place: q, k (B, H, dk), v
-    (B, H, dv), log_a, b (B, H), ``ssm`` (B, H, dk, dv) the layer's whole
-    leaf, row b slot b's; all float32. ``live``: the step's
+    (B, H, dv), b (B, H), log_a (B, H, 1) (or (B, H)) or, a gate a key
+    channel, (B, H, dk); ``ssm`` (B, H, dk, dv) the layer's whole leaf,
+    row b slot b's; all float32. ``live``: the step's
     :func:`live_slot_list`. -> (o (B, H, dv), the leaf): a live slot's row
     of both is ``step_rule``'s, a dead slot's state is not touched (the
     leaf is aliased in and out) and its o row is its v row."""
     if interpret is None:
         interpret = interpret_default()
+    if log_a.ndim == 3 and log_a.shape[-1] == 1:
+        log_a = log_a[..., 0]
     return _step_call(*live, q, k, v, log_a, b, ssm,
                       interpret=bool(interpret))

@@ -108,6 +108,24 @@ def test_chunkwise_rule_survives_repeated_keys():
     assert np.abs(S[0] - want_S).max() < 1e-4 * np.abs(want_S).max()
 
 
+@pytest.mark.parametrize("T", [5, 100])
+def test_gate_with_a_trailing_axis_is_the_same_rule(T):
+    """``log_a`` (.., H, 1), the form beside a gate a key channel's (..,
+    H, dk) (ISSUE 54), is today's rule bit for bit: the scalar branch
+    keeps ``(K K^T) * G``, in the XLA forms and through both kernels."""
+    q, k, v, log_a, b, S0 = rule_inputs(T, seed=4)
+    x = tuple(jnp.asarray(a)[None] for a in (q, k, v, log_a, b, S0))
+    wide = x[:3] + (x[3][..., None],) + x[4:]
+    for rule in (chunk_rule, chunk_rule_kernel):
+        for got, want in zip(rule(*wide), rule(*x)):
+            assert np.array_equal(np.asarray(got), np.asarray(want))
+    step = tuple(a[:, 0] for a in x[:5]) + (x[5],)
+    for got, want in zip(
+            step_rule(*step[:3], step[3][..., None], *step[4:]),
+            step_rule(*step)):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_one_token_update_equals_sequential():
     q, k, v, log_a, b, S0 = rule_inputs(3, seed=2)
     S = S0[None]

@@ -178,6 +178,13 @@ CASES = {
         _gdn_shapes(1, GDN_T)),
     "gdn_step_16_slots": (
         _gdn_step, _gdn_shapes(GDN_SLOTS) + [((GDN_SLOTS,), jnp.bool_)]),
+    # the step kernel with a gate a key channel at Solar-Open2's widths
+    # (cell 12: 64 heads of 128 x 128, 32 slots)
+    "kda_step_32_slots": (
+        _gdn_step,
+        [((32, 64, 128), f32)] * 4 + [((32, 64), f32),
+                                      ((32, 64, 128, 128), f32),
+                                      ((32,), jnp.bool_)]),
     # the latent read's kernel at cell 10's chunk, and at a prompt bucket
     # that two query tiles share (1,536 -> 2 x 768)
     "latent_read_c1024": (
