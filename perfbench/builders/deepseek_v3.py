@@ -75,3 +75,43 @@ def model(cfg, **overrides):
         rope_theta=float(cfg["rope_theta"]),
         rope_interleave=cfg["rope_interleave"],
         rms_eps=cfg["rms_norm_eps"], dtype="bfloat16"), **overrides}))
+
+
+def blocks(model):
+    """-> ``forward(params, ids (B, T)) -> (B, T, D)``: the program's own
+    blocks one after the other with no ``jax.checkpoint`` round them, for
+    a count that must see inside them (nothing is differentiated there; a
+    value cannot leave a checkpointed block but through its results, and
+    layers that are alike share one trace under it). The rotary tables are
+    ``DeepseekV3.apply``'s, line for line: ``perfbench/tests/
+    test_held_rows.py`` holds this equal to ``apply(return_hidden=True)``."""
+    import jax.numpy as jnp
+    cfg = model.config
+
+    def forward(params, ids):
+        B, T = ids.shape
+        dr = cfg.qk_rope_head_dim
+        f = cfg.rope_theta ** (-jnp.arange(0, dr, 2, dtype=jnp.float32) / dr)
+        ang = jnp.broadcast_to(
+            jnp.arange(T, dtype=jnp.float32)[None, :, None] * f,
+            (B, T, dr // 2))
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        x = params["wte"][ids]
+        for p in params["layers"]:
+            x = model._block(x, p, cos, sin)
+        return x
+
+    return forward
+
+
+def traced_counters(model, s):
+    """What of a traced step only this family's routing can say: the rows
+    its router sends the held experts and the held experts that get one
+    (``train_moe_experts_roofline``'s floor). ``runners/train.py`` asks any
+    builder for this hook, once, in a traced run: ``warm(params, batch)``
+    in set-up; ``count(params, batch)`` before the first traced step,
+    ``keep(params, batch)`` before every later one, each with the
+    parameters the step is about to use; ``counters(at) -> {counter:
+    number}`` once the profiler has closed."""
+    from pbench import mla_moe
+    return mla_moe.HeldRows(blocks(model), s)

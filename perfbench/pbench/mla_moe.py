@@ -11,8 +11,17 @@ attention and its expert layer took (PR 47; written out in
   forward and backward (``train_flops_per_token``: MFU's convention of
   ``pbench/flops.py``, backward = 2 x forward, recomputation not counted),
   the attention's own floor (``mla_flash_work``) and the held experts'
-  (``held_experts_work``). Neither floor can be beaten, so neither share
-  can pass 100 %.
+  (``held_rows_work``). The attention's floor follows from the shapes
+  and cannot be beaten. The held experts' follows from the ROUTING: the
+  rows the router sent the held experts in the traced steps and the held
+  experts that got one, each traced step counted on its own parameters
+  (``HeldRows``, which the runner has the builder make, PR 55), not the
+  rows an even router would have sent: this cell's router sends its held
+  experts a fraction of those within a dozen steps, and a floor from the
+  even count stood above the time of a program that multiplies the sent
+  rows alone (ledger, PR 50: 163 %). Built from what was sent, what was
+  called and the gradients the optimizer is owed, it cannot be beaten
+  either, so neither share can pass 100 %.
 * Device time by the program's ``jax.named_scope`` (``monitor/tag_schema.py
   :SCOPE_SCHEMA``): an ``XLA Ops`` event's scope is in its metadata's
   ``tf_op``, which ``pbench.moe.op_scopes`` reads out of the ``.xplane.pb``.
@@ -28,7 +37,7 @@ without the scopes (every other model, a commit before PR 47) gives
 nothing: the readers then return None.
 """
 
-from . import flops, moe
+from . import common, flops, moe
 
 MLA = "dstpu.attn.mla"
 EXPERTS = "dstpu.moe.experts"
@@ -68,10 +77,11 @@ def held_params(s):
 
 
 def held_experts_per_token(s):
-    """Expected held experts a token takes under uniform routing, which
-    routers with seeded random weights over uniform ids give nearly:
-    top_k x held / published. A skewed router gives this chip more or
-    fewer."""
+    """Expected held experts a token takes under uniform routing: top_k x
+    held / published. What ``train_flops_per_token`` (``mfu_routed``)
+    counts, the same on both sides of any pair; a router gives this chip
+    more or fewer (``HeldRows`` counts them), and this cell's gives far
+    fewer once it has trained a few steps."""
     return s["top_k"] * s["n_experts"] / s["n_experts_published"]
 
 
@@ -120,18 +130,139 @@ def mla_flash_work(batch, s, seq_len, itemsize=2):
     return ops, values * itemsize
 
 
-def held_experts_work(tokens, s, itemsize=2):
-    """One layer's held experts for ``tokens`` tokens of a step, forward
-    and backward -> (operations, bytes): 3 products x (forward + dx + dW)
-    over the expected held rows (``held_experts_per_token`` a token), 2 *
-    D * F each a row; each held weight read twice (forward, dx) and its
-    gradient written once; the held rows read and written once each way
-    (x and y forward, dy and dx backward)."""
+def held_rows_work(rows, called, s, layer_steps=1, itemsize=2):
+    """The held experts, forward and backward, over ``rows`` (token,
+    choice) pairs sent to them, of which ``called`` experts got at least
+    one: of one sparse layer and step, or the sums over ``layer_steps`` of
+    them (every term is linear) -> (operations, bytes). 3 products x
+    (forward + dx + dW), 2 * D * F each a row; a called expert's weights
+    read twice (forward, dx), as many bytes for 1 row as for 1,000, and
+    none for an expert that was sent nothing; EVERY held expert's gradient
+    written once (the optimizer takes a dense gradient a leaf: zeros are
+    written too); the rows read and written once each way (x and y
+    forward, dy and dx backward)."""
     D, F = s["d_model"], s["moe_d_ff"]
-    rows = tokens * held_experts_per_token(s)
     ops = 3 * 3 * 2 * rows * D * F
-    weights = 3 * s["n_experts"] * expert_params(s) * itemsize
+    weights = (2 * called + layer_steps * s["n_experts"]) \
+        * expert_params(s) * itemsize
     return ops, weights + 4 * rows * D * itemsize
+
+
+def held_experts_work(tokens, s, itemsize=2):
+    """``held_rows_work`` of what an EVEN router would send ``tokens``
+    tokens of a step: ``held_experts_per_token`` rows a token and every
+    held expert called. The count before PR 55, TRAIN_MOE.md's table and
+    tests/unit/test_deepseek_v3.py's arithmetic. No reader divides by it."""
+    return held_rows_work(tokens * held_experts_per_token(s),
+                          s["n_experts"], s, itemsize=itemsize)
+
+
+# ------------------------------------------------- the rows that were sent
+def held_rows_program(forward, s):
+    """The jitted (params, ids (B, T)) -> (sparse layers, held experts) int
+    count: the (token, choice) pairs whose chosen expert is held expert i
+    of that layer, as the program's own router chose. ``forward(params,
+    ids)`` runs the program's blocks (the builder's: it knows the family);
+    the program returns no count of its routing and carries no hook for
+    one, so while ``forward`` is traced a tap stands in for
+    ``moe/sharded_moe.route_topk`` and notes its answer. The tap sees the
+    choice and nothing after it: the count is the same whatever multiplies
+    the experts (``lax.ragged_dot``, the grouped kernels, a walk over the
+    held rows). A stopgap, and not safe beside another thread that traces:
+    it goes when the step returns its ``group_sizes`` (PERF.md section 7)."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.moe import sharded_moe
+    held = s["experts_offset"] + jnp.arange(s["n_experts"])
+
+    def count(params, ids):
+        route, seen = sharded_moe.route_topk, []
+
+        def tapped(*a, **k):
+            weights, experts = route(*a, **k)
+            seen.append(jnp.sum(experts[..., None] == held, axis=(0, 1)))
+            return weights, experts
+
+        sharded_moe.route_topk = tapped
+        try:
+            forward(params, ids)
+        finally:
+            sharded_moe.route_topk = route
+        if len(seen) != s["n_sparse"]:
+            raise common.CheckFailed(
+                f"the tap on moe/sharded_moe.route_topk saw {len(seen)} "
+                f"routings where the model has {s['n_sparse']} sparse "
+                f"layers: the program no longer looks the router up in its "
+                f"module at every call, or shares one trace between layers")
+        return jnp.stack(seen)
+
+    return jax.jit(count)
+
+
+class HeldRows:
+    """The runner's counters of the traced steps' routing
+    (``builders/deepseek_v3.traced_counters``). Every step donates its
+    parameters to the next, so each traced step's rows are counted on the
+    parameters IT is about to use. The first's at once: the profiler has
+    not opened, the device is idle, the host waits for the count. A later
+    one's parameters exist only between two traced steps: a copy of them
+    is made on the device there (one operation in the trace, the
+    parameters' bytes read and written once, and as many held until the
+    count) and counted once the profiler has closed, so the count's
+    program is in no trace."""
+
+    def __init__(self, forward, s):
+        import jax
+        import jax.numpy as jnp
+        self.s = s
+        self.program = held_rows_program(forward, s)
+        self.copy = jax.jit(lambda tree: jax.tree.map(jnp.copy, tree))
+        self.counts, self.later, self.tokens = [], [], 0
+
+    def count(self, params, batch):
+        """The routing of ``batch`` at ``params``, now: the host waits."""
+        import numpy as np
+        self.counts.append(np.asarray(
+            self.program(params, batch["input_ids"])))    # (layer, expert)
+        self.tokens += batch["input_ids"].size
+
+    def keep(self, params, batch):
+        """The same, counted by ``counters``: a copy of ``params`` is
+        enqueued and nothing waited for."""
+        self.later.append((self.copy(params), batch))
+
+    def counters(self, at):
+        """-> {counter: number}: the sums over the steps counted or kept
+        and their sparse layers; says them a layer-step beside the even
+        router's. Empties itself."""
+        import time
+        t0 = time.perf_counter()
+        while self.later:
+            self.count(*self.later.pop(0))
+        counts, s = self.counts, self.s
+        rows = int(sum(c.sum() for c in counts))
+        called = int(sum((c > 0).sum() for c in counts))
+        calls = len(counts) * s["n_sparse"]
+        even = self.tokens * s["n_sparse"] * held_experts_per_token(s)
+        common.say(
+            "held_rows", at=at, rows_a_layer_step=rows / calls,
+            even_router_a_layer_step=even / calls, sent_of_even=rows / even,
+            called_a_layer_step=called / calls, held_experts=s["n_experts"],
+            rows_by_step_and_layer=[c.sum(axis=1).tolist() for c in counts],
+            called_by_step_and_layer=[(c > 0).sum(axis=1).tolist()
+                                      for c in counts],
+            later_steps_seconds=time.perf_counter() - t0)
+        self.counts, self.tokens = [], 0
+        return {"held_rows_traced": rows,
+                "held_experts_called_traced": called}
+
+    def warm(self, params, batch):
+        """Set-up: both programs compile here; the line says how the
+        parameters the warm-up left route."""
+        import jax
+        jax.block_until_ready(self.copy(params))
+        self.count(params, batch)
+        self.counters("set-up: the parameters the warm-up left")
 
 
 # ------------------------------------------------------ the device's time

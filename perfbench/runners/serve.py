@@ -397,7 +397,13 @@ def run(ctx):
         "setup_s": setup_s,
     }
     if not ctx.rehearse:
-        common.say("client", **end_to_end,
+        # the machine stands still now and then (~0.12 s, PERF.md section 5,
+        # cell 3): a Router.step over five times the window's median one
+        durations = sorted(st[1] - st[0] for st in window_steps)
+        stalls = [d for d in durations
+                  if d > 5.0 * durations[len(durations) // 2]]  # [] of []
+        common.say("client", **end_to_end, router_step_stalls=len(stalls),
+                   router_step_stalled_s=sum(stalls),
                    ttft_p50_ms=common.percentile(s["ttft_ms"], 50),
                    ttft_p90_ms=common.percentile(s["ttft_ms"], 90),
                    completed_tok_s=s["tokens_in_window"] / seconds,

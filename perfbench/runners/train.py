@@ -7,6 +7,7 @@ window that a ``block_until_ready`` closes.
 """
 
 import collections
+import copy
 import time
 
 import numpy as np
@@ -64,10 +65,13 @@ def run(ctx):
 
     rng = np.random.default_rng(ctx.seed)
 
+    def draw(generator):
+        return {"input_ids": generator.integers(
+            0, s["vocab_size"], (rows, T), dtype=np.int32)}
+
     def new_batch():
         with tracing.span("perfbench.batch"):
-            return {"input_ids": rng.integers(
-                0, s["vocab_size"], (rows, T), dtype=np.int32)}
+            return draw(rng)
 
     # ---- correct, part 1: the untrained weights through both paths
     first = new_batch()
@@ -104,13 +108,21 @@ def run(ctx):
         losses.append(engine.train_batch(batch))
         batch = new_batch()
     jax.block_until_ready(losses)
+    # what of the traced steps only the model can say (a router's choices):
+    # a builder that has such counters is shown every traced step's
+    # parameters and batch before the step. Its programs compile here
+    tally = None
+    if ctx.trace and hasattr(builder, "traced_counters"):
+        tally = builder.traced_counters(model, s)
+        with jax.set_mesh(engine.mesh):
+            tally.warm(engine.state["params"], batch)
     common.say("warm", at_s=clock.now(), **ctx.meter.snapshot())
 
     # ---- the window
     run_ahead = job["steps_in_flight"]
     trace_at = ctx.seconds * job["trace_after_share"]
     inflight = collections.deque()
-    traced = None
+    traced, traced_counters = None, {}
     compiles_before = ctx.meter.count
     setup_s = clock.now()
     t_open = time.perf_counter()
@@ -131,11 +143,30 @@ def run(ctx):
             jax.block_until_ready(losses[-1])
             inflight.clear()
             t_capture = time.perf_counter()
+            if tally:
+                # a step's routing is counted on the parameters it is about
+                # to use, which it donates away: the first's now, before
+                # the profiler opens; a later one's from a copy made on the
+                # device between the traced steps, once the profiler has
+                # closed. The batches are drawn ahead from a copy of the
+                # generator: the window's own draws stay where they were.
+                # All inside the capture's span, which the rate MFU reads
+                # leaves out
+                ahead = copy.deepcopy(rng)
+                batches = [draw(ahead) for _ in range(job["trace_steps"])]
+                with jax.set_mesh(engine.mesh):
+                    tally.count(engine.state["params"], batches[0])
             with tracing.capture(ctx.trace_dir):
-                for _ in range(job["trace_steps"]):
+                for i in range(job["trace_steps"]):
+                    if tally and i:
+                        with jax.set_mesh(engine.mesh):
+                            tally.keep(engine.state["params"], batches[i])
                     one_step()
                 with tracing.span("perfbench.block_until_ready"):
                     jax.block_until_ready(losses[-1])
+            if tally:
+                with jax.set_mesh(engine.mesh):
+                    traced_counters = tally.counters("the traced steps")
             capture_s = time.perf_counter() - t_capture
             steps += job["trace_steps"]
             traced = job["trace_steps"]
@@ -176,6 +207,7 @@ def run(ctx):
         # the steps outside the capture is what MFU is taken from
         counters["tok_s_chip_outside_capture"] = (
             (steps - traced) * tokens_per_step / (window_s - capture_s) / n)
+        counters.update(traced_counters)
     return {"attempted": steps, "failed": 0,
             "end_to_end": {"train_tok_s_chip": tok_s_chip,
                            "setup_s": setup_s},
