@@ -30,7 +30,11 @@ published ``n_routed_experts`` outputs; the layer holds ``experts_held`` of
 them from ``experts_offset`` and computes ``sum over held experts +
 Shared(x)``: what the absent experts would add is left out and that partial
 sum goes on (``moe/sharded_moe.py:moe_swiglu_routed``, ``held=``), forward
-and backward. Nothing here stands in for the other chips or their exchange.
+and backward. The layer says how many experts the router chose among, so
+only the held experts' routed rows are gathered, multiplied and scattered,
+a chunk at a time; the absent experts' rows (7/8 of them at an even router,
+more once this share's router has trained) are never moved. Nothing here
+stands in for the other chips or their exchange.
 
 **A buffer leaf.** ``gate_bias`` (``e_score_correction_bias``) is a float32
 buffer: it takes no gradient (it only chooses) and the optimizer does not
@@ -318,7 +322,8 @@ class DeepseekV3:
                           "auto")
         y = moe_swiglu_routed(
             xs, weights, experts, p["moe_w1"], p["moe_w3"], p["moe_w2"],
-            grouped, held=(cfg.experts_offset, cfg.experts_held))
+            grouped, held=(cfg.experts_offset, cfg.experts_held,
+                           cfg.n_routed_experts))
         return y.reshape(B, T, D) + self._swiglu(x, p["ws1"], p["ws2"])
 
     def _block(self, x, p, cos, sin):

@@ -455,7 +455,10 @@ class DeepseekV32:
 
     def _moe(self, x, p):
         """Routed experts, the held share of them, beside the shared
-        expert: x (B, C, D) float32 normed -> (B, C, D) float32."""
+        expert: x (B, C, D) float32 normed -> (B, C, D) float32. The
+        two-part ``held`` keeps ``moe_swiglu_routed``'s one pass over every
+        routed row: a serving chunk wants the walk over the held rows
+        forward only and at a size of its own (ROADMAP.md S11(b))."""
         from ..moe.sharded_moe import moe_swiglu_routed, route_topk
         cfg = self.config
         B, C, D = x.shape

@@ -344,12 +344,26 @@ def moe_swiglu_routed(xs, weights, experts, w1, w3, w2, grouped="auto",
     chip: both ends of the products select it away), and it adds nothing
     and takes no gradient. What the absent experts would have added is
     left out: the partial sum another device's exchange would complete.
-    (The absent rows are still sorted and gathered: S * k rows exist
-    whatever share is held.)"""
+    (With this two-part form the absent rows are still sorted and
+    gathered: S * k rows exist whatever share is held.)
+
+    ``held`` = (offset, count, published), ``published`` the number of
+    experts the router chose among: the same sum, and the absent experts'
+    rows are never moved. Only the S * k int32 keys are sorted; the held
+    experts' rows are gathered, multiplied and scatter-added a chunk at a
+    time (:func:`_held_walk`), as many chunks as the router's rows fill
+    (:func:`held_walk_taken`), none where it sent none. So few rows that
+    one chunk would be all of them take the one pass above."""
     S, D = xs.shape
     k = experts.shape[1]
     E = w1.scale.shape[0] if hasattr(w1, "scale") else w1.shape[0]
     out_dtype = xs.dtype if out_dtype is None else out_dtype
+    if held is not None and len(held) == 3:
+        chunk = _walk_chunk(S * k, held[1], held[2])
+        if chunk < S * k:
+            return _held_walk(xs, weights, experts, w1, w3, w2, grouped,
+                              int8, held[0], chunk, out_dtype)
+        held = held[:2]
     with jax.named_scope("dstpu.moe.route"):
         flat_exp = experts.reshape(-1)
         flat_w = weights.reshape(-1).astype(out_dtype)
@@ -390,6 +404,149 @@ def moe_swiglu_routed(xs, weights, experts, w1, w3, w2, grouped="auto",
         y = jnp.sum((unsorted.astype(out_dtype)
                      * flat_w[:, None]).reshape(S, k, D), axis=1)
     return y
+
+
+def _walk_chunk(rows, count, published):
+    """Rows a chunk of the held walk: half of what an even router sends
+    ``count`` of ``published`` experts out of ``rows`` routed rows, up to
+    the next 128. A chunk costs its positions whether a held row fills
+    them or not, and a fixed part besides (the experts' weights read, their
+    gradients written). Timed on the chip at a share of 1/8 of 98,304 rows
+    against one and two times the even count (PERF.md section 5, PR 56):
+    half is the fastest wherever the router sends less than the even count
+    (8.8 / 11.4 / 15.3 ms a layer at 300 rows) and 4 ms of 23 behind two
+    times at the even count itself."""
+    return -(-rows * count // (2 * published * 128)) * 128
+
+
+def held_walk_taken(experts, k, held):
+    """How many chunks :func:`moe_swiglu_routed` walks for one layer's
+    routing ``experts`` (S, k) under ``held`` = (offset, count, published),
+    by the program's own rule: the held experts' rows over the chunk,
+    rounded up; every chunk past the first runs under ``dstpu.moe.spill``.
+    None where the one pass runs instead (no walk to take)."""
+    offset, count, published = held
+    rows = experts.shape[0] * k
+    chunk = _walk_chunk(rows, count, published)
+    if chunk >= rows:
+        return None
+    local = experts - offset
+    return -(-jnp.sum((local >= 0) & (local < count)) // chunk)
+
+
+def _held_walk(xs, weights, experts, w1, w3, w2, grouped, int8, offset,
+               chunk, out_dtype):
+    """:func:`moe_swiglu_routed` with a held share, the absent experts'
+    rows never moved. The S * k keys are sorted once, the held experts' n
+    rows in front; their sorted positions are walked in ``chunk``s, as
+    many as hold a row below n and no more (a loop whose trip count is
+    the router's: nothing is dropped, and a router that sends every row
+    here runs every chunk, the one pass's work). Chunk i gathers the
+    tokens of positions [i chunk, (i + 1) chunk) straight from xs,
+    multiplies them by the held groups clipped to that range, weighs them
+    and adds them to their tokens' rows of a float32 result. The backward
+    walks the same chunks again, each recomputed from the layer's inputs
+    (the only residuals), and adds each chunk's gradients to the sums.
+    Every chunk after the first runs under ``dstpu.moe.spill``: device
+    time there is held rows past the first chunk."""
+    from ..ops.pallas._common import counting_calls, note_call
+    S, D = xs.shape
+    k = experts.shape[1]
+    E, _, F = (w1.scale if hasattr(w1, "scale") else w1).shape
+    gp = resolve_grouped_params(grouped, chunk, E, D, F, xs.dtype)
+    if int8:
+        gp = dict(gp, int8=resolve_moe_int8(int8, chunk, E, D, F, xs.dtype))
+    noted = []
+    with jax.named_scope("dstpu.moe.route"):
+        local = experts.reshape(-1) - offset
+        key = jnp.where((local >= 0) & (local < E), local, E)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        # where each held group starts in the sorted order and where the
+        # last ends: bounds[-1] is n
+        bounds = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(
+            jnp.bincount(key, length=E + 1)[:E]).astype(jnp.int32)])
+        # positions past S * k in the last chunk lie past n like every
+        # absent row: selected away whatever row they name
+        order = jnp.pad(order, (0, -order.size % chunk))
+        flat_w = weights.reshape(-1).astype(jnp.float32)
+
+    def rows(i, xs, flat_w, order, bounds):
+        """Chunk i: its positions, its tokens, which rows are live, the
+        groups clipped to it and its operands gathered."""
+        with jax.named_scope("dstpu.moe.route"):
+            lo = i * chunk
+            at = lax.dynamic_slice(order, (lo,), (chunk,))
+            token = at // k
+            live = lo + jnp.arange(chunk) < bounds[-1]
+            sizes = jnp.diff(jnp.clip(bounds, lo, lo + chunk))
+            return (at, token, live, sizes, xs[token],
+                    jnp.where(live, flat_w[at], 0))
+
+    def weighed(xg, wr, w1, w3, w2, live, sizes):
+        """The live rows' weighed outputs (chunk, D) float32. The
+        products neither read nor WRITE a row past the groups (on the
+        chip ``lax.ragged_dot`` leaves there what the buffer held, in dx
+        too): both ends select those rows away, and the selects
+        transpose to selects."""
+        with jax.named_scope("dstpu.moe.route"):
+            xr = jnp.where(live[:, None], xg, 0)
+        # this body is traced once a loop and pass and the layer makes one
+        # chain: the first trace tells the tally, the others keep quiet
+        with jax.named_scope("dstpu.moe.experts"), counting_calls() as said:
+            o = _grouped_swiglu_ffn(xr, w1, w3, w2, sizes, gp)
+        if not noted:
+            noted.append(note_call("expert", any(
+                kernel for _, kernel in said.values())))
+        with jax.named_scope("dstpu.moe.combine"):
+            return jnp.where(live[:, None], o, 0).astype(jnp.float32) \
+                * wr[:, None]
+
+    def chunks(bounds, body, carry):
+        """``body(i, carry)`` over the chunks that hold a row: the first
+        under a ``cond``, the rest a loop under ``dstpu.moe.spill``."""
+        taken = -(-bounds[-1] // chunk)
+        carry = lax.cond(taken > 0, lambda c: body(0, c), lambda c: c,
+                         carry)
+        with jax.named_scope("dstpu.moe.spill"):
+            return lax.fori_loop(1, taken, body, carry)
+
+    def forward(xs, flat_w, w1, w3, w2, order, bounds):
+        def add(i, y):
+            _, token, live, sizes, xg, wr = rows(i, xs, flat_w, order,
+                                                 bounds)
+            o = weighed(xg, wr, w1, w3, w2, live, sizes)
+            with jax.named_scope("dstpu.moe.combine"):
+                return y.at[token].add(o)
+        y = chunks(bounds, add, jnp.zeros((S, D), jnp.float32))
+        return y.astype(out_dtype), (xs, flat_w, w1, w3, w2, order, bounds)
+
+    def backward(saved, dy):
+        xs, flat_w, w1, w3, w2, order, bounds = saved
+
+        def add(i, sums):
+            dxs, dflat_w, dw = sums
+            at, token, live, sizes, xg, wr = rows(i, xs, flat_w, order,
+                                                  bounds)
+            with jax.named_scope("dstpu.moe.combine"):
+                do = dy[token].astype(jnp.float32)
+            dxg, dwr, *dwi = jax.vjp(
+                lambda *a: weighed(*a, live, sizes), xg, wr, w1, w3, w2)[1](
+                    do)
+            with jax.named_scope("dstpu.moe.experts"):
+                dw = jax.tree.map(jnp.add, dw, tuple(dwi))
+            with jax.named_scope("dstpu.moe.route"):
+                return (dxs.at[token].add(dxg.astype(jnp.float32)),
+                        dflat_w.at[at].add(jnp.where(live, dwr, 0)), dw)
+        # the held experts' gradients are theirs whether a row came or not
+        with jax.named_scope("dstpu.moe.experts"):
+            dw = jax.tree.map(jnp.zeros_like, (w1, w3, w2))
+        dxs, dflat_w, dw = chunks(bounds, add, (
+            jnp.zeros((S, D), jnp.float32), jnp.zeros_like(flat_w), dw))
+        return (dxs.astype(xs.dtype), dflat_w, *dw, None, None)
+
+    walk = jax.custom_vjp(lambda *a: forward(*a)[0])
+    walk.defvjp(forward, backward)
+    return walk(xs, flat_w, w1, w3, w2, order, bounds)
 
 
 def topk_routing(logits, k=1):

@@ -389,6 +389,11 @@ SCOPE_SCHEMA = {
     "dstpu.moe.combine":
         "MoE layer: unsort, weight by the routing probabilities, sum over "
         "the k picks (and, expert-parallel, the all_to_all back)",
+    "dstpu.moe.spill":
+        "MoE layer, a held share walked a chunk at a time (moe/sharded_moe."
+        "py:_held_walk): every chunk after the layer's first, forward and "
+        "backward, with dstpu.moe.route / experts / combine inside it — "
+        "device time here is held rows past the first chunk",
     "dstpu.ssm.mix":
         "state-space (Mamba) mixer: in-projection, causal conv, the "
         "x / dt projections, the selective scan (prefill) or its one-step "

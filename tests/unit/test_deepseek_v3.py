@@ -210,8 +210,15 @@ def test_the_shares_add_up_to_the_uncut_layer(params):
     assert _rel(got_dx, want_dx) < TOL
 
 
+# a share as the one pass takes it and as the walk does (160 routed rows, a
+# chunk of 128: half an even router's 40, up to the next 128)
+HELD = pytest.mark.parametrize("held", [(4, 4), (4, 4, 16)],
+                               ids=["one_pass", "walk"])
+
+
+@HELD
 @pytest.mark.parametrize("backend", [False, True], ids=["ragged", "kernel"])
-def test_absent_rows_give_and_take_zero_under_grad(backend):
+def test_absent_rows_give_and_take_zero_under_grad(backend, held):
     """``moe_swiglu_routed(held=)`` under ``jax.grad``: a routed row whose
     expert lies elsewhere adds nothing to the output and takes no gradient,
     and the held rows' gradients are those of the dense sum over held
@@ -219,7 +226,7 @@ def test_absent_rows_give_and_take_zero_under_grad(backend):
     Pallas grouped kernels (interpreted)."""
     from deepspeed_tpu.moe import sharded_moe
     rng = np.random.default_rng(0)
-    S, k, D, F, held = 40, 4, 128, 128, (4, 4)
+    S, k, D, F = 40, 4, 128, 128
     xs = jnp.asarray(rng.normal(size=(S, D)), jnp.float32)
     experts = jnp.asarray(np.stack([rng.permutation(16)[:k]
                                     for _ in range(S)]), jnp.int32)
@@ -255,7 +262,9 @@ def test_absent_rows_give_and_take_zero_under_grad(backend):
     assert absent.any() and (np.asarray(got[1])[absent] == 0).all()
 
 
-def test_what_a_backend_leaves_past_the_groups_reaches_nothing(monkeypatch):
+@HELD
+def test_what_a_backend_leaves_past_the_groups_reaches_nothing(monkeypatch,
+                                                               held):
     """On the chip ``lax.ragged_dot`` never writes the rows past its groups'
     sum, the absent experts' rows: they hold what the buffer held, in the
     forward (the output) and in the backward (dx). PR 47's first chip run
@@ -265,7 +274,7 @@ def test_what_a_backend_leaves_past_the_groups_reaches_nothing(monkeypatch):
     output and gradients are those of the clean products."""
     from deepspeed_tpu.moe import sharded_moe
     rng = np.random.default_rng(1)
-    S, k, D, F, held = 40, 4, 128, 128, (4, 4)
+    S, k, D, F = 40, 4, 128, 128
     xs = jnp.asarray(rng.normal(size=(S, D)), jnp.float32)
     experts = jnp.asarray(np.stack([rng.permutation(16)[:k]
                                     for _ in range(S)]), jnp.int32)
@@ -306,6 +315,140 @@ def test_what_a_backend_leaves_past_the_groups_reaches_nothing(monkeypatch):
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert np.isfinite(np.asarray(g)).all()
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+# ------------------------------------------------------------------ the walk
+# 256 tokens x 4 choices of 16 experts of which experts 4 .. 7 are held:
+# 1,024 routed rows, a chunk of 128 = half an even router's 256. ``n`` held
+# rows, spread over the held experts and the tokens; None = an even draw.
+WALK_ROWS = {"even": None, "1.6x_even": 410, "every_row": 1024, "no_row": 0,
+             "a_chunk_less_one": 127, "a_chunk": 128, "a_chunk_and_one": 129,
+             "two_chunks": 256}
+
+
+def _walk_case(n):
+    rng = np.random.default_rng(56)
+    S, k, D, F = 256, 4, 128, 128
+    if n is None:
+        experts = np.stack([rng.permutation(16)[:k] for _ in range(S)])
+    else:
+        absent = np.r_[0:4, 8:16]
+        experts = absent[rng.integers(0, absent.size, (S, k))]
+        experts.reshape(-1)[rng.permutation(S * k)[:n]] = \
+            rng.integers(4, 8, n)
+    operands = (rng.normal(size=(S, D)), rng.uniform(size=(S, k)),
+                rng.normal(size=(4, D, F)) * 0.1,
+                rng.normal(size=(4, D, F)) * 0.1,
+                rng.normal(size=(4, F, D)) * 0.1, rng.normal(size=(S, D)))
+    return jnp.asarray(experts, jnp.int32), \
+        [jnp.asarray(a, jnp.float32) for a in operands]
+
+
+@pytest.mark.parametrize("rows", WALK_ROWS)
+def test_the_walk_equals_the_one_pass(rows, monkeypatch):
+    """ISSUE 56: with a three-part ``held`` only the held experts' rows are
+    gathered, multiplied and scattered, a chunk at a time, and nothing is
+    dropped: output and every gradient (xs, weights, w1, w3, w2) are the
+    one pass's whether the router sends the share an even load, more than
+    a chunk (through ``dstpu.moe.spill``), every row or none, and with the
+    last held row at a chunk's edge or one either side. The chunks the
+    program runs are those ``held_walk_taken`` says."""
+    from deepspeed_tpu.moe import sharded_moe
+    experts, (*operands, ct) = _walk_case(WALK_ROWS[rows])
+    n = int(((experts >= 4) & (experts < 8)).sum())
+    assert WALK_ROWS[rows] in (None, n)
+    ran, chain = [], sharded_moe._grouped_swiglu_ffn
+
+    def counted(*a):
+        jax.debug.callback(lambda: ran.append(1))
+        return chain(*a)
+    monkeypatch.setattr(sharded_moe, "_grouped_swiglu_ffn", counted)
+
+    def run(held, grad=True):
+        def y(*a):
+            return sharded_moe.moe_swiglu_routed(
+                a[0], a[1], experts, *a[2:], False, held=held)
+        with jax.default_matmul_precision("highest"):
+            if not grad:
+                return jax.jit(y)(*operands)
+            return jax.jit(jax.value_and_grad(
+                lambda *a: jnp.sum(ct * y(*a)), argnums=(0, 1, 2, 3, 4)))(
+                    *operands)
+    want, got = run((4, 4)), run((4, 4, 16))
+    assert abs(float(got[0]) - float(want[0])) \
+        < 2e-5 * max(1.0, abs(float(want[0])))
+    for g, w in zip(got[1], want[1]):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        assert _rel(g, w) < TOL if n else not np.asarray(g).any()
+    jax.effects_barrier()
+    del ran[:]
+    y = jax.block_until_ready(run((4, 4, 16), grad=False))
+    jax.effects_barrier()
+    taken = sharded_moe.held_walk_taken(experts, 4, (4, 4, 16))
+    assert len(ran) == int(taken) == -(-n // 128)
+    assert np.abs(np.asarray(y)
+                  - np.asarray(run((4, 4), grad=False))).max() < 2e-5
+    # the one pass has no chunks to count; so few rows that a chunk would
+    # be all of them take it
+    assert sharded_moe.held_walk_taken(experts[:32], 4, (4, 4, 16)) is None
+
+
+# ``moe_swiglu_routed`` without a share, with the two-part share and with a
+# three-part share over too few rows to walk, as the parent of PR 56 (08cda69)
+# lowered them, by the sha256 of ``lower().as_text()``: the walk is a path
+# of its own and these callers (OLMoE; ``deepseek_v32.py``,
+# ``solar_open2.py``) run the program they ran. A PR that changes the one
+# pass on purpose regenerates them (the assertion message holds the value).
+ONE_PASS_TEXT = {
+    ("none", "fwd"): "ffba925a319b8dda", ("none", "bwd"): "3a7f08ee7b9c3976",
+    ("two_part", "fwd"): "5e084ac73e84e752",
+    ("two_part", "bwd"): "7413bf6c3ad4bd07",
+    ("too_few_rows_to_walk", "fwd"): "299b160549fcd200",
+    ("too_few_rows_to_walk", "bwd"): "53434d12d6e5d03a",
+}
+
+
+@pytest.mark.parametrize("share, passes", ONE_PASS_TEXT)
+def test_the_one_pass_is_the_parents_text(share, passes):
+    from deepspeed_tpu.moe import sharded_moe
+    held = {"none": None, "two_part": (4, 4),
+            "too_few_rows_to_walk": (4, 4, 16)}[share]
+    S = 32 if share == "too_few_rows_to_walk" else 64
+    k, D, F, E = 4, 128, 128, 4 if held else 16
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    def loss(xs, weights, experts, w1, w3, w2):
+        return jnp.sum(sharded_moe.moe_swiglu_routed(
+            xs, weights, experts, w1, w3, w2, False, held=held))
+    fn = jax.grad(loss, argnums=(0, 1, 3, 4, 5)) if passes == "bwd" else loss
+    text = jax.jit(fn).lower(
+        f32(S, D), f32(S, k), jax.ShapeDtypeStruct((S, k), jnp.int32),
+        f32(E, D, F), f32(E, D, F), f32(E, F, D)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == ONE_PASS_TEXT[share, passes]
+
+
+def test_the_walks_scopes_reach_its_backward():
+    """The walk's backward is written out (a ``custom_vjp``): its gather and
+    scatter-add, its products and the cotangent's gather still carry
+    ``dstpu.moe.route`` / ``experts`` / ``combine``, and every chunk after a
+    layer's first, forward and backward, lies under ``dstpu.moe.spill``
+    with those inside it."""
+    from deepspeed_tpu.moe import sharded_moe
+    experts, (*operands, ct) = _walk_case(None)
+    text = jax.jit(jax.grad(lambda *a: jnp.sum(
+        ct * sharded_moe.moe_swiglu_routed(
+            a[0], a[1], experts, *a[2:], False, held=(4, 4, 16))),
+        argnums=(0, 1, 2, 3, 4))).lower(*operands).as_text(debug_info=True)
+    backward = re.findall(r'loc\("jit\(<lambda>\)/(transpose\(jvp\([^"]*)"',
+                          text)
+    for scope in mla_moe.SCOPES[1:]:
+        assert any(scope in n and "spill" not in n for n in backward), scope
+        assert any(-1 < n.find("dstpu.moe.spill") < n.find(scope)
+                   for n in backward), scope
+    assert "ragged_dot" in text
 
 
 # ----------------------------------------------- the leaf nobody optimizes

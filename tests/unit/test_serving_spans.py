@@ -622,9 +622,11 @@ def test_moe_reader(metric, bucketed):
     assert 0.0 < value <= 100.0
     assert said[0][0] == "moe_device_seconds"
     scoped = pb_moe.op_scopes(view.trace.path, "/device:TPU:")
+    # (a served layer walks no held share: nothing under dstpu.moe.spill)
     assert {sc for name in scoped.values()
             for sc in SCOPE_SCHEMA if sc in name} \
-        == {sc for sc in SCOPE_SCHEMA if sc.startswith("dstpu.moe.")}
+        == {sc for sc in SCOPE_SCHEMA if sc.startswith("dstpu.moe.")} \
+        - {"dstpu.moe.spill"}
 
 
 SSM_READERS = ("ssm_mix_share", "attn_window_share", "attn_shared_kv_share")
@@ -1197,11 +1199,13 @@ def test_scope_schema_lint_both_directions():
     assert opened - set(SCOPE_SCHEMA) == set(), "scopes not in SCOPE_SCHEMA"
     assert set(SCOPE_SCHEMA) - opened == set(), "registered, never opened"
     assert all(n.startswith("dstpu.") and m for n, m in SCOPE_SCHEMA.items())
-    # the benchmark's readers look for the same names
+    # the benchmark's readers look for the same names; dstpu.moe.spill lies
+    # round route / experts / combine, which they count it under, and is
+    # read in a trace by hand (ISSUE 56)
     assert {pb_moe.SCOPE_EXPERTS, *pb_moe.SCOPES_ROUTE, *pb_ssm.SCOPES,
             *pb_gdn.SCOPES, *pb_dsa.SCOPES, *pb_mla_moe.SCOPES,
             *pb_weights.SCOPES} \
-        == set(SCOPE_SCHEMA)
+        == set(SCOPE_SCHEMA) - {"dstpu.moe.spill"}
 
 
 def test_span_schema_lint_both_directions():

@@ -1031,7 +1031,8 @@ def test_kanana_share_step_fits_the_chip(v5e, monkeypatch):
     # layers' gradients and the inputs a step keeps of them
     sparse = sum(cell.layer_params()[::2])
     more = 3 * (2 * sparse + micro * T * cell.d_model * 2)
-    # no larger than with V padded to the keys' width (4,891,139,072 bytes:
-    # sandbox compile of PR 51's tree, PR 53; 4,251,410,944 without)
-    assert temp <= 4.892e9, temp
+    # the absent experts' rows are never gathered (2,692,935,168 bytes:
+    # sandbox compile, PR 56; 4,251,410,944 with the one pass over every
+    # routed row, PR 53, and 4,891,139,072 with V padded besides, PR 51)
+    assert temp <= 2.75e9, temp
     assert 14 * cell.num_params() + temp + more <= V5E_GB * 1e9, (temp, more)
