@@ -18,7 +18,7 @@ Three pieces:
     alpha-beta is refit from measured exposed collective time against
     the planner's own wire-byte model (``calibrate_links`` picks them
     up on the next ``plan()``), and ``op_cost`` rows carrying measured
-    per-step unit costs for each Pallas tunable op plus the compute
+    per-step unit costs for each named Pallas kernel plus the compute
     tick. Both are cache-file-only pseudo-ops exactly like
     ``comm_bench``'s ``comm_link``: never in the op REGISTRY, invisible
     to dispatch, device-kind refusal rules intact.
@@ -201,7 +201,7 @@ def seed_rows(decomp, report, device_kind=None):
         beta refit as (modeled wire bytes) / (measured exposed seconds)
         with the calibrated alpha carried over; ``calibrate_links``
         reads these on the next ``plan()``;
-      * one ``op_cost`` row per Pallas tunable op the trace attributed
+      * one ``op_cost`` row per named Pallas kernel the trace attributed
         time to, plus the measured compute tick — the measured per-op
         unit costs a later planner iteration prices ticks from.
 
@@ -252,7 +252,7 @@ def seed_rows(decomp, report, device_kind=None):
                 "measured_ms": round(t * 1e3, 4),
             })
 
-    # per-op unit costs: every Pallas tunable op with attributed time,
+    # per-op unit costs: every named Pallas kernel with attributed time,
     # plus the compute tick itself
     unit = dict(decomp.kernels)
     unit["compute_step"] = float(decomp.terms.get("compute", 0.0))

@@ -759,10 +759,11 @@ class InferenceEngineV2:
             def prefill(params, cache, ids, tb, to, length, rng, temp,
                         top_k, all_greedy, *slot):
                 self._install_trace_state()
-                logits, pools = model.apply_paged_prefill(
-                    params, ids, as_pools(cache), tb, to, length, *slot)
-                tok = self._sample_per_slot(logits, rng, temp, top_k,
-                                            all_greedy)
+                with jax.named_scope("dstpu.step.prefill"):
+                    logits, pools = model.apply_paged_prefill(
+                        params, ids, as_pools(cache), tb, to, length, *slot)
+                    tok = self._sample_per_slot(logits, rng, temp, top_k,
+                                                all_greedy)
                 return tok, like_boundary(pools, cache)
 
             # a bucket's program is a trace of its own: noted by its length
@@ -800,12 +801,13 @@ class InferenceEngineV2:
                 all_toks = []
                 pools = as_pools(cache)
                 for t in range(n):
-                    logits, pools = model.apply_paged_decode(
-                        params, tokens, lengths, pools, tables)
-                    tokens = self._sample_per_slot(
-                        logits, jax.random.fold_in(rng, t), temps,
-                        top_ks, all_greedy)
-                    lengths = lengths + 1
+                    with jax.named_scope("dstpu.step.decode"):
+                        logits, pools = model.apply_paged_decode(
+                            params, tokens, lengths, pools, tables)
+                        tokens = self._sample_per_slot(
+                            logits, jax.random.fold_in(rng, t), temps,
+                            top_ks, all_greedy)
+                        lengths = lengths + 1
                     all_toks.append(tokens)
                 return jnp.stack(all_toks), like_boundary(pools, cache)
 
@@ -841,20 +843,22 @@ class InferenceEngineV2:
                       c_table, c_temp, c_topk, d_tokens, d_lengths,
                       d_tables, rng, d_temps, d_topks, all_greedy, *c_slot):
                 self._install_trace_state()
-                c_logits, pools = model.apply_paged_chunk(
-                    params, c_ids, as_pools(cache), c_tb, c_to, c_start,
-                    c_len, c_table, *c_slot)
-                c_tok = self._sample_per_slot(
-                    c_logits, jax.random.fold_in(rng, 7919), c_temp,
-                    c_topk, all_greedy)
+                with jax.named_scope("dstpu.step.chunk"):
+                    c_logits, pools = model.apply_paged_chunk(
+                        params, c_ids, as_pools(cache), c_tb, c_to, c_start,
+                        c_len, c_table, *c_slot)
+                    c_tok = self._sample_per_slot(
+                        c_logits, jax.random.fold_in(rng, 7919), c_temp,
+                        c_topk, all_greedy)
                 toks = []
                 for t in range(n):
-                    logits, pools = model.apply_paged_decode(
-                        params, d_tokens, d_lengths, pools, d_tables)
-                    d_tokens = self._sample_per_slot(
-                        logits, jax.random.fold_in(rng, t), d_temps,
-                        d_topks, all_greedy)
-                    d_lengths = d_lengths + 1
+                    with jax.named_scope("dstpu.step.decode"):
+                        logits, pools = model.apply_paged_decode(
+                            params, d_tokens, d_lengths, pools, d_tables)
+                        d_tokens = self._sample_per_slot(
+                            logits, jax.random.fold_in(rng, t), d_temps,
+                            d_topks, all_greedy)
+                        d_lengths = d_lengths + 1
                     toks.append(d_tokens)
                 return c_tok, jnp.stack(toks), like_boundary(pools, cache)
 
@@ -876,12 +880,13 @@ class InferenceEngineV2:
             def chunk(params, cache, c_ids, c_tb, c_to, c_start, c_len,
                       c_table, c_temp, c_topk, rng, all_greedy, *c_slot):
                 self._install_trace_state()
-                c_logits, pools = model.apply_paged_chunk(
-                    params, c_ids, as_pools(cache), c_tb, c_to, c_start,
-                    c_len, c_table, *c_slot)
-                c_tok = self._sample_per_slot(
-                    c_logits, jax.random.fold_in(rng, 7919), c_temp,
-                    c_topk, all_greedy)
+                with jax.named_scope("dstpu.step.chunk"):
+                    c_logits, pools = model.apply_paged_chunk(
+                        params, c_ids, as_pools(cache), c_tb, c_to, c_start,
+                        c_len, c_table, *c_slot)
+                    c_tok = self._sample_per_slot(
+                        c_logits, jax.random.fold_in(rng, 7919), c_temp,
+                        c_topk, all_greedy)
                 return c_tok, like_boundary(pools, cache)
 
             self._chunk_jit = jax.jit(
@@ -929,9 +934,10 @@ class InferenceEngineV2:
 
             def dchunk(params, cache, ids, tb, to, start, tlen, table):
                 self._install_trace_state()
-                _logits, pools = draft.apply_paged_chunk(
-                    params, ids, as_pools(cache), tb, to, start, tlen,
-                    table)
+                with jax.named_scope("dstpu.step.chunk"):
+                    _logits, pools = draft.apply_paged_chunk(
+                        params, ids, as_pools(cache), tb, to, start, tlen,
+                        table)
                 return like_boundary(pools, cache)
 
             self._draft_chunk_jit = jax.jit(
@@ -956,19 +962,21 @@ class InferenceEngineV2:
 
             def propose(params, cache, tokens2, lengths, tables):
                 self._install_trace_state()
-                _lg, pools = draft.apply_paged_decode(
-                    params, tokens2[:, 0], lengths, as_pools(cache),
-                    tables)
+                with jax.named_scope("dstpu.step.decode"):
+                    _lg, pools = draft.apply_paged_decode(
+                        params, tokens2[:, 0], lengths, as_pools(cache),
+                        tables)
                 cur = tokens2[:, 1]
                 lengths = lengths + 1
                 props = []
                 for _ in range(k):
-                    logits, pools = draft.apply_paged_decode(
-                        params, cur, lengths, pools, tables)
-                    # only greedy sequences speculate, so the draft is
-                    # always greedy too
-                    cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    lengths = lengths + 1
+                    with jax.named_scope("dstpu.step.decode"):
+                        logits, pools = draft.apply_paged_decode(
+                            params, cur, lengths, pools, tables)
+                        # only greedy sequences speculate, so the draft
+                        # is always greedy too
+                        cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                        lengths = lengths + 1
                     props.append(cur)
                 return jnp.stack(props, axis=1), like_boundary(pools, cache)
 

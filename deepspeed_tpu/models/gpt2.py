@@ -543,6 +543,7 @@ class GPT2:
         qkv = qkv.reshape(B, T, 3, H, hd)
         return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
 
+    @jax.named_scope("dstpu.attn.flash")
     def block_attn(self, q, kk, v, *, causal, constrain, seq_sharded,
                    force_dense=False, window=None):
         """Attention backend dispatch: (B, T, H, hd) x3 -> (B, T, H, hd).

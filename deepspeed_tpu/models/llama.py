@@ -481,9 +481,29 @@ class Llama:
         dt = jnp.dtype(cfg.dtype)
         from ..ops.int8_weights import dequant_tree
         layer = dequant_tree(layer, dt)
-        B, T = x.shape[0], x.shape[1]
-        H, KVH, hd = cfg.n_head, cfg.n_kv_heads, cfg.d_head
         q, kk, v = self._attn_proj(x, layer)
+        attn = self.block_attn(q, kk, v, pos, causal=causal,
+                               constrain=constrain)
+        attn_out = self._wo(constrain(attn, act_spec), layer)
+        if cfg.parallel_block:
+            # falcon/phi: attention and MLP branch from the same input
+            x = x + attn_out + self._mlp(x, layer)
+        else:
+            x = x + attn_out
+            x = constrain(x, act_spec)
+            x = x + self._mlp(x, layer)
+        return constrain(x, act_spec)
+
+    @jax.named_scope("dstpu.attn.flash")
+    def block_attn(self, q, kk, v, pos, *, causal, constrain):
+        """The training attention between its weight products: (B, T, H,
+        hd) q and (B, T, KVH, hd) k, v -> (B, T, H * hd). Rotary, the
+        heads' sharding, GQA's repeat, the flash kernel (or the dense
+        softmax) and the output's layout."""
+        cfg = self.config
+        dt = jnp.dtype(cfg.dtype)
+        B, T = q.shape[0], q.shape[1]
+        H, KVH, hd = cfg.n_head, cfg.n_kv_heads, cfg.d_head
         q = self._rope(q, pos)
         kk = self._rope(kk, pos)
         head_spec = P(BATCH_AXES, None, "tensor", None)
@@ -523,15 +543,7 @@ class Llama:
             probs = jax.nn.softmax(scores, axis=-1).astype(dt)
             attn = jnp.einsum("bhts,bshd->bthd", probs,
                               v).reshape(B, T, H * hd)
-        attn_out = self._wo(constrain(attn, act_spec), layer)
-        if cfg.parallel_block:
-            # falcon/phi: attention and MLP branch from the same input
-            x = x + attn_out + self._mlp(x, layer)
-        else:
-            x = x + attn_out
-            x = constrain(x, act_spec)
-            x = x + self._mlp(x, layer)
-        return constrain(x, act_spec)
+        return attn
 
     def apply(self, params, input_ids, *, rng=None, train=False,
               seq_sharded=False, return_hidden=False):

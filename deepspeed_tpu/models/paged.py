@@ -380,23 +380,26 @@ class _Step:
             j = self.index[kind[1]]
 
             def read_fn(q, k=None, v=None):
-                return self.attend(q, self.cache["k"][j],
-                                   self.cache["v"][j], window,
-                                   self.tables[KV]), None
+                with jax.named_scope("dstpu.attn.paged"):
+                    return self.attend(q, self.cache["k"][j],
+                                       self.cache["v"][j], window,
+                                       self.tables[KV]), None
 
             return read_fn
         (kk, vk), j = _KEYS[kind], self.index[i]
         blocks, offsets, rows = self.dest[kind]
 
         def attn_fn(q, k, v):
-            kc, vc = paged_kv_write(
-                (self.cache[kk][j], self.cache[vk][j]),
-                (k.reshape((-1,) + k.shape[2:]),
-                 v.reshape((-1,) + v.shape[2:])),
-                blocks, offsets, rows=rows, kernel=self.use_kernel)
+            with jax.named_scope("dstpu.kv.write"):
+                kc, vc = paged_kv_write(
+                    (self.cache[kk][j], self.cache[vk][j]),
+                    (k.reshape((-1,) + k.shape[2:]),
+                     v.reshape((-1,) + v.shape[2:])),
+                    blocks, offsets, rows=rows, kernel=self.use_kernel)
             self.cache[kk][j], self.cache[vk][j] = kc, vc
-            return self.attend(q, kc, vc, window, self.tables[kind]), \
-                (kc, vc)
+            with jax.named_scope("dstpu.attn.paged"):
+                return self.attend(q, kc, vc, window, self.tables[kind]), \
+                    (kc, vc)
 
         return attn_fn
 

@@ -10,7 +10,8 @@ registry entry cannot silently rot the schema dashboards are built on).
 
 ``SPAN_SCHEMA`` below is the same contract for the host spans the
 serving path opens on the profiler's clock, ``SCOPE_SCHEMA`` for the
-``jax.named_scope``s that name device operations.
+``jax.named_scope``s that name device operations, ``KERNEL_SCHEMA`` for the
+names of the Pallas kernels among them.
 
 This module deliberately holds NOTHING but the registry: the lint
 collects emitted-tag literals by grepping the package with this file
@@ -488,6 +489,102 @@ SCOPE_SCHEMA = {
         "model width",
     "dstpu.mm.gmu":
         "Gated Memory Unit: its gate and its output projection",
+    # --- what every family shares (PR 57): read by name, with no list of
+    #     names, by perfbench/pbench/names.py
+    "dstpu.attn.paged":
+        "models/paged.py:_Step: the read of a KV / RING / SHARED layer's "
+        "pools through the block table, whatever implements it (the paged "
+        "decode or chunk kernel, or the dense gather off a TPU); a sibling "
+        "of dstpu.kv.write inside a family's own dstpu.attn.* scope",
+    "dstpu.kv.write":
+        "models/paged.py:_Step: the write of a step's new K and V rows "
+        "into the layer's donated pools (the write kernel, or the scatter)",
+    "dstpu.step.prefill":
+        "a serving program's bucketed prefill: the model's "
+        "apply_paged_prefill and the sampling of its first token; the "
+        "dstpu.step.* are the outermost scope of a serving program's "
+        "operations and say the phase, not the layer",
+    "dstpu.step.chunk":
+        "a serving program's prompt chunk (the chunk of a fused or "
+        "chunk-only dispatch, a draft model's catch-up chunk): "
+        "apply_paged_chunk and the sampling after it",
+    "dstpu.step.decode":
+        "ONE decode step of a serving program (a plain dispatch has "
+        "decode_steps_per_dispatch of them, a fused one _FUSED_STEPS, a "
+        "draft's propose program spec_k + 1): apply_paged_decode and the "
+        "sampling of its tokens",
+    "dstpu.attn.flash":
+        "a TRAINING attention layer of gpt2 / llama outside its weight "
+        "products: rotary, the q / k / v layout, the flash forward and "
+        "backward (or the ring, or the dense softmax off a TPU) and the "
+        "output's layout; what dstpu.attn.mla is to deepseek_v3",
+    "dstpu.optim.update":
+        "the training step after its gradients (runtime/engine.py "
+        "apply_update / finish_grads): unscale, the overflow check, the "
+        "global norm and clip, the optimizer's update, the cast of the "
+        "master weights back to the parameters' dtype",
+}
+
+# the name every ``pallas_call`` site under ``deepspeed_tpu/`` passes as
+# ``name=`` -> what the kernel is. In jax 0.9.0 that is a ``named_scope``
+# round the bind (so the name is in the operation's ``tf_op`` like any of
+# SCOPE_SCHEMA) and the Mosaic call's ``kernel_name``. Linted three ways by
+# tests/unit/test_serving_spans.py: every site carries a registered name,
+# no two sites share one, every registered name is at a site.
+_K = "dstpu.kernel."
+KERNEL_SCHEMA = {
+    _K + "flash_fwd": "flash attention forward, heads-major or standard",
+    _K + "flash_fwd_t": "flash attention forward on (B, H, hd, T) operands",
+    _K + "flash_bwd": "flash attention backward: dq, dk, dv (k-major)",
+    _K + "flash_bwd_t": "flash backward on transposed operands (k-major)",
+    _K + "flash_bwd_t_qmajor":
+        "flash backward on transposed operands, one q-major pass",
+    _K + "flash_block_fwd":
+        "one KV block of ring attention: the running max / sum / "
+        "accumulator carried over the rotation",
+    _K + "fused_ce": "unembed + cross-entropy statistics of a training step",
+    _K + "paged_decode": "paged decode attention over a step's work list",
+    _K + "paged_chunk":
+        "paged attention of a prompt chunk (or a bucketed prefill, or a "
+        "verify pass) against the cache and its own causal prefix",
+    _K + "kv_write": "a step's new K / V rows into the donated pools",
+    _K + "swiglu_forward":
+        "the routed experts' gate, up and down products in one forward call",
+    _K + "gmm": "grouped matmul of sorted rows against per-expert weights",
+    _K + "tgmm": "the grouped matmul's weight gradient",
+    _K + "swiglu_up": "grouped gate and up products with the SwiGLU between",
+    _K + "gmm_wq": "grouped matmul against int8 / int4 weights",
+    _K + "swiglu_up_wq": "grouped gate / up against int8 / int4 weights",
+    _K + "gdn_chunk":
+        "the gated delta rule over a call's chunks, state kept in VMEM",
+    _K + "gdn_step":
+        "the gated delta rule's one-token update over the live slots",
+    _K + "latent_read":
+        "a chunk's read of the selected latent rows (MLA, expanded form)",
+    _K + "ln_fwd": "LayerNorm forward",
+    _K + "ln_bwd": "LayerNorm backward: dx, dscale, dbias",
+    _K + "rms_fwd": "RMSNorm forward",
+    _K + "mlp_mm": "the dense MLP's tiled matmul (any operand layout)",
+    _K + "mlp_dw": "the dense MLP's weight gradient",
+    _K + "mlp_mm_wq": "the dense MLP's matmul against int8 / int4 weights",
+    _K + "quantize": "blockwise quantization to int8 with scales",
+    _K + "dequantize": "blockwise dequantization",
+    _K + "bsa_fwd": "block-sparse attention forward",
+    _K + "bsa_bwd_dq": "block-sparse attention backward: dq",
+    _K + "bsa_bwd_dkv": "block-sparse attention backward: dk, dv",
+}
+# the trace-time tally's word (KERNEL_SHARES, SHAPE_PATHS above: what
+# ``note_call`` counts a call under) -> the kernels such a call runs. The
+# tally counts calls of a traced body, the names label device operations.
+KERNEL_TALLY = {
+    "flash": tuple(_K + n for n in (
+        "flash_fwd", "flash_fwd_t", "flash_bwd", "flash_bwd_t",
+        "flash_bwd_t_qmajor")),
+    "expert": tuple(_K + n for n in (
+        "swiglu_forward", "gmm", "tgmm", "swiglu_up", "gmm_wq",
+        "swiglu_up_wq")),
+    "rule": (_K + "gdn_chunk", _K + "gdn_step"),
+    "latent_read": (_K + "latent_read",),
 }
 
 

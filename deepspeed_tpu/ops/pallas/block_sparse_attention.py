@@ -206,6 +206,7 @@ def _fwd(q, k, v, lists, bq, bk, H, causal, interpret):
     )
     return pl.pallas_call(
         functools.partial(_fwd_kernel, bq=bq, bk=bk, H=H, causal=causal),
+        name="dstpu.kernel.bsa_fwd",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((BH, T, d), q.dtype),
                    jax.ShapeDtypeStruct((BH, T, 128), jnp.float32)],
@@ -221,6 +222,7 @@ def _bwd(q, k, v, o, lse, do, lists, bq, bk, H, causal, interpret):
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, bq=bq, bk=bk, H=H,
                           causal=causal),
+        name="dstpu.kernel.bsa_bwd_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(BH, T // bq),
@@ -241,6 +243,7 @@ def _bwd(q, k, v, o, lse, do, lists, bq, bk, H, causal, interpret):
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, bq=bq, bk=bk, H=H,
                           causal=causal),
+        name="dstpu.kernel.bsa_bwd_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(BH, T // bk),

@@ -361,6 +361,7 @@ def _fwd(q, k, v, scale, causal, bq, bk, bh, t_real, interpret, window=0,
         functools.partial(_fwd_kernel, bq=bq, bk=bk, scale=scale,
                           causal=causal, t_real=t_real, window=window,
                           bias_cfgs=bias_cfgs, alibi_cfg=alibi_cfg),
+        name="dstpu.kernel.flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bh, bq, d), lambda b, i: (b, i, 0)),
@@ -473,6 +474,7 @@ def _fwd_t(q, k, v, scale, causal, bq, bk, bh, t_real, interpret,
         functools.partial(_fwd_kernel_t, bq=bq, bk=bk, scale=scale,
                           causal=causal, t_real=t_real, window=window,
                           bias_cfgs=bias_cfgs, alibi_cfg=alibi_cfg),
+        name="dstpu.kernel.flash_fwd_t",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bh, d, bq), lambda b, i: (b, 0, i)),
@@ -706,6 +708,7 @@ def _bwd(q, k, v, o, lse_t, do, scale, causal, bq, bk, bh, t_real,
                           ext_delta=dlse is not None, single_k=single_k,
                           window=window, bias_cfgs=bias_cfgs,
                           alibi_cfg=alibi_cfg),
+        name="dstpu.kernel.flash_bwd",
         grid=(BH // bh, T // bk),
         in_specs=[
             pl.BlockSpec((bh, T, d), lambda b, j: (b, 0, 0)),
@@ -970,6 +973,7 @@ def _bwd_t_qmajor(q, k, v, o, lse_t, do, scale, causal, bq, bk, bh,
         functools.partial(_bwd_kernel_t_qmajor, bq=bq, bk=bk, scale=scale,
                           causal=causal, t_real=t_real,
                           ext_delta=dlse is not None, window=window),
+        name="dstpu.kernel.flash_bwd_t_qmajor",
         grid=(BH // bh, T // bq),
         in_specs=[
             pl.BlockSpec((bh, d, bq), lambda b, i: (b, 0, i)),
@@ -1021,6 +1025,7 @@ def _bwd_t(q, k, v, o, lse_t, do, scale, causal, bq, bk, bh, t_real,
                           ext_delta=dlse is not None, single_k=single_k,
                           window=window, bias_cfgs=bias_cfgs,
                           alibi_cfg=alibi_cfg),
+        name="dstpu.kernel.flash_bwd_t",
         grid=(BH // bh, T // bk),
         in_specs=[
             pl.BlockSpec((bh, d, T), lambda b, j: (b, 0, 0)),
@@ -1182,6 +1187,7 @@ def flash_block_fwd(q, k, v, state, *, causal=False, block_q=128,
     mo, lo, acco = pl.pallas_call(
         functools.partial(_fwd_block_kernel, bq=bq, bk=bk, causal=causal,
                           t_real=T),
+        name="dstpu.kernel.flash_block_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bh, bq, d_pad), lambda b, i: (b, i, 0)),

@@ -114,6 +114,7 @@ def unembed_logits_stats(h, w, targets, *, block_m=_AUTO, block_n=_AUTO,
     t2 = targets.astype(jnp.int32)[:, None]
     logits, logz, gold = pl.pallas_call(
         functools.partial(_ce_kernel, bn=block_n, V=V),
+        name="dstpu.kernel.fused_ce",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, D), lambda i, j: (i, 0)),

@@ -175,6 +175,7 @@ def _chunk_call(q, k, v, log_a, b, state, *, interpret):
                        lambda r, g, n: (r, g, 0, 0, 0))
     o, state = pl.pallas_call(
         _chunk_kernel,
+        name="dstpu.kernel.gdn_chunk",
         grid=(B, G, N),
         in_specs=[tokens(dk), tokens(dk), tokens(dv), gate, gate, mat],
         out_specs=[tokens(dv), mat],
@@ -289,6 +290,7 @@ def _step_call(slot_of, n_live, q, k, v, log_a, b, ssm, *, interpret):
     tiled = H * round_up(dk, 8) * round_up(dv, 128) * 4
     return pl.pallas_call(
         _step_kernel,
+        name="dstpu.kernel.gdn_step",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(v.shape, jnp.float32),
                    jax.ShapeDtypeStruct(ssm.shape, jnp.float32)],

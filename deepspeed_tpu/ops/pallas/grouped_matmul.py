@@ -165,6 +165,7 @@ def _gmm(x, w, group_sizes, *, tm, tn, tk, trans_w, interpret):
                      (gid[i], kk, j))
     out = pl.pallas_call(
         functools.partial(_gmm_kernel, tm=tm, nk=K // tk, trans_w=trans_w),
+        name="dstpu.kernel.gmm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=grid,
@@ -229,6 +230,7 @@ def _swiglu_up(x, w1, w3, group_sizes, *, tm, tn, tk, interpret):
                           (gid[i], kk, j))
     return pl.pallas_call(
         functools.partial(_swiglu_up_kernel, tm=tm, nk=K // tk),
+        name="dstpu.kernel.swiglu_up",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(F // tn, G, K // tk),
@@ -287,6 +289,7 @@ def _tgmm(x, dy, group_sizes, E, *, tm, tn, tk, out_dtype, interpret):
     G = int(gids.shape[0])
     return pl.pallas_call(
         functools.partial(_tgmm_kernel, tm=tm, last_i=G - 1),
+        name="dstpu.kernel.tgmm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(K // tk, N // tn, G),
@@ -474,6 +477,7 @@ def _swiglu_forward(x, w1, w3, w2, group_sizes, *, tiles, interpret):
     row = lambda i, f, wid, mtid, *_: (mtid[i], 0)
     out = pl.pallas_call(
         functools.partial(_swiglu_forward_kernel, tm=tm, nf=F // tf),
+        name="dstpu.kernel.swiglu_forward",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(n, F // tf),
@@ -742,6 +746,7 @@ def _gmm_wq(x, q, s, group_sizes, *, tm, tn, tk, int4, interpret):
                           (gid[i], 0, j))
     return pl.pallas_call(
         functools.partial(_gmm_wq_kernel, tm=tm, nk=K // tk, int4=int4),
+        name="dstpu.kernel.gmm_wq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(N // tn, G, K // tk),
@@ -817,6 +822,7 @@ def _swiglu_up_wq(x, q1, s1, q3, s3, group_sizes, *, tm, tn, tk, int4,
     return pl.pallas_call(
         functools.partial(_swiglu_up_wq_kernel, tm=tm, nk=K // tk,
                           int4=int4),
+        name="dstpu.kernel.swiglu_up_wq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(F // tn, G, K // tk),

@@ -220,6 +220,7 @@ def _latent_read_call(q, rows, sel, wk_b, wv_b, q_pos, frontier, *,
     )
     o = pl.pallas_call(
         _read_kernel,
+        name="dstpu.kernel.latent_read",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, NQ * TQ, H * dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(

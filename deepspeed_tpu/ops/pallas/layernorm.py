@@ -89,6 +89,7 @@ def _run_fwd(x, scale, bias, eps, br, interpret):
     N, D = x.shape
     return pl.pallas_call(
         functools.partial(_ln_fwd_kernel, eps=eps),
+        name="dstpu.kernel.ln_fwd",
         grid=(N // br,),
         in_specs=[
             pl.BlockSpec((br, D), lambda i: (i, 0)),
@@ -105,6 +106,7 @@ def _run_bwd(x, scale, dy, eps, br, interpret):
     N, D = x.shape
     dx, ds, db = pl.pallas_call(
         functools.partial(_ln_bwd_kernel, eps=eps),
+        name="dstpu.kernel.ln_bwd",
         grid=(N // br,),
         in_specs=[
             pl.BlockSpec((br, D), lambda i: (i, 0)),
@@ -242,6 +244,7 @@ def fused_rmsnorm(x, scale, *, eps=1e-5, block_rows=256, interpret=None):
     def run(x2, br):
         return pl.pallas_call(
             functools.partial(_rms_fwd_kernel, eps=eps),
+            name="dstpu.kernel.rms_fwd",
             grid=(x2.shape[0] // br,),
             in_specs=[
                 pl.BlockSpec((br, D), lambda i: (i, 0)),

@@ -121,6 +121,7 @@ def _mm(a, b, *, a_t, b_t, out_t, bn, bm, bk, out_dtype, interpret):
     return pl.pallas_call(
         functools.partial(_mm_kernel, a_t=a_t, b_t=b_t, out_t=out_t,
                           nk=K // bk),
+        name="dstpu.kernel.mlp_mm",
         grid=grid,
         in_specs=[a_spec, b_spec],
         out_specs=o_spec,
@@ -175,6 +176,7 @@ def _dw(a, g, *, a_t, g_t, bkK, bm, bn, out_dtype, interpret):
     return pl.pallas_call(
         functools.partial(_dw_kernel, a_t=a_t, g_t=g_t, last_p=P - 1,
                           last_n=N // bn - 1),
+        name="dstpu.kernel.mlp_dw",
         grid=grid,
         in_specs=[a_spec, g_spec],
         out_specs=pl.BlockSpec((bkK, bm), lambda k, j, p, i: (k, j)),
@@ -342,6 +344,7 @@ def _mm_wq(a, q, s, *, a_t, out_t, bn, bm, bk, out_dtype, int4,
     return pl.pallas_call(
         functools.partial(_mm_wq_kernel, a_t=a_t, out_t=out_t,
                           nk=K // bk, int4=int4),
+        name="dstpu.kernel.mlp_mm_wq",
         grid=grid,
         in_specs=[a_spec, b_spec, s_spec],
         out_specs=o_spec,

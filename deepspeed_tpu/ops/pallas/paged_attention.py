@@ -417,6 +417,7 @@ def _decode_call(tables, lengths, slot_of, entry_of, count_of, n, q, kp, vp,
         functools.partial(_decode_kernel, N=N, BS=BS, KVH=KVH, G=G,
                           scale=scale, window=window, alibi=alibi,
                           alibi_scale=alibi_scale, alibi_bf16=alibi_bf16),
+        name="dstpu.kernel.paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, d), q.dtype),
         # operands 0-4 are the scalar-prefetched integers; q is 5
@@ -623,6 +624,7 @@ def _kv_write_call(row_of, block_of, offset_of, n_live, kn, vn, kp, vp, *,
     )
     return tuple(pl.pallas_call(
         functools.partial(_kv_write_kernel, R=R),
+        name="dstpu.kernel.kv_write",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(kp.shape, kp.dtype),
                    jax.ShapeDtypeStruct(vp.shape, vp.dtype)],
@@ -892,6 +894,7 @@ def paged_chunk_attention(q, k_cache, v_cache, table, start, true_len, *,
     out = pl.pallas_call(
         functools.partial(_chunk_kernel, BS=BS, KVH=KVH, G=G, BC=BC,
                           scale=float(scale), window=int(window)),
+        name="dstpu.kernel.paged_chunk",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((KVH, C_pad * G, d), q.dtype),
         interpret=interpret,

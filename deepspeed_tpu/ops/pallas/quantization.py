@@ -98,6 +98,7 @@ def quantize_blockwise(x, block=QUANT_BLOCK, use_pallas=True,
                   if nbp != nb else blocked)
         q, s = pl.pallas_call(
             _quant_kernel,
+            name="dstpu.kernel.quantize",
             grid=(nbp // rows,),
             in_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0))],
             out_specs=[
@@ -132,6 +133,7 @@ def dequantize_blockwise(q, s, meta, use_pallas=True, interpret=None):
         sp = jnp.pad(s, ((0, nbp - nb), (0, 0))) if nbp != nb else s
         out = pl.pallas_call(
             _dequant_kernel,
+            name="dstpu.kernel.dequantize",
             grid=(nbp // rows,),
             in_specs=[
                 pl.BlockSpec((rows, block), lambda i: (i, 0)),

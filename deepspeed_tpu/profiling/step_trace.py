@@ -24,7 +24,8 @@ the reconcile CLI share. The pipeline:
      (``host_offload``), a device-side layout copy (the one explicitly
      *unmodeled* key), or ``compute`` (matmul/fusion/Pallas/everything
      else). Pallas custom-call time is additionally broken out per
-     tunable-op name from the autotune registry (``kernels``).
+     kernel, by the ``dstpu.kernel.<op>`` name its call site passes
+     (``kernels``; ``monitor/tag_schema.py:KERNEL_SCHEMA``).
   4. **Collective legs** — when an event's args carry the HLO
      ``replica_groups=...`` text, the PR-3 parse
      (``runtime/zero/overlap.parse_replica_groups`` + ``match_axes``)
@@ -57,6 +58,7 @@ import os
 import re
 from dataclasses import dataclass, field, asdict
 
+from ..monitor.tag_schema import KERNEL_SCHEMA
 from ..utils.logging import logger
 
 SCHEMA_VERSION = 1
@@ -84,20 +86,10 @@ _COPY_RE = re.compile(r"^copy(-start|-done)?(?:\.(\d+))?$")
 # device time
 _HLO_NAME_RE = re.compile(r"^[a-z][a-z0-9_\-]*(?:\.\d+)?$")
 
-# fragments of our Pallas kernel symbol names -> the tunable-op name in
-# autotuning/kernel_registry.REGISTRY the kernel time is keyed under
-# (first match wins; specific before generic)
-KERNEL_OP_HINTS = (
-    ("paged_chunk", ("paged_chunk", "chunk_prefill", "_chunk_kernel")),
-    ("paged_decode", ("paged", "_decode_kernel")),
-    ("moe_grouped_mm", ("gmm", "tgmm", "swiglu", "grouped")),
-    ("ring_block", ("ring_block", "fwd_block")),
-    ("flash_attention", ("flash", "block_sparse",
-                         "_fwd_kernel", "_bwd_kernel")),
-    ("mlp_matmul", ("mlp", "_mm_kernel", "_dw_kernel")),
-    ("layernorm", ("layernorm", "rmsnorm", "_ln_", "_rms_")),
-    ("fused_ce", ("fused_ce", "_ce_kernel", "cross_entropy")),
-)
+# a Pallas kernel says its own name: every ``pallas_call`` site passes
+# ``name="dstpu.kernel.<op>"`` (monitor/tag_schema.py:KERNEL_SCHEMA), which
+# jax puts in the operation's op_name and the Mosaic call's kernel_name
+_KERNEL_NAME_RE = re.compile(r"dstpu\.kernel\.([a-z0-9_]+)")
 
 
 def family_of(name):
@@ -119,14 +111,11 @@ def family_of(name):
 
 
 def kernel_op_for(text):
-    """Registry tunable-op name for a Pallas/custom-call event, matched
-    on kernel-symbol fragments in the event name + args; None when the
-    call is not one of ours."""
-    t = text.lower()
-    for op, hints in KERNEL_OP_HINTS:
-        if any(h in t for h in hints):
-            return op
-    return None
+    """``<op>`` for an event whose name or args hold ``dstpu.kernel.<op>``,
+    a registered kernel name; None for anything else (a custom call that
+    is not one of ours, a name nobody registered)."""
+    m = _KERNEL_NAME_RE.search(text)
+    return m.group(1) if m and m.group(0) in KERNEL_SCHEMA else None
 
 
 # ------------------------------------------------------------- trace io
@@ -286,7 +275,7 @@ class StepDecomposition:
     terms: dict = field(default_factory=dict)      # DECOMP_TERMS -> ms
     unmodeled: dict = field(default_factory=dict)  # UNMODELED_KEYS -> ms
     collectives: list = field(default_factory=list)
-    kernels: dict = field(default_factory=dict)    # registry op -> ms
+    kernels: dict = field(default_factory=dict)    # kernel <op> -> ms
     per_op: list = field(default_factory=list)
     host_copy_ms: float = 0.0
     collective_total_ms: float = 0.0
@@ -382,9 +371,7 @@ def decompose(events, steps=1, mesh=None, trace_path=""):
             unmodeled["copy_layout"] += sdur / 1e3
             continue
         text = name + " " + _args_text(e)
-        kop = kernel_op_for(text) if (
-            fam == "pallas/custom-call" or "kernel" in text.lower()) \
-            else None
+        kop = kernel_op_for(text)
         if kop is not None:
             kernels[kop] += sdur / 1e3
         terms["compute"] += sdur / 1e3

@@ -696,6 +696,7 @@ class DeepSpeedEngine:
                     lambda g: (g * coef).astype(g.dtype), grads)
             return grads, finite, gnorm
 
+        @jax.named_scope("dstpu.optim.update")
         def apply_update(state, grads, lr):
             """grads: fp32 tree, already averaged over GAS; scale included."""
             scale = state["scale"]["scale"]
@@ -831,6 +832,7 @@ class DeepSpeedEngine:
                 rng=jax.random.fold_in(state["rng"], 0))
             return new_state
 
+        @jax.named_scope("dstpu.optim.update")
         def finish_grads(grads, scale):
             """Staged-API ZeRO-Offload: unscale/clip the accumulated grads
             on device before the host update."""

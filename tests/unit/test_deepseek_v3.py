@@ -654,6 +654,10 @@ def _lowers_to_the_parents_text(layout, passes, what):
     fn = jax.grad(loss, argnums=(0, 1, 2)) if passes == "bwd" else loss
     text = str(jax.make_jaxpr(fn)(*args)) if what == "compiled" \
         else jax.jit(fn).lower(*args).as_text()
+    # PR 57 gave each ``pallas_call`` a ``name=``, which a jaxpr prints (PR
+    # 51's read ``name=None``): the call differs from the parent's in that
+    # parameter alone, so it is put back before the hash is taken
+    text = re.sub(r"name=dstpu\.kernel\.[a-z_]+", "name=None", text)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == PARENT_TEXT[layout, passes, what]
 

@@ -23,7 +23,9 @@ from deepspeed_tpu.inference.v2 import InferenceEngineV2, Router
 from deepspeed_tpu.inference.v2 import engine_v2, router as router_mod
 from deepspeed_tpu.inference.v2.replica import Replica
 from deepspeed_tpu.models import GPT2, GPT2Config
-from deepspeed_tpu.monitor.tag_schema import SCOPE_SCHEMA, SPAN_SCHEMA
+from deepspeed_tpu.monitor.tag_schema import (
+    KERNEL_SCHEMA, KERNEL_SHARES, KERNEL_TALLY, SCOPE_SCHEMA, SHAPE_PATHS,
+    SPAN_SCHEMA)
 from deepspeed_tpu.monitor.telemetry import ServingTelemetry
 from deepspeed_tpu.utils import groups
 
@@ -32,8 +34,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path.insert(0, os.path.join(REPO, "perfbench"))
 from pbench import (common as pb_common, dsa as pb_dsa,  # noqa: E402
                     gdn as pb_gdn, mla_moe as pb_mla_moe,
-                    moe as pb_moe, ssm as pb_ssm, trace as pb_trace,
-                    weights as pb_weights)
+                    moe as pb_moe, names as pb_names, ssm as pb_ssm,
+                    trace as pb_trace, weights as pb_weights)
 
 _CFG = GPT2Config(n_layer=2, n_head=4, d_model=64, max_seq_len=128,
                   vocab_size=256, remat=False, dtype="float32")
@@ -1183,6 +1185,347 @@ _SCOPE_RE = re.compile(
     r"""(?:\bnamed_scope\(\s*|,\s*)["'](dstpu\.[A-Za-z0-9_.]+)["']\s*\)""")
 
 
+# ------------------------------------------------ the program's own names
+NAME_READERS = ("paged_attn_share", "kv_write_share",
+                "paged_chunk_kernel_share", "chunk_phase_share")
+UNNAMED_READERS = ("unnamed_busy_share", "train_unnamed_busy_share")
+
+
+def _fixture_trace(name):
+    return pb_trace.Trace(os.path.join(_FIXTURES, name + ".xplane.pb"))
+
+
+@pytest.mark.parametrize("metric", NAME_READERS + UNNAMED_READERS)
+@pytest.mark.parametrize("case", ["tiny4", "moe1", "dense1", "no-trace",
+                                  "no-dispatch", "bare-spans"])
+def test_name_readers_stand_on_a_program_without_the_names(
+        metric, case, bucketed):
+    """ISSUE 57 (PR 37's rule on the CPU): on a trace whose program gives
+    none of PR 57's names — the three fixtures recorded on the chip before
+    it, as the parent commit's programs are — the four readers of a name
+    return None and the two of the unnamed rest a number, which is all of
+    the busy time that PRs 26–54 left without a ``dstpu.*`` scope; no
+    trace, an empty window and spans without stats give None or that
+    number, and nothing raises."""
+    tr = bucketed[0]
+    bare = [(line, pb_trace.Event(e.name, e.start, e.end, {}))
+            for line, e in tr.host]
+    trace = {"no-trace": lambda: None,
+             "no-dispatch": lambda: _restated(
+                 _fixture_trace("dense1"), t0=0.0, t1=1e-9),
+             "bare-spans": lambda: _restated(tr, host=bare)}.get(
+                 case, lambda: _fixture_trace(case))()
+    said = []
+    view = _weights_view(trace, _cell_sizes("gpt2-medium"), said)
+    value = pb_common.load_module("layer_metrics", metric).read(view)
+    # by hand from ``python3 -m pbench.names perfbench/fixtures/<name>``:
+    # tiny4 has no tf_op table; moe1 names the three dstpu.moe.*, dense1
+    # the four dstpu.mm.*. A CPU capture has no device plane: no busy time
+    want = {"tiny4": 100.0, "moe1": 100 * 442.503 / 557.690,
+            "dense1": 100 * 252.191 / 288.901}.get(case) \
+        if metric in UNNAMED_READERS else None
+    assert value == (pytest.approx(want, rel=1e-5) if want else None)
+    assert [line for line, _ in said] == (
+        ["names_device_seconds"] if trace is not None else [])
+    assert pb_common.load_module("layer_metrics", metric).read(
+        types.SimpleNamespace(trace=trace)) == value
+
+
+@pytest.mark.parametrize("metric", NAME_READERS + UNNAMED_READERS)
+def test_name_readers_on_the_recorded_trace(metric):
+    """The recorded values on ``fixtures/names1.xplane.pb`` (the two-layer
+    GPT-2 of ``dense1`` served by a split-fuse engine on the v5e,
+    ``fixtures/record_names.py``): the device's own ``tf_op`` table with the
+    phases, the paged read and write and the three paged kernels under the
+    names the program gave them, through the TPU compiler."""
+    want = pb_common.load_json("fixtures", "names1.expected.json")
+    said = []
+    view = _weights_view(_fixture_trace("names1"), want["sizes"], said)
+    value = pb_common.load_module("layer_metrics", metric).read(view)
+    assert value == pytest.approx(want["values"][metric], rel=1e-9)
+    assert 0.0 < value < 100.0
+    walked = pb_names.walk(view)
+    for table in ("by_scope", "by_kernel"):
+        assert walked[table] == pytest.approx(want["walk"][table], rel=1e-9)
+    assert set(walked["by_kernel"]) == {
+        pb_names.KERNEL + k for k in ("paged_decode", "paged_chunk",
+                                      "kv_write")} < set(KERNEL_SCHEMA)
+    assert {pb_names.ATTN_PAGED, pb_names.KV_WRITE, pb_names.STEP_CHUNK,
+            "dstpu.step.decode"} < set(walked["by_scope"])
+    # what is under no phase is what XLA made after the program was traced
+    # (copy-done, slice-done, a fusion of its own), and is unnamed with the
+    # operations under a phase alone; a kernel is under its read or write
+    steps = sum(s for k, s in walked["by_scope"].items()
+                if k.startswith(pb_names.STEP))
+    assert 0.75 * walked["busy_s"] < steps <= walked["busy_s"]
+    assert walked["busy_s"] - steps < walked["unnamed_s"]
+    assert walked["by_scope"][pb_names.KV_WRITE] \
+        >= walked["by_kernel"][pb_names.KERNEL + "kv_write"]
+    assert walked["by_scope"][pb_names.ATTN_PAGED] >= sum(
+        walked["by_kernel"][pb_names.KERNEL + k]
+        for k in ("paged_decode", "paged_chunk"))
+    assert {e.stats["kind"] for e in view.trace.host_spans(
+        "dstpu.engine.dispatch")} == {"chunk", "fused", "decode"}
+    # the readers of PR 38's names find theirs in it too
+    assert pb_weights.share(view) > 0
+
+
+def _named_device(monkeypatch, ops, t0=0.0, t1=None):
+    """One device whose operations, a second each, have the ``tf_op``s
+    ``ops``, in a window of [t0, t1) and with as many busy seconds."""
+    events = [pb_trace.Event(f"%fusion.{i} = f32[8] fusion(op {i})", i,
+                             i + 1.0, {}) for i in range(len(ops))]
+    monkeypatch.setattr(pb_moe, "op_scopes", lambda path, prefix: {
+        e.name: op for e, op in zip(events, ops) if op})
+    t1 = len(ops) if t1 is None else t1
+    return types.SimpleNamespace(
+        path="p", t0=t0, t1=t1, devices={"d0": events},
+        busy_s=lambda: float(t1 - t0), host_spans=lambda name: [],
+        in_window=lambda d: [e for e in events
+                             if e.end > t0 and e.start < t1])
+
+
+def test_names_walk_counts_every_name_wherever_it_stands(monkeypatch):
+    """The arithmetic of ``pbench/names.py`` on a made-up device: an
+    operation counts under every name of its ``tf_op`` (nesting), a
+    backward and a rematerialised operation under the names inside
+    ``transpose(jvp(...))`` and after ``rematted_computation/``, a kernel
+    under its ``dstpu.kernel.*`` too; under a ``dstpu.step.*`` alone, or
+    under nothing, it is unnamed; outside the window it is not there."""
+    ops = [
+        "jit(fused)/dstpu.step.chunk/dstpu.attn.full/dstpu.attn.paged/"
+        "dstpu.kernel.paged_chunk/pallas_call",
+        "jit(fused)/dstpu.step.chunk/dstpu.attn.full/dstpu.kv.write/"
+        "dstpu.kernel.kv_write/pallas_call",
+        "jit(fused)/dstpu.step.decode/dstpu.attn.full/dstpu.attn.paged/"
+        "dstpu.kernel.paged_decode/pallas_call",
+        "jit(fused)/dstpu.step.decode/dstpu.mm.mlp/dot_general",
+        "jit(fused)/dstpu.step.decode/argmax",             # the phase alone
+        None,                                              # no tf_op at all
+        "jit(train_step)/transpose(jvp(dstpu.attn.flash))/"
+        "dstpu.kernel.flash_bwd_t/pallas_call",
+        "jit(train_step)/checkpoint/rematted_computation/dstpu.attn.flash/"
+        "dstpu.kernel.flash_fwd_t/pallas_call",
+        "jit(train_step)/dstpu.optim.update/mul",
+        "jit(fused)/dstpu.step.chunk/dstpu.mm.qkv/dot_general",  # outside
+    ]
+    said = []
+    view = types.SimpleNamespace(
+        trace=_named_device(monkeypatch, ops, t1=9.0),
+        say=lambda line, **f: said.append((line, f)))
+    got = pb_names.walk(view)
+    assert got["busy_s"] == 9.0 and got["unnamed_s"] == 2.0
+    assert got["unnamed_ops"] == {"fusion": 2.0}
+    assert got["by_scope"] == {
+        "dstpu.step.chunk": 2.0, "dstpu.step.decode": 3.0,
+        "dstpu.attn.full": 3.0, "dstpu.attn.paged": 2.0,
+        "dstpu.kv.write": 1.0, "dstpu.mm.mlp": 1.0,
+        "dstpu.attn.flash": 2.0, "dstpu.optim.update": 1.0,
+        "dstpu.kernel.paged_chunk": 1.0, "dstpu.kernel.kv_write": 1.0,
+        "dstpu.kernel.paged_decode": 1.0, "dstpu.kernel.flash_bwd_t": 1.0,
+        "dstpu.kernel.flash_fwd_t": 1.0}
+    assert got["by_kernel"] == {k: s for k, s in got["by_scope"].items()
+                                if k.startswith("dstpu.kernel.")}
+    reads = {m: pb_common.load_module("layer_metrics", m).read(view)
+             for m in NAME_READERS + UNNAMED_READERS}
+    assert reads == {
+        "paged_attn_share": pytest.approx(100 * 2 / 9),
+        "kv_write_share": pytest.approx(100 * 1 / 9),
+        "paged_chunk_kernel_share": pytest.approx(100 * 1 / 9),
+        "chunk_phase_share": pytest.approx(100 * 2 / 9),
+        "unnamed_busy_share": pytest.approx(100 * 2 / 9),
+        "train_unnamed_busy_share": pytest.approx(100 * 2 / 9)}
+    assert [line for line, _ in said] == ["names_device_seconds"]  # one walk
+    assert pb_names.components(
+        "jit(f)/transpose(jvp(dstpu.attn.flash))/dstpu.kernel.flash_bwd/x") \
+        == ["dstpu.attn.flash", "dstpu.kernel.flash_bwd"]
+    assert pb_names.components(None) == [] == pb_names.components("jit(f)/x")
+
+
+class _Recorded:
+    """A jitted program that remembers the shapes of its first call."""
+
+    def __init__(self, fn):
+        self.fn, self.args = fn, None
+
+    def __call__(self, *args):
+        if self.args is None:
+            self.args = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+                if hasattr(x, "shape") else x, args)
+        return self.fn(*args)
+
+    def text(self):
+        return self.fn.lower(*self.args).as_text(debug_info=True)
+
+
+def test_served_programs_name_their_phases_reads_writes_and_kernels():
+    """A tiny GPT-2's plain ``decode`` and ``fused`` programs carry, in their
+    lowered text, a ``dstpu.step.decode`` a decode step and a
+    ``dstpu.step.chunk``, ``dstpu.attn.paged`` and ``dstpu.kv.write`` of
+    ``models/paged.py:_Step``, and the paged kernels under the names their
+    ``pallas_call`` passes (interpret mode keeps the scope)."""
+    kernel_dispatch.reset()
+    groups.reset()
+    model = GPT2(_CFG)
+    engine = InferenceEngineV2(
+        model, params=model.init(jax.random.key(0)),
+        config=dict(_BASE, splitfuse_tokens=16, paged_kernel=True))
+    engine._get_decode()
+    engine._get_splitfuse()
+    engine._decode_jit = _Recorded(engine._decode_jit)
+    engine._splitfuse_jit = _Recorded(engine._splitfuse_jit)
+    for n in (30, 20, 9):
+        engine.put(np.arange(1, n, dtype=np.int32), max_new_tokens=5)
+    while engine.has_work:
+        engine.step()
+    both = {"dstpu.attn.paged", "dstpu.kv.write", "dstpu.step.decode",
+            "dstpu.kernel.paged_decode", "dstpu.mm.qkv", "dstpu.mm.attn_out",
+            "dstpu.mm.mlp", "dstpu.mm.unembed"}
+    with jax.set_mesh(engine.mesh):
+        decode, text = engine._decode_jit.text(), engine._splitfuse_jit.text()
+    assert set(pb_names.COMPONENT.findall(decode)) == both
+    assert set(pb_names.COMPONENT.findall(text)) == both | {
+        "dstpu.step.chunk", "dstpu.kernel.paged_chunk"}
+    # outermost the phase, then the read, then the kernel; the sampling of
+    # a step is under its phase and nothing else
+    assert "jit(fused)/dstpu.step.chunk/dstpu.attn.paged/" \
+        "dstpu.kernel.paged_chunk/pallas_call" in text
+    assert "jit(fused)/dstpu.step.decode/dstpu.kv.write/" in text
+    paths = set(re.findall(r'"(jit\(fused\)/[^"]*)"', text))
+    assert all(pb_names.components(p)[0].startswith(pb_names.STEP)
+               for p in paths if pb_names.components(p))
+    for phase in ("dstpu.step.chunk", "dstpu.step.decode"):
+        assert any(pb_names.components(p) == [phase] for p in paths), phase
+
+
+def test_train_step_names_its_attention_its_update_and_its_kernels():
+    """A tiny GPT-2's training step: ``dstpu.attn.flash`` round the
+    attention between its products, forward, recomputed and backward, with
+    the flash kernels' own names inside it, and ``dstpu.optim.update``
+    round everything after the gradients."""
+    import deepspeed_tpu
+    groups.reset()
+    model = GPT2(GPT2Config(
+        n_layer=2, n_head=4, d_model=64, max_seq_len=64, vocab_size=256,
+        remat=True, dtype="float32", use_flash_attention=True))
+    engine, *_ = deepspeed_tpu.initialize(model=model, config={
+        "train_batch_size": 8,
+        "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+        "zero_optimization": {"stage": 2}})
+    batch = jax.tree.map(engine._add_gas_dim,
+                         {"input_ids": np.zeros((8, 64), np.int32)})
+    batch = engine._shard_batch(batch, with_gas_dim=True)
+    with jax.set_mesh(engine.mesh):
+        text = engine._train_step_jit.lower(
+            engine.state, batch, engine._current_lr(), None).as_text(
+                debug_info=True)
+    # a call inside a checkpoint or a custom_vjp has a location of its own:
+    # the text holds the inner path and the call site apart
+    paths = set(re.findall(r'"([^"]*dstpu\.[^"]*)"', text))
+    named = {n for p in paths for n in pb_names.components(p)}
+    assert {"dstpu.attn.flash", "dstpu.optim.update"} < named
+    kernels = {n for n in named if n.startswith(pb_names.KERNEL)}
+    assert kernels and kernels <= set(KERNEL_TALLY["flash"])
+    # forward, recomputed and backward (the flash kernels' custom_vjp keeps
+    # the scope its forward was traced under; on this eight-device mesh the
+    # call is a shard_map body, whose location is one more piece)
+    flash = [p for p in paths if "dstpu.attn.flash" in p]
+    assert any("rematted_computation/dstpu.attn.flash" in p for p in flash)
+    assert any(p.startswith("checkpoint/dstpu.attn.flash/") for p in flash)
+    assert any("_fwd" in n for n in kernels) \
+        and any("_bwd" in n for n in kernels)
+    # the update is after the gradients: under no scope of the model's
+    update = [p for p in paths if "dstpu.optim.update" in p]
+    assert update and all(pb_names.components(p) == ["dstpu.optim.update"]
+                          for p in update)
+
+
+def _decode_paths(model, B=2, MB=3, BS=8):
+    """The ``op_name`` paths of one decode step of ``model``, lowered from
+    shapes alone (nothing compiles)."""
+    import jax.numpy as jnp
+
+    def tree():
+        params = model.init(jax.random.key(0))
+        return model.serving_params(params) \
+            if hasattr(model, "serving_params") else params
+
+    slots = {"slots": B} if getattr(model, "slot_state", False) else {}
+    cache = jax.eval_shape(
+        lambda: model.init_paged_cache(1 + B * MB, BS, **slots))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    text = jax.jit(model.apply_paged_decode).lower(
+        jax.eval_shape(tree), i32(B), i32(B), cache,
+        i32(B, MB)).as_text(debug_info=True)
+    return sorted(set(re.findall(r'"(jit\([^"]*)"', text)))
+
+
+_NEW_NAME = re.compile(
+    r"dstpu\.(?:step|kernel|kv)\.[a-z0-9_]+/?|dstpu\.attn\.paged/?"
+    r"|dstpu\.attn\.flash/?|dstpu\.optim\.update/?")
+
+
+def _selections(monkeypatch, ops):
+    """What each reader module that selects by scope makes of a device
+    whose operations have the ``tf_op``s ``ops``."""
+    def view():
+        return types.SimpleNamespace(
+            trace=_named_device(monkeypatch, ops),
+            say=lambda line, **f: None)
+    walked = pb_weights.walk(view())
+    return {"moe": pb_moe.device_seconds(view()),
+            "ssm": pb_ssm.scope_seconds(view()),
+            "gdn": pb_gdn.scope_seconds(view()),
+            "dsa": pb_dsa.scope_seconds(view()),
+            "mla_moe": pb_mla_moe.scope_seconds(view()),
+            "weights": walked and (walked["mm_s"], walked["by_scope"])}
+
+
+@pytest.mark.parametrize("family", ["gpt2", "phi4flash", "olmo_hybrid",
+                                    "deepseek_v32", "olmoe"])
+def test_scope_selections_are_unchanged_by_the_new_names(monkeypatch, family):
+    """``pbench/moe.py``, ``ssm.py``, ``gdn.py``, ``dsa.py``, ``mla_moe.py``
+    and ``weights.py`` pick an operation's scope out of their own sets: with
+    ``dstpu.attn.paged`` / ``dstpu.kv.write`` nested inside a family's
+    ``dstpu.attn.*``, a ``dstpu.step.*`` outermost and a ``dstpu.kernel.*``
+    innermost, each gives every operation of a tiny model's decode step the
+    seconds it gave with the new names taken out again (PR 38's test of
+    ``ssm.py``, for all six and ISSUE 57's names)."""
+    import dataclasses
+    from deepspeed_tpu import models
+    cls, cfg = {
+        "gpt2": (GPT2, models.GPT2_TINY),
+        "phi4flash": (models.Phi4Flash, models.PHI4FLASH_TINY),
+        "olmo_hybrid": (models.OlmoHybrid, models.OLMO_HYBRID_TINY),
+        "deepseek_v32": (models.DeepseekV32, models.DEEPSEEK_V32_TINY),
+        "olmoe": (models.OLMoE, models.OLMOE_TINY)}[family]
+    groups.reset()
+    paths = _decode_paths(cls(dataclasses.replace(cfg, dtype="float32")))
+    # as the engine's program and a kernel's call would have them
+    nested = [p.replace("/", "/dstpu.step.decode/", 1)
+              + "/dstpu.kernel.paged_decode/pallas_call" for p in paths]
+    stripped = [_NEW_NAME.sub("", p) for p in nested]
+    if family != "deepseek_v32":        # a latent layer has its own scope
+        assert any("dstpu.attn.paged" in p for p in paths)
+        assert any("dstpu.kv.write" in p for p in paths)
+    assert not any(_NEW_NAME.search(p) for p in stripped)
+    assert _selections(monkeypatch, nested) \
+        == _selections(monkeypatch, stripped)
+    got = _selections(monkeypatch, nested)
+    assert got["weights"][0] > 0 and (got["moe"][0] > 0) == (
+        family in ("deepseek_v32", "olmoe"))
+
+
+# read by name, with no list of names, by pbench/names.py (ISSUE 57)
+_GENERAL_SCOPES = {"dstpu.attn.paged", "dstpu.kv.write", "dstpu.step.prefill",
+                   "dstpu.step.chunk", "dstpu.step.decode",
+                   "dstpu.attn.flash", "dstpu.optim.update"}
+
+
 def _opened(rx):
     found = set()
     pkg = os.path.join(REPO, "deepspeed_tpu")
@@ -1201,11 +1544,75 @@ def test_scope_schema_lint_both_directions():
     assert all(n.startswith("dstpu.") and m for n, m in SCOPE_SCHEMA.items())
     # the benchmark's readers look for the same names; dstpu.moe.spill lies
     # round route / experts / combine, which they count it under, and is
-    # read in a trace by hand (ISSUE 56)
+    # read in a trace by hand (ISSUE 56); the seven of ISSUE 57 are read by
+    # pbench/names.py, which keeps no list and so needs no edit for the next
     assert {pb_moe.SCOPE_EXPERTS, *pb_moe.SCOPES_ROUTE, *pb_ssm.SCOPES,
             *pb_gdn.SCOPES, *pb_dsa.SCOPES, *pb_mla_moe.SCOPES,
             *pb_weights.SCOPES} \
-        == set(SCOPE_SCHEMA) - {"dstpu.moe.spill"}
+        == set(SCOPE_SCHEMA) - {"dstpu.moe.spill"} - _GENERAL_SCOPES
+    assert {pb_names.ATTN_PAGED, pb_names.KV_WRITE, pb_names.STEP_CHUNK} \
+        < _GENERAL_SCOPES <= set(SCOPE_SCHEMA)
+    assert all(pb_names.components(f"jit(f)/jvp({n})/mul") == [n]
+               for n in (*SCOPE_SCHEMA, *KERNEL_SCHEMA))
+
+
+def _pallas_call_sites():
+    """[(file, line, the ``name=`` literal or None)] of every ``pallas_call(``
+    in the package's code: tokens, so that a docstring or a comment that
+    says the word is no site."""
+    import tokenize
+    sites = []
+    pkg = os.path.join(REPO, "deepspeed_tpu")
+    for dirpath, _, files in os.walk(pkg):
+        for n in files:
+            if not n.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, n)
+            with open(path, "rb") as f:
+                toks = [t for t in tokenize.tokenize(f.readline)
+                        if t.type not in (tokenize.NL, tokenize.NEWLINE,
+                                          tokenize.COMMENT, tokenize.INDENT,
+                                          tokenize.DEDENT)]
+            for i, t in enumerate(toks):
+                if not (t.type == tokenize.NAME and t.string == "pallas_call"
+                        and toks[i + 1].string == "("):
+                    continue
+                depth, name = 0, None
+                for j in range(i + 1, len(toks)):
+                    depth += (toks[j].string in "([{" and toks[j].type
+                              == tokenize.OP) - (toks[j].string in ")]}"
+                                                 and toks[j].type
+                                                 == tokenize.OP)
+                    if depth == 0:
+                        break
+                    if depth == 1 and toks[j].string == "name" \
+                            and toks[j + 1].string == "=" \
+                            and toks[j + 2].type == tokenize.STRING:
+                        name = toks[j + 2].string.strip("\"'")
+                sites.append((os.path.relpath(path, REPO), t.start[0], name))
+    return sites
+
+
+def test_kernel_schema_lint_three_ways():
+    """ISSUE 57, item 1: every ``pallas_call(`` under ``deepspeed_tpu/``
+    passes a registered ``name=``, no two sites share one, and every
+    registered name is at a site; the tally's words (``note_call``) are the
+    ones the engines count under, and name registered kernels."""
+    sites = _pallas_call_sites()
+    assert len(sites) >= 30
+    unnamed = [(f, line) for f, line, name in sites if name is None]
+    assert not unnamed, f"pallas_call sites without name=: {unnamed}"
+    at = [name for _, _, name in sites]
+    assert sorted(at) == sorted(set(at)), "a kernel name at two sites"
+    assert set(at) - set(KERNEL_SCHEMA) == set(), "names not in KERNEL_SCHEMA"
+    assert set(KERNEL_SCHEMA) - set(at) == set(), "registered, at no site"
+    assert len(sites) == len(KERNEL_SCHEMA)
+    assert all(n.startswith(pb_names.KERNEL) and m
+               for n, m in KERNEL_SCHEMA.items())
+    assert set(KERNEL_TALLY) == set(KERNEL_SHARES) | set(SHAPE_PATHS)
+    listed = [n for names in KERNEL_TALLY.values() for n in names]
+    assert len(listed) == len(set(listed)) and set(listed) < set(KERNEL_SCHEMA)
+    assert pb_names.PAGED_CHUNK_KERNEL in KERNEL_SCHEMA
 
 
 def test_span_schema_lint_both_directions():

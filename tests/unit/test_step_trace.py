@@ -79,15 +79,18 @@ class TestClassifiers:
             "gather/scatter/DUS"
         assert family_of("parameter.0") == "other"
 
-    def test_kernel_op_hints_name_registry_ops(self):
-        from deepspeed_tpu.autotuning.kernel_registry import REGISTRY
-        for op, _ in step_trace.KERNEL_OP_HINTS:
-            assert op in REGISTRY, (
-                f"KERNEL_OP_HINTS names {op!r} which is not a "
-                f"registered tunable op")
-        assert kernel_op_for("flash_attention_fwd_kernel") == \
-            "flash_attention"
-        assert kernel_op_for("gmm_kernel call") == "moe_grouped_mm"
+    def test_kernel_op_is_the_registered_name(self):
+        from deepspeed_tpu.monitor.tag_schema import KERNEL_SCHEMA
+        for name in KERNEL_SCHEMA:
+            op = name[len("dstpu.kernel."):]
+            assert kernel_op_for(
+                f"custom-call.7 jit(f)/jvp(dstpu.attn.flash)/{name}/"
+                f"pallas_call") == op
+        assert kernel_op_for("dstpu.kernel.flash_fwd_t") == "flash_fwd_t"
+        assert kernel_op_for("custom-call.9 dstpu.kernel.gmm") == "gmm"
+        # a name nobody registered, and what the old hints guessed from
+        assert kernel_op_for("dstpu.kernel.nobodys") is None
+        assert kernel_op_for("flash_attention_fwd_kernel") is None
         assert kernel_op_for("plain_matmul") is None
 
 
@@ -215,17 +218,19 @@ class TestHostCopies:
 
 # ---------------------------------------------------------------- kernels
 class TestKernels:
-    def test_pallas_time_keyed_by_registry_op(self):
+    def test_pallas_time_keyed_by_kernel_name(self):
         events = device_meta() + [
             ev("custom-call.7", 0, 80,
-               long_name="custom-call.7 flash_attention_fwd_kernel"),
+               long_name="custom-call.7 jit(train_step)/"
+               "dstpu.attn.flash/dstpu.kernel.flash_fwd_t/pallas_call"),
             ev("custom-call.9", 100, 20,
-               long_name="custom-call.9 gmm_kernel"),
+               long_name="custom-call.9 dstpu.moe.experts/"
+               "dstpu.kernel.gmm/pallas_call"),
         ]
         d = decompose(events, steps=1)
         assert d.kernels == {
-            "flash_attention": pytest.approx(0.080),
-            "moe_grouped_mm": pytest.approx(0.020)}
+            "flash_fwd_t": pytest.approx(0.080),
+            "gmm": pytest.approx(0.020)}
         # kernel time is still compute (a breakdown, not a new term)
         assert d.terms["compute"] == pytest.approx(0.100)
 
