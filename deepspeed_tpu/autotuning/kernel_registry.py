@@ -795,19 +795,25 @@ def _pgc_defaults(b):
 
 
 def _pgc_candidates(b):
-    """Kernel-vs-dense plus the chunk-token tile sweep. Sweep entries
-    carry the CLAMPED tile (min(bc, C) — what the wrapper executes), so
-    two nominal tiles that clamp to one program are never both timed
-    and the cached winner records the tile that actually ran."""
-    from ..ops.pallas.paged_attention import PAGED_CHUNK_BLOCK_C
-    C = b["C"]
+    """Kernel-vs-dense plus the chunk-token tile sweep. ``block_c`` 0 is
+    the tile the kernel sizes from the shapes (``chunk_tile``: the cold
+    default); a sweep entry pins the tokens a query tile, and the kernel
+    sizes the KV heads and table entries of a step round it. Sweep
+    entries carry the CLAMPED tile (min(bc, C) — what the wrapper
+    executes) and only tiles the kernel accepts: a tile below the whole
+    chunk holds whole sublane tiles of folded rows (tokens x G a multiple
+    of 8). Two nominal tiles that clamp to one program,
+    or that the shape rule already gives, are never both timed, and the
+    cached winner records the tile that actually ran."""
+    from ..ops.pallas.paged_attention import chunk_tile
+    C, G = b["C"], b["g"]
     d = _pgc_defaults(b)
-    cands = [d, {"mode": "dense", "block_c": PAGED_CHUNK_BLOCK_C}]
-    eff_seen = {min(int(d["block_c"]), C)} if d["mode"] == "kernel" \
-        else set()
-    for bc in (64, 128, 256):
+    cands = [d, {"mode": "dense", "block_c": 0}]
+    eff_seen = {chunk_tile(C, b["kh"], G, b["d"], b["BS"], b["MB"],
+                           "bfloat16").block_c}
+    for bc in (64, 128, 256, 512):
         eff = min(bc, C)
-        if eff not in eff_seen:
+        if eff not in eff_seen and (eff == C or eff * G % 8 == 0):
             eff_seen.add(eff)
             cands.append({"mode": "kernel", "block_c": eff})
     return _dedup(cands)

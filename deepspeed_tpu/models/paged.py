@@ -76,16 +76,14 @@ import numpy as np
 from ..ops.pallas._common import note_call
 from ..ops.pallas.latent_attention import latent_chunk_attention
 from ..ops.pallas.paged_attention import (
-    PAGED_CHUNK_BLOCK_C, alibi_slopes, decode_entries_per_step,
-    decode_grid_steps, decode_work_list, kv_write_live_rows,
-    kv_write_row_list, paged_chunk_attention, paged_decode_attention,
-    paged_decode_attention_reference, paged_kv_write, resolve_paged_chunk,
-    resolve_paged_decode)
+    alibi_slopes, chunk_grid_steps, chunk_tile, chunk_work_list,
+    decode_entries_per_step, decode_grid_steps, decode_work_list,
+    kv_write_live_rows, kv_write_row_list, paged_chunk_attention,
+    paged_decode_attention, paged_decode_attention_reference, paged_kv_write,
+    resolve_paged_chunk, resolve_paged_decode)
 
 
 KV, RING, SHARED, STATE, LATENT = "kv", "ring", "shared", "state", "latent"
-# KV heads x rows x lanes of the chunk kernel's largest "auto" tile
-_CHUNK_TILE = 16 * 128 * 128
 # cache keys of each kind's leaves, one list entry a layer of that kind
 _KEYS = {KV: ("k", "v"), RING: ("ring_k", "ring_v"), STATE: ("conv", "ssm"),
          LATENT: ("lat", "idx")}
@@ -178,6 +176,12 @@ def uses_decode_kernel(model, B, MB, BS, dtype):
 
 
 def _chunk_kernel(geom, C, MB, BS):
+    """(whether a step of C queries a sequence reads through
+    ``paged_chunk_attention``, the :class:`ChunkTile` its calls take): the
+    answer the chunk trace takes, and the one ``Account`` counts grid
+    steps by. The engine's ``paged_block_c`` (or a measured winner) pins
+    the tokens a query tile; left "auto" the whole tile is read off the
+    shapes (``paged_attention.chunk_tile``)."""
     # ALiBi stays dense: the chunk kernel has no per-head bias input
     # (forced off BEFORE dispatch, so no search is paid for a tile the
     # model can never use)
@@ -187,21 +191,17 @@ def _chunk_kernel(geom, C, MB, BS):
     use, block_c = resolve_paged_chunk(
         False if geom.alibi else geom.kernel, geom.block_c, C, MB, BS,
         geom.n_kv_heads, G, geom.d_head, geom.dtype)
-    if geom.block_c == "auto":
-        # block_c counts tokens, and the kernel folds a KV head's G query
-        # heads into the tile's rows. The tile's q, out and three float32
-        # accumulators are KV heads x rows x lanes each: keep them to the
-        # largest the served shapes compile with, 16 heads x 128 rows x
-        # 128 lanes, in rows of a power of two (30 x 128 x 128 run out of
-        # VMEM as 32 heads of 64 do: sandbox compile for a v5e, PR 41),
-        # and under GQA to the rows a head that G = 1 gives it (10 heads x
-        # 512 rows x 128 lanes: PR 30)
-        rows = 2 ** int(math.log2(max(1, _CHUNK_TILE // (
-            geom.n_kv_heads * max(geom.d_head, 128)))))
-        if G > 1:
-            rows = min(rows, PAGED_CHUNK_BLOCK_C)
-        block_c = max(8, min(block_c, rows // G))
-    return use, block_c
+    return use, chunk_tile(C, geom.n_kv_heads, G, geom.d_head, BS, MB,
+                           geom.dtype, block_c)
+
+
+def _paged_windows(geom):
+    """{window: the layers that read a paged table with it} (not a layer
+    of recurrent state or a latent, nor one with no cache): the kernels'
+    work lists are one a window."""
+    return dict(Counter(
+        w for w, kind in zip(geom.windows, geom.kinds)
+        if kind in (KV, RING) or isinstance(kind, tuple)))
 
 
 def _latent_kernel(geom, C):
@@ -469,7 +469,11 @@ def chunk_step(geom, cache, token_blocks, token_offsets, start, true_len,
     ``start = 0``, and stops at token ``true_len - 1``."""
     C, MB = token_blocks.shape[0], table.shape[0]
     BS = block_size(cache)
-    use_kernel, block_c = _chunk_kernel(geom, C, MB, BS)
+    use_kernel, tile = _chunk_kernel(geom, C, MB, BS)
+    # the chunk kernel's grid: the runs of live blocks a query tile, one
+    # list per window size, shared by every layer that has it
+    work = {w: chunk_work_list(start, true_len, C, MB, BS, w, tile)
+            for w in _paged_windows(geom)} if use_kernel else {}
     tables, dest = {KV: table}, {KV: (token_blocks, token_offsets)}
     if RING in geom.kinds:
         ring = _ring_table(geom, jnp.reshape(slot, (1,)), MB)[0]
@@ -484,7 +488,7 @@ def chunk_step(geom, cache, token_blocks, token_offsets, start, true_len,
             # VMEM once, located via the table; GQA-native
             return paged_chunk_attention(
                 q[0], kc, vc, table, start, true_len, scale=geom.scale,
-                window=window, block_c=block_c)[None]
+                window=window, work=work[window])[None]
         return _dense_attention(
             geom, q, kc[table][None], vc[table][None],
             (start + jnp.arange(C))[None],
@@ -555,16 +559,19 @@ def batch_step(geom, cache, lengths, block_tables, C):
                 q[:, 0], kc, vc, tables, lengths, scale=geom.scale,
                 window=window)[:, None]
     else:
-        use_kernel, block_c = _chunk_kernel(geom, C, MB, BS)
+        use_kernel, tile = _chunk_kernel(geom, C, MB, BS)
+        # the batched split-fuse ride: each slot's span is a chunk with
+        # start = lengths[b], true_len = C, and a work list of its own
+        work = {(w, b): chunk_work_list(lengths[b], C, C, MB, BS, w, tile)
+                for w in _paged_windows(geom) for b in range(B)} \
+            if use_kernel else {}
 
         def attend(q, kc, vc, window, tables):
             if use_kernel:
-                # the batched split-fuse ride: each slot's span is a
-                # chunk with start = lengths[b], true_len = C
                 return jnp.stack([paged_chunk_attention(
                     q[b], kc, vc, tables[b], lengths[b],
                     jnp.int32(C), scale=geom.scale, window=window,
-                    block_c=block_c) for b in range(B)])
+                    work=work[window, b]) for b in range(B)])
             return _dense_attention(
                 geom, q, kc[tables], vc[tables], linpos, lengths + C,
                 window)
@@ -642,6 +649,7 @@ class Account:
 
     def __init__(self, model, slots, table_len, block_size, dtype):
         geom = geometry(model)
+        self._model = model
         self.slots, self.table_len, self.block_size = \
             slots, table_len, block_size
         # layers of each kind (a ``(SHARED, j)`` layer under ``SHARED``)
@@ -651,11 +659,13 @@ class Account:
         # that window} (layers with a paged table: not one of recurrent
         # state or a latent, nor one with no cache), and the table entries
         # of a slot one grid step of it takes
-        self._windows = dict(Counter(
-            w for w, kind in zip(geom.windows, geom.kinds)
-            if kind in (KV, RING) or isinstance(kind, tuple)))
+        self._windows = _paged_windows(geom)
         self._entries_per_step = decode_entries_per_step(
             geom.n_kv_heads, block_size, geom.d_head, dtype, table_len)
+        # the chunk kernel's tile, by the rows of a chunk program: asked
+        # at the first dispatch that has a chunk, when the engine has told
+        # the model its ``paged_block_c``
+        self._kv_heads, self._chunk_tiles = geom.n_kv_heads, {}
         # keys a query of a LATENT layer attends at most
         self._topk = model.config.index_topk if self.layers[LATENT] else 0
         self._kinds = [name for name, there in (
@@ -702,6 +712,25 @@ class Account:
         return (int(ctx.sum()) * layers,
                 int(np.minimum(ctx, self._topk).sum()) * layers)
 
+    def _chunk_grid(self, start, tokens, rows):
+        """(grid steps, query tiles x table entries) of one paged chunk
+        kernel call of a chunk of ``tokens`` real tokens in ``rows`` from
+        position ``start``, the layers' mean where their windows differ:
+        what the call's work list gives it, and the rectangle a call
+        walked before it had one. (0, 0) with no chunk or no such layer."""
+        if not rows or not self._windows:
+            return 0, 0
+        MB, BS = self.table_len, self.block_size
+        if rows not in self._chunk_tiles:
+            self._chunk_tiles[rows] = _chunk_kernel(
+                geometry(self._model), rows, MB, BS)[1]
+        tile = self._chunk_tiles[rows]
+        taken = sum(layers * chunk_grid_steps(
+            start, tokens, rows, self._kv_heads, MB, BS, w, tile)
+            for w, layers in self._windows.items())
+        return (round(taken / sum(self._windows.values())),
+                -(-rows // tile.block_c) * MB)
+
     def dispatch(self, lengths, tables, active, steps, chunk_start=0,
                  chunk_tokens=0, chunk_rows=0):
         """The stats of a ``dstpu.engine.dispatch`` span that are the
@@ -712,6 +741,8 @@ class Account:
         and a chunk's ``chunk_tokens`` real tokens of ``chunk_rows`` from
         position ``chunk_start``."""
         grid_steps = kernel_steps = table_entries = 0
+        chunk_grid, chunk_table = self._chunk_grid(
+            chunk_start, chunk_tokens, chunk_rows)
         write_rows, write_rows_offered = chunk_tokens, chunk_rows
         index_keys, attended_keys = self._selected_read(
             chunk_start, chunk_tokens)
@@ -741,7 +772,8 @@ class Account:
         state = self.layers[STATE]
         return dict(
             grid_steps=grid_steps, table_entries=table_entries,
-            kernel_steps=kernel_steps, write_rows=write_rows,
+            kernel_steps=kernel_steps, chunk_grid_steps=chunk_grid,
+            chunk_table_steps=chunk_table, write_rows=write_rows,
             write_rows_offered=write_rows_offered,
             state_updates=int(np.sum(active)) * steps * state if state
             else 0,

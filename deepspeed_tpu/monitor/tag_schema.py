@@ -231,7 +231,8 @@ _TALLY_STATS = tuple(f"{name}{stat}" for name in KERNEL_SHARES
 # what ``models/paged.py``'s ``Account`` counts of a program call over the
 # model's cache: of a bucketed prefill, and of a dispatch
 _ACCOUNT_PREFILL_STATS = ("rule_rows", "index_keys", "attended_keys")
-_ACCOUNT_STATS = ("grid_steps", "table_entries", "kernel_steps", "write_rows",
+_ACCOUNT_STATS = ("grid_steps", "table_entries", "kernel_steps",
+                  "chunk_grid_steps", "chunk_table_steps", "write_rows",
                   "write_rows_offered", "state_updates") \
     + _ACCOUNT_PREFILL_STATS
 
@@ -298,6 +299,13 @@ SPAN_SCHEMA = {
                    "decode steps, and kernel_steps = the grid steps it "
                    "takes them in, several entries of a slot a step (a "
                    "grid step took one entry when grid_steps was named); "
+                   "chunk_grid_steps of chunk_table_steps = the grid steps "
+                   "one paged-chunk kernel call of the dispatch's chunk "
+                   "takes (its work list's items x the blocks of KV heads: "
+                   "runs of table entries that hold a key some query of "
+                   "the tile attends) against query tiles x table entries, "
+                   "the rectangle a call walked before it had a work list "
+                   "(0 / 0 with no chunk); "
                    "write_rows of write_rows_offered = the "
                    "live rows (destination not scratch block 0) among "
                    "those one layer's KV writes are handed: slots x "

@@ -51,6 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._common import interpret_default as _interpret_default
+from .flash_attention import _lanes, _vmem_params
 
 NEG_INF = -1e30
 
@@ -63,8 +64,9 @@ NEG_INF = -1e30
 #                 'dense' elsewhere — emulating the blocked stream in the
 #                 Pallas interpreter is slower than one dense gather on
 #                 CPU, and the dense path is the proven parity fallback
+#                 block_c: 0 = the query tile is sized from the call's
+#                 shapes (chunk_tile); a measured winner names its tokens
 PAGED_DECODE_DEFAULTS = {"mode": "kernel"}
-PAGED_CHUNK_BLOCK_C = 128
 
 
 def paged_chunk_tune_defaults():
@@ -72,8 +74,7 @@ def paged_chunk_tune_defaults():
     is backend-dependent; the winner cache is keyed by device_kind, so
     the split can never leak across chips)."""
     on_tpu = jax.default_backend() == "tpu"
-    return {"mode": "kernel" if on_tpu else "dense",
-            "block_c": PAGED_CHUNK_BLOCK_C}
+    return {"mode": "kernel" if on_tpu else "dense", "block_c": 0}
 
 
 def resolve_paged_decode(setting, B, MB, BS, KVH, G, d, dtype):
@@ -99,7 +100,9 @@ def resolve_paged_chunk(setting, block_c, C, MB, BS, KVH, G, d, dtype):
     e.g. ALiBi models); ``block_c``: "auto" | int (engine
     ``paged_block_c``). "auto" fields resolve against the winner cache
     for this chunk-shape bucket; cold-cache defaults come from
-    :func:`paged_chunk_tune_defaults`. Returns (use_kernel, block_c).
+    :func:`paged_chunk_tune_defaults`. Returns (use_kernel, block_c),
+    ``block_c`` 0 where nothing names a tile: :func:`chunk_tile` then
+    sizes it from the call's shapes.
 
     The dispatch (which may run a measured search under
     on_first_use/search) is only consulted when its answer can matter
@@ -107,8 +110,7 @@ def resolve_paged_chunk(setting, block_c, C, MB, BS, KVH, G, d, dtype):
     discard."""
     use = None if setting == "auto" else bool(setting)
     if use is False:
-        return False, (PAGED_CHUNK_BLOCK_C if block_c == "auto"
-                       else int(block_c))
+        return False, (0 if block_c == "auto" else int(block_c))
     win = None
     if use is None or block_c == "auto":
         from ._common import dispatch, dtype_name, paged_chunk_bucket
@@ -736,47 +738,170 @@ def like_boundary(pools, cache):
 
 # ------------------------------------------------- chunked-prefill kernel
 
+# A grid step of the chunk kernel is one query tile of a block of KV heads
+# against a run of consecutive table entries, each sized from what it moves
+# (the kernel alone on a v5e at the served shapes,
+# benchmarks/paged_chunk_sweep.py: PERF.md, PR 58). A head's score tile is
+# rows (tokens x the G query heads a KV head folds) x keys in float32:
+# _CHUNK_ROWS x _CHUNK_KEYS of it is the vector registers' 256 KB, and a
+# tile that stays in them beat every larger one under MHA (30 heads of 128
+# at 8 k: 2.2 ms at 128 rows, 2.7 at 512, 3.6 at 1,024). The K/V of a run is
+# read once a query tile whatever the tile holds, so a tile is never under
+# _CHUNK_TOKENS tokens (G = 8: 512 rows a head). Heads then fill the step
+# up to _CHUNK_TILE_BYTES of q: their products are independent, which lets
+# one head's MXU work run beside another's exponentials (2 x 512 rows beat
+# 1 x 1,024 by a quarter at every context). A run's entries are blocks the
+# pipeline brings, K and V, an operand of the call each: _CHUNK_ENTRIES at
+# most.
+_CHUNK_ROWS = 128
+_CHUNK_KEYS = 512
+_CHUNK_TOKENS = 64
+_CHUNK_TILE_BYTES = 256 << 10
+_CHUNK_ENTRIES = 8
 
-def _chunk_kernel(tbl_ref, meta_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, BS, KVH, G, BC, scale,
-                  window):
-    """One (q-tile, table-entry) grid step of the SplitFuse chunk
-    program: q tile i (BC chunk tokens x G query heads per kv head,
-    folded rows) against the KV block the table's j-th entry names.
-    Causal masking is structural: a block entirely before the tile's
-    first query (and inside the valid-key range) takes the mask-free
-    fast path; only diagonal/limit-straddling blocks build the
+
+class ChunkTile(NamedTuple):
+    """A grid step of :func:`paged_chunk_attention`: ``block_c`` chunk
+    tokens x ``heads`` KV heads against ``entries`` table entries."""
+    block_c: int
+    heads: int
+    entries: int
+
+
+def chunk_tile(C, KVH, G, d, BS, MB, dtype, block_c=0):
+    """The :class:`ChunkTile` of a C-token chunk of KVH x G heads of d
+    over a table of MB blocks of BS tokens: read off the call's shapes and
+    nothing else. ``block_c`` > 0 pins the tokens a query tile (the
+    engine's ``paged_block_c``, an autotune winner); the heads and the
+    entries follow it."""
+    if block_c:
+        BC = max(1, min(int(block_c), C))
+    else:
+        BC = min(C, max(_CHUNK_TOKENS, _CHUNK_ROWS // G))
+    rows = _CHUNK_TILE_BYTES // (_lanes(d) * jnp.dtype(dtype).itemsize)
+    heads = max(h for h in range(1, KVH + 1)
+                if KVH % h == 0 and (h == 1 or h * BC * G <= rows))
+    return ChunkTile(BC, heads,
+                     max(1, min(_CHUNK_KEYS // BS, _CHUNK_ENTRIES, MB)))
+
+
+def _chunk_attended(xp, start, true_len, C, MB, BS, window, tile):
+    """(first entry, grid steps) of each query tile of a chunk, in ``xp``
+    (jax.numpy on the device, numpy on the host): tile i holds positions
+    ``q_lo .. q_hi`` = ``start + i * BC ..`` and attends table entries
+    ``max(0, q_lo - window + 1) // BS .. min(q_hi, limit - 1) // BS``,
+    ``entries`` of them a step. A tile of pads alone (``q_lo >= limit``)
+    takes one step, so that its rows come out finite."""
+    BC, _, N = tile
+    q_lo = start + xp.arange(-(-C // BC)) * BC
+    limit = start + true_len
+    last = xp.minimum(q_lo + BC - 1, limit - 1) // BS
+    first = xp.maximum(q_lo - window + 1, 0) // BS if window \
+        else xp.zeros_like(last)
+    first = xp.clip(first, 0, MB - 1)
+    steps = xp.where(q_lo < limit,
+                     (xp.clip(last, first, MB - 1) - first + N) // N, 1)
+    return first, steps
+
+
+def chunk_grid_steps(start, true_len, C, KVH, MB, BS, window, tile):
+    """The grid steps one :func:`paged_chunk_attention` call takes, on the
+    host in numpy: :func:`chunk_work_list`'s ``n`` times the blocks of KV
+    heads. What the engine's telemetry sets against the query tiles x table
+    entries a call took before the work list."""
+    _, steps = _chunk_attended(np, int(start), int(true_len), C, MB, BS,
+                               window, tile)
+    return int(np.sum(steps)) * (KVH // tile.heads)
+
+
+class ChunkWork(NamedTuple):
+    """:func:`chunk_work_list`'s items: two ``int32[items + 1]`` arrays,
+    the items' count ``n`` on the device, and the static tile they were
+    cut by."""
+    tile_of: jax.Array
+    entry_of: jax.Array
+    n: jax.Array
+    tile: ChunkTile
+
+
+def chunk_work_list(start, true_len, C, MB, BS, window, tile):
+    """The chunk kernel's grid, as data: item i is query tile
+    ``tile_of[i]`` against the ``tile.entries`` table entries from
+    ``entry_of[i]`` on, for every run of entries that holds a key some
+    query of the tile attends (:func:`_chunk_attended`). Tile-major, a
+    tile's items consecutive; from item ``n`` on ``tile_of`` reads the
+    tiles' count, so a tile's last item is the one whose successor names
+    another tile.
+
+    A handful of small integer operations (compares and sums, as
+    :func:`decode_work_list`): compute it once a chunk program and hand it
+    to every layer's call (layers with another ``window`` take a list of
+    their own)."""
+    N = tile.entries
+    first, steps = _chunk_attended(
+        jnp, jnp.asarray(start, jnp.int32), jnp.asarray(true_len, jnp.int32),
+        C, MB, BS, window, tile)
+    NC = first.shape[0]
+    ends = jnp.cumsum(steps)
+    item = jnp.arange(NC * -(-MB // N) + 1, dtype=jnp.int32)
+    tile_of = jnp.sum(ends[None, :] <= item[:, None], axis=1,
+                      dtype=jnp.int32)
+    mine = tile_of[:, None] == jnp.arange(NC, dtype=jnp.int32)[None, :]
+    # a tile's k-th item starts at entry first + k * N
+    entry_of = N * item + jnp.sum(
+        jnp.where(mine, (first - N * (ends - steps))[None, :], 0), axis=1,
+        dtype=jnp.int32)
+    return ChunkWork(tile_of, jnp.clip(entry_of, 0, MB - 1),
+                     ends[-1].astype(jnp.int32), tile)
+
+
+def _chunk_kernel(tbl_ref, meta_ref, tile_ref, entry_ref, q_ref, *refs, N,
+                  BS, G, BC, scale, window):
+    """Grid step (h, i) is item i of the work list for block h of the KV
+    heads: query tile ``tile_ref[i]`` (BC chunk tokens x G query heads a
+    KV head, folded rows) against the N KV blocks the table names from
+    entry ``entry_ref[i]`` on, as one run of ``N * BS`` keys (entries past
+    the tile's last are past every query or the frontier: masked). A
+    tile's items are consecutive, so its q and o tiles stay resident from
+    its first item to its last, and the output is divided and stored at
+    the last. Causal masking is structural: a run entirely before the
+    tile's first query (and inside the valid-key range) takes the
+    mask-free fast path; only diagonal/limit-straddling runs build the
     per-element mask."""
-    i = pl.program_id(0)
-    j = pl.program_id(1)
+    k_refs, v_refs = refs[:N], refs[N:2 * N]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * N:]
+    i = pl.program_id(1)
+    tile, j = tile_ref[i], entry_ref[i]
     start = meta_ref[0]
     limit = meta_ref[0] + meta_ref[1]            # keys < limit are real
+    S = N * BS
 
-    @pl.when(j == 0)
+    @pl.when((i == 0) | (tile_ref[jnp.maximum(i - 1, 0)] != tile))
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_lo = start + i * BC                        # tile's first q position
+    q_lo = start + tile * BC                     # tile's first q position
     q_hi = q_lo + BC - 1                         # tile's last q position
     k_lo = j * BS
-    k_hi = k_lo + BS - 1
-    # block liveness (mirrors the KV index map EXACTLY — a clamped
-    # block must never be computed on): some key is real and causally
-    # visible to some query of the tile
-    live = (k_lo < limit) & (k_lo <= q_hi)
-    if window:
-        live = live & (k_hi > q_lo - window)
+    k_hi = k_lo + S - 1
     # mask-free fast path: every key visible to every query
     full = (k_hi <= q_lo) & (k_hi < limit)
     if window:
         full = full & (k_lo > q_hi - window)
 
-    def _accumulate(s, vb):
+    def _run(blocks):
+        """The run's N blocks as one (heads, S, d) array."""
+        if N == 1:
+            return blocks[0][0]
+        return jnp.concatenate([b[0] for b in blocks], axis=1)
+
+    def _accumulate(s):
         """Online-softmax state update from scaled+masked scores
-        s (KVH, BC*G, BS) fp32."""
-        m_prev = m_ref[..., 0]                   # (KVH, BC*G)
+        s (heads, BC*G, S) fp32."""
+        vb = _run(v_refs)
+        m_prev = m_ref[..., 0]                   # (heads, BC*G)
         l_prev = l_ref[..., 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
         alpha = jnp.exp(m_prev - m_new)
@@ -784,39 +909,91 @@ def _chunk_kernel(tbl_ref, meta_ref, q_ref, k_ref, v_ref, o_ref,
         l_new = l_prev * alpha + jnp.sum(p, axis=-1)
         pv = jax.lax.dot_general(
             p.astype(vb.dtype), vb, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)  # (KVH, BC*G, d)
+            preferred_element_type=jnp.float32)  # (heads, BC*G, d)
         acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
         m_ref[...] = jnp.broadcast_to(m_new[..., None], m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new[..., None], l_ref.shape)
 
     def _scores():
-        kb = k_ref[0]                            # (KVH, BS, d)
         return jax.lax.dot_general(
-            q_ref[...], kb, (((2,), (2,)), ((0,), (0,))),
+            q_ref[...], _run(k_refs), (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale
 
-    @pl.when(live & full)
-    def _full_block():
-        _accumulate(_scores(), v_ref[0])
+    @pl.when(full)
+    def _full_run():
+        _accumulate(_scores())
 
-    @pl.when(live & jnp.logical_not(full))
-    def _masked_block():
+    @pl.when(jnp.logical_not(full))
+    def _masked_run():
         s = _scores()
-        shape = s.shape                          # (KVH, BC*G, BS)
+        rows = s.shape[1]                        # BC*G
         # row r of the folded q dim is chunk token r // G
-        qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 1) // G
-        kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+        qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // G
+        kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
         ok = (kpos <= qpos) & (kpos < limit)
         if window:
             ok = ok & (kpos > qpos - window)
-        _accumulate(jnp.where(ok, s, NEG_INF), v_ref[0])
+        _accumulate(jnp.where(ok[None], s, NEG_INF))
 
-    l = jnp.maximum(l_ref[..., 0], 1e-30)
-    o_ref[...] = (acc_ref[...] / l[..., None]).astype(o_ref.dtype)
+    @pl.when(tile_ref[i + 1] != tile)
+    def _store():
+        l = jnp.maximum(l_ref[..., 0], 1e-30)
+        o_ref[...] = (acc_ref[...] / l[..., None]).astype(o_ref.dtype)
+
+
+def _chunk_call(table, meta, tile_of, entry_of, n, qf, kp, vp, *, tile, G,
+                scale, window, interpret):
+    """The chunk kernel's ``pallas_call`` over the folded queries ``qf``
+    (KVH, rows, d): the grid is (blocks of KV heads, the work list's
+    ``n`` items)."""
+    BC, HB, N = tile
+    KVH, rows, d = qf.shape
+    BS, MB = kp.shape[2], table.shape[0]
+    NC = rows // (BC * G)
+    R = BC * G
+
+    # the pipeline may look one item ahead of the last, where ``tile_of``
+    # reads NC: keep every index it can form inside its array
+    def qo_index(h, i, tbl, meta, tile_of, entry_of):
+        return (h, jnp.minimum(tile_of[i], NC - 1), 0)
+
+    def kv_index(t):
+        def index(h, i, tbl, meta, tile_of, entry_of):
+            return (tbl[jnp.minimum(entry_of[i] + t, MB - 1)], h, 0, 0)
+        return index
+
+    kv_specs = [pl.BlockSpec((1, HB, BS, d), kv_index(t)) for t in range(N)]
+    isz, lanes = qf.dtype.itemsize, _lanes(d)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(KVH // HB, n),
+        in_specs=[pl.BlockSpec((HB, R, d), qo_index)] + kv_specs + kv_specs,
+        out_specs=pl.BlockSpec((HB, R, d), qo_index),
+        scratch_shapes=[
+            pltpu.VMEM((HB, R, 128), jnp.float32),        # running max
+            pltpu.VMEM((HB, R, 128), jnp.float32),        # running denom
+            pltpu.VMEM((HB, R, d), jnp.float32),          # out accumulator
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_chunk_kernel, N=N, BS=BS, G=G, BC=BC,
+                          scale=scale, window=window),
+        name="dstpu.kernel.paged_chunk",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qf.shape, qf.dtype),
+        interpret=interpret,
+        # q and out tiles, the run's K and V, then the float32 state and
+        # the score tile with its exponentials, counted as half a buffer
+        # pair each
+        **_vmem_params(*(2 * [HB * R * lanes * isz]
+                         + 2 * [HB * N * BS * lanes * kp.dtype.itemsize]
+                         + [HB * R * (lanes + 256) * 2]
+                         + [HB * R * max(N * BS, 128) * 6])),
+    )(table, meta, tile_of, entry_of, qf, *([kp] * N), *([vp] * N))
 
 
 def paged_chunk_attention(q, k_cache, v_cache, table, start, true_len, *,
-                          scale=None, window=0, block_c="auto",
+                          scale=None, window=0, block_c="auto", work=None,
                           interpret=None):
     """A C-token query chunk of ONE sequence attends over that
     sequence's paged KV blocks — the blocked-flash role of the
@@ -829,19 +1006,22 @@ def paged_chunk_attention(q, k_cache, v_cache, table, start, true_len, *,
     table, scratch-padded; start/true_len: scalar int32. Returns
     (C, H, d) in q's dtype.
 
-    Each KV block is located through the block table via a
-    scalar-prefetch index map and streamed through VMEM once; blocks
-    past ``start + true_len`` (and blocks causally dead for the whole
-    q tile) are clamped to the tile's first table entry in the index
-    map — consecutive repeats of one block id cost no fresh DMA — and
-    skipped in-kernel. Blocks fully before the diagonal take a
-    mask-free path; only straddling blocks build the per-element mask.
-    ``window`` > 0 restricts attention to the trailing window
-    (mistral). GQA is native: q folds to (KVH, C*G, d) and both dots
-    batch over KVH — the dense path's repeat_kv copies never exist.
-    ``block_c``: chunk-token tile ("auto" = the autotune winner cache's
-    choice for this shape bucket; see autotuning/kernel_registry.py
-    'paged_chunk').
+    The grid is the work list (:func:`chunk_work_list`: made here when
+    ``work`` is not given; a model makes it once for all its layers), so
+    its length is a device scalar and the call cannot be ``vmap``ped: a
+    grid step for every run of table entries that holds a key some query
+    of the tile attends, none for the table's tail past ``start +
+    true_len``, for entries after the tile's last query or before its
+    window. Each run's KV blocks are located through the block table via
+    scalar-prefetch index maps and streamed through VMEM; runs fully
+    before the diagonal take a mask-free path; only straddling runs
+    build the per-element mask. ``window`` > 0 restricts attention to the
+    trailing window (mistral). GQA is native: q folds to (KVH, C*G, d)
+    and both dots batch over the KV heads of a step — the dense path's
+    repeat_kv copies never exist. ``block_c``: chunk-token tile ("auto" =
+    the autotune winner cache's choice for this shape bucket, see
+    autotuning/kernel_registry.py 'paged_chunk'; that and 0 = from the
+    call's shapes, :func:`chunk_tile`).
     """
     C, H, d = q.shape
     NB, KVH, BS, _ = k_cache.shape
@@ -851,12 +1031,15 @@ def paged_chunk_attention(q, k_cache, v_cache, table, start, true_len, *,
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
         interpret = _interpret_default()
-    if block_c == "auto":
-        block_c = resolve_paged_chunk(
-            True, "auto", C, MB, BS, KVH, G, d, q.dtype)[1]
-    BC = max(1, min(int(block_c), C))
-    NC = -(-C // BC)
-    C_pad = NC * BC
+    if work is None:
+        if block_c == "auto":
+            block_c = resolve_paged_chunk(
+                True, "auto", C, MB, BS, KVH, G, d, q.dtype)[1]
+        work = chunk_work_list(
+            start, true_len, C, MB, BS, window,
+            chunk_tile(C, KVH, G, d, BS, MB, k_cache.dtype, block_c))
+    BC = work.tile.block_c
+    C_pad = -(-C // BC) * BC
     if C_pad != C:
         q = jnp.pad(q, ((0, C_pad - C), (0, 0), (0, 0)))
     # fold (chunk, group) query rows: (C_pad, KVH, G, d) -> (KVH, C_pad*G, d)
@@ -864,41 +1047,10 @@ def paged_chunk_attention(q, k_cache, v_cache, table, start, true_len, *,
         .reshape(KVH, C_pad * G, d)
     meta = jnp.stack([jnp.asarray(start, jnp.int32),
                       jnp.asarray(true_len, jnp.int32)])
-
-    def kv_index(i, j, tbl, meta):
-        s0 = meta[0]
-        limit = meta[0] + meta[1]
-        q_lo = s0 + i * BC
-        live = (j * BS < limit) & (j * BS <= q_lo + BC - 1)
-        if window:
-            live = live & (j * BS + BS - 1 > q_lo - window)
-        return (jnp.where(live, tbl[j], tbl[0]), 0, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(NC, MB),
-        in_specs=[
-            pl.BlockSpec((KVH, BC * G, d),
-                         lambda i, j, tbl, meta: (0, i, 0)),
-            pl.BlockSpec((1, KVH, BS, d), kv_index),
-            pl.BlockSpec((1, KVH, BS, d), kv_index),
-        ],
-        out_specs=pl.BlockSpec((KVH, BC * G, d),
-                               lambda i, j, tbl, meta: (0, i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((KVH, BC * G, 128), jnp.float32),  # running max
-            pltpu.VMEM((KVH, BC * G, 128), jnp.float32),  # running denom
-            pltpu.VMEM((KVH, BC * G, d), jnp.float32),    # out accumulator
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_chunk_kernel, BS=BS, KVH=KVH, G=G, BC=BC,
-                          scale=float(scale), window=int(window)),
-        name="dstpu.kernel.paged_chunk",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((KVH, C_pad * G, d), q.dtype),
-        interpret=interpret,
-    )(table, meta, qf, k_cache, v_cache)
+    out = _chunk_call(table, meta, work.tile_of, work.entry_of, work.n, qf,
+                      k_cache, v_cache, tile=work.tile, G=G,
+                      scale=float(scale), window=int(window),
+                      interpret=bool(interpret))
     out = out.reshape(KVH, C_pad, G, d).transpose(1, 0, 2, 3) \
         .reshape(C_pad, H, d)
     return out[:C]

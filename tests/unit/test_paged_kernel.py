@@ -39,18 +39,24 @@ def _pristine_dispatch(tmp_path, monkeypatch):
 
 
 def _chunk_case(C, H, KVH, d, NB, BS, MB, start, true_len, window,
-                block_c, dtype=jnp.float32, seed=0):
+                block_c, dtype=jnp.float32, seed=0, tile=None):
+    """``tile``: (tokens, KV heads, table entries) a grid step, where the
+    case says all three; else the shape rule's under ``block_c``."""
     ks = jax.random.split(jax.random.key(seed), 4)
     q = jax.random.normal(ks[0], (C, H, d), dtype)
     kc = jax.random.normal(ks[1], (NB, KVH, BS, d), dtype)
     vc = jax.random.normal(ks[2], (NB, KVH, BS, d), dtype)
     tbl = jax.random.randint(ks[3], (MB,), 0, NB, jnp.int32)
+    work = tile and paged_attention.chunk_work_list(
+        start, true_len, C, MB, BS, window, paged_attention.ChunkTile(*tile))
     out = paged_chunk_attention(q, kc, vc, tbl, jnp.int32(start),
                                 jnp.int32(true_len), window=window,
-                                block_c=block_c)
+                                block_c=block_c, work=work)
     ref = paged_chunk_attention_reference(
         q, kc, vc, tbl, jnp.int32(start), jnp.int32(true_len),
         window=window)
+    # pad rows too: nobody reads them, and they are finite
+    assert np.isfinite(np.asarray(out, np.float32)).all()
     tol = dict(rtol=5e-2, atol=5e-2) if dtype == jnp.bfloat16 \
         else dict(rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(
@@ -89,6 +95,101 @@ class TestChunkKernelParity:
                     window=0, block_c=8)
         _chunk_case(24, 4, 2, 32, 12, 16, 4, start=0, true_len=17,
                     window=0, block_c=128)
+
+
+    # ISSUE 58: the grid is a work list of runs of live entries, a step is
+    # tokens x KV heads x entries. C, H, KVH, d, NB, BS, MB, then the chunk
+    @pytest.mark.parametrize("case", [
+        # a long table behind a short context: 29 of 32 entries are tail
+        dict(shape=(32, 8, 1, 128, 40, 16, 32), start=16, true_len=32,
+             rule=(32, 1, 8)),
+        dict(shape=(32, 8, 1, 128, 40, 16, 32), start=16, true_len=32,
+             tile=(8, 1, 3)),
+        # the context ends on a query tile's edge and on a block's
+        dict(shape=(32, 4, 4, 64, 40, 16, 32), start=96, true_len=32,
+             tile=(16, 2, 1)),
+        dict(shape=(32, 4, 4, 64, 40, 16, 32), start=96, true_len=16,
+             tile=(16, 4, 1)),
+        # true_len < C: tiles 2 and 3 of four are pads alone
+        dict(shape=(32, 16, 2, 128, 40, 16, 32), start=100, true_len=9,
+             tile=(8, 1, 4)),
+        dict(shape=(32, 16, 2, 128, 40, 16, 32), start=100, true_len=9,
+             window=24, tile=(8, 2, 2)),
+        # a window cuts a tile's first entries: 7 entries behind, 2 attended
+        dict(shape=(16, 4, 2, 128, 40, 16, 16), start=120, true_len=16,
+             window=20, tile=(8, 2, 2)),
+        dict(shape=(16, 4, 2, 32, 40, 16, 16), start=120, true_len=13,
+             window=33, block_c=8),
+        # G = 8 (solar-open2's fold), bf16, at 1, 2, 4 and 5 entries a step
+        *[dict(shape=(16, 16, 2, 128, 30, 16, 12), start=70, true_len=16,
+               dtype=jnp.bfloat16, tile=(8, heads, n))
+          for heads, n in ((2, 1), (1, 2), (2, 4), (1, 5))],
+        # rows under the lanes, which the pipeline brings like any other
+        dict(shape=(32, 8, 8, 64, 30, 16, 12), start=40, true_len=30,
+             rule=(32, 8, 8)),
+        dict(shape=(32, 8, 8, 64, 30, 16, 12), start=40, true_len=30,
+             block_c=16),
+        # an explicit block_c that does not divide C, under GQA
+        dict(shape=(20, 8, 2, 128, 30, 16, 12), start=50, true_len=20,
+             block_c=8, dtype=jnp.bfloat16),
+    ], ids=lambda c: "-".join(f"{k}={v}" for k, v in c.items()
+                              if k not in ("shape", "dtype")))
+    def test_work_list_grid(self, case):
+        case = dict(case)
+        shape = case.pop("shape")
+        if "rule" in case:                   # what the shapes alone give
+            assert paged_attention.chunk_tile(
+                shape[0], shape[2], shape[1] // shape[2], shape[3],
+                shape[5], shape[6], jnp.float32) == case.pop("rule")
+        _chunk_case(*shape, window=case.pop("window", 0),
+                    block_c=case.pop("block_c", 0), **case)
+
+
+def _chunk_items_by_hand(start, true_len, C, MB, BS, window, BC, N):
+    """The chunk kernel's work list, position by position: for each query
+    tile the table entries that hold a real key some real query of it
+    attends, in runs of N from the first."""
+    items = []
+    for t in range(-(-C // BC)):
+        entries = sorted({k // BS
+                          for q in range(start + t * BC,
+                                         min(start + (t + 1) * BC,
+                                             start + true_len))
+                          for k in range(max(0, q - window + 1)
+                                         if window else 0, q + 1)})
+        # a window's first entry is the tile's FIRST query's, and a run
+        # has no gaps; a tile of pads alone keeps one item
+        runs = range(entries[0], entries[-1] + 1, N) if entries else \
+            [min(max(0, start + t * BC - window + 1) // BS, MB - 1)
+             if window else 0]
+        items += [(t, e) for e in runs]
+    return items
+
+
+class TestChunkWorkList:
+    @pytest.mark.parametrize("N", [1, 2, 5])
+    @pytest.mark.parametrize("BC", [8, 16, 24])
+    @pytest.mark.parametrize("window", [0, 20, 47])
+    @pytest.mark.parametrize("start, true_len", [
+        (0, 48), (0, 5), (37, 48), (64, 48), (80, 17), (150, 48), (96, 1)])
+    def test_items_are_the_live_runs(self, start, true_len, window, BC, N):
+        """:func:`chunk_work_list` on the device and
+        :func:`chunk_grid_steps` on the host against the enumeration."""
+        C, MB, BS = 48, 16, 16
+        tile = paged_attention.ChunkTile(BC, 2, N)
+        work = paged_attention.chunk_work_list(
+            jnp.int32(start), jnp.int32(true_len), C, MB, BS, window, tile)
+        want = _chunk_items_by_hand(start, true_len, C, MB, BS, window, BC,
+                                    N)
+        n = int(work.n)
+        assert list(zip(np.asarray(work.tile_of)[:n].tolist(),
+                        np.asarray(work.entry_of)[:n].tolist())) == want
+        # the sentinel the kernel's store looks for, inside the arrays
+        assert n < work.tile_of.shape[0]
+        assert (np.asarray(work.tile_of)[n:] == -(-C // BC)).all()
+        assert paged_attention.chunk_grid_steps(
+            start, true_len, C, 6, MB, BS, window, tile) == 3 * len(want)
+        assert len(want) <= -(-C // BC) * -(-MB // N)
 
 
 _CFG = GPT2Config(n_layer=2, n_head=4, d_model=64, max_seq_len=128,

@@ -428,6 +428,55 @@ def test_decode_grid_counter(monkeypatch, splitfuse_tokens, per_step):
     assert (want == runs) == (per_step == 1)
 
 
+@pytest.mark.parametrize("block_c", ["auto", 8])
+def test_chunk_grid_counter(monkeypatch, block_c):
+    """``chunk_grid_steps`` / ``chunk_table_steps`` on every dispatch span
+    that carries a chunk (ISSUE 58): the grid steps one paged-chunk kernel
+    call takes over the chunk's ``chunk_tokens`` real tokens of 16 from
+    ``chunk_start`` — counted here key by key: for each query tile the
+    table entries from 0 to its last real query's, in runs of the tile's
+    entries, times the blocks of KV heads — against query tiles x table
+    entries; 0 / 0 where the dispatch has no chunk."""
+    from deepspeed_tpu.models import paged
+    from deepspeed_tpu.ops.pallas.paged_attention import chunk_tile
+    kernel_dispatch.reset()
+    groups.reset()
+    model = GPT2(_CFG)
+    engine = InferenceEngineV2(
+        model, params=model.init(jax.random.key(0)),
+        config=dict(_BASE, splitfuse_tokens=16, paged_block_c=block_c))
+    stats, real_span = [], engine_v2.span
+
+    def recording_span(name, **st):
+        if name == "dstpu.engine.dispatch":
+            stats.append(st)
+        return real_span(name, **st)
+
+    monkeypatch.setattr(engine_v2, "span", recording_span)
+    _serve(Router([Replica("r0", engine)]))
+    C, BS, MB, KVH, d = 16, 8, 128 // 8, _CFG.n_head, _CFG.d_head
+    tile = chunk_tile(C, KVH, 1, d, BS, MB, "float32",
+                      0 if block_c == "auto" else block_c)
+    assert tile == paged._chunk_kernel(paged.geometry(model), C, MB, BS)[1]
+    assert tile.block_c == (16 if block_c == "auto" else 8)
+    chunks = [st for st in stats if st["chunk_tokens"]]
+    assert len(chunks) >= N_REQUESTS and len(chunks) < len(stats)
+    for st in chunks:
+        start, n = st["chunk_start"], st["chunk_tokens"]
+        want = 0
+        for t in range(C // tile.block_c):
+            q_lo = start + t * tile.block_c
+            last = min(q_lo + tile.block_c, start + n) - 1
+            want += -(-(last // BS + 1) // tile.entries) \
+                if q_lo < start + n else 1
+        assert st["chunk_grid_steps"] == want * (KVH // tile.heads)
+        assert st["chunk_table_steps"] == C // tile.block_c * MB
+        assert st["chunk_grid_steps"] \
+            < st["chunk_table_steps"] * (KVH // tile.heads)
+    assert all(st["chunk_grid_steps"] == st["chunk_table_steps"] == 0
+               for st in stats if not st["chunk_tokens"])
+
+
 @pytest.mark.parametrize("splitfuse_tokens", [0, 16])
 def test_kv_write_counter(monkeypatch, splitfuse_tokens):
     """``write_rows`` / ``write_rows_offered`` on every dispatch span, and

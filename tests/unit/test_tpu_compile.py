@@ -10,6 +10,7 @@ to a Mosaic custom call. Nothing executes, so nothing is said about
 results or speed. Skipped where the topology cannot be described.
 """
 
+import functools
 import json
 import os
 import re
@@ -94,9 +95,25 @@ def _flash_mla(grad):
     return jax.grad(loss, argnums=(0, 1, 2)) if grad else loss
 
 
-def _paged_chunk(q, kc, vc, table, start, true_len):
+def _paged_chunk(q, kc, vc, table, start, true_len, block_c=128):
     return paged_chunk_attention(q, kc, vc, table, start, true_len,
-                                 block_c=128, interpret=False)
+                                 block_c=block_c, interpret=False)
+
+
+def _paged_chunk_shapes(c, h, kvh, hd, mb, nb):
+    return [((c, h, hd), bf16)] + [((nb, kvh, BS, hd), bf16)] * 2 \
+        + [((mb,), i32), ((), i32), ((), i32)]
+
+
+# the chunk kernel at the long-prompt cells' calls (ISSUE 58): chunk
+# tokens, query heads, KV heads, head dim, table entries, pool blocks, the
+# engine's paged_block_c (0 = "auto": the tile is the shape rule's) -> the
+# (tokens, KV heads, table entries) of a grid step
+PAGED_CHUNK_CELLS = {
+    "cell12_solar_open2": ((1024, 64, 8, 128, 528, 8192, 0), (64, 2, 8)),
+    "cell9_olmo_hybrid": ((1024, 30, 30, 128, 136, 1024, 0), (128, 6, 8)),
+    "cell4_opt1.3b_pinned": ((256, 32, 32, 64, 32, 128, 64), (64, 16, 8)),
+}
 
 
 def _paged_decode(q, kc, vc, tb, ln):
@@ -172,6 +189,10 @@ CASES = {
     "paged_prefill_c320": (
         _paged_chunk,
         [((320, H, HD), bf16)] + POOL + [((5,), i32), ((), i32), ((), i32)]),
+    **{f"paged_chunk_{cell}": (
+        functools.partial(_paged_chunk, block_c=shape[-1]),
+        _paged_chunk_shapes(*shape[:-1]))
+       for cell, (shape, _) in PAGED_CHUNK_CELLS.items()},
     # the gated delta rule's two kernels at cell 9's shapes
     "gdn_chunk_1024x30x96x192": (
         lambda *a: chunk_rule_kernel(*a, interpret=False),
@@ -227,6 +248,39 @@ def test_paged_decode_is_the_custom_call_the_trace_reader_finds(v5e, cell):
         pattern = json.load(f)["kernels"]["paged_decode"]["pattern"]
     assert re.search(pattern, f"{head} custom-call({operands}), "
                      'custom_call_target="tpu_custom_call"')
+
+
+@pytest.mark.parametrize("cell", sorted(PAGED_CHUNK_CELLS))
+def test_paged_chunk_is_the_custom_call_the_trace_reader_finds(v5e, cell):
+    """The chunk kernel's grid length is a device scalar too (ISSUE 58),
+    and its step takes a block of the KV heads and several table entries:
+    it must still lower to one Mosaic custom call whose output is the one
+    rank-3 (KV heads, rows, head dim) array and whose first operands are
+    the int32 scalars — ``perfbench/trace_names.json``'s ``paged_chunk``
+    pattern, which ``paged_chunk_roofline`` finds the kernel by — and not
+    one the ``paged_decode`` pattern takes for its own. The tile is the
+    shape rule's."""
+    (c, h, kvh, hd, mb, nb, block_c), tile = PAGED_CHUNK_CELLS[cell]
+    from deepspeed_tpu.ops.pallas.paged_attention import chunk_tile
+    assert chunk_tile(c, kvh, h // kvh, hd, BS, mb, bf16, block_c) == tile
+    text = _compile(functools.partial(_paged_chunk, block_c=block_c),
+                    _paged_chunk_shapes(c, h, kvh, hd, mb, nb), v5e)
+    calls = [ln.strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(calls) == 1, calls
+    head = calls[0].split(" custom-call(")[0]
+    operands = calls[0].split("operand_layout_constraints={")[1]
+    operands = re.sub(r"\{[\d,]*\}", "", operands).split("}")[0]
+    assert head.split(" = ")[1].startswith(
+        f"bf16[{kvh},{c * (h // kvh)},{hd}]")
+    assert operands.split(", ")[:3] == ["s32[]", f"s32[{mb}]", "s32[2]"]
+    with open(os.path.join(os.path.dirname(__file__), "..", "..",
+                           "perfbench", "trace_names.json")) as f:
+        kernels = json.load(f)["kernels"]
+    event = f"{head} custom-call({operands}), " \
+        'custom_call_target="tpu_custom_call"'
+    assert re.search(kernels["paged_chunk"]["pattern"], event)
+    assert not re.search(kernels["paged_decode"]["pattern"], event)
 
 
 # the decode kernel where a grid step takes several table entries (ISSUE
@@ -611,33 +665,35 @@ def test_olmoe_programs_take_the_expert_kernel(v5e, monkeypatch, T):
 P4_KV, P4_G, P4_W = 10, 4, 128
 
 
-@pytest.mark.parametrize("block_c, fits", [(128, False), (32, True)])
-def test_chunk_tile_under_gqa_folds(v5e, block_c, fits):
+@pytest.mark.parametrize("block_c, tile", [(0, (64, 2, 8)),
+                                           (128, (128, 2, 8)),
+                                           (32, (32, 5, 8))])
+def test_chunk_tile_under_gqa_folds(v5e, block_c, tile):
     """The chunk kernel folds a KV head's G query heads into its tile's
-    rows: at 10 heads x 128 lanes a 128-token tile is 512 rows a head and
-    asks for more VMEM than a kernel may have, which refused every prefill
-    of the cell's first compile; ``models/paged.py`` caps an "auto" tile
-    at 128 // G, and that tile compiles."""
+    rows: at 10 heads x 128 lanes a 128-token tile of every head is 512
+    rows a head and asked for more VMEM than Mosaic's default gives a
+    kernel, which refused every prefill of the cell's first compile (ISSUE
+    30). Since ISSUE 58 a step takes a block of the heads and the call
+    asks for the VMEM its blocks need: the shape rule's tile and the
+    tokens an engine may pin compile, each with the heads and the entries
+    the rule puts round it."""
     from deepspeed_tpu.models import paged
     from deepspeed_tpu.models.phi4flash import PHI4_MINI_FLASH, Phi4Flash
     geom = Phi4Flash(PHI4_MINI_FLASH).paged_geometry()
     assert (geom.n_kv_heads, geom.n_head // geom.n_kv_heads, geom.d_head) \
         == (P4_KV, P4_G, P4_W)
-    assert paged._chunk_kernel(geom, 512, 64, BS) == (False, 32)  # on a CPU
+    assert paged._chunk_kernel(geom, 512, 64, BS) \
+        == (False, (64, 2, 8))                               # on a CPU
+    from deepspeed_tpu.ops.pallas.paged_attention import chunk_tile
+    assert chunk_tile(512, P4_KV, P4_G, P4_W, BS, 64, bf16, block_c) == tile
 
     def chunk(q, kc, vc, table, start, true_len):
         return paged_chunk_attention(q, kc, vc, table, start, true_len,
                                      scale=1.0, window=512,
                                      block_c=block_c, interpret=False)
 
-    shapes = [((512, P4_KV * P4_G, P4_W), bf16),
-              ((641, P4_KV, BS, P4_W), bf16), ((641, P4_KV, BS, P4_W), bf16),
-              ((64,), i32), ((), i32), ((), i32)]
-    if fits:
-        _compile(chunk, shapes, v5e)
-    else:
-        with pytest.raises(Exception, match="(?i)vmem|RESOURCE_EXHAUSTED"):
-            _compile(chunk, shapes, v5e)
+    _compile(chunk, _paged_chunk_shapes(512, P4_KV * P4_G, P4_KV, P4_W, 64,
+                                        641), v5e)
 
 
 def test_two_piece_product_keeps_its_rounding(v5e):
@@ -672,48 +728,46 @@ def _geometry(n_head, n_kv_heads, d_head, block_c="auto"):
 
 
 @pytest.mark.parametrize("name, heads, C, tile", [
-    ("cells-3-6-gpt2-medium", (16, 16, 64), 128, 128),
-    ("cell-4-opt-1.3b-pinned", (32, 32, 64, 64), 256, 64),
-    ("cells-5-8-olmoe", (16, 16, 128), 1024, 128),
-    ("cell-7-phi-4-mini-flash", (40, 10, 128), 512, 32),
-    ("cell-9-olmo-hybrid", (OH_KV, OH_KV, OH_W), OH_C, 64),
+    ("cells-3-6-gpt2-medium", (16, 16, 64), 128, (128, 8, 8)),
+    ("cell-4-opt-1.3b-pinned", (32, 32, 64, 64), 256, (64, 16, 8)),
+    ("cells-5-8-olmoe", (16, 16, 128), 1024, (128, 8, 8)),
+    ("cell-7-phi-4-mini-flash", (40, 10, 128), 512, (64, 2, 8)),
+    ("cell-9-olmo-hybrid", (OH_KV, OH_KV, OH_W), OH_C, (128, 6, 8)),
+    ("cell-12-solar-open2", (64, 8, 128), 1024, (64, 2, 8)),
 ], ids=lambda x: x if isinstance(x, str) else "")
 def test_auto_chunk_tile_of_the_served_shapes(monkeypatch, name, heads, C,
                                               tile):
-    """``"auto"`` resolves the chunk kernel's tile from KV heads x G x head
-    dim: what it gives every shape the benchmark ran before ISSUE 41 is
-    what it gave (cell 4 pins its own), and 30 x 128 gets the largest
-    power of two that keeps KV heads x rows x lanes inside the 16 x 128 x
-    128 the served shapes compile with."""
+    """``"auto"`` reads the chunk kernel's tile off KV heads x G x head
+    dim (``paged_attention.chunk_tile``, ISSUE 58): 128 rows a head but
+    never under 64 tokens, then as many KV heads as keep q inside 256 KB,
+    then 512 keys of table entries, eight at most (cell 4 pins its
+    tokens, and the heads and entries follow)."""
     from deepspeed_tpu.models import paged
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert paged._chunk_kernel(_geometry(*heads), C, 64, BS) == (True, tile)
 
 
-@pytest.mark.parametrize("block_c, fits", [(128, False), (64, True)])
-def test_chunk_tile_at_thirty_heads_of_128(v5e, monkeypatch, block_c, fits):
-    """30 heads x 128 rows x 128 lanes ask for more VMEM than a kernel may
-    have (the tile a cold winner cache gives); the 64 rows ``"auto"``
-    resolves to from the shape compile, at the cell's C = 1024 over a
-    table of 136 blocks."""
+@pytest.mark.parametrize("block_c, tile", [(128, (128, 6, 8)),
+                                           (64, (64, 15, 8))])
+def test_chunk_tile_at_thirty_heads_of_128(v5e, monkeypatch, block_c, tile):
+    """30 heads x 128 rows x 128 lanes asked for more VMEM than a kernel
+    may have while a step took every head (ISSUE 41: the tile a cold
+    winner cache gave; "auto" fell back to 64 rows). A step takes a block
+    of the heads now, so the tokens an engine pins compile at the cell's C
+    = 1024 over a table of 136 blocks, with the heads that fit beside
+    them."""
     from deepspeed_tpu.models import paged
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    if fits:
-        assert paged._chunk_kernel(
-            _geometry(OH_KV, OH_KV, OH_W), OH_C, OH_MB, BS) == (True, block_c)
+    assert paged._chunk_kernel(
+        _geometry(OH_KV, OH_KV, OH_W, block_c), OH_C, OH_MB, BS) \
+        == (True, tile)
 
     def chunk(q, kc, vc, table, start, true_len):
         return paged_chunk_attention(q, kc, vc, table, start, true_len,
                                      block_c=block_c, interpret=False)
 
-    shapes = [((OH_C, OH_KV, OH_W), bf16)] \
-        + [((OH_NB, OH_KV, BS, OH_W), bf16)] * 2 \
-        + [((OH_MB,), i32), ((), i32), ((), i32)]
-    if fits:
-        _compile(chunk, shapes, v5e)
-    else:
-        with pytest.raises(Exception, match="(?i)vmem|RESOURCE_EXHAUSTED"):
-            _compile(chunk, shapes, v5e)
+    _compile(chunk, _paged_chunk_shapes(OH_C, OH_KV, OH_KV, OH_W, OH_MB,
+                                        OH_NB), v5e)
 
 
 def _olmo_hybrid_programs(model):
